@@ -9,6 +9,7 @@ it selects which host loops exist based on role and distributed-ness.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Optional
 
@@ -67,8 +68,6 @@ def _init_jax_distributed(cfg: Config) -> None:
     global _jax_distributed_up
     if _jax_distributed_up:
         return
-    import os
-
     import jax
 
     kwargs = {}
@@ -95,10 +94,32 @@ def _init_jax_distributed(cfg: Config) -> None:
     _jax_distributed_up = True
 
 
-def init_state(fresh_env: bool = True) -> RuntimeState:
-    """Bring the process up (global.cc:105-297 + operations.cc:41-88)."""
+#: the checkout (or install prefix) that holds the ``byteps_tpu`` package
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def place_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a home before the first
+    program is built; returns the directory in force.
+
+    Placed from outside when ``JAX_COMPILATION_CACHE_DIR`` is set — jax
+    reads the variable itself and nothing is set in code.  Otherwise
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    how a later process finds the entries again — never a temporary name,
+    a pid or a time."""
     import jax
 
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(_CHECKOUT, ".jax_cache")
+        )
+    return jax.config.jax_compilation_cache_dir
+
+
+def init_state(fresh_env: bool = True) -> RuntimeState:
+    """Bring the process up (global.cc:105-297 + operations.cc:41-88)."""
     from byteps_tpu.comm.mesh import build_mesh, set_global_mesh
     from byteps_tpu.core.telemetry import PushPullSpeed
     from byteps_tpu.core.tracing import Tracer
@@ -120,10 +141,9 @@ def init_state(fresh_env: bool = True) -> RuntimeState:
         # multi-host JAX runtime (pod slices): opt-in coordinator bring-up —
         # the scheduler-node analogue for the ICI/DCN collective plane
         # (SURVEY §5.8: coordinator ↔ jax.distributed.initialize)
-        import os
-
         if os.environ.get("BYTEPS_JAX_DISTRIBUTED", "0") == "1":
             _init_jax_distributed(cfg)
+        place_compile_cache()
         st.mesh = build_mesh(cfg.mesh_shape)
         set_global_mesh(st.mesh)
         st.telemetry = PushPullSpeed(enabled=cfg.telemetry_on)

@@ -282,10 +282,7 @@ def _vary_all(x, mesh: Mesh):
         return x
 
     def cast(a):
-        try:
-            have = set(jax.typeof(a).vma)
-        except AttributeError:
-            have = set()
+        have = jax.typeof(a).vma
         need = tuple(ax for ax in all_axes if ax not in have)
         return lax.pcast(a, need, to="varying") if need else a
 

@@ -17,6 +17,8 @@ from typing import Optional
 
 import numpy as np
 
+from byteps_tpu.common.logging import logger
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libbyteps_tpu.so")
 
@@ -66,10 +68,12 @@ _lib: Optional[ctypes.CDLL] = None
 
 def _try_build() -> None:
     """Run make under a file lock: many worker processes import this module
-    concurrently on a fresh checkout, and only one should compile."""
-    try:
-        import fcntl
+    concurrently on a fresh checkout, and only one should compile.  A
+    failed build is logged, not raised — the numpy paths take over — so a
+    run that must have the native core checks ``HAVE_NATIVE``."""
+    import fcntl
 
+    try:
         with open(os.path.join(_DIR, ".build.lock"), "w") as lockf:
             fcntl.flock(lockf, fcntl.LOCK_EX)
             subprocess.run(
@@ -78,8 +82,13 @@ def _try_build() -> None:
                 capture_output=True,
                 timeout=120,
             )
-    except Exception:
-        pass
+    except subprocess.CalledProcessError as e:
+        logger.warning(
+            "native build failed (rc %s), using the numpy paths: %s",
+            e.returncode, e.stderr.decode(errors="replace")[-400:],
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning("native build did not run, using the numpy paths: %r", e)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -270,8 +279,9 @@ def _load() -> Optional[ctypes.CDLL]:
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None  # corrupt/partial .so → pure-Python fallbacks
+    except OSError as e:  # corrupt/partial .so → pure-Python fallbacks
+        logger.warning("cannot load %s, using the numpy paths: %s", _LIB_PATH, e)
+        return None
     if not hasattr(lib, "bps_wire_lossless_compress") and autobuild:
         # stale library from before the newest entry points (currently
         # the lossless wire-frame codec plane): rebuild, then

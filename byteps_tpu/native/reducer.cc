@@ -4,9 +4,8 @@
 // TPU-native re-design of the reference's cpu_reducer.cc (SURVEY §2.1):
 // OpenMP-parallel elementwise sum over the wire dtypes.  The reference
 // hand-rolls AVX+F16C intrinsics for fp16; we let the compiler
-// auto-vectorize (-O3 -march=native) for fp32/fp64/int types and provide
-// explicit scalar conversion loops for fp16/bf16, which GCC vectorizes
-// with native ISA support where available.
+// auto-vectorize (-O3, baseline ISA — see the Makefile) for fp32/fp64/int
+// types and provide explicit scalar conversion loops for fp16/bf16.
 //
 // Exposed via a C ABI consumed through ctypes (no pybind11 in this image).
 

@@ -15,8 +15,10 @@ Backward: the standard two-kernel split —
 Both recompute P = exp(QKᵀ·scale − lse) blockwise (no saved probabilities)
 using the forward's logsumexp and Δ = rowsum(dO ∘ O).
 
-Fully-masked causal blocks skip all matmuls via pl.when.  Dense jnp
-fallback off-TPU or for non-divisible shapes; differentiable end to end.
+Fully-masked causal blocks skip all matmuls via pl.when.  On a TPU the
+kernels are the only path (a sequence the blocks do not divide raises);
+off a TPU the dense reference stands in — see :func:`_kernel_path`, the one
+place that decides.  Differentiable end to end.
 
 This is the per-device compute of the transformer's attention; sequence
 parallelism composes on top (ring attention rotates KV blocks *between*
@@ -124,18 +126,6 @@ def _fwd_kernel_factory(dh, bq, bk, nk, causal, scale):
     return kernel
 
 
-# vma typing (varying-manual-axes) exists from jax 0.7+; on older versions
-# ShapeDtypeStruct has no vma kwarg, so callers must omit it entirely.
-# Probe by construction, not introspection: a wrapped/C-accelerated
-# __init__ would hide the kwarg from co_varnames and silently break
-# shard_map(check_vma=True).
-try:
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _HAS_VMA = True
-except TypeError:
-    _HAS_VMA = False
-
-
 def _vma_union(*xs):
     """Union of the inputs' varying-manual-axes sets, for pallas out_shapes.
 
@@ -150,7 +140,7 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    vma_kw = {"vma": _vma_union(q, k, v)} if _HAS_VMA else {}
+    vma = _vma_union(q, k, v)
     b, h, s, dh = q.shape
     nk = s // bk
     bh = b * h
@@ -160,8 +150,8 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
     out, lse = pl.pallas_call(
         _fwd_kernel_factory(dh, bq, bk, nk, causal, scale),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dh), q.dtype, **vma_kw),
-            jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32, **vma_kw),
+            jax.ShapeDtypeStruct((bh, s, dh), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32, vma=vma),
         ),
         grid=(bh, s // bq, nk),
         in_specs=[
@@ -285,7 +275,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    vma_kw = {"vma": _vma_union(q, k, v, o, lse, do)} if _HAS_VMA else {}
+    vma = _vma_union(q, k, v, o, lse, do)
     b, h, s, dh = q.shape
     bh = b * h
     nq, nk = s // bq, s // bk
@@ -304,7 +294,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
 
     dq = pl.pallas_call(
         _bwd_dq_kernel_factory(dh, bq, bk, nk, causal, scale),
-        out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype, **vma_kw),
+        out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype, vma=vma),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
@@ -325,8 +315,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     dk, dv = pl.pallas_call(
         _bwd_dkv_kernel_factory(dh, bq, bk, nq, causal, scale),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dh), k.dtype, **vma_kw),
-            jax.ShapeDtypeStruct((bh, s, dh), v.dtype, **vma_kw),
+            jax.ShapeDtypeStruct((bh, s, dh), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s, dh), v.dtype, vma=vma),
         ),
         grid=(bh, nk, nq),
         in_specs=[
@@ -429,10 +419,9 @@ def tuned_blocks(seq: int) -> tuple:
     """Best (block_q, block_k) for this sequence length, from the on-chip
     sweep artifact (tools/flash_tune.py → ops/flash_blocks.json).  Falls
     back to the nearest tuned seq below whose blocks DIVIDE this seq
-    (block choice varies slowly with S, but a non-dividing block would
-    silently demote the kernel to the dense fallback), then to
-    (128, 128) — the MXU-aligned safe default.  Callers passing explicit
-    block sizes bypass this table."""
+    (block choice varies slowly with S, and a non-dividing block is an
+    error on a TPU), then to (128, 128) — the MXU-aligned safe default.
+    Callers passing explicit block sizes bypass this table."""
     table = _tuned_table()
 
     def fits(entry) -> bool:
@@ -445,6 +434,43 @@ def tuned_blocks(seq: int) -> tuple:
     if below:
         return table[max(below)]
     return (128, 128)
+
+
+def _platform() -> str:
+    """Platform of the default device (a function so tests can stand in a
+    TPU without mocking devices)."""
+    return jax.devices()[0].platform
+
+
+def _kernel_path(s: int, bq: int, bk: int, interpret: bool) -> bool:
+    """THE decision between the Pallas kernels (True) and the dense
+    reference (False) — the only place either wrapper makes it.
+
+    On a TPU the kernels always run: neither ``interpret`` nor anything
+    else selects the reference there, and a sequence the blocks do not
+    divide raises instead of quietly costing an S×S score matrix.
+
+    Off a TPU (the CPU test harness; Mosaic cannot compile there) the
+    dense reference stands in, unless the caller asked for the Pallas
+    interpreter and the blocks divide."""
+    divides = s % bq == 0 and s % bk == 0
+    if _platform() == "tpu":
+        if not divides:
+            raise ValueError(
+                f"flash attention: blocks ({bq}, {bk}) do not divide seq {s}; "
+                "pad the sequence or pass block_q/block_k that divide it"
+            )
+        return True
+    return interpret and divides
+
+
+def _resolve(q, scale, block_q, block_k):
+    """Defaults filled in: (scale, block_q, block_k) for this q."""
+    s, dh = q.shape[2], q.shape[3]
+    tq, tk = tuned_blocks(s)
+    bq = min(block_q if block_q is not None else tq, s)
+    bk = min(block_k if block_k is not None else tk, s)
+    return (scale if scale is not None else dh**-0.5), bq, bk
 
 
 def flash_attention_lse(
@@ -463,13 +489,8 @@ def flash_attention_lse(
     o = o_a·e^{L_a−L} + o_b·e^{L_b−L}).  Differentiable in (q, k, v)
     including the lse output (its cotangent folds into the backward's
     delta term)."""
-    b, h, s, dh = q.shape
-    scale = scale if scale is not None else dh**-0.5
-    tq, tk = tuned_blocks(s)
-    bq = min(block_q if block_q is not None else tq, s)
-    bk = min(block_k if block_k is not None else tk, s)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if (s % bq or s % bk) or (not on_tpu and not interpret):
+    scale, bq, bk = _resolve(q, scale, block_q, block_k)
+    if not _kernel_path(q.shape[2], bq, bk, interpret):
         return _dense_reference_lse(q, k, v, causal, scale)
     return _flash_lse(q, k, v, causal, scale, bq, bk, interpret)
 
@@ -486,15 +507,10 @@ def flash_attention(
 ) -> jax.Array:
     """q/k/v: (B, H, S, dh) → (B, H, S, dh).
 
-    Pallas kernels (fwd + blocked bwd) when on TPU and S divides the block
-    sizes; dense jnp fallback otherwise.
+    Pallas kernels (fwd + blocked bwd) on a TPU, where S must divide by
+    the block sizes; what runs elsewhere is :func:`_kernel_path`'s call.
     """
-    b, h, s, dh = q.shape
-    scale = scale if scale is not None else dh**-0.5
-    tq, tk = tuned_blocks(s)
-    bq = min(block_q if block_q is not None else tq, s)
-    bk = min(block_k if block_k is not None else tk, s)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if (s % bq or s % bk) or (not on_tpu and not interpret):
+    scale, bq, bk = _resolve(q, scale, block_q, block_k)
+    if not _kernel_path(q.shape[2], bq, bk, interpret):
         return _dense_reference(q, k, v, causal, scale)
     return _flash(q, k, v, causal, scale, bq, bk, interpret)

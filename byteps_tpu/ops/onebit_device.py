@@ -10,8 +10,10 @@ Wire format matches the host codec exactly ([f32 scale][u32 words],
 bit = negative — native/compressor.cc), so the server's C++ decompressor
 consumes device-compressed payloads unchanged.
 
-The packing is a Pallas kernel on TPU (sublane reduction over a 32-wide
-bit-weight expansion) with a jnp fallback elsewhere.
+The packing is a Pallas kernel (lane reduction over a 32-wide bit-weight
+expansion).  On a TPU it is the only packer: any length is zero-padded on
+the device to the kernel's block.  Off a TPU :func:`_pack_jnp` stands in —
+see ``onebit_compress_device``, the one place that decides.
 """
 
 from __future__ import annotations
@@ -23,36 +25,40 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _pack_jnp(flat: jax.Array, scaling: bool) -> tuple:
-    n = flat.shape[0]
-    scale = jnp.where(
-        scaling, jnp.sum(jnp.abs(flat)) / n, jnp.float32(1.0)
+def _scale(flat: jax.Array, scaling: bool) -> jax.Array:
+    """The codec's L1 scale sum|x|/n (1.0 without scaling), f32 scalar."""
+    return jnp.where(
+        scaling, jnp.sum(jnp.abs(flat)) / flat.shape[0], jnp.float32(1.0)
     ).astype(jnp.float32)
-    pad = (-n) % 32
+
+
+def _pack_jnp(flat: jax.Array, scaling: bool) -> tuple:
+    pad = (-flat.shape[0]) % 32
     bits = jnp.signbit(jnp.pad(flat, (0, pad))).astype(jnp.uint32).reshape(-1, 32)
     weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[None, :]
     words = jnp.sum(bits * weights, axis=1).astype(jnp.uint32)
-    return scale, words
+    return _scale(flat, scaling), words
 
 
-def _pack_kernel(words_per_block: int):
-    from jax.experimental import pallas as pl
+#: words per grid cell → one native (8, 128) u32 output tile
+_WPB = 1024
+#: elements per grid cell; inputs are zero-padded to a multiple of this
+_BLOCK = 32 * _WPB
 
-    def kernel(x_ref, out_ref):
-        # x block: (words_per_block, 32) fp32; out block: (8, wpb/8) u32.
-        # Mosaic has no unsigned reductions: accumulate in int32 — the
-        # weights are distinct powers of two, so the wrapping sum is exactly
-        # the bitwise OR pattern — and bitcast at the store.
-        bits = jnp.signbit(x_ref[:]).astype(jnp.int32)
-        weights = jnp.left_shift(
-            jnp.int32(1), jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
-        )
-        acc = jnp.sum(bits * weights, axis=1)  # (words_per_block,)
-        out_ref[:] = jax.lax.bitcast_convert_type(
-            acc.reshape(out_ref.shape), jnp.uint32
-        )
 
-    return kernel
+def _pack_kernel(x_ref, out_ref):
+    # x block: (_WPB, 32) fp32; out block: (8, _WPB/8) u32.
+    # Mosaic has no unsigned reductions: accumulate in int32 — the
+    # weights are distinct powers of two, so the wrapping sum is exactly
+    # the bitwise OR pattern — and bitcast at the store.
+    bits = jnp.signbit(x_ref[:]).astype(jnp.int32)
+    weights = jnp.left_shift(
+        jnp.int32(1), jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
+    )
+    acc = jnp.sum(bits * weights, axis=1)  # (_WPB,)
+    out_ref[:] = jax.lax.bitcast_convert_type(
+        acc.reshape(out_ref.shape), jnp.uint32
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("scaling", "interpret"))
@@ -69,28 +75,32 @@ def onebit_compress_device(
 
     flat = grad.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
-    on_tpu = jax.devices()[0].platform == "tpu"
-    nwords = (n + 31) // 32
-    wpb = 1024  # words per grid cell → one native (8, 128) u32 output tile
-    if (not on_tpu and not interpret) or n % (32 * wpb) != 0:
+    # THE decision between the Pallas packer and _pack_jnp.  On a TPU the
+    # kernel runs for every length (the engine's default partition,
+    # 1,024,000 elements, is not a block multiple — hence the padding
+    # below).  Off a TPU Mosaic cannot compile, so the jnp packer stands
+    # in (what the CPU suite's engine tests run) unless the caller asked
+    # for the Pallas interpreter.
+    if jax.devices()[0].platform != "tpu" and not interpret:
         return _pack_jnp(flat, scaling)
 
-    scale = jnp.where(
-        scaling, jnp.sum(jnp.abs(flat)) / n, jnp.float32(1.0)
-    ).astype(jnp.float32)
-    x = flat.reshape(nwords, 32)
+    # pad with +0.0: sign bit clear, and the scale is taken over the n real
+    # elements — so the trimmed words and the scale are exactly the
+    # unpadded codec's, and the wire stays byte-identical
+    padded = jnp.pad(flat, (0, (-n) % _BLOCK))
+    nwords = padded.shape[0] // 32
     # Output blocks must be native (8, 128) u32 tiles: 1-D or (1, wpb)
     # blocks trip Mosaic's layout/divisibility checks.
     words = pl.pallas_call(
-        _pack_kernel(wpb),
+        _pack_kernel,
         out_shape=jax.ShapeDtypeStruct((nwords // 128, 128), jnp.uint32),
-        grid=(nwords // wpb,),
-        in_specs=[pl.BlockSpec((wpb, 32), lambda i: (i, 0))],
+        grid=(nwords // _WPB,),
+        in_specs=[pl.BlockSpec((_WPB, 32), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x)
-    return scale, words.reshape(nwords)
+    )(padded.reshape(nwords, 32))
+    return _scale(flat, scaling), words.reshape(nwords)[: (n + 31) // 32]
 
 
 def onebit_payload(scale: jax.Array, words: jax.Array) -> bytes:

@@ -20,11 +20,6 @@ import sys as _sys
 
 _sys.path.insert(0, _os.path.join(_os.path.dirname(_os.path.abspath(__file__)), ".."))
 
-if _os.environ.get("JAX_PLATFORMS"):
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
-
 import argparse
 import time
 

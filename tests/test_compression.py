@@ -306,9 +306,8 @@ class TestRNGParity:
             assert a.next() == int(b.fill(1)[0])
 
     def test_fill_is_much_faster_than_fromiter_path(self):
-        """The VERDICT r4 target: fallback RNG ≥10× faster on 1M draws —
-        the full factor is recorded in STATUS.md from a quiet-box
-        measurement.  Here: best-of-3 timings and a deliberately loose
+        """The target: fallback RNG ≥10× faster on 1M draws (a factor
+        only a quiet box shows).  Here: best-of-3 timings and a loose
         2× bar, so a contention spike on a shared CI core (the only
         timing hazard) cannot fail an otherwise-green suite while a
         true regression to scalar-op speed (≈10× slower) still would."""

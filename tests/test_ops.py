@@ -36,6 +36,21 @@ class TestFlashAttention:
         assert out.shape == q.shape
         assert np.all(np.isfinite(np.asarray(out)))
 
+    def test_odd_shapes_raise_on_a_tpu(self, monkeypatch):
+        """On a TPU the dense reference is never chosen quietly: a sequence
+        the blocks do not divide is an error in both wrappers.  The platform
+        is injected at the one decision point (_kernel_path)."""
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        q = jnp.zeros((1, 1, 200, 32), jnp.float32)  # 200 % 128 != 0
+        with pytest.raises(ValueError, match="do not divide seq 200"):
+            fa.flash_attention(q, q, q, causal=True)
+        with pytest.raises(ValueError, match="do not divide seq 200"):
+            fa.flash_attention_lse(q, q, q, interpret=True)
+        assert fa._kernel_path(256, 128, 128, interpret=False) is True
+
     def test_grad_flows(self):
         rng = np.random.default_rng(2)
         q = jnp.asarray(rng.normal(size=(1, 2, 128, 32)).astype(np.float32))
@@ -76,16 +91,19 @@ class TestFlashAttention:
 
 
 class TestOneBitDevice:
-    def test_wire_parity_with_host_codec(self):
+    # a block multiple; the engine's default partition (BYTEPS_PARTITION_BYTES
+    # / 4, NOT a block multiple: padded on the device); a ragged tail
+    @pytest.mark.parametrize("n", [32 * 1024 * 2, 1_024_000, 12_345])
+    def test_wire_parity_with_host_codec(self, n):
         """Device-compressed sign words must be byte-identical to the host
-        OneBitCompressor so the PS server decodes it unchanged.  The f32
-        scale (sum(|g|)/n) may differ by an ULP from the host codec's
-        accumulation order at kernel-eligible sizes, so it gets a float
+        OneBitCompressor so the PS server decodes it unchanged — at every
+        length, since the Pallas packer (here in the interpreter) pads to
+        its block and trims.  The f32 scale (sum(|g|)/n) may differ by an
+        ULP from the host codec's accumulation order, so it gets a float
         comparison rather than a byte one."""
         from byteps_tpu.compression.impl import OneBitCompressor
 
         rng = np.random.default_rng(3)
-        n = 32 * 1024 * 2  # kernel-eligible size (multiple of 32*wpb, wpb=1024)
         g = rng.normal(size=n).astype(np.float32)
         scale, words = onebit_compress_device(jnp.asarray(g), scaling=True,
                                               interpret=True)
@@ -106,7 +124,7 @@ class TestOneBitDevice:
         np.testing.assert_array_equal(np.signbit(np.asarray(out)), np.signbit(g))
         np.testing.assert_allclose(np.abs(np.asarray(out)), np.abs(g).mean(), rtol=1e-5)
 
-    def test_non_multiple_uses_jnp_path(self):
+    def test_off_tpu_without_interpret_uses_jnp_path(self):
         g = np.ones(100, np.float32)
         scale, words = onebit_compress_device(jnp.asarray(g), scaling=False)
         assert words.shape == (4,)  # ceil(100/32)

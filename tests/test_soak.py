@@ -2,8 +2,8 @@
 
 The full runs (150s × {tcp+shaped, shm, uds}: 25k+ rounds, 1000+
 elastic resizes, device codecs + rowsparse + async mixed throughout)
-are recorded in STATUS.md; CI keeps a seeded 8-second slice alive so
-the harness itself cannot rot.
+are run by hand with tools/soak.py; CI keeps a seeded 8-second slice
+alive so the harness itself cannot rot.
 """
 
 import os
