@@ -55,7 +55,7 @@ __version__ = "0.1.0"
 
 _SUBMODULES = (
     "api", "optim", "checkpoint", "callbacks", "cross_barrier", "data",
-    "mixed_precision", "profiler", "compression", "models", "ops",
+    "mixed_precision", "compression", "models", "ops",
     "parallel", "comm", "core", "common", "server", "launcher", "native",
     "haiku_plugin",
 )

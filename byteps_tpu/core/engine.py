@@ -50,7 +50,8 @@ class _Job:
     __slots__ = (
         "name", "ctx", "flat", "result", "dtype_id", "average", "handle",
         "pending", "lock", "shape", "np_dtype", "is_jax", "version", "t0",
-        "rowsparse", "device_parts", "failed", "trace_id", "step_counted",
+        "rowsparse", "device_parts", "failed", "trace_id", "parent_span",
+        "step_counted",
     )
 
     def __init__(self, name, ctx, flat, result, dtype_id, average, handle,
@@ -83,8 +84,11 @@ class _Job:
         # next generation (its cleared dedupe ledger would re-sum it)
         self.failed = False
         # distributed tracing: one trace id per push_pull invocation;
-        # every partition task's span joins it (0 = tracing off)
+        # every partition task's span joins it (0 = tracing off).  A job
+        # submitted inside a tracing.span (a training step) takes that
+        # span's trace id and names it as its tasks' parent
         self.trace_id = 0
+        self.parent_span = 0
         # once-guard for the flight recorder's step accounting: a job
         # leaves the in-flight count exactly once whether it finalized
         # or several of its tasks raced into _fail_job
@@ -508,12 +512,29 @@ class PipelineEngine:
         self._threads = []
 
     def _loop(self, q: ScheduledQueue, fn) -> None:
+        from byteps_tpu.core.telemetry import metrics
+        from byteps_tpu.core.tracing import span
+
+        # values at hand only: nothing is formatted per task on this path
+        stage = {"stage": q.queue_type.name}
+        span_name = "stage." + q.queue_type.name
         while not self._stop.is_set():
             task = q.get_task(timeout=0.2)
             if task is None:
                 continue
+            # enqueue → picked up: what the task WAITED for this stage;
+            # stage_dwell_seconds (enqueue → done, _proceed) holds the
+            # wait and the service together
+            if task.enqueued_at:
+                metrics().observe(
+                    "stage_wait_seconds",
+                    time.monotonic() - task.enqueued_at, labels=stage,
+                )
             try:
-                fn(task)
+                # the task's SERVICE on this stage thread
+                with span(span_name, parent=self._task_trace(task),
+                          key=task.key, tensor=task.tensor_name):
+                    fn(task)
             except Exception as e:  # surface errors on the handle
                 self._fail_task(
                     task, q.queue_type, repr(e),
@@ -595,10 +616,7 @@ class PipelineEngine:
         # buffer device jobs deliberately don't allocate.
         fuse_limit = self.cfg.fusion_threshold
         itemsize = np_dtype.itemsize
-        if self._traced():
-            from byteps_tpu.core.tracing import new_trace_id
-
-            job.trace_id = new_trace_id()
+        self._stamp_job_trace(job)
         self._step_begin()
         for part in ctx.partitions:
             p_compressed = (
@@ -825,10 +843,7 @@ class PipelineEngine:
             pending=1, shape=(nrows, row_len), np_dtype=vals.dtype,
             is_jax=False, version=ctx.version, rowsparse=rowsparse,
         )
-        if self._traced():
-            from byteps_tpu.core.tracing import new_trace_id
-
-            job.trace_id = new_trace_id()
+        self._stamp_job_trace(job)
         self._step_begin()
         task = TensorTableEntry(
             tensor_name=name,
@@ -1049,6 +1064,16 @@ class PipelineEngine:
             and getattr(self.tracer, "spans_enabled", True)
         )
 
+    def _stamp_job_trace(self, job: _Job) -> None:
+        """Give a job its trace id (submit runs on the caller's thread):
+        the step's, where the caller is inside a ``tracing.span``, so the
+        step's phases, the job's stage spans and the server's child spans
+        join one trace; a fresh one otherwise."""
+        if self._traced():
+            from byteps_tpu.core.tracing import current_span, new_trace_id
+
+            job.trace_id, job.parent_span = current_span() or (new_trace_id(), 0)
+
     def _stamp_task_trace(self, task: TensorTableEntry, job: _Job) -> None:
         """Give a partition task its span under the job's trace.  The
         span id is FIXED for the task's lifetime: every RPC attempt
@@ -1121,8 +1146,8 @@ class PipelineEngine:
             self.tracer.record_span(
                 job.name, finished.name, task.enqueued_wall,
                 time.time() - task.enqueued_wall,
-                span_args(task.trace_id, task.span_id, key=task.key,
-                          version=task.version),
+                span_args(task.trace_id, task.span_id, job.parent_span,
+                          key=task.key, version=task.version),
             )
         self.queues[finished].report_finish(task)
         if task.queue_list:
@@ -1146,7 +1171,11 @@ class PipelineEngine:
             # merge two rounds into one record (and skew the slow-step
             # rolling median)
             self._step_end(job)
-            self._finalize(job)
+            from byteps_tpu.core.tracing import span
+
+            step = (job.trace_id, job.parent_span) if job.trace_id else None
+            with span("engine.finalize", parent=step):
+                self._finalize(job)
 
     def _fail_job(self, job: _Job, status: Status) -> None:
         from byteps_tpu.core.state import get_state

@@ -16,23 +16,35 @@ Two event families share one tracer (docs/observability.md):
 
 Emission is ``<dir>/<local_rank>/comm.json`` in Chrome trace-event
 format (load via chrome://tracing or Perfetto).  ``flush()`` writes the
-CURRENT window and clears the buffer, so ``profiler.trace()`` can
+CURRENT window and clears the buffer, so :func:`profile` can
 capture any number of windows per process (the pre-observability tracer
 had a one-shot latch: the second flush silently dropped all events).
 
 Host stages are stamped by the pipeline engine; device-side collective
 timing is XLA's domain (use jax.profiler for that) — the tracer records
 the host-visible envelope, which is what the reference records too.
+
+**Phases on the profiler's clock** (:class:`span`, :func:`profile`): the
+program's own phases — the two-level step's and the engine's stages — are
+``jax.profiler.TraceAnnotation`` events named ``bps.<name>``, so they sit in
+the profiler's ``.xplane.pb`` beside the device's operations; every span
+also lands in the ``span_seconds{name}`` histogram, and in this tracer's
+file when it is on.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import random
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation, start_trace, stop_trace
+
+from byteps_tpu.core.telemetry import metrics
 
 _id_rng = random.SystemRandom()
 
@@ -182,7 +194,7 @@ class Tracer:
         """Write the current window and clear the buffer; returns the
         output path, or "" when disabled or nothing was recorded.
         Multiple windows per process are supported: each
-        ``profiler.trace()`` exit flushes its own window.  A window
+        :func:`profile` exit flushes its own window.  A window
         NEVER clobbers an earlier one — when ``comm.json`` already
         exists in the target directory (e.g. the shutdown flush landing
         in a dir a profiler window already used), the new window goes to
@@ -218,24 +230,6 @@ class Tracer:
         return path
 
 
-class StageTimer:
-    """Context manager stamping one stage interval onto a tracer."""
-
-    def __init__(self, tracer: Tracer, name: str, stage: str, step: int) -> None:
-        self.tracer = tracer
-        self.name = name
-        self.stage = stage
-        self.step = step
-
-    def __enter__(self):
-        self.t0 = time.time()
-        return self
-
-    def __exit__(self, *exc):
-        self.tracer.record(self.name, self.stage, self.t0, time.time() - self.t0, self.step)
-        return False
-
-
 #: process-global tracer — set by init_state (workers) / PSServer
 #: (servers) so layers without runtime-state access (chaos van, PS
 #: client) can stamp events on the owning process's timeline
@@ -249,3 +243,89 @@ def set_process_tracer(tracer: Optional[Tracer]) -> None:
 
 def get_process_tracer() -> Optional[Tracer]:
     return _process_tracer
+
+
+# --- phases on the profiler's clock ---------------------------------------
+
+#: the innermost open :class:`span` of each thread, as ``(trace id, span
+#: id)``; set only while the process tracer records spans
+_current = threading.local()
+
+
+def current_span() -> Optional[Tuple[int, int]]:
+    """``(trace id, span id)`` of the innermost :class:`span` open on this
+    thread, or None (no span open, or the process tracer is off)."""
+    return getattr(_current, "ids", None)
+
+
+class span:
+    """One phase of the program, ``with span("hybrid.hop_wait"): ...``:
+
+    - a ``jax.profiler.TraceAnnotation`` named ``bps.<name>`` with ``attrs``
+      as its stats: the phase lands in the profiler's trace, on its clock
+      and on this thread (free while no profiler session runs);
+    - one observation of its duration in ``span_seconds{name}``, always;
+    - while the process :class:`Tracer` records spans, a span event on this
+      thread's track.  Its parent is ``parent`` (a ``(trace id, span id)``
+      pair: a stage thread names the task it serves) or else the innermost
+      span open on this thread, whose trace id it shares: every span of one
+      training step carries the step's id, and so does every job the step
+      submits (``engine.submit`` reads :func:`current_span`).
+    """
+
+    __slots__ = ("name", "attrs", "parent", "_annotation", "_t0", "_wall",
+                 "_ids", "_outer")
+
+    def __init__(self, name: str, parent: Optional[Tuple[int, int]] = None,
+                 **attrs) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.parent = parent
+        self._annotation = TraceAnnotation("bps." + name, **attrs)
+
+    def __enter__(self) -> "span":
+        tracer = _process_tracer
+        self._ids = None
+        if tracer is not None and tracer.enabled and tracer.spans_enabled:
+            self._outer = current_span()
+            parent = self.parent or self._outer
+            trace_id = parent[0] if parent else new_trace_id()
+            self._ids = _current.ids = (trace_id, new_trace_id())
+            self._wall = time.time()
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        metrics().observe("span_seconds", dur, labels={"name": self.name})
+        if self._ids is not None:
+            _current.ids = self._outer
+            parent = self.parent or self._outer
+            tracer = _process_tracer
+            if tracer is not None:
+                tracer.record_span(
+                    threading.current_thread().name, self.name, self._wall, dur,
+                    span_args(*self._ids, parent[1] if parent else 0,
+                              **self.attrs),
+                )
+        return False
+
+
+@contextlib.contextmanager
+def profile(log_dir: str) -> Iterator[None]:
+    """Capture an XLA profile of the block into ``log_dir`` — the device's
+    operations and every ``bps.*`` span on one clock — and, where the
+    process tracer is on, flush its current window into the same directory
+    on exit.  Any number of windows per process; cross-process span files
+    merge via ``tools/trace_merge.py`` (docs/observability.md)."""
+    start_trace(log_dir)
+    try:
+        yield
+    finally:
+        stop_trace()
+        tracer = _process_tracer
+        if tracer is not None and tracer.enabled:
+            tracer.trace_dir = log_dir
+            tracer.flush()

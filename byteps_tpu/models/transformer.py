@@ -950,9 +950,12 @@ def build_train_step(
         # cotangents of replicated (invariant-typed) params are psum'd over
         # exactly the axes they're replicated on — the DistributedOptimizer
         # allreduce falls out of the type system, no manual collectives.
-        return jax.value_and_grad(
-            lambda p: _local_loss(cfg, mesh, p, tokens, targets)
-        )(params)
+        def forward(p):
+            # the backward pass reads transpose(jvp(forward)) in a trace
+            with jax.named_scope("forward"):
+                return _local_loss(cfg, mesh, p, tokens, targets)
+
+        return jax.value_and_grad(forward)(params)
 
     shmapped = jax.shard_map(
         loss_and_grad,
@@ -962,10 +965,13 @@ def build_train_step(
         check_vma=True,
     )
 
-    def step(params, opt_state, tokens, targets):
+    # the name is the trace's module line (jit_train_step) and part of the
+    # compile cache's key, which ignores scopes (see optim.py)
+    def train_step(params, opt_state, tokens, targets):
         loss, grads = shmapped(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0, 1) if donate else ())

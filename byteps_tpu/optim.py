@@ -228,21 +228,23 @@ def _ddp_apply(grads, loss, params, opt_state, optimizer, axis_name: str,
     is raveled into ONE ring so small leaves (biases, norm scales) don't
     each pay the block/chunk padding floor; unravel restores per-leaf
     dtypes."""
-    if quant_bits == 8:
-        from jax.flatten_util import ravel_pytree
+    with jax.named_scope("grad_sync"):
+        if quant_bits == 8:
+            from jax.flatten_util import ravel_pytree
 
-        from byteps_tpu.ops.quantized_allreduce import quantized_psum
+            from byteps_tpu.ops.quantized_allreduce import quantized_psum
 
-        flat, unravel = ravel_pytree(grads)
-        summed = quantized_psum(flat, axis_name)
-        grads = unravel(summed / lax.axis_size(axis_name))
-    else:
-        grads = jax.tree_util.tree_map(
-            lambda g: lax.pmean(g, axis_name), grads
-        )
-    loss = lax.pmean(loss, axis_name)
-    updates, opt_state = optimizer.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
+            flat, unravel = ravel_pytree(grads)
+            summed = quantized_psum(flat, axis_name)
+            grads = unravel(summed / lax.axis_size(axis_name))
+        else:
+            grads = jax.tree_util.tree_map(
+                lambda g: lax.pmean(g, axis_name), grads
+            )
+        loss = lax.pmean(loss, axis_name)
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
     return params, opt_state, loss
 
 
@@ -312,7 +314,7 @@ def build_data_parallel_step(
             every_k_schedule=accumulate_steps,
         )
 
-        def local_step(params, opt_state, batch):
+        def data_parallel_step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             loss = lax.pmean(loss, axis_name)
             updates, opt_state = optimizer.update(grads, opt_state, params)
@@ -321,14 +323,14 @@ def build_data_parallel_step(
 
     else:
 
-        def local_step(params, opt_state, batch):
+        def data_parallel_step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             return _ddp_apply(
                 grads, loss, params, opt_state, optimizer, axis_name,
                 quant_bits=grad_quant_bits,
             )
 
-    step = _compile_spmd_step(local_step, mesh, axis_name, donate)
+    step = _compile_spmd_step(data_parallel_step, mesh, axis_name, donate)
     # the (possibly MultiSteps-wrapped) transformation whose .init builds
     # a matching opt_state
     step.optimizer = optimizer
@@ -448,16 +450,22 @@ def build_flax_data_parallel_step(
     cross-replica BatchNorm behavior.
     """
 
-    def local_step(variables, opt_state, batch):
+    # named after its builder: the name is the trace's module line
+    # (jit_flax_data_parallel_step) and part of the compile cache's key,
+    # which ignores scopes — under the name it had before the scopes, a
+    # shared cache could hand back a program compiled without them
+    def flax_data_parallel_step(variables, opt_state, batch):
         x, y = batch
         params = variables["params"]
         rest = {k: v for k, v in variables.items() if k != "params"}
 
         def loss_fn(p):
-            out, mutated = apply_fn(
-                {"params": p, **rest}, x, train=True, mutable=["batch_stats"]
-            )
-            return loss_from_logits(out, y), mutated
+            # the backward pass reads transpose(jvp(forward)) in a trace
+            with jax.named_scope("forward"):
+                out, mutated = apply_fn(
+                    {"params": p, **rest}, x, train=True, mutable=["batch_stats"]
+                )
+                return loss_from_logits(out, y), mutated
 
         (loss, mutated), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         new_stats = _pmean_float_leaves(mutated.get("batch_stats", {}), axis_name)
@@ -469,4 +477,4 @@ def build_flax_data_parallel_step(
             variables["batch_stats"] = new_stats
         return variables, opt_state, loss
 
-    return _compile_spmd_step(local_step, mesh, axis_name, donate)
+    return _compile_spmd_step(flax_data_parallel_step, mesh, axis_name, donate)

@@ -29,6 +29,7 @@ levels of one step.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -40,6 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 import byteps_tpu as bps
 from byteps_tpu.comm.mesh import get_global_mesh
+from byteps_tpu.core.tracing import span
 
 
 class HybridDataParallel:
@@ -97,8 +99,14 @@ class HybridDataParallel:
 
         dp_size = self.mesh.shape[dp_axis]
 
-        def local_grad(p, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+        def forward(p, batch):
+            # the scope names every operation of the loss; the backward
+            # pass then reads transpose(jvp(forward)) in the trace
+            with jax.named_scope("forward"):
+                return loss_fn(p, batch)
+
+        def hybrid_grad(p, batch):
+            loss, grads = jax.value_and_grad(forward)(p, batch)
             loss = lax.pmean(loss, dp_axis)
             # level 1, the ICI reduce: under VMA-checked shard_map AD the
             # cotangent of every parameter is ALREADY psum'd over the
@@ -110,50 +118,59 @@ class HybridDataParallel:
 
         self._grad = jax.jit(
             jax.shard_map(
-                local_grad,
+                hybrid_grad,
                 mesh=self.mesh,
                 in_specs=(self._specs, batch_spec),
                 out_specs=(P(), self._specs),
                 check_vma=True,
             )
         )
-        self._apply = jax.jit(
-            lambda p, s, g: _apply(optimizer, p, s, g),
-        )
+
+        def hybrid_apply(p, s, g):
+            with jax.named_scope("optimizer"):
+                updates, s = optimizer.update(g, s, p)
+                return optax.apply_updates(p, updates), s
+
+        self._apply = jax.jit(hybrid_apply)
+        self._steps = 0
 
     def step(self, batch) -> float:
-        """One full two-level step; returns the (host-level) loss."""
-        loss, grads = self._grad(self.params, batch)
-        # level 2: the DCN hop — every gradient through the PS plane,
-        # front layers first (priority = −declaration order)
-        flat, treedef = jax.tree_util.tree_flatten(grads)
-        handles = []
-        for i, g in enumerate(flat):
-            # hand the engine the LIVE jax.Array: COPYD2H stages each
-            # partition asynchronously on its own thread (overlapping the
-            # remaining gathers) and the priority queue has real work to
-            # reorder — np.asarray here would serialize every gather on
-            # this thread before the first byte hit the wire
-            handles.append(
-                bps.push_pull_async(
-                    g,
-                    name=f"{self._prefix}{self._names[i]}",
-                    average=True,
-                    priority=-i,
+        """One full two-level step; returns the (host-level) loss.  Its
+        phases are ``tracing.span``s (docs/observability.md "Phases on the
+        profiler's clock")."""
+        self._steps += 1
+        with span("hybrid.step", step=self._steps, wall_ns=time.time_ns()):
+            with span("hybrid.grad_dispatch"):
+                loss, grads = self._grad(self.params, batch)
+            # level 2: the DCN hop — every gradient through the PS plane,
+            # front layers first (priority = −declaration order)
+            with span("hybrid.enqueue"):
+                flat, treedef = jax.tree_util.tree_flatten(grads)
+                # hand the engine the LIVE jax.Array: COPYD2H stages each
+                # partition asynchronously on its own thread (overlapping
+                # the remaining gathers) and the priority queue has real
+                # work to reorder — np.asarray here would serialize every
+                # gather on this thread before the first byte hit the wire
+                handles = [
+                    bps.push_pull_async(
+                        g,
+                        name=f"{self._prefix}{self._names[i]}",
+                        average=True,
+                        priority=-i,
+                    )
+                    for i, g in enumerate(flat)
+                ]
+            with span("hybrid.hop_wait"):
+                averaged = [bps.synchronize(h) for h in handles]
+            with span("hybrid.reput"):
+                g_global = jax.tree_util.tree_unflatten(treedef, averaged)
+                g_global = jax.tree.map(
+                    lambda g, sh: jax.device_put(jnp.asarray(g), sh),
+                    g_global, self._shardings,
                 )
-            )
-        averaged = [bps.synchronize(h) for h in handles]
-        g_global = jax.tree_util.tree_unflatten(treedef, averaged)
-        g_global = jax.tree.map(
-            lambda g, sh: jax.device_put(jnp.asarray(g), sh),
-            g_global, self._shardings,
-        )
-        self.params, self.opt_state = self._apply(
-            self.params, self.opt_state, g_global
-        )
-        return float(loss)
-
-
-def _apply(optimizer, params, opt_state, grads):
-    updates, opt_state = optimizer.update(grads, opt_state, params)
-    return optax.apply_updates(params, updates), opt_state
+            with span("hybrid.apply_dispatch"):
+                self.params, self.opt_state = self._apply(
+                    self.params, self.opt_state, g_global
+                )
+            with span("hybrid.loss_sync"):
+                return float(loss)

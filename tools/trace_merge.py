@@ -238,7 +238,11 @@ def critical_path(merged: dict) -> dict:
         span, parent = args.get("span"), args.get("parent")
         if args.get("trace"):
             traces.add(args["trace"])
-        if parent:
+        # a server stage under a worker RPC.  A WORKER span can have a
+        # parent too (a task submitted inside a tracing.span hangs under
+        # the step's phase; a stage thread's service span under its
+        # task): those stay RPC owners below, never someone's server time
+        if parent and ev.get("name") in _SERVER_STAGES:
             children.setdefault(parent, []).append({
                 "name": ev.get("name", ""),
                 "ts": float(ev.get("ts", 0.0)),
