@@ -11,7 +11,9 @@ are XLA collectives inside the jitted step, so the *host* pipeline is:
     PUSH     (DCN → PS server, priority-scheduled)
     PULL     (DCN ← PS server)
     DECOMPRESS
-    COPYH2D  (host→device, then the caller's next step consumes it)
+    COPYH2D  (host→device, one partition at a time as its PULL or
+              DECOMPRESS lands; the job's last partition then assembles
+              the leaf on the device — the caller's next step consumes it)
 
 Each stage is a ScheduledQueue + worker thread; PUSH/PULL completion is
 driven by PS-client callbacks, mirroring how ps-lite callbacks drive
@@ -22,6 +24,7 @@ scheduling core idea.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import time
@@ -44,19 +47,43 @@ from byteps_tpu.core.ready_table import ReadyTable
 from byteps_tpu.core.scheduler import ScheduledQueue
 
 
+@functools.cache
+def _assemble_program():
+    """``(parts, shape) → leaf`` as ONE compiled program per distinct
+    partition layout (jit keys it by the parts' shapes and ``shape``); an
+    eager concatenate + reshape would dispatch two and hold the flat copy
+    between them.  Built on first use: importing the engine starts no
+    backend."""
+    import jax
+    import jax.numpy as jnp
+
+    def assemble_parts(parts, shape):
+        return jnp.concatenate(parts).reshape(shape)
+
+    return jax.jit(assemble_parts, static_argnums=1)
+
+
+def _assemble(parts: list, shape: tuple):
+    """A job's device partitions, in offset order, as one array of its
+    submitted shape."""
+    if len(parts) == 1:
+        return parts[0].reshape(shape)
+    return _assemble_program()(parts, shape)
+
+
 class _Job:
     """One push_pull invocation: shared state across its partitions."""
 
     __slots__ = (
         "name", "ctx", "flat", "result", "dtype_id", "average", "handle",
         "pending", "lock", "shape", "np_dtype", "is_jax", "version", "t0",
-        "rowsparse", "device_parts", "failed", "trace_id", "parent_span",
-        "step_counted",
+        "rowsparse", "device_codec", "device_parts", "failed", "trace_id",
+        "parent_span", "step_counted",
     )
 
     def __init__(self, name, ctx, flat, result, dtype_id, average, handle,
                  pending, shape, np_dtype, is_jax, version, rowsparse=None,
-                 device_parts=None):
+                 device_codec=False):
         self.name = name
         self.ctx = ctx
         self.flat = flat
@@ -74,10 +101,14 @@ class _Job:
         # row-sparse jobs: {"push_payload": bytes, "pull_req": bytes}
         # (kRowSparsePushPull, common.h:267-271)
         self.rowsparse = rowsparse
-        # device-codec jobs: offset → decoded jax.Array per partition;
-        # assembled on DEVICE in _finalize (the result never round-trips
-        # through the host uncompressed)
-        self.device_parts = device_parts
+        # device-codec jobs compress before D2H and decode after H2D: they
+        # own no host result buffer (the gradient never exists
+        # uncompressed on the host)
+        self.device_codec = device_codec
+        # jax jobs: offset → the partition as a jax.Array, put there by
+        # COPYH2D (raw and host-codec jobs) or decoded there by DECOMPRESS
+        # (device-codec jobs); assembled on DEVICE in _finalize
+        self.device_parts = {} if is_jax else None
         # set when ANY task of this job fails: the abort fence the PS
         # client checks before (re)sending — a pending retry timer from
         # an abandoned round must not replay into the re-initialized
@@ -601,7 +632,7 @@ class PipelineEngine:
             name, ctx, flat, result, dtype_id, average, handle,
             pending=len(ctx.partitions), shape=np.shape(tensor),
             np_dtype=np_dtype, is_jax=is_jax, version=ctx.version,
-            device_parts={} if on_device else None,
+            device_codec=on_device,
         )
         # small-tensor fusion routing, per partition: uncompressed
         # partitions gauge their RAW size against the threshold;
@@ -623,7 +654,7 @@ class PipelineEngine:
                 part.key in self._compressors
                 and part.key not in self._compression_auto_off
             )
-            if job.device_parts is not None:
+            if on_device:
                 wire_est = self._device_codecs[part.key].wire_nbytes()
                 small = bool(fuse_limit) and wire_est <= fuse_limit
                 qlist = (
@@ -1101,7 +1132,7 @@ class PipelineEngine:
             # core_loops.cc:37-67) — the race-diagnosis tool
             from byteps_tpu.common import logging as bpslog
 
-            if job.device_parts is not None and finished in (
+            if job.device_codec and finished in (
                 QueueType.DECOMPRESS, QueueType.COPYH2D,
             ):
                 # device-codec jobs never write job.result — the decoded
@@ -1242,34 +1273,51 @@ class PipelineEngine:
             self._fail_job(job, Status.Aborted(f"{stage.name}: {reason}"))
 
     def _finalize(self, job: _Job) -> None:
-        """All partitions done: average (the plugin-side div by size,
-        torch/ops.cc:78-91), reshape, hand back."""
+        """All partitions done: assemble, reshape, hand back.
+
+        A jax job arrives with every partition already on the device
+        (``device_parts``: COPYH2D put it there, or a device codec decoded
+        it there), so what is left is a device-side concatenate + reshape
+        and the release of what the job held.  A numpy job (the
+        torch/TF/MXNet plugins) averages and reshapes its host buffer
+        (the plugin-side div by size, torch/ops.cc:78-91)."""
         from byteps_tpu.core.state import get_state
 
-        if job.device_parts is not None:
-            # device-codec path: partitions were decoded ON device — the
-            # assembly (concat/average/reshape) stays there too, so the
-            # aggregated gradient never exists uncompressed on the host
-            import jax.numpy as jnp
-
+        if job.is_jax:
             parts = [job.device_parts[off] for off in sorted(job.device_parts)]
-            out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-            if job.average:
+            # the job sits in reference cycles (handle ↔ caller) that only
+            # the collector breaks: drop the buffers by reference count now
+            job.device_parts = job.result = None
+            out = _assemble(parts, job.shape)
+            del parts
+            if job.device_codec and job.average:
+                # raw and host-codec partitions were averaged on the host,
+                # before their put (_h2d)
                 out = out / self.client.num_workers
-            get_state().handles.mark_done(job.handle, out.reshape(job.shape))
+            get_state().handles.mark_done(job.handle, out)
             return
         out = job.result
         if job.average and np.issubdtype(job.np_dtype, np.floating):
             out = out / self.client.num_workers
-        out = out.reshape(job.shape)
-        if job.is_jax:
-            import jax
+        get_state().handles.mark_done(job.handle, out.reshape(job.shape))
 
-            # async H2D: device_put returns immediately with the transfer
-            # in flight (the COPYH2D stream, core_loops.cc:650-753); the
-            # caller's next jitted step consumes the Array when ready
-            out = jax.device_put(out)
-        get_state().handles.mark_done(job.handle, out)
+    def _h2d(self, buf: np.ndarray, average: bool):
+        """One host buffer onto the device (a partition on the COPYH2D
+        thread; the healed tensor in heal_degraded).  The average is taken
+        in place first — the same divide as the numpy path's
+        ``out / num_workers``, on bytes still in cache, and none with one
+        worker (``x / 1 == x`` bit for bit).  device_put returns with the
+        transfer issued; jax keeps ``buf`` alive until it completes, and
+        nothing writes a result buffer after its COPYH2D."""
+        import jax
+
+        from byteps_tpu.core.telemetry import counters
+
+        n = self.client.num_workers
+        if average and n != 1 and np.issubdtype(buf.dtype, np.floating):
+            np.divide(buf, n, out=buf)
+        counters().bump("h2d_bytes", buf.nbytes)
+        return jax.device_put(buf)
 
     # --- recovery plane (docs/robustness.md "healing flow") --------------
 
@@ -1373,13 +1421,12 @@ class PipelineEngine:
                 result[p.offset : p.offset + p.length] = arr[: p.length]
         with self._init_lock:
             self._reinit_names.discard(name)
+        if is_jax:
+            return self._h2d(result, average).reshape(shape)
         out = result
         if average and np.issubdtype(np_dtype, np.floating):
             out = out / self.client.num_workers
-        out = out.reshape(shape)
-        if is_jax:
-            out = jax.device_put(out)
-        return out
+        return out.reshape(shape)
 
     def _copy_d2h_once(self, task: TensorTableEntry) -> None:
         """Per-partition device→host DMA (COPYD2H, core_loops.cc:378-443).
@@ -1397,7 +1444,7 @@ class PipelineEngine:
         from byteps_tpu.core.telemetry import counters
 
         job: _Job = task.context
-        if job.device_parts is not None:
+        if job.device_codec:
             dc = self._device_codecs[task.key]
             sl = job.flat[task.offset : task.offset + task.length]
             task.compressed = dc.compress(sl)  # D2H of the packed payload
@@ -2043,8 +2090,11 @@ class PipelineEngine:
         crosses host→device (jnp.asarray inside the adapter), and the
         decoded partition stays on device for _finalize's assembly."""
         job: _Job = task.context
-        if job.device_parts is not None:
+        if job.device_codec:
+            from byteps_tpu.core.telemetry import counters
+
             dc = self._device_codecs[task.key]
+            counters().bump("h2d_bytes", len(task.compressed))
             part = dc.decompress(task.compressed, task.length)
             with job.lock:
                 job.device_parts[task.offset] = part
@@ -2056,7 +2106,19 @@ class PipelineEngine:
         self._proceed(task)
 
     def _copy_h2d_once(self, task: TensorTableEntry) -> None:
-        """Host→device hand-back (COPYH2D, core_loops.cc:650-753).  The
-        device transfer itself happens lazily in _finalize via jnp.asarray;
-        this stage exists so tracing shows the full reference pipeline."""
+        """Per-partition host→device DMA (COPYH2D, core_loops.cc:650-753):
+        the mirror of COPYD2H.  A jax job's pulled (or host-decoded)
+        partition is averaged in place and put on the device here, on THIS
+        stage thread, as it lands — so the copy of partition k runs beside
+        the PULL of partition k+1, and what follows a job's last PULL is
+        one partition's copy and _finalize's device-side assemble.  numpy
+        jobs keep their result on the host and device-codec jobs already
+        decoded theirs on the device: both pass through."""
+        job: _Job = task.context
+        if job.is_jax and not job.device_codec:
+            part = self._h2d(
+                job.result[task.offset : task.offset + task.length], job.average
+            )
+            with job.lock:
+                job.device_parts[task.offset] = part
         self._proceed(task)

@@ -351,7 +351,7 @@ def leg_b(dry: bool) -> None:
         f"{param_bytes / 1e6:.1f} MB of f32 gradient per step")
     check_losses(label, losses, times)
     moved = {k: after.get(k, 0) - before.get(k, 0)
-             for k in ("d2h_bytes", "wire_tx_bytes", "wire_rx_bytes")}
+             for k in ("d2h_bytes", "wire_tx_bytes", "wire_rx_bytes", "h2d_bytes")}
     say(f"{label}: byte counters over {STEPS_B} steps {moved}; "
         f"steps x parameter bytes = {STEPS_B * param_bytes}")
     if set(moved.values()) != {STEPS_B * param_bytes}:
