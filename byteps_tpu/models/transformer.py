@@ -27,6 +27,7 @@ BASELINE.md) and GPT-2 medium (BASELINE.json config 5).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -37,7 +38,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from byteps_tpu.parallel.moe import moe_aux_loss, moe_mlp
+from byteps_tpu.parallel.moe import moe_aux_loss, moe_mlp, routing_counters
 from byteps_tpu.parallel.ring_attention import ring_attention
 
 
@@ -77,11 +78,16 @@ class TransformerConfig:
     remat: bool = True
     # use the Pallas flash-attention kernel for the per-device attention
     # when sequence parallelism is off (ring attention otherwise).
-    # Default off: measured on TPU v5e, XLA's fused dense attention beats
-    # the current Pallas kernel at trainable sequence lengths (seq 128:
-    # 412 vs 291 samples/s; seq 1024: 29.4 vs 13.9 on BERT-large) — the
-    # kernel is the memory-frugal option for long-context runs where the
-    # S^2 score matrix would not fit, not the short-seq fast path.
+    # Default off: bert_large_step, the cell that runs this family, is at
+    # sequence 128, one kernel block, where nothing was timed.  Measured
+    # on one v5e chip with the kernel as it is since PR 29 (bf16 operands
+    # on the MXU, masked blocks not fetched; tools/flash_tune.py, batch 16,
+    # 16 heads of 64, causal, forward + dQ + dK/dV; PERF.md section 6, PR
+    # 29): flash 2.36 ms against dense 2.16 at sequence 512, 6.53 against
+    # 8.63 at 1024, 19.68 against 32.09 at 2048 - dense wins at 512, the
+    # kernel from 1024 up, and where the S^2 scores do not fit it is the
+    # only way (models/latent_moe.py at sequence 8192).  The figures that
+    # stood here before (412 vs 291 samples/s at 128) were older than the code.
     use_flash: bool = False
     # sequence-parallel strategy when sp > 1: "ring" (ppermute KV blocks,
     # any head count) or "ulysses" (all-to-all head/seq reshard, needs
@@ -120,6 +126,22 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
+
+    # What the builders below ask of a model family (models/latent_moe.py's
+    # LatentMoEConfig answers the same four): its parameter table, its
+    # checks against a mesh, and per device, inside shard_map, its loss with
+    # whatever it counts (name -> int32, none here) and its logits.
+    def layouts(self) -> Dict[str, Tuple]:
+        return _layouts(self)
+
+    def validate_mesh(self, mesh: Mesh) -> None:
+        _validate_mesh(self, mesh)
+
+    def local_loss(self, mesh: Mesh, params, tokens, targets):
+        return _local_loss(self, mesh, params, tokens, targets), {}
+
+    def local_logits(self, mesh: Mesh, params, tokens):
+        return _local_logits(self, mesh, params, tokens)
 
 
 def bert_large(**kw) -> TransformerConfig:
@@ -226,11 +248,11 @@ def _is_layer_param(name: str) -> bool:
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, P]:
-    return {k: spec for k, (_, spec, _) in _layouts(cfg).items()}
+    return {k: spec for k, (_, spec, _) in cfg.layouts().items()}
 
 
 def grad_sync_axes(cfg: TransformerConfig) -> Dict[str, Tuple[str, ...]]:
-    return {k: axes for k, (_, _, axes) in _layouts(cfg).items()}
+    return {k: axes for k, (_, _, axes) in cfg.layouts().items()}
 
 
 def init_params(
@@ -581,15 +603,27 @@ def _local_loss(cfg: TransformerConfig, mesh: Mesh, params, tokens, targets):
     return loss
 
 
+def _local_logits(cfg: TransformerConfig, mesh: Mesh, params, tokens):
+    """(M, Bmb, S_local, V) logits, the same on every pipeline stage."""
+    logits, _ = _local_forward(cfg, mesh, params, tokens)
+    # select the last pipeline stage's logits (garbage elsewhere)
+    is_last = lax.axis_index("pp") == mesh.shape.get("pp", 1) - 1
+    return lax.psum(jnp.where(is_last, logits, 0.0), "pp")
+
+
 # ---------------------------------------------------------------------------
 # Public builders
 # ---------------------------------------------------------------------------
 
 
-def validate_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
-    """Config×mesh checks that can only run once the mesh is known.
+def validate_mesh(cfg, mesh: Mesh) -> None:
+    """Config×mesh checks that can only run once the mesh is known: the
+    model family's own (``cfg.validate_mesh``)."""
+    cfg.validate_mesh(mesh)
 
-    wq is tp-sharded on the query-head dim and wk/wv on the KV-head dim,
+
+def _validate_mesh(cfg: TransformerConfig, mesh: Mesh) -> None:
+    """wq is tp-sharded on the query-head dim and wk/wv on the KV-head dim,
     so both head counts must divide tp — otherwise the failure surfaces
     later as an opaque shard_map/NamedSharding error instead of naming
     the bad config (ADVICE r4)."""
@@ -624,17 +658,9 @@ def build_forward(cfg: TransformerConfig, mesh: Mesh) -> Callable:
     """
     validate_mesh(cfg, mesh)
     specs = param_specs(cfg)
-    pp = mesh.shape.get("pp", 1)
-
-    def fwd(params, tokens):
-        logits, _ = _local_forward(cfg, mesh, params, tokens)
-        # select the last pipeline stage's logits (garbage elsewhere)
-        is_last = lax.axis_index("pp") == pp - 1
-        logits = lax.psum(jnp.where(is_last, logits, 0.0), "pp")
-        return logits
 
     shmapped = jax.shard_map(
-        fwd,
+        functools.partial(cfg.local_logits, mesh),
         mesh=mesh,
         in_specs=(specs, P("dp", "sp")),
         out_specs=P(None, "dp", "sp", None),
@@ -941,6 +967,12 @@ def build_train_step(
     parameter is replicated on (the DistributedOptimizer semantics of the
     reference, generalized to a 4-D mesh).  The optimizer update runs on
     the sharded views under GSPMD propagation outside the shard_map.
+
+    The loss is the model family's (``cfg.local_loss``), and so is what it
+    counts beside it (a latent-attention MoE's routing statistics): the
+    counts leave the compiled step with the loss and reach the process's
+    counters (``parallel/moe.RoutingCounters``) without a blocking read.
+    The returned step has the compiled function's ``lower``.
     """
     validate_mesh(cfg, mesh)
     specs = param_specs(cfg)
@@ -953,25 +985,33 @@ def build_train_step(
         def forward(p):
             # the backward pass reads transpose(jvp(forward)) in a trace
             with jax.named_scope("forward"):
-                return _local_loss(cfg, mesh, p, tokens, targets)
+                return cfg.local_loss(mesh, p, tokens, targets)
 
-        return jax.value_and_grad(forward)(params)
+        return jax.value_and_grad(forward, has_aux=True)(params)
 
     shmapped = jax.shard_map(
         loss_and_grad,
         mesh=mesh,
         in_specs=(specs, P("dp", "sp"), P("dp", "sp")),
-        out_specs=(P(), specs),
+        out_specs=((P(), P()), specs),
         check_vma=True,
     )
 
     # the name is the trace's module line (jit_train_step) and part of the
     # compile cache's key, which ignores scopes (see optim.py)
     def train_step(params, opt_state, tokens, targets):
-        loss, grads = shmapped(params, tokens, targets)
+        (loss, counts), grads = shmapped(params, tokens, targets)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+        return params, opt_state, loss, counts
+
+    jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
+
+    def step(params, opt_state, tokens, targets):
+        params, opt_state, loss, counts = jitted(params, opt_state, tokens, targets)
+        routing_counters().push(counts)
         return params, opt_state, loss
 
-    return jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
+    step.lower = jitted.lower
+    return step

@@ -15,7 +15,14 @@ Backward: the standard two-kernel split —
 Both recompute P = exp(QKᵀ·scale − lse) blockwise (no saved probabilities)
 using the forward's logsumexp and Δ = rowsum(dO ∘ O).
 
-Fully-masked causal blocks skip all matmuls via pl.when.  On a TPU the
+The query/key head size and the value head size may differ (latent
+attention: 192 for q·k, 128 for v); the matrix products take their operands
+in the dtype they arrive in (bf16 in, bf16 on the MXU) and accumulate in
+f32, the softmax statistics are f32 throughout.
+
+Fully-masked causal blocks skip all matmuls via pl.when, and their K/V (or
+Q/dO) blocks are not fetched: the block index is clamped to the last one
+needed, and Pallas does not copy a block whose index did not change.  On a TPU the
 kernels are the only path (a sequence the blocks do not divide raises);
 off a TPU the dense reference stands in — see :func:`_kernel_path`, the one
 place that decides.  Differentiable end to end.
@@ -34,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
@@ -43,6 +51,15 @@ NEG_INF = -1e30
 # fails Mosaic's block-mapping check (the same layout jax's bundled TPU
 # flash kernel uses for its l/m residuals).
 LANES = 128
+
+#: what the forward leaves for the backward, by name: a ``jax.checkpoint``
+#: whose policy saves these (``save_only_these_names(*SAVED)``) does not run
+#: the forward kernel a second time to rebuild them — the output and one f32
+#: logsumexp a row, against the kernel's S^2 work
+SAVED = ("flash_out", "flash_lse")
+
+#: the kernels' names: a trace files their time under these
+FWD_KERNEL, DQ_KERNEL, DKV_KERNEL = "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"
 
 
 def _dense_reference(q, k, v, causal, scale):
@@ -82,7 +99,17 @@ def _causal_keep(qi, j, bq: int, bk: int):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel_factory(dh, bq, bk, nk, causal, scale):
+def _last_kv_block(qi, bq: int, bk: int):
+    """Index of the last KV block a causal query block qi reads."""
+    return ((qi + 1) * bq - 1) // bk
+
+
+def _first_q_block(j, bq: int, bk: int):
+    """Index of the first query block that sees causal KV block j."""
+    return (j * bk) // bq
+
+
+def _fwd_kernel_factory(bq, bk, nk, causal, scale):
     from jax.experimental import pallas as pl
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
@@ -97,12 +124,11 @@ def _fwd_kernel_factory(dh, bq, bk, nk, causal, scale):
 
         @pl.when(_block_needed(causal, qi, j, bq, bk))
         def _block():
-            q = q_ref[0].astype(jnp.float32) * scale
-            k = k_ref[0].astype(jnp.float32)
-            v = v_ref[0].astype(jnp.float32)
+            v = v_ref[0]
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
             if causal:
                 s = jnp.where(_causal_keep(qi, j, bq, bk), s, NEG_INF)
             m = m_scr[:]  # (bq, LANES), value broadcast across lanes
@@ -113,7 +139,8 @@ def _fwd_kernel_factory(dh, bq, bk, nk, causal, scale):
             m_scr[:] = m_new
             l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
 
         @pl.when(j == nk - 1)
@@ -136,37 +163,53 @@ def _vma_union(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
+def _kv_index(causal, bq, bk):
+    """Index map of a K/V block in a (bh, q block, kv block) grid."""
+    if not causal:
+        return lambda i, qi, j: (i, j, 0)
+    return lambda i, qi, j: (i, jnp.minimum(j, _last_kv_block(qi, bq, bk)), 0)
+
+
+def _q_index(causal, bq, bk):
+    """Index map of a Q/dO/lse/delta block in a (bh, kv block, q block) grid."""
+    if not causal:
+        return lambda i, j, qi: (i, qi, 0)
+    return lambda i, j, qi: (i, jnp.maximum(qi, _first_q_block(j, bq, bk)), 0)
+
+
 def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     vma = _vma_union(q, k, v)
-    b, h, s, dh = q.shape
+    b, h, s, dqk = q.shape
+    dv = v.shape[-1]
     nk = s // bk
     bh = b * h
-    qf = q.reshape(bh, s, dh)
-    kf = k.reshape(bh, s, dh)
-    vf = v.reshape(bh, s, dh)
+    qf = q.reshape(bh, s, dqk)
+    kf = k.reshape(bh, s, dqk)
+    vf = v.reshape(bh, s, dv)
+    kv_index = _kv_index(causal, bq, bk)
     out, lse = pl.pallas_call(
-        _fwd_kernel_factory(dh, bq, bk, nk, causal, scale),
+        _fwd_kernel_factory(bq, bk, nk, causal, scale),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dh), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32, vma=vma),
         ),
         grid=(bh, s // bq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, qi, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, qi, j: (i, j, 0)),
+            pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bk, dqk), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bq, dv), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             # bh and q-block cells are independent; only the k scan (which
@@ -175,8 +218,9 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=FWD_KERNEL,
     )(qf, kf, vf)
-    return out.reshape(b, h, s, dh), lse
+    return out.reshape(b, h, s, dv), lse
 
 
 # ---------------------------------------------------------------------------
@@ -184,7 +228,18 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel_factory(dh, bq, bk, nk, causal, scale):
+def _recompute_p(q, k, lse, qi, j, bq, bk, causal, scale):
+    """P = exp(QKᵀ·scale − lse) of one block pair, f32, masked if causal."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    p = jnp.exp(s - lse)
+    if causal:
+        p = jnp.where(_causal_keep(qi, j, bq, bk), p, 0.0)
+    return p
+
+
+def _bwd_dq_kernel_factory(bq, bk, nk, causal, scale):
     from jax.experimental import pallas as pl
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr):
@@ -197,22 +252,15 @@ def _bwd_dq_kernel_factory(dh, bq, bk, nk, causal, scale):
 
         @pl.when(_block_needed(causal, qi, j, bq, bk))
         def _block():
-            q = q_ref[0].astype(jnp.float32)
-            k = k_ref[0].astype(jnp.float32)
-            v = v_ref[0].astype(jnp.float32)
-            do = do_ref[0].astype(jnp.float32)
+            k = k_ref[0]
             lse = lse_ref[0][:, 0:1]      # (bq, 1) from lane-broadcast state
             delta = delta_ref[0][:, 0:1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            p = jnp.exp(s - lse)
-            if causal:
-                p = jnp.where(_causal_keep(qi, j, bq, bk), p, 0.0)
+            p = _recompute_p(q_ref[0], k, lse, qi, j, bq, bk, causal, scale)
             dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            ds = p * (dp - delta)
+            ds = (p * (dp - delta)).astype(k.dtype)
             dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
@@ -224,7 +272,7 @@ def _bwd_dq_kernel_factory(dh, bq, bk, nk, causal, scale):
     return kernel
 
 
-def _bwd_dkv_kernel_factory(dh, bq, bk, nq, causal, scale):
+def _bwd_dkv_kernel_factory(bq, bk, nq, causal, scale):
     from jax.experimental import pallas as pl
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
@@ -239,25 +287,20 @@ def _bwd_dkv_kernel_factory(dh, bq, bk, nq, causal, scale):
 
         @pl.when(_block_needed(causal, qi, j, bq, bk))
         def _block():
-            q = q_ref[0].astype(jnp.float32)
-            k = k_ref[0].astype(jnp.float32)
-            v = v_ref[0].astype(jnp.float32)
-            do = do_ref[0].astype(jnp.float32)
+            q = q_ref[0]
+            do = do_ref[0]
             lse = lse_ref[0][:, 0:1]      # (bq, 1) from lane-broadcast state
             delta = delta_ref[0][:, 0:1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # (bq, bk)
-            p = jnp.exp(s - lse)
-            if causal:
-                p = jnp.where(_causal_keep(qi, j, bq, bk), p, 0.0)
+            p = _recompute_p(q, k_ref[0], lse, qi, j, bq, bk, causal, scale)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
             dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                do, v_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            ds = p * (dp - delta)
+            ds = (p * (dp - delta)).astype(q.dtype)
             dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
@@ -276,13 +319,14 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     vma = _vma_union(q, k, v, o, lse, do)
-    b, h, s, dh = q.shape
+    b, h, s, dqk = q.shape
+    dv = v.shape[-1]
     bh = b * h
     nq, nk = s // bq, s // bk
-    qf, kf, vf = (x.reshape(bh, s, dh) for x in (q, k, v))
-    dof = do.reshape(bh, s, dh)
+    qf, kf = (x.reshape(bh, s, dqk) for x in (q, k))
+    vf, dof = (x.reshape(bh, s, dv) for x in (v, do))
     delta = jnp.sum(
-        dof.astype(jnp.float32) * o.reshape(bh, s, dh).astype(jnp.float32), axis=-1
+        dof.astype(jnp.float32) * o.reshape(bh, s, dv).astype(jnp.float32), axis=-1
     )  # (bh, s) → lane-broadcast like lse so its blocks stay tileable
     if dlse is not None:
         # An lse cotangent (ring-attention online-softmax merge, which
@@ -291,58 +335,62 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
         # kernels run unchanged on Δ' = Δ − dlse.
         delta = delta - dlse.reshape(bh, s).astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (bh, s, LANES))
+    lse = jnp.broadcast_to(lse[..., None], (bh, s, LANES))  # the residual is one value a row
 
+    kv_index = _kv_index(causal, bq, bk)
     dq = pl.pallas_call(
-        _bwd_dq_kernel_factory(dh, bq, bk, nk, causal, scale),
-        out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype, vma=vma),
+        _bwd_dq_kernel_factory(bq, bk, nk, causal, scale),
+        out_shape=jax.ShapeDtypeStruct((bh, s, dqk), q.dtype, vma=vma),
         grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, qi, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, qi, j: (i, j, 0)),
-            pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bk, dqk), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
+            pl.BlockSpec((1, bq, dv), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda i, qi, j: (i, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
+        scratch_shapes=[pltpu.VMEM((bq, dqk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=DQ_KERNEL,
     )(qf, kf, vf, dof, lse, delta)
 
-    dk, dv = pl.pallas_call(
-        _bwd_dkv_kernel_factory(dh, bq, bk, nq, causal, scale),
+    q_index = _q_index(causal, bq, bk)
+    dk, dv_ = pl.pallas_call(
+        _bwd_dkv_kernel_factory(bq, bk, nq, causal, scale),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dh), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s, dh), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s, dqk), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s, dv), v.dtype, vma=vma),
         ),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda i, j, qi: (i, qi, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, bq, dh), lambda i, j, qi: (i, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda i, j, qi: (i, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda i, j, qi: (i, qi, 0)),
+            pl.BlockSpec((1, bq, dqk), q_index),
+            pl.BlockSpec((1, bk, dqk), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bq, dv), q_index),
+            pl.BlockSpec((1, bq, LANES), q_index),
+            pl.BlockSpec((1, bq, LANES), q_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bk, dh), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bk, dqk), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda i, j, qi: (i, j, 0)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
+            pltpu.VMEM((bk, dqk), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=DKV_KERNEL,
     )(qf, kf, vf, dof, lse, delta)
 
-    shape = (b, h, s, dh)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -356,9 +404,16 @@ def _flash(q, k, v, causal, scale, bq, bk, interpret):
     return out
 
 
+def _residuals(q, k, v, out, lse):
+    """(out, lse a row) under their names, and the residual tuple."""
+    out = checkpoint_name(out, SAVED[0])
+    lse = checkpoint_name(lse[..., 0], SAVED[1])  # (bh, s): the lanes repeat one value
+    return out, lse, (q, k, v, out, lse)
+
+
 def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
-    return out, (q, k, v, out, lse)
+    out, _, res = _residuals(q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret))
+    return out, res
 
 
 def _flash_bwd(causal, scale, bq, bk, interpret, res, g):
@@ -376,8 +431,8 @@ def _flash_lse(q, k, v, causal, scale, bq, bk, interpret):
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, bq, bk, interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
-    return (out, lse[..., 0].reshape(q.shape[:3])), (q, k, v, out, lse)
+    out, lse, res = _residuals(q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret))
+    return (out, lse.reshape(q.shape[:3])), res
 
 
 def _flash_lse_bwd(causal, scale, bq, bk, interpret, res, g):
@@ -505,7 +560,8 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q/k/v: (B, H, S, dh) → (B, H, S, dh).
+    """q/k: (B, H, S, d_qk), v: (B, H, S, d_v) → (B, H, S, d_v); the
+    default scale is d_qk**-0.5.
 
     Pallas kernels (fwd + blocked bwd) on a TPU, where S must divide by
     the block sizes; what runs elsewhere is :func:`_kernel_path`'s call.

@@ -124,3 +124,178 @@ def moe_aux_loss(x: jax.Array, router_w: jax.Array, axis_size: int, e_local: int
     gates = jax.nn.softmax(x @ router_w, axis=-1)
     mask = jax.nn.one_hot(jnp.argmax(gates, axis=-1), e_total, dtype=x.dtype)
     return e_total * jnp.mean(jnp.mean(gates, axis=0) * jnp.mean(mask, axis=0))
+
+
+# ---------------------------------------------------------------------------
+# Held experts: sigmoid top-k routing over the whole model's experts, grouped
+# products over the experts this device holds, no capacity and no drop
+# ---------------------------------------------------------------------------
+
+#: what one compiled step reports of its routing, in this order
+ROUTING_STATS = ("moe_slots_routed", "moe_slots_held", "moe_slots_dropped",
+                 "moe_fullest_expert_slots")
+
+
+def sigmoid_topk_route(g: jax.Array, router_w: jax.Array, select_bias: jax.Array,
+                       top_k: int, scale: float) -> tuple:
+    """DeepSeek-V3's ``noaux_tc`` routing with one group.  ``g`` (T, D) and
+    ``router_w`` (D, E) in f32 — near-ties among E sigmoid scores decide which
+    experts run, so the scores are full f32 products.  The ``top_k`` largest
+    of ``score + select_bias`` are chosen; the bias picks and does not weigh:
+    ``w_i = scale · s_i / (Σ_chosen s_j + 1e-20)``.  Returns the chosen ids
+    (T, k) int32 and their weights (T, k) f32."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        g.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32))
+    _, ids = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), weights
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
+                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                    lo: int, n_experts: int) -> tuple:
+    """The routed experts' part of a layer's output that THIS device's
+    experts give: ``Σ_{i chosen and held} w_i E_i(g)``, every ``E`` a
+    bias-free SwiGLU.
+
+    g:        (T, D) tokens, compute dtype
+    ids, weights: (T, k) from :func:`sigmoid_topk_route`, over all
+              ``n_experts`` of the model
+    w_gate, w_up: (n_held, D, F), w_down: (n_held, F, D) — the experts
+              ``[lo, lo + n_held)``, which this device holds
+
+    The slots (token, choice) whose expert is held are ordered by expert
+    (one sort) and multiplied in grouped products (``lax.ragged_dot``: each
+    expert takes exactly its rows, however many).  No capacity, no drop: the
+    ordered slots are walked in chunks of twice what a uniform router sends
+    here: the usual step runs one chunk; when more slots arrive, the other
+    chunks run too (all T·k slots at most), so imbalance costs time and
+    never a token, and the buffers stay one chunk large.  What the experts held elsewhere would add is left
+    out: with expert parallelism their devices add it, and on one device
+    nothing stands in for them.
+
+    Returns ``(y (T, D) f32, stats (4,) int32 in ROUTING_STATS order)``."""
+    t, k = ids.shape
+    n_held, d = w_gate.shape[0], g.shape[1]
+    local = ids.reshape(-1) - lo
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)  # (T·k,)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=key.dtype)[None, :],
+                    axis=0, dtype=jnp.int32)  # slots of each held expert
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held slots first, by expert
+    ends = jnp.cumsum(sizes)
+    n_slots = ends[-1]
+    flat_w = weights.reshape(-1)
+
+    every = t * k
+    rows = min(every, _round_up(2 * every * n_held // n_experts, 8 if every < 4096 else 512))
+    n_chunks = -(-every // rows)
+    order = jnp.pad(order, (0, n_chunks * rows - every))
+
+    def chunk(y, c):
+        """Adds the slots [c·rows, (c+1)·rows) of the ordered list to y;
+        also returns how many slots the grouped products took."""
+        first = c * rows
+        slot = lax.dynamic_slice_in_dim(order, first, rows)
+        tok = slot // k
+        # this chunk's rows of each expert: its span cut to the chunk
+        mine = jnp.clip(jnp.minimum(ends, first + rows) - jnp.maximum(ends - sizes, first),
+                        0, rows)
+        # rows past the last held slot belong to no expert: a grouped
+        # product leaves them unwritten (garbage on the TPU), so every
+        # product's operand and result is cleared there by selection
+        live = (first + jnp.arange(rows) < n_slots)[:, None]
+        xs = jnp.where(live, g[tok], 0)  # (rows, D)
+
+        def grouped(lhs, rhs):
+            return jnp.where(live, lax.ragged_dot(lhs, rhs, mine), 0)
+
+        out = grouped(jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
+        return y.at[tok].add(out.astype(jnp.float32) * flat_w[slot][:, None]), jnp.sum(mine)
+
+    y0 = jnp.zeros((t, d), jnp.float32)
+    varying = tuple(jax.typeof(g).vma)  # the carry's type under shard_map: as g varies
+    if varying:
+        y0 = lax.pcast(y0, varying, to="varying")
+    none = jnp.zeros_like(n_slots)  # typed as the counts are, also under shard_map
+
+    def from_chunk(y, c):
+        """Chunk c, which has slots, and behind ONE cond whatever follows it:
+        a step pays for no branch it does not take (a cond a chunk cost 16
+        zero-filled cotangents a layer in the backward pass).  The usual step
+        ends with chunk 0; a layer with up to twice its slots adds chunk 1;
+        beyond that the remaining chunks are scanned, each skipped once the
+        slots run out, and rebuilt in the backward pass."""
+        y, took = chunk(y, c)
+        if c + 1 == n_chunks:
+            return y, took
+        if c == 0:
+            more = lambda y: from_chunk(y, 1)  # noqa: E731
+        else:
+            def more(y):
+                def if_any(y, i):
+                    return lax.cond(i * rows < n_slots, lambda y: chunk(y, i),
+                                    lambda y: (y, none), y)
+
+                y, each = lax.scan(jax.checkpoint(if_any), y, jnp.arange(c + 1, n_chunks))
+                return y, jnp.sum(each)
+
+        y, after = lax.cond(n_slots > (c + 1) * rows, more, lambda y: (y, none), y)
+        return y, took + after
+
+    y, taken = from_chunk(y0, 0)
+    stats = jnp.stack([jnp.asarray(every, jnp.int32), n_slots, n_slots - taken,
+                       jnp.max(sizes)])
+    return y, stats
+
+
+class RoutingCounters:
+    """What compiled steps count (name → int32 scalar: the ROUTING_STATS of
+    the expert layers), added to the process's counters
+    (``bps.get_robustness_counters()``) without a blocking read:
+    :meth:`push` keeps a step's device arrays and folds in only what is
+    ready; a snapshot of the counters waits for the rest."""
+
+    def __init__(self) -> None:
+        import threading
+
+        from byteps_tpu.core.telemetry import counters
+
+        self._lock = threading.Lock()
+        self._pending: list = []
+        self._totals = dict.fromkeys(ROUTING_STATS, 0)
+        counters().register_provider(self._snapshot)
+
+    def push(self, counts: dict) -> None:
+        if not counts:  # a family that counts nothing
+            return
+        with self._lock:
+            self._pending.append(counts)
+            self._fold(wait=False)
+
+    def _fold(self, wait: bool) -> None:
+        while self._pending and (
+                wait or all(v.is_ready() for v in self._pending[0].values())):
+            for name, v in jax.device_get(self._pending.pop(0)).items():
+                self._totals[name] = self._totals.get(name, 0) + int(v)
+
+    def _snapshot(self) -> dict:
+        with self._lock:
+            self._fold(wait=True)
+            return dict(self._totals)
+
+
+_routing_counters: Optional[RoutingCounters] = None
+
+
+def routing_counters() -> RoutingCounters:
+    """The process's one sink for routing statistics."""
+    global _routing_counters
+    if _routing_counters is None:
+        _routing_counters = RoutingCounters()
+    return _routing_counters

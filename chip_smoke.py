@@ -14,14 +14,24 @@ the entry points a user calls, at the full width of the models the repo lists:
          equal steps x parameter bytes, and a sum of one comes back
          bit-identical.
   leg C  the Pallas kernels, compiled by Mosaic at the shapes the models use:
-         flash attention forward + backward against the dense reference, the
-         onebit packer against the host codec, and an onebit key through leg
-         B's engine — each with the Mosaic custom call shown in the lowered
-         program, so it is known that the kernel is what ran.
+         flash attention forward + backward against the dense reference (at
+         BERT-large's 16 x 64 and at latent attention's 2 x 32 x 8192 with
+         192 for q.k and 128 for v, causal, against a blocked f32 dense
+         reference), the onebit packer against the host codec, and an onebit
+         key through leg B's engine — each with the Mosaic custom call shown
+         in the lowered program, so it is known that the kernel is what ran.
   leg D  only with several devices: leg A again at dp x tp=2, and
          ``__graft_entry__._dryrun_one_mesh`` for {dp, pp=2} and {dp, sp=2}
          on the real devices (pipeline ppermute, ring attention, MoE
          all_to_all over the interconnect).
+  leg E  the latent-attention MoE family at the published widths of
+         benchmark/configs/joyai_llm_flash_ep32.json, one step through
+         ``build_train_step``: its loss and every leaf's gradient — before
+         any optimizer — against the float32 plain reference computed there
+         (the benchmark builder's blocked ``plain_loss``), relative L2 a
+         leaf and its projection on the reference's, the worst leaf named;
+         as the state is made, and once more with the experts' choice
+         pinned by the selection bias, where no near-tie is left to flip.
 
 Every result line names the platform, device kind, device count and the jax /
 jaxlib / libtpu versions.  Step times are printed as information only: they
@@ -41,6 +51,7 @@ import argparse
 import collections
 import dataclasses
 import importlib
+import importlib.util
 import json
 import math
 import os
@@ -429,6 +440,58 @@ def leg_c_flash(dry: bool) -> None:
             f"{[f'{e:.1e}' for e in e_grads]})")
 
 
+def leg_c_flash_latent(dry: bool) -> None:
+    """The kernel at latent attention's shape (d_qk 192, d_v 128, sequence
+    8192, causal) against dense attention in f32 at ``highest`` precision,
+    computed a block of queries at a time (8k scores do not fit whole)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    b, h, s, dqk, dv = (1, 2, 512, 192, 128) if dry else (2, 32, 8192, 192, 128)
+    block = 128 if dry else 256
+    tag = f"leg C flash latent {(b, h, s, dqk, dv)} causal, blocks {fa.tuned_blocks(s)}"
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, k = (jax.random.normal(kk, (b, h, s, dqk), jnp.bfloat16) for kk in keys[:2])
+    v, ct = (jax.random.normal(kk, (b, h, s, dv), jnp.bfloat16) for kk in keys[2:])
+    scale = dqk ** -0.5
+
+    def flash_loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=scale, interpret=dry)
+        return jnp.sum(out.astype(jnp.float32) * ct.astype(jnp.float32)), out
+
+    @jax.checkpoint
+    def attend(qb, first, k, v):
+        scores = jnp.einsum("bhqd,bhkd->bhqk", qb, k) * scale
+        seen = jnp.arange(s)[None, :] <= (first + jnp.arange(block))[:, None]
+        return jnp.einsum("bhqk,bhkd->bhqd",
+                          jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1), v)
+
+    def dense_loss(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            blocks = jnp.moveaxis(q.reshape(b, h, s // block, block, dqk), 2, 0)
+            out = lax.map(lambda xs: attend(xs[0], xs[1], k, v),
+                          (blocks, block * jnp.arange(s // block)))
+            out = jnp.moveaxis(out, 0, 2).reshape(b, h, s, dv)
+        return jnp.sum(out * ct.astype(jnp.float32)), out
+
+    flash = jax.jit(jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True))
+    dense = jax.jit(jax.value_and_grad(dense_loss, argnums=(0, 1, 2), has_aux=True))
+    require_mosaic(tag, 3, dry, flash, q, k, v)
+    (_, out), grads = flash(q, k, v)
+    (_, want), want_grads = dense(*(x.astype(jnp.float32) for x in (q, k, v)))
+    errs = {}
+    for name, got, ref in zip(("out", "dq", "dk", "dv"), (out, *grads), (want, *want_grads)):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref)
+        errs[name] = float(np.abs(got - ref).max() / np.abs(ref).max())
+        if not (np.isfinite(got).all() and errs[name] < 2e-2):  # bf16: 8 mantissa bits
+            raise SystemExit(f"{tag} {name}: max error {errs[name]:.2e} of peak")
+    say(f"{tag}: forward and dq/dk/dv agree with the blocked f32 dense reference "
+        f"(max error / peak: { {n: f'{e:.1e}' for n, e in errs.items()} })")
+
+
 def leg_c_onebit(dry: bool) -> None:
     import jax
     import jax.numpy as jnp
@@ -502,6 +565,160 @@ def leg_d(dry: bool) -> None:
 
 
 # ---------------------------------------------------------------------------
+# leg E — the latent-attention MoE family at its published widths
+# ---------------------------------------------------------------------------
+
+
+#: leaves whose gradient moves by whole tokens when a near-tie between the
+#: 8th and 9th router score falls the other way
+ROUTED_LEAVES = ("router", "e_gate", "e_up", "e_down")
+#: leg E's limits, each between two readings at the cell's size on the chip
+#: (PERF.md section 6, PR 29; tools/latent_moe_precision.py and this leg's own
+#: key): the program's largest, and the smallest of the plain reference in
+#: bf16 with the norms' statistics, the router and the softmax in bf16 too
+#: ("below"), or a planted fault: a halved gradient reads 0.5 in its leaf and
+#: in its projection, a lost one 1.
+#: As made (7 keys | 6): loss <= 6.4e-5 | 5.7e-5, so 3 x the first alone;
+#: routers and routed experts 0.24-0.30 | 0.30-0.36, which near-ties alone
+#: nearly reach, so the limit stands against the halved gradient; other
+#: leaves at most 0.052-0.058 | 0.064-0.071, median 0.046-0.049 | 0.055-0.062.
+#: Choice pinned (3 keys | 2): routed 0.0299-0.0308 | 0.0339-0.0352, other
+#: leaves 0.0309-0.0314 | 0.0361-0.0365, median 0.0262-0.0266 | 0.0295-0.0298:
+#: half of the noise as made is flipped tokens.  Projection within 0.051 of 1
+#: as made and 0.0043 pinned, on both sides.
+LEG_E_LIMITS = {
+    "as made": {"loss": 1.7e-4, "routed": 0.40, "rest": 0.061, "median": 0.052,
+                "projection": 0.15},
+    "choice pinned": {"loss": 1.7e-4, "routed": 0.0325, "rest": 0.0338, "median": 0.028,
+                      "projection": 0.15},
+}
+#: the dry run's toy widths prove the control flow, not the limits
+DRY_RUN_LIMITS = {"loss": 1e-3, "routed": 0.40, "rest": 0.2, "median": 0.1, "projection": 0.15}
+
+
+def gradient_readings(got: dict, want: dict) -> dict:
+    """How far a gradient lies from the reference's, leaf by leaf (both are
+    flat dicts; ``want`` may live on the host).  ``routed`` and ``rest``:
+    the largest relative L2 distance among the routers and routed experts,
+    and among the other leaves, with its leaf; ``median`` of the other
+    leaves; ``projection``: the furthest from 1 of <got, want> / <want, want>
+    over all leaves, with its leaf — a halved gradient reads 0.5 there and a
+    lost one 0 however many near-ties flipped, since what a flipped token
+    adds is nearly orthogonal to the reference; ``zero``: leaves where the
+    reference has no gradient (the selection bias), where ``got`` must have
+    none either."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def pair(x, y):
+        # sums of one kind (a vdot adds up a million products in another order)
+        return jnp.sum((x - y) ** 2), jnp.sum(y * y), jnp.sum(x * y), jnp.sum(x * x)
+
+    apart, proj, zero = {}, {}, []
+    for k, y in want.items():
+        d2, n2, dot, mine = (float(v) for v in pair(got[k], y))
+        if n2 == 0.0:
+            if mine:
+                raise SystemExit(f"{k} has a gradient, the reference none")
+            zero.append(k)
+            continue
+        apart[k], proj[k] = math.sqrt(d2 / n2), dot / n2
+    routed = [k for k in apart if k.rsplit(".", 1)[-1] in ROUTED_LEAVES]
+    rest = [k for k in apart if k not in routed]
+
+    def worst(keys, value):
+        k = max(keys, key=value)
+        return [k, value(k)]
+
+    return {"routed": worst(routed, apart.get), "rest": worst(rest, apart.get),
+            "median": sorted(apart[k] for k in rest)[len(rest) // 2],
+            "projection": worst(apart, lambda k: abs(proj[k] - 1.0)), "zero": zero}
+
+
+def keep_gradient():
+    """An "optimizer" that keeps the gradient as its state and moves nothing:
+    the comparison before the optimizer, through build_train_step itself."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    return optax.GradientTransformation(
+        lambda p: jax.tree.map(jnp.zeros_like, p),
+        lambda g, state, p=None: (jax.tree.map(jnp.zeros_like, g), g))
+
+
+def pin_choice(params: dict, cfg: dict) -> dict:
+    """The same parameters with a selection bias under which every token
+    picks the same ``top_k`` experts, half of them held here: no near-tie is
+    left to fall the other way once the input is rounded, so the routers and
+    the routed experts can be held like the other leaves; and the held
+    experts see 16 times their usual slots — the layer's path under skew."""
+    import jax.numpy as jnp
+
+    k, lo, held = cfg["num_experts_per_tok"], cfg["held_expert_lo"], cfg["n_routed_experts"]
+    ids = [lo + i for i in range(k // 2)]
+    ids += [(lo + held + i) % cfg["router_width"] for i in range(k - k // 2)]
+    return {name: jnp.zeros_like(v).at[:, jnp.asarray(ids)].set(10.0)
+            if name.endswith("router_bias") else v for name, v in params.items()}
+
+
+def leg_e(dry: bool) -> None:
+    import jax
+
+    from byteps_tpu.comm.mesh import get_global_mesh
+    from byteps_tpu.models.transformer import build_train_step
+
+    bench = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(bench, "configs", "joyai_llm_flash_ep32.json")) as f:
+        cfg = json.load(f)
+    if dry:
+        cfg.update(cfg["rehearsal"])
+    spec = importlib.util.spec_from_file_location(
+        "smoke_joyai_builder", os.path.join(bench, "builders", "joyai_llm_flash.py"))
+    builder = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(builder)
+    label = (f"leg E (JoyAI-LLM-Flash share: {cfg['num_hidden_layers']} layers + MTP, "
+             f"{cfg['n_routed_experts']} of {cfg['router_width']} experts, vocab "
+             f"{cfg['vocab_size']}, {cfg['batch_per_chip']} x {cfg['max_seq']} tokens)")
+    if jax.device_count() != 1:
+        say(f"{label}: skipped, it is one chip's share and jax has {jax.device_count()}")
+        return
+    params, batch, _ = builder.make_state(cfg, jax.random.PRNGKey(29), get_global_mesh())
+    keep = keep_gradient()
+    step = build_train_step(builder._model_config(cfg), builder._mesh4(get_global_mesh()),
+                            keep, donate=False)
+    reference = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))
+    # a token whose 8th and 9th scores nearly tie picks another expert once
+    # its input is rounded to bf16: its whole share of the router's gradient
+    # moves to another column, and an expert gains or loses a whole token.
+    # So the routers and the routed experts read ~ sqrt(2 x the share of
+    # slots that flipped), the other leaves bf16's noise.  With the choice
+    # pinned they read noise too
+    for case, state in (("as made", params), ("choice pinned", pin_choice(params, cfg))):
+        t0 = time.perf_counter()
+        _, grads, loss = jax.block_until_ready(step(state, keep.init(state), *batch))
+        say(f"{label}, {case}: system step in {time.perf_counter() - t0:.1f} s (with "
+            f"compilation), loss {float(loss):.6f}")
+        want_loss, want = reference(state, batch)
+        read = gradient_readings(grads, want)
+        del grads, want
+        off = abs(float(loss) - float(want_loss)) / abs(float(want_loss))
+        say(f"{label}, {case}: loss {float(loss):.6f} against the f32 reference's "
+            f"{float(want_loss):.6f} ({off:.1e} apart); gradients by leaf, relative L2 to the "
+            f"reference's: routers and routed experts at most {read['routed'][1]:.2e} "
+            f"({read['routed'][0]}), the other leaves at most {read['rest'][1]:.2e} "
+            f"({read['rest'][0]}), median {read['median']:.2e}; projection on the reference's "
+            f"furthest from 1 by {read['projection'][1]:.2e} ({read['projection'][0]}); no "
+            f"gradient on either side: {read['zero']}")
+        lim = DRY_RUN_LIMITS if dry else LEG_E_LIMITS[case]
+        if not (off < lim["loss"] and read["rest"][1] < lim["rest"]
+                and read["median"] < lim["median"] and read["routed"][1] < lim["routed"]
+                and read["projection"][1] < lim["projection"]):
+            raise SystemExit(f"{label}, {case}: further from the reference than {lim} allow")
+
+
+# ---------------------------------------------------------------------------
 
 
 def main() -> int:
@@ -572,6 +789,7 @@ def main() -> int:
 
         mark = stats.mark()
         leg_c_flash(dry)
+        leg_c_flash_latent(dry)
         leg_c_onebit(dry)
         say(f"leg C: {stats.since(mark)}")
 
@@ -579,6 +797,10 @@ def main() -> int:
             mark = stats.mark()
             leg_d(dry)
             say(f"leg D: {stats.since(mark)}")
+
+        mark = stats.mark()
+        leg_e(dry)
+        say(f"leg E: {stats.since(mark)}")
 
         check_children(children)
         bps.shutdown()

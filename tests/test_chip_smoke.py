@@ -39,7 +39,8 @@ class TestChipSmoke:
         assert result["ok"] is True and result["dry_run"] is True
         assert result["device"]["platform"] == "cpu"
         assert all("DRY-RUN" in ln for ln in lines[:-1])
-        for leg in ("leg A", "leg B", "leg C flash", "leg C onebit"):
+        for leg in ("leg A", "leg B", "leg C flash", "leg C flash latent", "leg C onebit",
+                    "leg E"):
             assert any(leg in ln for ln in lines), leg
 
     def test_without_the_flag_no_tpu_is_a_failure(self):

@@ -1,0 +1,93 @@
+"""The main path's kernels compiled for the chip at their real widths,
+without a chip: the TPU's compiler is installed here and compiles for a
+described v5e (guides/on-chip-measurement §2).  Nothing runs, so this says
+nothing of results or times — it catches what Mosaic or XLA:TPU would refuse
+(a block over the VMEM limit, a misaligned slice) before chip time is spent.
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu, and every xdist worker imports every test file.
+All such tests stay in THIS file, so one worker holds the library.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_flash_kernels_compile_at_the_latent_attention_shape(one_chip, no_compile_cache,
+                                                             monkeypatch):
+    """(2, 32, 8192, 192 | 128) causal, forward and both backward kernels,
+    with the blocks ops/flash_blocks.json commits for that sequence."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    b, h, s, d_qk, d_v = 2, 32, 8192, 192, 128
+    q, k = (jax.ShapeDtypeStruct((b, h, s, d_qk), jnp.bfloat16, sharding=one_chip)
+            for _ in range(2))
+    v = jax.ShapeDtypeStruct((b, h, s, d_v), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=d_qk ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v).as_text()
+    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache):
+    """16 384 tokens, top-8 of 256, 8 held experts of width 768 at d 2048:
+    the sort, the grouped products (XLA:TPU's own ragged-dot kernel) and
+    their gradients."""
+    from byteps_tpu.parallel import moe
+
+    t, d, f, held, experts, k = 16384, 2048, 768, 8, 256, 8
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def loss(g, router, bias, w_gate, w_up, w_down):
+        ids, weights = moe.sigmoid_topk_route(g, router, bias, k, 2.5)
+        y, stats = moe.held_expert_mlp(g, ids, weights, w_gate, w_up, w_down,
+                                       lo=0, n_experts=experts)
+        return jnp.sum(y), stats
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 3, 4, 5), has_aux=True),
+        shape(t, d), shape(d, experts, dtype=jnp.float32), shape(experts, dtype=jnp.float32),
+        shape(held, d, f), shape(held, d, f), shape(held, f, d))
+    assert "ragged-dot" in compiled.as_text()
+    # one chunk of 8192 rows at a time: far under what all 131 072 slots would take
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

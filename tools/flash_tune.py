@@ -6,6 +6,12 @@ XLA's fused dense attention — the data behind TransformerConfig.use_flash
 defaults.  Refuses to run off-TPU (CPU timings say nothing about Mosaic).
 
     python tools/flash_tune.py [--seqs 512,1024,2048,4096] [--bh 8,4]
+
+The sweep behind ops/flash_blocks.json's 8192 entry (latent attention: 192
+for q·k, 128 for v; the dense path cannot hold 8k scores):
+
+    python tools/flash_tune.py --seqs 8192 --bh 2,32 --dh 192 --dv 128 \
+        --blocks 256,512,1024 --no-dense --out chiprun_out/flash_blocks.json
 """
 
 import argparse
@@ -23,7 +29,13 @@ def main() -> int:
     ap.add_argument("--seqs", default="512,1024,2048,4096")
     ap.add_argument("--bh", default="8,4",
                     help="batch,heads used at every seq")
-    ap.add_argument("--dh", type=int, default=64)
+    ap.add_argument("--dh", type=int, default=64, help="head size of q and k")
+    ap.add_argument("--dv", type=int, default=0, help="head size of v (0: as --dh)")
+    ap.add_argument("--blocks", default="128,256,512")
+    ap.add_argument("--no-dense", action="store_true",
+                    help="skip the dense reference (its S x S scores do not fit at long S)")
+    ap.add_argument("--out", default="",
+                    help="where to write the winners (default: ops/flash_blocks.json)")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--no-write", action="store_true",
                     help="don't persist winners to ops/flash_blocks.json")
@@ -39,11 +51,13 @@ def main() -> int:
     from byteps_tpu.ops.flash_attention import flash_attention, _dense_reference
 
     b, h = (int(x) for x in args.bh.split(","))
-    dh = args.dh
-    blocks = [128, 256, 512]
+    dh, dv = args.dh, args.dv or args.dh
+    blocks = [int(x) for x in args.blocks.split(",")]
 
     def time_fn(fn, *xs):
-        f = jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)))
+        # all three gradients: with dq alone XLA drops the dK/dV kernel
+        f = jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
+                                       argnums=(0, 1, 2)))
         out = f(*xs)
         jax.block_until_ready(out)
         t0 = time.perf_counter()
@@ -56,11 +70,13 @@ def main() -> int:
     winners = {}   # seq -> {blocks, flash_ms, dense_ms}
     for s in (int(x) for x in args.seqs.split(",")):
         q, k, v = (
-            jnp.asarray(rng.normal(size=(b, h, s, dh)).astype(np.float32) * 0.1,
+            jnp.asarray(rng.normal(size=(b, h, s, d)).astype(np.float32) * 0.1,
                         jnp.bfloat16)
-            for _ in range(3)
+            for d in (dh, dh, dv)
         )
         try:
+            if args.no_dense:
+                raise RuntimeError("skipped by --no-dense")
             dense_ms = time_fn(
                 lambda q, k, v: _dense_reference(q, k, v, True, dh ** -0.5), q, k, v
             )
@@ -110,7 +126,7 @@ def main() -> int:
         # ops/__init__ re-exports the flash_attention FUNCTION, which
         # shadows the submodule in from-import; resolve the module itself
         _fa_mod = importlib.import_module("byteps_tpu.ops.flash_attention")
-        path = _fa_mod._TUNED_PATH  # producer/consumer share one location
+        path = args.out or _fa_mod._TUNED_PATH  # producer/consumer share one location
         try:
             with open(path) as f:
                 doc = json.load(f)
@@ -122,10 +138,10 @@ def main() -> int:
             blocks[str(s)] = w["blocks"]
             meta[str(s)] = {
                 "flash_ms": w["flash_ms"], "dense_ms": w["dense_ms"],
-                "bh": args.bh, "dh": args.dh,
+                "bh": args.bh, "dh": dh, "dv": dv,
             }
         with open(path, "w") as f:
-            json.dump({"blocks": blocks, "meta": meta}, f, indent=1)
+            json.dump({**doc, "blocks": blocks, "meta": meta}, f, indent=1)
         print(f"wrote {len(winners)} tuned block entries -> {path}")
     return 0
 

@@ -1,0 +1,167 @@
+#!/usr/bin/env python3
+"""Where the limits on the latent-attention MoE family's precision come from.
+
+    python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
+
+For each seed, at the size of benchmark/configs/joyai_llm_flash_ep32.json and
+with the benchmark's own state (``make_state`` from the seed as run.py folds
+it), on the TPU:
+
+  f32       the plain reference (the builder's blocked ``plain_loss``): three
+            adamw steps, the first step's gradient kept
+  program   ``build_train_step`` over the ``LatentMoEConfig``: one step's
+            gradient (as chip_smoke.py's leg E takes it) and three adamw steps
+  stated    the plain reference at the precision the configuration states:
+            bf16 operands, f32 norm statistics, router and softmax — a model
+            of the program, to show that the control reads like it
+  below     the same with the norms' statistics, the router's scores and
+            weights and the softmax in bf16: the nearest precision below,
+            which ``correct`` has to refuse
+
+and against f32, as ``benchmark/run.py`` and leg E read them: the largest
+relative distance of the three losses (``reference_rtol``); the parameters
+after three steps as a share of the reference's own update, over all leaves
+and in the worst (``reference_update_rtol``: value, leaf_value); the first
+gradient by leaf (leg E's limits).  One JSON line a seed on stdout, all of
+them in ``chiprun_out/latent_moe_precision.json``.  ``--rehearse`` runs the
+configuration's rehearsal cuts on the CPU: control flow only, never a reading.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import importlib.util
+import json
+import math
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def _builder():
+    path = os.path.join(ROOT, "benchmark", "builders", "joyai_llm_flash.py")
+    spec = importlib.util.spec_from_file_location("precision_joyai_builder", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--pin", action="store_true",
+                    help="with chip_smoke.pin_choice: every token picks the same experts")
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh
+
+    from chip_smoke import gradient_readings, keep_gradient, pin_choice
+
+    from byteps_tpu.models.transformer import build_train_step
+
+    dev = jax.devices()[0]
+    if (dev.platform == "tpu") == args.rehearse:
+        raise SystemExit(f"readings come from the TPU (and --rehearse stays off it); "
+                         f"jax found {dev.platform!r}")
+    with open(os.path.join(ROOT, "benchmark", "configs", "joyai_llm_flash_ep32.json")) as f:
+        cfg = json.load(f)
+    if args.rehearse:
+        cfg.update(cfg["rehearsal"])
+    builder, steps = _builder(), 3
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    tx = builder.make_optimizer(cfg)
+    def plain_steps(loss_fn):
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def step(p, s, b):
+            loss, grads = jax.value_and_grad(loss_fn)(p, b)
+            updates, s = tx.update(grads, s, p)
+            return optax.apply_updates(p, updates), s, loss, grads
+
+        def run(p, batch):
+            """(losses, the first gradient, the parameters after the steps);
+            ``p`` is donated."""
+            s, losses, first = jax.jit(tx.init)(p), [], None
+            for i in range(steps):
+                p, s, loss, grads = step(p, s, batch)
+                losses.append(float(loss))
+                # off the device at once: the steps need the room
+                first = jax.device_get(grads) if i == 0 else first
+                del grads
+            return losses, first, p
+        return run
+
+    keep = keep_gradient()
+    model, mesh4 = builder._model_config(cfg), builder._mesh4(mesh)
+    grad_step = build_train_step(model, mesh4, keep, donate=False)
+    train_step = build_train_step(model, mesh4, tx)  # as the builder's ``build`` makes it
+
+    def program_steps(p, batch):
+        first = jax.device_get(grad_step(p, keep.init(p), *batch)[1])
+        s, losses = jax.jit(tx.init)(p), []
+        for _ in range(steps):
+            p, s, loss = train_step(p, s, *batch)
+            losses.append(float(loss))
+        return losses, first, p
+
+    runs = {"program": program_steps,
+            "stated": plain_steps(builder.plain_loss(cfg, jnp.bfloat16, jnp.float32)),
+            "below": plain_steps(builder.plain_loss(cfg, jnp.bfloat16, jnp.bfloat16))}
+    reference = plain_steps(builder.plain_loss(cfg))
+
+    @jax.jit
+    def dist(x, y):
+        return jnp.linalg.norm(x - y)
+
+    lines = []
+    for seed in args.seeds:
+        key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)  # as run.py
+        params, batch, _ = builder.make_state(cfg, key, mesh)
+        if args.pin:
+            params = pin_choice(params, cfg)
+        # the starting point, and what the reference makes of it, wait on the
+        # host: the device holds one run's state at a time (the f32 step
+        # alone takes 14 of its 15.75 GiB)
+        placed = jax.tree.map(lambda x: x.sharding, params)
+        start = jax.device_get(params)
+        want_losses, want_grads, want_params = reference(params, batch)
+        del params
+        want_params = jax.device_get(want_params)
+        moved = {k: float(np.linalg.norm(want_params[k] - start[k])) for k in start}
+        line = {"seed": seed, "pinned": args.pin, "f32_losses": want_losses}
+        for name, run in runs.items():
+            losses, grads, after = run(jax.device_put(start, placed), batch)
+            off = {k: float(dist(after[k], want_params[k])) for k in after}
+            del after
+            apart = {k: off[k] / moved[k] if moved[k] else (math.inf if off[k] else 0.0)
+                     for k in off}
+            worst = max(apart, key=apart.get)
+            line[name] = {
+                "losses": losses,
+                "loss_off": max(abs(g - w) / abs(w) for g, w in zip(losses, want_losses)),
+                "update": math.hypot(*off.values()) / math.hypot(*moved.values()),
+                "update_leaf": [worst, apart[worst]],
+                "update_median": sorted(apart.values())[len(apart) // 2],
+                "gradient": gradient_readings(grads, want_grads),
+            }
+            del grads
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+        del start, want_grads, want_params
+    if not args.rehearse:
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        name = f"latent_moe_precision{'_pinned' if args.pin else ''}.json"
+        with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
+            json.dump(lines, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
