@@ -23,9 +23,21 @@ re-runs that key's init barrier (elastic resize, engine restart, forced
 re-init) — a stale entry replayed into a re-numbered generation would
 corrupt sums, so the journal must never outlive the numbering.
 
-The payload is copied on record (the engine hands zero-copy views whose
-buffers die with the task); that copy is the whole cost of the feature
-on the hot path.
+The payload is kept by REFERENCE where nothing can write its buffer, and
+copied where something can — read off the buffer itself (``_held``),
+not a knob and not the caller's type: ``bytes`` (codec and row-sparse
+payloads) is kept as is; a read-only ``memoryview`` whose exporters are
+read-only all the way down and no larger than the view — the engine's
+staging array of a jax job, ``np.asarray(slice)``, which jax hands out
+read-only and nothing writes — is kept as that view (the view keeps
+the staging array alive past its task); anything writable (a numpy
+job's partition may alias the caller's array, which the caller may
+overwrite after ``synchronize``) is copied.
+Both bounds count referenced bytes like copied ones, so the journal pins
+at most ``BYTEPS_JOURNAL_BYTES`` of staging memory — what it used to
+allocate.  Counters ``journal_ref_bytes`` / ``journal_copy_bytes`` say
+which way each recorded byte went; on the raw jax path the record costs
+no pass over the partition.
 
 Server-side optimizer keys (docs/architecture.md "Server-side
 optimizer") change nothing here: the journal records gradient pushes
@@ -42,17 +54,62 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Union
+
+import numpy as np
+
+
+def _owned_unwritable(view: memoryview) -> bool:
+    """Is ``view`` the whole of a buffer that nothing can write?  Walks
+    the chain of exporters: every ``memoryview`` must be read-only and
+    every ``ndarray`` non-writeable, down to the owner of the memory,
+    and each must be exactly as large as ``view`` — a window into a
+    larger buffer would pin all of it, past the journal's byte bound.
+    An owner that exports a buffer itself must export it read-only
+    (``bytearray``, ``array``, a writable ``mmap`` do not); one that
+    exports none (the capsule behind a device-to-host copy) cannot be
+    written from Python at all."""
+    link = view
+    while link is not None:
+        if isinstance(link, memoryview):
+            if not link.readonly or link.nbytes != view.nbytes:
+                return False
+            link = link.obj
+        elif isinstance(link, np.ndarray):
+            if link.flags.writeable or link.nbytes != view.nbytes:
+                return False
+            link = link.base
+        else:
+            try:
+                owner = memoryview(link)
+            except TypeError:
+                return True
+            return owner.readonly and owner.nbytes == view.nbytes
+    return True
+
+
+def _held(payload) -> Union[bytes, memoryview]:
+    """What the journal keeps of ``payload``: the object itself when it
+    is ``bytes`` or a flat byte view of memory nothing can write, a
+    ``bytes`` copy otherwise."""
+    if isinstance(payload, bytes):
+        return payload
+    if (isinstance(payload, memoryview) and payload.nbytes == len(payload)
+            and _owned_unwritable(payload)):
+        return payload
+    return bytes(payload)
 
 
 @dataclass(frozen=True)
 class JournalEntry:
     """One journaled push: the exact bytes (and framing metadata) the
-    engine emitted for (key, version)."""
+    engine emitted for (key, version).  ``payload`` is ``bytes`` or a
+    read-only ``memoryview`` of unsigned bytes over memory nothing
+    writes (module docstring); either goes to ``send_message`` as is."""
 
     version: int
     cmd: int
-    payload: bytes
+    payload: Union[bytes, memoryview]
     fused: bool = False  # emitted inside an Op.FUSED pack (replay is
     #                      per-key unfused — the server sums identically)
 
@@ -76,7 +133,14 @@ class RoundJournal:
                fused: bool = False) -> None:
         """Record (or replace — an unfuse fallback re-emits the same
         round) one push's wire payload."""
-        entry = JournalEntry(int(version), int(cmd), bytes(payload), fused)
+        held = _held(payload)
+        entry = JournalEntry(int(version), int(cmd), held, fused)
+        from byteps_tpu.core.telemetry import counters
+
+        counters().bump(
+            "journal_ref_bytes" if held is payload else "journal_copy_bytes",
+            len(held),
+        )
         with self._lock:
             per = self._entries.get(key)
             if per is None:

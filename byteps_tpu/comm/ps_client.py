@@ -43,10 +43,10 @@ from byteps_tpu.comm.rendezvous import GROUP_ALL, GROUP_WORKERS, RESIZE_SEQ
 from byteps_tpu.comm.transport import (
     Message,
     Op,
-    _recv_exact,
     close_socket,
     connect,
     recv_message,
+    recv_payload,
     send_message,
 )
 
@@ -2207,7 +2207,7 @@ class PSClient:
                         payload = _ZERO_COPIED
                     else:
                         payload = (
-                            _recv_exact(sock, length) if length else b""
+                            recv_payload(sock, length) if length else b""
                         )
                     if crc is not None and frame_checksum(
                         trace, sink if zero_copied else payload

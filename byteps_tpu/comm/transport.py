@@ -257,7 +257,7 @@ def crc32c(data, crc: int = 0) -> int:
         return int(native(a.ctypes.data, n, crc))
     tbl = _crc32c_table()
     c = crc ^ 0xFFFFFFFF
-    for b in bytes(data):
+    for b in memoryview(data).cast("B"):  # no copy of a payload here either
         c = (c >> 8) ^ tbl[(c ^ b) & 0xFF]
     return c ^ 0xFFFFFFFF
 
@@ -396,9 +396,21 @@ def recv_into(sock: socket.socket, view: memoryview) -> None:
 
 
 def _recv_exact(sock: socket.socket, n: int) -> bytes:
+    """The small fixed blocks of a frame (header, trace, checksum; the
+    shm van's handshake) as ``bytes``.  Payloads go through
+    :func:`recv_payload`, which makes no second copy."""
+    return bytes(recv_payload(sock, n))
+
+
+def recv_payload(sock: socket.socket, n: int) -> bytearray:
+    """Receive a frame's ``n`` payload bytes and return the buffer they
+    were received INTO: one pass over the payload on this side of the
+    wire.  A fresh ``bytearray`` a frame that the returned message owns
+    alone — no pool, no reuse — so whoever holds a payload (a parked
+    push, a fused member, a stored snapshot) never sees it change."""
     buf = bytearray(n)
     recv_into(sock, memoryview(buf))
-    return bytes(buf)
+    return buf
 
 
 def recv_header_ex(sock: socket.socket) -> tuple:
@@ -465,7 +477,7 @@ def recv_message(sock: socket.socket) -> Message:
     op, status, flags, seq, key, cmd, version, length, trace, crc, lossless = (
         recv_header_ex(sock)
     )
-    payload = _recv_exact(sock, length) if length else b""
+    payload = recv_payload(sock, length) if length else b""
     verify_checksum(crc, trace, payload, op=op)
     if lossless:
         from byteps_tpu.compression.lossless import decompress_frame
