@@ -1864,8 +1864,8 @@ class PSClient:
                 return  # on_reply(None) already fired → retry scheduled
             try:
                 sc.send_msg(make_msg(seq))
-                # every frame that actually hit the wire (incl. retries) —
-                # the denominator tools/fusion_bench.py compares
+                # every frame that actually hit the wire (incl. retries):
+                # what fusion lowers (tests/test_fusion.py compares it)
                 counters().bump("wire_rpc")
             except (ConnectionError, OSError):
                 # died between alloc and send: claim the callback — if the
@@ -2547,7 +2547,7 @@ class PSClient:
         trace: Optional[tuple] = None,
         member_spans: Optional[List[int]] = None,
     ) -> None:
-        """One multi-key fused push+pull RPC (Op.FUSED; docs/perf.md).
+        """One multi-key fused push+pull RPC (Op.FUSED; docs/fusion.md).
 
         ``members`` is ``[(key, cmd, version, payload), ...]`` — small
         same-server partitions packed by the engine's FUSE stage.  The

@@ -118,7 +118,7 @@ class Op(enum.IntEnum):
     FUSED = 14        # multi-key fused push+pull: request packs N small
                       # sub-pushes for one server; the response is the N
                       # merged round payloads (small-tensor coalescing,
-                      # docs/perf.md).  One seq / deadline / retry state
+                      # docs/fusion.md).  One seq / deadline / retry state
                       # covers the whole frame.
     # control
     PING = 20

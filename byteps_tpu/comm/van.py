@@ -347,12 +347,9 @@ class ShmVan(Van):
         sock = _dial_with_retry(dial, timeout)
         # default 512KB (was 16MB): payloads larger than the ring stream
         # through it with cheap park/kick handoffs, so capacity buys
-        # nothing — while SMALL rings keep the working set in cache/TLB.
-        # Measured (SCALING_r05.json r5_findings.ring_size): the 8w×8srv
-        # cell cycled 64 conns × 2 × 16MB = 2GB of wrap-around pages and
-        # ran at 274 MB/s aggregate; with 512KB rings the same cell runs
-        # at 704 MB/s, and even a single pair moving 8MB payloads is ~8%
-        # faster (2979 vs 2762 MB/s, van_bench).
+        # nothing — while SMALL rings keep the working set in cache/TLB:
+        # 8 workers × 8 servers cycle 64 conns × 2 × 16MB = 2GB of
+        # wrap-around pages at the old size, 64MB at this one.
         size = int(os.environ.get("BYTEPS_SHM_RING_BYTES", str(512 << 10)))
         created = []
         tx = rx = None

@@ -94,7 +94,7 @@ class Config:
     # rounds observed per key before the policy verdict
     compression_auto_rounds: int = 3  # BYTEPS_COMPRESSION_AUTO_ROUNDS
 
-    # --- small-tensor fusion (docs/perf.md) ---
+    # --- small-tensor fusion (docs/fusion.md) ---
     # partitions at or below this many BYTES take the FUSE stage: same-
     # server neighbors are packed into one multi-key Op.FUSED RPC instead
     # of per-key push+pull pairs — the hot path stops paying per-message
@@ -102,7 +102,7 @@ class Config:
     # (every partition keeps its own RPC).  BOTH server engines speak
     # Op.FUSED (the C++ data plane since the native-parity port); off by
     # default purely because coalescing only pays on many-small-key
-    # workloads (docs/perf.md tuning note).
+    # workloads (docs/fusion.md tuning note).
     fusion_threshold: int = 0  # BYTEPS_FUSION_THRESHOLD
     # fusion buffer capacity per destination server; a full buffer
     # flushes immediately

@@ -116,7 +116,7 @@ class QueueType(enum.IntEnum):
     # Partitions below BYTEPS_FUSION_THRESHOLD bytes take FUSE instead of
     # PUSH — the stage packs same-server partitions into one multi-key
     # Op.FUSED frame, and the fused reply fans back out into each
-    # member's PULL stage (docs/perf.md).
+    # member's PULL stage (docs/fusion.md).
     FUSE = 12
 
 

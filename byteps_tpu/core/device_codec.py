@@ -1,4 +1,4 @@
-"""Device-side codec adapters for the engine pipeline (VERDICT r4 #4).
+"""Device-side codec adapters for the engine pipeline.
 
 The reference compresses on the CPU *after* staging the full fp32
 gradient to host (compress loop, core_loops.cc:498-536).  On TPU the

@@ -307,47 +307,35 @@ class _StripedStage:
         self.stripes[task.key % len(self.stripes)].report_finish(task)
 
 
-class PipelineEngine:
-    #: host pipeline stage order (PS path); COMPRESS/DECOMPRESS spliced in
-    #: when the tensor has a registered compressor (operations.cc:199-204)
-    STAGES = [QueueType.COPYD2H, QueueType.PUSH, QueueType.PULL, QueueType.COPYH2D]
-    STAGES_COMPRESSED = [
-        QueueType.COPYD2H, QueueType.COMPRESS, QueueType.PUSH,
-        QueueType.PULL, QueueType.DECOMPRESS, QueueType.COPYH2D,
-    ]
-    #: small partitions (≤ BYTEPS_FUSION_THRESHOLD bytes) swap PUSH for
-    #: FUSE: the multi-key fused RPC carries both halves of the round
-    #: trip, and the PULL stage delivers the fanned-out reply slice
-    #: locally (docs/perf.md)
-    STAGES_FUSED = [
-        QueueType.COPYD2H, QueueType.FUSE, QueueType.PULL, QueueType.COPYH2D,
-    ]
-    #: compressed wire path × fusion (docs/gradient-compression.md
-    #: "Compressed wire path"): a compressed partition whose WIRE size
-    #: (codec wire_nbytes) fits the fusion threshold rides the fuser like
-    #: any small partition — its member cmd carries
-    #: RequestType.COMPRESSED_PUSH_PULL so the server sums it through the
-    #: key's codec chain, and the fused reply slot comes back
-    #: codec-compressed for the DECOMPRESS stage to decode.  The two
-    #: headline wire optimizations finally multiply instead of excluding
-    #: each other.
-    STAGES_COMPRESSED_FUSED = [
-        QueueType.COPYD2H, QueueType.COMPRESS, QueueType.FUSE,
-        QueueType.PULL, QueueType.DECOMPRESS, QueueType.COPYH2D,
-    ]
-    #: device codec × fusion (docs/gradient-compression.md "Device
-    #: path"): the device packer emits the exact wire encoding ON
-    #: DEVICE, so COPYD2H already lands `task.compressed` — COMPRESS is
-    #: a pass-through, the fuser adds the device buffer's bytes as a
-    #: COMPRESSED_PUSH_PULL member, and the fused reply slot feeds the
-    #: device decoder on DECOMPRESS.  Same stage sequence as the host
-    #: compressed+fused path; the difference is WHERE the packing ran —
-    #: only compressed bytes ever cross the D2H boundary.
-    STAGES_DEVICE_COMPRESSED_FUSED = [
-        QueueType.COPYD2H, QueueType.COMPRESS, QueueType.FUSE,
-        QueueType.PULL, QueueType.DECOMPRESS, QueueType.COPYH2D,
+def _stages(compressed: bool, fused: bool) -> list:
+    """One partition's host pipeline (PS path), the one place that
+    decides it.
+
+    ``compressed``: the key has a codec, host or device — COMPRESS and
+    DECOMPRESS are spliced in (operations.cc:199-204).  A device codec
+    emits the exact wire encoding on the device, so COPYD2H already
+    lands ``task.compressed`` and COMPRESS is a pass-through: the
+    sequence is the same, the difference is where the packing ran.
+
+    ``fused``: the partition's wire size fits ``BYTEPS_FUSION_THRESHOLD``
+    — FUSE takes PUSH's place: the multi-key fused RPC carries both
+    halves of the round trip, and PULL delivers the fanned-out reply
+    slice locally (docs/fusion.md).  A compressed member's cmd carries
+    ``RequestType.COMPRESSED_PUSH_PULL``, so the server sums it through
+    the key's codec chain and the reply slot comes back codec-compressed
+    for DECOMPRESS (docs/gradient-compression.md "Compressed wire path",
+    "Device path")."""
+    return [
+        QueueType.COPYD2H,
+        *([QueueType.COMPRESS] if compressed else []),
+        QueueType.FUSE if fused else QueueType.PUSH,
+        QueueType.PULL,
+        *([QueueType.DECOMPRESS] if compressed else []),
+        QueueType.COPYH2D,
     ]
 
+
+class PipelineEngine:
     #: monotonically increasing engine-instance id: the tensor registry
     #: (and each ctx's ``initialized`` flag) outlives shutdown()/init()
     #: cycles, but servers started by a LATER init() have fresh stores —
@@ -650,30 +638,17 @@ class PipelineEngine:
         self._stamp_job_trace(job)
         self._step_begin()
         for part in ctx.partitions:
-            p_compressed = (
-                part.key in self._compressors
-                and part.key not in self._compression_auto_off
-            )
             if on_device:
-                wire_est = self._device_codecs[part.key].wire_nbytes()
-                small = bool(fuse_limit) and wire_est <= fuse_limit
-                qlist = (
-                    self.STAGES_DEVICE_COMPRESSED_FUSED if small
-                    else self.STAGES_COMPRESSED
-                )
-            elif p_compressed:
-                wire_est = self._compressors[part.key].wire_nbytes()
-                small = bool(fuse_limit) and wire_est <= fuse_limit
-                qlist = (
-                    self.STAGES_COMPRESSED_FUSED if small
-                    else self.STAGES_COMPRESSED
-                )
+                codec = self._device_codecs[part.key]
+            elif (part.key in self._compressors
+                  and part.key not in self._compression_auto_off):
+                codec = self._compressors[part.key]
             else:
-                small = (
-                    bool(fuse_limit)
-                    and part.length * itemsize <= fuse_limit
-                )
-                qlist = self.STAGES_FUSED if small else self.STAGES
+                codec = None
+            wire_est = (
+                part.length * itemsize if codec is None else codec.wire_nbytes()
+            )
+            small = bool(fuse_limit) and wire_est <= fuse_limit
             if small:
                 with self._fuse_lock:
                     self._staged_smalls += 1
@@ -685,9 +660,9 @@ class PipelineEngine:
                 offset=part.offset,
                 length=part.length,
                 total_partnum=len(ctx.partitions),
-                queue_list=list(qlist),
+                queue_list=_stages(codec is not None, small),
                 context=job,
-                fuse_staged=bool(small),
+                fuse_staged=small,
                 job=ctx.job,
             )
             self._stamp_task_trace(task, job)
@@ -1450,8 +1425,7 @@ class PipelineEngine:
             task.compressed = dc.compress(sl)  # D2H of the packed payload
             # the headline device-path number: bytes that actually
             # crossed the device→host boundary — compressed, vs the
-            # host path's raw staging below (docs/observability.md;
-            # tools/compression_bench.py D2H column)
+            # host path's raw staging below (docs/observability.md)
             counters().bump("d2h_bytes", len(task.compressed))
             self._proceed(task)
             return

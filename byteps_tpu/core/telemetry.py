@@ -107,11 +107,11 @@ class RobustnessCounters:
     - ``chaos_drop`` / ``chaos_delay`` / ``chaos_disconnect`` /
       ``chaos_truncate`` / ``chaos_corrupt`` — injected faults
 
-    Small-tensor fusion (docs/perf.md):
+    Small-tensor fusion (docs/fusion.md):
 
     - ``wire_rpc``             — data-plane frames actually sent (every
-      async push/pull/fused attempt, retries included) — the denominator
-      ``tools/fusion_bench.py`` compares fused vs. unfused
+      async push/pull/fused attempt, retries included) — what fusion
+      lowers, fused against unfused
     - ``fused_frames``         — multi-key Op.FUSED frames shipped
     - ``fused_keys``           — member partitions carried by those frames
       (``fused_keys / fused_frames`` = achieved pack density)

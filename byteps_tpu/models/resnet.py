@@ -1,5 +1,5 @@
 """ResNet family (flax) — the reference's throughput benchmark model
-(docs/performance.md:3-12: ResNet-50, batch 64/device).
+(BASELINE.md: ResNet-50, batch 64/device).
 
 TPU notes: NHWC layout (native for TPU convolutions), bf16 compute with
 fp32 batch-norm statistics, SAME padding so spatial dims stay MXU-tileable.

@@ -1,5 +1,5 @@
 """VGG family (flax) — the reference's communication-bound benchmark model
-(docs/performance.md:3-12: VGG-16, +100% over Horovod because its huge
+(BASELINE.md: VGG-16, +100% over Horovod there because its huge
 dense layers stress the gradient path — exactly what the PS/compression
 pipeline accelerates)."""
 

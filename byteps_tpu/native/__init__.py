@@ -423,7 +423,7 @@ def native_server_drain_spans(server_id: int, max_recs: int = 4096):
 def native_server_stripe_depths(server_id: int) -> list:
     """Current task backlog per reducer stripe of one native server
     instance (the ``native_stripe_queue_depth{stripe}`` gauge feed;
-    docs/perf.md hot-stripe note).  Empty once the instance is stopped
+    docs/fusion.md hot-stripe note).  Empty once the instance is stopped
     or the lib predates the striping surface."""
     lib = _load()
     if lib is None or not hasattr(lib, "bps_native_server_stripe_queue_depths"):

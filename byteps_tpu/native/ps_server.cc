@@ -429,7 +429,7 @@ std::unique_ptr<Codec> make_codec(const std::map<std::string, std::string>& kw,
 // stream.
 //
 // Transport is virtual so the engine composes with every van the Python
-// server supports (VERDICT r3 #3): FdConn covers the tcp and uds vans
+// server supports: FdConn covers the tcp and uds vans
 // (byte streams), ShmConn the shm van — headers and payloads through
 // mmap'd SPSC rings (shm_ring.py layout), with the UDS control socket as
 // handshake carrier + SIGKILL-liveness backstop.
@@ -1303,7 +1303,7 @@ class NativeServer {
   // current per-stripe task backlog (approximate, relaxed reads) — the
   // native_stripe_queue_depth{stripe} gauge feed: a persistently deep
   // stripe while its siblings idle means the key hash is aliasing hot
-  // keys onto one reducer (docs/perf.md)
+  // keys onto one reducer (docs/fusion.md)
   int32_t read_stripe_depths(uint64_t* out, int32_t cap) const {
     int32_t n = std::min<int32_t>(cap, (int32_t)stripes_.size());
     for (int32_t i = 0; i < n; ++i)
@@ -1381,7 +1381,7 @@ class NativeServer {
   // UDS listener variant: the uds van (shm=false) speaks the framed
   // protocol straight over the stream socket; the shm van (shm=true)
   // uses the socket for handshake/liveness and moves bytes through
-  // mmap'd rings (VERDICT r3 #3 — native engine × zero-copy transport).
+  // mmap'd rings (native engine × zero-copy transport).
   bool start_unix(const char* path, int num_workers, bool enable_async,
                   bool shm) {
     shm_van_ = shm;
@@ -1471,7 +1471,7 @@ class NativeServer {
     // BYTEPS_SERVER_STRIPES: reducer-thread count the key space shards
     // across.  Default min(4, cores): below 4 cores more stripes only
     // buy context switching; above, 4 reducers already saturate the
-    // memory bus this sum-and-memcpy workload lives on (docs/perf.md).
+    // memory bus this sum-and-memcpy workload lives on (docs/fusion.md).
     // When STRIPES is unset, an explicit BYTEPS_SERVER_ENGINE_THREAD is
     // honored as the stripe count — it was this engine's thread knob
     // before striping, and deployments that sized it must not silently
@@ -2359,7 +2359,7 @@ class NativeServer {
              body.size());
   }
 
-  // Op.FUSED scatter (docs/perf.md), run on the I/O thread: unpack one
+  // Op.FUSED scatter (docs/fusion.md), run on the I/O thread: unpack one
   // multi-key fused frame and fan its members out to their owning
   // stripes as kTaskFusedMember tasks, each a zero-copy VIEW into the
   // refcounted frame buffer.  The FusedReply countdown gathers the

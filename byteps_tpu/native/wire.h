@@ -43,7 +43,7 @@ enum Opcode : uint8_t {
   kPush = 11,
   kPull = 12,
   kRegisterCompressor = 13,
-  kFused = 14,   // multi-key fused push+pull frame (docs/perf.md)
+  kFused = 14,   // multi-key fused push+pull frame (docs/fusion.md)
   kPing = 20,
   kShutdown = 21,
   // recovery plane (docs/robustness.md "healing flow")

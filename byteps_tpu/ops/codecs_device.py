@@ -1,4 +1,4 @@
-"""On-device topk and dithering compression (round-2 VERDICT #8).
+"""On-device topk and dithering compression.
 
 Like :mod:`byteps_tpu.ops.onebit_device`, these move the compression the
 reference runs on the CPU (compress loop, core_loops.cc:498-536) onto the

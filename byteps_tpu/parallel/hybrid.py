@@ -16,9 +16,8 @@ network through the PS push/pull plane.  The TPU translation:
 - the optimizer applies the globally-averaged gradients and parameters
   return to the device with their ``NamedSharding`` for the next step.
 
-This is the composition VERDICT r4 #5 asked to see in one loop: the
-mesh plane and the PS plane are not alternatives, they are the two
-levels of one step.
+This is the composition in one loop: the mesh plane and the PS plane
+are not alternatives, they are the two levels of one step.
 
     mesh = Mesh(devices.reshape(2, 2), ("dp", "tp"))
     hdp = HybridDataParallel(loss_fn, params, optax.sgd(0.1), mesh=mesh,

@@ -1051,7 +1051,7 @@ class PSServer:
         # QoS (a priority above the default or a quota): with no
         # declaration the engine queues stay job-blind — byte-fair
         # service is a policy change, and "QoS off" must mean the exact
-        # legacy order (the honest A/B baseline tools/qos_bench.py runs)
+        # legacy order (the baseline tests/test_multitenant.py's demo runs)
         self._qos_active = any(
             q["priority"] > 1 or q["quota_mbps"] > 0 for q in qos.values()
         )
@@ -3003,7 +3003,7 @@ class NativePSServer:
         self._hist_provider = lambda: native_server_histograms(sid)
         metrics().register_hist_provider(self._hist_provider)
         # per-stripe task backlog of the key-striped reducer plane, one
-        # gauge series per reducer (docs/perf.md hot-stripe note): a
+        # gauge series per reducer (docs/fusion.md hot-stripe note): a
         # persistently deep stripe while its siblings idle means the key
         # hash is aliasing hot keys onto one reducer.  Sampled lazily at
         # exposition time; the stripe closures share one short-lived

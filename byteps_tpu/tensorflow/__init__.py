@@ -113,10 +113,9 @@ def _sync_grads(grads, sources, compression, op: str, scope: str):
     # in-graph dtype-bucket fusion: one host hop + one engine submit per
     # dtype instead of per tensor.  Worth it exactly when the concat/
     # split compile into a graph (tf.function — the Keras train-step
-    # case: 3.57 → 1.76 ms for a 30-tensor list, TF_OVERHEAD_r05.json);
-    # in eager mode the ~60 extra op dispatches cost MORE than the
-    # marshalling saved (6.11 → 10.48 ms), so "auto" fuses only while
-    # tracing.  1/0 force it on/off (all workers must agree: fusion
+    # case); in eager mode the ~60 extra op dispatches of a 30-tensor
+    # list cost MORE than the marshalling saved, so "auto" fuses only
+    # while tracing.  1/0 force it on/off (all workers must agree: fusion
     # changes the wire keys).
     use_fused = (
         fusion == "1"

@@ -128,8 +128,7 @@ def push_pull_group_fused(tensors, names, average: bool = True):
     """Differentiable grouped push_pull with IN-GRAPH fusion.
 
     The plain group path pays the py_function marshalling and one engine
-    submit per tensor (~6ms for a 30-tensor gradient list,
-    TF_OVERHEAD_r04.json).  Here the tensors are concatenated per dtype
+    submit per tensor.  Here the tensors are concatenated per dtype
     by TF's own C++ runtime, so the host hop marshals and submits ONE
     flat tensor per dtype, and the outputs are split/reshaped back
     in-graph.  Composes with the level-1 compressors (an fp16-compressed

@@ -4,8 +4,7 @@ plane: no per-step gradient barrier.  Backward hooks launch one async
 push_pull per parameter (front layers highest priority) and the NEXT
 forward's module pre-hooks block only on that module's own parameters,
 so step N+1's front layers compute while step N's back-layer gradients
-are still on the wire (OSDI'20 §5; measured end-to-end in
-OVERLAP_r05.json).
+are still on the wire (OSDI'20 §5).
 
 Single process (PS hop = identity):
 

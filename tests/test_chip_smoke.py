@@ -2,7 +2,8 @@
 
 ``chip_smoke.py`` is the on-chip bring-up proof; here, on the CPU, only its
 control flow is checked (``--cpu-dry-run``) and that without the flag — and
-likewise ``bench.py`` — it refuses to produce a result when there is no TPU.
+likewise ``benchmark/run.py`` without ``--rehearse`` — it refuses to produce a
+result when there is no TPU.
 Also: where ``bps.init()`` places the compile cache, and that the PS roles
 never initialise a JAX backend (they must not take the chip from the worker).
 """
@@ -11,7 +12,6 @@ import json
 import os
 import subprocess
 import sys
-import types
 
 import pytest
 
@@ -50,44 +50,15 @@ class TestChipSmoke:
         assert '"ok"' not in out.stdout
 
 
-@pytest.fixture
-def bench(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-
-    return bench
-
-
-class TestBenchRefusesToHideTheDevice:
+class TestBenchmarkRefusesToHideTheDevice:
     def test_cpu_is_a_nonzero_exit_with_no_result(self):
-        out = _run(["bench.py"])
+        """``benchmark/run.py`` is the yardstick: pinned to the CPU without
+        ``--rehearse`` it refuses before it builds or starts anything."""
+        out = _run(["benchmark/run.py", "--workload", "vgg16_local",
+                    "--seed", "1", "--seconds", "1"])
         assert out.returncode != 0
-        assert "cpu" in out.stderr
+        assert "cpu" in out.stderr and "TPU" in out.stderr
         assert out.stdout.strip() == ""
-
-    def test_unknown_device_kind_is_an_error(self, bench, monkeypatch):
-        import jax
-
-        fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v0 unheard-of")
-        monkeypatch.setattr(jax, "devices", lambda *a: [fake])
-        with pytest.raises(SystemExit, match="no peak figures"):
-            bench._require_tpu()
-
-    def test_only_out_of_memory_moves_to_the_next_candidate(self, bench):
-        def time_fn(batch):
-            if batch == 64:
-                raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
-            return batch * 2
-
-        assert bench._first_that_fits((64, 32), time_fn) == (32, 64)
-
-        def broken(batch):
-            raise ValueError("shape mismatch")
-
-        with pytest.raises(ValueError, match="shape mismatch"):
-            bench._first_that_fits((64, 32), broken)
-        with pytest.raises(RuntimeError, match="every candidate"):
-            bench._first_that_fits((64,), time_fn)
 
 
 class TestCompileCachePlacement:

@@ -256,7 +256,7 @@ class TestMultiWorkerRejoinIdentity:
 
 class TestElasticWorldSizeChange:
     def test_scale_down_then_up(self):
-        """2→1→2 workers across resume with a LIVE scheduler (VERDICT #5):
+        """2→1→2 workers across resume with a LIVE scheduler:
         stable keys, scheduler address book actually changes, servers adopt
         the new worker count, and traffic continues at every size."""
         import os
@@ -383,7 +383,7 @@ class TestElasticWorldSizeChange:
 
 class TestElasticServerResize:
     def test_server_scale_up_then_down(self):
-        """1→2→1 SERVERS across resume (round-2 VERDICT #6; the reference's
+        """1→2→1 SERVERS across resume (the reference's
         resume(num_servers) rewrites DMLC_NUM_SERVER,
         common/__init__.py:75-82): the resuming worker's register parks
         until the new server joins, a LIVE worker adopts the resize from a

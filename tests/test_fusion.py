@@ -1,4 +1,4 @@
-"""Small-tensor fusion: multi-key RPC coalescing (docs/perf.md).
+"""Small-tensor fusion: multi-key RPC coalescing (docs/fusion.md).
 
 Layers under test:
 
@@ -70,6 +70,27 @@ class TestFusedWire:
         body = encode_fused_push([(1, 0, 1, b"payload")])
         with pytest.raises(ValueError, match="truncated"):
             decode_fused_push(body[:-3])
+
+
+class TestStageLists:
+    """``engine._stages`` decides a partition's pipeline; these are the four
+    sequences the engine's five lists held (a device codec takes the
+    compressed ones)."""
+
+    Q = QueueType
+
+    @pytest.mark.parametrize("compressed,fused,want", [
+        (False, False, [Q.COPYD2H, Q.PUSH, Q.PULL, Q.COPYH2D]),
+        (True, False, [Q.COPYD2H, Q.COMPRESS, Q.PUSH, Q.PULL, Q.DECOMPRESS, Q.COPYH2D]),
+        (False, True, [Q.COPYD2H, Q.FUSE, Q.PULL, Q.COPYH2D]),
+        (True, True, [Q.COPYD2H, Q.COMPRESS, Q.FUSE, Q.PULL, Q.DECOMPRESS, Q.COPYH2D]),
+    ], ids=["raw", "compressed", "fused", "compressed_fused"])
+    def test_sequence(self, compressed, fused, want):
+        from byteps_tpu.core.engine import _stages
+
+        got = _stages(compressed, fused)
+        assert got == want
+        assert _stages(compressed, fused) is not got  # a task pops from its own list
 
 
 class TestFusionScheduling:

@@ -1,4 +1,4 @@
-"""Full-topology composition (VERDICT r4 #5): shard_map mesh training
+"""Full-topology composition: shard_map mesh training
 whose cross-host gradient hop rides the real PS plane, in ONE loop.
 
 Two worker subprocesses, each with a 4-device virtual CPU mesh

@@ -1,0 +1,177 @@
+"""The pieces the latent-attention MoE family brought, each against its own
+reference on the CPU: the held-expert layer (parallel/moe.py), the routing
+counters, and the flash kernel at d_qk != d_v (ops/flash_attention.py,
+Pallas interpreter).  (The model against its reference: tests/test_latent_moe.py.)
+"""
+
+import importlib
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import byteps_tpu as bps
+from byteps_tpu.models import latent_moe as lm
+from byteps_tpu.models import latent_moe_reference as ref
+from byteps_tpu.models import transformer as tfm
+from byteps_tpu.parallel import moe
+
+from test_latent_moe import _mesh, _state, _worst
+
+fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+
+
+# ---------------------------------------------------------------------------
+# the held-expert layer
+# ---------------------------------------------------------------------------
+
+
+def _layer_params(cfg, seed=3):
+    params = lm.init_params(cfg, jax.random.PRNGKey(seed))
+    lp = {k.split(".", 1)[1]: v[0] for k, v in params.items() if k.startswith("moe.")}
+    lp["router_bias"] = 0.2 * jax.random.normal(jax.random.PRNGKey(seed + 1),
+                                                lp["router_bias"].shape)
+    return lp
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """32 experts in 4 shares of 8: the shares' routed parts, and the shared
+    expert counted once, give what the reference gives with all 32."""
+    whole = lm.tiny_latent_moe(n_experts=32, experts_held=32, top_k=4)
+    lp = _layer_params(whole)
+    g = jax.random.normal(jax.random.PRNGKey(9), (48, whole.d_model))
+    want = ref.expert_mlp(whole, g, lp)
+    shared = lm._swiglu(g, lp["s_gate"], lp["s_up"], lp["s_down"])
+    total, held = shared, 0
+    for lo in range(0, 32, 8):
+        share = lm.tiny_latent_moe(n_experts=32, experts_held=8, expert_lo=lo, top_k=4)
+        lp_share = {**lp, **{w: lp[w][lo:lo + 8] for w in ("e_gate", "e_up", "e_down")}}
+        y, stats = lm.expert_mlp(share, g, lp_share)
+        total = total + (y - shared)  # this share's routed part alone
+        held += int(stats[1])
+        # and each share is what the reference gives for that share
+        np.testing.assert_allclose(y, ref.expert_mlp(share, g, lp_share), atol=1e-5)
+    assert held == 48 * 4  # every slot is held by exactly one share
+    np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("favoured, n_experts", [((4,), 8), ((4, 5), 8), ((4, 5), 32)])
+def test_no_slot_is_dropped_under_skew(favoured, n_experts):
+    """A selection bias that sends every token to the held experts: far more
+    slots than the usual chunk holds (2 chunks of 64 rows at 8 experts, 8 of
+    16 at 32: the second chunk, then the scanned rest), none dropped,
+    output = reference."""
+    cfg = lm.tiny_latent_moe(n_experts=n_experts, experts_held=2, expert_lo=4)
+    lp = _layer_params(cfg)
+    lp["router_bias"] = jnp.zeros(n_experts).at[jnp.asarray(favoured)].set(10.0)
+    tokens = 64
+    g = jax.random.normal(jax.random.PRNGKey(2), (tokens, cfg.d_model))
+    y, stats = jax.jit(lambda g, lp: lm.expert_mlp(cfg, g, lp))(g, lp)
+    routed, held, dropped, fullest = (int(v) for v in stats)
+    assert routed == tokens * cfg.top_k
+    assert held >= tokens * len(favoured)
+    if len(favoured) == 2:  # every slot is held: every chunk of the usual size runs
+        assert held == routed and routed % (2 * routed * 2 // n_experts) == 0
+    assert dropped == 0
+    assert fullest == tokens  # a token picks an expert at most once
+    np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
+    # and the gradient flows through every chunk
+    got = jax.grad(lambda lp: jnp.sum(lm.expert_mlp(cfg, g, lp)[0] ** 2))(lp)
+    want = jax.grad(lambda lp: jnp.sum(ref.expert_mlp(cfg, g, lp) ** 2))(lp)
+    off, leaf = _worst({k: got[k] for k in ("e_gate", "e_down", "router")}, want)
+    assert off < 1e-4, f"{leaf}: {off:.2e}"
+
+
+def test_bias_picks_and_does_not_weigh():
+    g = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 6))
+    bias = jnp.zeros(6).at[5].set(100.0)
+    ids, weights = moe.sigmoid_topk_route(g, w, bias, top_k=2, scale=2.5)
+    assert np.all(np.any(np.asarray(ids) == 5, axis=1))  # the bias picks
+    np.testing.assert_allclose(jnp.sum(weights, axis=1), 2.5, rtol=1e-6)  # and is not in the weights
+    scores = jax.nn.sigmoid(g @ w)
+    chosen = jnp.take_along_axis(scores, ids, axis=1)
+    np.testing.assert_allclose(weights, 2.5 * chosen / chosen.sum(1, keepdims=True), rtol=1e-5)
+
+
+def test_routing_counters_reach_the_programs_counters():
+    names = moe.ROUTING_STATS
+    before = bps.get_robustness_counters()
+    cfg = lm.tiny_latent_moe(experts_held=2, expert_lo=0)
+    params, tokens, targets = _state(cfg, bias=0.0)
+    tx = optax.sgd(0.1)
+    step = tfm.build_train_step(cfg, _mesh(), tx, donate=False)
+    for _ in range(2):
+        step(params, tx.init(params), tokens, targets)
+    after = bps.get_robustness_counters()
+    grown = {n: after.get(n, 0) - before.get(n, 0) for n in names}
+    layers = cfg.n_expert_layers + cfg.mtp_modules
+    assert grown["moe_slots_routed"] == 2 * layers * tokens.size * cfg.top_k
+    assert 0 < grown["moe_slots_held"] < grown["moe_slots_routed"]
+    assert grown["moe_slots_dropped"] == 0
+    assert grown["moe_slots_held"] / 2 <= grown["moe_fullest_expert_slots"] <= grown["moe_slots_held"]
+
+
+def test_routing_counters_fold_only_what_is_ready():
+    class Pending:
+        def is_ready(self):
+            return False
+
+    sink = moe.RoutingCounters.__new__(moe.RoutingCounters)
+    sink._lock, sink._pending = threading.Lock(), []
+    sink._totals = dict.fromkeys(moe.ROUTING_STATS, 0)
+    sink.push(dict(zip(moe.ROUTING_STATS, jnp.asarray([8, 4, 0, 3], jnp.int32))))
+    sink.push({"moe_slots_held": Pending()})  # a step still running: push must not wait for it
+    sink.push({})  # a family that counts nothing
+    assert sink._totals["moe_slots_held"] == 4 and len(sink._pending) == 1
+
+
+# ---------------------------------------------------------------------------
+# the flash kernel at d_qk != d_v (Pallas interpreter)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128)])
+def test_flash_kernel_with_two_head_sizes(causal, blocks):
+    b, h, s, d_qk, d_v = 1, 2, 256, 192, 128
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k = (jax.random.normal(kk, (b, h, s, d_qk)) for kk in keys[:2])
+    v, ct = (jax.random.normal(kk, (b, h, s, d_v)) for kk in keys[2:])
+    scale = d_qk ** -0.5
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, block_q=blocks[0],
+                                  block_k=blocks[1], interpret=True)
+
+    def dense(q, k, v):
+        return fa._dense_reference(q, k, v, causal, scale)
+
+    out = flash(q, k, v)
+    assert out.shape == (b, h, s, d_v)
+    np.testing.assert_allclose(out, dense(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * ct), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * ct), argnums=(0, 1, 2))(q, k, v)
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=5e-5, err_msg=f"d{name}")
+
+
+def test_flash_kernel_bf16_operands_stay_close_to_f32():
+    b, h, s = 1, 1, 256
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q, k = (jax.random.normal(kk, (b, h, s, 192)).astype(jnp.bfloat16) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (b, h, s, 128)).astype(jnp.bfloat16)
+    out = fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
+    want = fa._dense_reference(*(x.astype(jnp.float32) for x in (q, k, v)), True, 192 ** -0.5)
+    assert out.dtype == jnp.bfloat16
+    assert float(jnp.abs(out.astype(jnp.float32) - want).max()) < 2e-2 * float(jnp.abs(want).max())
+
+
+def test_committed_block_table_serves_the_cells_sequence():
+    assert fa.tuned_blocks(8192) != (128, 128), "ops/flash_blocks.json lacks the 8192 sweep"
+    bq, bk = fa.tuned_blocks(8192)
+    assert 8192 % bq == 0 and 8192 % bk == 0

@@ -1,5 +1,5 @@
 """Conv model zoo tests: ResNet/VGG train data-parallel on the CPU mesh
-(the reference's ResNet-50/VGG-16 benchmark models, docs/performance.md)."""
+(the reference's ResNet-50/VGG-16 benchmark models, BASELINE.md)."""
 
 import jax
 import jax.numpy as jnp

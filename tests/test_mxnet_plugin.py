@@ -253,7 +253,7 @@ print(f"MX_COMPRESSED_{r}_OK")
 
 
 class TestMxnetPluginExecution:
-    """EXECUTE the mxnet plugin (round-2 VERDICT #4): 2 worker
+    """EXECUTE the mxnet plugin: 2 worker
     subprocesses with the faithful tests/mxnet_shim on PYTHONPATH run
     DistributedTrainer (sync sum), broadcast_parameters,
     DistributedOptimizer, and (fresh cluster — keys are index-based) the

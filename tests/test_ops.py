@@ -193,7 +193,7 @@ class TestFlashLse:
 
 
 class TestDeviceCodecs:
-    """On-device topk/dithering (round-2 VERDICT #8): wire parity with the
+    """On-device topk/dithering: wire parity with the
     host codecs and the D2H byte reduction that motivates them."""
 
     def test_topk_payload_bit_matches_host_codec(self):

@@ -33,7 +33,7 @@ def fake_cluster(request, monkeypatch):
     Parametrized over the full engine × transport matrix: the Python and
     C++ engines each behind the tcp, uds, and shm vans — every PS test
     runs against every combination (the native-shm column is the no-GIL
-    engine composed with the zero-copy transport, VERDICT r3 #3)."""
+    engine composed with the zero-copy transport)."""
     engine, _, van = request.param.partition("-")
     if engine == "native":
         from byteps_tpu.native import HAVE_NATIVE, get_lib

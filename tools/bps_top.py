@@ -203,7 +203,7 @@ def render(url: str, cur: Sample, prev: Sample, dt: float,
             lines.append(cell)
     # reducer backlog of the key-striped native engine, one cell per
     # stripe — a persistently deep cell while its siblings sit at 0 is
-    # the hot-stripe signature (docs/perf.md).  Sorted numerically (s2
+    # the hot-stripe signature (docs/fusion.md).  Sorted numerically (s2
     # before s10); the series also carry a `server` instance label, so
     # cells are prefixed with it when more than one server shares the
     # endpoint (scaling_bench threads mode).

@@ -119,7 +119,8 @@ def main() -> int:
             )
     if winners and not args.no_write:
         # persist so the kernels' tuned_blocks() table picks the winners
-        # up on the next run (bench.py reruns follow in the chip watcher)
+        # up on the next run (then `python benchmark/run.py --workload
+        # joyai_flash_ep32_train8k`, the cell that runs these kernels)
         import importlib
         import json
 

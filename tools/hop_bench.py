@@ -1,0 +1,200 @@
+#!/usr/bin/env python3
+"""Host-path probe: what one partition costs on the PS hop's wire, alone.
+
+For sizing a van, journal or server change ON THE CHIP'S HOST, through
+``chiprun -- python tools/hop_bench.py --mode ...``, BEFORE predicting what it
+buys a PS cell (ROADMAP's first rule: ISSUE 30 sized its change in a CPU
+sandbox and predicted six times the gain the chip's host gave).  Its numbers
+are host-plane (Python, memcpy, loopback TCP between two processes), never a
+device metric.  One JSON line on stdout: median, p10, p90 ms a frame over
+``REPS`` timed passes after a warm-up pass.  No file, no jax backend.
+
+``--mode frame``: ``--frames`` frames of ``--bytes`` one way through this
+tree's ``send_message`` / ``recv_message``, each acked by a bare header, with
+and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
+``ndarray``, as ``np.asarray(slice)`` is on the TPU: the journal keeps a reference.
+``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
+connection from a child shaped like the server: a serve thread that receives,
+an engine thread that copies into the store, acks, ``tobytes()``es and replies.
+"""
+
+import argparse
+import contextlib
+import json
+import multiprocessing
+import os
+import queue
+import socket
+import sys
+import threading
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from byteps_tpu.comm.journal import RoundJournal  # noqa: E402
+from byteps_tpu.comm.transport import (  # noqa: E402
+    Message, Op, connect, listen, recv_header_ex, recv_into, recv_message,
+    send_message,
+)
+from byteps_tpu.core.telemetry import counters  # noqa: E402
+
+REPS = 5  # timed passes; one more runs first, unwarmed and uncounted
+POOL = 40  # distinct payload buffers, so no frame is sent from a warm cache line
+
+
+def _child(port_out, engine_thread: bool) -> None:
+    """The server's side: a PUSH is kept (with ``engine_thread`` copied into the
+    store, as a sum is) and acked, a PULL answered with the store's ``tobytes()``;
+    with ``engine_thread`` the serve thread only receives, a second does the rest."""
+    srv, port = listen("127.0.0.1", 0)
+    port_out.send(port)
+    conn, _ = srv.accept()
+    conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+    inbox, lock, store = queue.Queue(), threading.Lock(), {}
+    def handle(msg):
+        reply = Message(msg.op, key=msg.key, seq=msg.seq)
+        if msg.op == Op.PULL:
+            reply.payload = store[msg.key].tobytes()
+        elif engine_thread:
+            if msg.key not in store:
+                store[msg.key] = np.empty(len(msg.payload), np.uint8)
+            store[msg.key][:] = np.frombuffer(msg.payload, np.uint8)
+        else:
+            store[0] = msg.payload  # the previous frame dies here, as a store's does
+        send_message(conn, reply, lock)
+
+    def engine():
+        while True:
+            handle(inbox.get())
+
+    if engine_thread:
+        threading.Thread(target=engine, daemon=True).start()
+    while True:
+        try:
+            (inbox.put if engine_thread else handle)(recv_message(conn))
+        except ConnectionError:
+            return
+
+
+@contextlib.contextmanager
+def _connected(engine_thread: bool):
+    """Spawn the child; yield a socket dialled, as a worker's is, to its port."""
+    ctx = multiprocessing.get_context("spawn")
+    port_in, port_out = ctx.Pipe(duplex=False)
+    proc = ctx.Process(target=_child, args=(port_out, engine_thread), daemon=True)
+    proc.start()
+    try:
+        if not port_in.poll(120):
+            raise SystemExit("hop_bench: the child never listened")
+        with connect("127.0.0.1", port_in.recv()) as sock:
+            yield sock
+    finally:
+        proc.kill()
+
+
+def _payloads(nbytes: int) -> list:
+    pool = [np.full(nbytes, i, np.uint8) for i in range(POOL)]
+    for a in pool:
+        a.flags.writeable = False  # owned and read-only: a device-to-host copy
+    return [a.data for a in pool]
+
+
+def _on_each_header(sock, handle) -> None:
+    """A daemon thread calls ``handle(op, key)`` after every header it reads."""
+    def loop():
+        try:
+            while True:
+                op, _, _, _, key, *_ = recv_header_ex(sock)
+                handle(op, key)
+        except (ConnectionError, OSError):
+            return  # the bench closed the socket
+    threading.Thread(target=loop, daemon=True).start()
+
+
+def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
+    """``REPS`` + 1 passes of ``send_one(i, version)``, each waited out on ``done``."""
+    ms = []
+    for rep in range(REPS + 1):
+        t0 = time.perf_counter()
+        for i in range(frames):
+            send_one(i, rep + 1)
+        for _ in range(frames):
+            done.acquire()
+        ms.append((time.perf_counter() - t0) / frames * 1e3)
+    p10, median, p90 = np.percentile(ms[1:], [10, 50, 90])  # the first pass is warm-up
+    return {"median_ms": float(median), "p10_ms": float(p10), "p90_ms": float(p90)}
+
+
+def _frame_passes(pool: list, frames: int, journal) -> dict:
+    acks = threading.Semaphore(0)
+    with _connected(engine_thread=False) as sock:
+        def push(i, version):
+            if journal is not None:
+                journal.record(i, version, 0, pool[i % POOL])
+            send_message(sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]))
+
+        _on_each_header(sock, lambda op, key: acks.release())
+        return _passes(frames, push, acks)
+
+
+def frame(frames: int, nbytes: int) -> dict:
+    pool = _payloads(nbytes)
+    before = counters().snapshot()
+    out = {"journal_on": _frame_passes(pool, frames, RoundJournal(2, 64 << 20)),
+           "journal_off": _frame_passes(pool, frames, None)}
+    after = counters().snapshot()
+    for name in ("journal_ref_bytes", "journal_copy_bytes"):
+        out[name] = after.get(name, 0) - before.get(name, 0)
+    return out
+
+
+def echo(frames: int, nbytes: int) -> dict:
+    pool = _payloads(nbytes)
+    lock, journal = threading.Lock(), RoundJournal(2, 64 << 20)
+    result = np.empty(frames * nbytes, np.uint8)
+    to_pull, landed = queue.Queue(), threading.Semaphore(0)
+    with _connected(engine_thread=True) as sock:
+        def on_header(op, key):
+            if op == Op.PUSH:  # the ack: the puller asks for the partition back
+                to_pull.put(key)
+            else:
+                recv_into(sock, memoryview(result[key * nbytes:(key + 1) * nbytes]))
+                landed.release()
+
+        def puller():
+            while True:
+                key = to_pull.get()
+                send_message(sock, Message(Op.PULL, key=key, seq=key), lock)
+
+        def push(i, version):
+            journal.record(i, version, 0, pool[i % POOL])
+            send_message(sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]), lock)
+
+        _on_each_header(sock, on_header)
+        threading.Thread(target=puller, daemon=True).start()
+        out = _passes(frames, push, landed)
+    if bytes(result[-nbytes:]) != bytes(pool[(frames - 1) % POOL]):
+        raise SystemExit("hop_bench: the last partition came back changed")
+    return out
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--mode", choices=("frame", "echo"), required=True)
+    ap.add_argument("--frames", type=int, default=None,
+                    help="frames a pass (default: 150 one way, 162 echoed — a vgg16 step)")
+    ap.add_argument("--bytes", type=int, default=4_096_000, help="bytes a frame")
+    args = ap.parse_args()
+    frames = {"frame": 150, "echo": 162}[args.mode] if args.frames is None else args.frames
+    if frames < 1 or args.bytes < 1:
+        ap.error("--frames and --bytes are positive")
+    reading = {"frame": frame, "echo": echo}[args.mode](frames, args.bytes)
+    print(json.dumps({"mode": args.mode, "frames": frames, "bytes": args.bytes,
+                      "timed_passes": REPS, "host_cores": os.cpu_count(),
+                      "plane": "host", **reading}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -30,8 +30,7 @@ Usage:
 and attributes where the time of one training step went — engine-queue
 wait vs wire vs sum vs publish vs reply, split per engine (``python`` /
 ``native``; native server children are tagged ``engine: "native"`` by
-the drain) — the baseline artifact the multi-core key-striping work is
-judged against (TRACE_ATTRIB_r06.json).  Reducer-lane spans (the drain
+the drain).  Reducer-lane spans (the drain
 puts each stripe on its own ``stripe<N>`` Perfetto track) additionally
 get a per-stripe **occupancy** split — stripe identity comes from the
 span's ``stripe`` arg or, failing that, its ``stripe<N>`` tid — and the
@@ -395,7 +394,7 @@ def _print_attribution(attrib: dict) -> None:
                 f"({hot['sum_seconds'] * 1e3:.3f} ms vs sibling median "
                 f"{hot['sibling_median'] * 1e3:.3f} ms) — the flight "
                 "recorder's hot_stripe rule fires on this trace; see "
-                "docs/perf.md (BYTEPS_SERVER_STRIPES / key hash)"
+                "docs/fusion.md (BYTEPS_SERVER_STRIPES / key hash)"
             )
 
 
