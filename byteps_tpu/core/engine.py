@@ -63,6 +63,21 @@ def _assemble_program():
     return jax.jit(assemble_parts, static_argnums=1)
 
 
+@functools.cache
+def _split_program():
+    """``(flat, bounds) → partitions``: the mirror of ``_assemble_program``,
+    ONE compiled program per distinct partition layout where an eager
+    ``flat[lo:hi]`` a partition would dispatch one program each from the
+    caller's thread."""
+    import jax
+    from jax import lax
+
+    def split_parts(flat, bounds):
+        return [lax.slice(flat, (lo,), (hi,)) for lo, hi in bounds]
+
+    return jax.jit(split_parts, static_argnums=1)
+
+
 def _assemble(parts: list, shape: tuple):
     """A job's device partitions, in offset order, as one array of its
     submitted shape."""
@@ -78,15 +93,23 @@ class _Job:
         "name", "ctx", "flat", "result", "dtype_id", "average", "handle",
         "pending", "lock", "shape", "np_dtype", "is_jax", "version", "t0",
         "rowsparse", "device_codec", "device_parts", "failed", "trace_id",
-        "parent_span", "step_counted",
+        "parent_span", "step_counted", "d2h_parts",
     )
 
     def __init__(self, name, ctx, flat, result, dtype_id, average, handle,
                  pending, shape, np_dtype, is_jax, version, rowsparse=None,
-                 device_codec=False):
+                 device_codec=False, d2h_parts=None):
         self.name = name
         self.ctx = ctx
+        # the tensor as one flat array the COPYD2H thread slices: a numpy
+        # view, or the jax array of a device-codec or sharded job.  None
+        # where ``d2h_parts`` holds the partitions instead
         self.flat = flat
+        # raw jax jobs whose tensor one chip holds whole: offset → the
+        # partition as a single-device jax.Array whose copy to the host
+        # was started in submit (``_start_d2h``); COPYD2H pops each one,
+        # so the device slice dies when its task leaves the stage
+        self.d2h_parts = d2h_parts
         self.result = result
         self.dtype_id = dtype_id
         self.average = average
@@ -576,10 +599,13 @@ class PipelineEngine:
         drop every partition into the first stage queue.
 
         ``tensor`` may be a live jax Array: it is NOT materialized here —
-        shape/dtype metadata is enough to partition, and the actual
-        device→host transfer happens per partition on the COPYD2H stage
-        thread (the reference's async COPYD2H stream, core_loops.cc:378-443),
-        so the caller returns while the device is still computing.
+        shape/dtype metadata is enough to partition, so the caller returns
+        while the device is still computing.  Where one chip holds the
+        whole of it (single-device or fully replicated) and no device
+        codec packs it, every partition's device→host copy is STARTED
+        here, from that chip's copy (``_start_d2h``), and the COPYD2H
+        thread only collects; a sharded tensor and a device-codec job are
+        sliced on that thread, a partition at a time.
         """
         import jax
 
@@ -587,20 +613,20 @@ class PipelineEngine:
         ctx = registry.declare(name)
         is_jax = isinstance(tensor, jax.Array)
         if is_jax:
-            flat = tensor.reshape(-1)  # device-side metadata op, async
-            np_dtype = np.dtype(flat.dtype)
+            flat = None  # made below, once the job's path is known
+            np_dtype, n_elements = np.dtype(tensor.dtype), tensor.size
         else:
             flat = np.ascontiguousarray(np.asarray(tensor)).reshape(-1)
-            np_dtype = flat.dtype
+            np_dtype, n_elements = flat.dtype, flat.size
         dtype_id = int(to_datatype(np_dtype))
 
         def build_partitions(c):
-            partition_tensor(c, flat.size, np_dtype.itemsize, self.cfg.partition_bytes)
+            partition_tensor(c, n_elements, np_dtype.itemsize, self.cfg.partition_bytes)
 
         def on_first_init():
-            self._maybe_setup_compression(ctx, np_dtype, flat.size * np_dtype.itemsize)
+            self._maybe_setup_compression(ctx, np_dtype, n_elements * np_dtype.itemsize)
 
-        self._prepare_round(ctx, dtype_id, flat.size, build_partitions, on_first_init)
+        self._prepare_round(ctx, dtype_id, n_elements, build_partitions, on_first_init)
         # server-opt tensors pull UPDATED PARAMETERS, not gradient sums:
         # the worker-side divide must not fire (the declared rule folds
         # averaging server-side, same float op order)
@@ -615,12 +641,17 @@ class PipelineEngine:
             and bool(ctx.partitions)
             and all(p.key in self._device_codecs for p in ctx.partitions)
         )
-        result = None if on_device else np.empty(flat.shape, dtype=np_dtype)
+        result = None if on_device else np.empty(n_elements, dtype=np_dtype)
+        d2h_parts = None
+        if is_jax and not on_device and tensor.is_fully_replicated:
+            d2h_parts = self._start_d2h(tensor.addressable_data(0), ctx.partitions)
+        elif is_jax:
+            flat = tensor.reshape(-1)  # device-side metadata op, async
         job = _Job(
             name, ctx, flat, result, dtype_id, average, handle,
             pending=len(ctx.partitions), shape=np.shape(tensor),
             np_dtype=np_dtype, is_jax=is_jax, version=ctx.version,
-            device_codec=on_device,
+            device_codec=on_device, d2h_parts=d2h_parts,
         )
         # small-tensor fusion routing, per partition: uncompressed
         # partitions gauge their RAW size against the threshold;
@@ -667,6 +698,26 @@ class PipelineEngine:
             )
             self._stamp_task_trace(task, job)
             self.queues[QueueType.COPYD2H].add_task(task)
+
+    @staticmethod
+    def _start_d2h(leaf, partitions) -> dict:
+        """Every partition of ``leaf`` (a single-device jax.Array, possibly
+        still being computed) on its way to the host: offset → a device
+        array whose ``copy_to_host_async`` is issued, in partition order.
+        The split and the copies queue on the device behind whatever still
+        computes ``leaf``, then stream back to back with no host round
+        trip between partitions; each host buffer is one partition and
+        owns its memory, as ``np.asarray(slice)`` always was
+        (comm/journal.py keeps such a buffer by reference).  A leaf of one
+        partition is copied as it is: no slice and no reshape program."""
+        if len(partitions) == 1:
+            parts = [leaf]
+        else:
+            bounds = tuple((p.offset, p.offset + p.length) for p in partitions)
+            parts = _split_program()(leaf.reshape(-1), bounds)
+        for part in parts:
+            part.copy_to_host_async()
+        return {p.offset: part for p, part in zip(partitions, parts)}
 
     def _prepare_round(self, ctx, dtype_id, n_elements, build_partitions,
                        on_first_init=None):
@@ -1404,13 +1455,16 @@ class PipelineEngine:
         return out.reshape(shape)
 
     def _copy_d2h_once(self, task: TensorTableEntry) -> None:
-        """Per-partition device→host DMA (COPYD2H, core_loops.cc:378-443).
+        """Per-partition device→host staging (COPYD2H, core_loops.cc:378-443).
 
-        For jax inputs this is where the transfer actually happens — on
-        THIS stage thread, one partition at a time, so the PUSH thread is
-        already sending early partitions over DCN while later partitions
-        are still coming off the device (and while the caller's next jitted
-        step runs).  numpy inputs take a zero-copy slice view.
+        A jax partition whose copy ``submit`` started (``job.d2h_parts``)
+        is only COLLECTED here: ``np.asarray`` waits, without the GIL, for
+        a transfer that is complete or in flight, and the device slice is
+        dropped.  The PUSH thread is already sending early partitions over
+        DCN while later ones are still coming off the device (and while
+        the caller's next jitted step runs).  A sharded jax tensor is
+        sliced and read here, a partition at a time; numpy inputs take a
+        zero-copy slice view.
 
         Device-codec jobs invert the reference's order (compress AFTER
         staging, core_loops.cc:498-536): the Pallas/jnp packer runs on the
@@ -1429,8 +1483,12 @@ class PipelineEngine:
             counters().bump("d2h_bytes", len(task.compressed))
             self._proceed(task)
             return
-        sl = job.flat[task.offset : task.offset + task.length]
-        task.cpubuff = sl if isinstance(sl, np.ndarray) else np.asarray(sl)
+        if job.d2h_parts is not None:
+            task.cpubuff = np.asarray(job.d2h_parts.pop(task.offset)).reshape(-1)
+            counters().bump("d2h_prefetched_parts")
+        else:
+            sl = job.flat[task.offset : task.offset + task.length]
+            task.cpubuff = sl if isinstance(sl, np.ndarray) else np.asarray(sl)
         if job.is_jax:
             counters().bump("d2h_bytes", task.cpubuff.nbytes)
         self._proceed(task)

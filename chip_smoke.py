@@ -11,7 +11,8 @@ the entry points a user calls, at the full width of the models the repo lists:
          classes, 138 M parameters): every step's f32 gradient crosses
          COPYD2H -> PUSH -> server sum -> PULL -> COPYH2D to a scheduler and a
          server started with the launcher's own command; byte counters must
-         equal steps x parameter bytes, and a sum of one comes back
+         equal steps x parameter bytes, every partition's device-to-host
+         copy must have been started at submit, and a sum of one comes back
          bit-identical.
   leg C  the Pallas kernels, compiled by Mosaic at the shapes the models use:
          flash attention forward + backward against the dense reference (at
@@ -86,7 +87,7 @@ def say(msg: str) -> None:
 class CompileStats:
     """Counts XLA compilations and persistent-cache traffic, per program
     name.  Listeners fire on whichever thread compiles (the engine's stage
-    threads build the per-partition slice programs), hence the lock."""
+    threads build programs too), hence the lock."""
 
     def __init__(self, jax) -> None:
         self._lock = threading.Lock()
@@ -126,11 +127,13 @@ class CompileStats:
                 f"the cache's floors), {s:.1f} s compiling")
 
     def slices_since(self, mark: tuple) -> str:
-        """The engine's per-partition ``job.flat[a:b]`` programs (COPYD2H)."""
+        """The engine's partition programs: ``split_parts`` (one a leaf's
+        layout, dispatched in submit) and the ``job.flat[a:b]`` slices of
+        sharded and device-codec jobs (on the COPYD2H thread)."""
         seen = mark[4]
         with self._lock:
             new = {k: v[seen.get(k, 0):] for k, v in self.by_name.items()
-                   if "slice" in k and len(v) > seen.get(k, 0)}
+                   if ("slice" in k or "split_parts" in k) and len(v) > seen.get(k, 0)}
         if not new:
             return "no slice programs compiled"
         count = sum(len(v) for v in new.values())
@@ -367,6 +370,20 @@ def leg_b(dry: bool) -> None:
         f"steps x parameter bytes = {STEPS_B * param_bytes}")
     if set(moved.values()) != {STEPS_B * param_bytes}:
         raise SystemExit(f"{label}: counters disagree with the gradient size")
+    # the gradient is whole on one chip (replicated at dp > 1): COPYD2H only
+    # collects copies that submit started, for every partition of every leaf
+    from byteps_tpu.common.partition import partition_elements
+    from byteps_tpu.core.state import get_state
+
+    part_bytes = get_state().config.partition_bytes
+    parts = sum(len(partition_elements(leaf.size, leaf.dtype.itemsize, part_bytes))
+                for leaf in leaves)
+    started = (after.get("d2h_prefetched_parts", 0)
+               - before.get("d2h_prefetched_parts", 0))
+    say(f"{label}: {started} device-to-host copies started at submit; "
+        f"steps x partitions = {STEPS_B * parts}")
+    if started != STEPS_B * parts:
+        raise SystemExit(f"{label}: COPYD2H read partitions nobody had started")
 
     # one worker: the server's sum of one must be the input, bit for bit
     leaf = hdp.params["Dense_2"]["kernel"]

@@ -4,6 +4,7 @@ core_loops.cc:378-443, 650-753 — SURVEY §7's 'riskiest performance item'),
 and ``push_pull_async`` must return without materializing the device
 tensor on the caller thread."""
 
+import sys
 import threading
 import time
 
@@ -68,6 +69,11 @@ class TestStagingOverlap:
         engine._proceed = rec_proceed
         engine.client.push = rec_push
         overlapped = False
+        # COPYD2H now only collects copies that submit started: on the CPU
+        # it never blocks, so it would run through all its tasks inside one
+        # 5 ms switch interval before the woken PUSH thread got the GIL
+        switch_interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)
         try:
             # A loaded box can starve the stage threads long enough that
             # one round drains every D2H before the first push fires —
@@ -76,19 +82,20 @@ class TestStagingOverlap:
             for _attempt in range(3):
                 with ev_lock:
                     events.clear()
-                x = jnp.arange(64 * 1024, dtype=jnp.float32)  # 64 partitions
+                x = jnp.arange(256 * 1024, dtype=jnp.float32)  # 256 partitions
                 out = bps.push_pull(x, name="overlap.x", average=False)
                 np.testing.assert_allclose(
-                    np.asarray(out), np.arange(64 * 1024, dtype=np.float32)
+                    np.asarray(out), np.arange(256 * 1024, dtype=np.float32)
                 )
                 with ev_lock:
                     d2h = [t for kind, _, t in events if kind == "d2h_done"]
                     push = [t for kind, _, t in events if kind == "push"]
-                assert len(d2h) == 64 and len(push) == 64
+                assert len(d2h) == 256 and len(push) == 256
                 if min(push) < max(d2h):
                     overlapped = True
                     break
         finally:
+            sys.setswitchinterval(switch_interval)
             engine._proceed = orig_proceed
             engine.client.push = orig_push
             bps.shutdown()
@@ -144,6 +151,82 @@ class TestStagingOverlap:
         out = bps.push_pull(x, name="overlap.np", average=False)
         np.testing.assert_allclose(np.asarray(out), x)
         bps.shutdown()
+
+
+def _placed(x: np.ndarray, placement: str):
+    """``x`` as the engine may be handed it: on one device, whole on every
+    device of the forced CPU mesh (a dp step's gradient), or cut over them
+    along its first axis (a tensor-parallel leaf)."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    if placement == "numpy":
+        return x
+    if placement == "single":
+        return jax.device_put(x, jax.devices()[0])
+    mesh = Mesh(np.array(jax.devices()), ("d",))
+    spec = PartitionSpec() if placement == "replicated" else PartitionSpec("d")
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
+class TestCopyStartsAtSubmit:
+    """COPYD2H is a wait: where one chip holds the whole tensor, ``submit``
+    starts every partition's copy from that chip (``_start_d2h``) and the
+    stage thread collects — counts and equality only, on the CPU."""
+
+    @pytest.mark.parametrize("shape", [(8, 64), (8, 375)], ids=["one_part", "three_parts"])
+    @pytest.mark.parametrize("placement", ["single", "replicated", "sharded", "numpy"])
+    def test_parts_copied_from_one_chip(self, small_partition_cluster, monkeypatch,
+                                        placement, shape):
+        import jax
+
+        import byteps_tpu as bps
+        from byteps_tpu.core.engine import PipelineEngine
+        from byteps_tpu.core.telemetry import counters
+
+        assert len(jax.devices()) > 1  # conftest's forced mesh
+        x = np.random.default_rng(32).standard_normal(shape).astype(np.float32)
+        n_parts = -(-x.nbytes // 4096)
+        assert n_parts == {(8, 64): 1, (8, 375): 3}[shape]
+        started = []  # (the dict handed to the job, its parts' shardings)
+        real = PipelineEngine._start_d2h
+
+        def spy(leaf, partitions):
+            parts = real(leaf, partitions)
+            started.append((parts, [part.sharding for part in parts.values()]))
+            return parts
+
+        monkeypatch.setattr(PipelineEngine, "_start_d2h", staticmethod(spy))
+        names = ("d2h_bytes", "d2h_prefetched_parts", "journal_ref_bytes",
+                 "journal_copy_bytes", "h2d_bytes")
+        bps.init()
+        try:
+            before = {k: counters().get(k) for k in names}
+            out = bps.push_pull(_placed(x, placement), average=True,
+                                name=f"submit_copy.{placement}.{n_parts}")
+            grew = {k: counters().get(k) - before[k] for k in names}
+        finally:
+            bps.shutdown()
+        # one worker: the sum is the tensor, and x / 1 == x bit for bit
+        assert np.shape(out) == shape
+        np.testing.assert_array_equal(np.asarray(out), x)
+        on_device = placement != "numpy"
+        prefetched = placement in ("single", "replicated")
+        assert grew == {
+            "d2h_bytes": x.nbytes if on_device else 0,
+            "d2h_prefetched_parts": n_parts if prefetched else 0,
+            # a jax partition's staging array is referenced, whichever way
+            # it reached the host; a numpy one may alias the caller's array
+            "journal_ref_bytes": x.nbytes if on_device else 0,
+            "journal_copy_bytes": 0 if on_device else x.nbytes,
+            "h2d_bytes": x.nbytes if on_device else 0,
+        }
+        assert len(started) == (1 if prefetched else 0)
+        for parts, shardings in started:
+            assert len(shardings) == n_parts
+            # no program ran on the mesh to read a tensor one chip holds
+            assert all(len(sh.device_set) == 1 for sh in shardings), shardings
+            assert parts == {}  # each device slice dropped as its task left COPYD2H
 
 
 class TestPushRoundOrdering:

@@ -33,14 +33,28 @@ def reading():
     return run
 
 
-@pytest.mark.parametrize("mode", ["frame", "echo"])
+@pytest.mark.parametrize("mode", ["frame", "echo", "d2h"])
 def test_one_json_line_with_positive_medians(reading, mode):
     got = reading(mode)
     assert (got["mode"], got["frames"], got["bytes"]) == (mode, FRAMES, NBYTES)
     assert got["plane"] == "host" and got["host_cores"] == os.cpu_count()
-    sides = [got["journal_on"], got["journal_off"]] if mode == "frame" else [got]
-    for side in sides:
+    sides = {"frame": ["journal_on", "journal_off"], "echo": [],
+             "d2h": ["one_at_a_time", "issued_first"]}[mode]
+    for side in [got[name] for name in sides] or [got]:
         assert 0 < side["p10_ms"] <= side["median_ms"] <= side["p90_ms"]
+
+
+def test_d2h_reads_one_array_both_ways_and_says_where(reading):
+    """Both loops read every slice back unchanged (the tool exits non-zero
+    otherwise); the line names the device, so a CPU reading cannot pass for
+    the chip's, and on a mesh it also reads a replicated array's slices."""
+    got = reading("d2h")
+    assert got["device"] == "cpu" and got["devices"] >= 1
+    assert ("one_at_a_time_replicated" in got) == (got["devices"] > 1)
+    first = got["issued_first"]
+    assert 0 < first["issue_ms"] <= first["p90_ms"]
+    for side in (got["one_at_a_time"], first):
+        assert side["gb_per_s"] == pytest.approx(NBYTES / side["median_ms"] / 1e6)
 
 
 def test_the_journal_keeps_references_to_read_only_frames(reading):

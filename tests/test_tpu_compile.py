@@ -91,3 +91,24 @@ def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cac
     assert "ragged-dot" in compiled.as_text()
     # one chunk of 8192 rows at a time: far under what all 131 072 slots would take
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_engine_split_compiles_for_the_largest_vgg16_leaf(one_chip, no_compile_cache):
+    """VGG-16's first dense kernel, 25 088 × 4096 f32 flat, into the 101
+    partitions ``engine.submit`` copies to the host: one program, 101
+    outputs of a partition each, and no temporary beside them (the extra
+    HBM of a step's prefetch is the gradient's own bytes, no more)."""
+    from byteps_tpu.common.partition import partition_elements
+    from byteps_tpu.core.engine import _split_program
+
+    n = 25088 * 4096
+    bounds = tuple((lo, lo + ln) for lo, ln in partition_elements(n, 4, 4_096_000))
+    assert len(bounds) == 101
+    flat = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = _split_program().trace(flat, bounds).lower(
+        lowering_platforms=("tpu",)).compile()
+    outs = compiled.out_info
+    assert [o.shape for o in outs] == [(hi - lo,) for lo, hi in bounds]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert n * 4 <= memory.output_size_in_bytes < n * 4 + 101 * 4096

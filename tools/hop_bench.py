@@ -7,7 +7,8 @@ buys a PS cell (ROADMAP's first rule: ISSUE 30 sized its change in a CPU
 sandbox and predicted six times the gain the chip's host gave).  Its numbers
 are host-plane (Python, memcpy, loopback TCP between two processes), never a
 device metric.  One JSON line on stdout: median, p10, p90 ms a frame over
-``REPS`` timed passes after a warm-up pass.  No file, no jax backend.
+``REPS`` timed passes after a warm-up pass.  No file; ``--mode d2h`` alone
+starts a jax backend (and so wants the chip to itself).
 
 ``--mode frame``: ``--frames`` frames of ``--bytes`` one way through this
 tree's ``send_message`` / ``recv_message``, each acked by a bare header, with
@@ -16,6 +17,11 @@ and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
 ``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
 connection from a child shaped like the server: a serve thread that receives,
 an engine thread that copies into the store, acks, ``tobytes()``es and replies.
+``--mode d2h``: ``--frames`` slices of ``--bytes`` off ONE array on the first
+device, onto the host two ways: sliced and read one at a time (COPYD2H's loop
+up to PR 31), and through ``PipelineEngine._start_d2h``, which ``engine.submit``
+calls since PR 32: one split program, then every partition's
+``copy_to_host_async`` issued before the first is read.
 """
 
 import argparse
@@ -113,6 +119,11 @@ def _on_each_header(sock, handle) -> None:
     threading.Thread(target=loop, daemon=True).start()
 
 
+def _summary(ms: list) -> dict:
+    p10, median, p90 = np.percentile(ms[1:], [10, 50, 90])  # the first pass is warm-up
+    return {"median_ms": float(median), "p10_ms": float(p10), "p90_ms": float(p90)}
+
+
 def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
     """``REPS`` + 1 passes of ``send_one(i, version)``, each waited out on ``done``."""
     ms = []
@@ -123,8 +134,7 @@ def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
         for _ in range(frames):
             done.acquire()
         ms.append((time.perf_counter() - t0) / frames * 1e3)
-    p10, median, p90 = np.percentile(ms[1:], [10, 50, 90])  # the first pass is warm-up
-    return {"median_ms": float(median), "p10_ms": float(p10), "p90_ms": float(p90)}
+    return _summary(ms)
 
 
 def _frame_passes(pool: list, frames: int, journal) -> dict:
@@ -180,17 +190,64 @@ def echo(frames: int, nbytes: int) -> dict:
     return out
 
 
+def d2h(frames: int, nbytes: int) -> dict:
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from byteps_tpu.common.types import Partition
+    from byteps_tpu.core.engine import PipelineEngine
+
+    n = max(1, nbytes // 4)
+    host = np.arange(frames * n, dtype=np.float32)
+    flat = jax.block_until_ready(jax.device_put(host, jax.devices()[0]))
+    partitions = [Partition(key=i, offset=i * n, length=n) for i in range(frames)]
+    issue_ms = []
+
+    def one_at_a_time(src):
+        return [np.asarray(src[i * n:(i + 1) * n]) for i in range(frames)]
+
+    def issued_first(src):
+        t0 = time.perf_counter()
+        parts = PipelineEngine._start_d2h(src, partitions)  # the engine's own
+        issue_ms.append((time.perf_counter() - t0) / frames * 1e3)
+        return [np.asarray(parts.pop(p.offset)) for p in partitions]
+
+    readings = [("one_at_a_time", one_at_a_time, flat), ("issued_first", issued_first, flat)]
+    if len(jax.devices()) > 1:
+        # the gradient of a dp > 1 step: whole on every chip, and every
+        # partial slice of it a gather program on all of them
+        everywhere = NamedSharding(Mesh(np.array(jax.devices()), ("dp",)), PartitionSpec())
+        readings.append(("one_at_a_time_replicated", one_at_a_time,
+                         jax.block_until_ready(jax.device_put(host, everywhere))))
+    out = {"device": jax.devices()[0].device_kind, "devices": len(jax.devices())}
+    for name, one_pass, src in readings:
+        ms, held = [], None
+        for _ in range(REPS + 1):
+            t0 = time.perf_counter()
+            # a pass's host buffers live until the next has its own, as the
+            # round journal keeps a step's: every pass lands in fresh memory
+            held = one_pass(src)
+            ms.append((time.perf_counter() - t0) / frames * 1e3)
+            if not np.array_equal(held[-1], host[-n:]):
+                raise SystemExit(f"hop_bench: {name} read the last slice changed")
+        del held
+        out[name] = _summary(ms)
+        out[name]["gb_per_s"] = n * 4 / out[name]["median_ms"] / 1e6
+    out["issued_first"]["issue_ms"] = float(np.median(issue_ms[1:]))
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--mode", choices=("frame", "echo"), required=True)
+    ap.add_argument("--mode", choices=("frame", "echo", "d2h"), required=True)
     ap.add_argument("--frames", type=int, default=None,
-                    help="frames a pass (default: 150 one way, 162 echoed — a vgg16 step)")
+                    help="frames a pass (default: 150 one way, 162 echoed or read — a vgg16 step)")
     ap.add_argument("--bytes", type=int, default=4_096_000, help="bytes a frame")
     args = ap.parse_args()
-    frames = {"frame": 150, "echo": 162}[args.mode] if args.frames is None else args.frames
+    frames = {"frame": 150, "echo": 162, "d2h": 162}[args.mode] if args.frames is None else args.frames
     if frames < 1 or args.bytes < 1:
         ap.error("--frames and --bytes are positive")
-    reading = {"frame": frame, "echo": echo}[args.mode](frames, args.bytes)
+    reading = {"frame": frame, "echo": echo, "d2h": d2h}[args.mode](frames, args.bytes)
     print(json.dumps({"mode": args.mode, "frames": frames, "bytes": args.bytes,
                       "timed_passes": REPS, "host_cores": os.cpu_count(),
                       "plane": "host", **reading}), flush=True)
