@@ -14,8 +14,13 @@ ran on device 0 and a span named ``match`` was open.
 ``idle_unattributed_share``: % of device 0's idle time that no ``bps.*``
 phase covers; the span of the whole step (``bps.hybrid.step``) is no phase:
 it would cover whatever its children leave unnamed.  ``scope_ms``: self time
-(a ``while`` charged for what its body leaves) of device 0's operations whose
-scope path files under ``match`` = ``forward``, ``backward`` or ``optimizer``.
+(a ``while`` charged for what its body leaves) of one device's operations
+whose scope path files under ``match`` = ``forward``, ``backward`` or
+``optimizer``: the largest over the devices.  Every chip of a data-parallel
+step runs the same programs, and the profiler loses events of a busy device,
+never invents them: on four chips device 0, which also runs the hop's ≈ 170
+small programs a step, kept 1 of 5 ``jit_hybrid_apply`` runs in a traced
+window where the three others kept all 5 (PERF.md §6, PR 33).
 
 A program without such spans or without the ``forward`` scope (the parent of
 the PR that brought them) gives None for each, and so does a run with no TPU
@@ -142,7 +147,8 @@ def classify(path: str) -> str | None:
 def _load(path: str, mtime: float) -> dict:
     """``{"spans": the bps.* events of every host thread as (name, start_s,
     end_s), "bench": the harness's, "ops": device 0's operations under their
-    whole names, "paths": scope_paths}``."""
+    whole names, "device_ops": every device's by ordinal, "paths":
+    scope_paths}``."""
     from jax.profiler import ProfileData
 
     xp = _xplane()
@@ -163,7 +169,7 @@ def _load(path: str, mtime: float) -> dict:
                 out["bench"] += [e for e in events if e[0].startswith("bench.")]
                 out["spans"] += [e for e in events if e[0].startswith("bps.")]
     if devices:
-        out["ops"] = devices[min(devices)]
+        out["ops"], out["device_ops"] = devices[min(devices)], devices
     return out
 
 
@@ -214,13 +220,17 @@ def measure(trace: dict, quantity: str, match: str = ""):
     if not steps:
         return None
     if quantity == "scope_ms":
-        filed = collections.Counter()
-        for name, own in _xplane().self_seconds(trace["ops"], lo, hi).items():
-            filed[classify(trace["paths"].get(name, ""))] += own
-        # a program from before the scopes has transpose( (jax's own) and no
-        # forward: it reads nothing.  One with scopes whose update XLA fused
-        # into the backward pass reads 0 under optimizer
-        return filed[match] / steps * 1e3 if filed["forward"] else None
+        found = []
+        for ops in trace.get("device_ops", {0: trace["ops"]}).values():
+            filed = collections.Counter()
+            for name, own in _xplane().self_seconds(ops, lo, hi).items():
+                filed[classify(trace["paths"].get(name, ""))] += own
+            # a program from before the scopes has transpose( (jax's own) and
+            # no forward: it reads nothing.  One with scopes whose update XLA
+            # fused into the backward pass reads 0 under optimizer
+            if filed["forward"]:
+                found.append(filed[match] / steps * 1e3)
+        return max(found, default=None)
     if quantity == "idle_unattributed_share":
         named = _covered(trace["spans"], lambda n: n != WHOLE_STEP, lo, hi)
         idle = _idle(trace["ops"], lo, hi)

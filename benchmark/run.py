@@ -6,8 +6,8 @@
 Everything about a cell is data that this file finds by name: the cell's entry
 in ``BENCHMARK.json`` names a configuration (``configs/<config>.json``, which
 names its builder ``builders/<builder>.py``) and a traffic mix
-(``traffic/<traffic>.json``: step path, mesh, child processes, warm-up, how
-much to trace); every per-layer metric is ``metrics/<metric>.json`` (a reader
+(``traffic/<traffic>.json``: step path, mesh, child processes, warm-up,
+collections, how much to trace); every per-layer metric is ``metrics/<metric>.json`` (a reader
 ``readers/<reader>.py`` and its arguments).  A new cell, configuration,
 traffic mix or metric over an existing reader is new files and new entries;
 nothing here is edited.
@@ -22,7 +22,9 @@ each timed to a ``block_until_ready`` of its loss and parameters, with a
 ``gc.collect()`` every so many steps where the traffic says so and the
 device's memory read between steps.  ``samples_per_s`` is the global batch
 times the steps completed over the time they took — all of them, no median,
-no trimming.  With ``--trace 1`` a few steps inside the window are
+no trimming.  Every run says the shape of its step times (median, 10th and
+90th percentile, the slowest step, the share of the window in steps over
+1.25 x the median).  With ``--trace 1`` a few steps inside the window are
 profiled and the per-layer metrics are printed instead of the end-to-end ones.
 
 The last line of stdout is one JSON object (correct, attempted, failed,
@@ -77,6 +79,7 @@ def load_module(kind: str, name: str):
 
 
 xplane = load_module(".", "xplane")  # benchmark/xplane.py: the trace reduction
+harness = load_module("readers", "harness")  # the step record's arithmetic
 
 
 def load_cell(workload: str, rehearse: bool) -> tuple:
@@ -242,12 +245,17 @@ def run_window(jax, step, seconds: float, plan: dict | None, collect_every: int)
     on; starting and stopping the profiler falls between steps and is taken
     out of the window's length.  At step boundaries, at most four times a
     second, the device's memory footprint is read; the largest reading is the
-    run's ``memory_peak_bytes``."""
+    run's ``memory_peak_bytes``.  Every step's wall seconds (dispatch to the
+    ``block_until_ready`` of its loss and parameters) and every collection's
+    are kept in order (``step_s``, ``collect_s``): with the memory readings
+    between them they add up to the window, and say whether a slow run had a
+    few stalled steps or every step slower."""
     from jax.profiler import ProfileOptions, TraceAnnotation, start_trace, stop_trace
 
     attempted = failed = traced = 0
     losses, overhead, tracing, t_traced = [], 0.0, False, 0.0
-    peak_bytes, t_read, collecting = 0, -1.0, 0.0
+    peak_bytes, t_read = 0, -1.0
+    step_s, collect_s = [], []  # each step's and each collection's wall seconds, in order
     t_open = time.perf_counter()
     while True:
         now = time.perf_counter()
@@ -263,7 +271,7 @@ def run_window(jax, step, seconds: float, plan: dict | None, collect_every: int)
             t_collect = time.perf_counter()
             with TraceAnnotation(xplane.COLLECT):
                 gc.collect()
-            collecting += time.perf_counter() - t_collect
+            collect_s.append(time.perf_counter() - t_collect)
         if plan and not tracing and attempted >= 2:
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
             options = ProfileOptions()
@@ -272,6 +280,7 @@ def run_window(jax, step, seconds: float, plan: dict | None, collect_every: int)
             tracing, t_traced = True, time.perf_counter()
             overhead += t_traced - now
         attempted += 1
+        t_step = time.perf_counter()
         try:
             with TraceAnnotation(xplane.CALL):
                 out = step()
@@ -281,6 +290,7 @@ def run_window(jax, step, seconds: float, plan: dict | None, collect_every: int)
             say(f"step {attempted} raised {type(e).__name__}: {e}")
             failed += 1
             break
+        step_s.append(time.perf_counter() - t_step)
         traced += tracing
         losses.append(loss)
         if not math.isfinite(loss):
@@ -290,7 +300,8 @@ def run_window(jax, step, seconds: float, plan: dict | None, collect_every: int)
     t_close = time.perf_counter()
     return {"attempted": attempted, "failed": failed, "losses": losses,
             "window_s": t_close - t_open - overhead, "traced": traced,
-            "peak_bytes": max(peak_bytes, footprint(jax)), "collecting_s": collecting}
+            "peak_bytes": max(peak_bytes, footprint(jax)),
+            "step_s": step_s, "collect_s": collect_s}
 
 
 # ---------------------------------------------------------------------------
@@ -323,6 +334,8 @@ def main() -> int:
         result = measure(args, bench, cell, config, traffic, roles, children)
     finally:
         stop_children(children)
+    for name, c in result["compared"].items():  # the last lines of stderr
+        say(f"compared {name} {c['value']} limit {c['limit']}{'' if c['ok'] else ' NOT CORRECT'}")
     print(json.dumps(result), flush=True)
     return 0
 
@@ -381,7 +394,10 @@ def measure(args, bench, cell, config, traffic, roles, children) -> dict:
     for _ in range(warm):
         out = jax.block_until_ready(step())
         warm_losses.append(float(out[0]))
-    off = max(abs(g - w) / abs(w) for g, w in zip(warm_losses, reference))
+    # the largest share by which a warm-up loss misses the reference's; one that
+    # is no number misses by everything (max would drop a NaN that is not first)
+    off = max(math.inf if math.isnan(x) else x
+              for x in (abs(g - w) / abs(w) for g, w in zip(warm_losses, reference)))
     # the system's parameters against the reference's after the same steps, leaf
     # by leaf, as a share of how far the reference moved that leaf: a lost,
     # halved, doubled or stale gradient shows here at its full size, however
@@ -413,43 +429,59 @@ def measure(args, bench, cell, config, traffic, roles, children) -> dict:
                       for d in jax.local_devices())
     say(f"set-up {setup_s:.2f} s ({n0} compilations, {compile_s:.1f} s compiling, "
         f"{hits} cache hits); window {window['window_s']:.3f} s, {steps} steps, "
-        f"{window_compiles} compilations inside it, {window['collecting_s']:.3f} s of it in "
-        f"gc.collect(); footprint {window['peak_bytes'] / 2**30:.3f} GiB; loss "
+        f"{window_compiles} compilations inside it, {sum(window['collect_s']):.3f} s of it in "
+        f"{len(window['collect_s'])} gc.collect(); footprint "
+        f"{window['peak_bytes'] / 2**30:.3f} GiB; loss "
         f"{warm_losses[0]:.6g} at the first warm-up step, {min(window['losses'], default=math.nan):.6g} "
         f"to {max(window['losses'], default=math.nan):.6g} in the window, "
         f"{(window['losses'] or [math.nan])[-1]:.6g} at its end")
 
+    record = harness.step_record(window["step_s"], window["window_s"])
+    if record:
+        say("steps {steps}: median {p50_ms:.1f} ms, p10 {p10_ms:.1f}, p90 {p90_ms:.1f}, slowest "
+            "{slowest_ms:.1f} at index {slowest_index}; {slow:.2f} % of the window in steps over "
+            "{x} x the median, {inside:.2f} % in steps, {coll:.2f} % collecting".format(
+                **record, slow=record["slow_share"] * 100, x=harness.SLOW,
+                inside=record["in_steps_share"] * 100,
+                coll=sum(window["collect_s"]) / window["window_s"] * 100))
+        say("step ms: " + " ".join(f"{t * 1e3:.0f}" for t in window["step_s"][:400]))
+
     # ---- correct: decided outside the window --------------------------------
-    faults = []
+    # every number compared, beside its limit: {name: [value, limit]}; a number
+    # passes at or under its limit (the loss has to end under its first value:
+    # the largest ratio under 1).  The tolerances and their reasons stand in
+    # the configuration's file.  A window without a whole step has failed
     losses = window["losses"]
-    if window["failed"] or not losses:
-        faults.append(f"{window['failed']} of {window['attempted']} steps failed")
-    elif not losses[-1] < warm_losses[0]:  # finite: no step failed
-        faults.append(f"loss {losses[-1]} after the window, {warm_losses[0]} at the start")
-    if window_compiles:
-        faults.append(f"{window_compiles} compilations inside the window")
-    # the tolerance and its reason stand in the configuration's file
-    for got, want in zip(warm_losses, reference):
-        if not abs(got - want) <= config["reference_rtol"]["value"] * abs(want):
-            faults.append(f"warm-up losses {warm_losses} against the reference's {reference}")
-            break
+    compared = {
+        "steps_failed": [window["failed"] if losses else max(window["failed"], 1), 0],
+        "loss_end_over_first": [losses[-1] / warm_losses[0] if losses else math.inf,
+                                math.nextafter(1.0, 0.0)],
+        "compiles_in_window": [window_compiles, 0],
+        "loss_off_reference": [off, config["reference_rtol"]["value"]],
+    }
     tol = config.get("reference_update_rtol")  # absent where the optimizer makes it powerless
-    if tol and not (whole <= tol["value"] and apart[worst] <= tol["leaf_value"]):
-        faults.append(f"parameters after {warm} steps apart from the reference's by {whole:.3e} "
-                      f"of its update over all leaves, {apart[worst]:.3e} at {worst}")
+    if tol:
+        compared["update_off_all_leaves"] = [whole, tol["value"]]
+        compared["update_off_worst_leaf"] = [apart[worst], tol["leaf_value"]]
+    notes = []
     if traffic["step_path"] == "ps":
         ran = len(losses)  # every step that ran to its end moved its bytes
-        if not args.rehearse and grad_bytes != config["grad_bytes_per_step"]:
-            faults.append(f"{grad_bytes} bytes of gradient, not {config['grad_bytes_per_step']}")
-        for name in ("d2h_bytes", "wire_tx_bytes", "wire_rx_bytes"):
+        if not args.rehearse:
+            compared["grad_bytes_off"] = [abs(grad_bytes - config["grad_bytes_per_step"]), 0]
+        for name in ("d2h_bytes", "wire_tx_bytes", "wire_rx_bytes", "h2d_bytes"):
             grown = after["counters"].get(name, 0) - before["counters"].get(name, 0)
-            if grown != ran * grad_bytes:
-                faults.append(f"{name} grew by {grown}, not {ran} x {grad_bytes}")
-        faults += children_faults(roles, children)
+            compared[f"{name}_off"] = [abs(grown - ran * grad_bytes), 0]
+        notes = children_faults(roles, children)
+        compared["children_faults"] = [len(notes), 0]
     if args.rehearse:
-        faults.append("a rehearsal on the CPU is never a result")
-    for fault in faults:
-        say(f"NOT CORRECT: {fault}")
+        notes.append("a rehearsal on the CPU is never a result")
+    faults = [name for name, (value, limit) in compared.items() if not value <= limit]
+    for note in notes:
+        say(f"NOT CORRECT: {note}")
+    if faults:  # the comparisons that failed are named again in the run's last lines
+        say(f"failed: {', '.join(faults)}; worst leaf {worst}; warm-up losses {warm_losses} "
+            f"against the reference's {reference}")
+    faults += notes
 
     # ---- metrics -------------------------------------------------------------
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": chips,
@@ -474,6 +506,7 @@ def measure(args, bench, cell, config, traffic, roles, children) -> dict:
             "counters": {"before": before["counters"], "after": after["counters"]},
             "histograms": {"before": before["histograms"], "after": after["histograms"]},
             "steps": steps, "window_s": window["window_s"], "global_batch": global_batch,
+            "step_s": window["step_s"],
             "flops_per_sample": builder.flops_per_sample(config), "chips": chips,
             "peak_flops_per_s": peaks.get(dev.device_kind, {}).get("bf16_flops_per_s"),
             "peak_hbm_bytes": window["peak_bytes"], "peak_in_use_bytes": peak_in_use,
@@ -493,6 +526,10 @@ def measure(args, bench, cell, config, traffic, roles, children) -> dict:
     else:
         result["metrics"] = found
     bps.shutdown()
+    # last in the line: what decided ``correct`` (a number json cannot hold, by its name)
+    result["compared"] = {name: {"value": v if math.isfinite(v) else repr(v), "limit": limit,
+                                 "ok": name not in faults}
+                          for name, (v, limit) in compared.items()}
     return result
 
 

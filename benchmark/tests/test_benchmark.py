@@ -87,6 +87,29 @@ def test_gaps_are_named_by_annotation_and_neighbouring_programs():
     assert sum(gaps.values()) == pytest.approx(2.0 - 0.6)
 
 
+def test_a_gap_is_named_by_the_innermost_program_phase_that_covers_it():
+    """The hand trace with the program's phases beside the harness's: the
+    caller waits in ``bps.hybrid.hop_wait`` from t+0.3 to t+0.7 inside
+    ``bps.hybrid.step``, a stage thread serves ``bps.stage.PUSH`` from t+0.4
+    to t+0.6.  The long gap (t+0.3 .. t+0.7, middle t+0.5) is PUSH's, the
+    innermost; a gap that no phase covers keeps the harness's name."""
+    trace = hand_trace()
+    for t in (10.0, 11.0):
+        trace["host"] += [("bps.hybrid.step", t, t + 0.9), ("bps.hybrid.hop_wait", t + 0.3, t + 0.7),
+                          ("bps.stage.PUSH", t + 0.4, t + 0.6)]
+    r = xplane.reduce(trace)
+    assert r["steps"] == 2 and r["window_s"] == pytest.approx(2.0)  # bps.* bound no window
+    gaps = dict(map(tuple, r["idle_gaps"]))
+    assert gaps["bps.stage.PUSH:jit_grad_-_jit_apply"] == pytest.approx(0.8)
+    assert gaps["bps.hybrid.step:window_start_-_jit_grad"] == pytest.approx(0.1)
+    # t+0.8 .. t+1.1, middle t+0.95: the program's step is over, the harness blocks
+    assert gaps["bench.step.block:jit_apply_-_jit_grad"] == pytest.approx(0.3)
+    assert sum(gaps.values()) == pytest.approx(2.0 - 0.6)
+    only_wait = {**trace, "host": [h for h in trace["host"] if h[0] != "bps.stage.PUSH"]}
+    gaps = dict(map(tuple, xplane.reduce(only_wait)["idle_gaps"]))
+    assert gaps["bps.hybrid.hop_wait:jit_grad_-_jit_apply"] == pytest.approx(0.8)
+
+
 def test_an_operation_is_named_by_its_name_and_opcode():
     line = ("%psum_invariant.259 = f32[25088,4096]{1,0:T(8,128)S(1)} all-reduce(f32[25088,4096]{1,0:T(8,128)} "
             "%fusion.3), channel_id=5, replica_groups={{0,1,2,3}}")
@@ -246,3 +269,82 @@ def test_histogram_mean_and_counter_delta():
     delta = load("readers", "counter_delta.py")
     assert delta.read(run, counters=["wire_tx_bytes", "wire_rx_bytes"], scale=0.5) == pytest.approx(100.0)
     assert delta.read(run, counters=["absent"]) is None
+    # a counter of what should not happen is absent until raised: 0 beside its sibling
+    assert delta.read(run, counters=["absent"], beside=["wire_tx_bytes"]) == 0.0
+    assert delta.read(run, counters=["absent"], beside=["absent_too"]) is None
+
+
+# ---- the step record -------------------------------------------------------------
+
+EVEN = [0.5] * 20
+STALLED = [0.5] * 19 + [2.0]
+
+
+@pytest.mark.parametrize("step_s, window_s, want", [
+    # an even run: every step at the median, nothing slow
+    (EVEN, 10.0, {"steps": 20, "p50_ms": 500.0, "p10_ms": 500.0, "p90_ms": 500.0,
+                  "slowest_ms": 500.0, "slowest_index": 0, "slow_share": 0.0, "in_steps_share": 1.0}),
+    # one stalled step: the median does not move, the stall is 2.0 of 11.5 s + 0.5 s of collecting
+    (STALLED, 12.0, {"steps": 20, "p50_ms": 500.0, "p10_ms": 500.0, "p90_ms": 500.0,
+                     "slowest_ms": 2000.0, "slowest_index": 19, "slow_share": 2.0 / 12.0,
+                     "in_steps_share": 11.5 / 12.0}),
+    # every step slower from the start: the median carries it, no step is slow against it
+    ([0.55] * 20, 11.0, {"steps": 20, "p50_ms": 550.0, "slow_share": 0.0}),
+    ([0.4], 0.4, {"steps": 1, "p50_ms": 400.0, "p10_ms": 400.0, "p90_ms": 400.0, "slow_share": 0.0}),
+])
+def test_step_record(step_s, window_s, want):
+    got = load("readers", "harness.py").step_record(step_s, window_s)
+    for key, value in want.items():
+        assert got[key] == pytest.approx(value), key
+
+
+def test_step_quantities_of_the_harness_reader():
+    reader = load("readers", "harness.py")
+    run = {"step_s": STALLED, "window_s": 12.0}
+    assert reader.read(run, quantity="step_ms_p50") == pytest.approx(500.0)
+    assert reader.read(run, quantity="slow_step_share") == pytest.approx(100 * 2.0 / 12.0)
+    assert reader.read({"step_s": EVEN, "window_s": 10.0}, quantity="slow_step_share") == 0.0
+    # an empty window has no step to read, and a run from before the record none either
+    assert reader.step_record([], 0.0) is None
+    assert reader.read({"step_s": [], "window_s": 0.0}, quantity="step_ms_p50") is None
+    assert reader.read({"window_s": 40.0}, quantity="slow_step_share") is None
+
+
+def test_the_windows_steps_and_collections_add_up_to_it():
+    """``run_window`` keeps every step's and every collection's seconds: with
+    the memory readings between them they are the window."""
+    import time
+
+    import jax
+    import numpy as np
+
+    run = load("run.py")
+
+    def step():
+        time.sleep(0.02)
+        return np.float32(1.0), None
+
+    w = run.run_window(jax, step, 0.5, None, 4)
+    assert len(w["step_s"]) == len(w["losses"]) == w["attempted"] and w["failed"] == 0
+    assert len(w["collect_s"]) == (w["attempted"] - 1) // 4
+    assert all(t >= 0.02 for t in w["step_s"])
+    inside = sum(w["step_s"]) + sum(w["collect_s"])
+    assert 0.9 * w["window_s"] <= inside <= w["window_s"]
+    assert w["window_s"] >= 0.5
+
+
+def test_per_layer_metrics_list_cells_that_report_what_they_move():
+    """A ``workloads`` list names cells that exist, once each, and each of them
+    reports the end-to-end metric that the metric moves.  Nothing here names a
+    cell or a layer: whether a listed cell gives the reader something to read
+    is what a traced run of that cell shows (a missing value is refused
+    there), so a later cell, or a metric of one cell alone, is new entries
+    and new files, and no edit of this test."""
+    cells_of = {w["name"] for w in BENCH["workloads"]}
+    end_to_end = {m["name"]: set(m.get("workloads", cells_of)) for m in BENCH["end_to_end"]}
+    for m in BENCH["per_layer"]:
+        cells = m.get("workloads")
+        if cells is None:
+            continue
+        assert cells and len(set(cells)) == len(cells) and set(cells) <= cells_of, m["name"]
+        assert set(cells) <= end_to_end[m["moves"]], m["name"]

@@ -1,7 +1,7 @@
 """The ``phases`` reader on hand-built traces (``python -m pytest
 benchmark/tests -q``): idle time by phase, spans over several threads, self
 time by scope, the scope paths out of an xplane file's wire format, and the
-CPU rehearsal of the four-chip cell."""
+CPU rehearsal of the two PS cells."""
 
 import importlib.util
 import json
@@ -92,6 +92,20 @@ def test_self_time_by_scope():
     assert phases.measure(before, "scope_ms", "backward") is None
 
 
+def test_scope_time_is_the_largest_over_the_devices():
+    """Four chips run the same step; device 0's trace lost the update of the
+    second step (the profiler drops events of a busy device), device 1's has
+    both: the reading is device 1's."""
+    trace = hand_trace()
+    lost = [op for op in trace["ops"] if not (op[0].startswith("%fusion.9") and op[1] > 11.0)]
+    trace = {**trace, "ops": lost, "device_ops": {0: lost, 1: trace["ops"]}}
+    assert phases.measure(trace, "scope_ms", "optimizer") == pytest.approx(100.0)
+    assert phases.measure(trace, "scope_ms", "forward") == pytest.approx(150.0)
+    assert phases.measure({**trace, "device_ops": {0: lost}}, "scope_ms", "optimizer") == pytest.approx(50.0)
+    # the idle quantities stay device 0's
+    assert phases.measure(trace, "idle_in_ms", "bps.hybrid.reput") == pytest.approx(100.0)
+
+
 @pytest.mark.parametrize("path, want", [
     ("jit(local_step)/shard_map/jvp(forward)/VGG16/Conv_0/conv_general_dilated:", "forward"),
     ("jit(step)/shard_map/transpose(jvp(forward))/while/body/dot_general:", "backward"),
@@ -162,27 +176,41 @@ def test_scope_paths_from_the_wire_format():
     assert phases.scope_paths(b"") == {}
 
 
-# ---- the four-chip cell, rehearsed on four virtual CPU devices --------------------
+# ---- the PS cells, rehearsed on virtual CPU devices --------------------------------
 
 
-def test_the_four_chip_cell_rehearses_on_four_virtual_devices():
+@pytest.mark.parametrize("cell, devices", [("vgg16_ps", 1), ("vgg16_ps_dp4", 4)])
+def test_a_ps_cell_rehearses_on_virtual_devices(cell, devices):
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
     for name in [k for k in env if k.startswith(("DMLC_", "BYTEPS_"))]:
         del env[name]  # a PS test before this one may have left its cluster's addresses
     done = subprocess.run(
-        [sys.executable, os.path.join(HERE, "run.py"), "--workload", "vgg16_ps_dp4", "--seed",
+        [sys.executable, os.path.join(HERE, "run.py"), "--workload", cell, "--seed",
          "2147483659", "--seconds", "2", "--trace", "1", "--rehearse"],
         env=env, cwd=ROOT, capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["correct"] is False and line["metrics"] == {}  # a rehearsal is never a result
-    assert line["device"]["count"] == 4 and line["failed"] == 0
+    assert line["device"]["count"] == devices and line["failed"] == 0
     faults = [ln for ln in done.stderr.splitlines() if "NOT CORRECT" in ln]
     assert len(faults) == 1 and "rehearsal" in faults[0], faults  # nothing else was wrong
+    # every number compared stands beside its limit, last in the line and last on stderr
+    assert list(line)[-1] == "compared" and all(c["ok"] for c in line["compared"].values())
+    assert {"h2d_bytes_off", "d2h_bytes_off", "wire_tx_bytes_off", "wire_rx_bytes_off"} <= set(line["compared"])
+    last = done.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert all("] compared " in ln and " limit " in ln for ln in last), last
+    assert sum("] steps " in ln and "median" in ln for ln in done.stderr.splitlines()) == 1
     got = line["rehearsal"]
     for name in ("two_level_step.hop_wait_ms", "two_level_step.reput_ms", "two_level_step.enqueue_ms",
                  "host_engine.copyd2h_wait_ms", "host_engine.copyh2d_wait_ms", "host_engine.finalize_ms",
-                 "ps_plane.push_pull_wait_ms", "ps_plane.rpc_round_trip_ms"):
+                 "ps_plane.push_pull_wait_ms", "ps_plane.rpc_round_trip_ms",
+                 "host_engine.copyd2h_dwell_ms", "host_engine.copyh2d_dwell_ms",
+                 "ps_plane.push_pull_dwell_ms", "ps_plane.wire_mb_per_step",
+                 "two_level_step.step_ms_p50", "host_engine.h2d_mb_per_step",
+                 "host_engine.prefetched_parts_per_step"):
         assert got[name]["value"] > 0, name
+    assert got["ps_plane.wire_mb_per_step"]["value"] == 2 * got["host_engine.h2d_mb_per_step"]["value"]
+    assert got["ps_plane.journal_copied_mb_per_step"]["value"] == 0
+    assert got["two_level_step.slow_step_share"]["value"] >= 0
     assert not [k for k in got if k.startswith(("train_step.forward", "mesh_collectives"))]  # no TPU plane

@@ -20,6 +20,9 @@ import os
 import re
 
 CALL, BLOCK, COLLECT = "bench.step.call", "bench.step.block", "bench.collect"
+#: host annotations kept: the harness's, and the program's phases (tracing.span)
+HOST_PREFIXES = ("bench.", "bps.")
+PHASE = "bps."
 #: lines of a device plane: operations, and the programs they belong to
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
@@ -27,7 +30,8 @@ OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 def load(trace_dir: str) -> dict:
     """``{"devices": {ordinal: {"ops": [...], "modules": [...]}}, "host": [...]}``
     with events as ``(name, start_s, end_s)``; host events are the harness's
-    ``bench.*`` annotations only."""
+    ``bench.*`` annotations and the program's ``bps.*`` phases, of every
+    thread."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True))
@@ -42,7 +46,7 @@ def load(trace_dir: str) -> dict:
                 slot = out["devices"].setdefault(int(dev.group(1)), {"ops": [], "modules": []})
                 slot[kind].extend(_events(line))
             elif plane.name.startswith("/host:"):
-                out["host"].extend(e for e in _events(line) if e[0].startswith("bench."))
+                out["host"].extend(e for e in _events(line) if e[0].startswith(HOST_PREFIXES))
     return out
 
 
@@ -108,20 +112,26 @@ def _label(text: str) -> str:
 
 def idle_gaps(busy, modules, host, lo: float, hi: float) -> dict:
     """Seconds of device idleness inside ``[lo, hi]`` by what surrounded it:
-    XX
-    after>``.  ``busy`` is ``union``'s output."""
+    ``<what the host was doing>:<program before>_-_<program after>``.  The
+    host is doing the innermost (shortest) of the program's ``bps.*`` phases
+    open on any thread at the gap's middle; where none is, the innermost of
+    the harness's ``bench.*`` annotations; else ``outside_step``.  ``busy``
+    is ``union``'s output."""
     modules = sorted(modules, key=lambda e: e[1])
     starts = [m[1] for m in modules]
-    ends = sorted((m[2], m[0]) for m in modules)
-    end_times = [e[0] for e in ends]
     edges = [lo] + [t for pair in busy for t in pair] + [hi]
+    host = sorted(host, key=lambda h: h[1])
+    nxt, open_now = 0, []  # gaps come in time order: one sweep over the host's events
     total = collections.defaultdict(float)
     for a, b in zip(edges[0::2], edges[1::2]):
         if b - a <= 0:
             continue
         mid = (a + b) / 2
-        inside = [h for h in host if h[1] <= mid < h[2]]
-        # the innermost annotation names what the host was doing
+        while nxt < len(host) and host[nxt][1] <= mid:
+            open_now.append(host[nxt])
+            nxt += 1
+        open_now = [h for h in open_now if mid < h[2]]
+        inside = [h for h in open_now if h[0].startswith(PHASE)] or open_now
         doing = min(inside, key=lambda h: h[2] - h[1])[0] if inside else "outside_step"
         i = bisect.bisect_right(starts, mid)  # programs run one after another
         before = _label(modules[i - 1][0]) if i else "window_start"
