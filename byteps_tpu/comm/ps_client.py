@@ -25,6 +25,7 @@ retried summation stays exactly-once (see server.py).
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import socket
@@ -45,8 +46,10 @@ from byteps_tpu.comm.transport import (
     Op,
     close_socket,
     connect,
+    FramePool,
     recv_message,
     recv_payload,
+    release_frame,
     send_message,
 )
 
@@ -476,6 +479,198 @@ class _NativeServerConn:
             self._lib.bpsc_close(h)
         with self._lock:
             self.dead = True
+
+
+class _AsyncRpc:
+    """One async RPC's deadline + retry + revival state
+    (:meth:`PSClient._async_rpc` has the contract).
+
+    An object with methods, not a nest of closures: closures that name
+    each other are a reference cycle, and that cycle held the RPC's
+    payload, its sink and the engine's task and job until a collection.
+    Whoever waits on this RPC — the connection's callback table, the
+    timer wheel, the retry pool — holds a bound method of it, and it
+    holds none of them back: the last one to let go frees everything."""
+
+    __slots__ = (
+        "client", "make_msg", "key", "deliver", "on_error", "sink",
+        "abort_check", "precheck", "heal", "chase", "attempt", "healed",
+        "chases", "backoff", "sid",
+    )
+
+    def __init__(self, client, make_msg, key, deliver, on_error, sink,
+                 abort_check, precheck, heal, chase) -> None:
+        from byteps_tpu.comm.retry import Backoff
+
+        self.client = client
+        self.make_msg = make_msg
+        self.key = key
+        self.deliver = deliver
+        self.on_error = on_error
+        self.sink = sink
+        self.abort_check = abort_check
+        self.precheck = precheck
+        self.heal = heal
+        self.chase = chase
+        self.attempt = 0
+        self.healed = False
+        self.chases = 0
+        self.backoff = Backoff(base=client.cfg.rpc_backoff_s, cap=2.0)
+        # server-rank label for the robustness counters: a single sick
+        # server must be visible in the per-peer dimension, not just as
+        # an anonymous bump of the flat total (docs/observability.md)
+        try:
+            self.sid = str(client.server_for(key))
+        except (ValueError, ZeroDivisionError, IndexError, ConnectionError):
+            self.sid = "?"
+
+    def aborted_cleanup(self) -> bool:
+        """True (and routes to on_error) when the op is abandoned."""
+        if self.abort_check is not None and self.abort_check():
+            if self.on_error is not None:
+                self.on_error()
+            return True
+        return False
+
+    def finish_fail(self) -> None:
+        counters().bump("rpc_giveup", labels={"server": self.sid})
+        if self.on_error is not None:
+            self.on_error()
+
+    def fail(self) -> None:
+        # retries exhausted: before surfacing the error, try the
+        # in-place heal ONCE — resync the server's authoritative
+        # ledger, replay journaled pushes it never absorbed, then
+        # re-attempt this RPC (docs/robustness.md "healing flow").
+        # Off this thread: the heal blocks in dials and recovery
+        # RPCs, and fail() can fire from a recv-loop drain.
+        client = self.client
+        if (self.heal and not self.healed and not client._stop.is_set()
+                and client.cfg.resync_deadline_s > 0):
+            self.healed = True
+            client._dispatch_retry(self.heal_and_resend)
+            return
+        self.finish_fail()
+
+    def heal_and_resend(self) -> None:
+        if self.aborted_cleanup():
+            return
+        if self.client._heal_in_place(self.key, self.sid):
+            self.attempt = 0
+            self.send_attempt()
+        else:
+            self.finish_fail()
+
+    def retry_later(self) -> None:
+        client = self.client
+        if self.aborted_cleanup():
+            return  # abandoned: no resend, cleanup via on_error
+        if client._stop.is_set() or self.attempt >= client.cfg.rpc_retries:
+            self.fail()
+            return
+        self.attempt += 1
+        counters().bump("rpc_retry", labels={"server": self.sid})
+        # timer wheel, not threading.Timer: no per-retry thread churn
+        client._timer_after(self.backoff.next_delay(), self.send_attempt)
+
+    def chase_redirect(self, msg: Message) -> None:
+        # Op.WRONG_OWNER: the server holds a NEWER ownership map —
+        # this key migrated (docs/robustness.md "migration flow").
+        # Wait (bounded) for the book that map rode in on, then
+        # resend: routing re-runs per attempt, so the resend lands on
+        # the new owner, whose migrated per-(worker, key) ledger
+        # dedupes anything the old owner already summed.  A chase
+        # does not consume the retry budget (the server answered;
+        # nothing failed) but is capped so a pathological ping-pong
+        # still surfaces an error instead of looping forever.
+        client = self.client
+        counters().bump("wrong_owner_redirect", labels={"server": self.sid})
+        if self.aborted_cleanup():
+            return
+        if not self.chase:
+            # fused frames never chase: the new map may scatter the
+            # pack's members across servers, so resending the intact
+            # frame just ping-pongs — the caller's error path (engine
+            # unfuse fallback) regroups into per-key RPCs that each
+            # chase on their own
+            self.fail()
+            return
+        self.chases += 1
+        if client._stop.is_set() or self.chases > client._max_chases:
+            self.fail()
+            return
+        # off the recv thread: the map-epoch wait blocks
+        client._dispatch_retry(functools.partial(self.rechase, msg.version))
+
+    def rechase(self, target: int) -> None:
+        if self.aborted_cleanup():
+            return
+        self.client._wait_map_epoch(
+            target, timeout=min(2.0, 0.25 * self.chases)
+        )
+        self.send_attempt()
+
+    def send_attempt(self) -> None:
+        client = self.client
+        if self.aborted_cleanup():
+            return
+        if client._stop.is_set() or (
+            self.precheck is not None and not self.precheck()
+        ):
+            self.fail()
+            return
+        try:
+            sc = client._conn_for(self.key, revive=self.attempt > 0)
+        except (ConnectionError, OSError):
+            self.retry_later()
+            return
+        # arm BEFORE alloc: alloc_seq on a dead connection fires
+        # on_reply(None) synchronously, which must find the token
+        token = client._deadline_arm(sc, self.sid)
+        on_reply = functools.partial(self.on_reply, token, time.monotonic())
+        seq = sc.alloc_seq(on_reply, sink=self.sink)
+        if seq < 0:
+            return  # on_reply(None) already fired → retry scheduled
+        try:
+            sc.send_msg(self.make_msg(seq))
+            # every frame that actually hit the wire (incl. retries):
+            # what fusion lowers (tests/test_fusion.py compares it)
+            counters().bump("wire_rpc")
+        except (ConnectionError, OSError):
+            # died between alloc and send: claim the callback — if the
+            # drain beat us to it, on_reply(None) already retried
+            if sc.pop_cb(seq) is not None:
+                client._deadline_clear(token)
+                self.retry_later()
+
+    def on_reply(self, token, t_sent: float, msg: Optional[Message]) -> None:
+        """One attempt's reply (``None``: its connection died)."""
+        client = self.client
+        client._deadline_clear(token)
+        if msg is None:
+            self.retry_later()
+        elif msg.op == Op.WRONG_OWNER:
+            self.chase_redirect(msg)
+        elif self.aborted_cleanup():
+            pass  # late success on an abandoned op: cleanup only
+        else:
+            # per-ATTEMPT round trip (retries each time their own
+            # attempt; the retry cost itself shows up in
+            # retry_backoff_seconds + the rpc_retry counter).
+            # Labeled per server RANK like the rpc_* counters:
+            # the flight recorder's straggler rule needs "whose
+            # p99 ran away THIS step", which a flat family can
+            # never answer (docs/observability.md)
+            rpc_labels = {"server": self.sid}
+            if client.cfg.job_id:
+                # per-tenant slice (docs/async.md); job 0 keeps
+                # the pre-tenancy series shape
+                rpc_labels["job"] = str(client.cfg.job_id)
+            metrics().observe(
+                "rpc_round_trip_seconds", time.monotonic() - t_sent,
+                labels=rpc_labels,
+            )
+            self.deliver(msg)
 
 
 class PSClient:
@@ -1715,166 +1910,8 @@ class PSClient:
         their error path is the unfuse fallback, and the per-key unfused
         RPCs it spawns carry their own heal.
         """
-        from byteps_tpu.comm.retry import Backoff
-
-        state = {"attempt": 0}
-        backoff = Backoff(base=self.cfg.rpc_backoff_s, cap=2.0)
-        # server-rank label for the robustness counters: a single sick
-        # server must be visible in the per-peer dimension, not just as
-        # an anonymous bump of the flat total (docs/observability.md)
-        try:
-            sid = str(self.server_for(key))
-        except (ValueError, ZeroDivisionError, IndexError, ConnectionError):
-            sid = "?"
-
-        def aborted_cleanup() -> bool:
-            """True (and routes to on_error) when the op is abandoned."""
-            if abort_check is not None and abort_check():
-                if on_error is not None:
-                    on_error()
-                return True
-            return False
-
-        def finish_fail() -> None:
-            counters().bump("rpc_giveup", labels={"server": sid})
-            if on_error is not None:
-                on_error()
-
-        def fail() -> None:
-            # retries exhausted: before surfacing the error, try the
-            # in-place heal ONCE — resync the server's authoritative
-            # ledger, replay journaled pushes it never absorbed, then
-            # re-attempt this RPC (docs/robustness.md "healing flow").
-            # Off this thread: the heal blocks in dials and recovery
-            # RPCs, and fail() can fire from a recv-loop drain.
-            if (heal and not state.get("healed") and not self._stop.is_set()
-                    and self.cfg.resync_deadline_s > 0):
-                state["healed"] = True
-
-                def heal_and_resend() -> None:
-                    if aborted_cleanup():
-                        return
-                    if self._heal_in_place(key, sid):
-                        state["attempt"] = 0
-                        send_attempt()
-                    else:
-                        finish_fail()
-
-                self._dispatch_retry(heal_and_resend)
-                return
-            finish_fail()
-
-        def retry_later() -> None:
-            if aborted_cleanup():
-                return  # abandoned: no resend, cleanup via on_error
-            if self._stop.is_set() or state["attempt"] >= self.cfg.rpc_retries:
-                fail()
-                return
-            state["attempt"] += 1
-            counters().bump("rpc_retry", labels={"server": sid})
-            # timer wheel, not threading.Timer: no per-retry thread churn
-            self._timer_after(backoff.next_delay(), send_attempt)
-
-        def chase_redirect(msg: Message) -> None:
-            # Op.WRONG_OWNER: the server holds a NEWER ownership map —
-            # this key migrated (docs/robustness.md "migration flow").
-            # Wait (bounded) for the book that map rode in on, then
-            # resend: routing re-runs per attempt, so the resend lands on
-            # the new owner, whose migrated per-(worker, key) ledger
-            # dedupes anything the old owner already summed.  A chase
-            # does not consume the retry budget (the server answered;
-            # nothing failed) but is capped so a pathological ping-pong
-            # still surfaces an error instead of looping forever.
-            counters().bump("wrong_owner_redirect", labels={"server": sid})
-            if aborted_cleanup():
-                return
-            if not chase:
-                # fused frames never chase: the new map may scatter the
-                # pack's members across servers, so resending the intact
-                # frame just ping-pongs — the caller's error path (engine
-                # unfuse fallback) regroups into per-key RPCs that each
-                # chase on their own
-                fail()
-                return
-            state["chases"] = state.get("chases", 0) + 1
-            if self._stop.is_set() or state["chases"] > self._max_chases:
-                fail()
-                return
-            target = msg.version
-
-            def rechase() -> None:
-                if aborted_cleanup():
-                    return
-                self._wait_map_epoch(
-                    target, timeout=min(2.0, 0.25 * state["chases"])
-                )
-                send_attempt()
-
-            # off the recv thread: the map-epoch wait blocks
-            self._dispatch_retry(rechase)
-
-        def send_attempt() -> None:
-            if aborted_cleanup():
-                return
-            if self._stop.is_set() or (
-                precheck is not None and not precheck()
-            ):
-                fail()
-                return
-            try:
-                sc = self._conn_for(key, revive=state["attempt"] > 0)
-            except (ConnectionError, OSError):
-                retry_later()
-                return
-            token_box: list = [None]
-            t_sent = time.monotonic()
-
-            def on_reply(msg: Optional[Message]) -> None:
-                self._deadline_clear(token_box[0])
-                if msg is None:
-                    retry_later()
-                elif msg.op == Op.WRONG_OWNER:
-                    chase_redirect(msg)
-                elif aborted_cleanup():
-                    pass  # late success on an abandoned op: cleanup only
-                else:
-                    # per-ATTEMPT round trip (retries each time their own
-                    # attempt; the retry cost itself shows up in
-                    # retry_backoff_seconds + the rpc_retry counter).
-                    # Labeled per server RANK like the rpc_* counters:
-                    # the flight recorder's straggler rule needs "whose
-                    # p99 ran away THIS step", which a flat family can
-                    # never answer (docs/observability.md)
-                    rpc_labels = {"server": sid}
-                    if self.cfg.job_id:
-                        # per-tenant slice (docs/async.md); job 0 keeps
-                        # the pre-tenancy series shape
-                        rpc_labels["job"] = str(self.cfg.job_id)
-                    metrics().observe(
-                        "rpc_round_trip_seconds", time.monotonic() - t_sent,
-                        labels=rpc_labels,
-                    )
-                    deliver(msg)
-
-            # arm BEFORE alloc: alloc_seq on a dead connection fires
-            # on_reply(None) synchronously, which must find the token
-            token_box[0] = self._deadline_arm(sc, sid)
-            seq = sc.alloc_seq(on_reply, sink=sink)
-            if seq < 0:
-                return  # on_reply(None) already fired → retry scheduled
-            try:
-                sc.send_msg(make_msg(seq))
-                # every frame that actually hit the wire (incl. retries):
-                # what fusion lowers (tests/test_fusion.py compares it)
-                counters().bump("wire_rpc")
-            except (ConnectionError, OSError):
-                # died between alloc and send: claim the callback — if the
-                # drain beat us to it, on_reply(None) already retried
-                if sc.pop_cb(seq) is not None:
-                    self._deadline_clear(token_box[0])
-                    retry_later()
-
-        send_attempt()
+        _AsyncRpc(self, make_msg, key, deliver, on_error, sink,
+                  abort_check, precheck, heal, chase).send_attempt()
 
     # --- recovery plane: in-place heal via server-driven resync ----------
     #
@@ -2185,6 +2222,9 @@ class PSClient:
         from byteps_tpu.compression.lossless import decompress_frame
 
         ck_limit = checksum_conn_limit()
+        # this lane's receive buffers, for replies no sink takes (a codec's
+        # merged round, a fused reply): whoever consumes one releases it
+        pool = FramePool()
         try:
             while not self._stop.is_set():
                 try:
@@ -2207,7 +2247,8 @@ class PSClient:
                         payload = _ZERO_COPIED
                     else:
                         payload = (
-                            recv_payload(sock, length) if length else b""
+                            recv_payload(sock, length, pool)
+                            if length else b""
                         )
                     if crc is not None and frame_checksum(
                         trace, sink if zero_copied else payload
@@ -2220,6 +2261,7 @@ class PSClient:
                         # retried response overwrites it before the
                         # caller ever wakes).  Repeated mismatches
                         # poison the connection → revival re-dials.
+                        release_frame(payload)  # dropped unread
                         fails = sc.note_checksum_fail()
                         counters().bump("wire_checksum_fail", labels={
                             "side": "client",
@@ -2236,8 +2278,9 @@ class PSClient:
                         # mismatch — the callback stays registered, the
                         # deadline/retry machinery re-fetches, and
                         # repeated failures poison the connection
+                        container = payload
                         try:
-                            payload = decompress_frame(payload, op=op)
+                            payload = decompress_frame(container, op=op)
                         except LosslessError:
                             fails = sc.note_checksum_fail()
                             counters().bump("wire_lossless_fail", labels={
@@ -2249,6 +2292,8 @@ class PSClient:
                                 counters().bump("wire_checksum_conn_drop")
                                 return
                             continue
+                        finally:
+                            release_frame(container)  # decoded or dropped
                     if zero_copied:
                         self.zero_copy_pulls += 1
                 except (ConnectionError, OSError):
@@ -2261,6 +2306,9 @@ class PSClient:
                             version=version, status=status, flags=flags,
                         )
                     )
+                # this thread now blocks in the next header's recv: what
+                # it still names would live until a frame arrives
+                cb = payload = sink = None
         finally:
             # one lane dying poisons the whole striped connection: close
             # every lane (wakes the sibling receivers).  The DRAIN — fail
@@ -2595,6 +2643,8 @@ class PSClient:
                 if on_error is not None:
                     on_error()
                 return
+            finally:
+                release_frame(msg.payload)  # every slot is a copy out of it
             cb(reply)
 
         self._async_rpc(

@@ -402,14 +402,105 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes:
     return bytes(recv_payload(sock, n))
 
 
-def recv_payload(sock: socket.socket, n: int) -> bytearray:
+#: payloads under this size never come from a :class:`FramePool`: headers,
+#: books, INIT and PULL requests, most fused packs.  The allocator serves
+#: them from memory it holds anyway; the pool is for the partitions
+POOL_MIN_BYTES = 64 << 10
+#: the most idle bytes one pool keeps.  A pool holds what its connection
+#: had in flight at once (at most one push a key a worker, by the round
+#: gate); a frame returned past this ceiling dies with its last holder
+POOL_IDLE_BYTES = 1 << 30
+
+
+class Frame(bytearray):
+    """A payload buffer lent by a :class:`FramePool`: a ``bytearray`` to
+    every reader (``np.frombuffer``, ``len``, slicing, ``struct``), plus
+    the way back.  :func:`release_frame` returns it once; a frame nobody
+    releases dies with its last holder, as a plain ``bytearray`` does."""
+
+    __slots__ = ("_pool",)  # the pool it is out on loan from, or None
+
+
+class FramePool:
+    """The receive buffers of ONE connection, kept between frames.
+
+    ``take(n)`` hands out a buffer of exactly ``n`` bytes that the pool
+    already holds, or a fresh one where it holds none (counted:
+    ``host_buffers_fresh`` / ``host_buffers_reused``, ``site="frame"``).
+    A buffer comes back only by :func:`release_frame` — an explicit call at
+    the point where its last holder lets go, never a guess from a
+    reference count — and only once: the rule of :func:`recv_payload`
+    (whoever holds a payload never sees it change) rests on that.  The
+    pool therefore sizes itself: it never holds more buffers of a size
+    than the connection had in flight at once."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._idle: dict = {}  # size → [Frame, ...]
+        self._idle_bytes = 0
+
+    def take(self, n: int) -> Frame:
+        from byteps_tpu.core.telemetry import counters
+
+        with self._lock:
+            idle = self._idle.get(n)
+            frame = idle.pop() if idle else None
+            if frame is not None:
+                self._idle_bytes -= n
+        counters().bump(
+            "host_buffers_fresh" if frame is None else "host_buffers_reused",
+            labels=_FRAME_SITE,
+        )
+        if frame is None:
+            frame = Frame(n)
+        frame._pool = self
+        return frame
+
+    def give(self, frame: Frame) -> bool:
+        """Take ``frame`` back; False where it is not out on loan from
+        this pool (a second release, another pool's frame)."""
+        with self._lock:
+            if frame._pool is not self:
+                return False
+            frame._pool = None
+            if self._idle_bytes + len(frame) <= POOL_IDLE_BYTES:
+                self._idle.setdefault(len(frame), []).append(frame)
+                self._idle_bytes += len(frame)
+            return True
+
+
+_FRAME_SITE = {"site": "frame"}
+
+
+def release_frame(payload) -> bool:
+    """Hand a received payload back to the pool it came from, if it came
+    from one: call it where the LAST holder of ``payload`` lets go (its
+    sum is in the store; the reply it carried is decoded).  Anything else
+    — ``bytes``, a plain ``bytearray``, a frame already returned — is
+    left alone."""
+    pool = payload._pool if isinstance(payload, Frame) else None
+    return pool is not None and pool.give(payload)
+
+
+def recv_payload(sock: socket.socket, n: int,
+                 pool: Optional[FramePool] = None) -> bytearray:
     """Receive a frame's ``n`` payload bytes and return the buffer they
     were received INTO: one pass over the payload on this side of the
-    wire.  A fresh ``bytearray`` a frame that the returned message owns
-    alone — no pool, no reuse — so whoever holds a payload (a parked
-    push, a fused member, a stored snapshot) never sees it change."""
-    buf = bytearray(n)
-    recv_into(sock, memoryview(buf))
+    wire.  The returned message owns that buffer alone, so whoever holds
+    a payload (a parked push, a fused member, a stored snapshot) never
+    sees it change: without ``pool`` it is a fresh ``bytearray``; with
+    one, a :class:`Frame` that goes back to the pool only when its holder
+    says so (:func:`release_frame`)."""
+    if pool is None or n < POOL_MIN_BYTES:
+        buf = bytearray(n)
+        recv_into(sock, memoryview(buf))
+        return buf
+    buf = pool.take(n)
+    try:
+        recv_into(sock, memoryview(buf))
+    except BaseException:
+        release_frame(buf)  # nobody saw it
+        raise
     return buf
 
 
@@ -466,8 +557,10 @@ def verify_checksum(crc: Optional[int], trace: Optional[Tuple[int, int]],
         raise ChecksumError(op, crc, got)
 
 
-def recv_message(sock: socket.socket) -> Message:
-    """Receive one frame; verifies the CHECKSUM_FLAG CRC32C when the
+def recv_message(sock: socket.socket,
+                 pool: Optional[FramePool] = None) -> Message:
+    """Receive one frame (its payload from ``pool``, where one is given:
+    :func:`recv_payload`); verifies the CHECKSUM_FLAG CRC32C when the
     sender stamped one, then decompresses a LOSSLESS_FLAG container —
     in that order, so the CRC is checked over the exact bytes that
     shipped and a corrupt container never reaches the decompressor
@@ -477,12 +570,17 @@ def recv_message(sock: socket.socket) -> Message:
     op, status, flags, seq, key, cmd, version, length, trace, crc, lossless = (
         recv_header_ex(sock)
     )
-    payload = recv_payload(sock, length) if length else b""
-    verify_checksum(crc, trace, payload, op=op)
-    if lossless:
-        from byteps_tpu.compression.lossless import decompress_frame
+    payload = recv_payload(sock, length, pool) if length else b""
+    try:
+        verify_checksum(crc, trace, payload, op=op)
+        if lossless:
+            from byteps_tpu.compression.lossless import decompress_frame
 
-        payload = decompress_frame(payload, op=op)
+            container, payload = payload, decompress_frame(payload, op=op)
+            release_frame(container)  # decoded into a payload of its own
+    except (ChecksumError, LosslessError):
+        release_frame(payload)  # dropped unread
+        raise
     return Message(
         op, key=key, payload=payload, seq=seq, cmd=cmd, version=version,
         status=status, flags=flags, trace=trace,

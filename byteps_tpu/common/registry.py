@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 from byteps_tpu.common.types import DataType, Partition
 
@@ -53,6 +53,12 @@ class TensorContext:
     # via the byteps_job declare kwarg); job 0 keys are bit-identical to
     # the pre-tenancy layout.
     job: int = 0
+    # the tensor's pull target (core/engine.py ``_pull_target``): ONE host
+    # buffer of the whole tensor for its life, which every round of a jax
+    # job lands in and COPYH2D reads; ``pull_target_lent`` while a job
+    # holds it.  None until a jax job needs it, and after a failed round
+    pull_target: Any = None
+    pull_target_lent: bool = False
 
     @property
     def base_key(self) -> int:

@@ -34,6 +34,7 @@ import numpy as np
 
 from byteps_tpu.common.config import Config
 from byteps_tpu.common.partition import partition_tensor, validate_rowsparse
+from byteps_tpu.comm.transport import release_frame
 from byteps_tpu.common.registry import get_registry
 from byteps_tpu.common.types import (
     QueueType,
@@ -86,6 +87,9 @@ def _assemble(parts: list, shape: tuple):
     return _assemble_program()(parts, shape)
 
 
+_TARGET_SITE = {"site": "pull_target"}
+
+
 class _Job:
     """One push_pull invocation: shared state across its partitions."""
 
@@ -93,12 +97,13 @@ class _Job:
         "name", "ctx", "flat", "result", "dtype_id", "average", "handle",
         "pending", "lock", "shape", "np_dtype", "is_jax", "version", "t0",
         "rowsparse", "device_codec", "device_parts", "failed", "trace_id",
-        "parent_span", "step_counted", "d2h_parts",
+        "parent_span", "step_counted", "d2h_parts", "holds_target",
+        "__weakref__",
     )
 
     def __init__(self, name, ctx, flat, result, dtype_id, average, handle,
                  pending, shape, np_dtype, is_jax, version, rowsparse=None,
-                 device_codec=False, d2h_parts=None):
+                 device_codec=False, d2h_parts=None, holds_target=False):
         self.name = name
         self.ctx = ctx
         # the tensor as one flat array the COPYD2H thread slices: a numpy
@@ -110,7 +115,11 @@ class _Job:
         # was started in submit (``_start_d2h``); COPYD2H pops each one,
         # so the device slice dies when its task leaves the stage
         self.d2h_parts = d2h_parts
+        # the host buffer every PULL lands in.  ``holds_target``: it is the
+        # tensor's own (``ctx.pull_target``), lent to this job until its
+        # last partition is on the device (``_return_target``)
         self.result = result
+        self.holds_target = holds_target
         self.dtype_id = dtype_id
         self.average = average
         self.handle = handle
@@ -450,6 +459,7 @@ class PipelineEngine:
         self._fuse_lock = threading.Lock()
         self._threads: List[threading.Thread] = []
         self._init_lock = threading.Lock()
+        self._target_lock = threading.Lock()  # every ctx's pull_target
         # per-key stateful codec chains (per-partition compressor
         # instantiation, operations.cc:283-414)
         self._compressors: Dict[int, object] = {}
@@ -641,7 +651,10 @@ class PipelineEngine:
             and bool(ctx.partitions)
             and all(p.key in self._device_codecs for p in ctx.partitions)
         )
-        result = None if on_device else np.empty(n_elements, dtype=np_dtype)
+        result, holds_target = None, False
+        if not on_device:
+            result, holds_target = self._pull_target(
+                ctx, is_jax, n_elements, np_dtype)
         d2h_parts = None
         if is_jax and not on_device and tensor.is_fully_replicated:
             d2h_parts = self._start_d2h(tensor.addressable_data(0), ctx.partitions)
@@ -652,6 +665,7 @@ class PipelineEngine:
             pending=len(ctx.partitions), shape=np.shape(tensor),
             np_dtype=np_dtype, is_jax=is_jax, version=ctx.version,
             device_codec=on_device, d2h_parts=d2h_parts,
+            holds_target=holds_target,
         )
         # small-tensor fusion routing, per partition: uncompressed
         # partitions gauge their RAW size against the threshold;
@@ -698,6 +712,51 @@ class PipelineEngine:
             )
             self._stamp_task_trace(task, job)
             self.queues[QueueType.COPYD2H].add_task(task)
+
+    def _pull_target(self, ctx, engine_consumes: bool, n_elements: int,
+                     np_dtype) -> tuple:
+        """The host buffer a job's PULLs land in → ``(buffer, lent)``.
+
+        A jax job never hands its host result to anybody: COPYH2D puts
+        each partition on the device and the handle returns device
+        arrays.  Such a job borrows the tensor's one buffer
+        (``ctx.pull_target``, made by its first round) and gives it back
+        in ``_finalize``, once every partition's put is COMPLETE — so a
+        steady round writes no page the process did not hold.  A numpy
+        caller keeps what it is handed, and a second job in flight on one
+        name finds the buffer out: both get a fresh one (counted:
+        ``host_buffers_fresh`` / ``host_buffers_reused``,
+        ``site="pull_target"``)."""
+        from byteps_tpu.core.telemetry import counters
+
+        lent = False
+        if engine_consumes:
+            with self._target_lock:
+                if not ctx.pull_target_lent:
+                    lent = ctx.pull_target_lent = True
+                    buf = ctx.pull_target
+                    if (buf is not None and buf.size == n_elements
+                            and buf.dtype == np_dtype):
+                        counters().bump("host_buffers_reused", labels=_TARGET_SITE)
+                        return buf, True
+        counters().bump("host_buffers_fresh", labels=_TARGET_SITE)
+        buf = np.empty(n_elements, dtype=np_dtype)
+        if lent:  # the tensor's first round (or its first of this size)
+            ctx.pull_target = buf
+        return buf, lent
+
+    def _return_target(self, job: _Job, reusable: bool) -> None:
+        """``job`` is done with the tensor's pull target.  ``reusable``:
+        every byte of it that anybody reads has been read (the caller saw
+        each partition's put complete).  A failed round's buffer is
+        dropped instead: a late reply may still land in its sinks."""
+        if not job.holds_target:
+            return
+        job.holds_target = False
+        with self._target_lock:
+            if not reusable:
+                job.ctx.pull_target = None
+            job.ctx.pull_target_lent = False
 
     @staticmethod
     def _start_d2h(leaf, partitions) -> dict:
@@ -768,6 +827,11 @@ class PipelineEngine:
                 # uninitialized key and the server would drop the conn
                 if not ctx.partitions:
                     build_partitions(ctx)
+                if ctx.engine_epoch != self._epoch:
+                    # whatever held the tensor's pull target died with the
+                    # engine before this one (the registry outlives it)
+                    with self._target_lock:
+                        ctx.pull_target, ctx.pull_target_lent = None, False
                 if self._journal is not None:
                     # the barrier below restarts this key's round
                     # numbering: journaled payloads from the old
@@ -1237,6 +1301,7 @@ class PipelineEngine:
     def _fail_job(self, job: _Job, status: Status) -> None:
         from byteps_tpu.core.state import get_state
 
+        self._return_target(job, reusable=False)
         # step window closes before the handle completes — same
         # resubmission race as the _finalize path
         self._step_end(job)
@@ -1311,9 +1376,16 @@ class PipelineEngine:
 
         if job.is_jax:
             parts = [job.device_parts[off] for off in sorted(job.device_parts)]
-            # the job sits in reference cycles (handle ↔ caller) that only
-            # the collector breaks: drop the buffers by reference count now
             job.device_parts = job.result = None
+            if job.holds_target:
+                # the next round's PULLs overwrite the bytes these puts
+                # read: a put is done with them when its array is ready.
+                # All but the newest finished while later partitions were
+                # still on the wire
+                import jax
+
+                jax.block_until_ready(parts)
+                self._return_target(job, reusable=True)
             out = _assemble(parts, job.shape)
             del parts
             if job.device_codec and job.average:
@@ -1334,7 +1406,9 @@ class PipelineEngine:
         ``out / num_workers``, on bytes still in cache, and none with one
         worker (``x / 1 == x`` bit for bit).  device_put returns with the
         transfer issued; jax keeps ``buf`` alive until it completes, and
-        nothing writes a result buffer after its COPYH2D."""
+        nothing writes those bytes before then: a buffer that outlives its
+        job (the tensor's pull target) goes back only when every put of
+        the round is ready (``_finalize``)."""
         import jax
 
         from byteps_tpu.core.telemetry import counters
@@ -2051,6 +2125,8 @@ class PipelineEngine:
                             labels=self._job_labels(task.job))
                 arr = np.frombuffer(payload, dtype=job.np_dtype)
                 job.result[: arr.size] = arr
+                del arr
+                release_frame(payload)  # copied out: its last holder
                 self._proceed(task)
 
             self.client.pull(
@@ -2100,6 +2176,8 @@ class PipelineEngine:
                 # fallback (response length differed from the sink)
                 arr = np.frombuffer(payload, dtype=job.np_dtype)
                 job.result[task.offset : task.offset + task.length] = arr[: task.length]
+                del arr
+                release_frame(payload)  # copied out: its last holder
             self._proceed(task)
 
         self.client.pull(
@@ -2135,6 +2213,11 @@ class PipelineEngine:
         codec = self._compressors[task.key]
         arr = codec.decompress(task.compressed, task.length)
         job.result[task.offset : task.offset + task.length] = arr[: task.length]
+        del arr
+        # the pulled round is decoded into the result: its frame's last
+        # holder (a fused slot or a push's own payload is no frame)
+        release_frame(task.compressed)
+        task.compressed = None
         self._proceed(task)
 
     def _copy_h2d_once(self, task: TensorTableEntry) -> None:

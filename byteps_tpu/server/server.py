@@ -43,12 +43,14 @@ from byteps_tpu.common.types import (
     to_numpy_dtype,
 )
 from byteps_tpu.comm.transport import (
+    FramePool,
     Message,
     Op,
     close_socket,
     connect,
     listen,
     recv_message,
+    release_frame,
     send_message,
 )
 from byteps_tpu.comm.rendezvous import GROUP_ALL
@@ -83,6 +85,8 @@ class _KeyState:
         "pull_version",
         "raw_payload",
         "raw_version",
+        "lent",
+        "lent_accum",
         "migrated_to",
         "migrate_epoch",
         "job",
@@ -140,6 +144,12 @@ class _KeyState:
         self.pull_version = -1
         self.raw_payload: Optional[bytes] = None   # round-cached raw bytes
         self.raw_version = -1
+        # replies not yet sent that are a VIEW of a store buffer (lend):
+        # of the published round's (``store``), and of the round before's,
+        # which the publish turned into ``accum``.  The next round's first
+        # push may not write there while one is out (own_accum)
+        self.lent = 0
+        self.lent_accum = 0
         # elastic resharding tombstone (docs/robustness.md "migration
         # flow"): rank this key's state was shipped to (None = lives
         # here), and the map epoch of the last migration event in either
@@ -179,17 +189,25 @@ class _KeyState:
         self.req_bytes = 0
         self.lock = threading.Lock()
 
-    def wire_payload(self, compressed: bool, async_mode: bool = False) -> bytes:
+    def wire_payload(self, compressed: bool, async_mode: bool = False,
+                     lend: bool = False):
         """What a puller receives, honoring ITS requested wire format:
         compressed pulls get the codec-compressed merged result
         (server.cc:92-118), default pulls get raw bytes — mixed-config
         workers on one key stay correct.  In async mode the store mutates
         every push, so both formats encode on demand.
 
-        Raw bytes are serialized ONCE per round and served to every
-        puller from the cache — the reference caches response KVPairs for
-        the same reason (avoid per-request copies / re-registration,
-        server.cc:39-80)."""
+        ``lend``: the caller sends the payload itself and hands it back
+        (:meth:`give_back`) when the send is over.  A sync key's raw
+        round is then the published store's own bytes, a ``memoryview``
+        and no copy: nothing writes a published store in place (a round
+        sums into ``accum``; :meth:`own_accum` keeps a lent buffer from
+        becoming that), so the view is the round for as long as it is
+        held.  Where the store does change in place (async mode, a
+        server-side update rule) and for fused slots, raw bytes are
+        serialized ONCE per round and served to every puller from the
+        cache — the reference caches response KVPairs for the same reason
+        (avoid per-request copies / re-registration, server.cc:39-80)."""
         if compressed and self.compressor is not None:
             if async_mode:
                 return self.compressor.compress(self.store)
@@ -200,12 +218,56 @@ class _KeyState:
                 self.pull_payload = self.compressor.compress(self.store)
                 self.pull_version = self.store_version
             return self.pull_payload
+        from byteps_tpu.core.telemetry import counters
+
         if async_mode:
+            counters().bump("host_buffers_fresh", labels=_REPLY_SITE)
             return self.store.tobytes()
+        if lend and self.opt_rule is None:
+            counters().bump("host_buffers_reused", labels=_REPLY_SITE)
+            self.lent += 1
+            return memoryview(self.store).cast("B")
         if self.raw_version != self.store_version:
+            counters().bump("host_buffers_fresh", labels=_REPLY_SITE)
             self.raw_payload = self.store.tobytes()
             self.raw_version = self.store_version
         return self.raw_payload
+
+    def set_buffers(self, store, accum) -> None:
+        """New store and accumulator (INIT, a migration in or out): what
+        was lent of the old ones stays with the old ones."""
+        self.store, self.accum = store, accum
+        self.lent = self.lent_accum = 0
+
+    def give_back(self, view: memoryview) -> None:
+        """A lent round's reply was sent, or never will be."""
+        with self.lock:
+            if view.obj is self.store:
+                self.lent -= 1
+            elif view.obj is self.accum:
+                self.lent_accum -= 1
+            # else: the buffer was retired while lent (own_accum)
+
+    def own_accum(self) -> None:
+        """Before a round's first write into ``accum`` (caller holds the
+        lock): where a reply still views that buffer — it was the store a
+        round ago, and a writer queue or a slow socket has not sent it
+        yet — leave it to the reply and sum into a fresh one."""
+        if self.lent_accum:
+            from byteps_tpu.core.telemetry import counters
+
+            counters().bump("host_buffers_fresh", labels=_REPLY_SITE)
+            self.accum = np.empty_like(self.accum)
+            self.lent_accum = 0
+
+    def publish_swap(self) -> None:
+        """The summed round becomes the store; the round before it, with
+        whatever replies still view it, the next round's accumulator."""
+        self.store, self.accum = self.accum, self.store
+        self.lent, self.lent_accum = 0, self.lent
+
+
+_REPLY_SITE = {"site": "reply"}
 
 
 class _FusedReply:
@@ -1153,7 +1215,7 @@ class PSServer:
                     payload = (
                         self._rowsparse_gather(ks, rs_req)
                         if rs_req is not None
-                        else ks.wire_payload(pcomp, async_mode)
+                        else ks.wire_payload(pcomp, async_mode, lend=True)
                     )
                 except RuntimeError:
                     close_socket(pconn)
@@ -1408,8 +1470,7 @@ class PSServer:
             return False
         with ks.lock:
             # keep the tombstone, free the bulk
-            ks.store = None
-            ks.accum = None
+            ks.set_buffers(None, None)
             ks.push_seen = {}
             ks.init_done = {}
             ks.pull_payload = None
@@ -1661,10 +1722,10 @@ class PSServer:
                 or store_version >= ks.store_version):
             ks.dtype = dtype
             store = np.frombuffer(store_b, dtype=dtype).copy()
-            ks.store = store
-            ks.accum = (
+            ks.set_buffers(
+                store,
                 np.frombuffer(accum_b, dtype=dtype).copy()
-                if accum_b else np.zeros_like(store)
+                if accum_b else np.zeros_like(store),
             )
             ks.store_version = store_version
             ks.recv_count = int(meta.get("recv_count", 0))
@@ -1786,10 +1847,13 @@ class PSServer:
 
         ck_limit = checksum_conn_limit()
         ck_fails = 0
+        # this connection's receive buffers: a pushed partition lands in
+        # memory the process already holds (transport.FramePool)
+        pool = FramePool()
         try:
             while not self._stop.is_set():
                 try:
-                    msg = recv_message(conn)
+                    msg = recv_message(conn, pool)
                 except (ChecksumError, LosslessError) as e:
                     # end-to-end wire integrity (docs/robustness.md "Wire
                     # integrity"): a flipped payload bit that survived
@@ -2036,8 +2100,8 @@ class PSServer:
                 created = True
                 dtype = to_numpy_dtype(DataType(dtype_id))
                 ks.dtype = dtype
-                ks.store = np.zeros(n, dtype=dtype)
-                ks.accum = np.zeros(n, dtype=dtype)
+                ks.set_buffers(np.zeros(n, dtype=dtype),
+                               np.zeros(n, dtype=dtype))
             # server-opt profile, adopted from EVERY init like async_mode
             # above: a re-init without the extension returns the key to
             # plain SUM semantics.  Same (rule, hp) keeps the live slots
@@ -2274,14 +2338,23 @@ class PSServer:
         """Send one engine-thread reply.  QoS active → routed through
         the connection's writer so a slow tenant's socket never blocks
         the shared engine thread (docs/async.md); otherwise the classic
-        inline send, bit-identical single-tenant behavior."""
+        inline send, bit-identical single-tenant behavior.  A payload
+        that is a lent view of its key's store (``wire_payload(...,
+        lend=True)``) is handed back when the send is over, sent or not."""
         if not self._qos_active:
-            send_message(conn, msg, send_lock)
+            self._send_lent(conn, msg, send_lock)
             return
         self._submit_reply(
-            conn, lambda: send_message(conn, msg, send_lock),
+            conn, lambda: self._send_lent(conn, msg, send_lock),
             len(msg.payload) + 64,
         )
+
+    def _send_lent(self, conn, msg: Message, send_lock) -> None:
+        try:
+            send_message(conn, msg, send_lock)
+        finally:
+            if isinstance(msg.payload, memoryview):
+                self._key_state(msg.key).give_back(msg.payload)
 
     def _submit_reply(self, conn, fn, nbytes: int) -> None:
         """Queue one reply closure on the conn's writer, replacing a
@@ -2327,7 +2400,10 @@ class PSServer:
         SUM_RECVs into the accumulator.  Records the replay-ledger entry
         only AFTER the summation succeeded (a sum that raises must leave
         the retry eligible)."""
-        if self._async_ks(ks):
+        is_async = self._async_ks(ks)
+        if not is_async and ks.recv_count == 0:
+            ks.own_accum()  # the round's first write is below
+        if is_async:
             if ks.opt_rule is not None:
                 # async server-opt: the rule fires per push (no round
                 # barrier to average at); the SSP gate then bounds the
@@ -2456,6 +2532,13 @@ class PSServer:
         if redirect is not None:
             self._send_wrong_owner(conn, send_lock, msg, redirect)
             return
+        # the push's last holder lets go here: its bytes are in the
+        # accumulator (or were there already: a replay), and ``arr`` was
+        # the only view of them.  A parked push returned above, frame
+        # in hand; a codec's payload is left to its reference count
+        arr = None
+        if not compressed:
+            release_frame(msg.payload)
         t_summed = time.time()
         sum_dur = (t_summed - t_start) - published
         metrics().observe("server_sum_seconds", max(0.0, sum_dur))
@@ -2615,6 +2698,10 @@ class PSServer:
                         t_m1 - published, published, fused=True,
                     )
             self._flush_pulls(key, flush)
+        # every member was a copy out of the frame (decode_fused_push
+        # slices a bytearray) and every one is summed: the frame is
+        # consumed.  A parked or redirected frame returned above, whole
+        release_frame(msg.payload)
         # no unconditional "reply" span here: the ONE fused reply leaves
         # when its last member's round publishes — which may be this call
         # (flushed above) or a later worker's push entirely
@@ -2676,6 +2763,7 @@ class PSServer:
             if ks.recv_count == 0:
                 # sparse COPY_FIRST: rows this worker does NOT touch
                 # must start the round at zero, not last round's sum
+                ks.own_accum()
                 ks.accum[:] = 0
             # np.add.at accumulates duplicate indices correctly
             np.add.at(ks.accum.reshape(total_rows, row_len), idx, vals)
@@ -2711,7 +2799,7 @@ class PSServer:
             if ks.opt_step == 0:
                 # seed round: accum holds the workers' (identical)
                 # initial params verbatim — adopt them as the store
-                ks.store, ks.accum = ks.accum, ks.store
+                ks.publish_swap()
             else:
                 # accum = raw gradient sum; averaging happens inside
                 # the rule (same float op order as the worker engine's
@@ -2725,7 +2813,7 @@ class PSServer:
                 counters().bump("server_opt_updates")
             ks.opt_step += 1
         else:
-            ks.store, ks.accum = ks.accum, ks.store
+            ks.publish_swap()
         ks.store_version += 1
         ks.recv_count = 0
         if compressed:
@@ -2855,7 +2943,8 @@ class PSServer:
                 payload = (
                     self._rowsparse_gather(ks, msg.payload)
                     if rowsparse
-                    else ks.wire_payload(wants_compressed, is_async)
+                    else ks.wire_payload(wants_compressed, is_async,
+                                         lend=True)
                 )
                 ver = ks.store_version
             else:

@@ -16,12 +16,17 @@ and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
 ``ndarray``, as ``np.asarray(slice)`` is on the TPU: the journal keeps a reference.
 ``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
 connection from a child shaped like the server: a serve thread that receives,
-an engine thread that copies into the store, acks, ``tobytes()``es and replies.
+an engine thread that copies into the store, acks and replies.  Two readings:
+``fresh`` (the server up to PR 33: a new ``bytearray`` a received frame, the
+store's ``tobytes()`` a reply) and ``held`` (since PR 34: frames from the
+connection's ``FramePool``, released once copied; the reply a view of the store).
 ``--mode d2h``: ``--frames`` slices of ``--bytes`` off ONE array on the first
 device, onto the host two ways: sliced and read one at a time (COPYD2H's loop
 up to PR 31), and through ``PipelineEngine._start_d2h``, which ``engine.submit``
 calls since PR 32: one split program, then every partition's
-``copy_to_host_async`` issued before the first is read.
+``copy_to_host_async`` issued before the first is read.  Both keep a pass's host
+buffers until the next pass has its own (a step's cycles did, up to PR 33);
+``issued_first_freed`` lets them go first, as a step does since PR 34.
 """
 
 import argparse
@@ -41,8 +46,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from byteps_tpu.comm.journal import RoundJournal  # noqa: E402
 from byteps_tpu.comm.transport import (  # noqa: E402
-    Message, Op, connect, listen, recv_header_ex, recv_into, recv_message,
-    send_message,
+    FramePool, Message, Op, connect, listen, recv_header_ex, recv_into, recv_message,
+    release_frame, send_message,
 )
 from byteps_tpu.core.telemetry import counters  # noqa: E402
 
@@ -50,23 +55,26 @@ REPS = 5  # timed passes; one more runs first, unwarmed and uncounted
 POOL = 40  # distinct payload buffers, so no frame is sent from a warm cache line
 
 
-def _child(port_out, engine_thread: bool) -> None:
+def _child(port_out, engine_thread: bool, held: bool = False) -> None:
     """The server's side: a PUSH is kept (with ``engine_thread`` copied into the
-    store, as a sum is) and acked, a PULL answered with the store's ``tobytes()``;
+    store, as a sum is) and acked, a PULL answered with the store's bytes
+    (``tobytes()``; with ``held`` a view, and received frames from a pool);
     with ``engine_thread`` the serve thread only receives, a second does the rest."""
     srv, port = listen("127.0.0.1", 0)
     port_out.send(port)
     conn, _ = srv.accept()
     conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     inbox, lock, store = queue.Queue(), threading.Lock(), {}
+    pool = FramePool() if held else None
     def handle(msg):
         reply = Message(msg.op, key=msg.key, seq=msg.seq)
         if msg.op == Op.PULL:
-            reply.payload = store[msg.key].tobytes()
+            reply.payload = memoryview(store[msg.key]) if held else store[msg.key].tobytes()
         elif engine_thread:
             if msg.key not in store:
                 store[msg.key] = np.empty(len(msg.payload), np.uint8)
             store[msg.key][:] = np.frombuffer(msg.payload, np.uint8)
+            release_frame(msg.payload)
         else:
             store[0] = msg.payload  # the previous frame dies here, as a store's does
         send_message(conn, reply, lock)
@@ -79,17 +87,17 @@ def _child(port_out, engine_thread: bool) -> None:
         threading.Thread(target=engine, daemon=True).start()
     while True:
         try:
-            (inbox.put if engine_thread else handle)(recv_message(conn))
+            (inbox.put if engine_thread else handle)(recv_message(conn, pool))
         except ConnectionError:
             return
 
 
 @contextlib.contextmanager
-def _connected(engine_thread: bool):
+def _connected(engine_thread: bool, held: bool = False):
     """Spawn the child; yield a socket dialled, as a worker's is, to its port."""
     ctx = multiprocessing.get_context("spawn")
     port_in, port_out = ctx.Pipe(duplex=False)
-    proc = ctx.Process(target=_child, args=(port_out, engine_thread), daemon=True)
+    proc = ctx.Process(target=_child, args=(port_out, engine_thread, held), daemon=True)
     proc.start()
     try:
         if not port_in.poll(120):
@@ -162,10 +170,15 @@ def frame(frames: int, nbytes: int) -> dict:
 
 def echo(frames: int, nbytes: int) -> dict:
     pool = _payloads(nbytes)
+    return {"fresh": _echo_passes(pool, frames, nbytes, held=False),
+            "held": _echo_passes(pool, frames, nbytes, held=True)}
+
+
+def _echo_passes(pool: list, frames: int, nbytes: int, held: bool) -> dict:
     lock, journal = threading.Lock(), RoundJournal(2, 64 << 20)
     result = np.empty(frames * nbytes, np.uint8)
     to_pull, landed = queue.Queue(), threading.Semaphore(0)
-    with _connected(engine_thread=True) as sock:
+    with _connected(engine_thread=True, held=held) as sock:
         def on_header(op, key):
             if op == Op.PUSH:  # the ack: the puller asks for the partition back
                 to_pull.put(key)
@@ -212,7 +225,8 @@ def d2h(frames: int, nbytes: int) -> dict:
         issue_ms.append((time.perf_counter() - t0) / frames * 1e3)
         return [np.asarray(parts.pop(p.offset)) for p in partitions]
 
-    readings = [("one_at_a_time", one_at_a_time, flat), ("issued_first", issued_first, flat)]
+    readings = [("one_at_a_time", one_at_a_time, flat), ("issued_first", issued_first, flat),
+                ("issued_first_freed", issued_first, flat)]
     if len(jax.devices()) > 1:
         # the gradient of a dp > 1 step: whole on every chip, and every
         # partial slice of it a gather program on all of them
@@ -224,8 +238,10 @@ def d2h(frames: int, nbytes: int) -> dict:
         ms, held = [], None
         for _ in range(REPS + 1):
             t0 = time.perf_counter()
-            # a pass's host buffers live until the next has its own, as the
-            # round journal keeps a step's: every pass lands in fresh memory
+            # a pass's host buffers live until the next has its own, as a
+            # step's cycles kept them: every pass lands in fresh memory
+            if name.endswith("_freed"):
+                held = None  # as a step's do now: freed, then made again
             held = one_pass(src)
             ms.append((time.perf_counter() - t0) / frames * 1e3)
             if not np.array_equal(held[-1], host[-n:]):
