@@ -42,7 +42,9 @@ and retries exist for.  Classes:
 Determinism: ``BYTEPS_CHAOS_SEED`` seeds a per-connection
 ``random.Random`` derived from ``(seed, connection_index)``, where the
 index is a process-global counter — with a fixed seed and a fixed
-connect order, the fault schedule replays exactly.
+connect order, the fault schedule replays exactly.  A link's pull lanes
+(and the scheduler link) count in streams of their own, so the push
+lanes' indices are those of the servers dialed, in order.
 
 Knobs (probabilities in [0,1], applied per frame in the order drop →
 disconnect → truncate → corrupt → payload corrupt; delay is rolled
@@ -123,6 +125,12 @@ _conn_counter_lock = threading.Lock()
 #: disjoint.
 _ctrl_conn_counter = itertools.count(1 << 16)
 
+#: and a third for the PULL lanes a worker dials (a tcp link to a server is
+#: a push lane and a pull lane, ps_client._ServerConn): a link's push lanes
+#: keep the indices its one socket had, so a seeded schedule aimed at a
+#: server's pushes still hits them whatever else the link dials.
+_pull_conn_counter = itertools.count(1 << 17)
+
 
 def _next_conn_index() -> int:
     with _conn_counter_lock:
@@ -134,8 +142,13 @@ def _next_ctrl_conn_index() -> int:
         return next(_ctrl_conn_counter)
 
 
+def _next_pull_conn_index() -> int:
+    with _conn_counter_lock:
+        return next(_pull_conn_counter)
+
+
 def reset_conn_indices() -> None:
-    """Restart both connection-index streams from their origins.
+    """Restart the connection-index streams from their origins.
 
     The per-socket fault RNG is keyed by (seed, connection index), and
     the index is process-global — a seeded chaos schedule therefore
@@ -145,10 +158,11 @@ def reset_conn_indices() -> None:
     which sub-suite combination runs them — the order-dependence that
     made test_fusion's ``[native-s4]`` lane flake across pytest
     selections.  Test-harness only: live jobs never reset mid-run."""
-    global _conn_counter, _ctrl_conn_counter
+    global _conn_counter, _ctrl_conn_counter, _pull_conn_counter
     with _conn_counter_lock:
         _conn_counter = itertools.count()
         _ctrl_conn_counter = itertools.count(1 << 16)
+        _pull_conn_counter = itertools.count(1 << 17)
 
 
 def control_chaos_enabled() -> bool:
@@ -289,6 +303,7 @@ class ChaosSocket:
                  peer_port: int = 0) -> None:
         self._sock = sock
         self._p = params
+        self.conn_index = conn_index
         # independent stream per connection, reproducible per (seed, index)
         self._rng = random.Random((params.seed << 20) ^ conn_index)
         self._send_lock = threading.Lock()  # fault decisions are ordered
@@ -502,11 +517,15 @@ def make_chaos_van(inner):
                 port,
             )
 
-        def connect(self, host: str, port: int, timeout: float = 30.0):
+        def connect(self, host: str, port: int, timeout: float = 30.0,
+                    next_index=_next_conn_index):
             if host.startswith(CHAOS_PREFIX):
                 host = host[len(CHAOS_PREFIX):]
             sock = self.inner.connect(host, port, timeout=timeout)
-            return ChaosSocket(sock, self.params, _next_conn_index(),
+            return ChaosSocket(sock, self.params, next_index(),
                                peer_port=port)
+
+        def connect_pull_lane(self, host: str, port: int, timeout: float = 30.0):
+            return self.connect(host, port, timeout, _next_pull_conn_index)
 
     return ChaosVan()

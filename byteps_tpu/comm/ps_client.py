@@ -5,8 +5,9 @@ ps-lite surface the core consumes (SURVEY §2.4): zero-copy ``ZPush``/
 routing (EncodeDefaultKey, global.cc:628-677), scheduler rendezvous +
 global barrier (global.cc:289-294).
 
-One TCP connection per server; a receiver thread per connection demuxes
-responses by ``seq`` and fires callbacks — the callback thread then drives
+One link per server — over TCP a push lane and a pull lane, so bulk travels
+one way on a socket (``_ServerConn``; docs/transports.md); a receiver thread
+per connection demuxes responses by ``seq`` and fires callbacks — the callback thread then drives
 the next pipeline stage, exactly like ps-lite's callback threads drive
 FinishOrProceed.
 
@@ -67,41 +68,52 @@ class _ServerConn:
             warn_native_bypass_once,
         )
 
-        if streams > 1 and shaping_enabled():
+        from byteps_tpu.comm.van import SHM_PREFIX, UNIX_PREFIX, strip_chaos
+
+        shaped = shaping_enabled()
+        if streams > 1 and shaped:
             # each stripe would get its OWN virtual wire, silently scaling
             # the emulated link to N x BYTEPS_VAN_RATE_MBYTES_S — a shaped
             # link models one wire, so striping is forced off
             warn_native_bypass_once(
                 "ignoring BYTEPS_TCP_STREAMS>1 (a shaped link is one wire)"
             )
-            streams = 1
-        # data-plane link: shaped when BYTEPS_VAN_DELAY_MS /
-        # BYTEPS_VAN_RATE_MBYTES_S emulate a DCN link (shaping.py)
-        self.sock = maybe_shape(connect(host, port, timeout=dial_timeout))
-        self.send_lock = threading.Lock()
+        tcp = not shaped and not strip_chaos(host).startswith(
+            (UNIX_PREFIX, SHM_PREFIX)
+        )
         # striped lanes (BYTEPS_TCP_STREAMS, tcp only): extra parallel
         # connections to the same server, each framed message riding ONE
-        # lane chosen by key — per-key FIFO is preserved absolutely while
-        # distinct partitions fan out over independent kernel streams (the
-        # RDMA/UCX multi-lane van analogue, reference setup.py:312-330).
-        # Lane 0 doubles as the control lane (init/register/liveness).
-        from byteps_tpu.comm.van import SHM_PREFIX, UNIX_PREFIX, strip_chaos
-
-        self.stripes = [(self.sock, self.send_lock)]
-        if streams > 1 and not strip_chaos(host).startswith(
-            (UNIX_PREFIX, SHM_PREFIX)
-        ):
-            try:
-                for _ in range(streams - 1):
-                    self.stripes.append(
-                        (maybe_shape(connect(host, port, timeout=dial_timeout)),
-                         threading.Lock())
-                    )
-            except (ConnectionError, OSError):
-                for sock, _ in self.stripes[1:]:
-                    close_socket(sock)
-                close_socket(self.sock)
-                raise
+        # lane chosen by key, so distinct partitions fan out over
+        # independent kernel streams (the RDMA/UCX multi-lane van analogue,
+        # reference setup.py:312-330).
+        streams = max(1, streams) if tcp else 1
+        # On TCP, bulk travels ONE WAY on a socket: PULL requests go out on
+        # lanes of their own and the merged rounds come back on them, so a
+        # 4 MB reply's recv_into and another partition's 4 MB push sendmsg
+        # never take turns on one kernel socket's lock.  Everything else
+        # (PUSH, FUSED, INIT, control frames) rides the push lanes, whose
+        # replies are header-only acks; push lane 0 doubles as the control
+        # lane.  A key's PULL leaves only after its PUSH was acked, and its
+        # next PUSH only after that PULL was answered (the engine's round
+        # gate): the ack orders them, not a socket's FIFO.  A unix, shm or
+        # shaped link keeps one socket, which then carries both directions.
+        lanes = []
+        try:
+            for i in range(2 * streams if tcp else 1):
+                # data-plane link: shaped when BYTEPS_VAN_DELAY_MS /
+                # BYTEPS_VAN_RATE_MBYTES_S emulate a DCN link (shaping.py)
+                lanes.append(
+                    (maybe_shape(connect(host, port, timeout=dial_timeout,
+                                         pull_lane=i >= streams)),
+                     threading.Lock())
+                )
+        except (ConnectionError, OSError):
+            for sock, _ in lanes:
+                close_socket(sock)
+            raise
+        #: push lanes, then (tcp) as many pull lanes
+        self.stripes = lanes[:streams]
+        self.pull_stripes = lanes[streams:] or self.stripes
         self.cb_lock = threading.Lock()
         self.callbacks: Dict[int, Callable[[Message], None]] = {}
         #: seq → caller-owned buffer the response payload is received INTO
@@ -112,7 +124,7 @@ class _ServerConn:
         self.dead = False  # set once the LAST recv loop exits; cb_lock-guarded
         # receiver loops still running; the last one to exit runs the
         # mark_dead drain (see lane_exited)
-        self._live_lanes = len(self.stripes)
+        self._live_lanes = len(lanes)
         #: per-server label value for counter slices (the book index the
         #: conn was built for; "?" for stubs) — set by the caller
         self.server_label = "?"
@@ -137,15 +149,17 @@ class _ServerConn:
             self._live_lanes -= 1
             return self._live_lanes <= 0
 
-    def stripe_for(self, key: int):
-        """(sock, send_lock) lane for a key — stable, so same-key requests
-        stay ordered on one stream even when pipelined (async mode)."""
-        return self.stripes[key % len(self.stripes)]
+    def lanes(self) -> list:
+        """``(sock, send_lock, name)`` of every connection of the link."""
+        named = [(sock, lock, "push") for sock, lock in self.stripes]
+        if self.pull_stripes is not self.stripes:
+            named += [(sock, lock, "pull") for sock, lock in self.pull_stripes]
+        return named
 
     def close_all(self) -> None:
         """Close every lane: one lane dying poisons the whole connection
         (a partially-striped server link would strand keyed requests)."""
-        for sock, _ in self.stripes:
+        for sock, _, _ in self.lanes():
             close_socket(sock)
 
     def alloc_seq(
@@ -192,9 +206,33 @@ class _ServerConn:
             return cbs
 
     def send_msg(self, msg: Message) -> None:
-        """Frame + send on the key's lane (per-key FIFO across stripes)."""
-        sock, lock = self.stripe_for(msg.key)
+        """Frame + send on the message's lane: chosen by its ``op`` (a PULL
+        rides a pull lane) and striped by its key, both stable, so a key's
+        pushes share one stream and its pulls another."""
+        pull = msg.op == Op.PULL
+        lanes = self.pull_stripes if pull else self.stripes
+        sock, lock = lanes[msg.key % len(lanes)]
+        _count_bulk(pull and lanes is not self.stripes, "tx", msg.op,
+                    len(msg.payload))
         send_message(sock, msg, lock)
+
+
+_LANE_LABELS = {
+    (pull_lane, direction): {"lane": "pull" if pull_lane else "push",
+                             "dir": direction}
+    for pull_lane in (False, True) for direction in ("tx", "rx")
+}
+
+
+def _count_bulk(pull_lane: bool, direction: str, op, nbytes: int) -> None:
+    """``lane_bulk_bytes{lane, dir}``: data-plane (PUSH, PULL, FUSED) payload
+    bytes a lane sent | received.  On a split link ``push,tx`` and
+    ``pull,rx`` carry a round, ``pull,tx`` a row-sparse request's indices
+    and ``push,rx`` a fused frame's small reply: bulk met no bulk.  A
+    one-socket link files both directions under ``push``."""
+    if nbytes and op in (Op.PUSH, Op.PULL, Op.FUSED):
+        counters().bump("lane_bulk_bytes", nbytes,
+                        labels=_LANE_LABELS[pull_lane, direction])
 
 
 class _NativeServerConn:
@@ -2204,14 +2242,15 @@ class PSClient:
         callback table (responses come back on the lane that carried the
         request — the server answers per-connection)."""
         threads = [
-            threading.Thread(target=self._recv_loop, args=(sc, sock), daemon=True)
-            for sock, _ in sc.stripes
+            threading.Thread(target=self._recv_loop,
+                             args=(sc, sock, name == "pull"), daemon=True)
+            for sock, _, name in sc.lanes()
         ]
         sc.recv_thread = threads[0]
         for t in threads:
             t.start()
 
-    def _recv_loop(self, sc: _ServerConn, sock) -> None:
+    def _recv_loop(self, sc: _ServerConn, sock, pull_lane: bool = False) -> None:
         from byteps_tpu.comm.transport import (
             LosslessError,
             checksum_conn_limit,
@@ -2234,6 +2273,7 @@ class PSClient:
                     # fully received: dying mid-payload must leave it for
                     # mark_dead's cb(None) drain, never lose it
                     sink = sc.peek_sink(seq)
+                    _count_bulk(pull_lane, "rx", op, length)
                     # a lossless frame's `length` is the container size,
                     # never the caller's raw-sized sink — decode lands in
                     # an owned payload (no zero-copy for compressed frames)

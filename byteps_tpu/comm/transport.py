@@ -623,12 +623,16 @@ def send_message(sock: socket.socket, msg: Message, lock: Optional[threading.Loc
         _send(sock, msg)
 
 
-def connect(host: str, port: int, timeout: float = 30.0) -> socket.socket:
+def connect(host: str, port: int, timeout: float = 30.0,
+            pull_lane: bool = False) -> socket.socket:
     """Dial an address from the scheduler book; the van scheme is encoded
-    in the host string (``unix://...`` → UDS, else TCP)."""
+    in the host string (``unix://...`` → UDS, else TCP).  ``pull_lane``:
+    the connection is a server link's pull lane (``Van.connect_pull_lane``)."""
     from byteps_tpu.comm.van import van_for_address
 
-    return van_for_address(host).connect(host, port, timeout=timeout)
+    van = van_for_address(host)
+    dial = van.connect_pull_lane if pull_lane else van.connect
+    return dial(host, port, timeout=timeout)
 
 
 def connect_control(host: str, port: int, timeout: float = 30.0) -> socket.socket:

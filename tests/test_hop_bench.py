@@ -38,10 +38,21 @@ def test_one_json_line_with_positive_medians(reading, mode):
     got = reading(mode)
     assert (got["mode"], got["frames"], got["bytes"]) == (mode, FRAMES, NBYTES)
     assert got["plane"] == "host" and got["host_cores"] == os.cpu_count()
-    sides = {"frame": ["journal_on", "journal_off"], "echo": ["fresh", "held"],
+    sides = {"frame": ["journal_on", "journal_off"], "echo": ["fresh", "held", "split"],
              "d2h": ["one_at_a_time", "issued_first", "issued_first_freed"]}[mode]
     for side in [got[name] for name in sides]:
         assert 0 < side["p10_ms"] <= side["median_ms"] <= side["p90_ms"]
+
+
+def test_echo_reads_one_connection_and_one_a_direction(reading):
+    """``split`` is ``held`` with the pulls and their replies on a second
+    connection to the same child (a TCP link's push lane and pull lane);
+    every reading brings its last partition back unchanged, or the tool
+    exits non-zero."""
+    got = reading("echo")
+    assert [k for k in got if isinstance(got[k], dict)] == ["fresh", "held", "split"]
+    for side in ("held", "split"):
+        assert set(got[side]) == {"median_ms", "p10_ms", "p90_ms"}
 
 
 def test_d2h_reads_one_array_both_ways_and_says_where(reading):

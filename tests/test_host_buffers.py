@@ -4,6 +4,7 @@ receive into, the server's pull reply; and a step leaves nothing for the
 cycle collector.  Counts, identities and orders of events — no clock."""
 
 import gc
+import itertools
 import os
 import socket
 import struct
@@ -486,7 +487,42 @@ def _hybrid(leaf_parts: int = 2):
     return hdp, batch
 
 
-def test_a_steady_step_makes_no_fresh_buffer(cluster):
+def warm_steps(monkeypatch, hdp, batch, steps: int = 3, depth: int = 2) -> list:
+    """``steps`` warm-up steps that leave every frame pool as deep as a step
+    can ever need it.  A pool is as deep as the frames its connection held at
+    once, and whether the server still holds partition 0's frame when
+    partition 1's arrives is the threads' timing: so here the server lets go
+    of no frame until ``depth`` are out together (w1's two partitions; w2 is
+    under the pooled size).  Left to timing, the first step to overlap the
+    two, warm-up or counted, would make the pool's second frame."""
+    from byteps_tpu.server import server as server_mod
+
+    take, release = FramePool.take, server_mod.release_frame
+    taken, reached = itertools.count(1), threading.Event()
+
+    def counted_take(pool, n):
+        if next(taken) >= depth:
+            reached.set()
+        return take(pool, n)
+
+    def held_release(payload):
+        if isinstance(payload, Frame):
+            reached.wait(30)
+        return release(payload)
+
+    with monkeypatch.context() as m:
+        m.setattr(FramePool, "take", counted_take)
+        m.setattr(server_mod, "release_frame", held_release)
+        return [hdp.step(batch) for _ in range(steps)]
+
+
+#: five steady steps' growth: w1 is two partitions of PART bytes, w2 one
+#: small one (no pooled frame); nothing fresh
+STEADY = {("reused", "pull_target"): 10, ("reused", "frame"): 10,
+          ("reused", "reply"): 15}
+
+
+def test_a_steady_step_makes_no_fresh_buffer(cluster, monkeypatch):
     """Three warm-up rounds, then five in which every pull target, every
     received frame and every reply is memory the process already held."""
     import byteps_tpu as bps
@@ -494,17 +530,16 @@ def test_a_steady_step_makes_no_fresh_buffer(cluster):
     bps.init()
     try:
         hdp, batch = _hybrid()
-        losses = [hdp.step(batch) for _ in range(3)]
+        start = site_counts()
+        losses = warm_steps(monkeypatch, hdp, batch)
         before = site_counts()
         losses += [hdp.step(batch) for _ in range(5)]
-        got = grown(before)
+        got, ever = grown(before), grown(start)
     finally:
         bps.shutdown()
     assert losses[-1] < losses[0]
-    assert not any(kind == "fresh" for kind, _ in got), got
-    # w1 is two partitions of PART bytes, w2 one small one (no pooled frame)
-    assert got == {("reused", "pull_target"): 10, ("reused", "frame"): 10,
-                   ("reused", "reply"): 15}
+    assert got == STEADY
+    assert ever[("fresh", "frame")] == 2, ever
 
 
 def test_a_step_leaves_nothing_for_the_collector(cluster, monkeypatch):

@@ -519,9 +519,7 @@ def _stub_client_and_conn(sock):
     client._stop = threading.Event()
     client.zero_copy_pulls = 0
     sc = _ServerConn.__new__(_ServerConn)
-    sc.sock = sock
-    sc.send_lock = threading.Lock()
-    sc.stripes = [(sock, sc.send_lock)]
+    sc.stripes = sc.pull_stripes = [(sock, threading.Lock())]
     sc.cb_lock = threading.Lock()
     sc.callbacks = {}
     sc.sinks = {}

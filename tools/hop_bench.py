@@ -15,11 +15,14 @@ tree's ``send_message`` / ``recv_message``, each acked by a bare header, with
 and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
 ``ndarray``, as ``np.asarray(slice)`` is on the TPU: the journal keeps a reference.
 ``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
-connection from a child shaped like the server: a serve thread that receives,
-an engine thread that copies into the store, acks and replies.  Two readings:
-``fresh`` (the server up to PR 33: a new ``bytearray`` a received frame, the
-store's ``tobytes()`` a reply) and ``held`` (since PR 34: frames from the
-connection's ``FramePool``, released once copied; the reply a view of the store).
+connection from a child shaped like the server: a serve thread a connection that
+receives, an engine thread that copies into the store, acks and replies.  Three
+readings: ``fresh`` (the server up to PR 33: a new ``bytearray`` a received frame,
+the store's ``tobytes()`` a reply), ``held`` (since PR 34: frames from the
+connection's ``FramePool``, released once copied; the reply a view of the store)
+and ``split`` (since PR 35: ``held``, with the puller's requests and the replies
+on a SECOND connection to the same child, so bulk travels one way on a socket,
+as a TCP link's push lane and pull lane carry it).
 ``--mode d2h``: ``--frames`` slices of ``--bytes`` off ONE array on the first
 device, onto the host two ways: sliced and read one at a time (COPYD2H's loop
 up to PR 31), and through ``PipelineEngine._start_d2h``, which ``engine.submit``
@@ -55,18 +58,18 @@ REPS = 5  # timed passes; one more runs first, unwarmed and uncounted
 POOL = 40  # distinct payload buffers, so no frame is sent from a warm cache line
 
 
-def _child(port_out, engine_thread: bool, held: bool = False) -> None:
+def _child(port_out, engine_thread: bool, held: bool = False, conns: int = 1) -> None:
     """The server's side: a PUSH is kept (with ``engine_thread`` copied into the
     store, as a sum is) and acked, a PULL answered with the store's bytes
     (``tobytes()``; with ``held`` a view, and received frames from a pool);
-    with ``engine_thread`` the serve thread only receives, a second does the rest."""
+    with ``engine_thread`` a serve thread only receives, a second does the rest.
+    Each of the ``conns`` accepted connections has a serve thread, a send lock
+    and a pool of its own, and is answered on itself, as the server's are."""
     srv, port = listen("127.0.0.1", 0)
     port_out.send(port)
-    conn, _ = srv.accept()
-    conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-    inbox, lock, store = queue.Queue(), threading.Lock(), {}
-    pool = FramePool() if held else None
-    def handle(msg):
+    inbox, store = queue.Queue(), {}
+
+    def handle(msg, conn, lock):
         reply = Message(msg.op, key=msg.key, seq=msg.seq)
         if msg.op == Op.PULL:
             reply.payload = memoryview(store[msg.key]) if held else store[msg.key].tobytes()
@@ -81,29 +84,44 @@ def _child(port_out, engine_thread: bool, held: bool = False) -> None:
 
     def engine():
         while True:
-            handle(inbox.get())
+            handle(*inbox.get())
+
+    def serve(conn):
+        conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        lock, pool = threading.Lock(), FramePool() if held else None
+        while True:
+            try:
+                msg = recv_message(conn, pool)
+            except ConnectionError:
+                return
+            if engine_thread:
+                inbox.put((msg, conn, lock))
+            else:
+                handle(msg, conn, lock)
 
     if engine_thread:
         threading.Thread(target=engine, daemon=True).start()
-    while True:
-        try:
-            (inbox.put if engine_thread else handle)(recv_message(conn, pool))
-        except ConnectionError:
-            return
+    serving = [threading.Thread(target=serve, args=(srv.accept()[0],), daemon=True)
+               for _ in range(conns)]
+    for t in serving:
+        t.start()
+    for t in serving:
+        t.join()
 
 
 @contextlib.contextmanager
-def _connected(engine_thread: bool, held: bool = False):
-    """Spawn the child; yield a socket dialled, as a worker's is, to its port."""
+def _connected(engine_thread: bool, held: bool = False, conns: int = 1):
+    """Spawn the child; yield ``conns`` sockets dialled, as a worker's are, to its port."""
     ctx = multiprocessing.get_context("spawn")
     port_in, port_out = ctx.Pipe(duplex=False)
-    proc = ctx.Process(target=_child, args=(port_out, engine_thread, held), daemon=True)
+    proc = ctx.Process(target=_child, args=(port_out, engine_thread, held, conns), daemon=True)
     proc.start()
     try:
         if not port_in.poll(120):
             raise SystemExit("hop_bench: the child never listened")
-        with connect("127.0.0.1", port_in.recv()) as sock:
-            yield sock
+        port = port_in.recv()
+        with contextlib.ExitStack() as stack:
+            yield [stack.enter_context(connect("127.0.0.1", port)) for _ in range(conns)]
     finally:
         proc.kill()
 
@@ -147,7 +165,7 @@ def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
 
 def _frame_passes(pool: list, frames: int, journal) -> dict:
     acks = threading.Semaphore(0)
-    with _connected(engine_thread=False) as sock:
+    with _connected(engine_thread=False) as (sock,):
         def push(i, version):
             if journal is not None:
                 journal.record(i, version, 0, pool[i % POOL])
@@ -171,31 +189,39 @@ def frame(frames: int, nbytes: int) -> dict:
 def echo(frames: int, nbytes: int) -> dict:
     pool = _payloads(nbytes)
     return {"fresh": _echo_passes(pool, frames, nbytes, held=False),
-            "held": _echo_passes(pool, frames, nbytes, held=True)}
+            "held": _echo_passes(pool, frames, nbytes, held=True),
+            "split": _echo_passes(pool, frames, nbytes, held=True, conns=2)}
 
 
-def _echo_passes(pool: list, frames: int, nbytes: int, held: bool) -> dict:
-    lock, journal = threading.Lock(), RoundJournal(2, 64 << 20)
+def _echo_passes(pool: list, frames: int, nbytes: int, held: bool, conns: int = 1) -> dict:
+    journal = RoundJournal(2, 64 << 20)
     result = np.empty(frames * nbytes, np.uint8)
     to_pull, landed = queue.Queue(), threading.Semaphore(0)
-    with _connected(engine_thread=True, held=held) as sock:
+    with _connected(engine_thread=True, held=held, conns=conns) as socks:
+        # pushes and their acks on the first connection, pull requests and
+        # their replies on the last: one and the same unless ``conns`` is 2
+        lanes = [(sock, threading.Lock()) for sock in socks]
+        (push_sock, push_lock), (pull_sock, pull_lock) = lanes[0], lanes[-1]
+
         def on_header(op, key):
             if op == Op.PUSH:  # the ack: the puller asks for the partition back
                 to_pull.put(key)
             else:
-                recv_into(sock, memoryview(result[key * nbytes:(key + 1) * nbytes]))
+                recv_into(pull_sock, memoryview(result[key * nbytes:(key + 1) * nbytes]))
                 landed.release()
 
         def puller():
             while True:
                 key = to_pull.get()
-                send_message(sock, Message(Op.PULL, key=key, seq=key), lock)
+                send_message(pull_sock, Message(Op.PULL, key=key, seq=key), pull_lock)
 
         def push(i, version):
             journal.record(i, version, 0, pool[i % POOL])
-            send_message(sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]), lock)
+            send_message(push_sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]),
+                         push_lock)
 
-        _on_each_header(sock, on_header)
+        for sock, _ in lanes:
+            _on_each_header(sock, on_header)
         threading.Thread(target=puller, daemon=True).start()
         out = _passes(frames, push, landed)
     if bytes(result[-nbytes:]) != bytes(pool[(frames - 1) % POOL]):
