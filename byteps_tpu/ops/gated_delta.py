@@ -1,0 +1,179 @@
+"""The gated delta rule — linear attention with a data-dependent decay and a
+rank-one correction — in its chunked form.
+
+A head keeps a state ``S`` (d_k × d_v) along the sequence, zero at its start.
+At token t, with ``g_t ≤ 0`` the log of the decay and ``β_t`` the writing
+strength:
+
+    S ← exp(g_t) S;   u_t = β_t (v_t − Sᵀ k_t);   S ← S + k_t u_tᵀ;   o_t = Sᵀ q_t
+
+(:func:`gated_delta_recurrence`: one ``lax.scan`` over the positions, the
+definition; latency-bound, and its backward pass keeps a state a token.)
+
+:func:`chunked_gated_delta_rule` computes the same in chunks of C tokens.
+With γ_i the running sum of g inside a chunk, D_ij = exp(γ_i − γ_j) for
+i ≥ j and S₀ the state entering the chunk, the u of a chunk solve the
+unit-lower-triangular system
+
+    u_i + β_i Σ_{j<i} D_ij (k_i·k_j) u_j = β_i (v_i − exp(γ_i) S₀ᵀ k_i)
+
+so ``U = T (β V) − T (β e^γ K) S₀`` with ``T = (I + A)⁻¹``, which no state
+enters: every chunk's T is found at once (:func:`unit_lower_inverse`, matrix
+products only).  Then ``o_i = exp(γ_i) S₀ᵀ q_i + Σ_{j≤i} D_ij (k_j·q_i) u_j``
+and ``S_C = exp(γ_C) S₀ + Σ_j exp(γ_C − γ_j) k_j u_jᵀ``: one ``lax.scan``
+over the chunks carries S with two small products a step and leaves every
+chunk's entering state and u; the outputs are batched products after it.
+Every exponent is of a non-positive number, so nothing overflows however
+strong the decay.  γ, D, the inverse and S are f32; the products take their
+operands in ``compute_dtype`` and accumulate in f32.
+
+The backward pass is autodiff through that scan: all it runs is matrix
+products, so what it keeps is a chunk's entering state (in the compute dtype)
+and u, nothing a token.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+CHUNK = 64
+
+
+def gated_delta_recurrence(q, k, v, g, beta):
+    """The rule token by token.  q, k (B, H, S, d_k), v (B, H, S, d_v), g and
+    beta (B, H, S); the state is carried in g's dtype.  Returns o (B, H, S, d_v)."""
+    st = g.dtype
+    b, h, _, dk = q.shape
+
+    def token(state, xs):
+        q_t, k_t, v_t, g_t, beta_t = xs  # (B, H, d), (B, H)
+        state = jnp.exp(g_t)[..., None, None] * state
+        u = beta_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + k_t[..., :, None] * u[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    xs = tuple(jnp.moveaxis(x.astype(st), 2, 0) for x in (q, k, v, g, beta))
+    _, o = lax.scan(token, jnp.zeros((b, h, dk, v.shape[-1]), st), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def _inverse_by_blocks(a):
+    """Every round works on whole C × C matrices and picks its blocks by a
+    mask: slicing them out would leave trailing dims of 1, 2, 4 …, which a
+    TPU pads to whole tiles.  The rounds are a ``lax.scan`` over the block
+    size, so one round's masked copy of ``a`` is alive at a time (unrolled,
+    the compiler made all of them first: 1.2 GiB more at 16k tokens)."""
+    c = a.shape[-1]
+    rows = jnp.arange(c)
+
+    def below_diagonal(size):
+        """``a`` where it lies in the block below the diagonal of a 2·size
+        square on the diagonal, 0 elsewhere."""
+        square, lower_half = rows // (2 * size), (rows // size) % 2 == 1
+        return jnp.where((square[:, None] == square[None, :])
+                         & lower_half[:, None] & ~lower_half[None, :], a, 0.0)
+
+    def round_(inv, size):
+        # with T = diag(P⁻¹, Q⁻¹) so far and L the block below: T − T L T
+        return inv - jnp.einsum("...ij,...jk,...kl->...il", inv, below_diagonal(size), inv,
+                                precision=lax.Precision.HIGHEST), None
+
+    # the first round's T is the identity
+    inv = jnp.eye(c, dtype=a.dtype) - below_diagonal(1)
+    if c > 2:
+        inv, _ = lax.scan(round_, inv, 2 ** jnp.arange(1, c.bit_length() - 1))
+    return inv
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a):
+    """``(I + a)⁻¹`` for ``a`` (..., C, C) of which only the strictly lower
+    triangle is read, C a power of two.  Block by block from the diagonal
+    outwards — ``[[P, 0], [L, Q]]⁻¹ = [[P⁻¹, 0], [−Q⁻¹ L P⁻¹, Q⁻¹]]`` — so
+    log₂ C − 1 rounds of two batched products and no row-by-row substitution.  Its
+    backward pass keeps the inverse alone: ``dA = −Tᵀ dT Tᵀ``, strictly lower."""
+    if a.shape[-1] & (a.shape[-1] - 1):
+        raise ValueError(f"unit_lower_inverse needs a power of two, got {a.shape[-1]}")
+    return _inverse_by_blocks(a)
+
+
+def _inverse_fwd(a):
+    t = _inverse_by_blocks(a)
+    return t, t
+
+
+def _inverse_bwd(t, dt):
+    da = -jnp.einsum("...ji,...jk,...lk->...il", t, dt, t, precision=lax.Precision.HIGHEST)
+    rows = jnp.arange(t.shape[-1])
+    return (jnp.where(rows[:, None] > rows[None, :], da, 0.0),)
+
+
+unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype=None):
+    """q, k (B, H_k, S, d_k) — normalised and scaled by the caller —, v
+    (B, H_v, S, d_v), g ≤ 0 and beta (B, H_v, S); each key head serves
+    H_v / H_k value heads in a row (it is never repeated in memory).  Returns
+    o (B, H_v, S, d_v) f32.  A sequence that ``chunk`` does not divide raises:
+    padding would have to be the caller's choice (a padded token writes to
+    the state unless its beta is 0)."""
+    b, hk, s, dk = q.shape
+    hv, dv = v.shape[1], v.shape[-1]
+    if s % chunk:
+        raise ValueError(f"gated delta rule: chunk {chunk} does not divide sequence {s}")
+    if hv % hk:
+        raise ValueError(f"{hv} value heads are no multiple of {hk} key heads")
+    cdt = compute_dtype or q.dtype
+    f32 = jnp.float32
+    n, r = s // chunk, hv // hk
+
+    def product(spec, x, y):
+        return jnp.einsum(spec, x.astype(cdt), y.astype(cdt), preferred_element_type=f32)
+
+    # index letters: b batch, h key head, r value head of it, n chunk, i/j
+    # rows of a chunk, k/v the two head sizes
+    q, k = (x.reshape(b, hk, n, chunk, dk).astype(cdt) for x in (q, k))
+    v = v.reshape(b, hk, r, n, chunk, dv).astype(cdt)
+    beta = beta.astype(f32).reshape(b, hk, r, n, chunk)
+    gamma = jnp.cumsum(g.astype(f32).reshape(b, hk, r, n, chunk), axis=-1)
+    rows = jnp.arange(chunk)
+    seen = rows[:, None] >= rows[None, :]
+    # the mask goes on the exponent too: above the diagonal it is positive
+    # and may overflow, and an inf there would poison the gradient
+    decay = jnp.where(
+        seen, jnp.exp(jnp.where(seen, gamma[..., :, None] - gamma[..., None, :], 0.0)), 0.0)
+    e_gamma = jnp.exp(gamma)
+    to_end = jnp.exp(gamma[..., -1:] - gamma)  # exp(γ_C − γ_j)
+
+    kk = product("bhnik,bhnjk->bhnij", k, k)[:, :, None]
+    a = jnp.where(rows[:, None] > rows[None, :], beta[..., None] * decay * kk, 0.0)
+    t = unit_lower_inverse(a)
+    # T (β e^γ K) and T (β V): the row scales go on T's columns
+    w = product("bhrnij,bhnjk->bhrnik", t * (beta * e_gamma)[..., None, :], k).astype(cdt)
+    u0 = product("bhrnij,bhrnjv->bhrniv", t * beta[..., None, :], v)
+
+    def step(state, xs):
+        w_n, u0_n, k_n, to_end_n, decay_n = xs
+        held = state.astype(cdt)
+        u = u0_n - jnp.einsum("bhrik,bhrkv->bhriv", w_n, held, preferred_element_type=f32)
+        state = decay_n * state + jnp.einsum(
+            "bhik,bhriv->bhrkv", k_n, (to_end_n * u).astype(cdt), preferred_element_type=f32)
+        return state, (held, u.astype(cdt))
+
+    state0 = jnp.zeros((b, hk, r, dk, dv), f32)
+    varying = tuple(jax.typeof(q).vma)  # the carry's type under shard_map: as q varies
+    if varying:
+        state0 = lax.pcast(state0, varying, to="varying")
+    per_chunk = (jnp.moveaxis(w, 3, 0), jnp.moveaxis(u0, 3, 0), jnp.moveaxis(k, 2, 0),
+                 jnp.moveaxis(to_end, 3, 0)[..., None],
+                 jnp.moveaxis(e_gamma[..., -1], 3, 0)[..., None, None])
+    _, (entering, u) = lax.scan(step, state0, per_chunk)
+    entering, u = jnp.moveaxis(entering, 0, 3), jnp.moveaxis(u, 0, 3)
+
+    o = e_gamma[..., None] * product("bhnik,bhrnkv->bhrniv", q, entering)
+    qk = product("bhnik,bhnjk->bhnij", q, k)[:, :, None]
+    o = o + product("bhrnij,bhrnjv->bhrniv", decay * qk, u)
+    return o.reshape(b, hv, s, dv)

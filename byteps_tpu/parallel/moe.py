@@ -127,8 +127,9 @@ def moe_aux_loss(x: jax.Array, router_w: jax.Array, axis_size: int, e_local: int
 
 
 # ---------------------------------------------------------------------------
-# Held experts: sigmoid top-k routing over the whole model's experts, grouped
-# products over the experts this device holds, no capacity and no drop
+# Held experts: top-k routing (sigmoid or softmax scores) over the whole
+# model's experts, grouped products over the experts this device holds, no
+# capacity and no drop
 # ---------------------------------------------------------------------------
 
 #: what one compiled step reports of its routing, in this order
@@ -153,6 +154,19 @@ def sigmoid_topk_route(g: jax.Array, router_w: jax.Array, select_bias: jax.Array
     return ids.astype(jnp.int32), weights
 
 
+def softmax_topk_route(g: jax.Array, router_w: jax.Array, top_k: int) -> tuple:
+    """Softmax routing with the chosen weights renormalised (Qwen3-Next's
+    ``norm_topk_prob``): ``p = softmax(g W)`` over all E experts, the
+    ``top_k`` largest chosen, ``w_i = p_i / Σ_chosen p_j``.  f32 at full
+    precision for :func:`sigmoid_topk_route`'s reason: near-ties decide which
+    experts run.  Returns the chosen ids (T, k) int32 and weights (T, k) f32."""
+    probs = jax.nn.softmax(jnp.dot(
+        g.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32), axis=-1)
+    chosen, ids = lax.top_k(probs, top_k)
+    return ids.astype(jnp.int32), chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
 def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
@@ -165,7 +179,7 @@ def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
     bias-free SwiGLU.
 
     g:        (T, D) tokens, compute dtype
-    ids, weights: (T, k) from :func:`sigmoid_topk_route`, over all
+    ids, weights: (T, k) from a ``*_topk_route`` above, over all
               ``n_experts`` of the model
     w_gate, w_up: (n_held, D, F), w_down: (n_held, F, D) — the experts
               ``[lo, lo + n_held)``, which this device holds

@@ -33,6 +33,11 @@ the entry points a user calls, at the full width of the models the repo lists:
          leaf and its projection on the reference's, the worst leaf named;
          as the state is made, and once more with the experts' choice
          pinned by the selection bias, where no near-tie is left to flip.
+  leg F  the gated-delta MoE family at the published widths of
+         benchmark/configs/qwen3_next_80b_ep32.json (three gated-delta-rule
+         layers and one gated full-attention layer, 16 of 512 softmax-routed
+         experts), as leg E: loss and every leaf's gradient against the
+         float32 plain reference, routers and routed experts judged apart.
 
 Every result line names the platform, device kind, device count and the jax /
 jaxlib / libtpu versions.  Step times are printed as information only: they
@@ -680,24 +685,44 @@ def pin_choice(params: dict, cfg: dict) -> dict:
             if name.endswith("router_bias") else v for name, v in params.items()}
 
 
-def leg_e(dry: bool) -> None:
+#: leg F's limits.  The first gradient does not separate the nearest
+#: precision below at this size (readings on the chip, PR 36,
+#: tools/latent_moe_precision.py --config qwen3_next_80b_ep32, 3 seeds and
+#: this leg's key: the program | the reference with bf16 statistics — loss
+#: <= 3.2e-5 | 3.8e-5; routers and routed experts 0.174-0.198 | 0.197-0.202;
+#: other leaves at most 0.062-0.068 | 0.069-0.083, median 0.053-0.057 |
+#: 0.058-0.067; projection within 0.026 of 1 on both sides), so these stand
+#: above the program with room and against a planted fault: a halved
+#: gradient reads 0.5 in its leaf and in its projection, a lost one 1.
+#: Precision is held by the cell's reference_update_rtol.
+LEG_F_LIMITS = {
+    "as made": {"loss": 1.7e-4, "routed": 0.40, "rest": 0.10, "median": 0.07,
+                "projection": 0.15},
+}
+
+
+def _reference_leg(dry: bool, leg: str, config: str, what, cases, limits: dict) -> None:
+    """One step of ``benchmark/configs/<config>.json`` through
+    ``build_train_step`` with an optimizer that keeps the gradient: loss and
+    every leaf's gradient against the float32 plain reference (the builder's
+    blocked ``plain_loss``), once for each of ``cases`` (name → a function of
+    (params, cfg) that gives the state to run)."""
     import jax
 
     from byteps_tpu.comm.mesh import get_global_mesh
     from byteps_tpu.models.transformer import build_train_step
 
     bench = os.path.join(ROOT, "benchmark")
-    with open(os.path.join(bench, "configs", "joyai_llm_flash_ep32.json")) as f:
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
         cfg = json.load(f)
     if dry:
         cfg.update(cfg["rehearsal"])
     spec = importlib.util.spec_from_file_location(
-        "smoke_joyai_builder", os.path.join(bench, "builders", "joyai_llm_flash.py"))
+        f"smoke_{cfg['builder']}_builder", os.path.join(bench, "builders", f"{cfg['builder']}.py"))
     builder = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(builder)
-    label = (f"leg E (JoyAI-LLM-Flash share: {cfg['num_hidden_layers']} layers + MTP, "
-             f"{cfg['n_routed_experts']} of {cfg['router_width']} experts, vocab "
-             f"{cfg['vocab_size']}, {cfg['batch_per_chip']} x {cfg['max_seq']} tokens)")
+    label = f"leg {leg} ({what(cfg)}, vocab {cfg['vocab_size']}, " \
+            f"{cfg['batch_per_chip']} x {cfg['max_seq']} tokens)"
     if jax.device_count() != 1:
         say(f"{label}: skipped, it is one chip's share and jax has {jax.device_count()}")
         return
@@ -706,13 +731,14 @@ def leg_e(dry: bool) -> None:
     step = build_train_step(builder._model_config(cfg), builder._mesh4(get_global_mesh()),
                             keep, donate=False)
     reference = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))
-    # a token whose 8th and 9th scores nearly tie picks another expert once
-    # its input is rounded to bf16: its whole share of the router's gradient
-    # moves to another column, and an expert gains or loses a whole token.
-    # So the routers and the routed experts read ~ sqrt(2 x the share of
-    # slots that flipped), the other leaves bf16's noise.  With the choice
-    # pinned they read noise too
-    for case, state in (("as made", params), ("choice pinned", pin_choice(params, cfg))):
+    # a token whose last chosen and first unchosen scores nearly tie picks
+    # another expert once its input is rounded to bf16: its whole share of the
+    # router's gradient moves to another column, and an expert gains or loses
+    # a whole token.  So the routers and the routed experts read ~ sqrt(2 x
+    # the share of slots that flipped), the other leaves bf16's noise.  With
+    # the choice pinned they read noise too
+    for case, make in cases.items():
+        state = make(params, cfg)
         t0 = time.perf_counter()
         _, grads, loss = jax.block_until_ready(step(state, keep.init(state), *batch))
         say(f"{label}, {case}: system step in {time.perf_counter() - t0:.1f} s (with "
@@ -728,11 +754,30 @@ def leg_e(dry: bool) -> None:
             f"({read['rest'][0]}), median {read['median']:.2e}; projection on the reference's "
             f"furthest from 1 by {read['projection'][1]:.2e} ({read['projection'][0]}); no "
             f"gradient on either side: {read['zero']}")
-        lim = DRY_RUN_LIMITS if dry else LEG_E_LIMITS[case]
+        lim = DRY_RUN_LIMITS if dry else limits[case]
         if not (off < lim["loss"] and read["rest"][1] < lim["rest"]
                 and read["median"] < lim["median"] and read["routed"][1] < lim["routed"]
                 and read["projection"][1] < lim["projection"]):
             raise SystemExit(f"{label}, {case}: further from the reference than {lim} allow")
+
+
+def leg_e(dry: bool) -> None:
+    _reference_leg(
+        dry, "E", "joyai_llm_flash_ep32",
+        lambda cfg: (f"JoyAI-LLM-Flash share: {cfg['num_hidden_layers']} layers + MTP, "
+                     f"{cfg['n_routed_experts']} of {cfg['router_width']} experts"),
+        {"as made": lambda params, cfg: params, "choice pinned": pin_choice}, LEG_E_LIMITS)
+
+
+def leg_f(dry: bool) -> None:
+    """The gated-delta MoE family.  Its router has no selection bias to pin
+    the choice with, so there is the one case."""
+    _reference_leg(
+        dry, "F", "qwen3_next_80b_ep32",
+        lambda cfg: (f"Qwen3-Next share: {cfg['num_hidden_layers']} layers, one full-attention "
+                     f"layer in {cfg['full_attention_interval']}, {cfg['num_experts']} of "
+                     f"{cfg['router_width']} experts"),
+        {"as made": lambda params, cfg: params}, LEG_F_LIMITS)
 
 
 # ---------------------------------------------------------------------------
@@ -746,7 +791,10 @@ def main() -> int:
         help="pre-flight on the CPU at cut sizes with interpreted kernels; "
              "proves the control flow only, never a chip result",
     )
-    dry = ap.parse_args().cpu_dry_run
+    ap.add_argument("--legs", default="ABCDEF",
+                    help="the legs to run, e.g. F (all by default; B's children start anyway)")
+    args = ap.parse_args()
+    dry, legs = args.cpu_dry_run, set(args.legs.upper())
     if dry:
         _prefix = "[smoke DRY-RUN on the cpu: not a chip result]"
     if not os.path.isdir(NATIVE_DIR):
@@ -796,28 +844,23 @@ def main() -> int:
         say(f"compile cache: {jax.config.jax_compilation_cache_dir} "
             f"({'from JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'placed by bps.init()'})")
 
-        mark = stats.mark()
-        leg_a({"dp": n}, dry)
-        say(f"leg A: {stats.since(mark)}")
-
-        mark = stats.mark()
-        leg_b(dry)
-        say(f"leg B: {stats.since(mark)}; of these, {stats.slices_since(mark)}")
-
-        mark = stats.mark()
-        leg_c_flash(dry)
-        leg_c_flash_latent(dry)
-        leg_c_onebit(dry)
-        say(f"leg C: {stats.since(mark)}")
-
-        if n > 1 and n % 2 == 0:
+        def run(leg: str, *fns, note=lambda mark: "") -> None:
+            if leg not in legs:
+                return
             mark = stats.mark()
-            leg_d(dry)
-            say(f"leg D: {stats.since(mark)}")
+            for fn in fns:
+                fn()
+            say(f"leg {leg}: {stats.since(mark)}{note(mark)}")
 
-        mark = stats.mark()
-        leg_e(dry)
-        say(f"leg E: {stats.since(mark)}")
+        run("A", lambda: leg_a({"dp": n}, dry))
+        run("B", lambda: leg_b(dry),
+            note=lambda mark: f"; of these, {stats.slices_since(mark)}")
+        run("C", lambda: leg_c_flash(dry), lambda: leg_c_flash_latent(dry),
+            lambda: leg_c_onebit(dry))
+        if n > 1 and n % 2 == 0:
+            run("D", lambda: leg_d(dry))
+        run("E", lambda: leg_e(dry))
+        run("F", lambda: leg_f(dry))
 
         check_children(children)
         bps.shutdown()
@@ -826,7 +869,7 @@ def main() -> int:
     codes = [p.returncode for p in children]
     if any(codes):
         raise SystemExit(f"children did not exit cleanly on terminate: {codes}")
-    say(f"all legs passed in {time.perf_counter() - t_start:.0f} s; children "
+    say(f"legs {''.join(sorted(legs))} passed in {time.perf_counter() - t_start:.0f} s; children "
         f"exited with {codes}")
     result = {"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": n}}

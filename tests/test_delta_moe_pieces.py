@@ -1,0 +1,294 @@
+"""The pieces the gated-delta MoE family brought, each against its own
+reference on the CPU: the chunked gated delta rule against the token-by-token
+recurrence (ops/gated_delta.py), the partial rope, the output gate and the
+convolution against hand-written cases, the softmax router
+(parallel/moe.softmax_topk_route), the held share, the cell's blocked
+reference (benchmark/builders/qwen3_next.py) against
+models/delta_moe_reference.py, and the two other families' steps, which the
+PR that brought this one must not have moved.  (The model against its
+reference: tests/test_delta_moe.py.  Two files so that ``--dist loadfile``
+spreads them.)
+"""
+
+import hashlib
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.models import delta_moe as dm
+from byteps_tpu.models import delta_moe_reference as ref
+from byteps_tpu.models import latent_moe as lm
+from byteps_tpu.models import transformer as tfm
+from byteps_tpu.ops import gated_delta as gd
+from byteps_tpu.parallel import moe
+
+from test_delta_moe import _mesh, _state, _worst
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# the chunked rule against the token-by-token recurrence, on its own
+# ---------------------------------------------------------------------------
+
+
+def _rule_inputs(decay, b=2, hk=2, r=2, s=32, dk=8, dv=6, seed=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, hk, s, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, hk, s, dk)))
+    v = jax.random.normal(ks[2], (b, hk * r, s, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, hk * r, s)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, hk * r, s)))
+    return q, k, v, g, beta
+
+
+def _by_token(q, k, v, g, beta):
+    r = v.shape[1] // q.shape[1]
+    return gd.gated_delta_recurrence(jnp.repeat(q, r, 1), jnp.repeat(k, r, 1), v, g, beta)
+
+
+@pytest.mark.parametrize("decay", [1e-4, 1.0, 40.0], ids=["near_one", "middling", "near_zero"])
+@pytest.mark.parametrize("chunk", [4, 16, 32])
+def test_chunked_rule_is_the_recurrence(decay, chunk):
+    """Values and all five gradients; exp(g) from 0.9999 a token (the state
+    hardly fades) to e^-40 (nothing survives a token)."""
+    args = _rule_inputs(decay)
+    want = jax.jit(_by_token)(*args)
+    got = jax.jit(lambda *a: gd.chunked_gated_delta_rule(*a, chunk=chunk))(*args)
+    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()) + 1e-7)
+    weigh = jnp.cos(jnp.arange(want.size, dtype=jnp.float32)).reshape(want.shape)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gd.chunked_gated_delta_rule(*a, chunk=chunk) * weigh),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    wants = jax.jit(jax.grad(lambda *a: jnp.sum(_by_token(*a) * weigh),
+                             argnums=(0, 1, 2, 3, 4)))(*args)
+    for name, got_g, want_g in zip("q k v g beta".split(), grads, wants):
+        assert np.all(np.isfinite(got_g)), name
+        scale = float(jnp.abs(want_g).max())
+        np.testing.assert_allclose(got_g, want_g, atol=1e-4 * scale + 1e-9, err_msg=name)
+
+
+def test_rule_refuses_what_it_would_have_to_pad_or_guess():
+    q, k, v, g, beta = _rule_inputs(1.0, s=12)
+    with pytest.raises(ValueError, match="does not divide"):
+        gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=8)
+    with pytest.raises(ValueError, match="no multiple"):
+        gd.chunked_gated_delta_rule(q, k, v[:, :3], g[:, :3], beta[:, :3], chunk=4)
+
+
+@pytest.mark.parametrize("size", [2, 8, 64])
+def test_unit_lower_inverse_and_its_backward_pass(size):
+    a = 0.3 * jax.random.normal(jax.random.PRNGKey(size), (3, 2, size, size))
+    want = jnp.linalg.inv(jnp.eye(size) + jnp.tril(a, -1))
+    np.testing.assert_allclose(jax.jit(gd.unit_lower_inverse)(a), want,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    weigh = jnp.sin(jnp.arange(a.size, dtype=jnp.float32)).reshape(a.shape)
+    got = jax.jit(jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * weigh)))(a)
+    by_blocks = jax.jit(jax.grad(lambda a: jnp.sum(gd._inverse_by_blocks(a) * weigh)))(a)
+    np.testing.assert_allclose(got, by_blocks, atol=1e-4 * float(jnp.abs(by_blocks).max()))
+    assert not np.any(np.triu(np.asarray(got)))  # what is not read takes no gradient
+    with pytest.raises(ValueError, match="power of two"):
+        gd.unit_lower_inverse(jnp.zeros((6, 6)))
+
+
+# ---------------------------------------------------------------------------
+# hand-written cases
+# ---------------------------------------------------------------------------
+
+
+def test_partial_rope_turns_the_first_dims_in_half_rotation_pairs():
+    """Head of 6, rotary part 4: dims (0, 2) and (1, 3) are the pairs, dims 4
+    and 5 pass; position 0 is left alone."""
+    theta = 100.0
+    x = jnp.arange(1.0, 19.0).reshape(3, 6)
+    got = np.asarray(dm.rope_partial(x, 4, theta))
+    np.testing.assert_allclose(got[0], x[0])
+    np.testing.assert_allclose(got[:, 4:], x[:, 4:])
+    for pos in (1, 2):
+        for i, freq in ((0, 1.0), (1, theta ** -0.5)):
+            a, b = float(x[pos, i]), float(x[pos, i + 2])
+            c, s = np.cos(pos * freq), np.sin(pos * freq)
+            np.testing.assert_allclose(got[pos, i], a * c - b * s, rtol=1e-5)
+            np.testing.assert_allclose(got[pos, i + 2], b * c + a * s, rtol=1e-5)
+    np.testing.assert_allclose(got, ref.rope(x, 4, theta), rtol=1e-5)
+
+
+def test_output_gate_comes_from_the_query_projection():
+    """One token attends to itself alone, so attention's output is its value:
+    the mixer gives W_o (v ⊙ sigmoid(gate)), the gate being the second half
+    of each head's slice of the query projection."""
+    cfg = dm.tiny_delta_moe(full_attention_interval=1, n_layers=1, max_seq=1)
+    params, _, _ = _state(cfg)
+    lp = {k.split(".", 1)[1]: v[0] for k, v in params.items() if k.startswith("full.")}
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 1, cfg.d_model))
+    h = ref._rms(x, lp["mixer_norm"], cfg.norm_eps)[0, 0]
+    gate = jnp.einsum("d,dhk->hk", h, lp["wq"])[:, cfg.head_dim:]
+    v = jnp.repeat(jnp.einsum("d,dhk->hk", h, lp["wv"]), cfg.n_heads // cfg.n_kv_heads, axis=0)
+    want = jnp.einsum("hk,hkd->d", v * jax.nn.sigmoid(gate), lp["wo"])
+    np.testing.assert_allclose(dm._attention_mixer(cfg, x, lp)[0, 0], want, atol=1e-5)
+    np.testing.assert_allclose(ref.attention_mixer(cfg, x, lp)[0, 0], want, atol=1e-5)
+    closed = {**lp, "wq": lp["wq"].at[:, :, cfg.head_dim:].set(0.0)}  # gate 0: half open
+    np.testing.assert_allclose(dm._attention_mixer(cfg, x, closed)[0, 0],
+                               jnp.einsum("hk,hkd->d", 0.5 * v, lp["wo"]), atol=1e-5)
+
+
+def test_causal_conv_reads_the_past_only():
+    x = jnp.arange(1.0, 11.0).reshape(1, 5, 2)
+    taps = jnp.array([[1.0, 0.0], [10.0, 0.0], [100.0, 0.0], [1000.0, 1.0]])
+    got = np.asarray(dm.causal_conv(x, taps))
+    np.testing.assert_allclose(got[0, :, 1], x[0, :, 1])  # the last tap is the present
+    np.testing.assert_allclose(got[0, :, 0], [1000, 3100, 5310, 7531, 9753])
+
+
+def test_softmax_router_against_top_k_of_a_dense_softmax_with_planted_ties():
+    """Tokens 0 and 1 see exactly equal logits for two experts at the edge of
+    the choice: the router picks as ``lax.top_k`` does (the lower index), and
+    the weights are the chosen probabilities renormalised."""
+    t, d, e, k = 24, 8, 16, 3
+    g = jax.random.normal(jax.random.PRNGKey(0), (t, d))
+    w = jax.random.normal(jax.random.PRNGKey(1), (d, e))
+    w = w.at[:, 7].set(w[:, 3])  # experts 3 and 7 tie for every token
+    ids, weights = moe.softmax_topk_route(g, w, k)
+    probs = jax.nn.softmax(jnp.dot(g, w, precision="highest"), axis=-1)
+    want_p, want_ids = jax.lax.top_k(probs, k)
+    np.testing.assert_array_equal(ids, want_ids)
+    assert ids.dtype == jnp.int32 and weights.dtype == jnp.float32
+    np.testing.assert_allclose(weights, want_p / want_p.sum(1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(weights.sum(1), 1.0, rtol=1e-6)
+    both = np.any(np.asarray(ids) == 3, axis=1) & np.any(np.asarray(ids) == 7, axis=1)
+    only_7 = ~np.any(np.asarray(ids) == 3, axis=1) & np.any(np.asarray(ids) == 7, axis=1)
+    assert both.any() and not only_7.any()  # a tie at the edge goes to the lower id
+
+
+# ---------------------------------------------------------------------------
+# the held share
+# ---------------------------------------------------------------------------
+
+
+def _mlp_params(cfg, seed=3):
+    params = dm.init_params(cfg, jax.random.PRNGKey(seed))
+    return {k.split(".", 1)[1]: v[0] for k, v in params.items() if k.startswith("full.")}
+
+
+def test_32_shares_of_16_add_up_to_the_uncut_layer():
+    """The cell's cut at toy widths: a 512-wide router, top-10, in 32 shares
+    of 16 experts.  The shares' routed parts, and the gated shared expert
+    counted once, give what the reference gives with all 512."""
+    base = dict(n_experts=512, top_k=10, full_attention_interval=1, n_layers=1)
+    whole = dm.tiny_delta_moe(experts_held=512, **base)
+    lp = _mlp_params(whole)
+    g = jax.random.normal(jax.random.PRNGKey(9), (40, whole.d_model))
+    want = ref.expert_mlp(whole, g, lp)
+    shared = jax.nn.sigmoid(g @ lp["shared_gate"])[:, None] * dm._swiglu(
+        g, lp["s_gate"], lp["s_up"], lp["s_down"])
+    total, held = shared, 0
+    for lo in range(0, 512, 16):
+        share = dm.tiny_delta_moe(experts_held=16, expert_lo=lo, **base)
+        lp_share = {**lp, **{w: lp[w][lo:lo + 16] for w in ("e_gate", "e_up", "e_down")}}
+        y, stats = dm.expert_mlp(share, g, lp_share)
+        total = total + (y - shared)  # this share's routed part alone
+        held += int(stats[1])
+        assert int(stats[2]) == 0
+        if lo in (0, 496):  # and a share is what the reference gives for that share
+            np.testing.assert_allclose(y, ref.expert_mlp(share, g, lp_share), atol=1e-5)
+    assert held == 40 * 10  # every slot is held by exactly one share
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+def test_no_slot_is_dropped_under_a_skewed_router():
+    """A router that sends every token to the two held experts: eight times
+    the slots the usual chunk holds, none dropped, output = reference."""
+    cfg = dm.tiny_delta_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2,
+                            full_attention_interval=1, n_layers=1)
+    lp = _mlp_params(cfg)
+    g = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))) + 0.1
+    lp["router"] = jnp.zeros_like(lp["router"]).at[:, 4:6].set(1.0)  # positive tokens: 4 and 5 win
+    y, stats = jax.jit(lambda g, lp: dm.expert_mlp(cfg, g, lp))(g, lp)
+    routed, held, dropped, fullest = (int(v) for v in stats)
+    assert routed == held == 128 and dropped == 0 and fullest == 64
+    np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the cell's blocked reference, and the programs this PR must not move
+# ---------------------------------------------------------------------------
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    builder = _load("benchmark/builders/qwen3_next.py", "test_qwen3_next_builder")
+    with open(os.path.join(ROOT, "benchmark/configs/qwen3_next_80b_ep32.json")) as f:
+        cfg = json.load(f)
+    cfg.update(cfg["rehearsal"])
+    # toy widths: the blocking is what is under test, the widths are not
+    cfg.update(hidden_size=32, head_dim=8, num_attention_heads=4, num_key_value_heads=2,
+               linear_key_head_dim=8, linear_value_head_dim=6, linear_num_key_heads=2,
+               linear_num_value_heads=4, moe_intermediate_size=16,
+               shared_expert_intermediate_size=12, num_experts=4, router_width=16,
+               num_experts_per_tok=3, vocab_size=96, max_seq=64, chunk=16)
+    mcfg = builder._model_config(cfg)
+    params, tokens, targets = _state(mcfg, batch=2)
+    return builder, cfg, mcfg, params, (tokens, targets)
+
+
+def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch):
+    builder, cfg, mcfg, params, batch = rehearsal
+    # blocks smaller than the sequence, so that every loop has several turns
+    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2), ("RUN", 16),
+                       ("HEAD_GROUPS", 2)):
+        monkeypatch.setattr(builder, name, size)
+    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    off, leaf = _worst(grads, want_grads)
+    assert off < 1e-4, f"{leaf}: {off:.2e}"
+
+
+@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
+def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
+    builder, cfg, _, params, batch = rehearsal
+    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
+    loss, grads = jax.jit(jax.value_and_grad(
+        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
+    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
+    assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
+
+
+#: sha256 of the StableHLO text of one tiny train step (sgd, batch 2, no
+#: donation, one CPU device), frozen at the parent of the PR that brought the
+#: gated-delta family: what that PR added beside them (a router in
+#: parallel/moe.py, an entry in ops/flash_blocks.json) moved neither program.
+#: A change that means to move one re-freezes its digest here.
+FROZEN_LOWERINGS = {
+    "bert": "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
+    "latent_moe": "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
+}
+
+
+@pytest.mark.parametrize("family", sorted(FROZEN_LOWERINGS))
+def test_the_other_families_steps_lower_as_before(family):
+    if family == "bert":
+        cfg = tfm.tiny_test(causal=False)
+        params = tfm.init_params(cfg)
+    else:
+        cfg = lm.tiny_latent_moe()
+        params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    tx = optax.sgd(1.0)
+    tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
+    text = tfm.build_train_step(cfg, _mesh(), tx, donate=False).lower(
+        params, tx.init(params), tokens, tokens).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == FROZEN_LOWERINGS[family]

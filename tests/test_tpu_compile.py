@@ -93,6 +93,44 @@ def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cac
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile_cache,
+                                                            monkeypatch):
+    """(1, 16, 16384, 256 | 256) causal, forward and both backward kernels,
+    with the blocks ops/flash_blocks.json commits for that sequence (1024 x
+    1024, the 8192 entry's, is over the kernels' VMEM limit at head size 256)."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    assert fa.tuned_blocks(16384) != fa.tuned_blocks(8192)
+    q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=1 / 16).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
+    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+
+
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache):
+    """One sequence of 16 384 tokens, 16 key and 32 value heads of 128,
+    chunks of 64, bf16 operands: the rule and its gradients, with what the
+    backward pass keeps well under what a state a token would take (34 GB)."""
+    from byteps_tpu.ops import gated_delta as gd
+
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    s = 16384
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gd.chunked_gated_delta_rule(q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        shape(1, 16, s, 128), shape(1, 16, s, 128), shape(1, 32, s, 128),
+        shape(1, 32, s, dtype=jnp.float32), shape(1, 32, s, dtype=jnp.float32))
+    assert "while" in compiled.as_text()  # the scan over the 256 chunks
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
 def test_engine_split_compiles_for_the_largest_vgg16_leaf(one_chip, no_compile_cache):
     """VGG-16's first dense kernel, 25 088 × 4096 f32 flat, into the 101
     partitions ``engine.submit`` copies to the host: one program, 101

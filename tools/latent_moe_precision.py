@@ -1,15 +1,16 @@
 #!/usr/bin/env python3
-"""Where the limits on the latent-attention MoE family's precision come from.
+"""Where the limits on the two MoE families' precision come from.
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
+    python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
 
-For each seed, at the size of benchmark/configs/joyai_llm_flash_ep32.json and
-with the benchmark's own state (``make_state`` from the seed as run.py folds
+For each seed, at the size of benchmark/configs/<config>.json (by default
+joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
 it), on the TPU:
 
   f32       the plain reference (the builder's blocked ``plain_loss``): three
             adamw steps, the first step's gradient kept
-  program   ``build_train_step`` over the ``LatentMoEConfig``: one step's
+  program   ``build_train_step`` over the builder's model config: one step's
             gradient (as chip_smoke.py's leg E takes it) and three adamw steps
   stated    the plain reference at the precision the configuration states:
             bf16 operands, f32 norm statistics, router and softmax — a model
@@ -23,7 +24,7 @@ relative distance of the three losses (``reference_rtol``); the parameters
 after three steps as a share of the reference's own update, over all leaves
 and in the worst (``reference_update_rtol``: value, leaf_value); the first
 gradient by leaf (leg E's limits).  One JSON line a seed on stdout, all of
-them in ``chiprun_out/latent_moe_precision.json``.  ``--rehearse`` runs the
+them in ``chiprun_out/<config>_precision.json``.  ``--rehearse`` runs the
 configuration's rehearsal cuts on the CPU: control flow only, never a reading.
 """
 
@@ -41,9 +42,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _builder():
-    path = os.path.join(ROOT, "benchmark", "builders", "joyai_llm_flash.py")
-    spec = importlib.util.spec_from_file_location("precision_joyai_builder", path)
+def _builder(name: str):
+    path = os.path.join(ROOT, "benchmark", "builders", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"precision_{name}_builder", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -52,6 +53,9 @@ def _builder():
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--config", default="joyai_llm_flash_ep32",
+                    help="a configuration of benchmark/configs whose builder's plain_loss "
+                         "takes (compute, statistics)")
     ap.add_argument("--pin", action="store_true",
                     help="with chip_smoke.pin_choice: every token picks the same experts")
     ap.add_argument("--rehearse", action="store_true")
@@ -71,11 +75,11 @@ def main() -> int:
     if (dev.platform == "tpu") == args.rehearse:
         raise SystemExit(f"readings come from the TPU (and --rehearse stays off it); "
                          f"jax found {dev.platform!r}")
-    with open(os.path.join(ROOT, "benchmark", "configs", "joyai_llm_flash_ep32.json")) as f:
+    with open(os.path.join(ROOT, "benchmark", "configs", f"{args.config}.json")) as f:
         cfg = json.load(f)
     if args.rehearse:
         cfg.update(cfg["rehearsal"])
-    builder, steps = _builder(), 3
+    builder, steps = _builder(cfg["builder"]), 3
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     tx = builder.make_optimizer(cfg)
     def plain_steps(loss_fn):
@@ -157,7 +161,7 @@ def main() -> int:
         del start, want_grads, want_params
     if not args.rehearse:
         os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-        name = f"latent_moe_precision{'_pinned' if args.pin else ''}.json"
+        name = f"{args.config}_precision{'_pinned' if args.pin else ''}.json"
         with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
             json.dump(lines, f, indent=1)
     return 0
