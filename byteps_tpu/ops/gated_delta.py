@@ -27,18 +27,40 @@ Every exponent is of a non-positive number, so nothing overflows however
 strong the decay.  γ, D, the inverse and S are f32; the products take their
 operands in ``compute_dtype`` and accumulate in f32.
 
-The backward pass is autodiff through that scan: all it runs is matrix
-products, so what it keeps is a chunk's entering state (in the compute dtype)
-and u, nothing a token.
+Two implementations of that one algorithm, chosen by :func:`_kernel_path`
+from what the code can observe (the default device's platform and the
+shapes): on a TPU, at shapes the kernels tile, the Pallas kernels of
+``ops/gated_delta_kernels.py`` (``gdn_chunk_inverse``, ``gdn_scan_fwd`` and,
+behind a ``custom_vjp``, ``gdn_scan_bwd``: a chunk stays in VMEM from its
+first product to its last, the state in VMEM scratch along the sequence; the
+backward pass keeps T and a chunk's entering state in the compute dtype);
+everywhere else XLA's form below (:func:`_chunked_xla`: the scan over chunks
+and autodiff through it, which keeps a chunk's entering state and u), which
+is also the kernels' oracle beside the recurrence.  Nothing a token is kept
+by either.
 """
 
 from __future__ import annotations
+
+import functools
+import json
+import os
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from byteps_tpu.core.telemetry import counters
+from byteps_tpu.ops.gated_delta_kernels import STACK, gated_delta_kernels
+
 CHUNK = 64
+
+#: chunks a grid step of the three kernels (inverse, forward, backward) by
+#: sequence length: tools/gdn_tune.py's sweep on the chip, as
+#: ops/flash_blocks.json is tools/flash_tune.py's
+_TUNED_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "gdn_blocks.json")
+_DEFAULT_BLOCKS = (8, 8, 8)
 
 
 def gated_delta_recurrence(q, k, v, g, beta):
@@ -113,20 +135,84 @@ def _inverse_bwd(t, dt):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype=None):
+def _platform() -> str:
+    """Platform of the default device (a function so tests can stand in a
+    TPU without mocking devices)."""
+    return jax.devices()[0].platform
+
+
+def _kernel_path(chunk: int, dk: int, dv: int, interpret: bool) -> bool:
+    """THE decision between the Pallas kernels (True) and XLA's chunked form
+    (False), from the platform and the shapes alone.  The kernels tile a
+    chunk of 64 or 128 tokens (whole sublane tiles of any compute dtype, and
+    a whole number of them stacks to the MXU's 128 rows) and head sizes of
+    whole lane tiles; off a TPU they run only where the caller asked for the
+    Pallas interpreter.  Everything else is XLA's."""
+    tiles = chunk in (STACK // 2, STACK) and dk % 128 == 0 and dv % 128 == 0
+    return tiles and (interpret or _platform() == "tpu")
+
+
+@functools.cache
+def _tuned_table() -> dict:
+    try:
+        with open(_TUNED_PATH) as f:
+            return {int(s): tuple(b) for s, b in json.load(f)["blocks"].items()}
+    except (OSError, ValueError, KeyError, TypeError, AttributeError):
+        return {}
+
+
+def tuned_blocks(n_chunks: int, chunk: int, blocks: Optional[Sequence[int]] = None) -> tuple:
+    """Chunks a grid step for (inverse, forward, backward): the caller's, or
+    the table's entry for this sequence, or the default — each brought down
+    to a power of two that divides the sequence's chunks, the first kept a
+    whole number of stacks."""
+    wanted = tuple(blocks or _tuned_table().get(n_chunks * chunk, _DEFAULT_BLOCKS))
+    per_stack = max(STACK // chunk, 1)
+    if n_chunks % per_stack:
+        raise ValueError(f"gated delta kernels: {n_chunks} chunks of {chunk} are no whole "
+                         f"number of stacks of {STACK} rows")
+
+    def fit(nb, least):
+        while nb > least and n_chunks % nb:
+            nb //= 2
+        return max(nb, least)
+
+    return (fit(wanted[0], per_stack), fit(wanted[1], 1), fit(wanted[2], 1))
+
+
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype=None,
+                             interpret: bool = False, blocks: Optional[Sequence[int]] = None):
     """q, k (B, H_k, S, d_k) — normalised and scaled by the caller —, v
     (B, H_v, S, d_v), g ≤ 0 and beta (B, H_v, S); each key head serves
     H_v / H_k value heads in a row (it is never repeated in memory).  Returns
     o (B, H_v, S, d_v) f32.  A sequence that ``chunk`` does not divide raises:
     padding would have to be the caller's choice (a padded token writes to
-    the state unless its beta is 0)."""
-    b, hk, s, dk = q.shape
-    hv, dv = v.shape[1], v.shape[-1]
+    the state unless its beta is 0).  Which implementation runs is
+    :func:`_kernel_path`'s call; ``interpret`` asks for the Pallas interpreter
+    off a TPU (the CPU tests), ``blocks`` overrides the tuned chunks a grid
+    step of the kernels."""
+    s, hk, hv = q.shape[2], q.shape[1], v.shape[1]
     if s % chunk:
         raise ValueError(f"gated delta rule: chunk {chunk} does not divide sequence {s}")
     if hv % hk:
         raise ValueError(f"{hv} value heads are no multiple of {hk} key heads")
     cdt = compute_dtype or q.dtype
+    # decided once a traced call; bps.get_robustness_counters() shows which
+    if not _kernel_path(chunk, q.shape[-1], v.shape[-1], interpret):
+        counters().bump("gdn_xla_traces")
+        return _chunked_xla(q, k, v, g, beta, chunk, cdt)
+    counters().bump("gdn_kernel_traces")
+    f32 = jnp.float32
+    return gated_delta_kernels(
+        q.astype(cdt), k.astype(cdt), v.astype(cdt), g.astype(f32), beta.astype(f32), chunk,
+        tuned_blocks(s // chunk, chunk, blocks), interpret)
+
+
+def _chunked_xla(q, k, v, g, beta, chunk, cdt):
+    """XLA's form: every chunk's T at once, one ``lax.scan`` over the chunks,
+    the outputs batched products after it; the backward pass is autodiff."""
+    b, hk, s, dk = q.shape
+    hv, dv = v.shape[1], v.shape[-1]
     f32 = jnp.float32
     n, r = s // chunk, hv // hk
 

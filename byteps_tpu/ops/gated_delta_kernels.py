@@ -1,0 +1,393 @@
+"""The chunked gated delta rule as Pallas TPU kernels: everything a chunk
+needs lives in VMEM from its first product to its last.
+
+Three kernels, the mathematics of ``ops/gated_delta.py``'s docstring:
+
+``gdn_chunk_inverse`` — inside a chunk, no state.  Grid (key heads, blocks of
+chunks), every cell independent.  From k, g and β read once: γ, the decay
+matrix, ``K Kᵀ`` (shared by the value heads of a key head), ``A`` and
+``T = (I + A)⁻¹`` by the block rounds of ``gated_delta._inverse_by_blocks``,
+their products at f32 accuracy on matrices that stay in VMEM.  Chunks are
+stacked to the MXU's 128 rows (two chunks of 64 form one block-diagonal
+128 × 128 matrix: a round costs the array the same and serves both).  Writes T.
+
+``gdn_scan_fwd`` — along the sequence.  Grid (key heads [parallel], blocks of
+chunks [sequential]); the d_k × d_v f32 states of a key head's value heads
+stay in VMEM scratch across the chunk axis.  A chunk:
+``U = T β (V − e^γ ∘ K S)``, ``o = e^γ ∘ (Q S) + (D ∘ Q Kᵀ) U``,
+``S ← e^{γ_C} S + Kᵀ (e^{γ_C − γ} ∘ U)``.  Writes o and, for the backward
+pass, the chunk's entering state in the compute dtype.
+
+``gdn_scan_bwd`` — the same walk from the last chunk to the first carrying
+dS, a chunk's forward rebuilt from T and its entering state; the inverse's
+rule ``dA = −Tᵀ dT Tᵀ`` at f32 accuracy; gradients for q, k (summed over
+their value heads), v, g and β.
+
+γ, D, T and the states are f32; every other product takes its operands in the
+compute dtype and accumulates in f32.  Per-token scalars arrive as rows
+(chunk positions along the lanes) and are turned into columns by a masked
+sum: no transposes, no lane-offset slices but the one that splits a stacked T.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from byteps_tpu.ops.flash_attention import _vma_union as _vma
+
+#: the kernels' names: a trace files their time under these (none starts
+#: with ``flash_``: the benchmark's readers take such calls for flash kernels)
+INVERSE_KERNEL, FWD_KERNEL, BWD_KERNEL = "gdn_chunk_inverse", "gdn_scan_fwd", "gdn_scan_bwd"
+
+#: rows of the MXU: chunks are stacked to this many for the inverse
+STACK = 128
+
+_F32 = jnp.float32
+_EXACT = lax.Precision.HIGHEST
+
+
+def _dot(x, y, contract, precision=None):
+    """x · y over the given pair of dims, f32 out."""
+    return lax.dot_general(x, y, ((contract[:1], contract[1:]), ((), ())),
+                           precision=precision, preferred_element_type=_F32)
+
+
+_NN, _NT, _TN = (1, 0), (1, 1), (0, 0)
+
+
+def _iotas(n):
+    return (lax.broadcasted_iota(jnp.int32, (n, n), 0), lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _column(row, eye):
+    """(1, n) → (n, 1): a masked sum, no transpose."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(column, eye):
+    return jnp.sum(jnp.where(eye, column, 0.0), axis=0, keepdims=True)
+
+
+def _decays(g_row, seen, eye):
+    """From a chunk's g as a row: γ as a column, and the decay matrix D (0
+    above the diagonal; the mask goes on the exponent too)."""
+    gam_col = jnp.sum(jnp.where(seen, g_row, 0.0), axis=1, keepdims=True)
+    exponent = jnp.where(seen, gam_col - _row(gam_col, eye), 0.0)
+    return gam_col, jnp.where(seen, jnp.exp(exponent), 0.0)
+
+
+def _total(x):
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)  # (1, 1)
+
+
+# ---------------------------------------------------------------------------
+# inside a chunk: T = (I + A)⁻¹
+# ---------------------------------------------------------------------------
+
+
+def _inverse_kernel(chunk, w, groups, r):
+    """``w`` rows hold ``w // chunk`` chunks: one block-diagonal w × w matrix."""
+    from jax.experimental import pallas as pl
+
+    per = w // chunk
+
+    def kernel(k_ref, g_ref, b_ref, t_ref):
+        rows, cols = _iotas(w)
+        same = (rows // chunk) == (cols // chunk)
+        seen, strict, eye = same & (rows >= cols), same & (rows > cols), rows == cols
+        # the round that fills (i, j): the highest bit in which i and j differ
+        # — the block of that size below the diagonal of the square twice it
+        differ = rows ^ cols
+        level = jnp.where(strict, sum((differ >= 2 ** s).astype(jnp.int32)
+                                      for s in range(1, chunk.bit_length() - 1)), -1)
+
+        def group(i, carry):
+            k = k_ref[0, pl.ds(pl.multiple_of(i * w, w), w), :]
+            kk = _dot(k, k, _NT)
+            for h in range(r):
+                _, decay = _decays(g_ref[h, i], seen, eye)
+                a = _column(b_ref[h, i], eye) * decay * kk
+                # with T = diag(P⁻¹, Q⁻¹) so far and L the block below: T − T L T
+                inv = jnp.where(eye, 1.0, 0.0) - jnp.where(level == 0, a, 0.0)
+                for s in range(1, chunk.bit_length() - 1):
+                    inv = inv - _dot(_dot(inv, jnp.where(level == s, a, 0.0), _NN, _EXACT),
+                                     inv, _NN, _EXACT)
+                for j in range(per):
+                    t_ref[h, i * per + j] = inv[j * chunk:(j + 1) * chunk,
+                                                j * chunk:(j + 1) * chunk]
+            return carry
+
+        lax.fori_loop(0, groups, group, 0)
+
+    return kernel
+
+
+def _chunk_inverse(k, g, beta, chunk, nb, interpret):
+    """k (BH_k, S, d_k), g and beta (BH_v, S) f32 → T (BH_v, N, C, C) f32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bhk, s, dk = k.shape
+    bhv = g.shape[0]
+    r, n = bhv // bhk, s // chunk
+    w = max(chunk, STACK)
+    groups = nb * chunk // w
+    stacked = lambda x: x.reshape(bhv, s // w, 1, w)  # noqa: E731
+    scalars = pl.BlockSpec((r, groups, 1, w), lambda i, j: (i, j, 0, 0))
+    return pl.pallas_call(
+        _inverse_kernel(chunk, w, groups, r),
+        out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), _F32, vma=_vma(k, g, beta)),
+        grid=(bhk, n // nb),
+        in_specs=[pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i, j, 0)), scalars, scalars],
+        out_specs=pl.BlockSpec((r, nb, chunk, chunk), lambda i, j: (i, j, 0, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=INVERSE_KERNEL,
+    )(k, stacked(g), stacked(beta))
+
+
+# ---------------------------------------------------------------------------
+# along the sequence, forward
+# ---------------------------------------------------------------------------
+
+
+def _chunk_forward(q, k, v, t, g_row, b_row, state, masks, cdt):
+    """One chunk of one value head from its entering state (f32).  Returns
+    what the backward pass shares with it."""
+    seen, eye = masks
+    gam_col, decay = _decays(g_row, seen, eye)
+    gam_end = jnp.sum(g_row, axis=1, keepdims=True)  # (1, 1)
+    e_gamma, to_end = jnp.exp(gam_col), jnp.exp(gam_end - gam_col)
+    held = state.astype(cdt)
+    ks, qs = _dot(k, held, _NN), _dot(q, held, _NN)
+    rhs = (v.astype(_F32) - e_gamma * ks).astype(cdt)
+    t_beta = (t * b_row).astype(cdt)  # the row scales go on T's columns
+    u = _dot(t_beta, rhs, _NN)
+    return dict(decay=decay, e_gamma=e_gamma, to_end=to_end, last=jnp.exp(gam_end), held=held,
+                ks=ks, qs=qs, rhs=rhs, t_beta=t_beta, u=u, u_cdt=u.astype(cdt),
+                u_to_end=(to_end * u).astype(cdt))
+
+
+def _fwd_kernel(chunk, nb, r, cdt, save):
+    from jax.experimental import pallas as pl
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, o_ref, *rest):
+        entering_ref, state = rest if save else (None, rest[0])
+        rows, cols = _iotas(chunk)
+        masks = (rows >= cols, rows == cols)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _start():
+            state[...] = jnp.zeros_like(state)
+
+        def one(c, carry):
+            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            q, k = q_ref[0, at, :], k_ref[0, at, :]
+            qk = _dot(q, k, _NT)
+            for h in range(r):
+                f = _chunk_forward(q, k, v_ref[h, at, :], t_ref[h, c], g_ref[h, c], b_ref[h, c],
+                                   state[h], masks, cdt)
+                if save:
+                    entering_ref[h, c] = f["held"]
+                o_ref[h, at, :] = f["e_gamma"] * f["qs"] + _dot(
+                    (f["decay"] * qk).astype(cdt), f["u_cdt"], _NN)
+                state[h] = f["last"] * state[h] + _dot(k, f["u_to_end"], _TN)
+            return carry
+
+        lax.fori_loop(0, nb, one, 0)
+
+    return kernel
+
+
+def _specs(r, nb, chunk, dk, dv, index):
+    """Block specs of a (key heads, blocks of chunks) grid: q | k, v | o | do,
+    g | β, T, the entering states."""
+    from jax.experimental import pallas as pl
+
+    return dict(
+        qk=pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i, index(j), 0)),
+        v=pl.BlockSpec((r, nb * chunk, dv), lambda i, j: (i, index(j), 0)),
+        scalar=pl.BlockSpec((r, nb, 1, chunk), lambda i, j: (i, index(j), 0, 0)),
+        t=pl.BlockSpec((r, nb, chunk, chunk), lambda i, j: (i, index(j), 0, 0)),
+        state=pl.BlockSpec((r, nb, dk, dv), lambda i, j: (i, index(j), 0, 0)),
+    )
+
+
+def _scan_forward(q, k, v, g, beta, t, chunk, nb, save, interpret):
+    """→ o (BH_v, S, d_v) f32 and, if ``save``, every chunk's entering state
+    (BH_v, N, d_k, d_v) in the compute dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bhk, s, dk = q.shape
+    bhv, _, dv = v.shape
+    r, n, cdt = bhv // bhk, s // chunk, q.dtype
+    vma = _vma(q, k, v, g, beta, t)
+    spec = _specs(r, nb, chunk, dk, dv, lambda j: j)
+    by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
+    out_shape = [jax.ShapeDtypeStruct((bhv, s, dv), _F32, vma=vma)]
+    out_specs = [spec["v"]]
+    if save:
+        out_shape.append(jax.ShapeDtypeStruct((bhv, n, dk, dv), cdt, vma=vma))
+        out_specs.append(spec["state"])
+    out = pl.pallas_call(
+        _fwd_kernel(chunk, nb, r, cdt, save),
+        out_shape=out_shape,
+        grid=(bhk, n // nb),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"], spec["t"]],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=FWD_KERNEL,
+    )(q, k, v, by_chunk(g), by_chunk(beta), t)
+    return tuple(out) if save else (out[0], None)
+
+
+# ---------------------------------------------------------------------------
+# along the sequence, backward
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernel(chunk, nb, r, cdt):
+    from jax.experimental import pallas as pl
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, entering_ref, do_ref,
+               dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate):
+        rows, cols = _iotas(chunk)
+        seen, strict, eye = rows >= cols, rows > cols, rows == cols
+        is_last = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+
+        @pl.when(pl.program_id(1) == 0)
+        def _start():
+            dstate[...] = jnp.zeros_like(dstate)
+
+        def one(step, carry):
+            c = nb - 1 - step
+            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            q, k = q_ref[0, at, :], k_ref[0, at, :]
+            qk, kk = _dot(q, k, _NT), _dot(k, k, _NT)
+            dq = jnp.zeros(q.shape, _F32)
+            dk = jnp.zeros(k.shape, _F32)
+            dqk = jnp.zeros((chunk, chunk), _F32)  # summed over the value heads
+            dkk = jnp.zeros((chunk, chunk), _F32)
+            for h in range(r):
+                t, b_row = t_ref[h, c], b_ref[h, c]
+                held = entering_ref[h, c]
+                f = _chunk_forward(q, k, v_ref[h, at, :], t, g_ref[h, c], b_row,
+                                   held, (seen, eye), cdt)
+                decay, e_gamma, to_end = f["decay"], f["e_gamma"], f["to_end"]
+                b_col = _column(b_row, eye)
+                do = do_ref[h, at, :]
+                leaving = dstate[h]  # the cotangent of the state this chunk leaves
+                leaving_cdt = leaving.astype(cdt)
+
+                k_dstate = _dot(k, leaving_cdt, _NN)  # cotangent of e^{γ_C − γ} ∘ U
+                du = (_dot((decay * qk).astype(cdt), do.astype(cdt), _TN)
+                      + to_end * k_dstate).astype(cdt)
+                dp = _dot(do.astype(cdt), f["u_cdt"], _NT)
+                dqs = (e_gamma * do).astype(cdt)
+                drhs = _dot(f["t_beta"], du, _TN)
+                dt_beta = _dot(du, f["rhs"], _NT)
+                dks = (-e_gamma * drhs).astype(cdt)
+                dq = dq + _dot(dqs, held, _NT)
+                dk = dk + _dot(dks, held, _NT) + _dot(f["u_to_end"], leaving_cdt, _NT)
+                dv_ref[h, at, :] = drhs.astype(dv_ref.dtype)
+                dstate[h] = f["last"] * leaving + _dot(q, dqs, _TN) + _dot(k, dks, _TN)
+
+                # T = (I + A)⁻¹: dA = −Tᵀ dT Tᵀ, strictly lower
+                da = jnp.where(strict, -_dot(_dot(t, dt_beta * b_row, _TN, _EXACT),
+                                             t, _NT, _EXACT), 0.0)
+                dqk = dqk + decay * dp
+                dkk = dkk + da * b_col * decay
+                # the exponents γ_i − γ_j of D, through P = D ∘ Q Kᵀ and A = β D ∘ K Kᵀ
+                dexp = (dp * qk + da * b_col * kk) * decay
+                d_e_gamma = jnp.sum(do * f["qs"] - drhs * f["ks"], axis=1, keepdims=True)
+                d_to_end = jnp.sum(f["u"] * k_dstate, axis=1, keepdims=True) * to_end
+                d_last = _total(held.astype(_F32) * leaving) * f["last"]
+                dgam = (jnp.sum(dexp, axis=1, keepdims=True) + d_e_gamma * e_gamma - d_to_end
+                        - _column(jnp.sum(dexp, axis=0, keepdims=True), eye))
+                dgam = dgam + jnp.where(is_last, _total(d_to_end) + d_last, 0.0)
+                # γ is g's running sum: dg_m = Σ_{i ≥ m} dγ_i, as a row
+                dg_ref[h, c] = jnp.sum(jnp.where(seen, dgam, 0.0), axis=0, keepdims=True)
+                db_ref[h, c] = (jnp.sum(dt_beta * t, axis=0, keepdims=True)
+                                + _row(jnp.sum(da * decay * kk, axis=1, keepdims=True), eye))
+            dqk, dkk = dqk.astype(cdt), dkk.astype(cdt)
+            dq_ref[0, at, :] = (dq + _dot(dqk, k, _NN)).astype(dq_ref.dtype)
+            dk_ref[0, at, :] = (dk + _dot(dqk, q, _TN) + _dot(dkk, k, _NN)
+                                + _dot(dkk, k, _TN)).astype(dk_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, nb, one, 0)
+
+    return kernel
+
+
+def _scan_backward(q, k, v, g, beta, t, entering, do, chunk, nb, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bhk, s, dk = q.shape
+    bhv, _, dv = v.shape
+    r, n, cdt = bhv // bhk, s // chunk, q.dtype
+    vma = _vma(q, k, v, g, beta, t, entering, do)
+    last = n // nb - 1
+    spec = _specs(r, nb, chunk, dk, dv, lambda j: last - j)  # from the last block to the first
+    by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
+    shape = lambda x, dtype=None: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, dtype or x.dtype, vma=vma)
+    dq, dk_, dv_, dg, db = pl.pallas_call(
+        _bwd_kernel(chunk, nb, r, cdt),
+        out_shape=[shape(q), shape(k), shape(v), shape(by_chunk(g), _F32),
+                   shape(by_chunk(beta), _F32)],
+        grid=(bhk, n // nb),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"], spec["t"],
+                  spec["state"], spec["v"]],
+        out_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"]],
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=BWD_KERNEL,
+    )(q, k, v, by_chunk(g), by_chunk(beta), t, entering, do)
+    return dq, dk_, dv_, dg.reshape(g.shape).astype(g.dtype), db.reshape(beta.shape).astype(
+        beta.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the rule with its backward pass
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _rule(q, k, v, g, beta, chunk, blocks, interpret):
+    t = _chunk_inverse(k, g, beta, chunk, blocks[0], interpret)
+    return _scan_forward(q, k, v, g, beta, t, chunk, blocks[1], False, interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, blocks, interpret):
+    t = _chunk_inverse(k, g, beta, chunk, blocks[0], interpret)
+    o, entering = _scan_forward(q, k, v, g, beta, t, chunk, blocks[1], True, interpret)
+    return o, (q, k, v, g, beta, t, entering)
+
+
+def _rule_bwd(chunk, blocks, interpret, res, do):
+    return _scan_backward(*res, do, chunk, blocks[2], interpret)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def gated_delta_kernels(q, k, v, g, beta, chunk, blocks, interpret=False):
+    """q, k (B, H_k, S, d_k) and v (B, H_v, S, d_v) in the compute dtype, g
+    and beta (B, H_v, S) f32; ``blocks`` = chunks a grid step of the three
+    kernels (each divides S / chunk; the first is a whole number of stacks).
+    Returns o (B, H_v, S, d_v) f32.  Differentiable in all five."""
+    b, hk, s, dk = q.shape
+    hv, dv = v.shape[1], v.shape[-1]
+    o = _rule(q.reshape(b * hk, s, dk), k.reshape(b * hk, s, dk), v.reshape(b * hv, s, dv),
+              g.reshape(b * hv, s), beta.reshape(b * hv, s), chunk, tuple(blocks), interpret)
+    return o.reshape(b, hv, s, dv)

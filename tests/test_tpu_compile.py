@@ -9,6 +9,7 @@ process may load libtpu, and every xdist worker imports every test file.
 All such tests stay in THIS file, so one worker holds the library.
 """
 
+import functools
 import importlib
 
 import jax
@@ -110,24 +111,44 @@ def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile
         assert kernel in text, f"{kernel} is not in the compiled program"
 
 
-def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache):
+@pytest.mark.parametrize("implementation", ["kernels", "xla"])
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch,
+                                                         implementation):
     """One sequence of 16 384 tokens, 16 key and 32 value heads of 128,
-    chunks of 64, bf16 operands: the rule and its gradients, with what the
-    backward pass keeps well under what a state a token would take (34 GB)."""
+    chunks of 64, bf16 operands: the rule and its five gradients, with what
+    the backward pass keeps well under what a state a token would take (34
+    GB).  ``kernels`` is the path a TPU takes at these shapes — the lowered
+    module holds the three Pallas kernels, forward and backward —, ``xla``
+    the chunked form that stays their oracle (tools/gdn_tune.py times it)."""
     from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import gated_delta_kernels as gk
 
+    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         dims, dtype, sharding=one_chip)
     s = 16384
+    assert gd._kernel_path(gd.CHUNK, 128, 128, interpret=False)
+    rule = (functools.partial(gd.chunked_gated_delta_rule, compute_dtype=jnp.bfloat16)
+            if implementation == "kernels"
+            else lambda *a: gd._chunked_xla(*a, gd.CHUNK, jnp.bfloat16))
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(gd.chunked_gated_delta_rule(q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+        return jnp.sum(rule(q, k, v, g, beta))
 
-    compiled = _compile(
-        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-        shape(1, 16, s, 128), shape(1, 16, s, 128), shape(1, 32, s, 128),
-        shape(1, 32, s, dtype=jnp.float32), shape(1, 32, s, dtype=jnp.float32))
-    assert "while" in compiled.as_text()  # the scan over the 256 chunks
+    args = (shape(1, 16, s, 128), shape(1, 16, s, 128), shape(1, 32, s, 128),
+            shape(1, 32, s, dtype=jnp.float32), shape(1, 32, s, dtype=jnp.float32))
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+    text = compiled.as_text()
+    if implementation == "kernels":
+        for kernel in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL):
+            assert kernel in text, f"{kernel} is not in the compiled program"
+        assert text.count("tpu_custom_call") >= 3
+        assert "while" not in text  # no scan over the 256 chunks is left to XLA
+        # the forward alone: the inverse and the walk, no residual written
+        forward = _compile(loss, *args).as_text()
+        assert gk.FWD_KERNEL in forward and gk.BWD_KERNEL not in forward
+    else:
+        assert "while" in text and "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
 
 

@@ -1,0 +1,132 @@
+"""On-chip sizing of the gated delta rule: XLA's chunked form against the
+Pallas kernels (ops/gated_delta.py, ops/gated_delta_kernels.py), the chunks a
+grid step of each kernel swept.
+
+Times forward + all five gradients of ``sum(o**2)`` at the shape one layer of
+``qwen3_next_80b_ep32`` runs — (1, 16 | 32, 16384, 128 | 128), chunk 64, bf16 —
+for XLA's form and for the kernels, and each kernel alone at every block
+size.  Prints one JSON line; the best blocks go to ops/gdn_blocks.json (with
+the sweep's shape and milliseconds in ``meta``), where
+``gated_delta.tuned_blocks`` finds them.  Refuses to run off a TPU: a CPU
+timing says nothing of Mosaic.
+
+    python tools/gdn_tune.py [--shape 1,16,32,16384,128,128] [--blocks 4,8,16]
+
+``--rehearse`` is the CPU pre-flight of the same control flow (the Pallas
+interpreter at a toy length, nothing written): counts and control flow only.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", default="1,16,32,16384,128,128",
+                    help="batch, key heads, value heads, sequence, d_k, d_v")
+    ap.add_argument("--chunk", type=int, default=64)
+    ap.add_argument("--blocks", default="4,8,16", help="chunks a grid step to try")
+    ap.add_argument("--steps", type=int, default=10)
+    ap.add_argument("--out", default="", help="where to write the winners "
+                    "(default: ops/gdn_blocks.json)")
+    ap.add_argument("--no-write", action="store_true")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU pre-flight: the interpreter, one step, nothing written")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import gated_delta_kernels as gk
+
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.rehearse:
+        print("not on a TPU — refusing (kernel timings need real Mosaic); "
+              "--rehearse runs the control flow on the CPU", file=sys.stderr)
+        return 2
+    interpret = device.platform != "tpu"
+    b, hk, hv, s, dk, dv = (int(x) for x in args.shape.split(","))
+    chunk, steps = args.chunk, 1 if args.rehearse else args.steps
+    cdt = jnp.float32 if interpret else jnp.bfloat16  # the CPU has no bf16 batched products
+    n = s // chunk
+    sizes = [nb for nb in (int(x) for x in args.blocks.split(",")) if n % nb == 0]
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = (unit(jax.random.normal(ks[0], (b, hk, s, dk))) * dk ** -0.5).astype(cdt)
+    k = unit(jax.random.normal(ks[1], (b, hk, s, dk))).astype(cdt)
+    v = jax.random.normal(ks[2], (b, hv, s, dv)).astype(cdt)
+    g = -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, hv, s)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, hv, s)))
+
+    def ms(fn, *xs):
+        f = jax.jit(fn)
+        jax.block_until_ready(f(*xs))
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = f(*xs)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / steps * 1e3, 3)
+
+    def whole(rule):
+        # all five gradients: with fewer XLA drops what only the others need
+        return jax.value_and_grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=(0, 1, 2, 3, 4))
+
+    # each kernel alone, on the flat layout the kernels take
+    flat = (q.reshape(b * hk, s, dk), k.reshape(b * hk, s, dk), v.reshape(b * hv, s, dv),
+            g.reshape(b * hv, s), beta.reshape(b * hv, s))
+    t = jax.jit(lambda k, g, beta: gk._chunk_inverse(k, g, beta, chunk, sizes[0], interpret))(
+        flat[1], flat[3], flat[4])
+    _, entering = jax.jit(lambda *a: gk._scan_forward(*a, chunk, sizes[0], True, interpret))(
+        *flat, t)
+    do = jax.random.normal(ks[5], (b * hv, s, dv))
+    by_kernel = {gk.INVERSE_KERNEL: {}, gk.FWD_KERNEL: {}, gk.BWD_KERNEL: {}}
+    for nb in sizes:
+        if nb % max(gk.STACK // chunk, 1) == 0:
+            by_kernel[gk.INVERSE_KERNEL][nb] = ms(
+                lambda k, g, beta: gk._chunk_inverse(k, g, beta, chunk, nb, interpret),
+                flat[1], flat[3], flat[4])
+        by_kernel[gk.FWD_KERNEL][nb] = ms(
+            lambda *a: gk._scan_forward(*a, chunk, nb, True, interpret), *flat, t)
+        by_kernel[gk.BWD_KERNEL][nb] = ms(
+            lambda *a: gk._scan_backward(*a, chunk, nb, interpret), *flat, t, entering, do)
+    best = tuple(min(by_kernel[name], key=by_kernel[name].get)
+                 for name in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL))
+
+    xla_ms = ms(whole(lambda *a: gd._chunked_xla(*a, chunk, cdt)), q, k, v, g, beta)
+    kernels_ms = ms(whole(lambda *a: gd.chunked_gated_delta_rule(
+        *a, chunk=chunk, compute_dtype=cdt, interpret=interpret, blocks=best)), q, k, v, g, beta)
+
+    line = {
+        "device": f"{device.platform}:{device.device_kind}", "rehearsal": args.rehearse,
+        "shape": [b, hk, hv, s, dk, dv], "chunk": chunk, "dtype": jnp.dtype(cdt).name,
+        "what": "forward + five gradients of sum(o**2), ms a call; by_kernel: one kernel alone",
+        "xla_ms": xla_ms, "kernels_ms": kernels_ms, "blocks": list(best),
+        "by_kernel": {name: {str(nb): t_ms for nb, t_ms in times.items()}
+                      for name, times in by_kernel.items()},
+    }
+    if not (args.no_write or args.rehearse):
+        path = args.out or gd._TUNED_PATH  # producer and consumer share one location
+        try:
+            with open(path) as f:
+                doc = json.load(f)
+        except (OSError, ValueError):
+            doc = {}
+        doc.setdefault("blocks", {})[str(s)] = list(best)
+        doc.setdefault("meta", {})[str(s)] = {k_: line[k_] for k_ in (
+            "shape", "chunk", "dtype", "xla_ms", "kernels_ms", "by_kernel")}
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(doc, f, indent=1)
+    print(json.dumps(line))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
