@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from byteps_tpu.core.telemetry import counters, metrics
+from byteps_tpu.core.tracing import span
 
 from byteps_tpu.common.config import Config
 from byteps_tpu.common.hashing import assign_server
@@ -519,6 +520,27 @@ class _NativeServerConn:
             self.dead = True
 
 
+#: (server rank, op, job) → the two histograms an attempt's reply observes,
+#: kept at hand: ``rpc_round_trip_seconds{server[, job]}`` and
+#: ``rpc_reply_seconds{op, server}``
+_RPC_HISTS: Dict[tuple, tuple] = {}
+
+
+def _rpc_hists(sid: str, op: str, job_id: int) -> tuple:
+    held = _RPC_HISTS.get((sid, op, job_id))
+    if held is None:
+        labels = {"server": sid}
+        if job_id:
+            # per-tenant slice (docs/async.md); job 0 keeps the
+            # pre-tenancy series shape
+            labels["job"] = str(job_id)
+        held = _RPC_HISTS[sid, op, job_id] = (
+            metrics().held("rpc_round_trip_seconds", labels),
+            metrics().held("rpc_reply_seconds", {"op": op, "server": sid}),
+        )
+    return held
+
+
 class _AsyncRpc:
     """One async RPC's deadline + retry + revival state
     (:meth:`PSClient._async_rpc` has the contract).
@@ -533,7 +555,7 @@ class _AsyncRpc:
     __slots__ = (
         "client", "make_msg", "key", "deliver", "on_error", "sink",
         "abort_check", "precheck", "heal", "chase", "attempt", "healed",
-        "chases", "backoff", "sid",
+        "chases", "backoff", "sid", "op", "t_sent_done",
     )
 
     def __init__(self, client, make_msg, key, deliver, on_error, sink,
@@ -554,6 +576,10 @@ class _AsyncRpc:
         self.healed = False
         self.chases = 0
         self.backoff = Backoff(base=client.cfg.rpc_backoff_s, cap=2.0)
+        #: the newest attempt's request op, and the moment its send_msg
+        #: returned (0.0 while it has not: a reply can beat the return)
+        self.op = "?"
+        self.t_sent_done = 0.0
         # server-rank label for the robustness counters: a single sick
         # server must be visible in the per-peer dimension, not just as
         # an anonymous bump of the flat total (docs/observability.md)
@@ -669,8 +695,15 @@ class _AsyncRpc:
         seq = sc.alloc_seq(on_reply, sink=self.sink)
         if seq < 0:
             return  # on_reply(None) already fired → retry scheduled
+        msg = self.make_msg(seq)
+        self.op = msg.op.name
+        self.t_sent_done = 0.0
         try:
-            sc.send_msg(self.make_msg(seq))
+            # lane lock + frame + sendmsg: the part of this attempt that is
+            # the sender's (under bps.stage.PUSH | PULL on a stage thread)
+            with span("rpc.send." + self.op):
+                sc.send_msg(msg)
+            self.t_sent_done = time.monotonic()
             # every frame that actually hit the wire (incl. retries):
             # what fusion lowers (tests/test_fusion.py compares it)
             counters().bump("wire_rpc")
@@ -698,16 +731,15 @@ class _AsyncRpc:
             # Labeled per server RANK like the rpc_* counters:
             # the flight recorder's straggler rule needs "whose
             # p99 ran away THIS step", which a flat family can
-            # never answer (docs/observability.md)
-            rpc_labels = {"server": self.sid}
-            if client.cfg.job_id:
-                # per-tenant slice (docs/async.md); job 0 keeps
-                # the pre-tenancy series shape
-                rpc_labels["job"] = str(client.cfg.job_id)
-            metrics().observe(
-                "rpc_round_trip_seconds", time.monotonic() - t_sent,
-                labels=rpc_labels,
-            )
+            # never answer (docs/observability.md).  Beside it the
+            # attempt's second half by op: send returned → reply, the
+            # server's and the wire's part (a PUSH's 4 MB sendmsg is in
+            # the round trip and not in this)
+            now = time.monotonic()
+            round_trip, reply = _rpc_hists(
+                self.sid, self.op, client.cfg.job_id)
+            round_trip.observe(now - t_sent)
+            reply.observe(now - (self.t_sent_done or t_sent))
             self.deliver(msg)
 
 
@@ -2241,9 +2273,11 @@ class PSClient:
         """One receiver per lane; all lanes demux into the shared seq-keyed
         callback table (responses come back on the lane that carried the
         request — the server answers per-connection)."""
+        index = {"push": itertools.count(), "pull": itertools.count()}
         threads = [
             threading.Thread(target=self._recv_loop,
-                             args=(sc, sock, name == "pull"), daemon=True)
+                             args=(sc, sock, name == "pull"), daemon=True,
+                             name=f"bps-recv-{name}-{next(index[name])}")
             for sock, _, name in sc.lanes()
         ]
         sc.recv_thread = threads[0]
@@ -2251,104 +2285,29 @@ class PSClient:
             t.start()
 
     def _recv_loop(self, sc: _ServerConn, sock, pull_lane: bool = False) -> None:
-        from byteps_tpu.comm.transport import (
-            LosslessError,
-            checksum_conn_limit,
-            frame_checksum,
-            recv_header_ex,
-            recv_into,
-        )
-        from byteps_tpu.compression.lossless import decompress_frame
+        from byteps_tpu.comm.transport import checksum_conn_limit, recv_header_ex
 
         ck_limit = checksum_conn_limit()
         # this lane's receive buffers, for replies no sink takes (a codec's
         # merged round, a fused reply): whoever consumes one releases it
         pool = FramePool()
+        # one frame's service on this thread, parsed header → the callback's
+        # return (payload receive, integrity, the engine's _proceed), and of
+        # that the payload's receive alone.  A one-socket link files under
+        # "push", as lane_bulk_bytes does
+        lane = "pull" if pull_lane else "push"
+        span_name = "recv.frame." + lane
+        receiving = metrics().held("recv_payload_seconds", {"lane": lane})
         try:
             while not self._stop.is_set():
                 try:
-                    (op, status, flags, seq, key, cmd, version, length,
-                     trace, crc, lossless) = recv_header_ex(sock)
-                    # the callback is popped only AFTER the payload is
-                    # fully received: dying mid-payload must leave it for
-                    # mark_dead's cb(None) drain, never lose it
-                    sink = sc.peek_sink(seq)
-                    _count_bulk(pull_lane, "rx", op, length)
-                    # a lossless frame's `length` is the container size,
-                    # never the caller's raw-sized sink — decode lands in
-                    # an owned payload (no zero-copy for compressed frames)
-                    zero_copied = (not lossless and sink is not None
-                                   and length == len(sink))
-                    if zero_copied:
-                        # zero-copy: the aggregated payload lands directly
-                        # in the caller's result buffer — no intermediate
-                        # bytes object, no frombuffer+slice copy
-                        recv_into(sock, sink)
-                        payload = _ZERO_COPIED
-                    else:
-                        payload = (
-                            recv_payload(sock, length, pool)
-                            if length else b""
-                        )
-                    if crc is not None and frame_checksum(
-                        trace, sink if zero_copied else payload
-                    ) != crc:
-                        # end-to-end wire integrity (docs/robustness.md):
-                        # a corrupted reply is DROPPED before the seq
-                        # demux — the callback stays registered so the
-                        # deadline/retry machinery re-fetches (a zero-
-                        # copy sink holding garbage is harmless: the
-                        # retried response overwrites it before the
-                        # caller ever wakes).  Repeated mismatches
-                        # poison the connection → revival re-dials.
-                        release_frame(payload)  # dropped unread
-                        fails = sc.note_checksum_fail()
-                        counters().bump("wire_checksum_fail", labels={
-                            "side": "client",
-                            "op": getattr(op, "name", str(op)),
-                            "server": getattr(sc, "server_label", "?"),
-                        })
-                        if ck_limit and fails >= ck_limit:
-                            counters().bump("wire_checksum_conn_drop")
-                            return
-                        continue
-                    if lossless:
-                        # decompress AFTER integrity passes; a corrupt
-                        # container is dropped exactly like a CRC
-                        # mismatch — the callback stays registered, the
-                        # deadline/retry machinery re-fetches, and
-                        # repeated failures poison the connection
-                        container = payload
-                        try:
-                            payload = decompress_frame(container, op=op)
-                        except LosslessError:
-                            fails = sc.note_checksum_fail()
-                            counters().bump("wire_lossless_fail", labels={
-                                "side": "client",
-                                "op": getattr(op, "name", str(op)),
-                                "server": getattr(sc, "server_label", "?"),
-                            })
-                            if ck_limit and fails >= ck_limit:
-                                counters().bump("wire_checksum_conn_drop")
-                                return
-                            continue
-                        finally:
-                            release_frame(container)  # decoded or dropped
-                    if zero_copied:
-                        self.zero_copy_pulls += 1
+                    header = recv_header_ex(sock)
                 except (ConnectionError, OSError):
                     return
-                cb = sc.pop_cb(seq)
-                if cb is not None:
-                    cb(
-                        Message(
-                            op, key=key, payload=payload, seq=seq, cmd=cmd,
-                            version=version, status=status, flags=flags,
-                        )
-                    )
-                # this thread now blocks in the next header's recv: what
-                # it still names would live until a frame arrives
-                cb = payload = sink = None
+                with span(span_name):
+                    if not self._recv_frame(sc, sock, pull_lane, header, pool,
+                                            ck_limit, receiving):
+                        return
         finally:
             # one lane dying poisons the whole striped connection: close
             # every lane (wakes the sibling receivers).  The DRAIN — fail
@@ -2362,6 +2321,102 @@ class PSClient:
                         cb(None)
                     except Exception:  # noqa: BLE001
                         pass
+
+    def _recv_frame(self, sc: _ServerConn, sock, pull_lane: bool, header,
+                    pool: FramePool, ck_limit: int, receiving) -> bool:
+        """Receive one reply frame's payload and hand it to its callback;
+        False when the lane must close (its connection died, or it passed
+        the checksum-mismatch limit)."""
+        from byteps_tpu.comm.transport import (
+            LosslessError,
+            frame_checksum,
+            recv_into,
+        )
+        from byteps_tpu.compression.lossless import decompress_frame
+
+        (op, status, flags, seq, key, cmd, version, length,
+         trace, crc, lossless) = header
+        try:
+            # the callback is popped only AFTER the payload is
+            # fully received: dying mid-payload must leave it for
+            # mark_dead's cb(None) drain, never lose it
+            sink = sc.peek_sink(seq)
+            _count_bulk(pull_lane, "rx", op, length)
+            # a lossless frame's `length` is the container size,
+            # never the caller's raw-sized sink — decode lands in
+            # an owned payload (no zero-copy for compressed frames)
+            zero_copied = (not lossless and sink is not None
+                           and length == len(sink))
+            t0 = time.perf_counter()
+            if zero_copied:
+                # zero-copy: the aggregated payload lands directly
+                # in the caller's result buffer — no intermediate
+                # bytes object, no frombuffer+slice copy
+                recv_into(sock, sink)
+                payload = _ZERO_COPIED
+            else:
+                payload = (
+                    recv_payload(sock, length, pool)
+                    if length else b""
+                )
+            receiving.observe(time.perf_counter() - t0)
+            if crc is not None and frame_checksum(
+                trace, sink if zero_copied else payload
+            ) != crc:
+                # end-to-end wire integrity (docs/robustness.md):
+                # a corrupted reply is DROPPED before the seq
+                # demux — the callback stays registered so the
+                # deadline/retry machinery re-fetches (a zero-
+                # copy sink holding garbage is harmless: the
+                # retried response overwrites it before the
+                # caller ever wakes).  Repeated mismatches
+                # poison the connection → revival re-dials.
+                release_frame(payload)  # dropped unread
+                fails = sc.note_checksum_fail()
+                counters().bump("wire_checksum_fail", labels={
+                    "side": "client",
+                    "op": getattr(op, "name", str(op)),
+                    "server": getattr(sc, "server_label", "?"),
+                })
+                if ck_limit and fails >= ck_limit:
+                    counters().bump("wire_checksum_conn_drop")
+                    return False
+                return True
+            if lossless:
+                # decompress AFTER integrity passes; a corrupt
+                # container is dropped exactly like a CRC
+                # mismatch — the callback stays registered, the
+                # deadline/retry machinery re-fetches, and
+                # repeated failures poison the connection
+                container = payload
+                try:
+                    payload = decompress_frame(container, op=op)
+                except LosslessError:
+                    fails = sc.note_checksum_fail()
+                    counters().bump("wire_lossless_fail", labels={
+                        "side": "client",
+                        "op": getattr(op, "name", str(op)),
+                        "server": getattr(sc, "server_label", "?"),
+                    })
+                    if ck_limit and fails >= ck_limit:
+                        counters().bump("wire_checksum_conn_drop")
+                        return False
+                    return True
+                finally:
+                    release_frame(container)  # decoded or dropped
+            if zero_copied:
+                self.zero_copy_pulls += 1
+        except (ConnectionError, OSError):
+            return False
+        cb = sc.pop_cb(seq)
+        if cb is not None:
+            cb(
+                Message(
+                    op, key=key, payload=payload, seq=seq, cmd=cmd,
+                    version=version, status=status, flags=flags,
+                )
+            )
+        return True
 
     # --- key routing -----------------------------------------------------
 

@@ -319,6 +319,66 @@ class _Fuser:
         self._engine.queues[QueueType.PUSH].add_task(group)
 
 
+class _StageIdle:
+    """What a stage thread does between two services, by cause.
+
+    ``ScheduledQueue.get_task`` runs each of its waits in this context:
+    one wait is one observation in ``stage_idle_seconds{stage, why}`` with
+    ``why`` = ``starved`` | ``gated`` (the poll ticks of an idle process
+    too) and one profiler annotation ``bpswait.stage.<STAGE>.<why>``.  The
+    prefix is not ``bps.``: a wait is no phase, and a reader that names
+    device idle time after the phases open would find one under every gap.
+    What is left between two services is ``why="dequeue"``: taking the
+    queue's lock against the threads that add to it, the scan for an
+    eligible task, the loop's own bookkeeping, and however long the thread
+    waited for the GIL in them (:meth:`rest`, on the clock readings the
+    service spans made).  So a stage thread's service + starved + gated +
+    dequeue IS its wall clock, over any window."""
+
+    WAITS = ("starved", "gated")
+
+    def __init__(self, stage: str) -> None:
+        from jax.profiler import TraceAnnotation
+
+        from byteps_tpu.core.telemetry import metrics
+
+        self._annotate = TraceAnnotation
+        self._names = {w: f"bpswait.stage.{stage}.{w}" for w in self.WAITS}
+        self._hists = {
+            w: metrics().held("stage_idle_seconds", {"stage": stage, "why": w})
+            for w in self.WAITS + ("dequeue",)
+        }
+        for hist in self._hists.values():
+            hist.get()  # a cause that never occurs reads 0, not nothing
+        self._why = self.WAITS[0]
+        self._waited = 0.0  # in waits since ``mark``
+        self.mark = time.perf_counter()  # up to here all time is accounted
+
+    def __call__(self, why: str) -> "_StageIdle":
+        self._why = why
+        return self
+
+    def __enter__(self) -> None:
+        self._annotation = self._annotate(self._names[self._why])
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        self._hists[self._why].observe(dur)
+        self._waited += dur
+        return False
+
+    def rest(self, until: float, resume: float) -> None:
+        """The thread was free from ``mark`` to ``until`` and is accounted
+        for again from ``resume`` (a service's start and end; one moment
+        for a poll tick): what of that was no wait is the dequeue."""
+        self._hists["dequeue"].observe(until - self.mark - self._waited)
+        self._waited = 0.0
+        self.mark = resume
+
+
 class _StripedStage:
     """N parallel queues for a stage, striped by key.
 
@@ -374,6 +434,15 @@ class PipelineEngine:
     #: a ctx initialized under a previous engine must re-run its
     #: init-push barrier, exactly like an elastic server resize
     _epoch_counter = itertools.count()
+    #: a stage thread's longest wait for its queue before it looks at the
+    #: stop flag again (and the coarsest the idle account's edges get)
+    _POLL_S = 0.2
+    #: a stage thread reads its CPU clock around one task in this many:
+    #: time.thread_time() is a system call, 6 µs on the chip's host where a
+    #: monotonic reading is 0.09 (PERF.md §6 PR 38), and a step has 648 tasks.
+    #: Not a divisor of a model's partition count, so the sample walks
+    #: through a step's tasks
+    _CPU_EVERY = 16
 
     def __init__(self, cfg: Config, ps_client, telemetry=None, tracer=None,
                  flightrec=None) -> None:
@@ -418,6 +487,14 @@ class PipelineEngine:
             {cfg.job_id: cfg.job_credit_bytes}
             if cfg.job_credit_bytes > 0 else None
         )
+        from byteps_tpu.core.telemetry import metrics as _metrics
+
+        # stage_dwell_seconds{stage} at hand: _proceed observes one a task
+        # and stage, on whichever thread finished it
+        self._dwelt = {
+            qt: _metrics().held("stage_dwell_seconds", {"stage": qt.name})
+            for qt in QueueType
+        }
         self.queues: Dict[QueueType, Any] = {
             QueueType.COPYD2H: ScheduledQueue(QueueType.COPYD2H, discipline=disc),
             QueueType.COMPRESS: _StripedStage(QueueType.COMPRESS, pool),
@@ -505,8 +582,6 @@ class PipelineEngine:
         self._tuning_lock = threading.Lock()
         # the fleet fusion-threshold gauge feeds the tuner's walk (the
         # scheduler reads the aggregate's max as the fleet value)
-        from byteps_tpu.core.telemetry import metrics as _metrics
-
         _metrics().gauge_set("fusion_threshold_bytes", cfg.fusion_threshold)
         add_listener = getattr(ps_client, "add_tuning_listener", None)
         if add_listener is not None:
@@ -567,31 +642,57 @@ class PipelineEngine:
         from byteps_tpu.core.telemetry import metrics
         from byteps_tpu.core.tracing import span
 
-        # values at hand only: nothing is formatted per task on this path
-        stage = {"stage": q.queue_type.name}
-        span_name = "stage." + q.queue_type.name
+        # values at hand only: nothing is formatted, and no label set is
+        # hashed, per task on this path.  The thread's wall clock is its
+        # service (span_seconds of "stage.<STAGE>") and what it does between
+        # two services, by cause (docs/observability.md "Reading a hop
+        # thread by thread")
+        name = q.queue_type.name
+        span_name = "stage." + name
+        waited = metrics().held("stage_wait_seconds", {"stage": name})
+        # one service in _CPU_EVERY on two clocks, the thread's CPU time and
+        # the wall: their ratio is the share of a service the thread runs
+        on_cpu, on_wall = (
+            metrics().held("stage_sample_seconds", {"stage": name, "clock": c})
+            for c in ("cpu", "wall")
+        )
+        idle = _StageIdle(name)
+        served = 0
         while not self._stop.is_set():
-            task = q.get_task(timeout=0.2)
+            task = q.get_task(timeout=self._POLL_S, waiting=idle)
             if task is None:
+                now = time.perf_counter()
+                idle.rest(now, now)
                 continue
             # enqueue → picked up: what the task WAITED for this stage;
             # stage_dwell_seconds (enqueue → done, _proceed) holds the
             # wait and the service together
             if task.enqueued_at:
-                metrics().observe(
-                    "stage_wait_seconds",
-                    time.monotonic() - task.enqueued_at, labels=stage,
-                )
+                waited.observe(time.monotonic() - task.enqueued_at)
+            served += 1
+            sampled = served % self._CPU_EVERY == 0
+            # the task's SERVICE on this stage thread; the wall it does not
+            # spend on the CPU it is blocked (a full socket buffer, a device
+            # transfer, a lane's send lock) or waiting for the GIL
+            serving = span(span_name, parent=self._task_trace(task),
+                           key=task.key, tensor=task.tensor_name)
             try:
-                # the task's SERVICE on this stage thread
-                with span(span_name, parent=self._task_trace(task),
-                          key=task.key, tensor=task.tensor_name):
-                    fn(task)
+                with serving:
+                    if sampled:
+                        cpu0 = time.thread_time()
+                    try:
+                        fn(task)
+                    finally:
+                        if sampled:
+                            on_cpu.observe(time.thread_time() - cpu0)
             except Exception as e:  # surface errors on the handle
                 self._fail_task(
                     task, q.queue_type, repr(e),
                     degraded=isinstance(e, (ConnectionError, OSError)),
                 )
+            if sampled:
+                on_wall.observe(serving.ended - serving.started)
+            idle.rest(serving.started, serving.ended)
 
     # --- submission ------------------------------------------------------
 
@@ -1247,20 +1348,14 @@ class PipelineEngine:
                     job.name, task.key, finished.name, task.version,
                     float(np.linalg.norm(buf.astype(np.float64))), float(buf[0]),
                 )
-        if self.tracer is not None:
+        if self.tracer is not None and self.tracer.enabled:
             self.tracer.record(
                 job.name, finished.name, job.t0, time.time() - job.t0, job.version
             )
         # per-stage dwell, ENQUEUE→done: the latency dimension the flat
         # counters never had — p99 here names the stalled stage directly
         if task.enqueued_at:
-            from byteps_tpu.core.telemetry import metrics
-
-            metrics().observe(
-                "stage_dwell_seconds",
-                time.monotonic() - task.enqueued_at,
-                labels={"stage": finished.name},
-            )
+            self._dwelt[finished].observe(time.monotonic() - task.enqueued_at)
         if task.trace_id and self._traced():
             from byteps_tpu.core.tracing import span_args
 

@@ -402,10 +402,13 @@ class FlightRecorder:
                 del self._uploads[:-8]
         from byteps_tpu.common import logging as bpslog
 
+        # the evidence rides the line: the bundle stays on the machine that
+        # wrote it, a run's stderr comes back (one line a dumped bundle, so
+        # under the bundle's own rate limit)
         bpslog.warning(
-            "flight trigger %s fired at step %d — diagnostic bundle: %s "
+            "flight trigger %s fired at step %d: %s — diagnostic bundle: %s "
             "(inspect with: python tools/bps_doctor.py %s)",
-            rule, rec["step"], path, path,
+            rule, rec["step"], json.dumps(evidence, default=str), path, path,
         )
 
     def dump_bundle(self, rule: str, evidence: dict, rec: dict) -> str:
@@ -468,14 +471,31 @@ class FlightRecorder:
 
 
 def _rule_slow_step(rec: "FlightRecorder", r: dict) -> Optional[dict]:
-    """This step took ≫ the rolling median of recent steps."""
+    """This step took ≫ the rolling median of recent steps.  The evidence
+    names what a reader asks first: the stage whose dwell grew most against
+    the step before, and the retries and expired deadlines of this step."""
     dur = r.get("dur")
     if dur is None or len(rec._durs) < rec.min_history:
         return None
     med = statistics.median(rec._durs)
-    if med > 0 and dur > med * rec.slow_factor:
-        return {"dur": dur, "median": round(med, 6), "factor": rec.slow_factor}
-    return None
+    if not (med > 0 and dur > med * rec.slow_factor):
+        return None
+    evidence = {"dur": dur, "median": round(med, 6), "factor": rec.slow_factor}
+    with rec._lock:
+        earlier = [x for x in rec._ring if x is not r and x.get("k") == "step"]
+    before = earlier[-1].get("stages", {}) if earlier else {}
+    grown = {
+        stage: v["s"] - before.get(stage, {}).get("s", 0.0)
+        for stage, v in (r.get("stages") or {}).items()
+    }
+    if grown:
+        stage = max(grown, key=grown.get)
+        evidence["stage"] = stage
+        evidence["stage_dwell_grew_s"] = round(grown[stage], 6)
+    events = r.get("events") or {}
+    for name in ("rpc_retry", "rpc_deadline_expired"):
+        evidence[name] = events.get(name, 0)
+    return evidence
 
 
 def _rule_straggler_server(rec: "FlightRecorder", r: dict) -> Optional[dict]:

@@ -40,7 +40,7 @@ from __future__ import annotations
 import bisect
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 from byteps_tpu.common.types import QueueType, TensorTableEntry
 from byteps_tpu.core.ready_table import ReadyTable
@@ -189,13 +189,22 @@ class ScheduledQueue:
                 return False
         return True
 
-    def get_task(self, timeout: Optional[float] = None) -> Optional[TensorTableEntry]:
+    def get_task(self, timeout: Optional[float] = None,
+                 waiting: Optional[Callable[[str], ContextManager]] = None,
+                 ) -> Optional[TensorTableEntry]:
         """Pop the highest-priority eligible task of the least-served
         tenant; None on timeout.
 
         Re-waits the remaining budget after a wakeup that finds nothing
         eligible (spurious, or an ineligible add) — a single wait would
-        hand the stage loop a None and cost a full idle poll tick."""
+        hand the stage loop a None and cost a full idle poll tick.
+
+        ``waiting(why)`` gives the context every wait runs in, so the
+        stage loop can time and name it while the queue keeps no metric:
+        ``"starved"`` — the lanes held no task (the stage before has
+        delivered nothing) — or ``"gated"`` — they held tasks and none was
+        eligible (the round gate, ``scheduling_credit``, a tenant's
+        ``job_credit_bytes``)."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._cv:
             while True:
@@ -207,7 +216,12 @@ class ScheduledQueue:
                 )
                 if remaining is not None and remaining <= 0:
                     return None
-                self._cv.wait(remaining)
+                if waiting is None:
+                    self._cv.wait(remaining)
+                    continue
+                held = any(ln.tasks for ln in self._lanes.values())
+                with waiting("gated" if held else "starved"):
+                    self._cv.wait(remaining)
 
     def _pop_eligible(self) -> Optional[TensorTableEntry]:
         # tenants in virtual-time order (ties broken by lane insertion

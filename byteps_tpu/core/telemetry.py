@@ -24,6 +24,7 @@ Every metric name must appear in the docs/observability.md catalog —
 
 from __future__ import annotations
 
+import bisect
 import json
 import threading
 import time
@@ -311,8 +312,6 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, value: float) -> None:
-        import bisect
-
         i = bisect.bisect_left(self.bounds, value)
         with self._lock:
             self._counts[i] += 1
@@ -385,6 +384,46 @@ def _state_percentile(bounds, counts, q: float) -> float:
     return bounds[-1] if bounds else 0.0
 
 
+class HeldHistogram:
+    """One histogram of a constant label set, kept at hand by a per-task
+    path (a stage loop, a span name, an RPC's op): ``observe`` is a bisect
+    and the histogram's own lock, where ``MetricsRegistry.observe`` sorts
+    and hashes the labels under the registry's lock every time.  The
+    histogram is made at the first observation, and made again after a
+    ``MetricsRegistry.reset()`` (which moves ``generation`` on): nothing is
+    observed into a histogram the registry has dropped."""
+
+    __slots__ = ("_registry", "_name", "_labels", "_buckets", "_hist",
+                 "_generation")
+
+    def __init__(self, registry: "MetricsRegistry", name: str,
+                 labels: Optional[Dict[str, str]],
+                 buckets: Tuple[float, ...]) -> None:
+        self._registry = registry
+        self._name = name
+        self._labels = labels
+        self._buckets = buckets
+        self._hist: Optional[Histogram] = None
+        self._generation = -1
+
+    def get(self) -> Histogram:
+        """The live histogram (made now where it is not there yet: a
+        family that must read 0 rather than be absent calls this once)."""
+        generation = self._registry.generation
+        if self._generation != generation:
+            # the histogram first: a racing observe then finds either the
+            # old pair or fetches the same new histogram itself
+            self._hist = self._registry.histogram(
+                self._name, self._labels, self._buckets)
+            self._generation = generation
+        return self._hist
+
+    def observe(self, value: float) -> None:
+        if self._generation != self._registry.generation:
+            self.get()
+        self._hist.observe(value)
+
+
 class MetricsRegistry:
     """Counters + gauges + histograms behind one scrape surface.
 
@@ -400,6 +439,8 @@ class MetricsRegistry:
         self.counters = counter_store if counter_store is not None else RobustnessCounters()
         self._lock = threading.Lock()
         self._hists: Dict[Tuple[str, tuple], Histogram] = {}
+        #: moved on by reset(): a HeldHistogram re-makes its histogram then
+        self.generation = 0
         # Histogram providers (docs/observability.md) — the twin of the
         # counter-provider seam in RobustnessCounters: zero-arg callables
         # returning raw-bucket records, merged into every read surface.
@@ -440,6 +481,12 @@ class MetricsRegistry:
                 labels: Optional[Dict[str, str]] = None,
                 buckets: Tuple[float, ...] = LATENCY_BUCKETS) -> None:
         self.histogram(name, labels, buckets).observe(value)
+
+    def held(self, name: str, labels: Optional[Dict[str, str]] = None,
+             buckets: Tuple[float, ...] = LATENCY_BUCKETS) -> HeldHistogram:
+        """A handle on one (name, label set) for a caller that observes it
+        per task: keep the handle, not the labels (:class:`HeldHistogram`)."""
+        return HeldHistogram(self, name, labels, buckets)
 
     def gauge_set(self, name: str, value: float,
                   labels: Optional[Dict[str, str]] = None) -> None:
@@ -588,6 +635,7 @@ class MetricsRegistry:
     def reset(self) -> None:
         with self._lock:
             self._hists.clear()
+            self.generation += 1
             self._gauges.clear()
             self._gauge_fns.clear()
             providers = list(self._hist_providers.items())

@@ -44,7 +44,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation, start_trace, stop_trace
 
-from byteps_tpu.core.telemetry import metrics
+from byteps_tpu.core.telemetry import HeldHistogram, metrics
 
 _id_rng = random.SystemRandom()
 
@@ -247,6 +247,11 @@ def get_process_tracer() -> Optional[Tracer]:
 
 # --- phases on the profiler's clock ---------------------------------------
 
+#: ``span_seconds{name}`` of every span name this process has closed, kept at
+#: hand: a span's exit is one bisect, not a label set sorted and hashed under
+#: the registry's lock (the handles outlive ``metrics().reset()``)
+_span_hists: Dict[str, HeldHistogram] = {}
+
 #: the innermost open :class:`span` of each thread, as ``(trace id, span
 #: id)``; set only while the process tracer records spans
 _current = threading.local()
@@ -273,8 +278,8 @@ class span:
       submits (``engine.submit`` reads :func:`current_span`).
     """
 
-    __slots__ = ("name", "attrs", "parent", "_annotation", "_t0", "_wall",
-                 "_ids", "_outer")
+    __slots__ = ("name", "attrs", "parent", "started", "ended", "_annotation",
+                 "_wall", "_ids", "_outer")
 
     def __init__(self, name: str, parent: Optional[Tuple[int, int]] = None,
                  **attrs) -> None:
@@ -293,13 +298,21 @@ class span:
             self._ids = _current.ids = (trace_id, new_trace_id())
             self._wall = time.time()
         self._annotation.__enter__()
-        self._t0 = time.perf_counter()
+        self.started = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur = time.perf_counter() - self._t0
+        # ``started`` and ``ended`` bound what span_seconds holds, on
+        # time.perf_counter(): a caller that accounts for the time BETWEEN
+        # its spans (a stage loop) reads them instead of a clock of its own
+        self.ended = time.perf_counter()
+        dur = self.ended - self.started
         self._annotation.__exit__(*exc)
-        metrics().observe("span_seconds", dur, labels={"name": self.name})
+        hist = _span_hists.get(self.name)
+        if hist is None:
+            hist = _span_hists[self.name] = metrics().held(
+                "span_seconds", {"name": self.name})
+        hist.observe(dur)
         if self._ids is not None:
             _current.ids = self._outer
             parent = self.parent or self._outer

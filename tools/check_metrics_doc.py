@@ -7,6 +7,7 @@ Scans ``byteps_tpu/`` for metric registrations/bumps —
     counters().set_floor("name" ...)
     metrics().observe("name" ...)      # histograms
     metrics().histogram("name" ...)
+    metrics().held("name" ...)         # a histogram kept at hand
     metrics().gauge_set("name" ...) / gauge_fn("name" ...)
 
 — and fails (exit 1) listing any name absent from the metric catalog in
@@ -35,7 +36,7 @@ import sys
 #: call sites that mint a metric name; the first string literal argument
 #: is the name.  ``_bump`` covers the chaos van's counter helper.
 _CALL_RE = re.compile(
-    r"\.(?:bump|_bump|set_floor|observe|histogram|gauge_set|gauge_fn)\(\s*"
+    r"\.(?:bump|_bump|set_floor|observe|histogram|held|gauge_set|gauge_fn)\(\s*"
     r"(f?)\"([A-Za-z0-9_{}]+)\"",
 )
 
