@@ -125,11 +125,13 @@ _conn_counter_lock = threading.Lock()
 #: disjoint.
 _ctrl_conn_counter = itertools.count(1 << 16)
 
-#: and a third for the PULL lanes a worker dials (a tcp link to a server is
-#: a push lane and a pull lane, ps_client._ServerConn): a link's push lanes
-#: keep the indices its one socket had, so a seeded schedule aimed at a
-#: server's pushes still hits them whatever else the link dials.
-_pull_conn_counter = itertools.count(1 << 17)
+#: and a third for the lanes a split tcp link to a server ADDED to its one
+#: socket (the pull lanes, then the second sender's push lane:
+#: ps_client._ServerConn): a link's first push lanes keep the indices its
+#: one socket had, so a seeded schedule aimed at a server's pushes still
+#: hits them (those of the keys that stayed on lane 0) whatever else the
+#: link dials.
+_added_conn_counter = itertools.count(1 << 17)
 
 
 def _next_conn_index() -> int:
@@ -142,9 +144,9 @@ def _next_ctrl_conn_index() -> int:
         return next(_ctrl_conn_counter)
 
 
-def _next_pull_conn_index() -> int:
+def _next_added_conn_index() -> int:
     with _conn_counter_lock:
-        return next(_pull_conn_counter)
+        return next(_added_conn_counter)
 
 
 def reset_conn_indices() -> None:
@@ -158,11 +160,11 @@ def reset_conn_indices() -> None:
     which sub-suite combination runs them — the order-dependence that
     made test_fusion's ``[native-s4]`` lane flake across pytest
     selections.  Test-harness only: live jobs never reset mid-run."""
-    global _conn_counter, _ctrl_conn_counter, _pull_conn_counter
+    global _conn_counter, _ctrl_conn_counter, _added_conn_counter
     with _conn_counter_lock:
         _conn_counter = itertools.count()
         _ctrl_conn_counter = itertools.count(1 << 16)
-        _pull_conn_counter = itertools.count(1 << 17)
+        _added_conn_counter = itertools.count(1 << 17)
 
 
 def control_chaos_enabled() -> bool:
@@ -525,7 +527,7 @@ def make_chaos_van(inner):
             return ChaosSocket(sock, self.params, next_index(),
                                peer_port=port)
 
-        def connect_pull_lane(self, host: str, port: int, timeout: float = 30.0):
-            return self.connect(host, port, timeout, _next_pull_conn_index)
+        def connect_added_lane(self, host: str, port: int, timeout: float = 30.0):
+            return self.connect(host, port, timeout, _next_added_conn_index)
 
     return ChaosVan()

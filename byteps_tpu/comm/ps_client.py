@@ -37,7 +37,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from byteps_tpu.core.telemetry import counters, metrics
-from byteps_tpu.core.tracing import span
+from byteps_tpu.core.tracing import span, thread_tag
 
 from byteps_tpu.common.config import Config
 from byteps_tpu.common.hashing import assign_server
@@ -58,6 +58,17 @@ from byteps_tpu.comm.transport import (
 #: sentinel payload marking a response whose bytes were received directly
 #: into the caller's registered sink buffer (zero-copy pull)
 _ZERO_COPIED = object()
+
+
+#: threads that send PUSH frames at once over one split TCP link (the engine's
+#: PUSH stage runs as many: ``core/engine.py`` ``PipelineEngine.start``), and
+#: with them the least lanes such a link has A DIRECTION: a sender a push
+#: lane, and as many pull lanes, each with its receive thread.  One 4 MB
+#: ``sendmsg`` (and one ``recv_into``) is the kernel's copy made BY the
+#: calling thread, and one loopback stream carries about 3 GB/s: on the
+#: chip's host neither a second sender alone nor second sockets behind one
+#: sender moved a step, the two together did (PERF.md §6 PR 39)
+PUSH_SENDERS = 2
 
 
 class _ServerConn:
@@ -88,6 +99,11 @@ class _ServerConn:
         # independent kernel streams (the RDMA/UCX multi-lane van analogue,
         # reference setup.py:312-330).
         streams = max(1, streams) if tcp else 1
+        #: how many threads may usefully send PUSH frames on this link at
+        #: once: a split TCP link gives each a push lane; a link of one
+        #: socket has one send lock, which two senders would take in turns
+        self.push_senders = PUSH_SENDERS if tcp else 1
+        each = max(streams, self.push_senders)  # lanes a direction
         # On TCP, bulk travels ONE WAY on a socket: PULL requests go out on
         # lanes of their own and the merged rounds come back on them, so a
         # 4 MB reply's recv_into and another partition's 4 MB push sendmsg
@@ -98,23 +114,27 @@ class _ServerConn:
         # next PUSH only after that PULL was answered (the engine's round
         # gate): the ack orders them, not a socket's FIFO.  A unix, shm or
         # shaped link keeps one socket, which then carries both directions.
+        # Dial order: the ``streams`` push lanes a one-socket link had, the
+        # pull lanes, then the push lanes the second sender adds — what the
+        # split added dials ``added_lane``, so the first lanes keep their
+        # chaos connection indices.
         lanes = []
         try:
-            for i in range(2 * streams if tcp else 1):
+            for i in range(2 * each if tcp else 1):
                 # data-plane link: shaped when BYTEPS_VAN_DELAY_MS /
                 # BYTEPS_VAN_RATE_MBYTES_S emulate a DCN link (shaping.py)
                 lanes.append(
                     (maybe_shape(connect(host, port, timeout=dial_timeout,
-                                         pull_lane=i >= streams)),
+                                         added_lane=i >= streams)),
                      threading.Lock())
                 )
         except (ConnectionError, OSError):
             for sock, _ in lanes:
                 close_socket(sock)
             raise
-        #: push lanes, then (tcp) as many pull lanes
-        self.stripes = lanes[:streams]
-        self.pull_stripes = lanes[streams:] or self.stripes
+        #: push lanes (tcp: one a sender at least), then (tcp) as many pull lanes
+        self.stripes = lanes[:streams] + lanes[streams + each:]
+        self.pull_stripes = lanes[streams:streams + each] or self.stripes
         self.cb_lock = threading.Lock()
         self.callbacks: Dict[int, Callable[[Message], None]] = {}
         #: seq → caller-owned buffer the response payload is received INTO
@@ -209,7 +229,9 @@ class _ServerConn:
     def send_msg(self, msg: Message) -> None:
         """Frame + send on the message's lane: chosen by its ``op`` (a PULL
         rides a pull lane) and striped by its key, both stable, so a key's
-        pushes share one stream and its pulls another."""
+        pushes share one stream and its pulls another.  Two PUSH senders
+        that meet on one lane take turns on its lock, and part again at
+        their next keys."""
         pull = msg.op == Op.PULL
         lanes = self.pull_stripes if pull else self.stripes
         sock, lock = lanes[msg.key % len(lanes)]
@@ -467,6 +489,9 @@ class _NativeServerConn:
         cb(None)  # outside the lock: callbacks run user code
         return -1
 
+    #: the C++ lanes send on native threads: one Python sender feeds them
+    push_senders = 1
+
     def send_msg(self, msg: Message) -> None:
         payload = msg.payload or b""
         n = len(payload)
@@ -700,8 +725,9 @@ class _AsyncRpc:
         self.t_sent_done = 0.0
         try:
             # lane lock + frame + sendmsg: the part of this attempt that is
-            # the sender's (under bps.stage.PUSH | PULL on a stage thread)
-            with span("rpc.send." + self.op):
+            # the sender's (under bps.stage.PUSH | PULL on a stage thread),
+            # named like that thread's account (PUSH's second: ".1")
+            with span("rpc.send." + self.op + thread_tag()):
                 sc.send_msg(msg)
             self.t_sent_done = time.monotonic()
             # every frame that actually hit the wire (incl. retries):
@@ -1741,6 +1767,12 @@ class PSClient:
                          dial_timeout=dial_timeout)
         self._start_recv_loops(sc)
         return sc
+
+    def push_senders(self) -> int:
+        """How many PUSH stage threads the server links can feed at once:
+        the least over the links, decided by their kind (a split TCP link
+        has a push lane a sender; a unix, shm, shaped or native link one)."""
+        return min((sc.push_senders for sc in self._servers), default=1)
 
     def _count_zero_copy(self) -> None:
         self.zero_copy_pulls += 1

@@ -624,14 +624,16 @@ def send_message(sock: socket.socket, msg: Message, lock: Optional[threading.Loc
 
 
 def connect(host: str, port: int, timeout: float = 30.0,
-            pull_lane: bool = False) -> socket.socket:
+            added_lane: bool = False) -> socket.socket:
     """Dial an address from the scheduler book; the van scheme is encoded
-    in the host string (``unix://...`` → UDS, else TCP).  ``pull_lane``:
-    the connection is a server link's pull lane (``Van.connect_pull_lane``)."""
+    in the host string (``unix://...`` → UDS, else TCP).  ``added_lane``:
+    the connection is one a split server link has beside the sockets a
+    one-socket link had: a pull lane, the second sender's push lane
+    (``Van.connect_added_lane``)."""
     from byteps_tpu.comm.van import van_for_address
 
     van = van_for_address(host)
-    dial = van.connect_pull_lane if pull_lane else van.connect
+    dial = van.connect_added_lane if added_lane else van.connect
     return dial(host, port, timeout=timeout)
 
 

@@ -88,11 +88,12 @@ class Van:
     def connect(self, host: str, port: int, timeout: float = 30.0) -> socket.socket:
         raise NotImplementedError
 
-    def connect_pull_lane(self, host: str, port: int,
-                          timeout: float = 30.0) -> socket.socket:
-        """Dial a server link's pull lane (ps_client._ServerConn): a
-        connection like any other to every van but the chaos van, which
-        indexes its fault schedule apart from the push lanes'."""
+    def connect_added_lane(self, host: str, port: int,
+                           timeout: float = 30.0) -> socket.socket:
+        """Dial a lane a split server link added to its first sockets — a
+        pull lane, the second sender's push lane (ps_client._ServerConn):
+        a connection like any other to every van but the chaos van, which
+        indexes its fault schedule apart from the first push lanes'."""
         return self.connect(host, port, timeout=timeout)
 
 

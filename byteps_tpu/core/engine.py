@@ -599,26 +599,44 @@ class PipelineEngine:
         """Spawn one loop thread per host stage (BytePSGlobal::Start,
         global.cc:299-317).  The COMPRESS/DECOMPRESS striped pools spawn
         lazily when the first codec registers — uncompressed workers don't
-        pay for 2×threadpool_size idle pollers."""
+        pay for 2×threadpool_size idle pollers.
+
+        PUSH alone has as many threads as the server links can feed at
+        once (``PSClient.push_senders``, from the links' kind: two over
+        split TCP links, one over a unix, shm, shaped or native link): a
+        4 MB ``sendmsg`` is the kernel's copy made by the calling thread,
+        and one thread making a step's 162 of them one after another was
+        the PS hop (PERF.md §6 PR 39).  All of them serve the ONE PUSH
+        queue, so there is one priority order at dequeue, one credit
+        budget and one round gate; the gate, not a thread's FIFO, keeps a
+        key's rounds in order."""
+        senders = getattr(self.client, "push_senders", None)
         stages = [
-            (QueueType.COPYD2H, self._copy_d2h_once),
-            (QueueType.PUSH, self._push_once),
-            (QueueType.PULL, self._pull_once),
-            (QueueType.COPYH2D, self._copy_h2d_once),
+            (QueueType.COPYD2H, self._copy_d2h_once, 1),
+            (QueueType.PUSH, self._push_once,
+             senders() if senders is not None else 1),
+            (QueueType.PULL, self._pull_once, 1),
+            (QueueType.COPYH2D, self._copy_h2d_once, 1),
         ]
         if self.cfg.fusion_threshold > 0:
             # fusion off (the default) spawns no FUSE poller — the stage
             # only exists when small partitions can actually route to it
-            stages.insert(1, (QueueType.FUSE, self._fuse_once))
-        for qt, fn in stages:
-            self._spawn_stage(qt, fn)
+            stages.insert(1, (QueueType.FUSE, self._fuse_once, 1))
+        for qt, fn, threads in stages:
+            self._spawn_stage(qt, fn, threads)
 
-    def _spawn_stage(self, qt: QueueType, fn) -> None:
+    def _spawn_stage(self, qt: QueueType, fn, threads: int = 1) -> None:
+        """A thread a stripe of a striped stage; ``threads`` threads over
+        the one queue of any other, the first under the stage's own names
+        and thread i ≥ 1 under ``<STAGE>.<i>`` (:meth:`_loop`)."""
         q = self.queues[qt]
-        stripes = q.stripes if isinstance(q, _StripedStage) else [q]
-        for si, sq in enumerate(stripes):
+        if isinstance(q, _StripedStage):
+            loops = [(sq, 0) for sq in q.stripes]
+        else:
+            loops = [(q, i) for i in range(threads)]
+        for si, (sq, index) in enumerate(loops):
             t = threading.Thread(
-                target=self._loop, args=(sq, fn),
+                target=self._loop, args=(sq, fn, index),
                 name=f"bps-{qt.name}-{si}", daemon=True,
             )
             t.start()
@@ -638,16 +656,21 @@ class PipelineEngine:
             t.join(timeout=2.0)
         self._threads = []
 
-    def _loop(self, q: ScheduledQueue, fn) -> None:
-        from byteps_tpu.core.telemetry import metrics
-        from byteps_tpu.core.tracing import span
+    def _loop(self, q: ScheduledQueue, fn, index: int = 0) -> None:
+        from byteps_tpu.core.telemetry import counters, metrics
+        from byteps_tpu.core.tracing import span, tag_thread
 
         # values at hand only: nothing is formatted, and no label set is
         # hashed, per task on this path.  The thread's wall clock is its
         # service (span_seconds of "stage.<STAGE>") and what it does between
         # two services, by cause (docs/observability.md "Reading a hop
-        # thread by thread")
-        name = q.queue_type.name
+        # thread by thread").  A stage's second thread over one queue (PUSH
+        # over TCP) keeps an account of its own under "<STAGE>.1": summed
+        # under one name, two threads would read twice the wall clock.  The
+        # sends it makes (rpc.send.<OP>) take the same suffix
+        suffix = f".{index}" if index else ""
+        tag_thread(suffix)
+        name = q.queue_type.name + suffix
         span_name = "stage." + name
         waited = metrics().held("stage_wait_seconds", {"stage": name})
         # one service in _CPU_EVERY on two clocks, the thread's CPU time and
@@ -692,6 +715,10 @@ class PipelineEngine:
                 )
             if sampled:
                 on_wall.observe(serving.ended - serving.started)
+            if index:
+                # how much of the stage its second thread takes (PUSH alone
+                # has one: :meth:`start`)
+                counters().bump("push_second_sender_parts")
             idle.rest(serving.started, serving.ended)
 
     # --- submission ------------------------------------------------------

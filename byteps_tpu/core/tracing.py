@@ -257,6 +257,23 @@ _span_hists: Dict[str, HeldHistogram] = {}
 _current = threading.local()
 
 
+#: the suffix a thread's spans take where a call site asks for it (a stage's
+#: second thread over one queue: ``core/engine.py`` ``_loop``)
+_thread = threading.local()
+
+
+def tag_thread(suffix: str) -> None:
+    """Name this thread's share of spans that several threads make: a call
+    site that appends :func:`thread_tag` to a span's name keeps one series a
+    thread (``rpc.send.PUSH`` | ``rpc.send.PUSH.1``), so each stays a part
+    of that thread's own wall clock."""
+    _thread.suffix = suffix
+
+
+def thread_tag() -> str:
+    return getattr(_thread, "suffix", "")
+
+
 def current_span() -> Optional[Tuple[int, int]]:
     """``(trace id, span id)`` of the innermost :class:`span` open on this
     thread, or None (no span open, or the process tracer is off)."""

@@ -32,7 +32,7 @@ from byteps_tpu.core.telemetry import (
 from byteps_tpu.server.server import PSServer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-STAGES = ["COPYD2H", "PUSH", "PULL", "COPYH2D"]
+STAGES = ["COPYD2H", "PUSH", "PUSH.1", "PULL", "COPYH2D"]  # PUSH over tcp: two senders (ISSUE 39)
 PART = 4096  # bytes a partition
 PARTS = 24  # partitions of the one tensor
 
@@ -94,21 +94,36 @@ def raw_rounds(rounds, pause=0.0):
     # one closes its span a moment later
     deadline = time.monotonic() + 5.0
     while time.monotonic() < deadline and not all(
-            hist("span_seconds", name=name)["count"] == hist("stage_dwell_seconds", stage="PULL")["count"]
+            spans(name)["count"] == hist("stage_dwell_seconds", stage="PULL")["count"]
             for name in ("stage.PUSH", "stage.PULL", "stage.COPYH2D", "rpc.send.PUSH",
                          "rpc.send.PULL", "recv.frame.pull")):
         time.sleep(0.005)
 
 
-def account(stage):
-    return {
-        "service": hist("span_seconds", name=f"stage.{stage}"),
-        "cpu": hist("stage_sample_seconds", stage=stage, clock="cpu"),
-        "wall": hist("stage_sample_seconds", stage=stage, clock="wall"),
-        "starved": hist("stage_idle_seconds", stage=stage, why="starved"),
-        "gated": hist("stage_idle_seconds", stage=stage, why="gated"),
-        "dequeue": hist("stage_idle_seconds", stage=stage, why="dequeue"),
-    }
+def spans(name):
+    """``span_seconds`` of ``name`` over both PUSH senders | push lanes (the
+    second's are ``<name>.1``; nothing else has one)."""
+    both = [hist("span_seconds", name=n) for n in (name, name + ".1")]
+    return {k: sum(h[k] for h in both) for k in ("count", "sum")}
+
+
+def accounts():
+    """Every stage thread's account out of ONE snapshot: the moment the
+    wall clock beside it is read."""
+    held = metrics().snapshot()["histograms"]
+
+    def at(family, **labels):
+        key = family + "{" + ",".join(f'{k}="{v}"' for k, v in sorted(labels.items())) + "}"
+        return held.get(key, {"count": 0, "sum": 0.0})
+
+    return {stage: {
+        "service": at("span_seconds", name=f"stage.{stage}"),
+        "cpu": at("stage_sample_seconds", stage=stage, clock="cpu"),
+        "wall": at("stage_sample_seconds", stage=stage, clock="wall"),
+        "starved": at("stage_idle_seconds", stage=stage, why="starved"),
+        "gated": at("stage_idle_seconds", stage=stage, why="gated"),
+        "dequeue": at("stage_idle_seconds", stage=stage, why="dequeue"),
+    } for stage in STAGES}
 
 
 # ---------------------------------------------------------------------------
@@ -121,13 +136,16 @@ def test_a_stage_threads_account_closes_on_the_wall_clock(cluster):
 
     bps.init()
     raw_rounds(2)  # warm: programs compiled, the tensor declared
-    before, t0 = {s: account(s) for s in STAGES}, time.perf_counter()
+    before, t0 = accounts(), time.perf_counter()
     raw_rounds(6, pause=0.25)
     wall = time.perf_counter() - t0
-    after = {s: account(s) for s in STAGES}
+    after = accounts()
     for stage in STAGES:
         grown = {k: after[stage][k]["sum"] - before[stage][k]["sum"] for k in after[stage]}
         served = after[stage]["service"]["count"] - before[stage]["service"]["count"]
+        if stage.startswith("PUSH"):  # the two senders share the stage's tasks
+            other = "PUSH.1" if stage == "PUSH" else "PUSH"
+            served += after[other]["service"]["count"] - before[other]["service"]["count"]
         assert served == 6 * PARTS, stage
         # in service, waiting for a task (starved or gated) or taking one:
         # nothing else a stage thread does, and the account is exact but
@@ -150,16 +168,18 @@ def test_an_rpc_attempt_splits_into_send_and_reply_by_op(cluster):
 
     bps.init()
     raw_rounds(3)
-    pushes = hist("span_seconds", name="stage.PUSH")["count"]
+    pushes = spans("stage.PUSH")["count"]
     pulls = hist("span_seconds", name="stage.PULL")["count"]
     assert pushes == pulls == 3 * PARTS
     assert hist("rpc_reply_seconds", op="PUSH", server="0")["count"] == pushes
     assert hist("rpc_reply_seconds", op="PULL", server="0")["count"] == pulls
-    assert hist("span_seconds", name="rpc.send.PUSH")["count"] == pushes
     assert hist("span_seconds", name="rpc.send.PULL")["count"] == pulls
-    # the send is part of the stage's service; the reply's wait is not in the send
-    assert hist("span_seconds", name="rpc.send.PUSH")["sum"] <= hist(
-        "span_seconds", name="stage.PUSH")["sum"]
+    # a send span is named like the stage thread that made it, and is part of
+    # that thread's service; the reply's wait is not in the send
+    for sender in ("PUSH", "PUSH.1"):
+        sends, served = (hist("span_seconds", name=f"{kind}.{sender}")
+                         for kind in ("rpc.send", "stage"))
+        assert 0 < sends["count"] == served["count"] and sends["sum"] <= served["sum"], sender
     histograms = metrics().snapshot()["histograms"]
     # the round trip stays one family by server, every attempt in it once
     family = [k for k in histograms if k.startswith("rpc_round_trip_seconds")]
@@ -180,7 +200,7 @@ def test_the_receive_threads_have_names_and_a_span_a_frame(cluster):
     # a pull lane carries merged rounds and nothing else; every PUSH's ack
     # (and INIT's reply) comes back on the push lane
     assert frames["pull"]["count"] == pulls == 3 * PARTS
-    assert frames["push"]["count"] >= hist("span_seconds", name="stage.PUSH")["count"]
+    assert frames["push"]["count"] >= spans("stage.PUSH")["count"]
     for lane, served in frames.items():
         received = hist("recv_payload_seconds", lane=lane)
         assert received["count"] == served["count"], lane  # once a frame, both
@@ -192,7 +212,7 @@ def test_the_receive_threads_have_names_and_a_span_a_frame(cluster):
         tracks = {e["tid"] for e in events if e["name"] == f"recv.frame.{lane}"}
         assert tracks and all(t.startswith(f"bps-recv-{lane}-") for t in tracks), tracks
     names = {t.name for t in threading.enumerate()}
-    assert {"bps-recv-push-0", "bps-recv-pull-0"} <= names
+    assert {"bps-recv-push-0", "bps-recv-push-1", "bps-recv-pull-0", "bps-recv-pull-1"} <= names
 
 
 def test_a_wait_is_in_the_profile_and_no_phase_of_the_benchmarks(cluster, tmp_path):
@@ -212,7 +232,8 @@ def test_a_wait_is_in_the_profile_and_no_phase_of_the_benchmarks(cluster, tmp_pa
     waits = {n for n in names if n.startswith("bpswait.")}
     assert {f"bpswait.stage.{s}.starved" for s in STAGES} <= waits, waits
     assert all(n.rsplit(".", 1)[1] in ("starved", "gated") for n in waits)
-    assert {"bps.stage.PUSH", "bps.rpc.send.PUSH", "bps.recv.frame.pull"} <= names
+    assert {"bps.stage.PUSH", "bps.stage.PUSH.1", "bps.rpc.send.PUSH", "bps.rpc.send.PUSH.1",
+            "bps.recv.frame.pull"} <= names
     # benchmark/xplane.py keeps the harness's and the program's phases alone
     spec = importlib.util.spec_from_file_location(
         "bench_xplane", os.path.join(ROOT, "benchmark", "xplane.py"))

@@ -46,12 +46,14 @@ def test_one_json_line_with_positive_medians(reading, mode):
 
 def test_echo_reads_one_connection_and_one_a_direction(reading):
     """``split`` is ``held`` with the pulls and their replies on a second
-    connection to the same child (a TCP link's push lane and pull lane);
-    every reading brings its last partition back unchanged, or the tool
-    exits non-zero."""
+    connection to the same child (a TCP link's push lane and pull lane),
+    ``two_senders`` is ``split`` with two pushing threads, a push connection
+    each; every reading brings its last partition back unchanged, or the
+    tool exits non-zero."""
     got = reading("echo")
-    assert [k for k in got if isinstance(got[k], dict)] == ["fresh", "held", "split"]
-    for side in ("held", "split"):
+    assert [k for k in got if isinstance(got[k], dict)] == [
+        "fresh", "held", "split", "two_senders", "two_each"]
+    for side in ("held", "split", "two_senders", "two_each"):
         assert set(got[side]) == {"median_ms", "p10_ms", "p90_ms"}
 
 

@@ -149,8 +149,9 @@ def test_a_round_pushes_on_one_lane_and_lands_on_the_other(monkeypatch, mode):
     ``pull,rx``; no bulk meets bulk on a socket; and every result is
     bitwise what a one-socket (unix) link gives."""
     results, grown, wire, link = _rounds(monkeypatch, mode)
-    assert len(link.stripes) == len(link.pull_stripes) == 1
-    assert link.pull_stripes is not link.stripes and len(link.lanes()) == 2
+    # a push lane a PUSH sender (two since ISSUE 39) and as many pull lanes
+    assert (len(link.stripes), len(link.pull_stripes)) == (2, 2)
+    assert link.pull_stripes is not link.stripes and len(link.lanes()) == 4
     one_results, one_grown, _, one_link = _rounds(monkeypatch, mode, BYTEPS_VAN="uds")
     assert len(one_link.lanes()) == 1
     assert len(results) == len(one_results)
@@ -215,7 +216,7 @@ def test_either_lane_dying_fails_pending_once_and_both_come_back(monkeypatch, ki
                 time.sleep(0.02)
             fresh = client._servers[0]
             assert old.dead and fresh is not old and not fresh.dead
-            assert len(fresh.lanes()) == 2 and fresh.pull_stripes is not fresh.stripes
+            assert len(fresh.lanes()) == 4 and fresh.pull_stripes is not fresh.stripes
             assert all(sock.fileno() == -1 for sock, _, _ in old.lanes())
             # round 2, then the same push again as a lost ack's retry would
             # send it: the ledger is the key's, not a connection's
@@ -241,7 +242,7 @@ def test_either_lane_dying_fails_pending_once_and_both_come_back(monkeypatch, ki
     ("shm", {"BYTEPS_VAN": "shm"}, 1),
     ("shaped", {"BYTEPS_VAN_DELAY_MS": "0.1"}, 1),
     ("shaped_streams2", {"BYTEPS_VAN_DELAY_MS": "0.1", "BYTEPS_TCP_STREAMS": "2"}, 1),
-    ("chaos_tcp", {"BYTEPS_VAN": "chaos:tcp"}, 2),
+    ("chaos_tcp", {"BYTEPS_VAN": "chaos:tcp"}, 4),
 ])
 @within(120)
 def test_which_links_split(monkeypatch, link, env, lanes):
@@ -253,7 +254,7 @@ def test_which_links_split(monkeypatch, link, env, lanes):
         if platform.machine() not in ("x86_64", "AMD64", "i686"):
             pytest.skip("shm van requires x86-64 (TSO store ordering)")
     results, grown, wire, sc = _rounds(monkeypatch, "sync", **env)
-    assert len(sc.lanes()) == lanes and len(sc.stripes) == 1
+    assert len(sc.lanes()) == lanes and len(sc.stripes) == (2 if lanes == 4 else 1)
     assert (sc.pull_stripes is sc.stripes) == (lanes == 1)
     assert len(results) == ROUNDS
     assert grown[("push", "tx")] == wire["wire_tx_bytes"] == ROUNDS * 3 * PART
@@ -264,7 +265,8 @@ def test_which_links_split(monkeypatch, link, env, lanes):
 @within(60)
 def test_a_pull_lane_leaves_the_push_lanes_their_chaos_indices():
     """A seeded chaos schedule is keyed by (seed, connection index): the
-    pull lanes count in a stream of their own, so the push lanes of the
+    lanes a split link added (the pull lanes, then the second sender's push
+    lane) count in a stream of their own, so the first push lanes of the
     servers a worker dials are connections 0, 1, ... as their one sockets
     were, and a schedule aimed at a server's pushes still hits them."""
     from byteps_tpu.comm import chaos
@@ -276,7 +278,9 @@ def test_a_pull_lane_leaves_the_push_lanes_their_chaos_indices():
     links = [_ServerConn(CHAOS_PREFIX + host, port, dial_timeout=5) for _ in range(2)]
     try:
         assert [[sock.conn_index for sock, _, _ in sc.lanes()] for sc in links] == [
-            [0, 1 << 17], [1, (1 << 17) + 1]]
+            # push lanes 0 and 1, pull lanes 0 and 1: dialled first, last, second, third
+            [0, (1 << 17) + 2, 1 << 17, (1 << 17) + 1],
+            [1, (1 << 17) + 5, (1 << 17) + 3, (1 << 17) + 4]]
     finally:
         for sc in links:
             sc.close_all()
@@ -285,18 +289,19 @@ def test_a_pull_lane_leaves_the_push_lanes_their_chaos_indices():
 
 
 @within(120)
-def test_two_streams_are_two_lanes_a_direction(monkeypatch):
-    """``BYTEPS_TCP_STREAMS=2``: two push lanes and two pull lanes, keys
-    striped over each pair, the same bytes and the same sums."""
+def test_more_streams_are_more_lanes_a_direction(monkeypatch):
+    """``BYTEPS_TCP_STREAMS=3``: three push lanes and three pull lanes (one
+    or two streams are the two lanes a direction the two PUSH senders
+    need), keys striped over each set, the same bytes and the same sums."""
     one, one_grown, _, one_link = _rounds(monkeypatch, "sync")
-    two, two_grown, _, link = _rounds(monkeypatch, "sync", BYTEPS_TCP_STREAMS="2")
-    assert (len(one_link.stripes), len(one_link.pull_stripes)) == (1, 1)
-    assert (len(link.stripes), len(link.pull_stripes), len(link.lanes())) == (2, 2, 4)
-    assert [name for _, _, name in link.lanes()] == ["push", "push", "pull", "pull"]
-    assert len({id(sock) for sock, _, _ in link.lanes()}) == 4
-    for got, want in zip(two, one):
+    three, three_grown, _, link = _rounds(monkeypatch, "sync", BYTEPS_TCP_STREAMS="3")
+    assert (len(one_link.stripes), len(one_link.pull_stripes)) == (2, 2)
+    assert (len(link.stripes), len(link.pull_stripes), len(link.lanes())) == (3, 3, 6)
+    assert [name for _, _, name in link.lanes()] == ["push"] * 3 + ["pull"] * 3
+    assert len({id(sock) for sock, _, _ in link.lanes()}) == 6
+    for got, want in zip(three, one):
         np.testing.assert_array_equal(got, want)
-    assert two_grown == one_grown == {
+    assert three_grown == one_grown == {
         ("push", "tx"): ROUNDS * 3 * PART, ("pull", "rx"): ROUNDS * 3 * PART}
 
 
@@ -346,7 +351,7 @@ def test_a_steady_step_over_the_split_link_makes_no_fresh_buffer(monkeypatch):
         losses += [hdp.step(batch) for _ in range(5)]
         got, ever, moved, link = grown(buffers), grown(start), lane_growth(lanes), _link()
         bps.shutdown()
-    assert len(link.lanes()) == 2
+    assert len(link.lanes()) == 4
     assert losses[-1] < losses[0]
     assert got == STEADY
     assert ever[("fresh", "frame")] == 2, ever

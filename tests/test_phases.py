@@ -5,6 +5,7 @@ the compiled steps."""
 
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +158,13 @@ def run_hybrid(steps):
         batch_spec=(P("dp"), P("dp")),
     )
     losses = [hdp.step(batch) for _ in range(steps)]
+    # a step is done when its last partition is; the sender that pushed that
+    # one (two share the stage over tcp) closes its service span a moment later
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and sum(
+            hist("span_seconds", name=n)["count"] for n in ("stage.PUSH", "stage.PUSH.1")
+    ) < hist("stage_dwell_seconds", stage="PUSH")["count"]:
+        time.sleep(0.005)
     tracer = tracing.get_process_tracer()
     path = tracer.flush()
     bps.shutdown()
@@ -189,7 +197,8 @@ class TestHybridPhases:
             assert {e["name"] for e in tasks} == set(STAGES) | {"engine.finalize"}
             assert all(e["args"]["trace"] == step["args"]["trace"] for e in tasks)
             # and the stage thread's service span hangs under its task's span
-            served = [e for e in spans if e["name"] == "stage.PUSH"
+            # (by either of the two senders a tcp link has: ISSUE 39)
+            served = [e for e in spans if e["name"] in ("stage.PUSH", "stage.PUSH.1")
                       and e["args"]["trace"] == step["args"]["trace"]]
             push = {e["args"]["span"] for e in tasks if e["name"] == "PUSH"}
             assert served and {e["args"]["parent"] for e in served} == push
@@ -199,9 +208,13 @@ class TestHybridPhases:
         hdp, _ = run_hybrid(2)
         leaves = len(jax.tree.leaves(hdp.params))
         for stage in STAGES:
-            wait = hist("stage_wait_seconds", stage=stage)
+            # a stage's threads: PUSH's second sender observes under "PUSH.1"
+            threads = [stage, stage + ".1"] if stage == "PUSH" else [stage]
+            wait, served = (
+                {k: sum(h[k] for h in hs) for k in ("count", "sum")}
+                for hs in ([hist("stage_wait_seconds", stage=t) for t in threads],
+                           [hist("span_seconds", name=f"stage.{t}") for t in threads]))
             dwell = hist("stage_dwell_seconds", stage=stage)
-            served = hist("span_seconds", name=f"stage.{stage}")
             assert wait["count"] == dwell["count"] == served["count"] > 2 * leaves, stage
             assert 0 <= wait["sum"] <= dwell["sum"], stage
         assert hist("span_seconds", name="engine.finalize")["count"] == 2 * leaves

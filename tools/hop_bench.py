@@ -16,13 +16,20 @@ and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
 ``ndarray``, as ``np.asarray(slice)`` is on the TPU: the journal keeps a reference.
 ``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
 connection from a child shaped like the server: a serve thread a connection that
-receives, an engine thread that copies into the store, acks and replies.  Three
+receives, an engine thread that copies into the store, acks and replies.  Five
 readings: ``fresh`` (the server up to PR 33: a new ``bytearray`` a received frame,
 the store's ``tobytes()`` a reply), ``held`` (since PR 34: frames from the
-connection's ``FramePool``, released once copied; the reply a view of the store)
-and ``split`` (since PR 35: ``held``, with the puller's requests and the replies
+connection's ``FramePool``, released once copied; the reply a view of the store),
+``split`` (since PR 35: ``held``, with the puller's requests and the replies
 on a SECOND connection to the same child, so bulk travels one way on a socket,
-as a TCP link's push lane and pull lane carry it).
+as a TCP link's push lane and pull lane carry it), ``two_senders`` (since
+PR 39: ``split`` with TWO pushing threads, each on a push connection of its own,
+the odd keys on the second, and the puller on a third) and ``two_each``
+(``two_senders`` with two pullers, a pull connection each: a TCP link as it is
+since PR 39).  The child of the last three has four engine threads, a key's
+chosen as ``server/server.py`` ``_thread_for`` chooses it, as the real server
+has: with one, the probe stops at that thread's copy and 4 MB reply whatever
+the sender does.
 ``--mode d2h``: ``--frames`` slices of ``--bytes`` off ONE array on the first
 device, onto the host two ways: sliced and read one at a time (COPYD2H's loop
 up to PR 31), and through ``PipelineEngine._start_d2h``, which ``engine.submit``
@@ -34,6 +41,7 @@ buffers until the next pass has its own (a step's cycles did, up to PR 33);
 
 import argparse
 import contextlib
+import functools
 import json
 import multiprocessing
 import os
@@ -58,22 +66,32 @@ REPS = 5  # timed passes; one more runs first, unwarmed and uncounted
 POOL = 40  # distinct payload buffers, so no frame is sent from a warm cache line
 
 
-def _child(port_out, engine_thread: bool, held: bool = False, conns: int = 1) -> None:
-    """The server's side: a PUSH is kept (with ``engine_thread`` copied into the
+def _child(port_out, engine_threads: int, held: bool = False, conns: int = 1) -> None:
+    """The server's side: a PUSH is kept (with ``engine_threads`` copied into the
     store, as a sum is) and acked, a PULL answered with the store's bytes
     (``tobytes()``; with ``held`` a view, and received frames from a pool);
-    with ``engine_thread`` a serve thread only receives, a second does the rest.
-    Each of the ``conns`` accepted connections has a serve thread, a send lock
-    and a pool of its own, and is answered on itself, as the server's are."""
+    with ``engine_threads`` a serve thread only receives, and the key's engine
+    thread (the least loaded when the key first came, as ``_thread_for``) does
+    the rest.  Each of the ``conns`` accepted connections has a serve thread, a
+    send lock and a pool of its own, and is answered on itself, as the server's are."""
     srv, port = listen("127.0.0.1", 0)
     port_out.send(port)
-    inbox, store = queue.Queue(), {}
+    inboxes, store = [queue.Queue() for _ in range(engine_threads)], {}
+    thread_of, load, choosing = {}, [0] * engine_threads, threading.Lock()
+
+    def inbox_for(key, length):
+        with choosing:
+            tid = thread_of.get(key)
+            if tid is None:
+                tid = thread_of[key] = load.index(min(load))
+            load[tid] += length
+            return inboxes[tid]
 
     def handle(msg, conn, lock):
         reply = Message(msg.op, key=msg.key, seq=msg.seq)
         if msg.op == Op.PULL:
             reply.payload = memoryview(store[msg.key]) if held else store[msg.key].tobytes()
-        elif engine_thread:
+        elif engine_threads:
             if msg.key not in store:
                 store[msg.key] = np.empty(len(msg.payload), np.uint8)
             store[msg.key][:] = np.frombuffer(msg.payload, np.uint8)
@@ -82,7 +100,7 @@ def _child(port_out, engine_thread: bool, held: bool = False, conns: int = 1) ->
             store[0] = msg.payload  # the previous frame dies here, as a store's does
         send_message(conn, reply, lock)
 
-    def engine():
+    def engine(inbox):
         while True:
             handle(*inbox.get())
 
@@ -94,13 +112,13 @@ def _child(port_out, engine_thread: bool, held: bool = False, conns: int = 1) ->
                 msg = recv_message(conn, pool)
             except ConnectionError:
                 return
-            if engine_thread:
-                inbox.put((msg, conn, lock))
+            if engine_threads:
+                inbox_for(msg.key, len(msg.payload)).put((msg, conn, lock))
             else:
                 handle(msg, conn, lock)
 
-    if engine_thread:
-        threading.Thread(target=engine, daemon=True).start()
+    for inbox in inboxes:
+        threading.Thread(target=engine, args=(inbox,), daemon=True).start()
     serving = [threading.Thread(target=serve, args=(srv.accept()[0],), daemon=True)
                for _ in range(conns)]
     for t in serving:
@@ -110,11 +128,11 @@ def _child(port_out, engine_thread: bool, held: bool = False, conns: int = 1) ->
 
 
 @contextlib.contextmanager
-def _connected(engine_thread: bool, held: bool = False, conns: int = 1):
+def _connected(engine_threads: int, held: bool = False, conns: int = 1):
     """Spawn the child; yield ``conns`` sockets dialled, as a worker's are, to its port."""
     ctx = multiprocessing.get_context("spawn")
     port_in, port_out = ctx.Pipe(duplex=False)
-    proc = ctx.Process(target=_child, args=(port_out, engine_thread, held, conns), daemon=True)
+    proc = ctx.Process(target=_child, args=(port_out, engine_threads, held, conns), daemon=True)
     proc.start()
     try:
         if not port_in.poll(120):
@@ -150,13 +168,23 @@ def _summary(ms: list) -> dict:
     return {"median_ms": float(median), "p10_ms": float(p10), "p90_ms": float(p90)}
 
 
-def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
-    """``REPS`` + 1 passes of ``send_one(i, version)``, each waited out on ``done``."""
+def _passes(frames: int, send_one, done: threading.Semaphore, senders: int = 1) -> dict:
+    """``REPS`` + 1 passes of ``send_one(i, version)``, each waited out on ``done``;
+    with ``senders`` > 1 as many threads send, thread s every frame i ≡ s."""
+    def send_stride(first, version):
+        for i in range(first, frames, senders):
+            send_one(i, version)
+
     ms = []
     for rep in range(REPS + 1):
         t0 = time.perf_counter()
-        for i in range(frames):
-            send_one(i, rep + 1)
+        others = [threading.Thread(target=send_stride, args=(s, rep + 1), daemon=True)
+                  for s in range(1, senders)]
+        for t in others:
+            t.start()
+        send_stride(0, rep + 1)
+        for t in others:
+            t.join()
         for _ in range(frames):
             done.acquire()
         ms.append((time.perf_counter() - t0) / frames * 1e3)
@@ -165,7 +193,7 @@ def _passes(frames: int, send_one, done: threading.Semaphore) -> dict:
 
 def _frame_passes(pool: list, frames: int, journal) -> dict:
     acks = threading.Semaphore(0)
-    with _connected(engine_thread=False) as (sock,):
+    with _connected(engine_threads=0) as (sock,):
         def push(i, version):
             if journal is not None:
                 journal.record(i, version, 0, pool[i % POOL])
@@ -190,40 +218,49 @@ def echo(frames: int, nbytes: int) -> dict:
     pool = _payloads(nbytes)
     return {"fresh": _echo_passes(pool, frames, nbytes, held=False),
             "held": _echo_passes(pool, frames, nbytes, held=True),
-            "split": _echo_passes(pool, frames, nbytes, held=True, conns=2)}
+            "split": _echo_passes(pool, frames, nbytes, held=True, pulls=1, engine_threads=4),
+            "two_senders": _echo_passes(pool, frames, nbytes, held=True, pushes=2, pulls=1,
+                                        engine_threads=4),
+            "two_each": _echo_passes(pool, frames, nbytes, held=True, pushes=2, pulls=2,
+                                     engine_threads=4)}
 
 
-def _echo_passes(pool: list, frames: int, nbytes: int, held: bool, conns: int = 1) -> dict:
+def _echo_passes(pool: list, frames: int, nbytes: int, held: bool, pushes: int = 1,
+                 pulls: int = 0, engine_threads: int = 1) -> dict:
     journal = RoundJournal(2, 64 << 20)
     result = np.empty(frames * nbytes, np.uint8)
-    to_pull, landed = queue.Queue(), threading.Semaphore(0)
-    with _connected(engine_thread=True, held=held, conns=conns) as socks:
-        # pushes and their acks on the first connection, pull requests and
-        # their replies on the last: one and the same unless ``conns`` is 2
+    landed = threading.Semaphore(0)
+    with _connected(engine_threads, held=held, conns=pushes + pulls) as socks:
+        # ``pushes`` push connections, a pushing thread each, then ``pulls``
+        # pull connections, a puller each (none: the one connection carries
+        # both); key i on connection i mod them, either way
         lanes = [(sock, threading.Lock()) for sock in socks]
-        (push_sock, push_lock), (pull_sock, pull_lock) = lanes[0], lanes[-1]
+        push_lanes, pull_lanes = lanes[:pushes], lanes[pushes:] or lanes
+        to_pull = [queue.Queue() for _ in pull_lanes]
 
-        def on_header(op, key):
-            if op == Op.PUSH:  # the ack: the puller asks for the partition back
-                to_pull.put(key)
+        def on_header(sock, op, key):
+            if op == Op.PUSH:  # the ack: the key's puller asks for the partition back
+                to_pull[key % len(pull_lanes)].put(key)
             else:
-                recv_into(pull_sock, memoryview(result[key * nbytes:(key + 1) * nbytes]))
+                recv_into(sock, memoryview(result[key * nbytes:(key + 1) * nbytes]))
                 landed.release()
 
-        def puller():
+        def puller(asked, pull_sock, pull_lock):
             while True:
-                key = to_pull.get()
+                key = asked.get()
                 send_message(pull_sock, Message(Op.PULL, key=key, seq=key), pull_lock)
 
         def push(i, version):
             journal.record(i, version, 0, pool[i % POOL])
+            push_sock, push_lock = push_lanes[i % pushes]
             send_message(push_sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]),
                          push_lock)
 
         for sock, _ in lanes:
-            _on_each_header(sock, on_header)
-        threading.Thread(target=puller, daemon=True).start()
-        out = _passes(frames, push, landed)
+            _on_each_header(sock, functools.partial(on_header, sock))
+        for asked, (pull_sock, pull_lock) in zip(to_pull, pull_lanes):
+            threading.Thread(target=puller, args=(asked, pull_sock, pull_lock), daemon=True).start()
+        out = _passes(frames, push, landed, senders=pushes)
     if bytes(result[-nbytes:]) != bytes(pool[(frames - 1) % POOL]):
         raise SystemExit("hop_bench: the last partition came back changed")
     return out
