@@ -236,7 +236,11 @@ def rope_partial(x, rotary_dim: int, theta: float):
 
 def causal_conv(x, taps):
     """Depthwise causal convolution along the sequence: x (B, S, C), taps
-    (K, C) f32; ``y_t = Σ_j taps[j] · x_{t-K+1+j}``, zeros before the start.
+    (K, C) f32; ``y_t = Σ_j taps[j] · x_{t-K+1+j}``, zeros before the start
+    (the last tap weighs the present token, as ``Conv1d``'s does).  It
+    serves two families and both tap counts: this one's 4 taps under a silu,
+    and ``models/conv_moe.py``'s 3 taps between two gates
+    (tests/test_conv_moe_pieces.py holds the 3-tap case by hand).
     The shifted copies are taken in x's dtype and multiplied in f32 (on the
     chip 6 ms a layer less than shifting an f32 copy: PERF.md §6, PR 36)."""
     k, s = taps.shape[0], x.shape[1]

@@ -138,19 +138,20 @@ ROUTING_STATS = ("moe_slots_routed", "moe_slots_held", "moe_slots_dropped",
 
 
 def sigmoid_topk_route(g: jax.Array, router_w: jax.Array, select_bias: jax.Array,
-                       top_k: int, scale: float) -> tuple:
+                       top_k: int, scale: float, eps: float = 1e-20) -> tuple:
     """DeepSeek-V3's ``noaux_tc`` routing with one group.  ``g`` (T, D) and
     ``router_w`` (D, E) in f32 — near-ties among E sigmoid scores decide which
     experts run, so the scores are full f32 products.  The ``top_k`` largest
     of ``score + select_bias`` are chosen; the bias picks and does not weigh:
-    ``w_i = scale · s_i / (Σ_chosen s_j + 1e-20)``.  Returns the chosen ids
-    (T, k) int32 and their weights (T, k) f32."""
+    ``w_i = scale · s_i / (Σ_chosen s_j + eps)`` (``eps`` is DeepSeek-V3's by
+    default; LFM2-MoE publishes 1e-6).  Returns the chosen ids (T, k) int32
+    and their weights (T, k) f32."""
     scores = jax.nn.sigmoid(jnp.dot(
         g.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32))
     _, ids = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
     return ids.astype(jnp.int32), weights
 
 

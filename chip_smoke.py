@@ -38,6 +38,11 @@ the entry points a user calls, at the full width of the models the repo lists:
          layers and one gated full-attention layer, 16 of 512 softmax-routed
          experts), as leg E: loss and every leaf's gradient against the
          float32 plain reference, routers and routed experts judged apart.
+  leg G  the short-convolution MoE family at the published widths of
+         benchmark/configs/lfm2_24b_a2b_ep8.json (four double-gated
+         short-convolution mixers and one grouped-query attention layer at
+         heads of 64, a leading dense layer, 8 of 64 sigmoid-routed experts),
+         as leg F.
 
 Every result line names the platform, device kind, device count and the jax /
 jaxlib / libtpu versions.  Step times are printed as information only: they
@@ -701,6 +706,22 @@ LEG_F_LIMITS = {
 }
 
 
+#: leg G's limits.  Here the first gradient does tell the nearest precision
+#: below apart (readings on the chip, PR 40, tools/latent_moe_precision.py
+#: --config lfm2_24b_a2b_ep8, 3 seeds, and this leg's own key: the program |
+#: the reference with bf16 statistics — loss <= 5.3e-5 | 1.1e-4; routers and
+#: routed experts 0.212-0.224 | 0.282-0.283, always moe.router; other leaves
+#: at most 0.159-0.166 | 0.201-0.204, always moe.norm, whose whole gradient
+#: comes through the routed experts (no shared expert), so flipped near-ties
+#: reach it; median 0.056-0.059 | 0.071-0.072; projection within 0.029 of 1
+#: on both sides), so each limit stands between its two readings; the
+#: projection's stands against a planted fault (a halved gradient reads 0.5).
+LEG_G_LIMITS = {
+    "as made": {"loss": 1.7e-4, "routed": 0.25, "rest": 0.183, "median": 0.065,
+                "projection": 0.15},
+}
+
+
 def _reference_leg(dry: bool, leg: str, config: str, what, cases, limits: dict) -> None:
     """One step of ``benchmark/configs/<config>.json`` through
     ``build_train_step`` with an optimizer that keeps the gradient: loss and
@@ -780,6 +801,17 @@ def leg_f(dry: bool) -> None:
         {"as made": lambda params, cfg: params}, LEG_F_LIMITS)
 
 
+def leg_g(dry: bool) -> None:
+    """The short-convolution MoE family.  One case, as leg F: the cell's
+    selection bias is part of the seeded state, and pinning it is leg E's."""
+    _reference_leg(
+        dry, "G", "lfm2_24b_a2b_ep8",
+        lambda cfg: (f"LFM2-24B-A2B share: {cfg['num_hidden_layers']} layers from entry "
+                     f"{cfg['first_layer']} of the published list, {cfg['num_dense_layers']} "
+                     f"dense, {cfg['num_experts']} of {cfg['router_width']} experts"),
+        {"as made": lambda params, cfg: params}, LEG_G_LIMITS)
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -791,8 +823,8 @@ def main() -> int:
         help="pre-flight on the CPU at cut sizes with interpreted kernels; "
              "proves the control flow only, never a chip result",
     )
-    ap.add_argument("--legs", default="ABCDEF",
-                    help="the legs to run, e.g. F (all by default; B's children start anyway)")
+    ap.add_argument("--legs", default="ABCDEFG",
+                    help="the legs to run, e.g. G (all by default; B's children start anyway)")
     args = ap.parse_args()
     dry, legs = args.cpu_dry_run, set(args.legs.upper())
     if dry:
@@ -861,6 +893,7 @@ def main() -> int:
             run("D", lambda: leg_d(dry))
         run("E", lambda: leg_e(dry))
         run("F", lambda: leg_f(dry))
+        run("G", lambda: leg_g(dry))
 
         check_children(children)
         bps.shutdown()

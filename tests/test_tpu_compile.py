@@ -111,6 +111,43 @@ def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile
         assert kernel in text, f"{kernel} is not in the compiled program"
 
 
+def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_cache, monkeypatch):
+    """(2, 32, 8192, 64 | 64) causal — LFM2's attention layer with its 8
+    key/value heads repeated, half a lane tile in the contraction — forward
+    and both backward kernels, with the blocks ops/flash_blocks.json commits
+    for that sequence."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=1 / 8).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
+    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
+    """2 x 8192 tokens, 2048 channels, 3 taps, bf16 operands: the double-gated
+    short convolution between its two projections and its four gradients.
+    What the backward pass keeps and makes stays a few copies of the
+    (16 384, 6144) projection (201 MB in bf16), not one a tap in f32."""
+    from byteps_tpu.models import conv_moe as cm
+
+    cfg = cm.ConvMoEConfig(compute_dtype=jnp.bfloat16)
+    assert (cfg.d_model, cfg.conv_kernel) == (2048, 3)
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    lp = {k: shape(*s) for k, s in cm.stacks(cfg)["conv"][1].items()}
+
+    def loss(x, lp):
+        return jnp.sum(cm._conv_mixer(cfg, x, lp).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), shape(2, 8192, 2048, dtype=jnp.bfloat16), lp)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
 @pytest.mark.parametrize("implementation", ["kernels", "xla"])
 def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch,
                                                          implementation):

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""Where the limits on the two MoE families' precision come from.
+"""Where the limits on the three MoE families' precision come from.
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
     python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
+    python tools/latent_moe_precision.py --config lfm2_24b_a2b_ep8 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -153,6 +154,7 @@ def main() -> int:
                 "update": math.hypot(*off.values()) / math.hypot(*moved.values()),
                 "update_leaf": [worst, apart[worst]],
                 "update_median": sorted(apart.values())[len(apart) // 2],
+                "update_by_leaf": apart,
                 "gradient": gradient_readings(grads, want_grads),
             }
             del grads
