@@ -7,23 +7,29 @@ K/V block at a time through VMEM (O(block) footprint, never the S×S score
 matrix).  Online-softmax state (m, l, acc) lives in VMEM scratch persisted
 across KV grid steps; the per-row logsumexp is emitted for the backward.
 
-Backward: the standard two-kernel split —
-  dQ kernel: grid (bh, nq, nk), accumulates dQ for its query block while
-             streaming K/V blocks;
-  dKV kernel: grid (bh, nk, nq), accumulates dK/dV for its key block while
-             streaming Q/dO blocks.
-Both recompute P = exp(QKᵀ·scale − lse) blockwise (no saved probabilities)
-using the forward's logsumexp and Δ = rowsum(dO ∘ O).
+Backward: ONE kernel, grid (bh, nk, nq) with the query blocks innermost.
+A block pair's scores, P = exp(QKᵀ·scale − lse) (no saved probabilities:
+the forward's logsumexp and Δ = rowsum(dO ∘ O) rebuild them), dP and dS are
+computed once and feed all three gradients — five products a pair.  dK and
+dV accumulate in f32 scratch for their key block along the query blocks, as
+the forward's state does; dQ accumulates in an f32 scratch over the WHOLE
+sequence of one (batch·head), each pair adding into its query block's rows,
+key blocks ascending, and is cast and written once, while the last key
+block's steps pass.  The kernel states the VMEM this takes from its shapes
+(:func:`_bwd_vmem_bytes`).  A pair is computed transposed — keys down,
+queries across — so lse and Δ are read as one row and only dQ's product
+takes a transposed operand.
 
 The query/key head size and the value head size may differ (latent
 attention: 192 for q·k, 128 for v); the matrix products take their operands
 in the dtype they arrive in (bf16 in, bf16 on the MXU) and accumulate in
 f32, the softmax statistics are f32 throughout.
 
-Fully-masked causal blocks skip all matmuls via pl.when, and their K/V (or
-Q/dO) blocks are not fetched: the block index is clamped to the last one
-needed, and Pallas does not copy a block whose index did not change.  On a TPU the
-kernels are the only path (a sequence the blocks do not divide raises);
+Fully-masked causal blocks skip all matmuls via pl.when, and their K/V (in
+the backward kernel Q/dO/lse/Δ) blocks are not fetched: the block index is
+clamped to the last one needed, and Pallas does not copy a block whose index
+did not change.  On a TPU the kernels are the only path (a sequence the
+blocks do not divide raises);
 off a TPU the dense reference stands in — see :func:`_kernel_path`, the one
 place that decides.  Differentiable end to end.
 
@@ -45,11 +51,13 @@ from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
-# TPU vector lanes. Per-row softmax state (m, l, lse, delta) is carried
-# broadcast across a trailing LANES dim so every block-mapped ref keeps its
-# last two dims (8, 128)-tileable — a (bh, s) residual with (1, bq) blocks
-# fails Mosaic's block-mapping check (the same layout jax's bundled TPU
-# flash kernel uses for its l/m residuals).
+# TPU vector lanes.  The forward kernel carries its per-row softmax state (m,
+# l, and the lse it emits) broadcast across a trailing LANES dim, so every
+# block-mapped ref keeps its last two dims (8, 128)-tileable — a (bh, s)
+# output with (1, bq) blocks fails Mosaic's block-mapping check (the same
+# layout jax's bundled TPU flash kernel uses for its l/m residuals).  The
+# residual is one value a row, and the backward kernel reads it so: lse and Δ
+# as (bh, 1, s), a (1, bq) block along the lanes.
 LANES = 128
 
 #: what the forward leaves for the backward, by name: a ``jax.checkpoint``
@@ -59,7 +67,7 @@ LANES = 128
 SAVED = ("flash_out", "flash_lse")
 
 #: the kernels' names: a trace files their time under these
-FWD_KERNEL, DQ_KERNEL, DKV_KERNEL = "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"
+FWD_KERNEL, BWD_KERNEL = "flash_fwd", "flash_bwd"
 
 
 def _dense_reference(q, k, v, causal, scale):
@@ -85,12 +93,12 @@ def _block_needed(causal: bool, qi, j, bq: int, bk: int):
     return True if not causal else (j * bk < (qi + 1) * bq)
 
 
-def _causal_keep(qi, j, bq: int, bk: int):
-    """(bq, bk) bool mask of causally-visible positions for block pair."""
-    import jax
-
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _causal_keep(qi, j, bq: int, bk: int, keys_down: bool = False):
+    """Bool mask of a block pair's causally-visible positions: (bq, bk), or
+    (bk, bq) with the keys down and the queries across."""
+    shape, q_dim = ((bk, bq), 1) if keys_down else ((bq, bk), 0)
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     return rows >= cols
 
 
@@ -171,7 +179,7 @@ def _kv_index(causal, bq, bk):
 
 
 def _q_index(causal, bq, bk):
-    """Index map of a Q/dO/lse/delta block in a (bh, kv block, q block) grid."""
+    """Index map of a Q/dO block in a (bh, kv block, q block) grid."""
     if not causal:
         return lambda i, j, qi: (i, qi, 0)
     return lambda i, j, qi: (i, jnp.maximum(qi, _first_q_block(j, bq, bk)), 0)
@@ -224,93 +232,80 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p(q, k, lse, qi, j, bq, bk, causal, scale):
-    """P = exp(QKᵀ·scale − lse) of one block pair, f32, masked if causal."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    p = jnp.exp(s - lse)
-    if causal:
-        p = jnp.where(_causal_keep(qi, j, bq, bk), p, 0.0)
-    return p
-
-
-def _bwd_dq_kernel_factory(bq, bk, nk, causal, scale):
+def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale):
     from jax.experimental import pallas as pl
 
-    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr):
-        qi = pl.program_id(1)
-        j = pl.program_id(2)
+    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+        j = pl.program_id(1)   # key block (sequential: dQ accumulates over it)
+        qi = pl.program_id(2)  # query block (sequential, innermost)
+        rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query block's of dq_scr
 
         @pl.when(j == 0)
-        def _init():
-            dq_scr[:] = jnp.zeros_like(dq_scr)
-
-        @pl.when(_block_needed(causal, qi, j, bq, bk))
-        def _block():
-            k = k_ref[0]
-            lse = lse_ref[0][:, 0:1]      # (bq, 1) from lane-broadcast state
-            delta = delta_ref[0][:, 0:1]
-            p = _recompute_p(q_ref[0], k, lse, qi, j, bq, bk, causal, scale)
-            dp = jax.lax.dot_general(
-                do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = (p * (dp - delta)).astype(k.dtype)
-            dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-        @pl.when(j == nk - 1)
-        def _emit():
-            dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-    return kernel
-
-
-def _bwd_dkv_kernel_factory(bq, bk, nq, causal, scale):
-    from jax.experimental import pallas as pl
-
-    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-               dk_scr, dv_scr):
-        j = pl.program_id(1)   # key block
-        qi = pl.program_id(2)  # query block (sequential)
+        def _init_dq():
+            dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
 
         @pl.when(qi == 0)
-        def _init():
+        def _init_dkv():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
 
         @pl.when(_block_needed(causal, qi, j, bq, bk))
         def _block():
-            q = q_ref[0]
-            do = do_ref[0]
-            lse = lse_ref[0][:, 0:1]      # (bq, 1) from lane-broadcast state
-            delta = delta_ref[0][:, 0:1]
-            p = _recompute_p(q, k_ref[0], lse, qi, j, bq, bk, causal, scale)
+            # everything (bk, bq), keys down and queries across: lse and Δ are
+            # then one row, and of the five products only dQ's takes its left
+            # operand transposed
+            q, k, do = q_ref[0], k_ref[0], do_ref[0]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            pt = jnp.exp(st - lse_ref[0])
+            if causal:
+                pt = jnp.where(_causal_keep(qi, j, bq, bk, keys_down=True), pt, 0.0)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dp = jax.lax.dot_general(
-                do, v_ref[0], (((1,), (1,)), ((), ())),
+            dpt = jax.lax.dot_general(
+                v_ref[0], do, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            ds = (p * (dp - delta)).astype(q.dtype)
+            dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
             dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
+                dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
 
         @pl.when(qi == nq - 1)
-        def _emit():
+        def _emit_dkv():
             dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
+        @pl.when(j == nk - 1)
+        def _emit_dq():
+            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+
     return kernel
+
+
+def _bwd_vmem_bytes(s, bq, bk, dqk, dv, itemsize) -> int:
+    """What the backward kernel asks of VMEM, from its shapes: dQ's
+    accumulator and dK's and dV's in f32, every block-mapped operand twice
+    (Pallas double-buffers them), the (bk, bq) intermediates — scores, P, dP
+    and dS in f32, P's and dS's casts —, a quarter over for what Mosaic
+    spills, and never under Mosaic's own default.  A head size takes whole
+    lane tiles."""
+    dqk, dv = (-(-d // LANES) * LANES for d in (dqk, dv))
+    acc = 4 * (s * dqk + bk * (dqk + dv))
+    blocks = 2 * itemsize * (2 * (bq + bk) * dqk + (bq + 2 * bk) * dv) + 2 * 2 * 8 * bq * 4
+    inter = (4 * 4 + 2 * itemsize) * bq * bk
+    return max((acc + blocks + inter) * 5 // 4, 16 * 2**20)
 
 
 def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
@@ -327,67 +322,56 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     vf, dof = (x.reshape(bh, s, dv) for x in (v, do))
     delta = jnp.sum(
         dof.astype(jnp.float32) * o.reshape(bh, s, dv).astype(jnp.float32), axis=-1
-    )  # (bh, s) → lane-broadcast like lse so its blocks stay tileable
+    )
     if dlse is not None:
         # An lse cotangent (ring-attention online-softmax merge, which
         # consumes lse) folds EXACTLY into the delta term: with
         # ∂lse/∂s_ij = p_ij, ds_ij = p_ij·(dp_ij − Δ_i + dlse_i), so the
-        # kernels run unchanged on Δ' = Δ − dlse.
+        # kernel runs unchanged on Δ' = Δ − dlse.
         delta = delta - dlse.reshape(bh, s).astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[..., None], (bh, s, LANES))
-    lse = jnp.broadcast_to(lse[..., None], (bh, s, LANES))  # the residual is one value a row
-
-    kv_index = _kv_index(causal, bq, bk)
-    dq = pl.pallas_call(
-        _bwd_dq_kernel_factory(bq, bk, nk, causal, scale),
-        out_shape=jax.ShapeDtypeStruct((bh, s, dqk), q.dtype, vma=vma),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bk, dqk), kv_index),
-            pl.BlockSpec((1, bk, dv), kv_index),
-            pl.BlockSpec((1, bq, dv), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, dqk), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name=DQ_KERNEL,
-    )(qf, kf, vf, dof, lse, delta)
+    # one value a row, laid along the lanes: a (1, bq) block of (bh, 1, s)
+    delta, lse = delta.reshape(bh, 1, s), lse.reshape(bh, 1, s)
 
     q_index = _q_index(causal, bq, bk)
-    dk, dv_ = pl.pallas_call(
-        _bwd_dkv_kernel_factory(bq, bk, nq, causal, scale),
+    row_index = lambda i, j, qi: (i, 0, q_index(i, j, qi)[1])  # noqa: E731
+    kv_index = lambda i, j, qi: (i, j, 0)  # noqa: E731
+    # dQ's block leaves VMEM once: its index stands still until the last key
+    # block, whose steps write one query block each
+    dq_index = lambda i, j, qi: (i, jnp.where(j == nk - 1, qi, 0), 0)  # noqa: E731
+    dq, dk, dv_ = pl.pallas_call(
+        _bwd_kernel_factory(bq, bk, nq, nk, causal, scale),
         out_shape=(
+            jax.ShapeDtypeStruct((bh, s, dqk), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, dqk), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, dv), v.dtype, vma=vma),
         ),
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, dqk), q_index),
-            pl.BlockSpec((1, bk, dqk), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bk, dqk), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
             pl.BlockSpec((1, bq, dv), q_index),
-            pl.BlockSpec((1, bq, LANES), q_index),
-            pl.BlockSpec((1, bq, LANES), q_index),
+            pl.BlockSpec((1, 1, bq), row_index),
+            pl.BlockSpec((1, 1, bq), row_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bk, dqk), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, bq, dqk), dq_index),
+            pl.BlockSpec((1, bk, dqk), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
         ),
         scratch_shapes=[
+            pltpu.VMEM((s, dqk), jnp.float32),
             pltpu.VMEM((bk, dqk), jnp.float32),
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # dK/dV accumulate along the query blocks and dQ along the key
+            # blocks of one (batch·head): only that axis is free
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(s, bq, bk, dqk, dv, q.dtype.itemsize),
         ),
         interpret=interpret,
-        name=DKV_KERNEL,
+        name=BWD_KERNEL,
     )(qf, kf, vf, dof, lse, delta)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape))

@@ -458,7 +458,7 @@ def leg_c_flash(dry: bool) -> None:
         ))
         tag = f"leg C flash causal={causal}"
         require_mosaic(f"{tag} forward", 1, dry, fwd, q, k, v)
-        require_mosaic(f"{tag} backward", 3, dry, bwd, q, k, v)
+        require_mosaic(f"{tag} backward", 2, dry, bwd, q, k, v)
         e_out = close("forward", fwd(q, k, v), ref(q32, k32, v32))
         e_grads = [close(f"d{n}", g, r) for n, g, r in
                    zip("qkv", bwd(q, k, v), ref_bwd(q32, k32, v32))]
@@ -506,7 +506,7 @@ def leg_c_flash_latent(dry: bool) -> None:
 
     flash = jax.jit(jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True))
     dense = jax.jit(jax.value_and_grad(dense_loss, argnums=(0, 1, 2), has_aux=True))
-    require_mosaic(tag, 3, dry, flash, q, k, v)
+    require_mosaic(tag, 2, dry, flash, q, k, v)
     (_, out), grads = flash(q, k, v)
     (_, want), want_grads = dense(*(x.astype(jnp.float32) for x in (q, k, v)))
     errs = {}

@@ -64,30 +64,80 @@ class TestFlashAttention:
         g = jax.grad(loss)(q)
         assert np.all(np.isfinite(np.asarray(g)))
 
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_backward_kernels_match_dense_grads(self, causal):
-        """The blocked dQ/dKV kernels must reproduce dense-attention
+    # (causal, block_q, block_k, d_qk, d_v) at sequence 256: square blocks;
+    # latent attention's 192 | 128 scaled down; block_q != block_k either way
+    # round (8 key blocks of 32: dQ accumulates across the outer, sequential
+    # axis; 2 query blocks a key block and 4 key blocks a query block on the
+    # causal diagonal); one block a side
+    @pytest.mark.parametrize("causal,bq,bk,d_qk,d_v", [
+        (False, 64, 64, 32, 32), (True, 64, 64, 32, 32),
+        (False, 64, 64, 48, 32), (True, 64, 64, 48, 32),
+        (False, 128, 32, 48, 32), (True, 128, 32, 48, 32),
+        (False, 32, 128, 48, 32), (True, 32, 128, 48, 32),
+        (True, 256, 64, 32, 48), (True, 64, 256, 32, 48),
+    ])
+    def test_backward_kernel_matches_dense_grads(self, causal, bq, bk, d_qk, d_v):
+        """The one backward kernel (scores, P, dP, dS once a block pair; dQ,
+        dK and dV accumulated from them) must reproduce dense-attention
         gradients for independent q, k, v."""
         rng = np.random.default_rng(5)
-        shape = (2, 2, 256, 32)
-        q = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-        ct = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+        q, k, v, ct = (jnp.asarray(rng.normal(size=(2, 2, 256, d)).astype(np.float32))
+                       for d in (d_qk, d_qk, d_v, d_v))
 
         def flash_loss(q, k, v):
-            out = flash_attention(q, k, v, causal=causal, block_q=64,
-                                  block_k=64, interpret=True)
+            out = flash_attention(q, k, v, causal=causal, block_q=bq,
+                                  block_k=bk, interpret=True)
             return jnp.sum(out * ct)
 
         def dense_loss(q, k, v):
-            return jnp.sum(_dense_reference(q, k, v, causal, 32**-0.5) * ct)
+            return jnp.sum(_dense_reference(q, k, v, causal, d_qk**-0.5) * ct)
 
         gq, gk, gv = jax.grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
         rq, rk, rv = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
         np.testing.assert_allclose(np.asarray(gq), np.asarray(rq), rtol=2e-3, atol=2e-4)
         np.testing.assert_allclose(np.asarray(gk), np.asarray(rk), rtol=2e-3, atol=2e-4)
         np.testing.assert_allclose(np.asarray(gv), np.asarray(rv), rtol=2e-3, atol=2e-4)
+
+    @pytest.mark.parametrize("lse_out", [False, True])
+    def test_backward_is_one_kernel(self, lse_out):
+        """Every backward flash call is the one kernel: a gradient's program
+        holds one ``flash_fwd`` and one ``flash_bwd`` and no third."""
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        q = jnp.ones((1, 2, 128, 32), jnp.float32)
+
+        def loss(q, k, v):
+            if lse_out:
+                out, lse = fa.flash_attention_lse(q, k, v, causal=True, block_q=64,
+                                                  block_k=32, interpret=True)
+                return jnp.sum(out) + jnp.sum(lse)
+            return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=64,
+                                              block_k=32, interpret=True))
+
+        def kernels(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn.params["name"]
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from kernels(sub)
+
+        names = sorted(kernels(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr))
+        assert names == [fa.BWD_KERNEL, fa.FWD_KERNEL]
+        assert not fa.BWD_KERNEL.startswith(("flash_bwd_dq", "flash_bwd_dkv"))
+
+    def test_backward_states_its_vmem(self):
+        """The kernel's VMEM limit comes from its shapes: dQ's f32
+        accumulator over the whole sequence of one (batch·head) is inside
+        it, and the three cells' shapes stay under a v5e's 128 MiB."""
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        for s, (d_qk, d_v) in ((8192, (192, 128)), (16384, (256, 256)), (8192, (64, 64))):
+            bq, bk = fa.tuned_blocks(s)
+            asked = fa._bwd_vmem_bytes(s, bq, bk, d_qk, d_v, 2)
+            lanes = -(-d_qk // fa.LANES) * fa.LANES
+            assert s * lanes * 4 < asked < 100 * 2**20, (s, d_qk, asked)
 
 
 class TestOneBitDevice:
@@ -156,33 +206,34 @@ class TestFlashLse:
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(np.asarray(lse), ref_lse, rtol=2e-4, atol=2e-5)
 
-    def test_lse_cotangent_folds_into_backward(self):
-        """grad through a function of BOTH outputs (out, lse) must match
-        the dense autodiff reference — the dlse→delta fold."""
+    # (causal, block_q, block_k, d_qk, d_v) at sequence 64
+    @pytest.mark.parametrize("causal,bq,bk,d_qk,d_v", [
+        (True, 16, 16, 8, 8), (False, 16, 16, 8, 8),
+        (True, 32, 8, 24, 16), (False, 8, 32, 24, 16),
+    ])
+    def test_lse_cotangent_folds_into_backward(self, causal, bq, bk, d_qk, d_v):
+        """grad through a function of BOTH outputs (out, lse), so with a
+        non-zero lse cotangent, must match the dense autodiff reference —
+        the dlse→delta fold, seen by the one backward kernel."""
         from byteps_tpu.ops.flash_attention import (
-            _dense_reference,
+            _dense_reference_lse,
             flash_attention_lse,
         )
 
         rng = np.random.default_rng(6)
         q, k, v = (
-            jnp.asarray(rng.normal(size=(1, 1, 32, 8)).astype(np.float32))
-            for _ in range(3)
+            jnp.asarray(rng.normal(size=(1, 2, 64, d)).astype(np.float32))
+            for d in (d_qk, d_qk, d_v)
         )
 
         def loss_flash(q, k, v):
             o, lse = flash_attention_lse(
-                q, k, v, causal=True, block_q=16, block_k=16, interpret=True
+                q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True
             )
             return jnp.sum(o**2) + jnp.sum(jnp.sin(lse))
 
         def loss_dense(q, k, v):
-            scale = 8 ** -0.5
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-            mask = jnp.tril(jnp.ones((32, 32), bool))
-            s = jnp.where(mask, s, -1e30)
-            lse = jax.scipy.special.logsumexp(s, axis=-1)
-            o = _dense_reference(q, k, v, True, scale)
+            o, lse = _dense_reference_lse(q, k, v, causal, d_qk ** -0.5)
             return jnp.sum(o**2) + jnp.sum(jnp.sin(lse))
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)

@@ -51,8 +51,10 @@ def _compile(fn, *args):
 
 def test_flash_kernels_compile_at_the_latent_attention_shape(one_chip, no_compile_cache,
                                                              monkeypatch):
-    """(2, 32, 8192, 192 | 128) causal, forward and both backward kernels,
-    with the blocks ops/flash_blocks.json commits for that sequence."""
+    """(2, 32, 8192, 192 | 128) causal, the forward kernel and the backward
+    kernel (dQ's f32 accumulator over the whole sequence, 8.4 MB in VMEM
+    under the limit the kernel states), with the blocks ops/flash_blocks.json
+    commits for that sequence."""
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     b, h, s, d_qk, d_v = 2, 32, 8192, 192, 128
     q, k = (jax.ShapeDtypeStruct((b, h, s, d_qk), jnp.bfloat16, sharding=one_chip)
@@ -64,9 +66,9 @@ def test_flash_kernels_compile_at_the_latent_attention_shape(one_chip, no_compil
         return jnp.sum(out.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v).as_text()
-    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
         assert kernel in text, f"{kernel} is not in the compiled program"
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache):
@@ -96,26 +98,28 @@ def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cac
 
 def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile_cache,
                                                             monkeypatch):
-    """(1, 16, 16384, 256 | 256) causal, forward and both backward kernels,
-    with the blocks ops/flash_blocks.json commits for that sequence (1024 x
-    1024, the 8192 entry's, is over the kernels' VMEM limit at head size 256)."""
+    """(1, 16, 16384, 256 | 256) causal, the forward kernel and the backward
+    kernel, with the blocks ops/flash_blocks.json commits for that sequence:
+    1024 x 1024, which the pair of backward kernels could not hold under
+    Mosaic's default VMEM limit at head size 256; the one kernel asks for its
+    own (dQ's accumulator alone is 16.8 MB here)."""
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    assert fa.tuned_blocks(16384) != fa.tuned_blocks(8192)
+    assert fa.tuned_blocks(16384) == (1024, 1024)
     q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=1 / 16).astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
-    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
         assert kernel in text, f"{kernel} is not in the compiled program"
 
 
 def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_cache, monkeypatch):
     """(2, 32, 8192, 64 | 64) causal — LFM2's attention layer with its 8
-    key/value heads repeated, half a lane tile in the contraction — forward
-    and both backward kernels, with the blocks ops/flash_blocks.json commits
-    for that sequence."""
+    key/value heads repeated, half a lane tile in the contraction — the
+    forward kernel and the backward kernel, with the blocks
+    ops/flash_blocks.json commits for that sequence."""
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16, sharding=one_chip)
 
@@ -123,9 +127,9 @@ def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_c
         return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=1 / 8).astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
-    for kernel in (fa.FWD_KERNEL, fa.DQ_KERNEL, fa.DKV_KERNEL):
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
         assert kernel in text, f"{kernel} is not in the compiled program"
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):

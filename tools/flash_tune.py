@@ -1,17 +1,23 @@
 """On-chip flash-attention block-size sweep vs the dense reference.
 
-Times fwd+bwd (value_and_grad of a sum-of-squares) for the Pallas flash
-kernel across (block_q, block_k) candidates and sequence lengths, against
-XLA's fused dense attention — the data behind TransformerConfig.use_flash
-defaults.  Refuses to run off-TPU (CPU timings say nothing about Mosaic).
+Times fwd+bwd (value_and_grad of a sum-of-squares: the forward kernel and
+the one backward kernel, which share a (block_q, block_k)) and the forward
+alone, across block candidates and sequence lengths, against XLA's fused
+dense attention — the data behind TransformerConfig.use_flash defaults.
+Refuses to run off-TPU (CPU timings say nothing about Mosaic).
 
     python tools/flash_tune.py [--seqs 512,1024,2048,4096] [--bh 8,4]
 
-The sweep behind ops/flash_blocks.json's 8192 entry (latent attention: 192
-for q·k, 128 for v; the dense path cannot hold 8k scores):
+The sweeps behind ops/flash_blocks.json's 8192 and 16384 entries, at the
+three cells' shapes (latent attention: 192 for q·k, 128 for v; gated
+attention 256 | 256; grouped-query 64 | 64 — the dense path cannot hold
+8k scores); the table is keyed by the sequence alone, so where two shapes
+of one sequence disagree the entry is the one that costs the cells least:
 
     python tools/flash_tune.py --seqs 8192 --bh 2,32 --dh 192 --dv 128 \
         --blocks 256,512,1024 --no-dense --out chiprun_out/flash_blocks.json
+    python tools/flash_tune.py --seqs 16384 --bh 1,16 --dh 256 --dv 256 ...
+    python tools/flash_tune.py --seqs 8192 --bh 2,32 --dh 64 --dv 64 ...
 """
 
 import argparse
@@ -54,10 +60,9 @@ def main() -> int:
     dh, dv = args.dh, args.dv or args.dh
     blocks = [int(x) for x in args.blocks.split(",")]
 
-    def time_fn(fn, *xs):
-        # all three gradients: with dq alone XLA drops the dK/dV kernel
-        f = jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
-                                       argnums=(0, 1, 2)))
+    def time_fn(fn, *xs, grad=True):
+        loss = lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)  # noqa: E731
+        f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
         out = f(*xs)
         jax.block_until_ready(out)
         t0 = time.perf_counter()
@@ -88,27 +93,27 @@ def main() -> int:
             for bk in blocks:
                 if s % bq or s % bk:
                     continue
+                flash = lambda q, k, v, bq=bq, bk=bk: flash_attention(  # noqa: E731
+                    q, k, v, causal=True, block_q=bq, block_k=bk)
                 try:
-                    ms = time_fn(
-                        lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                            q, k, v, causal=True, block_q=bq, block_k=bk
-                        ),
-                        q, k, v,
-                    )
+                    ms = time_fn(flash, q, k, v)
+                    fwd_ms = time_fn(flash, q, k, v, grad=False)
                 except Exception as e:  # noqa: BLE001
                     print(f"seq {s} flash bq={bq} bk={bk}: {type(e).__name__}")
                     continue
                 tag = ""
                 if best is None or ms < best[0]:
-                    best = (ms, bq, bk)
+                    best = (ms, bq, bk, fwd_ms)
                     tag = " *"
-                print(f"seq {s} flash bq={bq} bk={bk}: {ms:8.2f} ms{tag}")
+                print(f"seq {s} flash bq={bq} bk={bk}: {ms:8.2f} ms "
+                      f"(forward alone {fwd_ms:6.2f}){tag}")
         if dense_ms is not None:
             print(f"seq {s} dense:               {dense_ms:8.2f} ms")
         if best is not None:
             winners[s] = {
                 "blocks": [best[1], best[2]],
                 "flash_ms": round(best[0], 3),
+                "fwd_ms": round(best[3], 3),
                 "dense_ms": None if dense_ms is None else round(dense_ms, 3),
             }
         if best is not None and dense_ms is not None:
@@ -138,7 +143,8 @@ def main() -> int:
         for s, w in winners.items():
             blocks[str(s)] = w["blocks"]
             meta[str(s)] = {
-                "flash_ms": w["flash_ms"], "dense_ms": w["dense_ms"],
+                "flash_ms": w["flash_ms"], "fwd_ms": w["fwd_ms"],
+                "dense_ms": w["dense_ms"],
                 "bh": args.bh, "dh": dh, "dv": dv,
             }
         with open(path, "w") as f:
