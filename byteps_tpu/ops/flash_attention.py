@@ -33,6 +33,25 @@ blocks do not divide raises);
 off a TPU the dense reference stands in — see :func:`_kernel_path`, the one
 place that decides.  Differentiable end to end.
 
+The band (``window=W`` with ``causal=True``): query ``i`` sees key ``j`` iff
+``0 <= i - j < W`` — itself and the ``W - 1`` keys before it.  A banded call
+does not walk the whole (query block, key block) square and skip: its
+innermost grid axis is only as long as the band is wide in blocks
+(:func:`_band_steps`), and step ``t`` of it is the ``t``-th block of the
+band — key block ``first + t`` of query block ``qi`` forward, query block
+``first + t`` of key block ``j`` backward — so a block pair outside the band
+is neither computed, nor fetched, nor stepped over.  What is left to skip by
+``pl.when`` are the steps past the band's end near the sequence's edges
+(their block index is clamped, so nothing is copied); the mask inside the
+band's two edge blocks is the causal comparison and ``i - j < W``.  dQ's
+accumulator is cleared where a query block meets its first key block and the
+running sum is written at every pair, the last of which is whole (at a window
+the last key block meets only the last query blocks, so "while the last key
+block's steps pass" would leave the others unwritten).  Banded calls carry
+their own kernel names (``flash_fwd_win``, ``flash_bwd_win``) and their own
+table of blocks, keyed by (sequence, window).  ``window=None`` is the code
+above, unchanged.
+
 This is the per-device compute of the transformer's attention; sequence
 parallelism composes on top (ring attention rotates KV blocks *between*
 devices, these kernels handle the blocks *within* one device).
@@ -68,19 +87,23 @@ SAVED = ("flash_out", "flash_lse")
 
 #: the kernels' names: a trace files their time under these
 FWD_KERNEL, BWD_KERNEL = "flash_fwd", "flash_bwd"
+#: and of a banded call's (``window=``): filed apart whatever scope path is kept
+FWD_WIN_KERNEL, BWD_WIN_KERNEL = "flash_fwd_win", "flash_bwd_win"
 
 
-def _dense_reference(q, k, v, causal, scale):
-    return _dense_reference_lse(q, k, v, causal, scale)[0]
+def _dense_reference(q, k, v, causal, scale, window=None):
+    return _dense_reference_lse(q, k, v, causal, scale, window)[0]
 
 
-def _dense_reference_lse(q, k, v, causal, scale):
+def _dense_reference_lse(q, k, v, causal, scale, window=None):
     """Dense (out, lse) from ONE (s, s) score matrix — the lse fallback
     must not materialize scores twice (round-3 advisor finding)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         qlen, klen = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), bool))
+        if window is not None:  # 0 <= i - j < window
+            mask &= ~jnp.tril(jnp.ones((qlen, klen), bool), -window)
         s = jnp.where(mask, s, NEG_INF)
     s32 = s.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(s32, axis=-1)
@@ -88,17 +111,27 @@ def _dense_reference_lse(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v), lse
 
 
-def _block_needed(causal: bool, qi, j, bq: int, bk: int):
-    """Whether KV block j contributes anything to query block qi."""
-    return True if not causal else (j * bk < (qi + 1) * bq)
+def _block_needed(causal: bool, qi, j, bq: int, bk: int, window=None):
+    """Whether KV block j contributes anything to query block qi: its first
+    key is not after the block's last query and, at a window, its last key
+    is within the window of the block's first query."""
+    if not causal:
+        return True
+    needed = j * bk < (qi + 1) * bq
+    if window is not None:
+        needed &= (j + 1) * bk - 1 > qi * bq - window
+    return needed
 
 
-def _causal_keep(qi, j, bq: int, bk: int, keys_down: bool = False):
+def _causal_keep(qi, j, bq: int, bk: int, keys_down: bool = False, window=None):
     """Bool mask of a block pair's causally-visible positions: (bq, bk), or
-    (bk, bq) with the keys down and the queries across."""
+    (bk, bq) with the keys down and the queries across; at a window, of those
+    the ones fewer than ``window`` back."""
     shape, q_dim = ((bk, bq), 1) if keys_down else ((bq, bk), 0)
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    if window is not None:
+        return (rows >= cols) & (rows - cols < window)
     return rows >= cols
 
 
@@ -117,20 +150,55 @@ def _first_q_block(j, bq: int, bk: int):
     return (j * bk) // bq
 
 
-def _fwd_kernel_factory(bq, bk, nk, causal, scale):
+def _first_kv_block(qi, bq: int, bk: int, window: int):
+    """Index of the first KV block a banded query block qi reads: the one
+    that holds the key ``window - 1`` before the block's first query.  (A
+    Python ``qi`` gives a Python index: the grid's length is static.)"""
+    largest = max if isinstance(qi, int) else jnp.maximum
+    return largest(qi * bq - window + 1, 0) // bk
+
+
+def _last_q_block(j, bq: int, bk: int, window: int, nq: int):
+    """Index of the last query block that sees banded KV block j: the one
+    that holds the query ``window - 1`` after the block's last key."""
+    least = min if isinstance(j, int) else jnp.minimum
+    return least(((j + 1) * bk + window - 2) // bq, nq - 1)
+
+
+def _band_steps(s: int, bq: int, bk: int, window: int) -> tuple:
+    """How many KV blocks the widest query block's band spans, and how many
+    query blocks the widest KV block's: the lengths of the banded kernels'
+    innermost grid axes (forward, backward)."""
+    nq = s // bq
+    fwd = max(_last_kv_block(qi, bq, bk) - _first_kv_block(qi, bq, bk, window) + 1
+              for qi in range(nq))
+    bwd = max(_last_q_block(j, bq, bk, window, nq) - _first_q_block(j, bq, bk) + 1
+              for j in range(s // bk))
+    return fwd, bwd
+
+
+def _fwd_kernel_factory(bq, bk, nk, causal, scale, window=None):
+    """``nk``: the innermost grid axis's length — the KV blocks, or at a
+    window the band's (:func:`_band_steps`), step ``t`` being KV block
+    ``first + t`` of its query block."""
     from jax.experimental import pallas as pl
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
         qi = pl.program_id(1)
-        j = pl.program_id(2)
+        step = j = pl.program_id(2)
+        if window is not None:
+            j = _first_kv_block(qi, bq, bk, window) + step
 
-        @pl.when(j == 0)
+        @pl.when(step == 0)
         def _init():
             m_scr[:] = jnp.full_like(m_scr, NEG_INF)
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-        @pl.when(_block_needed(causal, qi, j, bq, bk))
+        # a row with no visible key in its band's first block adds that block
+        # at weight 1 under m = NEG_INF, which the first real maximum's
+        # exp(NEG_INF - m) wipes: every row sees at least itself
+        @pl.when(_block_needed(causal, qi, j, bq, bk, window))
         def _block():
             v = v_ref[0]
             s = jax.lax.dot_general(
@@ -138,7 +206,7 @@ def _fwd_kernel_factory(bq, bk, nk, causal, scale):
                 preferred_element_type=jnp.float32,
             ) * scale
             if causal:
-                s = jnp.where(_causal_keep(qi, j, bq, bk), s, NEG_INF)
+                s = jnp.where(_causal_keep(qi, j, bq, bk, window=window), s, NEG_INF)
             m = m_scr[:]  # (bq, LANES), value broadcast across lanes
             l = l_scr[:]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -151,7 +219,7 @@ def _fwd_kernel_factory(bq, bk, nk, causal, scale):
                 preferred_element_type=jnp.float32,
             )
 
-        @pl.when(j == nk - 1)
+        @pl.when(step == nk - 1)
         def _emit():
             l = l_scr[:]
             l = jnp.where(l == 0, 1.0, l)
@@ -171,35 +239,45 @@ def _vma_union(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _kv_index(causal, bq, bk):
-    """Index map of a K/V block in a (bh, q block, kv block) grid."""
+def _kv_index(causal, bq, bk, window=None):
+    """Index map of a K/V block in a (bh, q block, kv block) grid; at a
+    window the last axis counts from the band's first KV block, and the index
+    is held at the band's last."""
     if not causal:
         return lambda i, qi, j: (i, j, 0)
+    if window is not None:
+        return lambda i, qi, t: (i, jnp.minimum(_first_kv_block(qi, bq, bk, window) + t,
+                                                _last_kv_block(qi, bq, bk)), 0)
     return lambda i, qi, j: (i, jnp.minimum(j, _last_kv_block(qi, bq, bk)), 0)
 
 
-def _q_index(causal, bq, bk):
-    """Index map of a Q/dO block in a (bh, kv block, q block) grid."""
+def _q_index(causal, bq, bk, window=None, nq=None):
+    """Index map of a Q/dO block in a (bh, kv block, q block) grid; at a
+    window the last axis counts from the band's first query block, and the
+    index is held at the band's last."""
     if not causal:
         return lambda i, j, qi: (i, qi, 0)
+    if window is not None:
+        return lambda i, j, t: (i, jnp.minimum(_first_q_block(j, bq, bk) + t,
+                                               _last_q_block(j, bq, bk, window, nq)), 0)
     return lambda i, j, qi: (i, jnp.maximum(qi, _first_q_block(j, bq, bk)), 0)
 
 
-def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
+def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     vma = _vma_union(q, k, v)
     b, h, s, dqk = q.shape
     dv = v.shape[-1]
-    nk = s // bk
+    nk = s // bk if window is None else _band_steps(s, bq, bk, window)[0]
     bh = b * h
     qf = q.reshape(bh, s, dqk)
     kf = k.reshape(bh, s, dqk)
     vf = v.reshape(bh, s, dv)
-    kv_index = _kv_index(causal, bq, bk)
+    kv_index = _kv_index(causal, bq, bk, window)
     out, lse = pl.pallas_call(
-        _fwd_kernel_factory(bq, bk, nk, causal, scale),
+        _fwd_kernel_factory(bq, bk, nk, causal, scale, window),
         out_shape=(
             jax.ShapeDtypeStruct((bh, s, dv), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32, vma=vma),
@@ -226,7 +304,7 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=FWD_KERNEL,
+        name=FWD_KERNEL if window is None else FWD_WIN_KERNEL,
     )(qf, kf, vf)
     return out.reshape(b, h, s, dv), lse
 
@@ -236,26 +314,45 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale):
+def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
+    """``nq``, ``nk``: the sequence's query and key blocks.  ``steps``: the
+    innermost grid axis's length — ``nq``, or at a window the band's
+    (:func:`_band_steps`), step ``t`` being query block ``first + t`` of its
+    key block."""
     from jax.experimental import pallas as pl
+
+    steps = nq if steps is None else steps
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
         j = pl.program_id(1)   # key block (sequential: dQ accumulates over it)
-        qi = pl.program_id(2)  # query block (sequential, innermost)
+        step = qi = pl.program_id(2)  # query block (sequential, innermost)
+        if window is not None:
+            qi = _first_q_block(j, bq, bk) + step
         rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query block's of dq_scr
 
-        @pl.when(j == 0)
-        def _init_dq():
+        def clear_dq():
             dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
 
-        @pl.when(qi == 0)
+        def write_dq():
+            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+
+        if window is None:
+            pl.when(j == 0)(clear_dq)
+
+        @pl.when(step == 0)
         def _init_dkv():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
 
-        @pl.when(_block_needed(causal, qi, j, bq, bk))
+        needed = _block_needed(causal, qi, j, bq, bk, window)
+        if window is not None:
+            needed &= qi < nq  # the band's steps past the sequence's end
+
+        @pl.when(needed)
         def _block():
+            if window is not None:  # the first key block this query block meets
+                pl.when(j == _first_kv_block(qi, bq, bk, window))(clear_dq)
             # everything (bk, bq), keys down and queries across: lse and Δ are
             # then one row, and of the five products only dQ's takes its left
             # operand transposed
@@ -265,7 +362,8 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale):
             ) * scale
             pt = jnp.exp(st - lse_ref[0])
             if causal:
-                pt = jnp.where(_causal_keep(qi, j, bq, bk, keys_down=True), pt, 0.0)
+                pt = jnp.where(_causal_keep(qi, j, bq, bk, keys_down=True, window=window),
+                               pt, 0.0)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
                 pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -281,15 +379,16 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale):
             dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
                 dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
+            if window is not None:  # the running sum; a block's last pair writes it whole
+                write_dq()
 
-        @pl.when(qi == nq - 1)
+        @pl.when(step == steps - 1)
         def _emit_dkv():
             dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
-        @pl.when(j == nk - 1)
-        def _emit_dq():
-            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+        if window is None:
+            pl.when(j == nk - 1)(write_dq)
 
     return kernel
 
@@ -309,7 +408,7 @@ def _bwd_vmem_bytes(s, bq, bk, dqk, dv, itemsize) -> int:
 
 
 def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
-                    dlse=None):
+                    dlse=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -332,20 +431,22 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     # one value a row, laid along the lanes: a (1, bq) block of (bh, 1, s)
     delta, lse = delta.reshape(bh, 1, s), lse.reshape(bh, 1, s)
 
-    q_index = _q_index(causal, bq, bk)
+    steps = nq if window is None else _band_steps(s, bq, bk, window)[1]
+    q_index = _q_index(causal, bq, bk, window, nq)
     row_index = lambda i, j, qi: (i, 0, q_index(i, j, qi)[1])  # noqa: E731
     kv_index = lambda i, j, qi: (i, j, 0)  # noqa: E731
     # dQ's block leaves VMEM once: its index stands still until the last key
-    # block, whose steps write one query block each
+    # block, whose steps write one query block each.  At a window it follows
+    # the query blocks, each pair writing the running sum
     dq_index = lambda i, j, qi: (i, jnp.where(j == nk - 1, qi, 0), 0)  # noqa: E731
     dq, dk, dv_ = pl.pallas_call(
-        _bwd_kernel_factory(bq, bk, nq, nk, causal, scale),
+        _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window, steps),
         out_shape=(
             jax.ShapeDtypeStruct((bh, s, dqk), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, dqk), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, s, dv), v.dtype, vma=vma),
         ),
-        grid=(bh, nk, nq),
+        grid=(bh, nk, steps),
         in_specs=[
             pl.BlockSpec((1, bq, dqk), q_index),
             pl.BlockSpec((1, bk, dqk), kv_index),
@@ -355,7 +456,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
             pl.BlockSpec((1, 1, bq), row_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bq, dqk), dq_index),
+            pl.BlockSpec((1, bq, dqk), dq_index if window is None else q_index),
             pl.BlockSpec((1, bk, dqk), kv_index),
             pl.BlockSpec((1, bk, dv), kv_index),
         ),
@@ -371,7 +472,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
             vmem_limit_bytes=_bwd_vmem_bytes(s, bq, bk, dqk, dv, q.dtype.itemsize),
         ),
         interpret=interpret,
-        name=BWD_KERNEL,
+        name=BWD_KERNEL if window is None else BWD_WIN_KERNEL,
     )(qf, kf, vf, dof, lse, delta)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape))
@@ -382,9 +483,9 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, bq, bk, interpret):
-    out, _ = _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, bq, bk, interpret, window=None):
+    out, _ = _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window)
     return out
 
 
@@ -395,35 +496,37 @@ def _residuals(q, k, v, out, lse):
     return out, lse, (q, k, v, out, lse)
 
 
-def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret):
-    out, _, res = _residuals(q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret))
+def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, window=None):
+    out, _, res = _residuals(
+        q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret, window))
     return out, res
 
 
-def _flash_bwd(causal, scale, bq, bk, interpret, res, g):
+def _flash_bwd(causal, scale, bq, bk, interpret, window, res, g):
     q, k, v, o, lse = res
-    return _flash_backward(q, k, v, o, lse, g, causal, scale, bq, bk, interpret)
+    return _flash_backward(q, k, v, o, lse, g, causal, scale, bq, bk, interpret, window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, causal, scale, bq, bk, interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, causal, scale, bq, bk, interpret, window=None):
+    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window)
     return out, lse[..., 0].reshape(q.shape[:3])  # (b, h, s)
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, bq, bk, interpret):
-    out, lse, res = _residuals(q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret))
+def _flash_lse_fwd(q, k, v, causal, scale, bq, bk, interpret, window=None):
+    out, lse, res = _residuals(
+        q, k, v, *_flash_forward(q, k, v, causal, scale, bq, bk, interpret, window))
     return (out, lse.reshape(q.shape[:3])), res
 
 
-def _flash_lse_bwd(causal, scale, bq, bk, interpret, res, g):
+def _flash_lse_bwd(causal, scale, bq, bk, interpret, window, res, g):
     q, k, v, o, lse = res
     do, dlse = g
     return _flash_backward(
-        q, k, v, o, lse, do, causal, scale, bq, bk, interpret, dlse=dlse
+        q, k, v, o, lse, do, causal, scale, bq, bk, interpret, dlse=dlse, window=window
     )
 
 
@@ -441,27 +544,38 @@ _tuned_cache: Optional[dict] = None
 
 
 def _tuned_table() -> dict:
+    """The artifact's two tables: ``blocks`` by sequence, ``banded`` by
+    (sequence, window); each empty where the file has none that reads."""
     global _tuned_cache
     if _tuned_cache is None:
+        _tuned_cache = {"blocks": {}, "banded": {}}
         try:
             with open(_TUNED_PATH) as f:
-                _tuned_cache = {
-                    int(k): tuple(v)
-                    for k, v in json.load(f)["blocks"].items()
-                }
+                doc = json.load(f)
+            _tuned_cache["blocks"] = {int(k): tuple(v) for k, v in doc["blocks"].items()}
+            _tuned_cache["banded"] = {tuple(int(x) for x in k.split(",")): tuple(v)
+                                      for k, v in doc.get("banded", {}).items()}
         except (OSError, ValueError, KeyError, TypeError, AttributeError):
-            _tuned_cache = {}
+            pass
     return _tuned_cache
 
 
-def tuned_blocks(seq: int) -> tuple:
+def tuned_blocks(seq: int, window: Optional[int] = None) -> tuple:
     """Best (block_q, block_k) for this sequence length, from the on-chip
     sweep artifact (tools/flash_tune.py → ops/flash_blocks.json).  Falls
     back to the nearest tuned seq below whose blocks DIVIDE this seq
     (block choice varies slowly with S, and a non-dividing block is an
     error on a TPU), then to (128, 128) — the MXU-aligned safe default.
-    Callers passing explicit block sizes bypass this table."""
-    table = _tuned_table()
+    Callers passing explicit block sizes bypass this table.
+
+    A banded call (``window``) has a table of its own, keyed by (sequence,
+    window): a band is a few blocks wide, so smaller blocks compute less
+    outside it.  Where that has no entry that divides, the sequence's."""
+    tables = _tuned_table()
+    banded = tables["banded"].get((seq, window))
+    if banded and seq % banded[0] == 0 and seq % banded[1] == 0:
+        return banded
+    table = tables["blocks"]
 
     def fits(entry) -> bool:
         bq, bk = entry
@@ -503,10 +617,13 @@ def _kernel_path(s: int, bq: int, bk: int, interpret: bool) -> bool:
     return interpret and divides
 
 
-def _resolve(q, scale, block_q, block_k):
+def _resolve(q, scale, block_q, block_k, causal=True, window=None):
     """Defaults filled in: (scale, block_q, block_k) for this q."""
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"flash attention: window={window} is a causal band of at least the "
+                         "query itself (causal=True, window >= 1)")
     s, dh = q.shape[2], q.shape[3]
-    tq, tk = tuned_blocks(s)
+    tq, tk = tuned_blocks(s, window)
     bq = min(block_q if block_q is not None else tq, s)
     bk = min(block_k if block_k is not None else tk, s)
     return (scale if scale is not None else dh**-0.5), bq, bk
@@ -521,17 +638,18 @@ def flash_attention_lse(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> tuple:
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(b, h, s)`` — the hook ring attention needs to merge per-hop partial
     attention online (o, lse merging is exact: L = logaddexp(L_a, L_b),
     o = o_a·e^{L_a−L} + o_b·e^{L_b−L}).  Differentiable in (q, k, v)
     including the lse output (its cotangent folds into the backward's
-    delta term)."""
-    scale, bq, bk = _resolve(q, scale, block_q, block_k)
+    delta term).  ``window``: as :func:`flash_attention`'s."""
+    scale, bq, bk = _resolve(q, scale, block_q, block_k, causal, window)
     if not _kernel_path(q.shape[2], bq, bk, interpret):
-        return _dense_reference_lse(q, k, v, causal, scale)
-    return _flash_lse(q, k, v, causal, scale, bq, bk, interpret)
+        return _dense_reference_lse(q, k, v, causal, scale, window)
+    return _flash_lse(q, k, v, causal, scale, bq, bk, interpret, window)
 
 
 def flash_attention(
@@ -543,14 +661,17 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """q/k: (B, H, S, d_qk), v: (B, H, S, d_v) → (B, H, S, d_v); the
     default scale is d_qk**-0.5.
 
     Pallas kernels (fwd + blocked bwd) on a TPU, where S must divide by
     the block sizes; what runs elsewhere is :func:`_kernel_path`'s call.
+    ``window=W`` (with ``causal=True``; anything else raises): query ``i``
+    sees key ``j`` iff ``0 <= i - j < W``, by the banded kernels.
     """
-    scale, bq, bk = _resolve(q, scale, block_q, block_k)
+    scale, bq, bk = _resolve(q, scale, block_q, block_k, causal, window)
     if not _kernel_path(q.shape[2], bq, bk, interpret):
-        return _dense_reference(q, k, v, causal, scale)
-    return _flash(q, k, v, causal, scale, bq, bk, interpret)
+        return _dense_reference(q, k, v, causal, scale, window)
+    return _flash(q, k, v, causal, scale, bq, bk, interpret, window)

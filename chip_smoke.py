@@ -43,6 +43,12 @@ the entry points a user calls, at the full width of the models the repo lists:
          short-convolution mixers and one grouped-query attention layer at
          heads of 64, a leading dense layer, 8 of 64 sigmoid-routed experts),
          as leg F.
+  leg H  the sliding-window / global-attention MoE family at the published
+         widths of benchmark/configs/trinity_mini_ep16.json (four
+         sliding-window layers through the banded flash kernels and one
+         global layer without positions, gated attention at heads of 128,
+         sandwich norms, a leading dense layer, 8 of 128 sigmoid-routed
+         experts beside a shared expert), as leg F.
 
 Every result line names the platform, device kind, device count and the jax /
 jaxlib / libtpu versions.  Step times are printed as information only: they
@@ -722,6 +728,23 @@ LEG_G_LIMITS = {
 }
 
 
+#: leg H's limits.  The first gradient tells the nearest precision below
+#: apart here too (readings on the chip, PR 42, tools/latent_moe_precision.py
+#: --config trinity_mini_ep16, 3 seeds, and this leg's own key: the program |
+#: the reference with bf16 statistics — loss <= 3.4e-5 | 4.3e-5; routers and
+#: routed experts 0.181-0.192 | 0.240-0.255, always moe.router; other leaves
+#: at most 0.044-0.048 | 0.059-0.066, moe.norm or moe.post_norm (the shared
+#: expert carries every token's gradient there whichever way a near-tie
+#: falls, so they read a third of lfm2's); median 0.035-0.038 | 0.047-0.051;
+#: projection within 0.027 of 1 on both sides), so each limit stands between
+#: its two readings; the projection's stands against a planted fault (a halved
+#: gradient reads 0.5).
+LEG_H_LIMITS = {
+    "as made": {"loss": 1.7e-4, "routed": 0.215, "rest": 0.054, "median": 0.043,
+                "projection": 0.15},
+}
+
+
 def _reference_leg(dry: bool, leg: str, config: str, what, cases, limits: dict) -> None:
     """One step of ``benchmark/configs/<config>.json`` through
     ``build_train_step`` with an optimizer that keeps the gradient: loss and
@@ -812,6 +835,18 @@ def leg_g(dry: bool) -> None:
         {"as made": lambda params, cfg: params}, LEG_G_LIMITS)
 
 
+def leg_h(dry: bool) -> None:
+    """The sliding-window / global-attention MoE family.  One case, as legs F
+    and G."""
+    _reference_leg(
+        dry, "H", "trinity_mini_ep16",
+        lambda cfg: (f"Trinity-Mini share: {cfg['num_hidden_layers']} layers from entry "
+                     f"{cfg['first_layer']} of the published list, {cfg['num_dense_layers']} "
+                     f"dense, window {cfg['sliding_window']}, {cfg['num_experts']} of "
+                     f"{cfg['router_width']} experts"),
+        {"as made": lambda params, cfg: params}, LEG_H_LIMITS)
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -823,8 +858,8 @@ def main() -> int:
         help="pre-flight on the CPU at cut sizes with interpreted kernels; "
              "proves the control flow only, never a chip result",
     )
-    ap.add_argument("--legs", default="ABCDEFG",
-                    help="the legs to run, e.g. G (all by default; B's children start anyway)")
+    ap.add_argument("--legs", default="ABCDEFGH",
+                    help="the legs to run, e.g. H (all by default; B's children start anyway)")
     args = ap.parse_args()
     dry, legs = args.cpu_dry_run, set(args.legs.upper())
     if dry:
@@ -894,6 +929,7 @@ def main() -> int:
         run("E", lambda: leg_e(dry))
         run("F", lambda: leg_f(dry))
         run("G", lambda: leg_g(dry))
+        run("H", lambda: leg_h(dry))
 
         check_children(children)
         bps.shutdown()

@@ -140,6 +140,214 @@ class TestFlashAttention:
             assert s * lanes * 4 < asked < 100 * 2**20, (s, d_qk, asked)
 
 
+class TestFlashBand:
+    """``window=W``: query i sees key j iff 0 <= i - j < W.  The banded
+    kernels (interpret mode) against the dense mask, forward and dQ, dK, dV."""
+
+    @staticmethod
+    def _fa():
+        import importlib
+
+        return importlib.import_module("byteps_tpu.ops.flash_attention")
+
+    @staticmethod
+    def _qkv(d_qk=32, d_v=32, s=256, seed=7):
+        rng = np.random.default_rng(seed)
+        return tuple(jnp.asarray(rng.normal(size=(1, 2, s, d)).astype(np.float32))
+                     for d in (d_qk, d_qk, d_v, d_v))
+
+    def _both(self, window, bq, bk, d_qk=32, d_v=32, s=256):
+        """((out, dQ, dK, dV) of the kernels, the same of the dense mask)."""
+        fa = self._fa()
+        q, k, v, ct = self._qkv(d_qk, d_v, s)
+
+        def run(attend):
+            out, grads = jax.value_and_grad(
+                lambda q, k, v: (lambda o: (jnp.sum(o * ct), o))(attend(q, k, v)),
+                argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (out[1],) + grads
+
+        return (run(lambda q, k, v: fa.flash_attention(
+                    q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True, window=window)),
+                run(lambda q, k, v: fa._dense_reference(q, k, v, True, d_qk ** -0.5, window)))
+
+    def test_the_dense_mask_is_the_published_one(self):
+        """kv > q - sliding_window, causal: at window 2 a query sees itself
+        and the key before it."""
+        fa = self._fa()
+        q = jnp.zeros((1, 1, 4, 8))
+        v = jnp.eye(4)[None, None]
+        got = fa._dense_reference(q, q, v, True, 1.0, 2)[0, 0]  # uniform over what is seen
+        np.testing.assert_allclose(got, [[1, 0, 0, 0], [.5, .5, 0, 0], [0, .5, .5, 0],
+                                         [0, 0, .5, .5]], atol=1e-6)
+
+    # (window, block_q, block_k, d_qk, d_v) at sequence 256: W under, at and
+    # over a block; W no multiple of the block; W = 1 (the diagonal alone);
+    # block_q != block_k both ways round; d_qk != d_v; one block a side
+    @pytest.mark.parametrize("window,bq,bk,d_qk,d_v", [
+        (32, 64, 64, 32, 32), (64, 64, 64, 32, 32), (128, 64, 64, 32, 32),
+        (100, 64, 64, 48, 32), (1, 64, 64, 32, 32), (37, 32, 32, 32, 48),
+        (64, 128, 32, 48, 32), (64, 32, 128, 48, 32), (96, 256, 64, 32, 32),
+        (70, 64, 256, 32, 48), (200, 256, 256, 32, 32),
+    ])
+    def test_band_matches_the_dense_mask_forward_and_backward(self, window, bq, bk, d_qk, d_v):
+        got, want = self._both(window, bq, bk, d_qk, d_v)
+        for name, g, w in zip(("out", "dQ", "dK", "dV"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("window,bq,bk", [(256, 64, 64), (1000, 64, 32), (256, 128, 256)])
+    def test_a_window_over_the_sequence_is_causal_bit_for_bit(self, window, bq, bk):
+        fa = self._fa()
+        q, k, v, ct = self._qkv()
+
+        def run(**kw):
+            return jax.value_and_grad(
+                lambda q, k, v: (lambda o: (jnp.sum(o * ct), o))(fa.flash_attention(
+                    q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True, **kw)),
+                argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+        (_, out_w), grads_w = run(window=window)
+        (_, out_c), grads_c = run()
+        for a, b in zip((out_w,) + grads_w, (out_c,) + grads_c):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_every_query_block_of_dq_is_written(self):
+        """At a window the LAST key block meets only the last query blocks:
+        dQ's blocks are written as their own pairs pass, not while the last
+        key block's steps do.  Sixteen query blocks, a band at most three
+        blocks wide: every block of dQ holds its gradient."""
+        fa = self._fa()
+        got, want = self._both(20, 16, 16)
+        assert fa._band_steps(256, 16, 16, 20) == (3, 3)
+        dq = np.asarray(got[1])
+        assert np.all(np.isfinite(dq))
+        for block in range(16):
+            rows = slice(16 * block, 16 * (block + 1))
+            assert np.any(dq[:, :, rows] != 0), block
+            np.testing.assert_allclose(dq[:, :, rows], np.asarray(want[1])[:, :, rows],
+                                       rtol=2e-3, atol=2e-4)
+
+    def test_the_band_is_as_wide_as_its_blocks_not_as_the_sequence(self):
+        """The banded kernels' innermost grid axis: 3 blocks at the cell's
+        1024 x 1024 over 16 384 tokens (16 without the band), 5 at 512."""
+        fa = self._fa()
+        assert fa._band_steps(16384, 1024, 1024, 2048) == (3, 3)
+        assert fa._band_steps(16384, 512, 512, 2048) == (5, 5)
+        assert fa._band_steps(16384, 1024, 512, 2048) == (6, 3)
+        assert fa._band_steps(256, 64, 64, 256) == (4, 4)  # the whole causal triangle
+
+    def test_lse_output_and_its_cotangent_under_a_window(self):
+        fa = self._fa()
+        q, k, v, ct = self._qkv()
+
+        def loss(attend):
+            def f(q, k, v):
+                o, lse = attend(q, k, v)
+                return jnp.sum(o * ct) + jnp.sum(jnp.sin(lse))
+            return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+        got = loss(lambda q, k, v: fa.flash_attention_lse(
+            q, k, v, causal=True, block_q=64, block_k=32, interpret=True, window=48))
+        want = loss(lambda q, k, v: fa._dense_reference_lse(q, k, v, True, 32 ** -0.5, 48))
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+        for g, w in zip(got[1], want[1]):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4)
+
+    @pytest.mark.parametrize("kw", [dict(causal=False, window=8), dict(causal=True, window=0)])
+    def test_a_window_that_is_no_causal_band_raises(self, kw):
+        fa = self._fa()
+        q = jnp.zeros((1, 1, 64, 16))
+        for attend in (fa.flash_attention, fa.flash_attention_lse):
+            with pytest.raises(ValueError, match="causal band"):
+                attend(q, q, q, **kw)
+
+    def test_banded_calls_carry_their_own_kernel_names(self):
+        fa = self._fa()
+        q = jnp.ones((1, 2, 128, 32), jnp.float32)
+
+        def kernels(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn.params["name"]
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from kernels(sub)
+
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=64, block_k=32,
+                                              interpret=True, window=40))
+
+        names = sorted(kernels(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr))
+        assert names == ["flash_bwd_win", "flash_fwd_win"]
+        assert (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) == ("flash_fwd_win", "flash_bwd_win")
+
+    #: sha256 of what ``window=None`` lowers to, frozen at the parent of the PR
+    #: that brought the band: the StableHLO text of a gradient's program in
+    #: interpret mode ((causal, lse output) → digest), and the jaxpr of the
+    #: TPU path at qwen3-next's shape, kernels' bodies included (a Mosaic
+    #: module's own text holds the file's line numbers and cannot be frozen)
+    FROZEN_WITHOUT_A_WINDOW = {
+        (True, False): "6d387562cec12f1e", (True, True): "15062387814322b4",
+        (False, False): "86d5adc5ec66e202", (False, True): "d2905411e9475f56",
+        "tpu_jaxpr": "410cbf3fa02cea60",
+    }
+
+    @pytest.mark.parametrize("causal,lse_out", [(True, False), (True, True), (False, False),
+                                                (False, True)])
+    def test_without_a_window_the_kernels_lower_as_before(self, causal, lse_out):
+        import hashlib
+
+        fa = self._fa()
+
+        def loss(q, k, v):
+            if lse_out:
+                o, lse = fa.flash_attention_lse(q, k, v, causal=causal, block_q=64, block_k=32,
+                                                interpret=True)
+                return jnp.sum(o) + jnp.sum(lse)
+            return jnp.sum(fa.flash_attention(q, k, v, causal=causal, block_q=64, block_k=32,
+                                              interpret=True))
+
+        q, v = jnp.ones((1, 2, 128, 32), jnp.float32), jnp.ones((1, 2, 128, 48), jnp.float32)
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            self.FROZEN_WITHOUT_A_WINDOW[causal, lse_out]
+
+    def test_without_a_window_the_tpu_path_traces_as_before(self, monkeypatch):
+        import hashlib
+
+        fa = self._fa()
+        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16)
+        grad = jax.grad(lambda q, k, v: jnp.sum(
+            fa.flash_attention(q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
+        text = str(jax.make_jaxpr(grad)(q, q, q))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            self.FROZEN_WITHOUT_A_WINDOW["tpu_jaxpr"]
+
+    def test_banded_blocks_have_a_table_of_their_own(self, monkeypatch, tmp_path):
+        import json
+
+        fa = self._fa()
+        path = tmp_path / "flash_blocks.json"
+        path.write_text(json.dumps({"blocks": {"512": [256, 256]},
+                                    "banded": {"512,128": [64, 128], "512,96": [100, 100]}}))
+        monkeypatch.setattr(fa, "_TUNED_PATH", str(path))
+        monkeypatch.setattr(fa, "_tuned_cache", None)
+        assert fa.tuned_blocks(512) == (256, 256)  # the plain entry, untouched
+        assert fa.tuned_blocks(512, 128) == (64, 128)
+        assert fa.tuned_blocks(512, 64) == (256, 256)  # no banded entry: the sequence's
+        assert fa.tuned_blocks(512, 96) == (256, 256)  # one that does not divide is not used
+
+    def test_the_committed_tables(self, monkeypatch):
+        """The existing entries stand as they were; the cell's banded entry is there."""
+        fa = self._fa()
+        monkeypatch.setattr(fa, "_tuned_cache", None)
+        assert [fa.tuned_blocks(s) for s in (512, 1024, 2048, 8192, 16384)] == [
+            (512, 512), (512, 512), (512, 512), (1024, 1024), (1024, 1024)]
+        bq, bk = fa.tuned_blocks(16384, 2048)
+        assert 16384 % bq == 0 and 16384 % bk == 0 and (16384, 2048) in fa._tuned_table()["banded"]
+
+
 class TestOneBitDevice:
     # a block multiple; the engine's default partition (BYTEPS_PARTITION_BYTES
     # / 4, NOT a block multiple: padded on the device); a ragged tail
@@ -436,9 +644,9 @@ class TestTunedBlocks:
         seen = {}
         orig_flash = fa._flash
 
-        def spy(q, k, v, causal, scale, bq, bk, interpret):
+        def spy(q, k, v, causal, scale, bq, bk, interpret, window=None):
             seen["blocks"] = (bq, bk)
-            return orig_flash(q, k, v, causal, scale, bq, bk, interpret)
+            return orig_flash(q, k, v, causal, scale, bq, bk, interpret, window)
 
         monkeypatch.setattr(fa, "_flash", spy)
         rng = np.random.default_rng(0)

@@ -236,7 +236,9 @@ class TestMigrationWire:
                 st.store, g2
             )  # round-2 publish traveled bitwise
             assert a._keys[key].migrated_to == 1  # tombstone at old owner
-            assert a._keys[key].store is None     # bulk freed
+            # the old owner frees the bulk once the new one has acknowledged
+            _wait(lambda: a._keys[key].store is None,
+                  msg="the old owner never freed the migrated key's bulk")
             # stale-map push to the OLD owner redirects with the epoch
             send_message(w, Message(Op.PUSH, key=key, seq=9, flags=1,
                                     cmd=CMD_F32, version=3,

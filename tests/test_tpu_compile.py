@@ -132,6 +132,32 @@ def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_c
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["banded", "global"])
+def test_flash_kernels_compile_at_the_sliding_window_shape(one_chip, no_compile_cache,
+                                                           monkeypatch, window):
+    """(1, 32, 16384, 128 | 128) — Trinity-Mini's mixers with the 4 key/value
+    heads repeated: the banded pair at window 2048 with the blocks
+    ops/flash_blocks.json commits for (16384, 2048), an innermost grid axis
+    as long as the band is wide and not as the sequence; and the full causal
+    pair of the global layer at the sequence's plain entry."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    bq, bk = fa.tuned_blocks(16384, window)
+    assert (16384, 2048) in fa._tuned_table()["banded"]
+    assert max(fa._band_steps(16384, bq, bk, 2048)) <= 6 < 16384 // max(bq, bk)
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=128 ** -0.5, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
+    wanted = (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) if window else (fa.FWD_KERNEL, fa.BWD_KERNEL)
+    for kernel in wanted:
+        assert kernel in text, f"{kernel} is not in the compiled program"
+    assert (fa.FWD_WIN_KERNEL in text) == bool(window)
+    assert text.count("tpu_custom_call") >= 2
+
+
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
     """2 x 8192 tokens, 2048 channels, 3 taps, bf16 operands: the double-gated
     short convolution between its two projections and its four gradients.

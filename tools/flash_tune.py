@@ -18,6 +18,19 @@ of one sequence disagree the entry is the one that costs the cells least:
         --blocks 256,512,1024 --no-dense --out chiprun_out/flash_blocks.json
     python tools/flash_tune.py --seqs 16384 --bh 1,16 --dh 256 --dv 256 ...
     python tools/flash_tune.py --seqs 8192 --bh 2,32 --dh 64 --dv 64 ...
+
+``--window W`` sweeps the BANDED kernels (query i sees key j iff 0 <= i - j <
+W; ``flash_fwd_win`` / ``flash_bwd_win``) and writes the winners to the
+artifact's second table, ``banded``, keyed by "sequence,window"; the plain
+entries stay as they are.  The sweep behind its (16384, 2048) entry, the
+sliding-window layers of ``trinity_mini_ep16_train16k``; ``--check`` first
+holds the kernels at each block pair to the dense mask (out, dQ, dK, dV,
+queries a block at a time in f32), and ``--causal-too`` times the full causal
+kernels at the sequence's plain entry beside them:
+
+    python tools/flash_tune.py --seqs 16384 --bh 1,32 --dh 128 --window 2048 \
+        --blocks 256,512,1024 --no-dense --check --causal-too \
+        --out chiprun_out/flash_blocks.json
 """
 
 import argparse
@@ -43,6 +56,15 @@ def main() -> int:
     ap.add_argument("--out", default="",
                     help="where to write the winners (default: ops/flash_blocks.json)")
     ap.add_argument("--steps", type=int, default=10)
+    ap.add_argument("--window", type=int, default=0,
+                    help="sweep the banded kernels at this window (0: the full causal ones)")
+    ap.add_argument("--check", action="store_true",
+                    help="with --window: hold every block pair to the dense mask first")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU pre-flight of the control flow: kernels interpreted, nothing "
+                         "written, never a timing")
+    ap.add_argument("--causal-too", action="store_true",
+                    help="with --window: also time the full causal kernels at the plain entry")
     ap.add_argument("--no-write", action="store_true",
                     help="don't persist winners to ops/flash_blocks.json")
     args = ap.parse_args()
@@ -50,15 +72,56 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    if jax.devices()[0].platform != "tpu":
-        print("not on TPU — refusing (flash timings need real Mosaic)")
+    if (jax.devices()[0].platform == "tpu") == args.rehearse:
+        print("not on TPU — refusing (flash timings need real Mosaic; --rehearse stays off it)")
         return 2
+    if args.rehearse:
+        args.no_write = True
 
     from byteps_tpu.ops.flash_attention import flash_attention, _dense_reference
 
     b, h = (int(x) for x in args.bh.split(","))
     dh, dv = args.dh, args.dv or args.dh
     blocks = [int(x) for x in args.blocks.split(",")]
+    window = args.window or None
+
+    def dense_banded_grads(q, k, v, ct, block=512):
+        """(out, dQ, dK, dV) of sum(out * ct) under the band in f32, a block
+        of queries at a time against the ``window + block`` keys that end
+        with it (the keys padded by the window at the front: their positions
+        are negative and nobody sees them)."""
+        s = q.shape[2]
+        span, block = min(window, s), min(block, s)
+        q, k, v, ct = (x.astype(jnp.float32) for x in (q, k, v, ct))
+        pad = ((0, 0), (0, 0), (span, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+
+        @jax.jit
+        def one(q, k, v, ct, dk, dv_, first):
+            cut = lambda x, n: jax.lax.dynamic_slice_in_dim(x, first, n, axis=2)  # noqa: E731
+
+            def loss(qb, kb, vb):
+                sc = jnp.einsum("bhqd,bhkd->bhqk", qb, kb, precision="highest") * dh ** -0.5
+                qp = first + jnp.arange(block)[:, None]
+                kp = first - span + jnp.arange(span + block)[None, :]
+                seen = (kp >= 0) & (kp <= qp) & (kp > qp - window)
+                p = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), -1)
+                out = jnp.einsum("bhqk,bhkd->bhqd", p, vb, precision="highest")
+                return jnp.sum(out * cut(ct, block)), out
+
+            (_, out), (gq, gk, gv) = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                cut(q, block), cut(k, span + block), cut(v, span + block))
+            add = lambda acc, g: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
+                acc, cut(acc, span + block) + g, first, axis=2)
+            return out, gq, add(dk, gk), add(dv_, gv)
+
+        outs, dqs = [], []
+        dk, dv_ = jnp.zeros_like(k), jnp.zeros_like(v)
+        for first in range(0, s, block):
+            out, gq, dk, dv_ = one(q, k, v, ct, dk, dv_, first)
+            outs.append(out)
+            dqs.append(gq)
+        return jnp.concatenate(outs, 2), jnp.concatenate(dqs, 2), dk[:, :, span:], dv_[:, :, span:]
 
     def time_fn(fn, *xs, grad=True):
         loss = lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)  # noqa: E731
@@ -83,18 +146,41 @@ def main() -> int:
             if args.no_dense:
                 raise RuntimeError("skipped by --no-dense")
             dense_ms = time_fn(
-                lambda q, k, v: _dense_reference(q, k, v, True, dh ** -0.5), q, k, v
+                lambda q, k, v: _dense_reference(q, k, v, True, dh ** -0.5, window), q, k, v
             )
         except Exception as e:  # noqa: BLE001 (dense S^2 can OOM at long S)
             dense_ms = None
             print(f"seq {s}: dense failed ({type(e).__name__})")
         best = None
+        if window and args.check:
+            ct = jnp.asarray(rng.normal(size=v.shape).astype(np.float32), jnp.bfloat16)
+            want = dense_banded_grads(q, k, v, ct)
+        if window and args.causal_too:
+            causal = lambda q, k, v: flash_attention(  # noqa: E731
+                q, k, v, causal=True, interpret=args.rehearse)
+            ms, fwd_ms = time_fn(causal, q, k, v), time_fn(causal, q, k, v, grad=False)
+            print(f"seq {s} full causal kernels at the plain entry: {ms:8.2f} ms "
+                  f"(forward alone {fwd_ms:6.2f})")
         for bq in blocks:
             for bk in blocks:
                 if s % bq or s % bk:
                     continue
                 flash = lambda q, k, v, bq=bq, bk=bk: flash_attention(  # noqa: E731
-                    q, k, v, causal=True, block_q=bq, block_k=bk)
+                    q, k, v, causal=True, block_q=bq, block_k=bk, window=window,
+                    interpret=args.rehearse)
+                if window and args.check:
+                    def weighed(q, k, v, ct, flash=flash):
+                        out = flash(q, k, v)
+                        return jnp.sum(out.astype(jnp.float32) * ct), out
+
+                    (_, out), got = jax.jit(jax.value_and_grad(
+                        weighed, argnums=(0, 1, 2), has_aux=True))(q, k, v, ct)
+                    off = [float(jnp.linalg.norm(a.astype(jnp.float32) - w) / jnp.linalg.norm(w))
+                           for a, w in zip((out,) + got, want)]
+                    print(f"seq {s} window {window} bq={bq} bk={bk}: out, dQ, dK, dV off the "
+                          f"dense mask by {' '.join(f'{x:.2e}' for x in off)} (relative L2)")
+                    if not max(off) < 2e-2:  # bf16 operands against f32
+                        raise SystemExit("the banded kernels disagree with the dense mask")
                 try:
                     ms = time_fn(flash, q, k, v)
                     fwd_ms = time_fn(flash, q, k, v, grad=False)
@@ -138,17 +224,20 @@ def main() -> int:
                 doc = json.load(f)
         except (OSError, ValueError):
             doc = {}
-        blocks = doc.get("blocks", {})
-        meta = doc.get("meta", {})
+        # a banded sweep fills the second table and leaves the first as it is
+        table, notes = ("banded", "banded_meta") if window else ("blocks", "meta")
+        blocks = doc.get(table, {})
+        meta = doc.get(notes, {})
         for s, w in winners.items():
-            blocks[str(s)] = w["blocks"]
-            meta[str(s)] = {
+            entry = f"{s},{window}" if window else str(s)
+            blocks[entry] = w["blocks"]
+            meta[entry] = {
                 "flash_ms": w["flash_ms"], "fwd_ms": w["fwd_ms"],
                 "dense_ms": w["dense_ms"],
                 "bh": args.bh, "dh": dh, "dv": dv,
             }
         with open(path, "w") as f:
-            json.dump({**doc, "blocks": blocks, "meta": meta}, f, indent=1)
+            json.dump({**doc, table: blocks, notes: meta}, f, indent=1)
         print(f"wrote {len(winners)} tuned block entries -> {path}")
     return 0
 
