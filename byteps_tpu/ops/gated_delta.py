@@ -27,6 +27,15 @@ Every exponent is of a non-positive number, so nothing overflows however
 strong the decay.  γ, D, the inverse and S are f32; the products take their
 operands in ``compute_dtype`` and accumulate in f32.
 
+One layout for every caller, **token-major**: q, k ``(B, S, H_k, d_k)``, v and
+o ``(B, S, H_v, d_v)``, g and β ``(B, S, H_v)`` — tokens down, heads side by
+side along the lanes, which is how the projections around the rule write and
+read them.  The kernels take the ``(B, S, H·d)`` array itself and find a head
+by their index maps (a caller's reshape to ``(B, S, H, d)`` and the one back
+cancel; an XLA operation ON the 4-D shape would be a copy on a TPU, whose
+tiles lie over the last two dims); XLA's form turns to head-major inside
+itself.
+
 Two implementations of that one algorithm, chosen by :func:`_kernel_path`
 from what the code can observe (the default device's platform and the
 shapes): on a TPU, at shapes the kernels tile, the Pallas kernels of
@@ -64,10 +73,10 @@ _DEFAULT_BLOCKS = (8, 8, 8)
 
 
 def gated_delta_recurrence(q, k, v, g, beta):
-    """The rule token by token.  q, k (B, H, S, d_k), v (B, H, S, d_v), g and
-    beta (B, H, S); the state is carried in g's dtype.  Returns o (B, H, S, d_v)."""
+    """The rule token by token.  q, k (B, S, H, d_k), v (B, S, H, d_v), g and
+    beta (B, S, H); the state is carried in g's dtype.  Returns o (B, S, H, d_v)."""
     st = g.dtype
-    b, h, _, dk = q.shape
+    b, _, h, dk = q.shape
 
     def token(state, xs):
         q_t, k_t, v_t, g_t, beta_t = xs  # (B, H, d), (B, H)
@@ -76,9 +85,9 @@ def gated_delta_recurrence(q, k, v, g, beta):
         state = state + k_t[..., :, None] * u[..., None, :]
         return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
 
-    xs = tuple(jnp.moveaxis(x.astype(st), 2, 0) for x in (q, k, v, g, beta))
+    xs = tuple(jnp.moveaxis(x.astype(st), 1, 0) for x in (q, k, v, g, beta))
     _, o = lax.scan(token, jnp.zeros((b, h, dk, v.shape[-1]), st), xs)
-    return jnp.moveaxis(o, 0, 2)
+    return jnp.moveaxis(o, 0, 1)
 
 
 def _inverse_by_blocks(a):
@@ -182,16 +191,16 @@ def tuned_blocks(n_chunks: int, chunk: int, blocks: Optional[Sequence[int]] = No
 
 def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype=None,
                              interpret: bool = False, blocks: Optional[Sequence[int]] = None):
-    """q, k (B, H_k, S, d_k) — normalised and scaled by the caller —, v
-    (B, H_v, S, d_v), g ≤ 0 and beta (B, H_v, S); each key head serves
+    """q, k (B, S, H_k, d_k) — normalised and scaled by the caller —, v
+    (B, S, H_v, d_v), g ≤ 0 and beta (B, S, H_v); each key head serves
     H_v / H_k value heads in a row (it is never repeated in memory).  Returns
-    o (B, H_v, S, d_v) f32.  A sequence that ``chunk`` does not divide raises:
+    o (B, S, H_v, d_v) f32.  A sequence that ``chunk`` does not divide raises:
     padding would have to be the caller's choice (a padded token writes to
     the state unless its beta is 0).  Which implementation runs is
     :func:`_kernel_path`'s call; ``interpret`` asks for the Pallas interpreter
     off a TPU (the CPU tests), ``blocks`` overrides the tuned chunks a grid
     step of the kernels."""
-    s, hk, hv = q.shape[2], q.shape[1], v.shape[1]
+    s, hk, hv = q.shape[1], q.shape[2], v.shape[2]
     if s % chunk:
         raise ValueError(f"gated delta rule: chunk {chunk} does not divide sequence {s}")
     if hv % hk:
@@ -210,7 +219,10 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype
 
 def _chunked_xla(q, k, v, g, beta, chunk, cdt):
     """XLA's form: every chunk's T at once, one ``lax.scan`` over the chunks,
-    the outputs batched products after it; the backward pass is autodiff."""
+    the outputs batched products after it; the backward pass is autodiff.
+    Head-major inside itself: the operands are turned on the way in, o on the
+    way out."""
+    q, k, v, g, beta = (jnp.moveaxis(x, 1, 2) for x in (q, k, v, g, beta))
     b, hk, s, dk = q.shape
     hv, dv = v.shape[1], v.shape[-1]
     f32 = jnp.float32
@@ -262,4 +274,4 @@ def _chunked_xla(q, k, v, g, beta, chunk, cdt):
     o = e_gamma[..., None] * product("bhnik,bhrnkv->bhrniv", q, entering)
     qk = product("bhnik,bhnjk->bhnij", q, k)[:, :, None]
     o = o + product("bhrnij,bhrnjv->bhrniv", decay * qk, u)
-    return o.reshape(b, hv, s, dv)
+    return jnp.moveaxis(o.reshape(b, hv, s, dv), 1, 2)

@@ -23,6 +23,15 @@ dS, a chunk's forward rebuilt from T and its entering state; the inverse's
 rule ``dA = −Tᵀ dT Tᵀ`` at f32 accuracy; gradients for q, k (summed over
 their value heads), v, g and β.
 
+The operands are token-major, as the projections around the rule write and
+read them: q, k ``(B, S, H_k·d_k)``, v, o and their cotangents
+``(B, S, H_v·d_v)``, heads side by side along the lanes.  Grid cell ``(i, j)``
+finds key head ``i % H_k`` of batch ``i // H_k`` by its index map — a
+``(chunks·C, d_k)`` block at column block ``i % H_k``, and the head's ``r``
+value heads as one block ``r·d_v`` lanes wide — so no head-major copy of any
+of them exists.  g, β, T and the entering states are the kernels' own: value
+heads down the rows, ``(B·H_v, …)``.
+
 γ, D, T and the states are f32; every other product takes its operands in the
 compute dtype and accumulates in f32.  Per-token scalars arrive as rows
 (chunk positions along the lanes) and are turned into columns by a masked
@@ -126,13 +135,13 @@ def _inverse_kernel(chunk, w, groups, r):
     return kernel
 
 
-def _chunk_inverse(k, g, beta, chunk, nb, interpret):
-    """k (BH_k, S, d_k), g and beta (BH_v, S) f32 → T (BH_v, N, C, C) f32."""
+def _chunk_inverse(k, g, beta, hk, chunk, nb, interpret):
+    """k (B, S, H_k·d_k), g and beta (BH_v, S) f32 → T (BH_v, N, C, C) f32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bhk, s, dk = k.shape
-    bhv = g.shape[0]
+    b, s, width = k.shape
+    bhk, bhv, dk = b * hk, g.shape[0], width // hk
     r, n = bhv // bhk, s // chunk
     w = max(chunk, STACK)
     groups = nb * chunk // w
@@ -142,7 +151,8 @@ def _chunk_inverse(k, g, beta, chunk, nb, interpret):
         _inverse_kernel(chunk, w, groups, r),
         out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), _F32, vma=_vma(k, g, beta)),
         grid=(bhk, n // nb),
-        in_specs=[pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i, j, 0)), scalars, scalars],
+        in_specs=[pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i // hk, j, i % hk)),
+                  scalars, scalars],
         out_specs=pl.BlockSpec((r, nb, chunk, chunk), lambda i, j: (i, j, 0, 0)),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
@@ -177,6 +187,7 @@ def _fwd_kernel(chunk, nb, r, cdt, save):
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, o_ref, *rest):
         entering_ref, state = rest if save else (None, rest[0])
+        dv = v_ref.shape[-1] // r  # value head h of the key head: lanes h·d_v …
         rows, cols = _iotas(chunk)
         masks = (rows >= cols, rows == cols)
 
@@ -189,11 +200,12 @@ def _fwd_kernel(chunk, nb, r, cdt, save):
             q, k = q_ref[0, at, :], k_ref[0, at, :]
             qk = _dot(q, k, _NT)
             for h in range(r):
-                f = _chunk_forward(q, k, v_ref[h, at, :], t_ref[h, c], g_ref[h, c], b_ref[h, c],
-                                   state[h], masks, cdt)
+                lanes = slice(h * dv, (h + 1) * dv)
+                f = _chunk_forward(q, k, v_ref[0, at, lanes], t_ref[h, c], g_ref[h, c],
+                                   b_ref[h, c], state[h], masks, cdt)
                 if save:
                     entering_ref[h, c] = f["held"]
-                o_ref[h, at, :] = f["e_gamma"] * f["qs"] + _dot(
+                o_ref[0, at, lanes] = f["e_gamma"] * f["qs"] + _dot(
                     (f["decay"] * qk).astype(cdt), f["u_cdt"], _NN)
                 state[h] = f["last"] * state[h] + _dot(k, f["u_to_end"], _TN)
             return carry
@@ -203,33 +215,41 @@ def _fwd_kernel(chunk, nb, r, cdt, save):
     return kernel
 
 
-def _specs(r, nb, chunk, dk, dv, index):
-    """Block specs of a (key heads, blocks of chunks) grid: q | k, v | o | do,
-    g | β, T, the entering states."""
+def _specs(hk, r, nb, chunk, dk, dv, index):
+    """Block specs of a (batch · key heads, blocks of chunks) grid: q | k and
+    v | o | do token-major (cell i is key head i % hk of batch i // hk, its r
+    value heads side by side), g | β, T and the entering states by value head."""
     from jax.experimental import pallas as pl
 
     return dict(
-        qk=pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i, index(j), 0)),
-        v=pl.BlockSpec((r, nb * chunk, dv), lambda i, j: (i, index(j), 0)),
+        qk=pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i // hk, index(j), i % hk)),
+        v=pl.BlockSpec((1, nb * chunk, r * dv), lambda i, j: (i // hk, index(j), i % hk)),
         scalar=pl.BlockSpec((r, nb, 1, chunk), lambda i, j: (i, index(j), 0, 0)),
         t=pl.BlockSpec((r, nb, chunk, chunk), lambda i, j: (i, index(j), 0, 0)),
         state=pl.BlockSpec((r, nb, dk, dv), lambda i, j: (i, index(j), 0, 0)),
     )
 
 
-def _scan_forward(q, k, v, g, beta, t, chunk, nb, save, interpret):
-    """→ o (BH_v, S, d_v) f32 and, if ``save``, every chunk's entering state
+def _dims(q, v, g, hk):
+    """(BH_k, BH_v, r, d_k, d_v) of token-major q (B, S, H_k·d_k) and v
+    (B, S, H_v·d_v) beside g (BH_v, S)."""
+    b, bhv = q.shape[0], g.shape[0]
+    return b * hk, bhv, bhv // (b * hk), q.shape[-1] // hk, v.shape[-1] // (bhv // b)
+
+
+def _scan_forward(q, k, v, g, beta, t, hk, chunk, nb, save, interpret):
+    """→ o (B, S, H_v·d_v) f32 and, if ``save``, every chunk's entering state
     (BH_v, N, d_k, d_v) in the compute dtype."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bhk, s, dk = q.shape
-    bhv, _, dv = v.shape
-    r, n, cdt = bhv // bhk, s // chunk, q.dtype
+    bhk, bhv, r, dk, dv = _dims(q, v, g, hk)
+    s = q.shape[1]
+    n, cdt = s // chunk, q.dtype
     vma = _vma(q, k, v, g, beta, t)
-    spec = _specs(r, nb, chunk, dk, dv, lambda j: j)
+    spec = _specs(hk, r, nb, chunk, dk, dv, lambda j: j)
     by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
-    out_shape = [jax.ShapeDtypeStruct((bhv, s, dv), _F32, vma=vma)]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, _F32, vma=vma)]
     out_specs = [spec["v"]]
     if save:
         out_shape.append(jax.ShapeDtypeStruct((bhv, n, dk, dv), cdt, vma=vma))
@@ -261,6 +281,7 @@ def _bwd_kernel(chunk, nb, r, cdt):
         rows, cols = _iotas(chunk)
         seen, strict, eye = rows >= cols, rows > cols, rows == cols
         is_last = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+        dv = v_ref.shape[-1] // r
 
         @pl.when(pl.program_id(1) == 0)
         def _start():
@@ -278,11 +299,12 @@ def _bwd_kernel(chunk, nb, r, cdt):
             for h in range(r):
                 t, b_row = t_ref[h, c], b_ref[h, c]
                 held = entering_ref[h, c]
-                f = _chunk_forward(q, k, v_ref[h, at, :], t, g_ref[h, c], b_row,
+                lanes = slice(h * dv, (h + 1) * dv)
+                f = _chunk_forward(q, k, v_ref[0, at, lanes], t, g_ref[h, c], b_row,
                                    held, (seen, eye), cdt)
                 decay, e_gamma, to_end = f["decay"], f["e_gamma"], f["to_end"]
                 b_col = _column(b_row, eye)
-                do = do_ref[h, at, :]
+                do = do_ref[0, at, lanes]
                 leaving = dstate[h]  # the cotangent of the state this chunk leaves
                 leaving_cdt = leaving.astype(cdt)
 
@@ -296,7 +318,7 @@ def _bwd_kernel(chunk, nb, r, cdt):
                 dks = (-e_gamma * drhs).astype(cdt)
                 dq = dq + _dot(dqs, held, _NT)
                 dk = dk + _dot(dks, held, _NT) + _dot(f["u_to_end"], leaving_cdt, _NT)
-                dv_ref[h, at, :] = drhs.astype(dv_ref.dtype)
+                dv_ref[0, at, lanes] = drhs.astype(dv_ref.dtype)
                 dstate[h] = f["last"] * leaving + _dot(q, dqs, _TN) + _dot(k, dks, _TN)
 
                 # T = (I + A)⁻¹: dA = −Tᵀ dT Tᵀ, strictly lower
@@ -327,16 +349,17 @@ def _bwd_kernel(chunk, nb, r, cdt):
     return kernel
 
 
-def _scan_backward(q, k, v, g, beta, t, entering, do, chunk, nb, interpret):
+def _scan_backward(q, k, v, g, beta, t, entering, do, hk, chunk, nb, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bhk, s, dk = q.shape
-    bhv, _, dv = v.shape
-    r, n, cdt = bhv // bhk, s // chunk, q.dtype
+    bhk, bhv, r, dk, dv = _dims(q, v, g, hk)
+    s = q.shape[1]
+    n, cdt = s // chunk, q.dtype
     vma = _vma(q, k, v, g, beta, t, entering, do)
     last = n // nb - 1
-    spec = _specs(r, nb, chunk, dk, dv, lambda j: last - j)  # from the last block to the first
+    # from the last block to the first
+    spec = _specs(hk, r, nb, chunk, dk, dv, lambda j: last - j)
     by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
     shape = lambda x, dtype=None: jax.ShapeDtypeStruct(  # noqa: E731
         x.shape, dtype or x.dtype, vma=vma)
@@ -362,32 +385,39 @@ def _scan_backward(q, k, v, g, beta, t, entering, do, chunk, nb, interpret):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _rule(q, k, v, g, beta, chunk, blocks, interpret):
-    t = _chunk_inverse(k, g, beta, chunk, blocks[0], interpret)
-    return _scan_forward(q, k, v, g, beta, t, chunk, blocks[1], False, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _rule(q, k, v, g, beta, hk, chunk, blocks, interpret):
+    t = _chunk_inverse(k, g, beta, hk, chunk, blocks[0], interpret)
+    return _scan_forward(q, k, v, g, beta, t, hk, chunk, blocks[1], False, interpret)[0]
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, blocks, interpret):
-    t = _chunk_inverse(k, g, beta, chunk, blocks[0], interpret)
-    o, entering = _scan_forward(q, k, v, g, beta, t, chunk, blocks[1], True, interpret)
+def _rule_fwd(q, k, v, g, beta, hk, chunk, blocks, interpret):
+    t = _chunk_inverse(k, g, beta, hk, chunk, blocks[0], interpret)
+    o, entering = _scan_forward(q, k, v, g, beta, t, hk, chunk, blocks[1], True, interpret)
     return o, (q, k, v, g, beta, t, entering)
 
 
-def _rule_bwd(chunk, blocks, interpret, res, do):
-    return _scan_backward(*res, do, chunk, blocks[2], interpret)
+def _rule_bwd(hk, chunk, blocks, interpret, res, do):
+    return _scan_backward(*res, do, hk, chunk, blocks[2], interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+def by_value_head(x):
+    """A per-token scalar of every value head, (B, S, H_v) → (B·H_v, S): the
+    rows the kernels read g and β from (2 MB each at 16k tokens: XLA's)."""
+    b, s, hv = x.shape
+    return jnp.moveaxis(x, 2, 1).reshape(b * hv, s)
+
+
 def gated_delta_kernels(q, k, v, g, beta, chunk, blocks, interpret=False):
-    """q, k (B, H_k, S, d_k) and v (B, H_v, S, d_v) in the compute dtype, g
-    and beta (B, H_v, S) f32; ``blocks`` = chunks a grid step of the three
+    """q, k (B, S, H_k, d_k) and v (B, S, H_v, d_v) in the compute dtype, g
+    and beta (B, S, H_v) f32; ``blocks`` = chunks a grid step of the three
     kernels (each divides S / chunk; the first is a whole number of stacks).
-    Returns o (B, H_v, S, d_v) f32.  Differentiable in all five."""
-    b, hk, s, dk = q.shape
-    hv, dv = v.shape[1], v.shape[-1]
-    o = _rule(q.reshape(b * hk, s, dk), k.reshape(b * hk, s, dk), v.reshape(b * hv, s, dv),
-              g.reshape(b * hv, s), beta.reshape(b * hv, s), chunk, tuple(blocks), interpret)
-    return o.reshape(b, hv, s, dv)
+    Returns o (B, S, H_v, d_v) f32.  Differentiable in all five."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    o = _rule(q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk), v.reshape(b, s, hv * dv),
+              by_value_head(g), by_value_head(beta), hk, chunk, tuple(blocks), interpret)
+    return o.reshape(b, s, hv, dv)

@@ -41,27 +41,35 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _rule_inputs(decay, b=2, hk=2, r=2, s=32, dk=8, dv=6, seed=1):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (b, hk, s, dk))) * dk ** -0.5
-    k = unit(jax.random.normal(ks[1], (b, hk, s, dk)))
-    v = jax.random.normal(ks[2], (b, hk * r, s, dv))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, hk * r, s)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, hk * r, s)))
-    return q, k, v, g, beta
+    q = unit(jax.random.normal(ks[0], (b, s, hk, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, s, hk, dk)))
+    v = jax.random.normal(ks[2], (b, s, hk * r, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, hk * r)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hk * r)))
+    return q, k, v, g, beta  # token-major: the one layout every caller has
 
 
 def _by_token(q, k, v, g, beta):
-    r = v.shape[1] // q.shape[1]
-    return gd.gated_delta_recurrence(jnp.repeat(q, r, 1), jnp.repeat(k, r, 1), v, g, beta)
+    r = v.shape[2] // q.shape[2]
+    return gd.gated_delta_recurrence(jnp.repeat(q, r, 2), jnp.repeat(k, r, 2), v, g, beta)
 
 
 #: implementation → (chunk, the inputs' shape): XLA's chunked form at toy widths,
 #: the Pallas kernels (in the interpreter, two chunks a grid step so that the
-#: state crosses grid steps) at a shape that tiles
+#: state crosses grid steps) at a shape that tiles.  Every input is random by
+#: position, so an index map that took one batch, key head or value head for
+#: another would read another's numbers: the cases with B = 2 and two key
+#: heads of two (or three) value heads each in one call are there for that,
+#: one of them with d_v ≠ d_k so that a block's lanes are counted in the
+#: right head size
 _IMPLEMENTATIONS = {
     "xla-4": ("xla", 4, {}), "xla-16": ("xla", 16, {}), "xla-32": ("xla", 32, {}),
     "kernels-1x1": ("kernels", 64, dict(b=1, hk=1, s=256, dk=128, dv=128)),
     "kernels-2x2": ("kernels", 64, dict(b=2, hk=2, s=256, dk=128, dv=128)),
     "kernels-128": ("kernels", 128, dict(b=1, hk=1, s=512, dk=128, dv=128)),
+    "kernels-2x2x2-wide-v": ("kernels", 64, dict(b=2, hk=2, r=2, s=128, dk=128, dv=256)),
+    "kernels-2x2x3": ("kernels", 64, dict(b=2, hk=2, r=3, s=128, dk=128, dv=128)),
+    "kernels-2x2x2-128": ("kernels", 128, dict(b=2, hk=2, r=2, s=256, dk=128, dv=128)),
 }
 
 
@@ -159,7 +167,7 @@ def test_rule_refuses_what_it_would_have_to_pad_or_guess():
     with pytest.raises(ValueError, match="does not divide"):
         gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=8)
     with pytest.raises(ValueError, match="no multiple"):
-        gd.chunked_gated_delta_rule(q, k, v[:, :3], g[:, :3], beta[:, :3], chunk=4)
+        gd.chunked_gated_delta_rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3], chunk=4)
 
 
 @pytest.mark.parametrize("size", [2, 8, 64])
@@ -224,6 +232,87 @@ def test_causal_conv_reads_the_past_only():
     got = np.asarray(dm.causal_conv(x, taps))
     np.testing.assert_allclose(got[0, :, 1], x[0, :, 1])  # the last tap is the present
     np.testing.assert_allclose(got[0, :, 0], [1000, 3100, 5310, 7531, 9753])
+
+
+@pytest.mark.parametrize("taps_k", [3, 4])
+def test_the_rounded_convolution_has_the_convolutions_own_gradients(taps_k):
+    """``_conv_rounded`` is ``causal_conv`` rounded to x's dtype; its written
+    backward pass — the shifted copies of one padded cotangent — gives
+    autodiff's dx and dtaps (in f32 to rounding; the first and the last
+    positions, where the padding shows, among them)."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 6))
+    taps = jax.random.normal(jax.random.PRNGKey(1), (taps_k, 6))
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    np.testing.assert_array_equal(dm._conv_rounded(x, taps), dm.causal_conv(x, taps))
+    got = jax.grad(lambda x, t: jnp.sum(dm._conv_rounded(x, t) * weigh), argnums=(0, 1))(x, taps)
+    want = jax.grad(lambda x, t: jnp.sum(dm.causal_conv(x, t) * weigh), argnums=(0, 1))(x, taps)
+    for name, g, w in zip(("dx", "dtaps"), got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6, err_msg=name)
+    # in bf16 the result is the f32 sum rounded once, and dx comes back in bf16
+    low = dm._conv_rounded(x.astype(jnp.bfloat16), taps)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        low, dm.causal_conv(x.astype(jnp.bfloat16), taps).astype(jnp.bfloat16))
+    dx = jax.grad(lambda x: jnp.sum(dm._conv_rounded(x, taps).astype(jnp.float32) * weigh))(
+        x.astype(jnp.bfloat16))
+    assert dx.dtype == jnp.bfloat16
+    np.testing.assert_allclose(dx.astype(jnp.float32), want[0], rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("s", [16, 12, 5], ids=["eight_rows", "four_rows", "one_row"])
+def test_the_tiles_view_is_the_heads_of_the_token_major_array(s):
+    """(B, S, n·d) → (B, S/r, n, r, d) and back: position (b, t, h·d + i)
+    lands at (b, t // r, h, t % r, i), r = gcd(S, 8); a statistic over the
+    last dim there is the statistic over a head's lanes."""
+    b, n, d = 2, 3, 4
+    x = jax.random.normal(jax.random.PRNGKey(s), (b, s, n * d))
+    tiles = dm._head_tiles(x, n)
+    rows = np.gcd(s, 8)
+    assert tiles.shape == (b, s // rows, n, rows, d)
+    np.testing.assert_array_equal(dm._tokens(tiles), x)
+    for t, h in [(0, 0), (s - 1, n - 1), (s // 2, 1)]:
+        np.testing.assert_array_equal(tiles[1, t // rows, h, t % rows], x[1, t, h * d:(h + 1) * d])
+    np.testing.assert_allclose(
+        dm._tokens(jnp.broadcast_to(jnp.sum(tiles ** 2, -1, keepdims=True), tiles.shape)),
+        jnp.repeat(jnp.sum(x.reshape(b, s, n, d) ** 2, -1), d, axis=-1), rtol=1e-6)
+
+
+def test_the_token_major_mixer_is_the_head_major_one():
+    """``_delta_scan`` against the same mathematics written head by head on
+    (B, H, S, d) arrays with the recurrence for the rule: values and the
+    gradients of every input — batch 2, two key heads of two value heads, so
+    that a head taken for another would show."""
+    cfg = dm.tiny_delta_moe(lin_k_heads=2, lin_v_heads=4, max_seq=16)
+    hk, hv, dk, dv = cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim
+    b, s = 2, cfg.max_seq
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    qkvz = jax.random.normal(ks[0], (b, s, cfg.lin_channels + hv * dv))
+    ba = jax.random.normal(ks[1], (b, s, 2 * hv))
+    lp = {"conv": jax.random.normal(ks[2], (cfg.conv_kernel, cfg.lin_channels)),
+          "a_log": jax.random.normal(ks[3], (hv,)), "dt_bias": jnp.ones((hv,)),
+          "gdn_norm": 1.0 + 0.1 * jax.random.normal(ks[4], (dv,))}
+
+    def head_major(qkvz, ba, lp):
+        act = jax.nn.silu(dm.causal_conv(qkvz[..., :cfg.lin_channels], lp["conv"]))
+        q, k, v = jnp.split(act, [hk * dk, 2 * hk * dk], axis=-1)
+        heads = lambda t, n, d: jnp.moveaxis(t.reshape(b, s, n, d), 2, 1)  # noqa: E731
+        q, k = (t * dm._inv_l2(t) for t in (heads(q, hk, dk), heads(k, hk, dk)))
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(lp["a_log"]) * jax.nn.softplus(ba[..., hv:] + lp["dt_bias"])
+        r = hv // hk
+        o = gd.gated_delta_recurrence(*(jnp.moveaxis(t, 1, 2) for t in (
+            jnp.repeat(q * dk ** -0.5, r, 1), jnp.repeat(k, r, 1), heads(v, hv, dv))), g, beta)
+        o = lp["gdn_norm"] * o * jax.lax.rsqrt(jnp.mean(o ** 2, -1, keepdims=True) + cfg.norm_eps)
+        z = qkvz[..., cfg.lin_channels:].reshape(b, s, hv, dv)
+        return (o * jax.nn.silu(z)).reshape(b, s, hv * dv)
+
+    weigh = jnp.sin(jnp.arange(b * s * hv * dv, dtype=jnp.float32)).reshape(b, s, hv * dv)
+    run = lambda fn: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda *a: jnp.sum(fn(*a) * weigh), argnums=(0, 1, 2)))(qkvz, ba, lp)
+    (got, got_g), (want, want_g) = run(lambda *a: dm._delta_scan(cfg, *a)), run(head_major)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()))
 
 
 def test_softmax_router_against_top_k_of_a_dense_softmax_with_planted_ties():

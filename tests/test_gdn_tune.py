@@ -34,6 +34,10 @@ def test_one_json_line_says_what_ran_where(line):
     assert line["rehearsal"] is True and line["device"].startswith("cpu")  # no device metric
     assert (line["shape"], line["chunk"]) == (SHAPE, 64)
     assert line["xla_ms"] > 0 and line["kernels_ms"] > 0
+    # the mixer's whole gdn_scan part beside the rule alone
+    assert line["mixer_ms"] > 0
+    assert line["around_kernels_ms"] == pytest.approx(line["mixer_ms"] - line["kernels_ms"],
+                                                       abs=2e-3)
 
 
 def test_every_kernel_is_timed_at_every_block_and_the_best_are_named(line):

@@ -181,9 +181,10 @@ def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cach
 @pytest.mark.parametrize("implementation", ["kernels", "xla"])
 def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch,
                                                          implementation):
-    """One sequence of 16 384 tokens, 16 key and 32 value heads of 128,
-    chunks of 64, bf16 operands: the rule and its five gradients, with what
-    the backward pass keeps well under what a state a token would take (34
+    """One sequence of 16 384 tokens, 16 key and 32 value heads of 128
+    token-major (heads side by side along the lanes, as every caller has
+    them), chunks of 64, bf16 operands: the rule and its five gradients, with
+    what the backward pass keeps well under what a state a token would take (34
     GB).  ``kernels`` is the path a TPU takes at these shapes — the lowered
     module holds the three Pallas kernels, forward and backward —, ``xla``
     the chunked form that stays their oracle (tools/gdn_tune.py times it)."""
@@ -202,8 +203,8 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_ca
     def loss(q, k, v, g, beta):
         return jnp.sum(rule(q, k, v, g, beta))
 
-    args = (shape(1, 16, s, 128), shape(1, 16, s, 128), shape(1, 32, s, 128),
-            shape(1, 32, s, dtype=jnp.float32), shape(1, 32, s, dtype=jnp.float32))
+    args = (shape(1, s, 16, 128), shape(1, s, 16, 128), shape(1, s, 32, 128),
+            shape(1, s, 32, dtype=jnp.float32), shape(1, s, 32, dtype=jnp.float32))
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
     text = compiled.as_text()
     if implementation == "kernels":
@@ -217,6 +218,65 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_ca
     else:
         assert "while" in text and "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+def _relayouts_under(text: str, scope: str, least_bytes: int) -> list:
+    """The ``transpose`` and ``copy`` instructions of an optimized module (any
+    computation, fused ones too) whose result holds at least ``least_bytes``
+    and whose ``op_name`` lies under ``scope``.  XLA:TPU writes a change of
+    layout as a ``copy`` between two layouts; a reshape that is none is a
+    ``bitcast`` and is not listed."""
+    import math
+    import re
+
+    item = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* (transpose|copy)\(", line)
+        if not m or scope not in (re.search(r'op_name="([^"]*)"', line) or [""])[0]:
+            continue
+        size = math.prod(int(d) for d in m.group(3).split(",") if d) * item.get(m.group(2), 4)
+        if size >= least_bytes:
+            found.append(f"{m.group(1)}: {m.group(4)} of {m.group(2)}[{m.group(3)}]")
+    return found
+
+
+def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_cache,
+                                                           monkeypatch):
+    """``_delta_mixer``'s forward and gradient for one sequence of 16 384
+    tokens at Qwen3-Next's widths (2048 → 12 288 | 64, 16 | 32 heads of 128):
+    from ``w_qkvz``'s product to ``w_out``'s no copy of q, k, v, z, o or a
+    cotangent of theirs in another layout exists — the compiled module holds
+    no ``transpose`` and no layout-changing ``copy`` of 64 MB or more under
+    ``gdn_scan`` (a (16384, 2048) bf16 array is 64 MB; the parent's module
+    of this case holds 17 such copies: the head-major operands and their ways
+    back) —, the three kernels take token-major operands, and the temporaries
+    stay under what the parent's module of the same case needs (2.63 GiB; this
+    one 2.41)."""
+    from byteps_tpu.models import delta_moe as dm
+    from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import gated_delta_kernels as gk
+
+    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    cfg = dm.DeltaMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    s = cfg.max_seq
+    assert (s, cfg.lin_channels, cfg.lin_v_heads * cfg.lin_v_dim) == (16384, 8192, 4096)
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in dm.layer_shapes(cfg)["lin"].items()}
+    x = jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(dm._delta_mixer(cfg, x, lp).astype(jnp.float32) ** 2)
+
+    for fn in (loss, jax.grad(loss, argnums=(0, 1))):
+        compiled = _compile(fn, x, lp)
+        text = compiled.as_text()
+        assert gk.FWD_KERNEL in text and gk.INVERSE_KERNEL in text
+        # q | k as the kernels' operand
+        assert f"bf16[1,{s},{cfg.lin_k_heads * cfg.lin_k_dim}]" in text
+        assert _relayouts_under(text, "gdn_scan", 64 * 2**20) == []
+    assert gk.BWD_KERNEL in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
 
 
 def test_engine_split_compiles_for_the_largest_vgg16_leaf(one_chip, no_compile_cache):

@@ -355,11 +355,14 @@ def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
 #: sliding-window family: what that PR added beside them (a ``window``
 #: argument that is None by default in ops/flash_attention.py) moved no
 #: program.  The first three digests are tests/test_conv_moe_pieces.py's,
-#: unchanged.  A change that means to move one re-freezes its digest here.
+#: unchanged.  A change that means to move one re-freezes its digest here:
+#: PR 45 moved ``delta_moe`` (its linear mixer stays token-major and the rule
+#: takes (B, S, H, d) operands) and no other — ``conv_moe`` and this family
+#: import ``causal_conv`` / ``rope_partial``, whose text did not change.
 FROZEN_LOWERINGS = {
     "bert": "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     "latent_moe": "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
-    "delta_moe": "442fd2b4e62628470b9115296bf6a69ad679c9812891f26bd886b166463ca2d7",
+    "delta_moe": "2dbb1d030a085c4d84a96d165e67f6fe9d10bd5f8337003bf13938886e6c7da6",
     "conv_moe": "1fdc10e9c17fe944aeff4c8ad0123ae5c290fa9fa27e5e9fa49ec8731fd0c129",
 }
 

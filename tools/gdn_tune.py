@@ -1,16 +1,23 @@
 """On-chip sizing of the gated delta rule: XLA's chunked form against the
 Pallas kernels (ops/gated_delta.py, ops/gated_delta_kernels.py), the chunks a
-grid step of each kernel swept.
+grid step of each kernel swept, and the linear mixer's whole ``gdn_scan``
+part around them.
 
 Times forward + all five gradients of ``sum(o**2)`` at the shape one layer of
-``qwen3_next_80b_ep32`` runs — (1, 16 | 32, 16384, 128 | 128), chunk 64, bf16 —
-for XLA's form and for the kernels, and each kernel alone at every block
-size.  Prints one JSON line; the best blocks go to ops/gdn_blocks.json (with
-the sweep's shape and milliseconds in ``meta``), where
+``qwen3_next_80b_ep32`` runs — one sequence of 16 384 tokens, 16 | 32 heads of
+128 | 128 token-major, chunk 64, bf16 — for XLA's form and for the kernels,
+and each kernel alone at every block size; then ``mixer_ms``: forward + every
+gradient of ``models/delta_moe._delta_scan`` (the projection's output in,
+``w_out``'s operand out: convolution, silu, l2 norms, the rule, the gated
+norm) at the same shape, so ``mixer_ms − kernels_ms`` is what XLA does around
+the kernels.  Prints one JSON line; the best blocks go to ops/gdn_blocks.json
+(with the sweep's shape and milliseconds in ``meta``), where
 ``gated_delta.tuned_blocks`` finds them.  Refuses to run off a TPU: a CPU
 timing says nothing of Mosaic.
 
     python tools/gdn_tune.py [--shape 1,16,32,16384,128,128] [--blocks 4,8,16]
+
+(``--shape``: batch, key heads, value heads, sequence, d_k, d_v.)
 
 ``--rehearse`` is the CPU pre-flight of the same control flow (the Pallas
 interpreter at a toy length, nothing written): counts and control flow only.
@@ -42,6 +49,7 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
+    from byteps_tpu.models import delta_moe as dm
     from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
 
@@ -57,13 +65,13 @@ def main() -> int:
     n = s // chunk
     sizes = [nb for nb in (int(x) for x in args.blocks.split(",")) if n % nb == 0]
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    ks = jax.random.split(jax.random.PRNGKey(0), 9)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = (unit(jax.random.normal(ks[0], (b, hk, s, dk))) * dk ** -0.5).astype(cdt)
-    k = unit(jax.random.normal(ks[1], (b, hk, s, dk))).astype(cdt)
-    v = jax.random.normal(ks[2], (b, hv, s, dv)).astype(cdt)
-    g = -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, hv, s)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, hv, s)))
+    q = (unit(jax.random.normal(ks[0], (b, s, hk, dk))) * dk ** -0.5).astype(cdt)
+    k = unit(jax.random.normal(ks[1], (b, s, hk, dk))).astype(cdt)
+    v = jax.random.normal(ks[2], (b, s, hv, dv)).astype(cdt)
+    g = -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, s, hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hv)))
 
     def ms(fn, *xs):
         f = jax.jit(fn)
@@ -78,24 +86,25 @@ def main() -> int:
         # all five gradients: with fewer XLA drops what only the others need
         return jax.value_and_grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=(0, 1, 2, 3, 4))
 
-    # each kernel alone, on the flat layout the kernels take
-    flat = (q.reshape(b * hk, s, dk), k.reshape(b * hk, s, dk), v.reshape(b * hv, s, dv),
-            g.reshape(b * hv, s), beta.reshape(b * hv, s))
-    t = jax.jit(lambda k, g, beta: gk._chunk_inverse(k, g, beta, chunk, sizes[0], interpret))(
+    # each kernel alone, on what the kernels take: q, k, v token-major with
+    # the heads side by side, g and beta a row a value head
+    flat = (q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk), v.reshape(b, s, hv * dv),
+            gk.by_value_head(g), gk.by_value_head(beta))
+    t = jax.jit(lambda k, g, beta: gk._chunk_inverse(k, g, beta, hk, chunk, sizes[0], interpret))(
         flat[1], flat[3], flat[4])
-    _, entering = jax.jit(lambda *a: gk._scan_forward(*a, chunk, sizes[0], True, interpret))(
+    _, entering = jax.jit(lambda *a: gk._scan_forward(*a, hk, chunk, sizes[0], True, interpret))(
         *flat, t)
-    do = jax.random.normal(ks[5], (b * hv, s, dv))
+    do = jax.random.normal(ks[5], (b, s, hv * dv))
     by_kernel = {gk.INVERSE_KERNEL: {}, gk.FWD_KERNEL: {}, gk.BWD_KERNEL: {}}
     for nb in sizes:
         if nb % max(gk.STACK // chunk, 1) == 0:
             by_kernel[gk.INVERSE_KERNEL][nb] = ms(
-                lambda k, g, beta: gk._chunk_inverse(k, g, beta, chunk, nb, interpret),
+                lambda k, g, beta: gk._chunk_inverse(k, g, beta, hk, chunk, nb, interpret),
                 flat[1], flat[3], flat[4])
         by_kernel[gk.FWD_KERNEL][nb] = ms(
-            lambda *a: gk._scan_forward(*a, chunk, nb, True, interpret), *flat, t)
+            lambda *a: gk._scan_forward(*a, hk, chunk, nb, True, interpret), *flat, t)
         by_kernel[gk.BWD_KERNEL][nb] = ms(
-            lambda *a: gk._scan_backward(*a, chunk, nb, interpret), *flat, t, entering, do)
+            lambda *a: gk._scan_backward(*a, hk, chunk, nb, interpret), *flat, t, entering, do)
     best = tuple(min(by_kernel[name], key=by_kernel[name].get)
                  for name in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL))
 
@@ -103,11 +112,26 @@ def main() -> int:
     kernels_ms = ms(whole(lambda *a: gd.chunked_gated_delta_rule(
         *a, chunk=chunk, compute_dtype=cdt, interpret=interpret, blocks=best)), q, k, v, g, beta)
 
+    # the mixer's whole gdn_scan part around the rule, the kernels at the
+    # committed table's blocks (what a train step takes)
+    cfg = dm.DeltaMoEConfig(lin_k_heads=hk, lin_v_heads=hv, lin_k_dim=dk, lin_v_dim=dv,
+                            chunk=chunk, compute_dtype=cdt, max_seq=s)
+    lp = {"conv": jax.random.normal(ks[6], (cfg.conv_kernel, cfg.lin_channels)) * 0.5,
+          "a_log": jnp.zeros((hv,)), "dt_bias": jnp.ones((hv,)), "gdn_norm": jnp.ones((dv,))}
+    qkvz = jax.random.normal(ks[7], (b, s, cfg.lin_channels + hv * dv)).astype(cdt)
+    ba = jax.random.normal(ks[8], (b, s, 2 * hv))
+    # (a rehearsal's mixer takes XLA's form of the rule: _kernel_path's call)
+    mixer_ms = ms(jax.value_and_grad(
+        lambda qkvz, ba, lp: jnp.sum(dm._delta_scan(cfg, qkvz, ba, lp).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2)), qkvz, ba, lp)
+
     line = {
         "device": f"{device.platform}:{device.device_kind}", "rehearsal": args.rehearse,
         "shape": [b, hk, hv, s, dk, dv], "chunk": chunk, "dtype": jnp.dtype(cdt).name,
-        "what": "forward + five gradients of sum(o**2), ms a call; by_kernel: one kernel alone",
-        "xla_ms": xla_ms, "kernels_ms": kernels_ms, "blocks": list(best),
+        "what": "forward + every gradient of sum(o**2), ms a call; by_kernel: one kernel "
+                "alone; mixer_ms: models/delta_moe._delta_scan, the rule inside it",
+        "xla_ms": xla_ms, "kernels_ms": kernels_ms, "mixer_ms": mixer_ms,
+        "around_kernels_ms": round(mixer_ms - kernels_ms, 3), "blocks": list(best),
         "by_kernel": {name: {str(nb): t_ms for nb, t_ms in times.items()}
                       for name, times in by_kernel.items()},
     }
@@ -120,7 +144,7 @@ def main() -> int:
             doc = {}
         doc.setdefault("blocks", {})[str(s)] = list(best)
         doc.setdefault("meta", {})[str(s)] = {k_: line[k_] for k_ in (
-            "shape", "chunk", "dtype", "xla_ms", "kernels_ms", "by_kernel")}
+            "shape", "chunk", "dtype", "xla_ms", "kernels_ms", "mixer_ms", "by_kernel")}
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         with open(path, "w") as f:
             json.dump(doc, f, indent=1)
