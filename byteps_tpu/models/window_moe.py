@@ -49,9 +49,9 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.models.conv_moe import _logits, _rms, _swiglu, _xent_sums, expert_mlp
-from byteps_tpu.models.delta_moe import rope_partial
 from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
 from byteps_tpu.ops.flash_attention import flash_attention
+from byteps_tpu.ops.head_norm import head_norm_rope
 from byteps_tpu.parallel.moe import ROUTING_STATS
 
 _ALL_AXES = ("dp", "pp", "sp", "tp")
@@ -228,19 +228,17 @@ def _attention_mixer(cfg: WindowMoEConfig, x, lp, stack: str):
     cdt, hd, eps = cfg.compute_dtype, cfg.head_dim, cfg.norm_eps
     with jax.named_scope(SCOPES[stack]):
         g = _rms(x, lp["norm"], eps).astype(cdt)
-        q, k, v, z = (jnp.einsum("bsd,dhk->bhsk", g, lp[w].astype(cdt))
-                      for w in ("wq", "wk", "wv", "wg"))
-        q, k = _rms(q, lp["q_norm"], eps).astype(cdt), _rms(k, lp["k_norm"], eps).astype(cdt)
-        if stack == "win":
-            q, k = rope_partial(q, hd, cfg.rope_theta), rope_partial(k, hd, cfg.rope_theta)
-        # the kernels take equal head counts: a key/value head is repeated for
-        # its group of queries (their gradients add up by the repeat's transpose)
-        group = cfg.n_heads // cfg.n_kv_heads
-        o = flash_attention(q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
-                            causal=True, scale=hd ** -0.5,
+        q, k, v = (jnp.einsum("bsd,dhk->bhsk", g, lp[w].astype(cdt)) for w in ("wq", "wk", "wv"))
+        z = jnp.einsum("bsd,dhk->bshk", g, lp["wg"].astype(cdt))  # as W_o's product reads it
+        theta = cfg.rope_theta if stack == "win" else None
+        q = head_norm_rope(q, lp["q_norm"], eps, theta)
+        k = head_norm_rope(k, lp["k_norm"], eps, theta)
+        # the kernels find a query head's key/value head themselves: K and V
+        # go in at their own head count
+        o = flash_attention(q, k, v, causal=True, scale=hd ** -0.5,
                             window=cfg.sliding_window if stack == "win" else None)
-        o = o * jax.nn.sigmoid(z.astype(jnp.float32)).astype(cdt)
-        return _rms(jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(cdt)), lp["post_norm"], eps)
+        o = o.transpose(0, 2, 1, 3) * jax.nn.sigmoid(z.astype(jnp.float32)).astype(cdt)
+        return _rms(jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cdt)), lp["post_norm"], eps)
 
 
 def _dense_mlp(cfg: WindowMoEConfig, x, lp):

@@ -20,6 +20,14 @@ block's steps pass.  The kernel states the VMEM this takes from its shapes
 queries across — so lse and Δ are read as one row and only dQ's product
 takes a transposed operand.
 
+Grouped queries: K and V may hold fewer heads than Q, a count that divides
+Q's.  They go to the kernels as they are, (batch·h_kv, s, d), and a grid row
+``i`` (a query head) finds its key/value head at row ``i // group`` by the
+index maps (:func:`_kv_row`): per (head, query block) the same blocks are
+fetched, from an array ``group`` times smaller than a repeated one.  The
+backward kernel still writes dK and dV a query head; the wrapper sums a
+group's.  Equal head counts lower as they did before any of this.
+
 The query/key head size and the value head size may differ (latent
 attention: 192 for q·k, 128 for v); the matrix products take their operands
 in the dtype they arrive in (bf16 in, bf16 on the MXU) and accumulate in
@@ -97,7 +105,11 @@ def _dense_reference(q, k, v, causal, scale, window=None):
 
 def _dense_reference_lse(q, k, v, causal, scale, window=None):
     """Dense (out, lse) from ONE (s, s) score matrix — the lse fallback
-    must not materialize scores twice (round-3 advisor finding)."""
+    must not materialize scores twice (round-3 advisor finding).  Fewer
+    key/value heads than query heads are repeated here, each for its group."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         qlen, klen = s.shape[-2], s.shape[-1]
@@ -239,16 +251,29 @@ def _vma_union(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _kv_index(causal, bq, bk, window=None):
+def _kv_index(causal, bq, bk, window=None, group=1):
     """Index map of a K/V block in a (bh, q block, kv block) grid; at a
     window the last axis counts from the band's first KV block, and the index
-    is held at the band's last."""
+    is held at the band's last.  ``group`` query heads read one key/value
+    head (:func:`_kv_row`)."""
     if not causal:
-        return lambda i, qi, j: (i, j, 0)
-    if window is not None:
-        return lambda i, qi, t: (i, jnp.minimum(_first_kv_block(qi, bq, bk, window) + t,
-                                                _last_kv_block(qi, bq, bk)), 0)
-    return lambda i, qi, j: (i, jnp.minimum(j, _last_kv_block(qi, bq, bk)), 0)
+        index = lambda i, qi, j: (i, j, 0)  # noqa: E731
+    elif window is not None:
+        index = lambda i, qi, t: (i, jnp.minimum(_first_kv_block(qi, bq, bk, window) + t,  # noqa: E731
+                                                 _last_kv_block(qi, bq, bk)), 0)
+    else:
+        index = lambda i, qi, j: (i, jnp.minimum(j, _last_kv_block(qi, bq, bk)), 0)  # noqa: E731
+    return _kv_row(index, group)
+
+
+def _kv_row(index, group: int):
+    """``index`` with its first block index turned from the grid's row — a
+    query head, ``i = batch · h + head`` — into the key/value head that serves
+    it: with ``h = h_kv · group`` that is row ``i // group`` of K and V laid
+    out (batch · h_kv, s, d).  At equal head counts the map itself."""
+    if group == 1:
+        return index
+    return lambda i, *at: (i // group,) + index(i, *at)[1:]
 
 
 def _q_index(causal, bq, bk, window=None, nq=None):
@@ -269,13 +294,13 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
 
     vma = _vma_union(q, k, v)
     b, h, s, dqk = q.shape
-    dv = v.shape[-1]
+    dv, h_kv = v.shape[-1], k.shape[1]
     nk = s // bk if window is None else _band_steps(s, bq, bk, window)[0]
     bh = b * h
     qf = q.reshape(bh, s, dqk)
-    kf = k.reshape(bh, s, dqk)
-    vf = v.reshape(bh, s, dv)
-    kv_index = _kv_index(causal, bq, bk, window)
+    kf = k.reshape(b * h_kv, s, dqk)
+    vf = v.reshape(b * h_kv, s, dv)
+    kv_index = _kv_index(causal, bq, bk, window, h // h_kv)
     out, lse = pl.pallas_call(
         _fwd_kernel_factory(bq, bk, nk, causal, scale, window),
         out_shape=(
@@ -414,11 +439,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
 
     vma = _vma_union(q, k, v, o, lse, do)
     b, h, s, dqk = q.shape
-    dv = v.shape[-1]
-    bh = b * h
+    dv, h_kv = v.shape[-1], k.shape[1]
+    bh, group = b * h, h // h_kv
     nq, nk = s // bq, s // bk
-    qf, kf = (x.reshape(bh, s, dqk) for x in (q, k))
-    vf, dof = (x.reshape(bh, s, dv) for x in (v, do))
+    qf, kf = q.reshape(bh, s, dqk), k.reshape(b * h_kv, s, dqk)
+    vf, dof = v.reshape(b * h_kv, s, dv), do.reshape(bh, s, dv)
     delta = jnp.sum(
         dof.astype(jnp.float32) * o.reshape(bh, s, dv).astype(jnp.float32), axis=-1
     )
@@ -435,6 +460,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
     q_index = _q_index(causal, bq, bk, window, nq)
     row_index = lambda i, j, qi: (i, 0, q_index(i, j, qi)[1])  # noqa: E731
     kv_index = lambda i, j, qi: (i, j, 0)  # noqa: E731
+    # K and V come in a key/value head, dK and dV go out a query head
+    kv_read = _kv_row(kv_index, group)
     # dQ's block leaves VMEM once: its index stands still until the last key
     # block, whose steps write one query block each.  At a window it follows
     # the query blocks, each pair writing the running sum
@@ -449,8 +476,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
         grid=(bh, nk, steps),
         in_specs=[
             pl.BlockSpec((1, bq, dqk), q_index),
-            pl.BlockSpec((1, bk, dqk), kv_index),
-            pl.BlockSpec((1, bk, dv), kv_index),
+            pl.BlockSpec((1, bk, dqk), kv_read),
+            pl.BlockSpec((1, bk, dv), kv_read),
             pl.BlockSpec((1, bq, dv), q_index),
             pl.BlockSpec((1, 1, bq), row_index),
             pl.BlockSpec((1, 1, bq), row_index),
@@ -475,6 +502,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
         name=BWD_KERNEL if window is None else BWD_WIN_KERNEL,
     )(qf, kf, vf, dof, lse, delta)
 
+    if group > 1:
+        # dK and dV leave the kernel one per QUERY head; a key/value head's is
+        # the sum over its group, in the operands' dtype: what the transpose of
+        # a caller's ``jnp.repeat`` was
+        dk, dv_ = (jnp.sum(x.reshape(b, h_kv, group, s, x.shape[-1]), axis=2) for x in (dk, dv_))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape))
 
 
@@ -617,11 +649,14 @@ def _kernel_path(s: int, bq: int, bk: int, interpret: bool) -> bool:
     return interpret and divides
 
 
-def _resolve(q, scale, block_q, block_k, causal=True, window=None):
+def _resolve(q, k, v, scale, block_q, block_k, causal=True, window=None):
     """Defaults filled in: (scale, block_q, block_k) for this q."""
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"flash attention: window={window} is a causal band of at least the "
                          "query itself (causal=True, window >= 1)")
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(f"flash attention: {k.shape[1]} key and {v.shape[1]} value heads must be "
+                         f"one count that divides the {q.shape[1]} query heads")
     s, dh = q.shape[2], q.shape[3]
     tq, tk = tuned_blocks(s, window)
     bq = min(block_q if block_q is not None else tq, s)
@@ -646,7 +681,7 @@ def flash_attention_lse(
     o = o_a·e^{L_a−L} + o_b·e^{L_b−L}).  Differentiable in (q, k, v)
     including the lse output (its cotangent folds into the backward's
     delta term).  ``window``: as :func:`flash_attention`'s."""
-    scale, bq, bk = _resolve(q, scale, block_q, block_k, causal, window)
+    scale, bq, bk = _resolve(q, k, v, scale, block_q, block_k, causal, window)
     if not _kernel_path(q.shape[2], bq, bk, interpret):
         return _dense_reference_lse(q, k, v, causal, scale, window)
     return _flash_lse(q, k, v, causal, scale, bq, bk, interpret, window)
@@ -663,15 +698,18 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """q/k: (B, H, S, d_qk), v: (B, H, S, d_v) → (B, H, S, d_v); the
-    default scale is d_qk**-0.5.
+    """q: (B, H, S, d_qk), k: (B, H_kv, S, d_qk), v: (B, H_kv, S, d_v) →
+    (B, H, S, d_v); the default scale is d_qk**-0.5.  ``H_kv`` divides ``H``:
+    query head ``h`` reads key/value head ``h // (H / H_kv)``, found by the
+    kernels' index maps (no repeated copy of K or V exists), and dK, dV are
+    summed over a head's group of queries.
 
     Pallas kernels (fwd + blocked bwd) on a TPU, where S must divide by
     the block sizes; what runs elsewhere is :func:`_kernel_path`'s call.
     ``window=W`` (with ``causal=True``; anything else raises): query ``i``
     sees key ``j`` iff ``0 <= i - j < W``, by the banded kernels.
     """
-    scale, bq, bk = _resolve(q, scale, block_q, block_k, causal, window)
+    scale, bq, bk = _resolve(q, k, v, scale, block_q, block_k, causal, window)
     if not _kernel_path(q.shape[2], bq, bk, interpret):
         return _dense_reference(q, k, v, causal, scale, window)
     return _flash(q, k, v, causal, scale, bq, bk, interpret, window)

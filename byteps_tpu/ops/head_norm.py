@@ -1,0 +1,244 @@
+"""Per-head RMSNorm and rotary embedding in one pass each way — what stands
+between a q | k projection and the flash kernels in a mixer whose heads are a
+lane tile wide.
+
+``head_norm_rope(x, w, eps, theta)``: x (B, H, S, d) as the projection wrote
+it → ``w · x / rms(x)`` over a head, turned by its position where ``theta``
+is one (``None``: the mixer knows no positions), in x's dtype.  The statistic,
+the scale and the rotation are f32 and the result is rounded ONCE.  The
+rotation is written over the whole head, ``y · cos + roll(y, d/2) · sin``:
+dimension i is paired with i + d/2, both turned by ``pos · theta^(-2i/d)``
+(``models/delta_moe.rope_partial`` at the whole head), so both halves hold
+the same angles and ``sin`` carries the sign, minus on the first half.
+
+Two implementations behind one ``custom_vjp``, chosen in ONE function
+(:func:`_kernel_path`, from the platform and the shapes): on a TPU at head
+sizes of whole lane tiles two Pallas kernels, ``head_norm_fwd`` (reads x,
+writes the flash kernels' operand) and ``head_norm_bwd`` (reads the cotangent
+and x, writes dx and the scale's gradient a query block), each a block's
+arithmetic in registers; XLA's form of the same equations everywhere else
+(every CPU test) and as the kernels' oracle.  Written as XLA's form alone the
+compiled mixer still held an f32 copy of q, its two f32 half heads (the
+roll's slices) and a head-dim-major copy (tests/test_tpu_compile.py holds
+what it holds now).  The backward pass keeps x and w of the forward and
+rebuilds the statistic, one lane reduction a row.
+
+The kernels' grid is (query block, batch·head), the heads innermost: a
+block of the (S, d) f32 tables is fetched once a query block and stands
+still while the heads pass (Pallas does not copy a block whose index did not
+change) — 16 MB of tables a pass, not 16 MB a head.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from byteps_tpu.ops.flash_attention import _vma_union as _vma
+
+LANES = 128
+#: rows of a block: (1024, 128) is 256 kB of bf16 and 512 kB a table
+BLOCK_ROWS = 1024
+#: f32 sublanes: the scale's gradient leaves the kernel as (8, d) partial sums
+SUBLANES = 8
+
+FWD_KERNEL, BWD_KERNEL = "head_norm_fwd", "head_norm_bwd"
+
+
+def rope_tables(s: int, d: int, theta: float):
+    """(cos, sin) (S, d) f32 of the rotation written over the whole head."""
+    half = d // 2
+    freqs = jnp.asarray(theta, jnp.float32) ** (-jnp.arange(half, dtype=jnp.float32) * 2 / d)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+
+
+def _platform() -> str:
+    """Platform of the default device (a function so that a test or a
+    compile for a described chip can stand in a TPU)."""
+    return jax.devices()[0].platform
+
+
+def _block_rows(s: int) -> int:
+    return min(BLOCK_ROWS, s)
+
+
+def _kernel_path(s: int, d: int, interpret: bool) -> bool:
+    """THE decision between the Pallas kernels (True) and XLA's form
+    (False).  The kernels take heads of whole lane tiles and a sequence of
+    whole blocks; on a TPU they run wherever they can, off a TPU (Mosaic
+    cannot compile there) only under the Pallas interpreter."""
+    fits = d % LANES == 0 and s % _block_rows(s) == 0 and _block_rows(s) % SUBLANES == 0
+    return fits and (interpret or _platform() == "tpu")
+
+
+# ---------------------------------------------------------------------------
+# the equations, on f32 values: XLA's form whole, a kernel's on one block
+# ---------------------------------------------------------------------------
+
+
+def _turn(y, cos, sin, roll):
+    return y * cos + roll(y) * sin
+
+
+def _forward(x32, w, eps, tables, roll):
+    r = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    y = x32 * r * w
+    return y if tables is None else _turn(y, *tables, roll)
+
+
+def _backward(g32, x32, w, eps, tables, roll):
+    """(dx, g · n before any sum): ``g`` turned back (the rotation's
+    transpose is the rotation by the negative angle), then the norm's."""
+    if tables is not None:
+        g32 = _turn(g32, tables[0], -tables[1], roll)
+    r = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    n, dn = x32 * r, g32 * w
+    return r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True)), g32 * n
+
+
+def _xla_roll(y):
+    return jnp.roll(y, y.shape[-1] // 2, axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# the kernels
+# ---------------------------------------------------------------------------
+
+
+def _lane_roll(y):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(y, shift=y.shape[-1] // 2, axis=y.ndim - 1)
+
+
+def _specs(bh, s, d, rows, turned):
+    """(grid, a (1, rows, d) block of a (bh, s, d) array, the scale's block,
+    the tables' blocks): query blocks outermost, heads innermost."""
+    from jax.experimental import pallas as pl
+
+    block = pl.BlockSpec((1, rows, d), lambda qi, i: (i, qi, 0))
+    scale = pl.BlockSpec((1, d), lambda qi, i: (0, 0))
+    tables = [pl.BlockSpec((rows, d), lambda qi, i: (qi, 0))] * (2 if turned else 0)
+    return (s // rows, bh), block, scale, tables
+
+
+def _forward_kernels(x, w, eps, tables, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = x.shape
+    grid, block, scale, table_specs = _specs(b * h, s, d, _block_rows(s), tables is not None)
+
+    def kernel(x_ref, w_ref, *refs):
+        *table_refs, y_ref = refs
+        held = tuple(t[...] for t in table_refs) or None
+        y_ref[0] = _forward(x_ref[0].astype(jnp.float32), w_ref[...], eps, held,
+                            _lane_roll).astype(y_ref.dtype)
+
+    y = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b * h, s, d), x.dtype, vma=_vma(x, w)),
+        grid=grid,
+        in_specs=[block, scale, *table_specs],
+        out_specs=block,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=FWD_KERNEL,
+    )(x.reshape(b * h, s, d), w.reshape(1, d).astype(jnp.float32), *(tables or ()))
+    return y.reshape(x.shape)
+
+
+def _backward_kernels(g, x, w, eps, tables, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = x.shape
+    rows = _block_rows(s)
+    grid, block, scale, table_specs = _specs(b * h, s, d, rows, tables is not None)
+
+    def kernel(g_ref, x_ref, w_ref, *refs):
+        *table_refs, dx_ref, dw_ref = refs
+        held = tuple(t[...] for t in table_refs) or None
+        dx, gn = _backward(g_ref[0].astype(jnp.float32), x_ref[0].astype(jnp.float32),
+                           w_ref[...], eps, held, _lane_roll)
+        dx_ref[0] = dx.astype(dx_ref.dtype)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _clear():
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+        # a query block's rows added sublane tile on sublane tile: no sum
+        # across sublanes in the kernel, the (8, d) that is left is XLA's
+        dw_ref[0] = dw_ref[0] + jnp.sum(gn.reshape(rows // SUBLANES, SUBLANES, d), axis=0)
+
+    vma = _vma(g, x, w)
+    dx, dw = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((b * h, s, d), x.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((s // rows, SUBLANES, d), jnp.float32, vma=vma)),
+        grid=grid,
+        in_specs=[block, block, scale, *table_specs],
+        out_specs=(block, pl.BlockSpec((1, SUBLANES, d), lambda qi, i: (qi, 0, 0))),
+        compiler_params=pltpu.CompilerParams(
+            # the scale's gradient of a query block accumulates along the heads
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=BWD_KERNEL,
+    )(g.reshape(b * h, s, d), x.reshape(b * h, s, d), w.reshape(1, d).astype(jnp.float32),
+      *(tables or ()))
+    return dx.reshape(x.shape), jnp.sum(dw, axis=(0, 1))
+
+
+# ---------------------------------------------------------------------------
+# public API with custom VJP
+# ---------------------------------------------------------------------------
+
+
+def _tables(x, theta):
+    return None if theta is None else rope_tables(x.shape[-2], x.shape[-1], theta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _head_norm_rope(x, w, eps, theta, interpret):
+    return _fwd(x, w, eps, theta, interpret)[0]
+
+
+def _fwd(x, w, eps, theta, interpret):
+    if _kernel_path(x.shape[-2], x.shape[-1], interpret):
+        y = _forward_kernels(x, w, eps, _tables(x, theta), interpret)
+    else:
+        y = _forward(x.astype(jnp.float32), w, eps, _tables(x, theta), _xla_roll).astype(x.dtype)
+    return y, (x, w)
+
+
+def _bwd(eps, theta, interpret, res, g):
+    x, w = res
+    if _kernel_path(x.shape[-2], x.shape[-1], interpret):
+        dx, dw = _backward_kernels(g, x, w, eps, _tables(x, theta), interpret)
+    else:
+        dx, gn = _backward(g.astype(jnp.float32), x.astype(jnp.float32), w, eps,
+                           _tables(x, theta), _xla_roll)
+        dx, dw = dx.astype(x.dtype), jnp.sum(gn, axis=tuple(range(gn.ndim - 1)))
+    return dx, dw.astype(w.dtype)
+
+
+_head_norm_rope.defvjp(_fwd, _bwd)
+
+
+def head_norm_rope(x, w, eps: float, theta=None, interpret: bool = False):
+    """x (B, H, S, d), w (d,) → ``w · x / rms(x)`` over a head, turned by its
+    position where ``theta`` is given, in x's dtype; differentiable in x and
+    w.  What runs where is :func:`_kernel_path`'s call."""
+    if x.shape[-1] % 2:
+        raise ValueError(f"a head of {x.shape[-1]} has no halves to pair")
+    # under shard_map the scale is replicated and x varies: the scale's
+    # cotangent is then summed over x's axes by this cast's transpose
+    need = tuple(jax.typeof(x).vma - jax.typeof(w).vma)
+    if need:
+        w = lax.pcast(w, need, to="varying")
+    return _head_norm_rope(x, w, eps, theta, interpret)

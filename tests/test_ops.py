@@ -17,6 +17,20 @@ from byteps_tpu.ops.onebit_device import (
 )
 
 
+def _pallas_calls(jaxpr):
+    """(kernel name, operand shapes) of every ``pallas_call`` of a jaxpr,
+    nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], [x.aval.shape for x in eqn.invars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def _kernel_names(fn, *args):
+    return sorted(name for name, _ in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, causal):
@@ -98,6 +112,99 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(gk), np.asarray(rk), rtol=2e-3, atol=2e-4)
         np.testing.assert_allclose(np.asarray(gv), np.asarray(rv), rtol=2e-3, atol=2e-4)
 
+    # (group, causal, window, block_q, block_k, d_qk, d_v, lse output) at
+    # sequence 256, two batches of 2 key/value heads: a key/value head found
+    # by index map for its 2 | 4 query heads, full and banded, d_qk != d_v,
+    # block_q != block_k both ways round, with and without the lse output
+    @pytest.mark.parametrize("group,causal,window,bq,bk,d_qk,d_v,lse_out", [
+        (2, True, None, 64, 64, 32, 32, False), (4, True, None, 64, 64, 48, 32, False),
+        (2, False, None, 128, 32, 48, 32, False), (4, False, None, 32, 128, 32, 48, True),
+        (2, True, None, 64, 32, 48, 32, True), (4, True, None, 256, 64, 32, 48, True),
+        (2, True, 64, 64, 64, 32, 32, False), (4, True, 100, 64, 64, 48, 32, False),
+        (2, True, 37, 32, 32, 32, 48, True), (4, True, 64, 128, 32, 48, 32, True),
+        (4, True, 96, 32, 128, 32, 48, False), (2, True, 1, 64, 64, 32, 32, False),
+    ])
+    def test_grouped_heads_by_index_map_match_repeated_heads(self, group, causal, window, bq, bk,
+                                                              d_qk, d_v, lse_out):
+        """K and V with fewer heads than Q: the kernels (interpret mode)
+        against the dense reference on heads repeated BY THE CALLER — output,
+        lse where asked, and dQ, dK, dV (a key/value head's gradient is the
+        sum over its group: the repeat's transpose)."""
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        rng = np.random.default_rng(11)
+        b, h_kv = 2, 2
+        h = h_kv * group
+        q, k, v, ct = (jnp.asarray(rng.normal(size=(b, heads, 256, d)).astype(np.float32))
+                       for heads, d in ((h, d_qk), (h_kv, d_qk), (h_kv, d_v), (h, d_v)))
+
+        def loss(attend):
+            def f(q, k, v):
+                o, lse = attend(q, k, v)
+                return jnp.sum(o * ct) + (jnp.sum(jnp.sin(lse)) if lse_out else 0.0), (o, lse)
+            (_, outs), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return outs + grads
+
+        kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True, window=window)
+        if lse_out:
+            kernels = lambda q, k, v: fa.flash_attention_lse(q, k, v, **kw)  # noqa: E731
+        else:
+            kernels = lambda q, k, v: (fa.flash_attention(q, k, v, **kw), jnp.zeros(()))  # noqa: E731
+        repeated = lambda q, k, v: fa._dense_reference_lse(  # noqa: E731
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1), causal, d_qk ** -0.5,
+            window)
+        got, want = loss(kernels), loss(repeated)
+        assert got[3].shape == k.shape and got[4].shape == v.shape
+        for name, g, w in zip(("out", "lse", "dQ", "dK", "dV"), got, want):
+            if name == "lse" and not lse_out:
+                continue
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("window", [None, 40])
+    def test_grouped_heads_off_the_kernels_take_the_dense_reference(self, window):
+        """Off a TPU and without the interpreter the dense reference stands
+        in and repeats inside: the same numbers as repeated operands."""
+        rng = np.random.default_rng(12)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, heads, 96, 16)).astype(np.float32))
+                   for heads in (6, 2, 2))
+        got = flash_attention(q, k, v, causal=True, window=window)
+        want = flash_attention(q, jnp.repeat(k, 3, axis=1), jnp.repeat(v, 3, axis=1), causal=True,
+                               window=window)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("heads", [(4, 3, 3), (4, 2, 4), (4, 8, 8)])
+    def test_head_counts_that_form_no_groups_raise(self, heads):
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        q, k, v = (jnp.zeros((1, n, 64, 16)) for n in heads)
+        for attend in (fa.flash_attention, fa.flash_attention_lse):
+            with pytest.raises(ValueError, match="divides the 4 query heads"):
+                attend(q, k, v, causal=True)
+
+    def test_grouped_kernels_take_k_and_v_at_their_own_head_count(self):
+        """The engaged mechanism, read from the traced program: both kernel
+        calls take K and V as (batch · h_kv, s, d) — no repeated copy is an
+        operand —, Q first and V third, and the K/V index maps divide the
+        grid's row by the group."""
+        import importlib
+
+        fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+        q, k = jnp.ones((2, 8, 128, 32), jnp.float32), jnp.ones((2, 2, 128, 32), jnp.float32)
+        v = jnp.ones((2, 2, 128, 48), jnp.float32)
+
+        grad = jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=32, interpret=True)), argnums=(0, 1, 2))
+        found = dict(_pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr))
+        assert found[fa.FWD_KERNEL] == [(16, 128, 32), (4, 128, 32), (4, 128, 48)]
+        assert found[fa.BWD_KERNEL][:4] == [(16, 128, 32), (4, 128, 32), (4, 128, 48), (16, 128, 48)]
+        index = fa._kv_index(True, 64, 32, None, 4)
+        assert [index(i, 1, 0)[0] for i in (0, 3, 4, 15)] == [0, 0, 1, 3]
+        same = fa._kv_index(True, 64, 32)
+        assert fa._kv_row(same, 1) is same  # equal head counts: the map as it was
+
     @pytest.mark.parametrize("lse_out", [False, True])
     def test_backward_is_one_kernel(self, lse_out):
         """Every backward flash call is the one kernel: a gradient's program
@@ -115,14 +222,7 @@ class TestFlashAttention:
             return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=64,
                                               block_k=32, interpret=True))
 
-        def kernels(jaxpr):
-            for eqn in jaxpr.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    yield eqn.params["name"]
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    yield from kernels(sub)
-
-        names = sorted(kernels(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr))
+        names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
         assert names == [fa.BWD_KERNEL, fa.FWD_KERNEL]
         assert not fa.BWD_KERNEL.startswith(("flash_bwd_dq", "flash_bwd_dkv"))
 
@@ -266,18 +366,11 @@ class TestFlashBand:
         fa = self._fa()
         q = jnp.ones((1, 2, 128, 32), jnp.float32)
 
-        def kernels(jaxpr):
-            for eqn in jaxpr.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    yield eqn.params["name"]
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    yield from kernels(sub)
-
         def loss(q, k, v):
             return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=64, block_k=32,
                                               interpret=True, window=40))
 
-        names = sorted(kernels(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr))
+        names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
         assert names == ["flash_bwd_win", "flash_fwd_win"]
         assert (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) == ("flash_fwd_win", "flash_bwd_win")
 
@@ -346,6 +439,115 @@ class TestFlashBand:
             (512, 512), (512, 512), (512, 512), (1024, 1024), (1024, 1024)]
         bq, bk = fa.tuned_blocks(16384, 2048)
         assert 16384 % bq == 0 and 16384 % bk == 0 and (16384, 2048) in fa._tuned_table()["banded"]
+
+
+class TestHeadNormRope:
+    """``ops/head_norm.head_norm_rope``: a head's RMSNorm and its rotation in
+    one pass each way, held to ``_rms`` then ``rope_partial`` (the two passes
+    the sliding-window family's mixers made), XLA's form and the kernels
+    (interpret mode) alike."""
+
+    @staticmethod
+    def _old(x, w, eps, theta):
+        from byteps_tpu.models.conv_moe import _rms
+        from byteps_tpu.models.delta_moe import rope_partial
+
+        y = _rms(x, w, eps).astype(x.dtype)
+        return y if theta is None else rope_partial(y, x.shape[-1], theta)
+
+    @staticmethod
+    def _inputs(shape, dtype=jnp.float32, seed=21):
+        rng = np.random.default_rng(seed)
+        x = jnp.asarray(rng.normal(size=shape).astype(np.float32) * 1.7, dtype)
+        w = jnp.asarray(1 + 0.2 * rng.normal(size=shape[-1:]).astype(np.float32))
+        ct = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+        return x, w, ct
+
+    # (shape, theta, interpret): heads of a lane tile take the kernels under
+    # the interpreter (one block and several a sequence), anything else XLA's form
+    @pytest.mark.parametrize("shape,theta,interpret", [
+        ((2, 3, 256, 128), 10000.0, True), ((2, 3, 256, 128), None, True),
+        ((1, 2, 2048, 128), 10000.0, True), ((1, 2, 2048, 256), 500000.0, True),
+        ((2, 3, 256, 128), 10000.0, False), ((2, 4, 16, 8), 10000.0, False),
+        ((2, 4, 16, 8), None, False), ((1, 2, 24, 6), 10000.0, True),
+    ])
+    def test_matches_norm_then_rope_and_its_gradient(self, shape, theta, interpret):
+        """f32 in: the output, dx and the scale's gradient are ``_rms`` then
+        ``rope_partial``'s to 1e-6."""
+        from byteps_tpu.ops import head_norm as hn
+
+        assert hn._kernel_path(shape[-2], shape[-1], interpret) == \
+            (interpret and shape[-1] % 128 == 0)
+        x, w, ct = self._inputs(shape)
+
+        def run(f):
+            return (f(x, w),) + jax.grad(lambda x, w: jnp.sum(f(x, w) * ct), argnums=(0, 1))(x, w)
+
+        got = run(lambda x, w: hn.head_norm_rope(x, w, 1e-5, theta, interpret=interpret))
+        want = run(lambda x, w: self._old(x, w, 1e-5, theta))
+        for name, g, r in zip(("out", "dx", "dw"), got, want):
+            assert g.shape == r.shape and g.dtype == r.dtype
+            scale = float(jnp.max(jnp.abs(r)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0, atol=1e-6 * scale,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("theta", [10000.0, None])
+    def test_bf16_in_is_f32_arithmetic_rounded_once(self, theta):
+        """bf16 in and out, nothing between them rounded: the result is the
+        f32 result of the same bf16 values, rounded — by the kernels and by
+        XLA's form, to the bit —, where norm then rope rounded twice."""
+        from byteps_tpu.ops import head_norm as hn
+
+        x, w, ct = self._inputs((1, 2, 256, 128), jnp.bfloat16)
+        exact = self._old(x.astype(jnp.float32), w, 1e-5, theta)
+        for interpret in (True, False):
+            y = hn.head_norm_rope(x, w, 1e-5, theta, interpret=interpret)
+            assert y.dtype == jnp.bfloat16
+            off = np.abs(np.asarray(y, np.float32) - np.asarray(exact))
+            # one rounding to bf16's 8 bits: half a unit in the last place
+            assert np.all(off <= np.abs(np.asarray(exact)) * 2.0 ** -8 + 1e-30)
+            dx, dw = jax.grad(lambda x, w: jnp.sum(hn.head_norm_rope(
+                x, w, 1e-5, theta, interpret=interpret).astype(jnp.float32) * ct),
+                argnums=(0, 1))(x, w)
+            assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
+
+    def test_statistics_rotation_and_scale_are_f32(self):
+        """The stated precision, read from the traced program of a bf16 call:
+        the square, its mean, the rsqrt, the tables and every product are f32;
+        bf16 appears only as the input and the one final rounding."""
+        from byteps_tpu.ops import head_norm as hn
+
+        x, w, _ = self._inputs((1, 2, 16, 8), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda x, w: hn._fwd(x, w, 1e-5, 10000.0, False)[0])(x, w)
+        arithmetic = [e for e in jaxpr.eqns if e.primitive.name in
+                      ("mul", "add", "sub", "rsqrt", "reduce_sum", "div", "cos", "sin", "integer_pow")]
+        assert arithmetic and all(v.aval.dtype == jnp.float32
+                                  for e in arithmetic for v in e.outvars)
+        casts = [e for e in jaxpr.eqns if e.primitive.name == "convert_element_type"
+                 and e.outvars[0].aval.dtype == jnp.bfloat16]
+        assert len(casts) == 1
+
+    def test_an_odd_head_has_no_halves(self):
+        from byteps_tpu.ops import head_norm as hn
+
+        with pytest.raises(ValueError, match="no halves"):
+            hn.head_norm_rope(jnp.ones((1, 1, 4, 7)), jnp.ones((7,)), 1e-5, 10000.0)
+
+    def test_the_kernels_are_the_program_where_heads_tile(self, monkeypatch):
+        """On a TPU at heads of a lane tile both passes are one Pallas call
+        each — ``head_norm_fwd``, ``head_norm_bwd`` —, read from the traced
+        gradient; at a head that does not tile, none."""
+        from byteps_tpu.ops import head_norm as hn
+
+        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+
+        grad = jax.grad(lambda x, w: jnp.sum(hn.head_norm_rope(x, w, 1e-5, 10000.0)
+                                             .astype(jnp.float32)), argnums=(0, 1))
+        x = jax.ShapeDtypeStruct((1, 4, 2048, 128), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((128,), jnp.float32)
+        assert _kernel_names(grad, x, w) == [hn.BWD_KERNEL, hn.FWD_KERNEL]
+        small = jax.ShapeDtypeStruct((1, 4, 2048, 64), jnp.bfloat16)
+        assert _kernel_names(grad, small, jax.ShapeDtypeStruct((64,), jnp.float32)) == []
 
 
 class TestOneBitDevice:

@@ -11,6 +11,8 @@ All such tests stay in THIS file, so one worker holds the library.
 
 import functools
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,30 +134,38 @@ def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_c
     assert text.count("tpu_custom_call") >= 2
 
 
-@pytest.mark.parametrize("window", [2048, None], ids=["banded", "global"])
+@pytest.mark.parametrize("window,kv_heads", [(2048, 32), (None, 32), (2048, 4), (None, 4)],
+                         ids=["banded", "global", "banded_grouped", "global_grouped"])
 def test_flash_kernels_compile_at_the_sliding_window_shape(one_chip, no_compile_cache,
-                                                           monkeypatch, window):
-    """(1, 32, 16384, 128 | 128) — Trinity-Mini's mixers with the 4 key/value
-    heads repeated: the banded pair at window 2048 with the blocks
+                                                           monkeypatch, window, kv_heads):
+    """(1, 32 | 32 or 4, 16384, 128 | 128) — Trinity-Mini's mixers, with the 4
+    key/value heads repeated to 32 and as they are (the kernels find head
+    ``h // 8`` by index map): the banded pair at window 2048 with the blocks
     ops/flash_blocks.json commits for (16384, 2048), an innermost grid axis
     as long as the band is wide and not as the sequence; and the full causal
-    pair of the global layer at the sequence's plain entry."""
+    pair of the global layer at the sequence's plain entry.  Grouped, K and V
+    are the kernels' operands at 4 heads and no array of 32 stands for them."""
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     bq, bk = fa.tuned_blocks(16384, window)
     assert (16384, 2048) in fa._tuned_table()["banded"]
     assert max(fa._band_steps(16384, bq, bk, 2048)) <= 6 < 16384 // max(bq, bk)
     q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, kv_heads, 16384, 128), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         out = fa.flash_attention(q, k, v, causal=True, scale=128 ** -0.5, window=window)
         return jnp.sum(out.astype(jnp.float32))
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k).as_text()
     wanted = (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) if window else (fa.FWD_KERNEL, fa.BWD_KERNEL)
     for kernel in wanted:
         assert kernel in text, f"{kernel} is not in the compiled program"
+        q_, k_, v_ = _kernel_operands(text, kernel)[:3]
+        assert (q_, k_, v_) == ("bf16[32,16384,128]",) + (f"bf16[{kv_heads},16384,128]",) * 2
     assert (fa.FWD_WIN_KERNEL in text) == bool(window)
     assert text.count("tpu_custom_call") >= 2
+    if kv_heads < 32:
+        assert not re.search(r"bf16\[4,8,16384,128\]\S* broadcast\(", text)
 
 
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
@@ -277,6 +287,128 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
         assert _relayouts_under(text, "gdn_scan", 64 * 2**20) == []
     assert gk.BWD_KERNEL in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+_ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+_SHAPE = r"\b(bf16|f32|s32|u32|pred)\[([\d,]*)\]"
+
+
+def _bytes_of(shapes: str) -> int:
+    return sum(math.prod(int(d) for d in dims.split(",") if d) * _ITEM[t]
+               for t, dims in re.findall(_SHAPE, shapes))
+
+
+def _top_level(text: str) -> list:
+    """The ENTRY computation's instructions of an optimized module as (name,
+    opcode, result shapes, operand names, is a Pallas kernel, ``op_name``)."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for line in entry[:entry.index("\n}")].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line)
+        if not m:
+            continue
+        body = m.group(2).split(", metadata=")[0]
+        op = re.search(r" ([a-z\-]+)\(", body)
+        args = body[op.end():].split(")", 1)[0]
+        found.append((m.group(1), op.group(1), body[:op.start()],
+                      [a.lstrip("%") for a in re.findall(r"%[\w.\-]+", args)],
+                      "tpu_custom_call" in body,
+                      (re.search(r'op_name="([^"]*)"', line) or ["", ""])[1]))
+    return found
+
+
+def _kernel_operands(text: str, kernel: str) -> list:
+    """The operands' types (``bf16[32,16384,128]``), in order, of the one
+    Pallas call named ``kernel`` (a whole word of its ``op_name``)."""
+    ops = _top_level(text)
+    types = {name: re.sub(r"\{.*", "", result.strip().lstrip("(")) for name, _, result, *_ in ops}
+    (call,) = [o for o in ops if o[4] and re.search(rf"\b{kernel}\b", o[5])]
+    return [types[a] for a in call[3]]
+
+
+def _named_bytes(text: str) -> int:
+    """Bytes that the top-level operations OUTSIDE the Pallas kernels name:
+    each ``fusion`` | ``copy`` | ``broadcast`` | ``reduce`` | ``convert``'s
+    result and operands (a matrix product is a fusion here; the asynchronous
+    copies that stage an operand for one are not counted twice)."""
+    ops = _top_level(text)
+    size = {name: _bytes_of(result) for name, _, result, *_ in ops}
+    return sum(size[name] + sum(size.get(a, 0) for a in args)
+               for name, opcode, _, args, kernel, _ in ops
+               if not kernel and opcode in ("fusion", "copy", "broadcast", "reduce", "convert"))
+
+
+# (GiB the parent's module of the case names outside its kernels, GiB this one may)
+_MIXER_BYTES = {"win": (8.90, 5.8), "glob": (6.73, 5.8)}
+
+
+@pytest.mark.parametrize("stack", ["win", "glob"])
+def test_attention_mixer_moves_each_tensor_once_at_published_widths(one_chip, no_compile_cache,
+                                                                    monkeypatch, stack):
+    """``window_moe._attention_mixer``'s gradient (no recomputation) for one
+    sequence of 16 384 tokens at Trinity-Mini's widths (2048 → 32 | 4 | 4 | 32
+    heads of 128), sliding and global: the flash kernels take q
+    ``bf16[32,16384,128]`` first and K, V ``bf16[4,16384,128]`` — what
+    benchmark/readers/window_moe.py parses, and no repeated copy —; head norm
+    and rope are one kernel each way (``head_norm_fwd`` | ``head_norm_bwd``,
+    for q and for k); under the mixer's scope nothing broadcasts, copies or
+    transposes 64 MB or more and no f32 array of 256 MB is written, other
+    than the forward kernel's logsumexp on 128 lanes and XLA's copy of it to
+    take lane 0 (ROADMAP S6: a ``kernels`` item of all four flash cells).
+    The bytes the top-level operations outside the kernels name (results +
+    operands of every fusion, copy, broadcast, reduce and convert): the
+    parent's module of the same case **8.90 GiB** sliding | **6.73** global
+    (the 8-fold repeat and its transpose's sum, q whole in f32 both ways, its
+    half heads written and concatenated, a head-dim-major copy for the
+    norm's reduction), this one **5.53 | 5.49 GiB**, held under 5.8."""
+    from byteps_tpu.models import window_moe as wm
+    from byteps_tpu.ops import head_norm as hn
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    cfg = wm.WindowMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    s, scope = cfg.max_seq, wm.SCOPES[stack]
+    assert (s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (16384, 32, 4, 128)
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in wm.stacks(cfg)[stack][1].items()}
+    x = jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(wm._attention_mixer(cfg, x, lp, stack).astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), x, lp).as_text()
+    forward, backward = ((fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) if stack == "win" else
+                         (fa.FWD_KERNEL, fa.BWD_KERNEL))
+    for kernel in (forward, backward):
+        assert _kernel_operands(text, kernel)[:3] == [
+            "bf16[32,16384,128]", "bf16[4,16384,128]", "bf16[4,16384,128]"]
+    ops = _top_level(text)
+    assert sum(o[4] and o[0].startswith(hn.FWD_KERNEL) for o in ops) == 2
+    assert sum(o[4] and o[0].startswith(hn.BWD_KERNEL) for o in ops) == 2
+    # under the scope a call's instruction starts with its kernel's name, which
+    # is how benchmark/readers/window_moe.py's ``_flash_call`` tells the calls
+    lse = {o[0] for o in ops if o[4] and o[0].startswith(forward)}
+    assert len(lse) == 1 and sum(o[4] and o[0].startswith(backward) for o in ops) == 1
+    for name, opcode, result, _, kernel, op_name in ops:
+        size = _bytes_of(result)
+        moved = opcode in ("broadcast", "copy", "transpose") and size >= 64 * 2**20
+        wide = "f32[" in result and size >= 256 * 2**20
+        if not kernel and scope in op_name and (moved or wide):
+            assert lse & set(_sources(ops, name)), f"{name}: {opcode} of {result} under {scope}"
+    parent, ceiling = _MIXER_BYTES[stack]
+    assert _named_bytes(text) < ceiling * 2**30 < parent * 2**30
+
+
+def _sources(ops: list, name: str) -> list:
+    """``name``'s operands, through ``get-tuple-element``s and ``bitcast``s."""
+    by_name = {o[0]: o for o in ops}
+    found, todo = [], [name]
+    while todo:
+        for arg in by_name[todo.pop()][3]:
+            found.append(arg)
+            if arg in by_name and by_name[arg][1] in ("get-tuple-element", "bitcast"):
+                todo.append(arg)
+    return found
 
 
 def test_engine_split_compiles_for_the_largest_vgg16_leaf(one_chip, no_compile_cache):

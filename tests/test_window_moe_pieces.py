@@ -62,16 +62,26 @@ def test_a_global_layer_knows_no_positions_and_a_windowed_layer_does(monkeypatch
     x = jax.random.normal(jax.random.PRNGKey(0), (2, cfg.max_seq, cfg.d_model))
     calls = []
 
-    def shifted(rope):
-        def turned(t, *args):
+    def shifted(rope, positions=lambda *args, **kw: True):
+        """``rope`` with every token turned as if it stood five places
+        later: five rows put before the sequence and cut off again (a row of
+        zeros is normed to zeros, so the program's norm-and-rope takes it
+        too).  ``positions``: whether this call turns anything at all."""
+        def turned(t, *args, **kw):
+            if not positions(*args, **kw):
+                return rope(t, *args, **kw)
             calls.append(t.shape)
             s = t.shape[-2]
             padded = jnp.concatenate([jnp.zeros_like(t[..., :5, :]), t], axis=-2)
-            out = rope(padded, *args)[..., 5:, :]  # positions 5 .. s + 4
+            out = rope(padded, *args, **kw)[..., 5:, :]  # positions 5 .. s + 4
             assert out.shape[-2] == s
             # q alone is shifted: odd calls (k) keep their positions
-            return out if len(calls) % 2 else rope(t, *args)
+            return out if len(calls) % 2 else rope(t, *args, **kw)
         return turned
+
+    # the program's one pass norms a head and turns it: head_norm_rope(x, w, eps, theta),
+    # theta None where the mixer knows no positions
+    has_theta = lambda w, eps, theta=None, **kw: theta is not None  # noqa: E731
 
     for kind in (FULL, SLIDING):
         system, reference = _mixers(cfg, kind)
@@ -79,7 +89,7 @@ def test_a_global_layer_knows_no_positions_and_a_windowed_layer_does(monkeypatch
         plain = system(x, lp), reference(x, lp)
         calls.clear()
         with monkeypatch.context() as m:
-            m.setattr(wm, "rope_partial", shifted(wm.rope_partial))
+            m.setattr(wm, "head_norm_rope", shifted(wm.head_norm_rope, has_theta))
             m.setattr(ref, "rope", shifted(ref.rope))
             moved = system(x, lp), reference(x, lp)
         if kind == FULL:

@@ -31,6 +31,15 @@ kernels at the sequence's plain entry beside them:
     python tools/flash_tune.py --seqs 16384 --bh 1,32 --dh 128 --window 2048 \
         --blocks 256,512,1024 --no-dense --check --causal-too \
         --out chiprun_out/flash_blocks.json
+
+``--kv-heads N`` makes K and V at N heads (a count that divides the query
+heads): the kernels find a query head's key/value head by index map, and
+every timing is printed beside the same call on heads REPEATED by the caller
+(``jnp.repeat`` and, backward, its transpose's sum: what a mixer paid before
+the kernels took grouped heads).  The sizing of Trinity's mixers, 32 | 4:
+
+    python tools/flash_tune.py --seqs 16384 --bh 1,32 --kv-heads 4 --dh 128 \
+        --window 2048 --blocks 1024 --no-dense --check --causal-too --no-write
 """
 
 import argparse
@@ -50,6 +59,8 @@ def main() -> int:
                     help="batch,heads used at every seq")
     ap.add_argument("--dh", type=int, default=64, help="head size of q and k")
     ap.add_argument("--dv", type=int, default=0, help="head size of v (0: as --dh)")
+    ap.add_argument("--kv-heads", type=int, default=0,
+                    help="heads of k and v (0: as q's); fewer are also timed repeated by the caller")
     ap.add_argument("--blocks", default="128,256,512")
     ap.add_argument("--no-dense", action="store_true",
                     help="skip the dense reference (its S x S scores do not fit at long S)")
@@ -82,6 +93,8 @@ def main() -> int:
 
     b, h = (int(x) for x in args.bh.split(","))
     dh, dv = args.dh, args.dv or args.dh
+    h_kv = args.kv_heads or h
+    group = h // h_kv
     blocks = [int(x) for x in args.blocks.split(",")]
     window = args.window or None
 
@@ -134,13 +147,23 @@ def main() -> int:
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / args.steps * 1e3  # ms
 
+    def repeated(fn):
+        """``fn`` on key/value heads the caller repeats for their groups."""
+        return lambda q, k, v: fn(q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1))
+
+    def beside_repeated(fn, *xs):
+        if group == 1:
+            return ""
+        ms, fwd_ms = time_fn(repeated(fn), *xs), time_fn(repeated(fn), *xs, grad=False)
+        return f"; heads repeated by the caller {ms:8.2f} ms (forward alone {fwd_ms:6.2f})"
+
     rng = np.random.default_rng(0)
     winners = {}   # seq -> {blocks, flash_ms, dense_ms}
     for s in (int(x) for x in args.seqs.split(",")):
         q, k, v = (
-            jnp.asarray(rng.normal(size=(b, h, s, d)).astype(np.float32) * 0.1,
+            jnp.asarray(rng.normal(size=(b, heads, s, d)).astype(np.float32) * 0.1,
                         jnp.bfloat16)
-            for d in (dh, dh, dv)
+            for heads, d in ((h, dh), (h_kv, dh), (h_kv, dv))
         )
         try:
             if args.no_dense:
@@ -153,14 +176,17 @@ def main() -> int:
             print(f"seq {s}: dense failed ({type(e).__name__})")
         best = None
         if window and args.check:
-            ct = jnp.asarray(rng.normal(size=v.shape).astype(np.float32), jnp.bfloat16)
-            want = dense_banded_grads(q, k, v, ct)
+            ct = jnp.asarray(rng.normal(size=q.shape[:3] + (dv,)).astype(np.float32), jnp.bfloat16)
+            out, dq, *dkv = dense_banded_grads(
+                q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1), ct)
+            # a key/value head's gradient is the sum over its group of queries
+            want = (out, dq, *(x.reshape(b, h_kv, group, s, -1).sum(2) for x in dkv))
         if window and args.causal_too:
             causal = lambda q, k, v: flash_attention(  # noqa: E731
                 q, k, v, causal=True, interpret=args.rehearse)
             ms, fwd_ms = time_fn(causal, q, k, v), time_fn(causal, q, k, v, grad=False)
             print(f"seq {s} full causal kernels at the plain entry: {ms:8.2f} ms "
-                  f"(forward alone {fwd_ms:6.2f})")
+                  f"(forward alone {fwd_ms:6.2f}){beside_repeated(causal, q, k, v)}")
         for bq in blocks:
             for bk in blocks:
                 if s % bq or s % bk:
@@ -192,7 +218,7 @@ def main() -> int:
                     best = (ms, bq, bk, fwd_ms)
                     tag = " *"
                 print(f"seq {s} flash bq={bq} bk={bk}: {ms:8.2f} ms "
-                      f"(forward alone {fwd_ms:6.2f}){tag}")
+                      f"(forward alone {fwd_ms:6.2f}){tag}{beside_repeated(flash, q, k, v)}")
         if dense_ms is not None:
             print(f"seq {s} dense:               {dense_ms:8.2f} ms")
         if best is not None:
@@ -234,7 +260,7 @@ def main() -> int:
             meta[entry] = {
                 "flash_ms": w["flash_ms"], "fwd_ms": w["fwd_ms"],
                 "dense_ms": w["dense_ms"],
-                "bh": args.bh, "dh": dh, "dv": dv,
+                "bh": args.bh, "dh": dh, "dv": dv, "kv_heads": h_kv,
             }
         with open(path, "w") as f:
             json.dump({**doc, table: blocks, notes: meta}, f, indent=1)
