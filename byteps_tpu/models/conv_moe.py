@@ -15,52 +15,35 @@ whole head, no gate (``ops/flash_attention.py``).  The first
 does not weigh, and no shared expert.  Bias-free, RMSNorm ``w · x / rms(x)``,
 the head tied to the embedding, no position table.
 
-This device holds the experts ``[expert_lo, expert_lo + experts_held)`` of
-every expert layer and the first ``vocab_size`` rows of the vocabulary: its
-share of a deployment in which several devices share each layer.  The router
-scores all ``n_experts``; what the experts held elsewhere would add is left
-out (``parallel/moe.held_expert_mlp``).
-
-``transformer.build_train_step`` / ``build_forward`` take a
-:class:`ConvMoEConfig` as they take a ``TransformerConfig``: the config
-answers for its family with the parameter table (:func:`layouts`), the mesh
-checks, the per-device loss (:func:`local_loss`) and logits.  Parameters are
-stacked by kind (``conv``, ``attn``: the mixers; ``dense``, ``moe``: the
-MLPs), and layer ``i`` takes the next entry of its mixer's stack and of its
-MLP's; every mixer and every MLP is rebuilt in the backward pass on its own.
-The plain reference is ``models/conv_moe_reference.py``.
-
-Shared with the other families, by import: the convolution and the rotary
-embedding (``models/delta_moe.py``), routing and the held experts
-(``parallel/moe.py``), the flash kernels.  ``_rms``, ``_swiglu`` and the
-blocked cross-entropy stand here a third time: they differ from
-``delta_moe``'s only by the norm's scale (``w`` here, ``1 + w`` there) and by
-the tied head; one home for them is ROADMAP D5's own change.
+A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
+says one is (the share of experts and vocabulary this device holds, the
+protocol of a family with listed layers, what the families share).
+Parameters are stacked by kind (``conv``, ``attn``: the mixers; ``dense``,
+``moe``: the MLPs), and layer ``i`` takes the next entry of its mixer's stack
+and of its MLP's; every mixer and every MLP is rebuilt in the backward pass on
+its own.  The plain reference is ``models/conv_moe_reference.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from byteps_tpu.models.delta_moe import causal_conv, rope_partial
-from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.models import moe_family as mf
+from byteps_tpu.models.moe_family import causal_conv, rms, rope_partial, swiglu
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.parallel.moe import ROUTING_STATS, held_expert_mlp, sigmoid_topk_route
+from byteps_tpu.parallel.moe import sigmoid_topk_route
 
-_ALL_AXES = ("dp", "pp", "sp", "tp")
 #: ``layer_types`` entry → the stack that holds that mixer's parameters
 MIXERS = {"conv": "conv", "full_attention": "attn"}
 
 
 @dataclasses.dataclass(frozen=True)
-class ConvMoEConfig:
+class ConvMoEConfig(mf.PatternedFamily):
     vocab_size: int = 65536  # rows of the vocabulary held here
     d_model: int = 2048
     layer_types: Tuple[str, ...] = ("conv", "conv", "full_attention", "conv")
@@ -86,45 +69,15 @@ class ConvMoEConfig:
     compute_dtype: Any = jnp.float32
     remat: bool = True
 
+    mixers = MIXERS
+    family = "short-convolution"
+    lacks = ("expert exchange, pipeline split, head sharding or hand-over of the "
+             "convolution's last tokens between sequence shards")
+
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        unknown = sorted(set(self.layer_types) - set(MIXERS))
-        if unknown or not self.layer_types:
-            raise ValueError(f"layer_types holds {unknown or 'nothing'}: a layer's mixer is "
-                             f"one of {sorted(MIXERS)}")
-        if not 0 <= self.n_dense_layers <= len(self.layer_types):
-            raise ValueError(f"{self.n_dense_layers} leading dense layers in a model of "
-                             f"{len(self.layer_types)}")
-        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
-            raise ValueError(
-                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
-                f"lie outside the router's {self.n_experts}")
-        if self.n_heads % self.n_kv_heads:
-            raise ValueError("query heads must be a multiple of key/value heads")
-        if self.head_dim % 2:
-            raise ValueError(f"rope needs an even head_dim, got {self.head_dim}")
-
-    @property
-    def n_layers(self) -> int:
-        return len(self.layer_types)
-
-    def kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """Layer by layer, the stacks (mixer's, MLP's) it reads."""
-        return tuple((MIXERS[t], "dense" if i < self.n_dense_layers else "moe")
-                     for i, t in enumerate(self.layer_types))
-
-    # what transformer.build_train_step / build_forward ask of a family
-    def layouts(self) -> Dict[str, Tuple]:
-        return layouts(self)
-
-    def validate_mesh(self, mesh: Mesh) -> None:
-        validate_mesh(self, mesh)
-
-    def local_loss(self, mesh: Mesh, params, tokens, targets):
-        return local_loss(self, mesh, params, tokens, targets)
-
-    def local_logits(self, mesh: Mesh, params, tokens):
-        return local_logits(self, params, tokens)[None]  # one microbatch, no pipeline
+        super().__post_init__()
+        self._check_grouped_heads()
+        self._check_even_rope("head_dim")
 
 
 def tiny_conv_moe(**kw) -> ConvMoEConfig:
@@ -158,50 +111,28 @@ def stacks(cfg: ConvMoEConfig) -> Dict[str, Tuple[int, Dict[str, tuple]]]:
         "moe": {"norm": (d,), "router": (d, cfg.n_experts), "router_bias": (cfg.n_experts,),
                 "e_gate": (e, d, fe), "e_up": (e, d, fe), "e_down": (e, fe, d)},
     }
-    used = [stack for pair in cfg.kinds() for stack in pair]
-    return {k: (used.count(k), v) for k, v in shapes.items() if k in used}
+    return cfg.stack_sizes(shapes)
 
 
 def layouts(cfg: ConvMoEConfig) -> Dict[str, Tuple]:
-    """name → (global shape, partition spec, gradient sync axes), as
-    ``transformer._layouts`` gives them.  Everything is replicated: this
-    family runs data-parallel only so far (:func:`validate_mesh`).  There is
-    no ``head``: the logits are taken with ``embed``."""
-    shapes = {"embed": (cfg.vocab_size, cfg.d_model), "norm_f": (cfg.d_model,)}
-    for stack, (n, per_layer) in stacks(cfg).items():
-        shapes.update({f"{stack}.{k}": (n,) + s for k, s in per_layer.items()})
-    return {k: (s, P(), _ALL_AXES) for k, s in shapes.items()}
+    """name → (global shape, partition spec, gradient sync axes): every leaf
+    replicated (``moe_family.layouts``).  There is no ``head``: the logits are
+    taken with ``embed``."""
+    return mf.layouts({"embed": (cfg.vocab_size, cfg.d_model), "norm_f": (cfg.d_model,)},
+                      stacks(cfg))
+
+
+#: how the leaves start, beside ``moe_family.INIT_RULES``: ones for the norms'
+#: scales, N(0, 1/kernel) convolution taps, N(0, 0.01²) for the selection bias
+#: (a trained balance's size: zeros would hide a bias that weighs)
+INIT = {"*norm*": mf.ones, "router_bias": mf.normal(0.01),
+        **dict.fromkeys(("w_in", "taps", "w_out"), mf.fan_in(-2))}
 
 
 def init_params(cfg: ConvMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
-    """f32 parameters from ``key``, jittable (made on the device): N(0,
-    1/fan_in) matrices, 0.02 for the embedding, N(0, 1/kernel) convolution
-    taps, ones for the norms' scales, N(0, 0.01²) for the selection bias (a
-    trained balance's size: zeros would hide a bias that weighs)."""
-    params = {}
-    for i, (name, (shape, _, _)) in enumerate(layouts(cfg).items()):
-        leaf, k = name.rsplit(".", 1)[-1], jax.random.fold_in(key, i)
-        if "norm" in leaf:
-            params[name] = jnp.ones(shape, jnp.float32)
-        else:
-            # the contracted dims: wo its two before the last, wq/wk/wv the model's
-            if leaf == "wo":
-                fan_in = math.prod(shape[-3:-1])
-            else:
-                fan_in = shape[-3 if leaf in ("wq", "wk", "wv") else -2]
-            std = {"embed": 0.02, "router_bias": 0.01}.get(leaf, fan_in ** -0.5)
-            params[name] = std * jax.random.normal(k, shape, jnp.float32)
-    return params
-
-
-def validate_mesh(cfg: ConvMoEConfig, mesh: Mesh) -> None:
-    for ax in ("pp", "sp", "tp"):
-        if mesh.shape.get(ax, 1) != 1:
-            raise ValueError(
-                f"the short-convolution MoE family runs data-parallel only: mesh has "
-                f"{ax}={mesh.shape[ax]} (no expert exchange, pipeline split, head sharding "
-                "or hand-over of the convolution's last tokens between sequence shards is "
-                "built for it yet)")
+    """f32 parameters from ``key``, jittable (made on the device), by
+    :data:`INIT`."""
+    return mf.init_params(layouts(cfg), key, INIT)
 
 
 # ---------------------------------------------------------------------------
@@ -209,22 +140,12 @@ def validate_mesh(cfg: ConvMoEConfig, mesh: Mesh) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _rms(x, w, eps: float):
-    """RMSNorm ``w · x / rms(x)`` with f32 statistics; returns f32."""
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w
-
-
-def _swiglu(g, w_gate, w_up, w_down):
-    return (jax.nn.silu(g @ w_gate) * (g @ w_up)) @ w_down
-
-
 def _conv_mixer(cfg: ConvMoEConfig, x, lp):
     """x (B, S, D) → the double-gated short convolution's output (B, S, D),
     compute dtype."""
     cdt = cfg.compute_dtype
     with jax.named_scope("conv_proj"):
-        g = _rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
+        g = rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
         bcx = g @ lp["w_in"].astype(cdt)
     with jax.named_scope("short_conv"):
         b_gate, c_gate, inner = jnp.split(bcx, 3, axis=-1)
@@ -240,10 +161,10 @@ def _attention_mixer(cfg: ConvMoEConfig, x, lp):
     compute dtype."""
     cdt, hd = cfg.compute_dtype, cfg.head_dim
     with jax.named_scope("gqa_attention"):
-        g = _rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
+        g = rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
         q, k, v = (jnp.einsum("bsd,dhk->bhsk", g, lp[w].astype(cdt)) for w in ("wq", "wk", "wv"))
-        q = rope_partial(_rms(q, lp["q_norm"], cfg.norm_eps).astype(cdt), hd, cfg.rope_theta)
-        k = rope_partial(_rms(k, lp["k_norm"], cfg.norm_eps).astype(cdt), hd, cfg.rope_theta)
+        q = rope_partial(rms(q, lp["q_norm"], cfg.norm_eps).astype(cdt), hd, cfg.rope_theta)
+        k = rope_partial(rms(k, lp["k_norm"], cfg.norm_eps).astype(cdt), hd, cfg.rope_theta)
         # the kernels take equal head counts: a key/value head is repeated for
         # its group of queries (their gradients add up by the repeat's transpose)
         group = cfg.n_heads // cfg.n_kv_heads
@@ -256,28 +177,24 @@ def _dense_mlp(cfg: ConvMoEConfig, x, lp):
     """x (B, S, D) → the dense SwiGLU of its norm (B, S, D), compute dtype."""
     cdt = cfg.compute_dtype
     with jax.named_scope("dense_mlp"):
-        g = _rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
-        return _swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
+        g = rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
+        return swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
 
 
 def expert_mlp(cfg: ConvMoEConfig, g32, lp):
     """An expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
     experts' routed part, and nothing else (the model has no shared expert).
     Returns (y (T, D) f32, routing stats)."""
-    cdt = cfg.compute_dtype
-    with jax.named_scope("moe_route"):
-        ids, weights = sigmoid_topk_route(g32, lp["router"], lp["router_bias"], cfg.top_k,
-                                          cfg.routed_scale, eps=cfg.route_eps)
-    with jax.named_scope("moe_experts"):
-        return held_expert_mlp(
-            g32.astype(cdt), ids, weights,
-            *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down")),
-            lo=cfg.expert_lo, n_experts=cfg.n_experts)
+    def route(g32, lp):
+        return sigmoid_topk_route(g32, lp["router"], lp["router_bias"], cfg.top_k,
+                                  cfg.routed_scale, eps=cfg.route_eps)
+
+    return mf.routed_mlp(cfg, g32, g32, lp, route)  # cast where the experts read
 
 
 def _moe_mlp(cfg: ConvMoEConfig, x, lp):
     b, s, d = x.shape
-    g32 = _rms(x, lp["norm"], cfg.norm_eps).reshape(b * s, d)
+    g32 = rms(x, lp["norm"], cfg.norm_eps).reshape(b * s, d)
     y, stats = expert_mlp(cfg, g32, lp)
     return x + y.reshape(b, s, d).astype(x.dtype), stats
 
@@ -290,67 +207,15 @@ def _hidden(cfg: ConvMoEConfig, params, tokens):
 
     run = {"conv": residual(_conv_mixer), "attn": residual(_attention_mixer),
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_mlp(cfg, x, lp)}
-    if cfg.remat:
-        # a layer's mixer and its MLP are each rebuilt in the backward pass,
-        # one at a time; of attention all but the kernel's output and row
-        # statistics, so that the forward kernel does not run twice
-        keep_flash = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
-        run = {k: jax.checkpoint(f, policy=keep_flash if k == "attn" else None)
-               for k, f in run.items()}
-
     x = params["embed"][tokens].astype(cfg.compute_dtype)
-    stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
-    stacked = {stack: {k.split(".", 1)[1]: v for k, v in params.items()
-                       if k.startswith(stack + ".")} for stack in run}
-    seen = dict.fromkeys(run, 0)  # how many layers of each stack have run
-    for pair in cfg.kinds():
-        for stack in pair:
-            lp = {k: v[seen[stack]] for k, v in stacked[stack].items()}
-            seen[stack] += 1
-            if stack == "moe":
-                x, each = run[stack](x, lp)
-                stats = stats + each
-            else:
-                x = run[stack](x, lp)
-    return x, stats
-
-
-def _logits(cfg: ConvMoEConfig, x, scale, embed):
-    """The head is the embedding: logits over the held rows, f32."""
-    h = _rms(x, scale, cfg.norm_eps).astype(cfg.compute_dtype)
-    return lax.dot_general(h, embed.astype(cfg.compute_dtype),
-                           (((h.ndim - 1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.float32)
+    return mf.walk(cfg, run, ("attn",), params, x)
 
 
 def local_logits(cfg: ConvMoEConfig, params, tokens):
-    """(B, S) → (B, S, V) f32 logits over the held rows."""
+    """(B, S) → (B, S, V) f32 logits over the held rows; the head is the
+    embedding."""
     x, _ = _hidden(cfg, params, tokens)
-    return _logits(cfg, x, params["norm_f"], params["embed"])
-
-
-#: rows of logits that stand at a time in the loss
-ROW_BLOCK = 2048
-
-
-def _xent_sums(cfg: ConvMoEConfig, params, x, targets):
-    """(sum of token cross-entropies, tokens counted); targets < 0 are
-    ignored.  A block of rows at a time, each rebuilt in the backward pass:
-    the (B·S, V) logits never stand whole."""
-    d = x.shape[-1]
-    block = math.gcd(x.size // d, ROW_BLOCK)
-
-    def one(xb, tb, scale, embed):
-        logits = _logits(cfg, xb, scale, embed)
-        gold = jnp.take_along_axis(logits, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
-        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - gold) * (tb >= 0))
-
-    if cfg.remat:
-        one = jax.checkpoint(one)
-    scale, embed = params["norm_f"], params["embed"]
-    total = jnp.sum(lax.map(lambda xs: one(*xs, scale, embed),
-                            (x.reshape(-1, block, d), targets.reshape(-1, block))))
-    return total, jnp.sum(targets >= 0).astype(jnp.float32)
+    return mf.row_logits(cfg, x, params["norm_f"], params["embed"])
 
 
 def local_loss(cfg: ConvMoEConfig, mesh: Mesh, params, tokens, targets):
@@ -358,7 +223,5 @@ def local_loss(cfg: ConvMoEConfig, mesh: Mesh, params, tokens, targets):
     the step's routing stats (ROUTING_STATS name → int32) summed over the
     data-parallel ranks."""
     x, stats = _hidden(cfg, params, tokens)
-    total, count = _xent_sums(cfg, params, x, targets)
-    for ax in ("dp", "sp"):
-        total, count, stats = lax.psum(total, ax), lax.psum(count, ax), lax.psum(stats, ax)
-    return total / count, dict(zip(ROUTING_STATS, stats))
+    return mf.mean_loss(
+        *mf.xent_sums(cfg, mf.row_logits, x, targets, params["norm_f"], params["embed"]), stats)

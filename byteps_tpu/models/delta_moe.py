@@ -12,41 +12,34 @@ layer's MLP is ``top_k`` of ``n_experts`` softmax-routed experts plus one
 shared expert behind a sigmoid gate.  Bias-free, RMSNorm with a ``1 + w``
 scale, untied head, no position table.
 
-This device holds the experts ``[expert_lo, expert_lo + experts_held)`` of
-every layer and the first ``vocab_size`` rows of the vocabulary: its share of
-a deployment in which several devices share each layer.  The router scores
-all ``n_experts``; what the experts held elsewhere would add is left out
-(``parallel/moe.held_expert_mlp``).
-
-``transformer.build_train_step`` / ``build_forward`` take a
-:class:`DeltaMoEConfig` as they take a ``TransformerConfig``: the config
-answers for its family with the parameter table (:func:`layouts`), the mesh
-checks, the per-device loss (:func:`local_loss`) and logits.  The stack is
-one ``lax.scan`` over the periods, every mixer and every MLP in it rebuilt in
-the backward pass.  The plain reference is ``models/delta_moe_reference.py``.
+A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
+says one is (the share of experts and vocabulary this device holds, the
+protocol, what the families share).  The stack is one ``lax.scan`` over the
+periods, every mixer and every MLP in it rebuilt in the backward pass.  The
+plain reference is ``models/delta_moe_reference.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.models import moe_family as mf
+from byteps_tpu.models.moe_family import causal_conv, rope_partial
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.gated_delta import CHUNK, chunked_gated_delta_rule
-from byteps_tpu.parallel.moe import ROUTING_STATS, held_expert_mlp, softmax_topk_route
-
-_ALL_AXES = ("dp", "pp", "sp", "tp")
+from byteps_tpu.parallel.moe import ROUTING_STATS, softmax_topk_route
 
 
 @dataclasses.dataclass(frozen=True)
-class DeltaMoEConfig:
+class DeltaMoEConfig(mf.Family):
     vocab_size: int = 151936  # rows of the vocabulary held here
     d_model: int = 2048
     n_layers: int = 48
@@ -76,17 +69,18 @@ class DeltaMoEConfig:
     compute_dtype: Any = jnp.float32
     remat: bool = True
 
+    family = "gated-delta"
+    lacks = ("expert exchange, pipeline split, head sharding or state hand-over between "
+             "sequence shards")
+
     def __post_init__(self):
         if self.n_layers % self.full_attention_interval:
             raise ValueError(f"{self.n_layers} layers are no whole number of periods of "
                              f"{self.full_attention_interval}")
-        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
-            raise ValueError(
-                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
-                f"lie outside the router's {self.n_experts}")
-        if self.n_heads % self.n_kv_heads or self.lin_v_heads % self.lin_k_heads:
-            raise ValueError("query heads must be a multiple of key/value heads, and the "
-                             "rule's value heads of its key heads")
+        super().__post_init__()
+        self._check_grouped_heads()
+        if self.lin_v_heads % self.lin_k_heads:
+            raise ValueError("the rule's value heads must be a multiple of its key heads")
         if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
             raise ValueError(
                 f"rope needs an even rotary_dim within the head, got {self.rotary_dim}")
@@ -99,19 +93,6 @@ class DeltaMoEConfig:
     def lin_channels(self) -> int:
         """What the convolution runs over: q, k and v of the rule."""
         return 2 * self.lin_k_heads * self.lin_k_dim + self.lin_v_heads * self.lin_v_dim
-
-    # what transformer.build_train_step / build_forward ask of a family
-    def layouts(self) -> Dict[str, Tuple]:
-        return layouts(self)
-
-    def validate_mesh(self, mesh: Mesh) -> None:
-        validate_mesh(self, mesh)
-
-    def local_loss(self, mesh: Mesh, params, tokens, targets):
-        return local_loss(self, mesh, params, tokens, targets)
-
-    def local_logits(self, mesh: Mesh, params, tokens):
-        return local_logits(self, params, tokens)[None]  # one microbatch, no pipeline
 
 
 def tiny_delta_moe(**kw) -> DeltaMoEConfig:
@@ -159,50 +140,27 @@ def layer_shapes(cfg: DeltaMoEConfig) -> Dict[str, Dict[str, tuple]]:
 
 
 def layouts(cfg: DeltaMoEConfig) -> Dict[str, Tuple]:
-    """name → (global shape, partition spec, gradient sync axes), as
-    ``transformer._layouts`` gives them.  Everything is replicated: this
-    family runs data-parallel only so far (:func:`validate_mesh`)."""
+    """name → (global shape, partition spec, gradient sync axes): every leaf
+    replicated (``moe_family.layouts``)."""
     d, v = cfg.d_model, cfg.vocab_size
-    shapes = {"embed": (v, d), "norm_f": (d,), "head": (d, v)}
     lead = {"lin": (cfg.n_periods, cfg.full_attention_interval - 1), "full": (cfg.n_periods,)}
-    for kind, per_layer in layer_shapes(cfg).items():
-        if math.prod(lead[kind]):
-            shapes.update({f"{kind}.{k}": lead[kind] + s for k, s in per_layer.items()})
-    return {k: (s, P(), _ALL_AXES) for k, s in shapes.items()}
+    return mf.layouts({"embed": (v, d), "norm_f": (d,), "head": (d, v)},
+                      {kind: (lead[kind], per_layer)
+                       for kind, per_layer in layer_shapes(cfg).items() if math.prod(lead[kind])})
+
+
+#: how the leaves start, beside ``moe_family.INIT_RULES``: 0 for the RMSNorms'
+#: ``1 + w`` scales and 1 for the gated norm's, ``A_log = log U(0, 16)`` and
+#: ``dt_bias = 1``, N(0, 1/kernel) convolution taps
+INIT = {"gdn_norm": mf.ones, "dt_bias": mf.ones, "*norm*": mf.zeros,
+        "a_log": mf.log_uniform(1e-3, 16.0), "shared_gate": mf.fan_in(-1),
+        **dict.fromkeys(("w_qkvz", "w_ba", "conv", "w_out"), mf.fan_in(-2))}
 
 
 def init_params(cfg: DeltaMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
-    """f32 parameters from ``key``, jittable (made on the device): N(0,
-    1/fan_in) matrices, 0.02 for the embedding, N(0, 1/kernel) convolution
-    taps, 0 for the RMSNorms' ``1 + w`` scales and 1 for the gated norm's,
-    ``A_log = log U(0, 16)`` and ``dt_bias = 1``."""
-    params = {}
-    for i, (name, (shape, _, _)) in enumerate(layouts(cfg).items()):
-        leaf, k = name.rsplit(".", 1)[-1], jax.random.fold_in(key, i)
-        if leaf in ("gdn_norm", "dt_bias"):
-            params[name] = jnp.ones(shape, jnp.float32)
-        elif "norm" in leaf:
-            params[name] = jnp.zeros(shape, jnp.float32)
-        elif leaf == "a_log":
-            params[name] = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1e-3, 16.0))
-        else:
-            # the contracted dims: wo its two before the last, the taps the kernel
-            if leaf == "wo":
-                fan_in = math.prod(shape[-3:-1])
-            else:
-                fan_in = shape[{"wq": -3, "wk": -3, "wv": -3, "shared_gate": -1}.get(leaf, -2)]
-            std = 0.02 if name == "embed" else fan_in ** -0.5
-            params[name] = std * jax.random.normal(k, shape, jnp.float32)
-    return params
-
-
-def validate_mesh(cfg: DeltaMoEConfig, mesh: Mesh) -> None:
-    for ax in ("pp", "sp", "tp"):
-        if mesh.shape.get(ax, 1) != 1:
-            raise ValueError(
-                f"the gated-delta MoE family runs data-parallel only: mesh has "
-                f"{ax}={mesh.shape[ax]} (no expert exchange, pipeline split, head sharding "
-                "or state hand-over between sequence shards is built for it yet)")
+    """f32 parameters from ``key``, jittable (made on the device), by
+    :data:`INIT`."""
+    return mf.init_params(layouts(cfg), key, INIT)
 
 
 # ---------------------------------------------------------------------------
@@ -210,10 +168,8 @@ def validate_mesh(cfg: DeltaMoEConfig, mesh: Mesh) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _rms(x, w, eps: float):
-    """RMSNorm with a ``1 + w`` scale and f32 statistics; returns f32."""
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * (1.0 + w)
+#: RMSNorm with a ``1 + w`` scale and f32 statistics; returns f32
+_rms = functools.partial(mf.rms, plus_one=True)
 
 
 def _inv_l2(x, eps: float = 1e-6):
@@ -244,38 +200,6 @@ def _per_head(x, n: int, stat):
     the linear mixer that needs the tiles' view."""
     tiles = _head_tiles(x, n)
     return _tokens(jnp.broadcast_to(stat(tiles), tiles.shape))
-
-
-def rope_partial(x, rotary_dim: int, theta: float):
-    """Rotary embedding on the first ``rotary_dim`` of the last dim of x
-    (..., S, d), the rest untouched.  Half-rotation pairing: dimension i is
-    paired with i + rotary_dim/2, both rotated by ``pos · theta^(-2i/rotary_dim)``."""
-    s, half = x.shape[-2], rotary_dim // 2
-    freqs = jnp.asarray(theta, jnp.float32) ** (
-        -jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]  # (S, half)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    a, b, rest = x32[..., :half], x32[..., half:rotary_dim], x32[..., rotary_dim:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest], axis=-1).astype(x.dtype)
-
-
-def causal_conv(x, taps):
-    """Depthwise causal convolution along the sequence: x (B, S, C), taps
-    (K, C) f32; ``y_t = Σ_j taps[j] · x_{t-K+1+j}``, zeros before the start
-    (the last tap weighs the present token, as ``Conv1d``'s does).  It
-    serves two families and both tap counts: this one's 4 taps under a silu,
-    and ``models/conv_moe.py``'s 3 taps between two gates
-    (tests/test_conv_moe_pieces.py holds the 3-tap case by hand).
-    The shifted copies are taken in x's dtype and multiplied in f32 (on the
-    chip 6 ms a layer less than shifting an f32 copy: PERF.md §6, PR 36)."""
-    k, s = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + s].astype(jnp.float32) * taps[j] for j in range(k))
-
-
-def _swiglu(g, w_gate, w_up, w_down):
-    return (jax.nn.silu(g @ w_gate) * (g @ w_up)) @ w_down
 
 
 @jax.custom_vjp
@@ -376,19 +300,11 @@ def expert_mlp(cfg: DeltaMoEConfig, g32, lp):
     """A layer's MLP on normed tokens ``g32`` (T, D) f32: the held experts'
     routed part plus the gated shared expert.  Returns (y (T, D) f32,
     routing stats)."""
-    cdt = cfg.compute_dtype
-    g = g32.astype(cdt)
-    with jax.named_scope("moe_route"):
-        ids, weights = softmax_topk_route(g32, lp["router"], cfg.top_k)
-    with jax.named_scope("moe_experts"):
-        y, stats = held_expert_mlp(
-            g, ids, weights, *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down")),
-            lo=cfg.expert_lo, n_experts=cfg.n_experts)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(g, *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
-        open_ = jax.nn.sigmoid(jnp.dot(g, lp["shared_gate"].astype(cdt),
-                                       preferred_element_type=jnp.float32))[:, None]
-    return y + open_ * shared.astype(jnp.float32), stats
+    def route(g32, lp):
+        return softmax_topk_route(g32, lp["router"], cfg.top_k)
+
+    # cast once, before the router: both kinds of expert and the gate read this copy
+    return mf.routed_mlp(cfg, g32, g32.astype(cfg.compute_dtype), lp, route, "moe_shared")
 
 
 def _mlp(cfg: DeltaMoEConfig, x, lp):
@@ -401,9 +317,6 @@ def _mlp(cfg: DeltaMoEConfig, x, lp):
 def _hidden(cfg: DeltaMoEConfig, params, tokens):
     """tokens (B, S) → the stack's output before the final norm, and the
     routing stats summed over the layers."""
-    def kind(prefix):
-        return {k.split(".", 1)[1]: v for k, v in params.items() if k.startswith(prefix + ".")}
-
     def residual(mixer):
         return lambda x, lp: x + mixer(cfg, x, lp).astype(x.dtype)
 
@@ -416,8 +329,7 @@ def _hidden(cfg: DeltaMoEConfig, params, tokens):
         # output and row statistics, so that the forward kernel does not run
         # twice
         delta, mlp = jax.checkpoint(delta), jax.checkpoint(mlp)
-        attention = jax.checkpoint(
-            attention, policy=jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
+        attention = jax.checkpoint(attention, policy=mf.keep_flash())
 
     def period(x, lps):
         stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
@@ -428,7 +340,7 @@ def _hidden(cfg: DeltaMoEConfig, params, tokens):
         return x, stats + last
 
     x = params["embed"][tokens].astype(cfg.compute_dtype)
-    x, stats = lax.scan(period, x, {"lin": kind("lin"), "full": kind("full")})
+    x, stats = lax.scan(period, x, {kind: mf.stack_of(params, kind) for kind in ("lin", "full")})
     return x, jnp.sum(stats, 0)
 
 
@@ -443,37 +355,10 @@ def local_logits(cfg: DeltaMoEConfig, params, tokens):
     return _logits(cfg, x, params["norm_f"], params["head"])
 
 
-#: rows of logits that stand at a time in the loss
-ROW_BLOCK = 2048
-
-
-def _xent_sums(cfg: DeltaMoEConfig, params, x, targets):
-    """(sum of token cross-entropies, tokens counted); targets < 0 are
-    ignored.  A block of rows at a time, each rebuilt in the backward pass:
-    the (B·S, V) logits never stand whole."""
-    d = x.shape[-1]
-    rows = x.size // d
-    block = math.gcd(rows, ROW_BLOCK)
-
-    def one(xb, tb, scale, head):
-        logits = _logits(cfg, xb, scale, head)
-        gold = jnp.take_along_axis(logits, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
-        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - gold) * (tb >= 0))
-
-    if cfg.remat:
-        one = jax.checkpoint(one)
-    scale, head = params["norm_f"], params["head"]
-    total = jnp.sum(lax.map(lambda xs: one(*xs, scale, head),
-                            (x.reshape(-1, block, d), targets.reshape(-1, block))))
-    return total, jnp.sum(targets >= 0).astype(jnp.float32)
-
-
 def local_loss(cfg: DeltaMoEConfig, mesh: Mesh, params, tokens, targets):
     """The global mean next-token cross-entropy, identical on every rank, and
     the step's routing stats (ROUTING_STATS name → int32) summed over the
     data-parallel ranks."""
     x, stats = _hidden(cfg, params, tokens)
-    total, count = _xent_sums(cfg, params, x, targets)
-    for ax in ("dp", "sp"):
-        total, count, stats = lax.psum(total, ax), lax.psum(count, ax), lax.psum(stats, ax)
-    return total / count, dict(zip(ROUTING_STATS, stats))
+    return mf.mean_loss(
+        *mf.xent_sums(cfg, _logits, x, targets, params["norm_f"], params["head"]), stats)

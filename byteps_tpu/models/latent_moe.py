@@ -10,39 +10,30 @@ bottlenecks, a 64-wide rotary key shared by all heads, 192-wide q·k and
 embedding and the head for a second loss.  Bias-free, RMSNorm, untied head,
 no position table.
 
-This device holds the experts ``[expert_lo, expert_lo + experts_held)`` of
-every expert layer and the first ``vocab_size`` rows of the vocabulary: its
-share of a deployment in which several devices share each layer.  The router
-scores all ``n_experts``; what the experts held elsewhere would add is left
-out (``parallel/moe.held_expert_mlp``).
-
-``transformer.build_train_step`` / ``build_forward`` take a
-:class:`LatentMoEConfig` as they take a ``TransformerConfig``: the config
-answers for its family with the parameter table (:func:`layouts`), the mesh
-checks, the per-device loss (:func:`local_loss`) and logits.  Each stack is one remat'ed ``lax.scan``.  The plain
-reference is ``models/latent_moe_reference.py``.
+A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
+says one is (the share of experts and vocabulary this device holds, the
+protocol, what the families share).  Each stack is one remat'ed ``lax.scan``.
+The plain reference is ``models/latent_moe_reference.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.models import moe_family as mf
+from byteps_tpu.models.moe_family import rms, swiglu
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.parallel.moe import ROUTING_STATS, held_expert_mlp, sigmoid_topk_route
-
-_ALL_AXES = ("dp", "pp", "sp", "tp")
+from byteps_tpu.parallel.moe import ROUTING_STATS, sigmoid_topk_route
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentMoEConfig:
+class LatentMoEConfig(mf.Family):
     vocab_size: int = 129280  # rows of the vocabulary held here
     d_model: int = 2048
     n_heads: int = 32
@@ -68,32 +59,18 @@ class LatentMoEConfig:
     compute_dtype: Any = jnp.float32
     remat: bool = True
 
+    family = "latent-attention"
+    lacks = "expert exchange, pipeline split or head sharding"
+
     def __post_init__(self):
         if self.mtp_modules not in (0, 1):
             raise ValueError(f"mtp_modules {self.mtp_modules}: depth 0 or 1 is built")
-        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
-            raise ValueError(
-                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
-                f"lie outside the router's {self.n_experts}")
-        if self.qk_rope_dim % 2:
-            raise ValueError(f"rope needs an even qk_rope_dim, got {self.qk_rope_dim}")
+        super().__post_init__()
+        self._check_even_rope("qk_rope_dim")
 
     @property
     def qk_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
-
-    # what transformer.build_train_step / build_forward ask of a family
-    def layouts(self) -> Dict[str, Tuple]:
-        return layouts(self)
-
-    def validate_mesh(self, mesh: Mesh) -> None:
-        validate_mesh(self, mesh)
-
-    def local_loss(self, mesh: Mesh, params, tokens, targets):
-        return local_loss(self, mesh, params, tokens, targets)
-
-    def local_logits(self, mesh: Mesh, params, tokens):
-        return local_logits(self, params, tokens)[None]  # one microbatch, no pipeline
 
 
 def tiny_latent_moe(**kw) -> LatentMoEConfig:
@@ -152,58 +129,33 @@ def stacks(cfg: LatentMoEConfig) -> Dict[str, Tuple[int, Dict[str, tuple]]]:
 
 
 def layouts(cfg: LatentMoEConfig) -> Dict[str, Tuple]:
-    """name → (global shape, partition spec, gradient sync axes), as
-    ``transformer._layouts`` gives them.  Everything is replicated: this
-    family runs data-parallel only so far (:func:`validate_mesh`)."""
+    """name → (global shape, partition spec, gradient sync axes): every leaf
+    replicated (``moe_family.layouts``)."""
     d, v = cfg.d_model, cfg.vocab_size
-    shapes = {"embed": (v, d), "norm_f": (d,), "head": (d, v)}
+    top = {"embed": (v, d), "norm_f": (d,), "head": (d, v)}
     if cfg.mtp_modules:
-        shapes.update({"mtp_norm_e": (d,), "mtp_norm_h": (d,), "mtp_proj": (2 * d, d),
-                       "mtp_norm_f": (d,)})
-    for stack, (n, per_layer) in stacks(cfg).items():
-        shapes.update({f"{stack}.{k}": (n,) + s for k, s in per_layer.items()})
-    return {k: (s, P(), _ALL_AXES) for k, s in shapes.items()}
+        top.update({"mtp_norm_e": (d,), "mtp_norm_h": (d,), "mtp_proj": (2 * d, d),
+                    "mtp_norm_f": (d,)})
+    return mf.layouts(top, stacks(cfg))
+
+
+#: how the leaves start, beside ``moe_family.INIT_RULES``: ones for the norms'
+#: scales, zero selection bias (where training starts); wq_b and wkv_b
+#: contract their first dim
+INIT = {"*norm*": mf.ones, "router_bias": mf.zeros, "wq_a": mf.fan_in(-2),
+        "wkv_a": mf.fan_in(-2), "wq_b": mf.fan_in(-3), "wkv_b": mf.fan_in(-3),
+        "mtp_proj": mf.fan_in(-2)}
 
 
 def init_params(cfg: LatentMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
-    """f32 parameters from ``key``, jittable (made on the device): N(0,
-    1/fan_in) matrices, 0.02 for the embedding, ones for the norms' scales,
-    zero selection bias (where training starts)."""
-    params = {}
-    for i, (name, (shape, _, _)) in enumerate(layouts(cfg).items()):
-        leaf = name.rsplit(".", 1)[-1]
-        if "norm" in leaf:
-            params[name] = jnp.ones(shape, jnp.float32)
-        elif leaf == "router_bias":
-            params[name] = jnp.zeros(shape, jnp.float32)
-        else:
-            # the contracted dims: wq_b/wkv_b contract their first, wo its first two
-            fan_in = math.prod(shape[-3:-1]) if leaf == "wo" else shape[
-                -3 if leaf in ("wq_b", "wkv_b") else -2]
-            std = 0.02 if name == "embed" else fan_in ** -0.5
-            params[name] = std * jax.random.normal(
-                jax.random.fold_in(key, i), shape, jnp.float32)
-    return params
-
-
-def validate_mesh(cfg: LatentMoEConfig, mesh: Mesh) -> None:
-    for ax in ("pp", "sp", "tp"):
-        if mesh.shape.get(ax, 1) != 1:
-            raise ValueError(
-                f"the latent-attention MoE family runs data-parallel only: mesh has "
-                f"{ax}={mesh.shape[ax]} (no expert exchange, pipeline split or head "
-                "sharding is built for it yet)")
+    """f32 parameters from ``key``, jittable (made on the device), by
+    :data:`INIT`."""
+    return mf.init_params(layouts(cfg), key, INIT)
 
 
 # ---------------------------------------------------------------------------
 # Forward pieces (per device, inside shard_map)
 # ---------------------------------------------------------------------------
-
-
-def _rms(x, scale, eps: float):
-    """RMSNorm with f32 statistics; returns f32."""
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * scale
 
 
 def _rope_interleaved(x, positions, theta: float):
@@ -219,21 +171,17 @@ def _rope_interleaved(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _swiglu(g, w_gate, w_up, w_down):
-    return (jax.nn.silu(g @ w_gate) * (g @ w_up)) @ w_down
-
-
 def _attention(cfg: LatentMoEConfig, x, lp):
     """x (B, S, D) → x + latent attention."""
     cdt, nope, r = cfg.compute_dtype, cfg.qk_nope_dim, cfg.kv_lora_rank
     with jax.named_scope("mla_attention"):
         b, s, _ = x.shape
         positions = jnp.arange(s)
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps).astype(cdt)
-        c_q = _rms(h @ lp["wq_a"].astype(cdt), lp["q_norm"], cfg.norm_eps).astype(cdt)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps).astype(cdt)
+        c_q = rms(h @ lp["wq_a"].astype(cdt), lp["q_norm"], cfg.norm_eps).astype(cdt)
         q = jnp.einsum("bsr,rhk->bhsk", c_q, lp["wq_b"].astype(cdt))
         kv_a = h @ lp["wkv_a"].astype(cdt)
-        c_kv = _rms(kv_a[..., :r], lp["kv_norm"], cfg.norm_eps).astype(cdt)
+        c_kv = rms(kv_a[..., :r], lp["kv_norm"], cfg.norm_eps).astype(cdt)
         kv = jnp.einsum("bsr,rhk->bhsk", c_kv, lp["wkv_b"].astype(cdt))
         # one rotary key a token, shared by every head
         k_rope = _rope_interleaved(kv_a[:, None, :, r:], positions, cfg.rope_theta)
@@ -252,8 +200,8 @@ def _attention(cfg: LatentMoEConfig, x, lp):
 def _dense_layer(cfg: LatentMoEConfig, x, lp):
     cdt = cfg.compute_dtype
     x = _attention(cfg, x, lp)
-    g = _rms(x, lp["mlp_norm"], cfg.norm_eps).astype(cdt)
-    y = _swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
+    g = rms(x, lp["mlp_norm"], cfg.norm_eps).astype(cdt)
+    y = swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
     return x + y.astype(x.dtype)
 
 
@@ -261,40 +209,32 @@ def expert_mlp(cfg: LatentMoEConfig, g32, lp):
     """The expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
     experts' routed part plus the shared expert.  Returns (y (T, D) f32,
     routing stats)."""
-    cdt = cfg.compute_dtype
-    g = g32.astype(cdt)
-    with jax.named_scope("moe_route"):
-        ids, weights = sigmoid_topk_route(
+    def route(g32, lp):
+        return sigmoid_topk_route(
             g32, lp["router"], lp["router_bias"], cfg.top_k, cfg.routed_scale)
-    with jax.named_scope("moe_experts"):
-        y, stats = held_expert_mlp(
-            g, ids, weights, *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down")),
-            lo=cfg.expert_lo, n_experts=cfg.n_experts)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(g, *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
-    return y + shared.astype(jnp.float32), stats
+
+    # cast once, before the router: both kinds of expert read this copy
+    return mf.routed_mlp(cfg, g32, g32.astype(cfg.compute_dtype), lp, route, "moe_shared")
 
 
 def _expert_layer(cfg: LatentMoEConfig, x, lp):
     x = _attention(cfg, x, lp)
     b, s, d = x.shape
-    g32 = _rms(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * s, d)
+    g32 = rms(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * s, d)
     y, stats = expert_mlp(cfg, g32, lp)
     return x + y.reshape(b, s, d).astype(x.dtype), stats
 
 
 def _run_stack(cfg: LatentMoEConfig, layer_fn, params, stack: str, x):
     """One remat'ed scan over a stack's layers.  Returns (x, per-layer aux)."""
-    lps = {k.split(".", 1)[1]: v for k, v in params.items() if k.startswith(stack + ".")}
+    lps = mf.stack_of(params, stack)
     if not lps:
         return x, None
     body = lambda carry, lp: layer_fn(cfg, carry, lp)  # noqa: E731
     if cfg.remat:
-        # all is rebuilt in the backward pass but attention's output and row
-        # statistics: keeping them (≈ 0.13 GB a layer at 2 x 8192 tokens)
-        # saves running the forward kernel, the layer's costliest part, twice
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
+        # attention's output and row statistics kept: ≈ 0.13 GB a layer at
+        # 2 x 8192 tokens
+        body = jax.checkpoint(body, policy=mf.keep_flash())
     return lax.scan(body, x, lps)
 
 
@@ -312,8 +252,8 @@ def _hidden(cfg: LatentMoEConfig, params, tokens, targets=None):
         with jax.named_scope("mtp"):
             # h'_i = W_eh [RMSNorm(Emb(t_{i+1})) ; RMSNorm(x_i)]: the next
             # token's embedding first, the main stack's state second
-            nxt = _rms(params["embed"][jnp.maximum(targets, 0)], params["mtp_norm_e"], cfg.norm_eps)
-            both = jnp.concatenate([nxt, _rms(x, params["mtp_norm_h"], cfg.norm_eps)], axis=-1)
+            nxt = rms(params["embed"][jnp.maximum(targets, 0)], params["mtp_norm_e"], cfg.norm_eps)
+            both = jnp.concatenate([nxt, rms(x, params["mtp_norm_h"], cfg.norm_eps)], axis=-1)
             x_mtp = (both.astype(cdt) @ params["mtp_proj"].astype(cdt)).astype(cdt)
             x_mtp, mtp_stats = _run_stack(cfg, _expert_layer, params, "mtp", x_mtp)
             stats = stats + jnp.sum(mtp_stats, 0)
@@ -321,7 +261,7 @@ def _hidden(cfg: LatentMoEConfig, params, tokens, targets=None):
 
 
 def _logits(cfg: LatentMoEConfig, params, x, norm: str):
-    h = _rms(x, params[norm], cfg.norm_eps).astype(cfg.compute_dtype)
+    h = rms(x, params[norm], cfg.norm_eps).astype(cfg.compute_dtype)
     return (h @ params["head"].astype(cfg.compute_dtype)).astype(jnp.float32)
 
 
@@ -355,8 +295,7 @@ def local_loss(cfg: LatentMoEConfig, mesh: Mesh, params, tokens, targets):
     x, x_mtp, stats = _hidden(cfg, params, tokens, targets)
 
     def mean(total, count):
-        for ax in ("dp", "sp"):
-            total, count = lax.psum(total, ax), lax.psum(count, ax)
+        total, count = mf.over_ranks(total, count)
         return total / count
 
     loss = mean(*_xent_sums(cfg, params, x, "norm_f", targets))
@@ -365,6 +304,5 @@ def local_loss(cfg: LatentMoEConfig, mesh: Mesh, params, tokens, targets):
             after = jnp.concatenate([targets[:, 1:], jnp.full_like(targets[:, :1], -1)], axis=1)
             loss = loss + cfg.mtp_lambda * mean(
                 *_xent_sums(cfg, params, x_mtp, "mtp_norm_f", after))
-    for ax in ("dp", "sp"):
-        stats = lax.psum(stats, ax)
+    (stats,) = mf.over_ranks(stats)
     return loss, dict(zip(ROUTING_STATS, stats))

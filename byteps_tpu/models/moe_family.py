@@ -1,0 +1,367 @@
+"""What the mixture-of-experts families behind ``build_train_step`` share —
+``latent_moe``, ``delta_moe``, ``conv_moe``, ``window_moe`` — each decision
+written once.
+
+A device holds the experts ``[expert_lo, expert_lo + experts_held)`` of every
+expert layer and the first ``vocab_size`` rows of the vocabulary: its share of
+a deployment in which several devices share each layer.  The router scores
+all ``n_experts``; what the experts held elsewhere would add is left out
+(``parallel/moe.held_expert_mlp``).
+
+A family is a module.  ``transformer.build_train_step`` / ``build_forward``
+take its config as they take a ``TransformerConfig``: a frozen dataclass that
+says ``class XConfig(Family)`` (or :class:`PatternedFamily`, where
+``layer_types`` lists the layers one by one) and names itself (``family``,
+``lacks``); beside it the module holds ``layouts(cfg)``, ``local_loss(cfg,
+mesh, params, tokens, targets)`` and ``local_logits(cfg, params, tokens)``,
+and the base answers with them.  What a family writes itself is its fields,
+its shapes, its mixers, how its layers are stacked and run, and one table of
+rules for :func:`init_params`; what it takes from here is the norm, the
+SwiGLU, the short convolution and the rotary embedding, the held experts' MLP
+with its scopes, the blocked cross-entropy, the sums over the ranks, and the
+walk over listed layers.
+
+Nothing here branches on which family calls: what differs between them comes
+in as data (``1 + w`` or ``w``, a router, a scope's name, a logits function,
+a table).  No ``*_moe`` module imports another; all import this one.
+"""
+
+from __future__ import annotations
+
+import fnmatch
+import math
+import sys
+from typing import Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
+
+from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.parallel.moe import ROUTING_STATS, held_expert_mlp
+
+_ALL_AXES = ("dp", "pp", "sp", "tp")
+#: rows of logits that stand at a time in the blocked loss
+ROW_BLOCK = 2048
+
+
+# ---------------------------------------------------------------------------
+# The protocol: what transformer.build_train_step / build_forward ask of a family
+# ---------------------------------------------------------------------------
+
+
+class Family:
+    """Base of a family's config.  It reads the fields every family has
+    (``expert_lo``, ``experts_held``, ``n_experts``) and the family's module
+    (see the module's docstring)."""
+
+    #: the words of :meth:`validate_mesh`'s refusal: the family's name, and
+    #: what beside data parallelism is not built for it
+    family = lacks = ""
+
+    def __post_init__(self):
+        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
+            raise ValueError(
+                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
+                f"lie outside the router's {self.n_experts}")
+
+    def _check_grouped_heads(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("query heads must be a multiple of key/value heads")
+
+    def _check_even_rope(self, field: str):
+        if getattr(self, field) % 2:
+            raise ValueError(f"rope needs an even {field}, got {getattr(self, field)}")
+
+    def _module(self):
+        return sys.modules[type(self).__module__]
+
+    def layouts(self) -> Dict[str, Tuple]:
+        return self._module().layouts(self)
+
+    def validate_mesh(self, mesh: Mesh) -> None:
+        for ax in ("pp", "sp", "tp"):
+            if mesh.shape.get(ax, 1) != 1:
+                raise ValueError(
+                    f"the {self.family} MoE family runs data-parallel only: mesh has "
+                    f"{ax}={mesh.shape[ax]} (no {self.lacks} is built for it yet)")
+
+    def local_loss(self, mesh: Mesh, params, tokens, targets):
+        return self._module().local_loss(self, mesh, params, tokens, targets)
+
+    def local_logits(self, mesh: Mesh, params, tokens):
+        # one microbatch, no pipeline
+        return self._module().local_logits(self, params, tokens)[None]
+
+
+class PatternedFamily(Family):
+    """A family whose layers are static data: ``layer_types[i]`` names layer
+    i's mixer, and the first ``n_dense_layers`` layers' MLP is dense, the
+    others' routed (stacks ``dense``, ``moe``)."""
+
+    #: ``layer_types`` entry → the stack that holds that mixer's parameters
+    mixers = {}
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = sorted(set(self.layer_types) - set(self.mixers))
+        if unknown or not self.layer_types:
+            raise ValueError(f"layer_types holds {unknown or 'nothing'}: a layer's mixer is "
+                             f"one of {sorted(self.mixers)}")
+        if not 0 <= self.n_dense_layers <= len(self.layer_types):
+            raise ValueError(f"{self.n_dense_layers} leading dense layers in a model of "
+                             f"{len(self.layer_types)}")
+        super().__post_init__()
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """Layer by layer, the stacks (mixer's, MLP's) it reads."""
+        return tuple((self.mixers[t], "dense" if i < self.n_dense_layers else "moe")
+                     for i, t in enumerate(self.layer_types))
+
+    def stack_sizes(self, shapes: Dict[str, Dict[str, tuple]]):
+        """``shapes`` (stack → per-layer shapes) → stack → (layers, per-layer
+        shapes), the stacks some layer reads."""
+        used = [stack for pair in self.kinds() for stack in pair]
+        return {k: (used.count(k), v) for k, v in shapes.items() if k in used}
+
+
+# ---------------------------------------------------------------------------
+# Parameters: a flat dict; ``<stack>.<name>`` carries the stack's leading dims
+# ---------------------------------------------------------------------------
+
+
+def layouts(top: Dict[str, tuple], stacks: Dict[str, Tuple]) -> Dict[str, Tuple]:
+    """name → (global shape, partition spec, gradient sync axes), as
+    ``transformer._layouts`` gives them: the top-level leaves, then
+    ``<stack>.<name>`` for every ``stack → (leading dims, per-layer shapes)``
+    (a count of layers, or a tuple where a stack has two leading dims).
+    Everything is replicated: these families run data-parallel only so far
+    (:meth:`Family.validate_mesh`)."""
+    shapes = dict(top)
+    for stack, (lead, per_layer) in stacks.items():
+        lead = lead if isinstance(lead, tuple) else (lead,)
+        shapes.update({f"{stack}.{k}": lead + s for k, s in per_layer.items()})
+    return {k: (s, P(), _ALL_AXES) for k, s in shapes.items()}
+
+
+def stack_of(params, stack: str):
+    """The entries ``<stack>.<name>`` of ``params``, by ``<name>``."""
+    return {k.split(".", 1)[1]: v for k, v in params.items() if k.startswith(stack + ".")}
+
+
+# A rule makes one leaf: (key: () -> the leaf's PRNG key, shape) -> f32 array.
+
+
+def ones(key, shape):
+    return jnp.ones(shape, jnp.float32)
+
+
+def zeros(key, shape):
+    return jnp.zeros(shape, jnp.float32)
+
+
+def normal(std: float):
+    """N(0, std²)."""
+    return lambda key, shape: std * jax.random.normal(key(), shape, jnp.float32)
+
+
+def fan_in(*dims: int):
+    """N(0, 1 / fan-in), the fan-in the product of the dims a product with
+    this leaf contracts."""
+    return lambda key, shape: normal(math.prod(shape[d] for d in dims) ** -0.5)(key, shape)
+
+
+def log_uniform(lo: float, hi: float):
+    """log U(lo, hi)."""
+    return lambda key, shape: jnp.log(jax.random.uniform(key(), shape, jnp.float32, lo, hi))
+
+
+#: leaf (or a pattern of ``fnmatch``) → rule, where the families agree: 0.02
+#: for the embedding, N(0, 1 / fan-in) matrices that contract the dim before
+#: their last (a head of (model, vocabulary) too), projections to heads that
+#: contract the model dim, the one back from heads that contracts both of its
+INIT_RULES = {
+    "embed": normal(0.02),
+    "wq": fan_in(-3), "wk": fan_in(-3), "wv": fan_in(-3), "wo": fan_in(-3, -2),
+    **dict.fromkeys(("head", "router", "w_gate", "w_up", "w_down", "e_gate", "e_up", "e_down",
+                     "s_gate", "s_up", "s_down"), fan_in(-2)),
+}
+
+
+def init_params(layout: Dict[str, Tuple], key: jax.Array,
+                rules: Dict[str, Callable]) -> Dict[str, jax.Array]:
+    """f32 parameters from ``key``, jittable (made on the device), in
+    ``layout``'s order.  Leaf ``i`` is drawn with ``fold_in(key, i)`` by the
+    rule its name has (the part after the stack's) in ``rules``, the family's
+    table, or else in :data:`INIT_RULES`; an exact name comes before a
+    pattern.  A leaf without a rule is refused: a new leaf's scale is a
+    decision."""
+    table = {**INIT_RULES, **rules}
+    params = {}
+    for i, (name, (shape, _, _)) in enumerate(layout.items()):
+        leaf = name.rsplit(".", 1)[-1]
+        rule = table.get(leaf) or next(
+            (r for pattern, r in table.items() if fnmatch.fnmatchcase(leaf, pattern)), None)
+        if rule is None:
+            raise ValueError(f"init_params has no rule for the leaf {name!r}: the family's "
+                             "table says how each of its leaves starts")
+        params[name] = rule(lambda: jax.random.fold_in(key, i), shape)
+    return params
+
+
+# ---------------------------------------------------------------------------
+# Forward pieces (per device, inside shard_map)
+# ---------------------------------------------------------------------------
+
+
+def rms(x, w, eps: float, plus_one: bool = False):
+    """RMSNorm ``w · x / rms(x)`` — ``(1 + w) · x / rms(x)`` with
+    ``plus_one`` — with f32 statistics; returns f32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * (
+        1.0 + w if plus_one else w)
+
+
+def swiglu(g, w_gate, w_up, w_down):
+    return (jax.nn.silu(g @ w_gate) * (g @ w_up)) @ w_down
+
+
+def rope_partial(x, rotary_dim: int, theta: float):
+    """Rotary embedding on the first ``rotary_dim`` of the last dim of x
+    (..., S, d), the rest untouched.  Half-rotation pairing: dimension i is
+    paired with i + rotary_dim/2, both rotated by ``pos · theta^(-2i/rotary_dim)``."""
+    s, half = x.shape[-2], rotary_dim // 2
+    freqs = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]  # (S, half)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b, rest = x32[..., :half], x32[..., half:rotary_dim], x32[..., rotary_dim:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest], axis=-1).astype(x.dtype)
+
+
+def causal_conv(x, taps):
+    """Depthwise causal convolution along the sequence: x (B, S, C), taps
+    (K, C) f32; ``y_t = Σ_j taps[j] · x_{t-K+1+j}``, zeros before the start
+    (the last tap weighs the present token, as ``Conv1d``'s does).  It
+    serves two families and both tap counts: ``delta_moe``'s 4 taps under a
+    silu, and ``conv_moe``'s 3 taps between two gates
+    (tests/test_conv_moe_pieces.py holds the 3-tap case by hand).
+    The shifted copies are taken in x's dtype and multiplied in f32 (on the
+    chip 6 ms a layer less than shifting an f32 copy: PERF.md §6, PR 36)."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + s].astype(jnp.float32) * taps[j] for j in range(k))
+
+
+def routed_mlp(cfg, g32, g, lp, route: Callable, shared_scope: Optional[str] = None):
+    """An expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
+    experts' routed part (``parallel/moe.held_expert_mlp``: what the experts
+    held elsewhere would add is left out) plus, under ``shared_scope`` where
+    the family has one, the shared expert that every token takes — behind
+    ``sigmoid(g · shared_gate)`` where the layer has that leaf, at weight 1
+    where not.  ``route(g32, lp)`` → (ids, weights) is the family's router.
+    ``g`` is what the experts read: the tokens in the compute dtype where the
+    family has them, or ``g32`` again — then they are cast where each expert
+    reads them.  The three scopes are what the benchmark's readers file a
+    step's operations by.  Returns (y (T, D) f32, routing stats)."""
+    cdt = cfg.compute_dtype
+    with jax.named_scope("moe_route"):
+        ids, weights = route(g32, lp)
+    with jax.named_scope("moe_experts"):
+        y, stats = held_expert_mlp(
+            g.astype(cdt), ids, weights, *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down")),
+            lo=cfg.expert_lo, n_experts=cfg.n_experts)
+    if shared_scope is None:
+        return y, stats
+    with jax.named_scope(shared_scope):
+        g = g.astype(cdt)
+        shared = swiglu(g, *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
+        open_ = None
+        if "shared_gate" in lp:
+            open_ = jax.nn.sigmoid(jnp.dot(g, lp["shared_gate"].astype(cdt),
+                                           preferred_element_type=jnp.float32))[:, None]
+    shared = shared.astype(jnp.float32)
+    return y + (shared if open_ is None else open_ * shared), stats
+
+
+def keep_flash():
+    """The remat policy of an attention part: all of it is rebuilt in the
+    backward pass but the kernel's output and row statistics, so that the
+    forward kernel, a layer's costliest part, does not run twice.  A new
+    object each call, and jax keeps traced functions apart by it: a caller
+    makes one for the parts it wants lowered as one."""
+    return jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
+
+
+def walk(cfg, run: Dict[str, Callable], flash: Tuple[str, ...], params, x):
+    """The layers of a :class:`PatternedFamily`, unrolled: layer by layer of
+    ``cfg.kinds()``, the mixer's part and the MLP's, each on the next entry of
+    its stack.  ``run[stack](x, lp)`` → x, or (x, routing stats) from a part
+    that routes; each is rebuilt in the backward pass on its own, one at a
+    time (the stacks of ``flash`` keeping their kernel's output).  Returns
+    (x, the routing stats summed over the layers)."""
+    if cfg.remat:
+        policy = keep_flash()
+        run = {k: jax.checkpoint(f, policy=policy if k in flash else None)
+               for k, f in run.items()}
+    stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
+    stacked = {stack: stack_of(params, stack) for stack in run}
+    seen = dict.fromkeys(run, 0)  # how many layers of each stack have run
+    for pair in cfg.kinds():
+        for stack in pair:
+            lp = {k: v[seen[stack]] for k, v in stacked[stack].items()}
+            seen[stack] += 1
+            x = run[stack](x, lp)
+            if isinstance(x, tuple):
+                x, each = x
+                stats = stats + each
+    return x, stats
+
+
+def row_logits(cfg, x, scale, rows):
+    """Logits of the final norm of x with a (vocabulary, model) matrix — a
+    tied embedding, or a head laid out as one — over the held rows, f32."""
+    h = rms(x, scale, cfg.norm_eps).astype(cfg.compute_dtype)
+    return lax.dot_general(h, rows.astype(cfg.compute_dtype),
+                           (((h.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def xent_sums(cfg, logits: Callable, x, targets, scale, head):
+    """(sum of token cross-entropies, tokens counted); targets < 0 are
+    ignored.  ``logits(cfg, x, scale, head)`` is the family's.  A block of
+    rows at a time, each rebuilt in the backward pass: the (B·S, V) logits
+    never stand whole."""
+    d = x.shape[-1]
+    block = math.gcd(x.size // d, ROW_BLOCK)
+
+    def one(xb, tb, scale, head):
+        rows = logits(cfg, xb, scale, head)
+        gold = jnp.take_along_axis(rows, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
+        return jnp.sum((jax.nn.logsumexp(rows, axis=-1) - gold) * (tb >= 0))
+
+    if cfg.remat:
+        one = jax.checkpoint(one)
+    total = jnp.sum(lax.map(lambda xs: one(*xs, scale, head),
+                            (x.reshape(-1, block, d), targets.reshape(-1, block))))
+    return total, jnp.sum(targets >= 0).astype(jnp.float32)
+
+
+def over_ranks(*sums):
+    """Each of ``sums`` summed over the data-parallel and sequence ranks."""
+    for ax in ("dp", "sp"):
+        sums = tuple(lax.psum(s, ax) for s in sums)
+    return sums
+
+
+def mean_loss(total, count, stats):
+    """The global mean token cross-entropy, identical on every rank, and the
+    step's routing stats (ROUTING_STATS name → int32) summed over the ranks."""
+    total, count, stats = over_ranks(total, count, stats)
+    return total / count, dict(zip(ROUTING_STATS, stats))

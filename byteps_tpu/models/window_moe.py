@@ -17,24 +17,14 @@ expert that every token takes ungated.  The embedding is scaled by
 ``√d_model`` (``mup``); bias-free, RMSNorm ``w · x / rms(x)``, untied head, no
 position table, no auxiliary loss.
 
-This device holds the experts ``[expert_lo, expert_lo + experts_held)`` and
-the first ``vocab_size`` rows of embedding and head: its share of a layer
-that several devices divide.  The router scores all ``n_experts``; what the
-experts held elsewhere would add is left out; the shared expert is whole.
-
-Parameters are stacked by kind (``win``, ``glob``: the mixers; ``dense``,
-``moe``: the MLPs), layer ``i`` takes the next entry of its two stacks, and
-every mixer and every MLP is rebuilt in the backward pass on its own.  The
-plain reference is ``models/window_moe_reference.py``.
-
-By import, not a fifth time: the norm (``w``, where ``delta_moe``'s is
-``1 + w``), the SwiGLU, the routed experts' wrapper (scopes ``moe_route``,
-``moe_experts``) and the blocked cross-entropy are ``models/conv_moe.py``'s —
-its tied head contracts with a (vocabulary, model) matrix, which is how this
-family lays out its UNTIED head, so the same function serves once handed
-``head`` where it reads ``embed``; rope is ``delta_moe.rope_partial`` at the
-whole head.  What differed and stands here: the mixer (gate, two masks, rope
-on one kind alone), the sandwich norms, the shared expert, the embedding's scale.
+A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
+says one is (the share of experts and vocabulary this device holds — the
+shared expert is whole —, the protocol of a family with listed layers, what
+the families share).  Parameters are stacked by kind (``win``, ``glob``: the
+mixers; ``dense``, ``moe``: the MLPs), layer ``i`` takes the next entry of its
+two stacks, and every mixer and every MLP is rebuilt in the backward pass on
+its own.  The untied head is laid out as the embedding is, (vocabulary,
+model).  The plain reference is ``models/window_moe_reference.py``.
 """
 
 from __future__ import annotations
@@ -45,16 +35,14 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from byteps_tpu.models.conv_moe import _logits, _rms, _swiglu, _xent_sums, expert_mlp
-from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.models import moe_family as mf
+from byteps_tpu.models.moe_family import rms, swiglu
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.head_norm import head_norm_rope
-from byteps_tpu.parallel.moe import ROUTING_STATS
+from byteps_tpu.parallel.moe import sigmoid_topk_route
 
-_ALL_AXES = ("dp", "pp", "sp", "tp")
 #: ``layer_types`` entry → the stack that holds that mixer's parameters
 MIXERS = {"sliding_attention": "win", "full_attention": "glob"}
 #: stack → the scope its mixer's operations are filed under
@@ -62,7 +50,7 @@ SCOPES = {"win": "window_attention", "glob": "global_attention"}
 
 
 @dataclasses.dataclass(frozen=True)
-class WindowMoEConfig:
+class WindowMoEConfig(mf.PatternedFamily):
     vocab_size: int = 200192  # rows of the vocabulary held here
     d_model: int = 2048
     layer_types: Tuple[str, ...] = ("sliding_attention",) * 3 + ("full_attention",)
@@ -89,48 +77,18 @@ class WindowMoEConfig:
     compute_dtype: Any = jnp.float32
     remat: bool = True
 
+    mixers = MIXERS
+    family = "sliding-window"
+    lacks = ("expert exchange, pipeline split, head sharding or hand-over of a window's "
+             "keys between sequence shards")
+
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        unknown = sorted(set(self.layer_types) - set(MIXERS))
-        if unknown or not self.layer_types:
-            raise ValueError(f"layer_types holds {unknown or 'nothing'}: a layer's mixer is "
-                             f"one of {sorted(MIXERS)}")
-        if not 0 <= self.n_dense_layers <= len(self.layer_types):
-            raise ValueError(f"{self.n_dense_layers} leading dense layers in a model of "
-                             f"{len(self.layer_types)}")
-        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
-            raise ValueError(
-                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
-                f"lie outside the router's {self.n_experts}")
-        if self.n_heads % self.n_kv_heads:
-            raise ValueError("query heads must be a multiple of key/value heads")
-        if self.head_dim % 2:
-            raise ValueError(f"rope needs an even head_dim, got {self.head_dim}")
+        super().__post_init__()
+        self._check_grouped_heads()
+        self._check_even_rope("head_dim")
         if self.sliding_window < 1:
             raise ValueError(f"a sliding window holds the query itself at least, got "
                              f"{self.sliding_window}")
-
-    @property
-    def n_layers(self) -> int:
-        return len(self.layer_types)
-
-    def kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """Layer by layer, the stacks (mixer's, MLP's) it reads."""
-        return tuple((MIXERS[t], "dense" if i < self.n_dense_layers else "moe")
-                     for i, t in enumerate(self.layer_types))
-
-    # what transformer.build_train_step / build_forward ask of a family
-    def layouts(self) -> Dict[str, Tuple]:
-        return layouts(self)
-
-    def validate_mesh(self, mesh: Mesh) -> None:
-        validate_mesh(self, mesh)
-
-    def local_loss(self, mesh: Mesh, params, tokens, targets):
-        return local_loss(self, mesh, params, tokens, targets)
-
-    def local_logits(self, mesh: Mesh, params, tokens):
-        return local_logits(self, params, tokens)[None]  # one microbatch, no pipeline
 
 
 def tiny_window_moe(**kw) -> WindowMoEConfig:
@@ -169,50 +127,28 @@ def stacks(cfg: WindowMoEConfig) -> Dict[str, Tuple[int, Dict[str, tuple]]]:
                 "e_gate": (e, d, fe), "e_up": (e, d, fe), "e_down": (e, fe, d),
                 "s_gate": (d, fs), "s_up": (d, fs), "s_down": (fs, d), "post_norm": (d,)},
     }
-    used = [stack for pair in cfg.kinds() for stack in pair]
-    return {k: (used.count(k), v) for k, v in shapes.items() if k in used}
+    return cfg.stack_sizes(shapes)
 
 
 def layouts(cfg: WindowMoEConfig) -> Dict[str, Tuple]:
-    """name → (global shape, partition spec, gradient sync axes), as
-    ``transformer._layouts`` gives them.  Everything is replicated: this
-    family runs data-parallel only so far (:func:`validate_mesh`).  ``head``
-    is laid out as the embedding is, (vocabulary, model)."""
+    """name → (global shape, partition spec, gradient sync axes): every leaf
+    replicated (``moe_family.layouts``).  ``head`` is laid out as the
+    embedding is, (vocabulary, model)."""
     v, d = cfg.vocab_size, cfg.d_model
-    shapes = {"embed": (v, d), "norm_f": (d,), "head": (v, d)}
-    for stack, (n, per_layer) in stacks(cfg).items():
-        shapes.update({f"{stack}.{k}": (n,) + s for k, s in per_layer.items()})
-    return {k: (s, P(), _ALL_AXES) for k, s in shapes.items()}
+    return mf.layouts({"embed": (v, d), "norm_f": (d,), "head": (v, d)}, stacks(cfg))
+
+
+#: how the leaves start, beside ``moe_family.INIT_RULES``: ones for the norms'
+#: scales, N(0, 0.01²) for the selection bias (a trained balance's size: zeros
+#: would hide a bias that weighs); the head contracts its last dim
+INIT = {"*norm*": mf.ones, "router_bias": mf.normal(0.01), "wg": mf.fan_in(-3),
+        "head": mf.fan_in(-1)}
 
 
 def init_params(cfg: WindowMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
-    """f32 parameters from ``key``, jittable (made on the device): N(0,
-    1/fan_in) matrices, 0.02 for the embedding, ones for the norms' scales,
-    N(0, 0.01²) for the selection bias (a trained balance's size: zeros would
-    hide a bias that weighs)."""
-    params = {}
-    for i, (name, (shape, _, _)) in enumerate(layouts(cfg).items()):
-        leaf, k = name.rsplit(".", 1)[-1], jax.random.fold_in(key, i)
-        if "norm" in leaf:
-            params[name] = jnp.ones(shape, jnp.float32)
-        else:
-            # the contracted dims: wo its two before the last, the head its last
-            if leaf == "wo":
-                fan_in = math.prod(shape[-3:-1])
-            else:
-                fan_in = shape[{"wq": -3, "wk": -3, "wv": -3, "wg": -3, "head": -1}.get(leaf, -2)]
-            std = {"embed": 0.02, "router_bias": 0.01}.get(leaf, fan_in ** -0.5)
-            params[name] = std * jax.random.normal(k, shape, jnp.float32)
-    return params
-
-
-def validate_mesh(cfg: WindowMoEConfig, mesh: Mesh) -> None:
-    for ax in ("pp", "sp", "tp"):
-        if mesh.shape.get(ax, 1) != 1:
-            raise ValueError(
-                f"the sliding-window MoE family runs data-parallel only: mesh has "
-                f"{ax}={mesh.shape[ax]} (no expert exchange, pipeline split, head sharding "
-                "or hand-over of a window's keys between sequence shards is built for it yet)")
+    """f32 parameters from ``key``, jittable (made on the device), by
+    :data:`INIT`."""
+    return mf.init_params(layouts(cfg), key, INIT)
 
 
 # ---------------------------------------------------------------------------
@@ -227,7 +163,7 @@ def _attention_mixer(cfg: WindowMoEConfig, x, lp, stack: str):
     before it."""
     cdt, hd, eps = cfg.compute_dtype, cfg.head_dim, cfg.norm_eps
     with jax.named_scope(SCOPES[stack]):
-        g = _rms(x, lp["norm"], eps).astype(cdt)
+        g = rms(x, lp["norm"], eps).astype(cdt)
         q, k, v = (jnp.einsum("bsd,dhk->bhsk", g, lp[w].astype(cdt)) for w in ("wq", "wk", "wv"))
         z = jnp.einsum("bsd,dhk->bshk", g, lp["wg"].astype(cdt))  # as W_o's product reads it
         theta = cfg.rope_theta if stack == "win" else None
@@ -238,37 +174,37 @@ def _attention_mixer(cfg: WindowMoEConfig, x, lp, stack: str):
         o = flash_attention(q, k, v, causal=True, scale=hd ** -0.5,
                             window=cfg.sliding_window if stack == "win" else None)
         o = o.transpose(0, 2, 1, 3) * jax.nn.sigmoid(z.astype(jnp.float32)).astype(cdt)
-        return _rms(jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cdt)), lp["post_norm"], eps)
+        return rms(jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cdt)), lp["post_norm"], eps)
 
 
 def _dense_mlp(cfg: WindowMoEConfig, x, lp):
     """x (B, S, D) → the dense SwiGLU between its two norms (B, S, D) f32."""
     cdt = cfg.compute_dtype
     with jax.named_scope("dense_mlp"):
-        g = _rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
-        y = _swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
-        return _rms(y, lp["post_norm"], cfg.norm_eps)
+        g = rms(x, lp["norm"], cfg.norm_eps).astype(cdt)
+        y = swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
+        return rms(y, lp["post_norm"], cfg.norm_eps)
 
 
 def moe_mlp(cfg: WindowMoEConfig, g32, lp):
     """An expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
-    experts' routed part (``conv_moe.expert_mlp``: scopes ``moe_route`` and
-    ``moe_experts``) plus the shared expert, which every token takes at
+    experts' routed part plus the shared expert, which every token takes at
     weight 1.  Returns (y (T, D) f32, routing stats)."""
-    cdt = cfg.compute_dtype
-    y, stats = expert_mlp(cfg, g32, lp)
-    with jax.named_scope("shared_expert"):
-        shared = _swiglu(g32.astype(cdt), *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
-    return y + shared.astype(jnp.float32), stats
+    def route(g32, lp):
+        return sigmoid_topk_route(g32, lp["router"], lp["router_bias"], cfg.top_k,
+                                  cfg.routed_scale, eps=cfg.route_eps)
+
+    # cast where each expert reads
+    return mf.routed_mlp(cfg, g32, g32, lp, route, "shared_expert")
 
 
 def _moe_layer(cfg: WindowMoEConfig, x, lp):
     b, s, d = x.shape
     with jax.named_scope("moe_experts"):  # the MLP's two norms are filed with the experts
-        g32 = _rms(x, lp["norm"], cfg.norm_eps).reshape(b * s, d)
+        g32 = rms(x, lp["norm"], cfg.norm_eps).reshape(b * s, d)
     y, stats = moe_mlp(cfg, g32, lp)
     with jax.named_scope("moe_experts"):
-        y = _rms(y.reshape(b, s, d), lp["post_norm"], cfg.norm_eps)
+        y = rms(y.reshape(b, s, d), lp["post_norm"], cfg.norm_eps)
     return x + y.astype(x.dtype), stats
 
 
@@ -280,38 +216,16 @@ def _hidden(cfg: WindowMoEConfig, params, tokens):
 
     run = {"win": residual(_attention_mixer, "win"), "glob": residual(_attention_mixer, "glob"),
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_layer(cfg, x, lp)}
-    if cfg.remat:
-        # a layer's mixer and its MLP are each rebuilt in the backward pass,
-        # one at a time; of attention all but the kernel's output and row
-        # statistics, so that the forward kernel does not run twice
-        keep_flash = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
-        run = {k: jax.checkpoint(f, policy=keep_flash if k in SCOPES else None)
-               for k, f in run.items()}
-
     x = params["embed"][tokens]
     if cfg.mup:
         x = x * math.sqrt(cfg.d_model)
-    x = x.astype(cfg.compute_dtype)
-    stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
-    stacked = {stack: {k.split(".", 1)[1]: v for k, v in params.items()
-                       if k.startswith(stack + ".")} for stack in run}
-    seen = dict.fromkeys(run, 0)  # how many layers of each stack have run
-    for pair in cfg.kinds():
-        for stack in pair:
-            lp = {k: v[seen[stack]] for k, v in stacked[stack].items()}
-            seen[stack] += 1
-            if stack == "moe":
-                x, each = run[stack](x, lp)
-                stats = stats + each
-            else:
-                x = run[stack](x, lp)
-    return x, stats
+    return mf.walk(cfg, run, tuple(SCOPES), params, x.astype(cfg.compute_dtype))
 
 
 def local_logits(cfg: WindowMoEConfig, params, tokens):
     """(B, S) → (B, S, V) f32 logits over the held rows."""
     x, _ = _hidden(cfg, params, tokens)
-    return _logits(cfg, x, params["norm_f"], params["head"])
+    return mf.row_logits(cfg, x, params["norm_f"], params["head"])
 
 
 def local_loss(cfg: WindowMoEConfig, mesh: Mesh, params, tokens, targets):
@@ -319,10 +233,5 @@ def local_loss(cfg: WindowMoEConfig, mesh: Mesh, params, tokens, targets):
     the step's routing stats (ROUTING_STATS name → int32) summed over the
     data-parallel ranks."""
     x, stats = _hidden(cfg, params, tokens)
-    # conv_moe's blocked cross-entropy reads its (vocabulary, model) matrix
-    # under ``embed``: here that matrix is the untied head
-    total, count = _xent_sums(cfg, {"norm_f": params["norm_f"], "embed": params["head"]},
-                              x, targets)
-    for ax in ("dp", "sp"):
-        total, count, stats = lax.psum(total, ax), lax.psum(count, ax), lax.psum(stats, ax)
-    return total / count, dict(zip(ROUTING_STATS, stats))
+    return mf.mean_loss(
+        *mf.xent_sums(cfg, mf.row_logits, x, targets, params["norm_f"], params["head"]), stats)

@@ -10,7 +10,6 @@ reference: tests/test_conv_moe.py.  Two files so that ``--dist loadfile``
 spreads them.)
 """
 
-import hashlib
 import importlib.util
 import json
 import os
@@ -18,17 +17,14 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from byteps_tpu.models import conv_moe as cm
 from byteps_tpu.models import conv_moe_reference as ref
-from byteps_tpu.models import delta_moe as dm
-from byteps_tpu.models import latent_moe as lm
-from byteps_tpu.models import transformer as tfm
+from byteps_tpu.models import moe_family as mf
 from byteps_tpu.parallel import moe
 
-from test_conv_moe import _mesh, _state, _worst
+from test_conv_moe import _state, _worst
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -50,7 +46,7 @@ def test_three_taps_read_the_present_and_the_two_tokens_before_it():
     x = jnp.arange(1.0, 11.0).reshape(1, 5, 2)
     taps = jnp.array([[1.0, 0.0], [10.0, 0.0], [100.0, 1.0]])
     want = [100, 310, 531, 753, 975]  # x_t·100 + x_{t-1}·10 + x_{t-2}·1, zeros before the start
-    for conv in (dm.causal_conv, ref.short_conv):
+    for conv in (mf.causal_conv, ref.short_conv):
         got = np.asarray(conv(x, taps))
         np.testing.assert_allclose(got[0, :, 1], x[0, :, 1])
         np.testing.assert_allclose(got[0, :, 0], want)
@@ -98,7 +94,7 @@ def test_rope_turns_the_whole_head_in_half_rotation_pairs():
     alone; nothing passes unrotated."""
     theta = 100.0
     x = jnp.arange(1.0, 13.0).reshape(3, 4)
-    got = np.asarray(dm.rope_partial(x, 4, theta))
+    got = np.asarray(mf.rope_partial(x, 4, theta))
     np.testing.assert_allclose(got[0], x[0])
     for pos in (1, 2):
         for i, freq in ((0, 1.0), (1, theta ** -0.5)):
@@ -291,36 +287,3 @@ def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
         builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
     assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
     assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
-
-
-#: sha256 of the StableHLO text of one tiny train step (sgd, batch 2, no
-#: donation, one CPU device), frozen at the parent of the PR that brought the
-#: short-convolution family: what that PR added beside them (an ``eps``
-#: argument whose default is the old constant in parallel/moe.py) moved no
-#: program.  The first two digests are tests/test_delta_moe_pieces.py's, unchanged.
-#: A change that means to move one re-freezes its digest here: PR 45 moved
-#: ``delta_moe`` (its linear mixer stays token-major and the rule takes
-#: (B, S, H, d) operands) and no other.
-FROZEN_LOWERINGS = {
-    "bert": "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
-    "latent_moe": "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
-    "delta_moe": "2dbb1d030a085c4d84a96d165e67f6fe9d10bd5f8337003bf13938886e6c7da6",
-}
-
-
-@pytest.mark.parametrize("family", sorted(FROZEN_LOWERINGS))
-def test_the_other_families_steps_lower_as_before(family):
-    if family == "bert":
-        cfg = tfm.tiny_test(causal=False)
-        params = tfm.init_params(cfg)
-    elif family == "latent_moe":
-        cfg = lm.tiny_latent_moe()
-        params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    else:
-        cfg = dm.tiny_delta_moe()
-        params = dm.init_params(cfg, jax.random.PRNGKey(0))
-    tx = optax.sgd(1.0)
-    tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
-    text = tfm.build_train_step(cfg, _mesh(), tx, donate=False).lower(
-        params, tx.init(params), tokens, tokens).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == FROZEN_LOWERINGS[family]

@@ -1,0 +1,233 @@
+"""What ``transformer.build_train_step`` lowers to, family by family, and what
+``models/moe_family.py`` decides for the four MoE families on its own.
+
+The guard (ISSUE 47): a change that means to move no program shows it here
+before the chip is asked.  Three things are held for every MoE family, all
+frozen on the parent of the PR that wrote ``moe_family.py`` and the same after
+it: the StableHLO text of the tiny train step at float32 AND at bfloat16 (the
+dtype every cell computes in; at float32 an ``.astype`` is a no-op that leaves
+no trace in the text, so only the bfloat16 text sees a cast that moved), the
+parameters a seed gives, and how many operations the step files under each of
+the ``jax.named_scope`` names that the benchmark's readers sort every
+``train_step.*_ms`` metric by.  A change that means to move one re-freezes it
+here and says so.
+"""
+
+import ast
+import functools
+import glob
+import hashlib
+import importlib.util
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.models import conv_moe, delta_moe, latent_moe, window_moe
+from byteps_tpu.models import moe_family as mf
+from byteps_tpu.models import transformer as tfm
+from byteps_tpu.parallel.mesh_utils import make_training_mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
+            "window_moe": window_moe}
+
+#: sha256 of the StableHLO text of one tiny train step (sgd 1.0, batch 2, no
+#: donation, one CPU device).  The four float32 digests of ``bert``,
+#: ``latent_moe``, ``delta_moe`` and ``conv_moe`` are the ones that
+#: tests/test_{delta,conv,window}_moe_pieces.py froze (PRs 36, 40, 42; PR 45
+#: moved ``delta_moe``); ``window_moe`` and the bfloat16 ones were taken on the
+#: parent of PR 47.
+FROZEN_LOWERINGS = {
+    ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
+    ("latent_moe", "float32"): "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
+    ("delta_moe", "float32"): "2dbb1d030a085c4d84a96d165e67f6fe9d10bd5f8337003bf13938886e6c7da6",
+    ("conv_moe", "float32"): "1fdc10e9c17fe944aeff4c8ad0123ae5c290fa9fa27e5e9fa49ec8731fd0c129",
+    ("window_moe", "float32"): "5acde3888dac2d39d7c44fb91a4207941ae7d0fac21e43cf940676b7229f3398",
+    ("latent_moe", "bfloat16"): "41743827d35059357bc0833fb43b751e5a5399054d9ba96886b4bcdbb32432b5",
+    ("delta_moe", "bfloat16"): "0091f1e476d768ea883bef53d9739fa2b531e18b3597f2efda8cb2a704245352",
+    ("conv_moe", "bfloat16"): "4bd52dc92dd55203afbef1683e339a3364885c1773e46898c9b3dd45b3bb5286",
+    ("window_moe", "bfloat16"): "96a08d44dafff01fd94a06503a4c895ea8f26d6c9dcb5bbb4af8cdead8994aae",
+}
+
+#: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
+#: shape, dtype and bytes, in the order of ``layouts()``
+FROZEN_PARAMETERS = {
+    "latent_moe": "bd1836107f6ad448e2b1c3cd8d3fe4f55ce57caeb2400e3d5daf9907a6f7cb79",
+    "delta_moe": "8bfcfa5a4b01a8a44f0f934ae49c063ca1c23a8706ea4bdcfc2271d7642e55ba",
+    "conv_moe": "f4fc219077bd48be16757941eb58ce12bd017c8d91e938d818e638eccd7d39b4",
+    "window_moe": "3d45bbea9a827d634d53bc91e00efbd1c80f4f58fcc242b9d196fc2d1b06a46e",
+}
+
+#: family → scope → operations of the bfloat16 step filed under it.  The scopes
+#: and their order are ``SCOPES`` of benchmark/readers/<family>.py (held equal
+#: below); an operation is filed under the first of them that its scope path
+#: has as a segment, which is the readers' rule.
+FROZEN_SCOPE_OPERATIONS = {
+    "latent_moe": {"mtp": 578, "mla_attention": 1788, "moe_route": 154, "moe_experts": 948,
+                   "moe_shared": 80},
+    "delta_moe": {"gdn_scan": 1580, "gdn_proj": 105, "gated_attention": 584, "moe_route": 172,
+                  "moe_experts": 948, "moe_shared": 164},
+    "conv_moe": {"short_conv": 369, "conv_proj": 258, "gqa_attention": 534, "dense_mlp": 105,
+                 "moe_route": 231, "moe_experts": 1401},
+    "window_moe": {"window_attention": 1884, "global_attention": 456, "dense_mlp": 165,
+                   "moe_route": 231, "shared_expert": 132, "moe_experts": 1794},
+}
+
+
+@functools.cache
+def _lowered(family: str, dtype: str):
+    if family == "bert":
+        cfg = tfm.tiny_test(causal=False)
+        params = tfm.init_params(cfg)
+    else:
+        module = FAMILIES[family]
+        cfg = getattr(module, f"tiny_{family}")(compute_dtype=jnp.dtype(dtype))
+        params = module.init_params(cfg, jax.random.PRNGKey(0))
+    mesh = make_training_mesh(1, {"dp": 1, "pp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
+    tx = optax.sgd(1.0)
+    tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
+    return tfm.build_train_step(cfg, mesh, tx, donate=False).lower(
+        params, tx.init(params), tokens, tokens)
+
+
+@pytest.mark.parametrize("family,dtype", sorted(FROZEN_LOWERINGS),
+                         ids=["-".join(case) for case in sorted(FROZEN_LOWERINGS)])
+def test_the_steps_lower_as_before(family, dtype):
+    text = _lowered(family, dtype).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == FROZEN_LOWERINGS[family, dtype]
+
+
+def parameters_digest(params) -> str:
+    digest = hashlib.sha256()
+    for name, leaf in params.items():
+        leaf = np.asarray(leaf)
+        digest.update(f"{name} {leaf.shape} {leaf.dtype}\n".encode())
+        digest.update(leaf.tobytes())
+    return digest.hexdigest()
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_seed_gives_the_parameters_it_gave(family):
+    module = FAMILIES[family]
+    cfg = getattr(module, f"tiny_{family}")()
+    params = module.init_params(cfg, jax.random.PRNGKey(0))
+    assert list(params) == list(cfg.layouts())
+    assert parameters_digest(params) == FROZEN_PARAMETERS[family]
+
+
+_NAMED_LOC = re.compile(r'^(#loc\d+) = loc\("([^"]*)"\(#loc\d+\)\)$', re.M)
+_OPERATION = re.compile(r"^(?!#loc).* loc\((#loc\d+)\)$", re.M)
+
+
+def scope_operations(text: str, scopes) -> dict:
+    """scope → lines of ``text`` (StableHLO with debug info) whose location is
+    a scope path with that scope the first of ``scopes`` among its segments."""
+    paths = dict(_NAMED_LOC.findall(text))
+    counts = dict.fromkeys(scopes, 0)
+    for loc in _OPERATION.findall(text):
+        parts = paths.get(loc, "").split("/")
+        scope = next((s for s in scopes if s in parts), None)
+        if scope is not None:
+            counts[scope] += 1
+    return counts
+
+
+def _readers_scopes(family: str) -> tuple:
+    spec = importlib.util.spec_from_file_location(
+        f"bench_reader_{family}", os.path.join(REPO, "benchmark", "readers", f"{family}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return tuple(module.SCOPES)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_every_scope_the_readers_file_by_holds_its_operations(family):
+    want = FROZEN_SCOPE_OPERATIONS[family]
+    assert tuple(want) == _readers_scopes(family)
+    got = scope_operations(_lowered(family, "bfloat16").as_text(debug_info=True), tuple(want))
+    for scope in want:  # by name: a scope that lost its last operation says which
+        assert got[scope] > 0, f"{family}: no operation is filed under {scope}"
+    assert got == want
+
+
+# ---------------------------------------------------------------------------
+# What moe_family.py decides on its own
+# ---------------------------------------------------------------------------
+
+
+def test_no_family_imports_another():
+    """Every ``*_moe`` module leans on ``moe_family`` and on no sibling."""
+    for path in glob.glob(os.path.join(REPO, "byteps_tpu", "models", "*_moe.py")):
+        imported = set()
+        with open(path) as source:
+            tree = ast.parse(source.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                imported |= {node.module or ""} | {f"{node.module}.{a.name}" for a in node.names}
+            elif isinstance(node, ast.Import):
+                imported |= {a.name for a in node.names}
+        siblings = sorted(name for name in imported if name.split(".")[-1].endswith("_moe"))
+        assert not siblings, f"{os.path.basename(path)} imports {siblings}"
+
+
+def test_init_refuses_a_leaf_without_a_rule():
+    layout = mf.layouts({"embed": (6, 4), "norm_f": (4,)}, {"moe": (2, {"router": (4, 3)})})
+    key = jax.random.PRNGKey(0)
+    with pytest.raises(ValueError, match="norm_f"):
+        mf.init_params(layout, key, {})
+    params = mf.init_params(layout, key, {"*norm*": mf.zeros, "norm_f": mf.ones})
+    assert list(params) == ["embed", "norm_f", "moe.router"]
+    assert params["moe.router"].shape == (2, 4, 3)
+    np.testing.assert_array_equal(params["norm_f"], np.ones(4))  # the exact name, not the pattern
+    np.testing.assert_array_equal(  # leaf 2 of the layout, fan-in the dim before the last
+        params["moe.router"], 0.5 * jax.random.normal(jax.random.fold_in(key, 2), (2, 4, 3)))
+
+
+def test_the_walk_runs_the_listed_layers_in_order():
+    cfg = conv_moe.tiny_conv_moe(
+        layer_types=("conv", "full_attention", "conv"), n_dense_layers=2, remat=False)
+    params = {f"{stack}.w": 10.0 * (i + 1) + jnp.arange(3.0)  # layer l of a stack holds 10·i + l
+              for i, stack in enumerate(("conv", "attn", "dense", "moe"))}
+    ran = []
+
+    def part(stack):
+        def run(x, lp):
+            ran.append((stack, float(lp["w"])))
+            return (x + 1, jnp.arange(4, dtype=jnp.int32)) if stack == "moe" else x + 1
+        return run
+
+    x, stats = mf.walk(cfg, {s: part(s) for s in ("conv", "attn", "dense", "moe")}, ("attn",),
+                       params, jnp.zeros(()))
+    assert ran == [("conv", 10.0), ("dense", 30.0), ("attn", 20.0), ("dense", 31.0),
+                   ("conv", 11.0), ("moe", 40.0)]
+    assert float(x) == 6 and list(stats) == [0, 1, 2, 3]
+
+
+def test_the_blocked_loss_is_the_unblocked_one(monkeypatch):
+    """14 rows in blocks of gcd(14, 4) = 2, some targets ignored."""
+    monkeypatch.setattr(mf, "ROW_BLOCK", 4)
+    cfg = window_moe.tiny_window_moe()
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, 7, cfg.d_model)), jnp.float32)
+    scale = jnp.asarray(1 + 0.1 * rng.normal(size=cfg.d_model), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(cfg.vocab_size, cfg.d_model)), jnp.float32)
+    targets = jnp.asarray(rng.integers(-1, cfg.vocab_size, size=(2, 7)), jnp.int32).at[0, 0].set(-1)
+
+    def blocked(x, scale, head):
+        return mf.xent_sums(cfg, mf.row_logits, x, targets, scale, head)[0]
+
+    def whole(x, scale, head):
+        logits = mf.row_logits(cfg, x, scale, head)
+        gold = jnp.take_along_axis(logits, jnp.maximum(targets, 0)[..., None], axis=-1)[..., 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - gold) * (targets >= 0))
+
+    got, want = (jax.value_and_grad(f, argnums=(0, 1, 2))(x, scale, head) for f in (blocked, whole))
+    assert float(mf.xent_sums(cfg, mf.row_logits, x, targets, scale, head)[1]) == int(
+        jnp.sum(targets >= 0)) < 14
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)

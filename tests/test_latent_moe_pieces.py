@@ -16,6 +16,7 @@ import pytest
 import byteps_tpu as bps
 from byteps_tpu.models import latent_moe as lm
 from byteps_tpu.models import latent_moe_reference as ref
+from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel import moe
 
@@ -44,7 +45,7 @@ def test_shares_add_up_to_the_uncut_layer():
     lp = _layer_params(whole)
     g = jax.random.normal(jax.random.PRNGKey(9), (48, whole.d_model))
     want = ref.expert_mlp(whole, g, lp)
-    shared = lm._swiglu(g, lp["s_gate"], lp["s_up"], lp["s_down"])
+    shared = mf.swiglu(g, lp["s_gate"], lp["s_up"], lp["s_down"])
     total, held = shared, 0
     for lo in range(0, 32, 8):
         share = lm.tiny_latent_moe(n_experts=32, experts_held=8, expert_lo=lo, top_k=4)

@@ -443,16 +443,15 @@ class TestFlashBand:
 
 class TestHeadNormRope:
     """``ops/head_norm.head_norm_rope``: a head's RMSNorm and its rotation in
-    one pass each way, held to ``_rms`` then ``rope_partial`` (the two passes
+    one pass each way, held to ``rms`` then ``rope_partial`` (the two passes
     the sliding-window family's mixers made), XLA's form and the kernels
     (interpret mode) alike."""
 
     @staticmethod
     def _old(x, w, eps, theta):
-        from byteps_tpu.models.conv_moe import _rms
-        from byteps_tpu.models.delta_moe import rope_partial
+        from byteps_tpu.models.moe_family import rms, rope_partial
 
-        y = _rms(x, w, eps).astype(x.dtype)
+        y = rms(x, w, eps).astype(x.dtype)
         return y if theta is None else rope_partial(y, x.shape[-1], theta)
 
     @staticmethod
@@ -472,7 +471,7 @@ class TestHeadNormRope:
         ((2, 4, 16, 8), None, False), ((1, 2, 24, 6), 10000.0, True),
     ])
     def test_matches_norm_then_rope_and_its_gradient(self, shape, theta, interpret):
-        """f32 in: the output, dx and the scale's gradient are ``_rms`` then
+        """f32 in: the output, dx and the scale's gradient are ``rms`` then
         ``rope_partial``'s to 1e-6."""
         from byteps_tpu.ops import head_norm as hn
 

@@ -10,7 +10,6 @@ moved.  (The model against its reference: tests/test_window_moe.py.  Two
 files so that ``--dist loadfile`` spreads them.)
 """
 
-import hashlib
 import importlib.util
 import json
 import os
@@ -18,12 +17,8 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from byteps_tpu.models import conv_moe as cm
-from byteps_tpu.models import delta_moe as dm
-from byteps_tpu.models import latent_moe as lm
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.models import window_moe as wm
 from byteps_tpu.models import window_moe_reference as ref
@@ -358,36 +353,3 @@ def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
         builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
     assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
     assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
-
-
-#: sha256 of the StableHLO text of one tiny train step (sgd, batch 2, no
-#: donation, one CPU device), frozen at the parent of the PR that brought the
-#: sliding-window family: what that PR added beside them (a ``window``
-#: argument that is None by default in ops/flash_attention.py) moved no
-#: program.  The first three digests are tests/test_conv_moe_pieces.py's,
-#: unchanged.  A change that means to move one re-freezes its digest here:
-#: PR 45 moved ``delta_moe`` (its linear mixer stays token-major and the rule
-#: takes (B, S, H, d) operands) and no other — ``conv_moe`` and this family
-#: import ``causal_conv`` / ``rope_partial``, whose text did not change.
-FROZEN_LOWERINGS = {
-    "bert": "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
-    "latent_moe": "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
-    "delta_moe": "2dbb1d030a085c4d84a96d165e67f6fe9d10bd5f8337003bf13938886e6c7da6",
-    "conv_moe": "1fdc10e9c17fe944aeff4c8ad0123ae5c290fa9fa27e5e9fa49ec8731fd0c129",
-}
-
-
-@pytest.mark.parametrize("family", sorted(FROZEN_LOWERINGS))
-def test_the_other_families_steps_lower_as_before(family):
-    if family == "bert":
-        cfg = tfm.tiny_test(causal=False)
-        params = tfm.init_params(cfg)
-    else:
-        module = {"latent_moe": lm, "delta_moe": dm, "conv_moe": cm}[family]
-        cfg = getattr(module, f"tiny_{family}")()
-        params = module.init_params(cfg, jax.random.PRNGKey(0))
-    tx = optax.sgd(1.0)
-    tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
-    text = tfm.build_train_step(cfg, _mesh(), tx, donate=False).lower(
-        params, tx.init(params), tokens, tokens).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == FROZEN_LOWERINGS[family]
