@@ -31,7 +31,7 @@ from __future__ import annotations
 import fnmatch
 import math
 import sys
-from typing import Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +39,8 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
-from byteps_tpu.parallel.moe import ROUTING_STATS, held_expert_mlp
+from byteps_tpu.parallel.moe import (ROUTING_STATS, HeldPlan, held_expert_apply,
+                                     held_expert_plan)
 
 _ALL_AXES = ("dp", "pp", "sp", "tp")
 #: rows of logits that stand at a time in the blocked loss
@@ -259,24 +260,52 @@ def causal_conv(x, taps):
     return sum(padded[:, j:j + s].astype(jnp.float32) * taps[j] for j in range(k))
 
 
-def routed_mlp(cfg, g32, g, lp, route: Callable, shared_scope: Optional[str] = None):
+class Decision(NamedTuple):
+    """An expert layer's routing, made: where each slot goes among the held
+    experts (``parallel/moe.HeldPlan``) and what each choice weighs, (T, k)
+    f32.  8 bytes a slot: small enough to keep where tokens are rebuilt."""
+
+    plan: HeldPlan
+    weights: jax.Array
+
+
+def decide(cfg, g32, lp, route: Callable) -> Decision:
+    """An expert layer's routing decided on the tokens ``g32`` (T, D) f32,
+    which need not be the tokens the experts will read: ``route(g32, lp)`` →
+    (ids, weights), the family's router, and the held experts' plan from the
+    ids, all under ``moe_route``.  :func:`routed_mlp` takes it in a router's
+    place."""
+    with jax.named_scope("moe_route"):
+        ids, weights = route(g32, lp)
+        return Decision(held_expert_plan(ids, cfg.expert_lo, cfg.experts_held), weights)
+
+
+def routed_mlp(cfg, g32, g, lp, route: Union[Callable, Decision],
+               shared_scope: Optional[str] = None, act: Callable = jax.nn.silu):
     """An expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
-    experts' routed part (``parallel/moe.held_expert_mlp``: what the experts
-    held elsewhere would add is left out) plus, under ``shared_scope`` where
+    experts' routed part (``parallel/moe.held_expert_apply``: what the experts
+    held elsewhere would add is left out; ``act`` is their gate's activation)
+    plus, under ``shared_scope`` where
     the family has one, the shared expert that every token takes — behind
     ``sigmoid(g · shared_gate)`` where the layer has that leaf, at weight 1
-    where not.  ``route(g32, lp)`` → (ids, weights) is the family's router.
+    where not.  ``route(g32, lp)`` → (ids, weights) is the family's router, or
+    ``route`` is the :class:`Decision` made earlier (:func:`decide`).
     ``g`` is what the experts read: the tokens in the compute dtype where the
     family has them, or ``g32`` again — then they are cast where each expert
     reads them.  The three scopes are what the benchmark's readers file a
     step's operations by.  Returns (y (T, D) f32, routing stats)."""
     cdt = cfg.compute_dtype
-    with jax.named_scope("moe_route"):
-        ids, weights = route(g32, lp)
+    made = not callable(route)
+    if not made:
+        with jax.named_scope("moe_route"):
+            ids, weights = route(g32, lp)
     with jax.named_scope("moe_experts"):
-        y, stats = held_expert_mlp(
-            g.astype(cdt), ids, weights, *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down")),
-            lo=cfg.expert_lo, n_experts=cfg.n_experts)
+        # cast before the plan: the order the four older families' steps lower in
+        read = g.astype(cdt), *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down"))
+        plan, weights = route if made else (
+            held_expert_plan(ids, cfg.expert_lo, cfg.experts_held), weights)
+        y, stats = held_expert_apply(read[0], plan, weights, *read[1:],
+                                     n_experts=cfg.n_experts, act=act)
     if shared_scope is None:
         return y, stats
     with jax.named_scope(shared_scope):
@@ -299,12 +328,24 @@ def keep_flash():
     return jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
 
 
+class Handed(NamedTuple):
+    """What a layer's mixer part returns to :func:`walk` when the MLP part of
+    the same layer is to get a value beside ``x``."""
+
+    x: jax.Array
+    value: Any
+
+
 def walk(cfg, run: Dict[str, Callable], flash: Tuple[str, ...], params, x):
     """The layers of a :class:`PatternedFamily`, unrolled: layer by layer of
     ``cfg.kinds()``, the mixer's part and the MLP's, each on the next entry of
     its stack.  ``run[stack](x, lp)`` → x, or (x, routing stats) from a part
     that routes; each is rebuilt in the backward pass on its own, one at a
-    time (the stacks of ``flash`` keeping their kernel's output).  Returns
+    time (the stacks of ``flash`` keeping their kernel's output).  A mixer's
+    part may return :class:`Handed`: its value goes to the MLP's part of the
+    same layer as a third argument — an output of the one rebuilt part and an
+    input of the other, so it is kept, and what only it needs (a sort, say) is
+    in neither rebuild.  Returns
     (x, the routing stats summed over the layers)."""
     if cfg.remat:
         policy = keep_flash()
@@ -314,11 +355,14 @@ def walk(cfg, run: Dict[str, Callable], flash: Tuple[str, ...], params, x):
     stacked = {stack: stack_of(params, stack) for stack in run}
     seen = dict.fromkeys(run, 0)  # how many layers of each stack have run
     for pair in cfg.kinds():
+        handed = ()
         for stack in pair:
             lp = {k: v[seen[stack]] for k, v in stacked[stack].items()}
             seen[stack] += 1
-            x = run[stack](x, lp)
-            if isinstance(x, tuple):
+            x, handed = run[stack](x, lp, *handed), ()
+            if isinstance(x, Handed):
+                x, handed = x.x, (x.value,)
+            elif isinstance(x, tuple):
                 x, each = x
                 stats = stats + each
     return x, stats

@@ -14,7 +14,7 @@ group owns ``n_experts / group_size`` experts.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -172,21 +172,56 @@ def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
+class HeldPlan(NamedTuple):
+    """What :func:`held_expert_plan` decides from the chosen ids alone, for
+    :func:`held_expert_apply`: which slots (token, choice) go to experts held
+    here, in which order, and how many each expert takes."""
+
+    order: jax.Array  # (T·k,) int32: the slots, held ones first, by expert
+    sizes: jax.Array  # (n_held,) int32: slots of each held expert
+
+
+def held_expert_plan(ids: jax.Array, lo: int, n_held: int) -> HeldPlan:
+    """The part of a held-expert layer that depends on the routing ids (T, k)
+    alone: the slots whose expert is one of ``[lo, lo + n_held)`` ordered by
+    expert (one sort) and counted.  It touches no token, so a caller may make
+    it before the tokens the experts read exist, keep it, and put an exchange
+    between it and :func:`held_expert_apply`."""
+    local = ids.reshape(-1) - lo
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)  # (T·k,)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=key.dtype)[None, :],
+                    axis=0, dtype=jnp.int32)  # slots of each held expert
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held slots first, by expert
+    return HeldPlan(order, sizes)
+
+
 def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                     lo: int, n_experts: int) -> tuple:
+    """:func:`held_expert_plan` and :func:`held_expert_apply` in one call, the
+    experts bias-free SwiGLUs: ``ids`` (T, k) as ``weights``, ``lo`` the first
+    held expert."""
+    plan = held_expert_plan(ids, lo, w_gate.shape[0])
+    return held_expert_apply(g, plan, weights, w_gate, w_up, w_down, n_experts, jax.nn.silu)
+
+
+def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
+                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                      n_experts: int, act: Callable) -> tuple:
     """The routed experts' part of a layer's output that THIS device's
     experts give: ``Σ_{i chosen and held} w_i E_i(g)``, every ``E`` a
-    bias-free SwiGLU.
+    bias-free gated MLP ``(act(g W_gate) ⊙ g W_up) W_down``.
 
     g:        (T, D) tokens, compute dtype
-    ids, weights: (T, k) from a ``*_topk_route`` above, over all
-              ``n_experts`` of the model
+    plan:     :func:`held_expert_plan` of the chosen ids (T, k)
+    weights:  (T, k) from a ``*_topk_route`` above, over all ``n_experts``
+              of the model
     w_gate, w_up: (n_held, D, F), w_down: (n_held, F, D) — the experts
               ``[lo, lo + n_held)``, which this device holds
+    act:      the gate's activation (``jax.nn.silu``: SwiGLU)
 
-    The slots (token, choice) whose expert is held are ordered by expert
-    (one sort) and multiplied in grouped products (``lax.ragged_dot``: each
+    The slots (token, choice) whose expert is held come ordered by expert
+    and are multiplied in grouped products (``lax.ragged_dot``: each
     expert takes exactly its rows, however many).  No capacity, no drop: the
     ordered slots are walked in chunks of twice what a uniform router sends
     here: the usual step runs one chunk; when more slots arrive, the other
@@ -196,13 +231,9 @@ def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
     nothing stands in for them.
 
     Returns ``(y (T, D) f32, stats (4,) int32 in ROUTING_STATS order)``."""
-    t, k = ids.shape
+    t, k = weights.shape
     n_held, d = w_gate.shape[0], g.shape[1]
-    local = ids.reshape(-1) - lo
-    key = jnp.where((local >= 0) & (local < n_held), local, n_held)  # (T·k,)
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=key.dtype)[None, :],
-                    axis=0, dtype=jnp.int32)  # slots of each held expert
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held slots first, by expert
+    order, sizes = plan
     ends = jnp.cumsum(sizes)
     n_slots = ends[-1]
     flat_w = weights.reshape(-1)
@@ -230,7 +261,7 @@ def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
         def grouped(lhs, rhs):
             return jnp.where(live, lax.ragged_dot(lhs, rhs, mine), 0)
 
-        out = grouped(jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
+        out = grouped(act(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
         return y.at[tok].add(out.astype(jnp.float32) * flat_w[slot][:, None]), jnp.sum(mine)
 
     y0 = jnp.zeros((t, d), jnp.float32)

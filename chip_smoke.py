@@ -49,6 +49,11 @@ the entry points a user calls, at the full width of the models the repo lists:
          global layer without positions, gated attention at heads of 128,
          sandwich norms, a leading dense layer, 8 of 128 sigmoid-routed
          experts beside a shared expert), as leg F.
+  leg I  the early-routed MoE family at the published widths of
+         benchmark/configs/smallthinker_21b_ep8.json (a router that decides
+         before the attention, one global layer without positions and three
+         sliding-window layers at 7 query heads a key/value head, 8 of 64
+         ReLU-gated experts in every layer), as leg F.
 
 Every result line names the platform, device kind, device count and the jax /
 jaxlib / libtpu versions.  Step times are printed as information only: they
@@ -745,6 +750,24 @@ LEG_H_LIMITS = {
 }
 
 
+#: leg I's limits.  The first gradient tells the nearest precision below
+#: apart here as well (readings on the chip, PR 48,
+#: tools/latent_moe_precision.py --config smallthinker_21b_ep8, 3 seeds, and
+#: this leg's own key: the program | the reference with bf16 statistics —
+#: loss <= 8.6e-6 | 2.0e-5; routers and routed experts 0.074-0.078 |
+#: 0.086-0.088, moe.e_gate or win.router (relu's kink beside the near-ties:
+#: a gate within rounding of zero opens or shuts a whole hidden unit); other
+#: leaves at most 0.064-0.067 | 0.077, always moe.norm, whose whole gradient
+#: comes through the routed experts; median 0.022-0.026 | 0.028-0.030;
+#: projection within 0.004 of 1 on both sides), so each limit stands between
+#: its two readings; the projection's stands against a planted fault (a halved
+#: gradient reads 0.5).
+LEG_I_LIMITS = {
+    "as made": {"loss": 1.7e-4, "routed": 0.082, "rest": 0.072, "median": 0.027,
+                "projection": 0.15},
+}
+
+
 def _reference_leg(dry: bool, leg: str, config: str, what, cases, limits: dict) -> None:
     """One step of ``benchmark/configs/<config>.json`` through
     ``build_train_step`` with an optimizer that keeps the gradient: loss and
@@ -847,6 +870,18 @@ def leg_h(dry: bool) -> None:
         {"as made": lambda params, cfg: params}, LEG_H_LIMITS)
 
 
+def leg_i(dry: bool) -> None:
+    """The early-routed MoE family.  One case, as legs F to H: its router has
+    no selection bias to pin the choice with."""
+    _reference_leg(
+        dry, "I", "smallthinker_21b_ep8",
+        lambda cfg: (f"SmallThinker-21BA3B share: {cfg['num_hidden_layers']} layers from entry "
+                     f"{cfg['first_layer']} of the published lists, window "
+                     f"{cfg['sliding_window_size']}, {cfg['moe_num_primary_experts']} of "
+                     f"{cfg['router_width']} experts"),
+        {"as made": lambda params, cfg: params}, LEG_I_LIMITS)
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -858,7 +893,7 @@ def main() -> int:
         help="pre-flight on the CPU at cut sizes with interpreted kernels; "
              "proves the control flow only, never a chip result",
     )
-    ap.add_argument("--legs", default="ABCDEFGH",
+    ap.add_argument("--legs", default="ABCDEFGHI",
                     help="the legs to run, e.g. H (all by default; B's children start anyway)")
     args = ap.parse_args()
     dry, legs = args.cpu_dry_run, set(args.legs.upper())
@@ -930,6 +965,7 @@ def main() -> int:
         run("F", lambda: leg_f(dry))
         run("G", lambda: leg_g(dry))
         run("H", lambda: leg_h(dry))
+        run("I", lambda: leg_i(dry))
 
         check_children(children)
         bps.shutdown()

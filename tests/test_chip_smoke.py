@@ -36,6 +36,7 @@ def _run(args, **env):
 DRY_RUN_GROUPS = {
     "ABCD": ("leg A", "leg B", "leg C flash", "leg C flash latent", "leg C onebit"),
     "E": ("leg E",), "F": ("leg F",), "G": ("leg G",), "H": ("leg H",),
+    "I": ("leg I",),
 }
 
 

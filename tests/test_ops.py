@@ -114,7 +114,8 @@ class TestFlashAttention:
 
     # (group, causal, window, block_q, block_k, d_qk, d_v, lse output) at
     # sequence 256, two batches of 2 key/value heads: a key/value head found
-    # by index map for its 2 | 4 query heads, full and banded, d_qk != d_v,
+    # by index map for its 2 | 4 | 7 query heads (7: SmallThinker's 28 | 4, a
+    # group that is no power of two), full and banded, d_qk != d_v,
     # block_q != block_k both ways round, with and without the lse output
     @pytest.mark.parametrize("group,causal,window,bq,bk,d_qk,d_v,lse_out", [
         (2, True, None, 64, 64, 32, 32, False), (4, True, None, 64, 64, 48, 32, False),
@@ -123,6 +124,8 @@ class TestFlashAttention:
         (2, True, 64, 64, 64, 32, 32, False), (4, True, 100, 64, 64, 48, 32, False),
         (2, True, 37, 32, 32, 32, 48, True), (4, True, 64, 128, 32, 48, 32, True),
         (4, True, 96, 32, 128, 32, 48, False), (2, True, 1, 64, 64, 32, 32, False),
+        (7, True, None, 64, 64, 32, 32, False), (7, True, None, 128, 32, 48, 32, True),
+        (7, True, 64, 64, 64, 32, 32, False), (7, True, 100, 32, 128, 32, 48, True),
     ])
     def test_grouped_heads_by_index_map_match_repeated_heads(self, group, causal, window, bq, bk,
                                                               d_qk, d_v, lse_out):

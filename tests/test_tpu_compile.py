@@ -168,6 +168,34 @@ def test_flash_kernels_compile_at_the_sliding_window_shape(one_chip, no_compile_
         assert not re.search(r"bf16\[4,8,16384,128\]\S* broadcast\(", text)
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["banded_group_of_7", "global_group_of_7"])
+def test_flash_kernels_compile_at_the_early_routed_shape(one_chip, no_compile_cache, monkeypatch,
+                                                         window):
+    """(2, 28 | 4, 16384, 128 | 128) — SmallThinker's mixers: seven query
+    heads a key/value head (the kernels find head ``h // 7`` by index map; K
+    and V are their operands at 2 x 4 heads and no array of 56 stands for
+    them), the banded pair at window 4096 with the blocks
+    ops/flash_blocks.json commits for (16384, 4096), and the full causal pair
+    of the global layer."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    assert (16384, 4096) in fa._tuned_table()["banded"]
+    q = jax.ShapeDtypeStruct((2, 28, 16384, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 4, 16384, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=128 ** -0.5, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k).as_text()
+    wanted = (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) if window else (fa.FWD_KERNEL, fa.BWD_KERNEL)
+    for kernel in wanted:
+        assert kernel in text, f"{kernel} is not in the compiled program"
+        q_, k_, v_ = _kernel_operands(text, kernel)[:3]
+        assert (q_, k_, v_) == ("bf16[56,16384,128]",) + ("bf16[8,16384,128]",) * 2
+    assert (fa.FWD_WIN_KERNEL in text) == bool(window)
+    assert not re.search(r"bf16\[2,4,7,16384,128\]\S* broadcast\(", text)
+
+
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
     """2 x 8192 tokens, 2048 channels, 3 taps, bf16 operands: the double-gated
     short convolution between its two projections and its four gradients.

@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
-"""Where the limits on the four MoE families' precision come from.
+"""Where the limits on the five MoE families' precision come from.
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
     python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
     python tools/latent_moe_precision.py --config lfm2_24b_a2b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config trinity_mini_ep16 --seeds ...
+    python tools/latent_moe_precision.py --config smallthinker_21b_ep8 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
