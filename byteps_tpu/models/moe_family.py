@@ -284,7 +284,9 @@ def routed_mlp(cfg, g32, g, lp, route: Union[Callable, Decision],
                shared_scope: Optional[str] = None, act: Callable = jax.nn.silu):
     """An expert layer's MLP on normed tokens ``g32`` (T, D) f32: the held
     experts' routed part (``parallel/moe.held_expert_apply``: what the experts
-    held elsewhere would add is left out; ``act`` is their gate's activation)
+    held elsewhere would add is left out; the rows it walks follow the slots
+    held, a first chunk of 9/8 of the even load and tail chunks of a quarter;
+    ``act`` is their gate's activation)
     plus, under ``shared_scope`` where
     the family has one, the shared expert that every token takes — behind
     ``sigmoid(g · shared_gate)`` where the layer has that leaf, at weight 1

@@ -134,7 +134,7 @@ def moe_aux_loss(x: jax.Array, router_w: jax.Array, axis_size: int, e_local: int
 
 #: what one compiled step reports of its routing, in this order
 ROUTING_STATS = ("moe_slots_routed", "moe_slots_held", "moe_slots_dropped",
-                 "moe_fullest_expert_slots")
+                 "moe_fullest_expert_slots", "moe_rows_walked")
 
 
 def sigmoid_topk_route(g: jax.Array, router_w: jax.Array, select_bias: jax.Array,
@@ -205,6 +205,49 @@ def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
     return held_expert_apply(g, plan, weights, w_gate, w_up, w_down, n_experts, jax.nn.silu)
 
 
+def _varying(x: jax.Array, axes: frozenset) -> jax.Array:
+    """x typed as varying over ``axes`` too (under ``shard_map``; x elsewhere)."""
+    need = tuple(axes - jax.typeof(x).vma)
+    return lax.pcast(x, need, to="varying") if need else x
+
+
+@jax.custom_vjp
+def _add_rows(y: jax.Array, at: jax.Array, rows: jax.Array) -> jax.Array:
+    """``y.at[at].add(rows)``, the sort made by hand.  XLA:TPU sorts a
+    scatter's indices itself and gathers the rows in that order; at some
+    shapes it fuses that gather into the scatter, which then takes over twice
+    as long whatever the rows (into (32 768, 2560) f32: 17.4 ms for 27 648
+    rows and 15.6 for 6144, where 49 152 rows take 9.3; PERF.md §6 PR 49).
+    Sorted here and the gather kept behind a barrier, the same sum takes 7.3
+    and 4.8 ms, and no shape tried takes 0.3 ms more than XLA's own."""
+    at, order = lax.sort_key_val(at, jnp.arange(at.shape[0], dtype=at.dtype))
+    return y.at[at].add(lax.optimization_barrier(rows[order]), indices_are_sorted=True)
+
+
+@jax.custom_vjp
+def _take_rows(x: jax.Array, at: jax.Array) -> jax.Array:
+    """``x[at]``, whose backward pass is :func:`_add_rows` (as that one's is
+    this): each the other's transpose, so both ways a scatter is sorted by hand."""
+    return x[at]
+
+
+_add_rows.defvjp(lambda y, at, rows: (_add_rows(y, at, rows), at),
+                 lambda at, d: (d, None, _take_rows(d, at)))
+_take_rows.defvjp(lambda x, at: (x[at], (x, at)),
+                  lambda kept, d: (_add_rows(jnp.zeros_like(kept[0]), kept[1], d), None))
+
+
+def held_walk(every: int, n_held: int, n_experts: int) -> tuple:
+    """The geometry of :func:`held_expert_apply`'s walk over ``every`` = T·k
+    ordered slots, from shapes alone: the rows of the first chunk — 9/8 of
+    the even load ``every · n_held / n_experts``, what a uniform router sends
+    here and an eighth — and of each tail chunk, a quarter of it; both in
+    whole tiles of 512 rows (of 8 below 4096 slots)."""
+    unit = 8 if every < 4096 else 512
+    first = min(every, _round_up(-(-9 * every * n_held // (8 * n_experts)), unit))
+    return first, _round_up(-(-every * n_held // (4 * n_experts)), unit)
+
+
 def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
                       w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                       n_experts: int, act: Callable) -> tuple:
@@ -223,30 +266,28 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
     The slots (token, choice) whose expert is held come ordered by expert
     and are multiplied in grouped products (``lax.ragged_dot``: each
     expert takes exactly its rows, however many).  No capacity, no drop: the
-    ordered slots are walked in chunks of twice what a uniform router sends
-    here: the usual step runs one chunk; when more slots arrive, the other
-    chunks run too (all T·k slots at most), so imbalance costs time and
-    never a token, and the buffers stay one chunk large.  What the experts held elsewhere would add is left
-    out: with expert parallelism their devices add it, and on one device
-    nothing stands in for them.
+    ordered slots are walked in chunks, and the rows walked follow the slots
+    held (:func:`held_walk`): a first chunk of 9/8 of what a uniform router
+    sends here, which the usual step ends with, and behind one ``cond`` tail
+    chunks of a quarter of that load for as long as slots are left (all T·k
+    slots at most) — a layer that holds L times the even load walks at most
+    max(9/8, L + 1/4) times it.  So imbalance costs time in proportion and
+    never a token, and the buffers stay one chunk large.  What the experts
+    held elsewhere would add is left out: with expert parallelism their
+    devices add it, and on one device nothing stands in for them.
 
-    Returns ``(y (T, D) f32, stats (4,) int32 in ROUTING_STATS order)``."""
+    Returns ``(y (T, D) f32, stats (5,) int32 in ROUTING_STATS order)``."""
     t, k = weights.shape
-    n_held, d = w_gate.shape[0], g.shape[1]
     order, sizes = plan
-    ends = jnp.cumsum(sizes)
-    n_slots = ends[-1]
-    flat_w = weights.reshape(-1)
-
     every = t * k
-    rows = min(every, _round_up(2 * every * n_held // n_experts, 8 if every < 4096 else 512))
-    n_chunks = -(-every // rows)
-    order = jnp.pad(order, (0, n_chunks * rows - every))
+    first_rows, tail_rows = held_walk(every, w_gate.shape[0], n_experts)
+    n_tail = -(-(every - first_rows) // tail_rows)
+    order = jnp.pad(order, (0, first_rows + n_tail * tail_rows - every))
 
-    def chunk(y, c):
-        """Adds the slots [c·rows, (c+1)·rows) of the ordered list to y;
-        also returns how many slots the grouped products took."""
-        first = c * rows
+    def rows_of(g, flat_w, w_gate, w_up, w_down, order, ends, sizes, first, rows):
+        """The slots [first, first + rows) of the ordered list: what they add
+        to y (rows, D) f32, and beside it the tokens they add it to and how
+        many slots the grouped products took."""
         slot = lax.dynamic_slice_in_dim(order, first, rows)
         tok = slot // k
         # this chunk's rows of each expert: its span cut to the chunk
@@ -255,48 +296,77 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         # rows past the last held slot belong to no expert: a grouped
         # product leaves them unwritten (garbage on the TPU), so every
         # product's operand and result is cleared there by selection
-        live = (first + jnp.arange(rows) < n_slots)[:, None]
-        xs = jnp.where(live, g[tok], 0)  # (rows, D)
+        live = (first + jnp.arange(rows) < ends[-1])[:, None]
+        xs = jnp.where(live, _take_rows(g, tok), 0)  # (rows, D)
 
         def grouped(lhs, rhs):
             return jnp.where(live, lax.ragged_dot(lhs, rhs, mine), 0)
 
         out = grouped(act(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
-        return y.at[tok].add(out.astype(jnp.float32) * flat_w[slot][:, None]), jnp.sum(mine)
+        return out.astype(jnp.float32) * flat_w[slot][:, None], (tok, jnp.sum(mine))
 
-    y0 = jnp.zeros((t, d), jnp.float32)
-    varying = tuple(jax.typeof(g).vma)  # the carry's type under shard_map: as g varies
-    if varying:
-        y0 = lax.pcast(y0, varying, to="varying")
-    none = jnp.zeros_like(n_slots)  # typed as the counts are, also under shard_map
+    # The tail: a loop that is as long as the slots ask, which jax cannot turn
+    # round by itself, so it brings its own backward pass — the same chunks
+    # walked again.  (A scan over all n_tail chunks can be turned round, but a
+    # step of it that is skipped still adds a zero cotangent of the three
+    # weight arrays to the scan's carry.)
 
-    def from_chunk(y, c):
-        """Chunk c, which has slots, and behind ONE cond whatever follows it:
-        a step pays for no branch it does not take (a cond a chunk cost 16
-        zero-filled cotangents a layer in the backward pass).  The usual step
-        ends with chunk 0; a layer with up to twice its slots adds chunk 1;
-        beyond that the remaining chunks are scanned, each skipped once the
-        slots run out, and rebuilt in the backward pass."""
-        y, took = chunk(y, c)
-        if c + 1 == n_chunks:
-            return y, took
-        if c == 0:
-            more = lambda y: from_chunk(y, 1)  # noqa: E731
-        else:
-            def more(y):
-                def if_any(y, i):
-                    return lax.cond(i * rows < n_slots, lambda y: chunk(y, i),
-                                    lambda y: (y, none), y)
+    @jax.custom_vjp
+    def tail(y, *read):
+        """y with the tail chunks added, one after the other while slots are
+        left; the slots they took; how many ran."""
+        def chunk(carry):
+            i, y, took = carry
+            add, (tok, n) = rows_of(*read, first_rows + i * tail_rows, tail_rows)
+            return i + 1, _add_rows(y, tok, add), took + n
 
-                y, each = lax.scan(jax.checkpoint(if_any), y, jnp.arange(c + 1, n_chunks))
-                return y, jnp.sum(each)
+        n_slots = read[-2][-1]
+        none = jnp.zeros_like(n_slots)  # typed as the counts are, also under shard_map
+        ran, y, took = lax.while_loop(lambda c: first_rows + c[0] * tail_rows < n_slots,
+                                      chunk, (none, y, none))
+        return y, took, ran
 
-        y, after = lax.cond(n_slots > (c + 1) * rows, more, lambda y: (y, none), y)
-        return y, took + after
+    def tail_forth(y, *read):
+        out = tail(y, *read)
+        return out, (read, out[2])
 
-    y, taken = from_chunk(y0, 0)
+    def tail_back(kept, cotangents):
+        """Each tail chunk that ran, rebuilt and pulled back on its own: y's
+        cotangent reaches every chunk as it is (a chunk only adds to y), and a
+        chunk that did not run adds nothing, not even zeros."""
+        (*inputs, order, ends, sizes), ran = kept
+        dy = cotangents[0]
+
+        def chunk(i, sums):
+            _, pull, (tok, _) = jax.vjp(
+                lambda *a: rows_of(*a, order, ends, sizes, first_rows + i * tail_rows, tail_rows),
+                *inputs, has_aux=True)
+            return jax.tree.map(jnp.add, sums, pull(_take_rows(dy, tok)))
+
+        sums = lax.fori_loop(jnp.zeros_like(ran), ran, chunk,
+                             tuple(jnp.zeros_like(x) for x in inputs))
+        return (dy, *sums, None, None, None)  # the plan is integers
+
+    tail.defvjp(tail_forth, tail_back)
+
+    ends = jnp.cumsum(sizes)
+    n_slots = ends[-1]
+    varying = jax.typeof(g).vma  # under shard_map the walk's arrays vary as g does
+    y, *read = (_varying(x, varying) for x in (
+        jnp.zeros((t, g.shape[1]), jnp.float32), g, weights.reshape(-1), w_gate, w_up, w_down,
+        order, ends, sizes))
+    add, (tok, taken) = rows_of(*read, 0, first_rows)
+    y, walked = _add_rows(y, tok, add), jnp.asarray(first_rows, jnp.int32)
+    if n_tail:
+        # behind ONE cond: the usual step ends with the first chunk and pays
+        # for no branch it does not take (a cond a chunk cost 16 zero-filled
+        # cotangents a layer in the backward pass)
+        none = jnp.zeros_like(n_slots)
+        y, after, ran = lax.cond(n_slots > first_rows, lambda y: tail(y, *read),
+                                 lambda y: (y, none, none), y)
+        taken, walked = taken + after, walked + ran * tail_rows
     stats = jnp.stack([jnp.asarray(every, jnp.int32), n_slots, n_slots - taken,
-                       jnp.max(sizes)])
+                       jnp.max(sizes), walked])
     return y, stats
 
 

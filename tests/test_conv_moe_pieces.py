@@ -217,15 +217,16 @@ def test_8_shares_of_8_add_up_to_the_uncut_layer():
 
 
 def test_no_slot_is_dropped_under_a_skewed_router():
-    """A selection bias that sends every token to the two held experts: eight
-    times the slots the usual chunk holds, none dropped, output = reference."""
+    """A selection bias that sends every token to the two held experts: sixteen
+    times the even load (the first chunk and every tail chunk run), none
+    dropped, output = reference."""
     cfg = cm.tiny_conv_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2)
     lp = _layer(cfg, "moe")
     g = jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))
     lp["router_bias"] = jnp.zeros_like(lp["router_bias"]).at[4:6].set(10.0)
     y, stats = jax.jit(lambda g, lp: cm.expert_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest = (int(v) for v in stats)
-    assert routed == held == 128 and dropped == 0 and fullest == 64
+    routed, held, dropped, fullest, walked = (int(v) for v in stats)
+    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
     np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
 
 

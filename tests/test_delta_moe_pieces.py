@@ -368,16 +368,17 @@ def test_32_shares_of_16_add_up_to_the_uncut_layer():
 
 
 def test_no_slot_is_dropped_under_a_skewed_router():
-    """A router that sends every token to the two held experts: eight times
-    the slots the usual chunk holds, none dropped, output = reference."""
+    """A router that sends every token to the two held experts: sixteen times
+    the even load (the first chunk and every tail chunk run), none dropped,
+    output = reference."""
     cfg = dm.tiny_delta_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2,
                             full_attention_interval=1, n_layers=1)
     lp = _mlp_params(cfg)
     g = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))) + 0.1
     lp["router"] = jnp.zeros_like(lp["router"]).at[:, 4:6].set(1.0)  # positive tokens: 4 and 5 win
     y, stats = jax.jit(lambda g, lp: dm.expert_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest = (int(v) for v in stats)
-    assert routed == held == 128 and dropped == 0 and fullest == 64
+    routed, held, dropped, fullest, walked = (int(v) for v in stats)
+    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
     np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
 
 

@@ -147,12 +147,12 @@ def test_a_mixers_part_hands_the_mlps_part_of_its_layer_a_value():
 
     def mlp(x, lp, handed):
         got.append((float(lp["w"]), float(handed["from"])))
-        return x + handed["from"], jnp.arange(4, dtype=jnp.int32)
+        return x + handed["from"], jnp.arange(5, dtype=jnp.int32)
 
     x, stats = mf.walk(cfg, {"glob": mixer, "win": mixer, "moe": mlp}, ("glob", "win"),
                        params, jnp.zeros(()))
     assert got == [(30.0, 10.0), (31.0, 20.0), (32.0, 21.0)]
-    assert float(x) == 3 + 10 + 20 + 21 and list(stats) == [0, 3, 6, 9]
+    assert float(x) == 3 + 10 + 20 + 21 and list(stats) == [0, 3, 6, 9, 12]
     with pytest.raises(TypeError):  # an MLP's part that is handed nothing it expects
         mf.walk(cfg, {"glob": lambda x, lp: x, "win": mixer, "moe": mlp}, (), params,
                 jnp.zeros(()))
@@ -259,7 +259,8 @@ def test_4_shares_of_2_add_up_to_the_uncut_layer():
 
 def test_no_slot_is_dropped_under_a_skewed_router():
     """A router that sends every token to the two held experts: sixteen times
-    the slots the usual chunk holds, none dropped, output = reference."""
+    the even load (the first chunk and every tail chunk run), none dropped,
+    output = reference."""
     cfg = em.tiny_early_route_moe(n_experts=32, experts_held=2, expert_lo=4)
     lp = _layer(cfg, "moe")
     a = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model)))
@@ -268,8 +269,8 @@ def test_no_slot_is_dropped_under_a_skewed_router():
         cfg, a, a, lp, mf.decide(cfg, a, {"router": router}, functools.partial(em._route, cfg)),
         act=jax.nn.relu))
     y, stats = run(a, lp)
-    routed, held, dropped, fullest = (int(v) for v in stats)
-    assert routed == held == 128 and dropped == 0 and fullest == 64
+    routed, held, dropped, fullest, walked = (int(v) for v in stats)
+    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
     np.testing.assert_allclose(y, ref.experts(cfg, a, ref.route(cfg, a, router), lp), atol=1e-5)
 
 

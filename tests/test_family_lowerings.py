@@ -46,19 +46,24 @@ READERS = {"early_route_moe": "window_moe"}
 #: moved ``delta_moe``); ``window_moe`` and the bfloat16 ones were taken on the
 #: parent of PR 47.  ``early_route_moe``'s were taken when PR 48 wrote the
 #: family; the nine above stood through the seam that PR opened in
-#: ``held_expert_mlp``, ``routed_mlp`` and ``walk``.
+#: ``held_expert_mlp``, ``routed_mlp`` and ``walk``.  PR 49 meant to move the
+#: five MoE families' steps and only those (``held_expert_apply`` walks a
+#: first chunk of 9/8 of the even load and a loop of tail chunks, and counts
+#: the rows it walked as a fifth routing statistic): their ten digests and the
+#: ``moe_experts`` counts below were taken again on its tree; ``bert``'s
+#: digest and all five ``FROZEN_PARAMETERS`` stood.
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
-    ("latent_moe", "float32"): "d65b1bd0f5366d10484dbfafe6611b1aae3b9fda3dbd3b252cda6f8854e865a6",
-    ("delta_moe", "float32"): "2dbb1d030a085c4d84a96d165e67f6fe9d10bd5f8337003bf13938886e6c7da6",
-    ("conv_moe", "float32"): "1fdc10e9c17fe944aeff4c8ad0123ae5c290fa9fa27e5e9fa49ec8731fd0c129",
-    ("window_moe", "float32"): "5acde3888dac2d39d7c44fb91a4207941ae7d0fac21e43cf940676b7229f3398",
-    ("latent_moe", "bfloat16"): "41743827d35059357bc0833fb43b751e5a5399054d9ba96886b4bcdbb32432b5",
-    ("delta_moe", "bfloat16"): "0091f1e476d768ea883bef53d9739fa2b531e18b3597f2efda8cb2a704245352",
-    ("conv_moe", "bfloat16"): "4bd52dc92dd55203afbef1683e339a3364885c1773e46898c9b3dd45b3bb5286",
-    ("window_moe", "bfloat16"): "96a08d44dafff01fd94a06503a4c895ea8f26d6c9dcb5bbb4af8cdead8994aae",
-    ("early_route_moe", "float32"): "a0d24f82d8ae26ae0bd32161aab68b55ea26d9d8abc926d60fb57a0749420678",
-    ("early_route_moe", "bfloat16"): "3ffdfed34d57ce2f082b9a5b433e36d68d72a7b35b350f672a0f1c2e679e4e54",
+    ("latent_moe", "float32"): "aa0eb6b9f2bb1a06bb5a08ca84e3deb7a6dc26f6cdb1e61c65d2786939433568",
+    ("delta_moe", "float32"): "437ff0afc25fd4c9488d929566e981e8a131633cdb50a7a2f647b6a92a95c009",
+    ("conv_moe", "float32"): "95f2090fe5229e127f12ed6b1ea8617c4d6dcfa72e8d8882f5f9bd7ff93a5556",
+    ("window_moe", "float32"): "daf994c570bd0e34feff7e82530c81cd591b7f3ee9278f906a67de0bbdf427d3",
+    ("latent_moe", "bfloat16"): "04047b18e091c0c91cc3879a61f6909b543eb6d164aee2950d067cbe07274a69",
+    ("delta_moe", "bfloat16"): "b407658493f399ca07d5cc5550f69cd57e090804b42b4ff5d8f0c47db8b1820b",
+    ("conv_moe", "bfloat16"): "f49e110bb95eb7f469300f5a008e0271a50926ce4d48d5ac930c2b29d4c66dc3",
+    ("window_moe", "bfloat16"): "6e28ab1f925e5f3e429bfba4c578ac3253130e116416cb4fa10427a923d48bcf",
+    ("early_route_moe", "float32"): "a459dbbc96cd775e1555b0a2aba6b83b8ac111826972fc5eabfb690c37e0afb4",
+    ("early_route_moe", "bfloat16"): "f9c56cdea66713f8086887fc62d6d1f0698cb987a71c68729ebbed125766a931",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -79,16 +84,16 @@ FROZEN_PARAMETERS = {
 #: layer and no shared expert; its ``moe_route`` holds the held experts' plan
 #: (the sort) beside the router, its ``moe_experts`` no sort.
 FROZEN_SCOPE_OPERATIONS = {
-    "latent_moe": {"mtp": 578, "mla_attention": 1788, "moe_route": 154, "moe_experts": 948,
+    "latent_moe": {"mtp": 578, "mla_attention": 1788, "moe_route": 154, "moe_experts": 1002,
                    "moe_shared": 80},
     "delta_moe": {"gdn_scan": 1580, "gdn_proj": 105, "gated_attention": 584, "moe_route": 172,
-                  "moe_experts": 948, "moe_shared": 164},
+                  "moe_experts": 1002, "moe_shared": 164},
     "conv_moe": {"short_conv": 369, "conv_proj": 258, "gqa_attention": 534, "dense_mlp": 105,
-                 "moe_route": 231, "moe_experts": 1401},
+                 "moe_route": 231, "moe_experts": 1488},
     "window_moe": {"window_attention": 1884, "global_attention": 456, "dense_mlp": 165,
-                   "moe_route": 231, "shared_expert": 132, "moe_experts": 1794},
+                   "moe_route": 231, "shared_expert": 132, "moe_experts": 1947},
     "early_route_moe": {"window_attention": 1248, "global_attention": 222, "moe_route": 412,
-                        "moe_experts": 1988},
+                        "moe_experts": 2104},
 }
 
 
@@ -214,14 +219,14 @@ def test_the_walk_runs_the_listed_layers_in_order():
     def part(stack):
         def run(x, lp):
             ran.append((stack, float(lp["w"])))
-            return (x + 1, jnp.arange(4, dtype=jnp.int32)) if stack == "moe" else x + 1
+            return (x + 1, jnp.arange(5, dtype=jnp.int32)) if stack == "moe" else x + 1
         return run
 
     x, stats = mf.walk(cfg, {s: part(s) for s in ("conv", "attn", "dense", "moe")}, ("attn",),
                        params, jnp.zeros(()))
     assert ran == [("conv", 10.0), ("dense", 30.0), ("attn", 20.0), ("dense", 31.0),
                    ("conv", 11.0), ("moe", 40.0)]
-    assert float(x) == 6 and list(stats) == [0, 1, 2, 3]
+    assert float(x) == 6 and list(stats) == [0, 1, 2, 3, 4]
 
 
 def test_the_blocked_loss_is_the_unblocked_one(monkeypatch):

@@ -62,8 +62,8 @@ def test_shares_add_up_to_the_uncut_layer():
 @pytest.mark.parametrize("favoured, n_experts", [((4,), 8), ((4, 5), 8), ((4, 5), 32)])
 def test_no_slot_is_dropped_under_skew(favoured, n_experts):
     """A selection bias that sends every token to the held experts: far more
-    slots than the usual chunk holds (2 chunks of 64 rows at 8 experts, 8 of
-    16 at 32: the second chunk, then the scanned rest), none dropped,
+    slots than the first chunk holds (72 rows of 128 | 256 at 8 experts, 24
+    of 256 at 32: the tail chunks run, 16 | 8 rows each), none dropped,
     output = reference."""
     cfg = lm.tiny_latent_moe(n_experts=n_experts, experts_held=2, expert_lo=4)
     lp = _layer_params(cfg)
@@ -71,11 +71,13 @@ def test_no_slot_is_dropped_under_skew(favoured, n_experts):
     tokens = 64
     g = jax.random.normal(jax.random.PRNGKey(2), (tokens, cfg.d_model))
     y, stats = jax.jit(lambda g, lp: lm.expert_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest = (int(v) for v in stats)
+    routed, held, dropped, fullest, walked = (int(v) for v in stats)
     assert routed == tokens * cfg.top_k
     assert held >= tokens * len(favoured)
-    if len(favoured) == 2:  # every slot is held: every chunk of the usual size runs
-        assert held == routed and routed % (2 * routed * 2 // n_experts) == 0
+    first_rows, tail_rows = moe.held_walk(routed, 2, n_experts)
+    assert held <= walked < held + tail_rows and (walked - first_rows) % tail_rows == 0
+    if len(favoured) == 2:  # every slot is held: every chunk runs, and that is every slot
+        assert held == routed == walked
     assert dropped == 0
     assert fullest == tokens  # a token picks an expert at most once
     np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
@@ -84,6 +86,82 @@ def test_no_slot_is_dropped_under_skew(favoured, n_experts):
     want = jax.grad(lambda lp: jnp.sum(ref.expert_mlp(cfg, g, lp) ** 2))(lp)
     off, leaf = _worst({k: got[k] for k in ("e_gate", "e_down", "router")}, want)
     assert off < 1e-4, f"{leaf}: {off:.2e}"
+
+
+def _plain_held(g, ids, weights, w_gate, w_up, w_down, lo, act):
+    """Every held expert on every token, what a token did not choose masked."""
+    y = jnp.zeros(g.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        out = (act(g @ w_gate[e]) * (g @ w_up[e])) @ w_down[e]
+        y = y + jnp.sum(jnp.where(ids == lo + e, weights, 0.0), axis=1)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("load", [0.5, 1.0, 1.2, 1.5, 2.5, None],
+                         ids=lambda load: f"{load}x" if load else "every_slot")
+@pytest.mark.parametrize("n_held, n_experts, act", [(2, 8, jax.nn.silu), (4, 32, jax.nn.relu)],
+                         ids=["2of8-silu", "4of32-relu"])
+def test_the_walk_follows_the_slots_held(n_held, n_experts, act, load):
+    """``held_expert_apply`` on a plan that holds ``load`` times the even
+    share of the slots (every slot where ``load`` is None), unevenly over the
+    held experts: the plain reference's output and gradients, nothing dropped,
+    and rows walked in proportion — at most max(9/8, L + 1/4) of the even
+    load (and a tile of 8) up to L = 2, at most 2 L of it beyond."""
+    tokens, k, d, f, lo = 256, 4, 16, 24, 3
+    every = tokens * k
+    even = every * n_held // n_experts
+    held = every if load is None else int(load * even)
+    keys = jax.random.split(jax.random.PRNGKey(n_experts + held), 8)
+    # which slots are held, and by whom: expert lo takes half of them
+    local = jnp.maximum(jax.random.randint(keys[0], (every,), -n_held, n_held), 0)
+    elsewhere = (lo + n_held + jax.random.randint(keys[1], (every,), 0, n_experts - n_held)) \
+        % n_experts
+    is_held = jax.random.permutation(keys[2], every) < held
+    ids = jnp.where(is_held, lo + local, elsewhere).astype(jnp.int32).reshape(tokens, k)
+    weights = jax.random.uniform(keys[3], (tokens, k), minval=0.1)
+    g = jax.random.normal(keys[4], (tokens, d))
+    w_gate, w_up = (0.3 * jax.random.normal(key, (n_held, d, f)) for key in keys[5:7])
+    w_down = 0.3 * jax.random.normal(keys[7], (n_held, f, d))
+
+    def run(g, weights, w_gate, w_up, w_down):
+        plan = moe.held_expert_plan(ids, lo, n_held)
+        return moe.held_expert_apply(g, plan, weights, w_gate, w_up, w_down, n_experts, act)
+
+    def loss(fn):  # fn → (y, anything): a scalar of y, its gradients, and fn's own result
+        def scalar(*a):
+            out = fn(*a)
+            return jnp.sum(jnp.sin(out[0])), out
+        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    read = g, weights, w_gate, w_up, w_down
+    (_, (y, stats)), got = loss(run)(*read)
+    (_, (want_y, _)), want = loss(
+        lambda *a: (_plain_held(a[0], ids, *a[1:], lo, act), None))(*read)
+    routed, n, dropped, fullest, walked = (int(v) for v in stats)
+    assert (routed, n, dropped) == (every, held, 0)
+    assert fullest == max(int(jnp.sum(ids == lo + e)) for e in range(n_held))
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    for name, a, b in zip(("g", "weights", "w_gate", "w_up", "w_down"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=5e-5, err_msg=name)
+    share = held / even
+    assert walked >= held
+    assert walked <= (max(9 / 8, share + 1 / 4) * even + 8 if share <= 2 else 2 * share * even)
+
+
+@pytest.mark.parametrize("every, n_held, n_experts, want", [
+    (32768 * 6, 8, 64, (27648, 6144)),  # smallthinker_21b_ep8
+    (16384 * 4, 8, 64, (9216, 2048)),  # lfm2_24b_a2b_ep8
+    (16384 * 8, 8, 128, (9216, 2048)),  # trinity_mini_ep16
+    (16384 * 10, 16, 512, (6144, 1536)),  # qwen3_next_80b_ep32
+    (16384 * 8, 8, 256, (4608, 1024)),  # joyai_llm_flash_ep32
+    (1024, 2, 8, (288, 64)), (1024, 4, 32, (144, 32)),  # the walk's test above, in tiles of 8
+    (64, 8, 8, (64, 16)),  # all experts held: one chunk, every slot
+    (6, 1, 16, (6, 8)),  # fewer slots than a tile
+])
+def test_the_walk_is_sized_from_shapes(every, n_held, n_experts, want):
+    """The first chunk 9/8 of the even load and the tail chunks a quarter of
+    it, in whole tiles: the five cells' shapes, and the edges."""
+    assert moe.held_walk(every, n_held, n_experts) == want
 
 
 def test_bias_picks_and_does_not_weigh():
@@ -113,6 +191,7 @@ def test_routing_counters_reach_the_programs_counters():
     assert grown["moe_slots_routed"] == 2 * layers * tokens.size * cfg.top_k
     assert 0 < grown["moe_slots_held"] < grown["moe_slots_routed"]
     assert grown["moe_slots_dropped"] == 0
+    assert grown["moe_slots_held"] <= grown["moe_rows_walked"] <= grown["moe_slots_routed"]
     assert grown["moe_slots_held"] / 2 <= grown["moe_fullest_expert_slots"] <= grown["moe_slots_held"]
 
 
@@ -124,7 +203,7 @@ def test_routing_counters_fold_only_what_is_ready():
     sink = moe.RoutingCounters.__new__(moe.RoutingCounters)
     sink._lock, sink._pending = threading.Lock(), []
     sink._totals = dict.fromkeys(moe.ROUTING_STATS, 0)
-    sink.push(dict(zip(moe.ROUTING_STATS, jnp.asarray([8, 4, 0, 3], jnp.int32))))
+    sink.push(dict(zip(moe.ROUTING_STATS, jnp.asarray([8, 4, 0, 3, 8], jnp.int32))))
     sink.push({"moe_slots_held": Pending()})  # a step still running: push must not wait for it
     sink.push({})  # a family that counts nothing
     assert sink._totals["moe_slots_held"] == 4 and len(sink._pending) == 1

@@ -126,5 +126,6 @@ def test_pinned_choice_sends_every_token_to_the_same_experts(smoke, rehearsal):
     ids, _ = moe.sigmoid_topk_route(g, lp["router"], lp["router_bias"], mcfg.top_k, mcfg.routed_scale)
     assert {tuple(sorted(row)) for row in np.asarray(ids).tolist()} == {(0, 1, 2, 3, 8, 9, 10, 11)}
     _, stats = lm.expert_mlp(mcfg, g, lp)
-    routed, held, dropped, fullest = (int(v) for v in stats)
-    assert (routed, held, dropped, fullest) == (40 * 8, 40 * 4, 0, 40)  # 16 x a uniform router's share
+    routed, held, dropped, fullest, walked = (int(v) for v in stats)
+    # 16 x a uniform router's share: the first chunk and the tail chunks it takes, to the row
+    assert (routed, held, dropped, fullest, walked) == (40 * 8, 40 * 4, 0, 40, 40 * 4)

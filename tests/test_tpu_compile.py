@@ -73,13 +73,19 @@ def test_flash_kernels_compile_at_the_latent_attention_shape(one_chip, no_compil
     assert text.count("tpu_custom_call") >= 2
 
 
-def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache):
-    """16 384 tokens, top-8 of 256, 8 held experts of width 768 at d 2048:
-    the sort, the grouped products (XLA:TPU's own ragged-dot kernel) and
-    their gradients."""
+@pytest.mark.parametrize("t, d, experts, k, first_rows, temp_gib", [
+    (16384, 2048, 256, 8, 4608, 0.6), (32768, 2560, 64, 6, 27648, 1.5)],
+    ids=["joyai_llm_flash_ep32", "smallthinker_21b_ep8"])
+def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache,
+                                                        t, d, experts, k, first_rows, temp_gib):
+    """Top-k of ``experts``, 8 held experts of width 768, at two cells'
+    shapes — 16 384 tokens at d 2048, top-8 of 256, and the heaviest load the
+    benchmark has, 32 768 tokens at d 2560, top-6 of 64: the sort, the grouped
+    products (XLA:TPU's own ragged-dot kernel) and their gradients, the first
+    chunk and the tail's loop."""
     from byteps_tpu.parallel import moe
 
-    t, d, f, held, experts, k = 16384, 2048, 768, 8, 256, 8
+    f, held = 768, 8
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         dims, dtype, sharding=one_chip)
 
@@ -94,8 +100,10 @@ def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cac
         shape(t, d), shape(d, experts, dtype=jnp.float32), shape(experts, dtype=jnp.float32),
         shape(held, d, f), shape(held, d, f), shape(held, f, d))
     assert "ragged-dot" in compiled.as_text()
-    # one chunk of 8192 rows at a time: far under what all 131 072 slots would take
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    assert moe.held_walk(t * k, held, experts)[0] == first_rows
+    # a chunk of 9/8 of the even load at a time (a tail chunk is a quarter of
+    # it): 0.44 | 1.17 GiB, far under what all t·k slots would take
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gib * 2**30
 
 
 def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile_cache,

@@ -172,4 +172,5 @@ def test_routing_counts_reach_the_programs_counters(tiny):
     slots = t.tokens.size * t.cfg.top_k * expert_layers
     assert grown["moe_slots_routed"] == slots
     assert 0 < grown["moe_slots_held"] < slots and grown["moe_slots_dropped"] == 0
+    assert grown["moe_slots_held"] <= grown["moe_rows_walked"] <= slots  # the chunks that ran
     assert 0 < grown["moe_fullest_expert_slots"] <= grown["moe_slots_held"]
