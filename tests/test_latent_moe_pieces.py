@@ -1,7 +1,9 @@
 """The pieces the latent-attention MoE family brought, each against its own
 reference on the CPU: the held-expert layer (parallel/moe.py), the routing
-counters, and the flash kernel at d_qk != d_v (ops/flash_attention.py,
-Pallas interpreter).  (The model against its reference: tests/test_latent_moe.py.)
+counters, the flash kernel at d_qk != d_v (ops/flash_attention.py, Pallas
+interpreter), and the pass between the mixer's products and that kernel
+(ops/mla_heads.py, Pallas interpreter and XLA's form).  (The model against
+its reference: tests/test_latent_moe.py.)
 """
 
 import importlib
@@ -18,6 +20,7 @@ from byteps_tpu.models import latent_moe as lm
 from byteps_tpu.models import latent_moe_reference as ref
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
+from byteps_tpu.ops import mla_heads as mh
 from byteps_tpu.parallel import moe
 
 from test_latent_moe import _mesh, _state, _worst
@@ -255,3 +258,142 @@ def test_committed_block_table_serves_the_cells_sequence():
     assert fa.tuned_blocks(8192) != (128, 128), "ops/flash_blocks.json lacks the 8192 sweep"
     bq, bk = fa.tuned_blocks(8192)
     assert 8192 % bq == 0 and 8192 % bk == 0
+
+
+# ---------------------------------------------------------------------------
+# from the mixer's products to the flash kernel's operands (ops/mla_heads.py)
+# ---------------------------------------------------------------------------
+
+_NOPE, _ROPE, _THETA = 128, 64, 32e6  # the published head: the kernels take no other
+
+
+def _as_the_parent_wrote_them(q, kv, k_rope):
+    """q (B, H, S, 192), kv (B, H, S, 256) as ``bsr,rhk->bhsk`` gave them and
+    the rotary key (B, S, 64), columns in the weights' own order → the flash
+    kernel's q, k, v by interleaved rope, concatenate and broadcast: what
+    ``latent_moe._attention`` did up to PR 49, rounded once as it was."""
+    rope = lambda x: ref._rope(x.astype(jnp.float32), _THETA).astype(x.dtype)  # noqa: E731
+    b, h, s, _ = q.shape
+    key = jnp.broadcast_to(rope(k_rope[:, None]), (b, h, s, _ROPE))
+    return (jnp.concatenate([q[..., :_NOPE], rope(q[..., _NOPE:])], axis=-1),
+            jnp.concatenate([kv[..., :_NOPE], key], axis=-1), kv[..., _NOPE:])
+
+
+def _through_the_pass(q, kv, k_rope, interpret):
+    """The same three from the same columns, ordered as the mixer's weights
+    order them now: token-major, nope | rope | key | value apart, the rotary
+    columns even-first."""
+    b, h, s, _ = q.shape
+    tokens = lambda x: x.transpose(0, 2, 1, 3).reshape(b, s, -1)  # noqa: E731
+    return mh.mla_heads(tokens(q[..., :_NOPE]), tokens(mh.even_first(q[..., _NOPE:])),
+                        tokens(kv[..., :_NOPE]), tokens(kv[..., _NOPE:]),
+                        mh.even_first(k_rope), h, _THETA, interpret=interpret)
+
+
+def _mixer_arrays(dtype, b=2, h=4, s=64, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    normal = lambda k, *dims: jax.random.normal(k, dims).astype(dtype)  # noqa: E731
+    return ((normal(keys[0], b, h, s, _NOPE + _ROPE), normal(keys[1], b, h, s, 2 * _NOPE),
+             normal(keys[2], b, s, _ROPE)),
+            (normal(keys[3], b, h, s, s), normal(keys[4], b, h, s, _NOPE)))
+
+
+def _seen(operands, weights):
+    """A scalar of what attention sees of (q, k, v): the scores and v."""
+    q, k, v = (x.astype(jnp.float32) for x in operands)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
+    return jnp.sum(scores * weights[0]) + jnp.sum(v * weights[1])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+def test_the_pass_builds_what_rope_and_concatenate_built(monkeypatch, dtype, interpret):
+    """Forward: the parts without positions and v are the parent's bit for
+    bit, the rotary parts the parent's in even-first order.  Backward: every
+    cotangent — the q product's, the kv product's, and the rotary key's with
+    its sum over the heads — is the parent's, taken through the permutation
+    back to the weights' own column order."""
+    monkeypatch.setattr(mh, "BLOCK_ROWS", 32)  # 2 query blocks x 2 batches x 2 pairs
+    assert mh._kernel_path(64, 4, _NOPE, _ROPE, _NOPE, interpret) == interpret
+    arrays, weights = _mixer_arrays(dtype)
+    want = _as_the_parent_wrote_them(*arrays)
+    got = _through_the_pass(*arrays, interpret)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    tol = dict(rtol=0, atol=1e-5) if dtype == jnp.float32 else dict(rtol=2**-7, atol=0)
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == w.shape and g.dtype == dtype
+        np.testing.assert_array_equal(f32(g[..., :_NOPE]), f32(w[..., :_NOPE]), err_msg=name)
+    for name, g, w in zip("qk", got, want):
+        np.testing.assert_allclose(f32(g[..., _NOPE:]), f32(mh.even_first(w[..., _NOPE:])),
+                                   err_msg=f"{name}'s rotary part", **tol)
+    grads = [jax.grad(lambda *a, f=f: _seen(f(*a), weights), argnums=(0, 1, 2))(*arrays)
+             for f in (lambda *a: _through_the_pass(*a, interpret), _as_the_parent_wrote_them)]
+    for name, g, w in zip(("q", "kv", "the rotary key"), *grads):
+        assert g.shape == w.shape and g.dtype == dtype
+        # bf16: one rounding of a sum over 4 heads x 64 keys, in another order
+        worst = np.abs(f32(w)).max()
+        np.testing.assert_allclose(f32(g), f32(w), rtol=0, err_msg=f"d {name}",
+                                   atol=(1e-4 if dtype == jnp.float32 else 2**-6) * worst)
+
+
+def test_the_kernels_are_xlas_form_of_the_pass(monkeypatch):
+    """Operands and every cotangent, bf16: the two implementations round the
+    same f32 equations once, so they agree to the last place (a fused
+    multiply-add here or there), the key's sum over the heads too."""
+    monkeypatch.setattr(mh, "BLOCK_ROWS", 32)
+    arrays, _ = _mixer_arrays(jnp.bfloat16, seed=1)
+    cts = _as_the_parent_wrote_them(*_mixer_arrays(jnp.bfloat16, seed=2)[0])
+    outs = []
+    for interpret in (True, False):
+        out, back = jax.vjp(lambda *a: _through_the_pass(*a, interpret), *arrays)
+        outs.append(out + back(cts))
+    for g, w in zip(*outs):
+        g, w = (np.asarray(x.astype(jnp.float32)) for x in (g, w))
+        np.testing.assert_allclose(g, w, rtol=2**-7, atol=0)
+
+
+def test_a_score_does_not_see_a_common_permutation():
+    """The pass turns the rotary columns even-first, the configuration's rope
+    turns them interleaved: q·k over a head is the same number either way,
+    because q's and k's columns take the same order."""
+    arrays, _ = _mixer_arrays(jnp.float32, seed=3)
+    scores = lambda q, k, v: jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")  # noqa: E731
+    np.testing.assert_allclose(scores(*_through_the_pass(*arrays, False)),
+                               scores(*_as_the_parent_wrote_them(*arrays)), rtol=0, atol=2e-4)
+    # and a permutation that q and k do NOT share is seen
+    q, k, v = _through_the_pass(*arrays, False)
+    assert not np.allclose(scores(q, _as_the_parent_wrote_them(*arrays)[1], v),
+                           scores(q, k, v), atol=1e-1)
+
+
+def test_a_sequence_of_no_whole_block_takes_xlas_form(monkeypatch):
+    """48 rows under blocks of 32: the chooser answers from the shapes, and
+    the result is still the parent's."""
+    monkeypatch.setattr(mh, "BLOCK_ROWS", 32)
+    assert not mh._kernel_path(48, 4, _NOPE, _ROPE, _NOPE, interpret=True)
+    assert not mh._kernel_path(64, 3, _NOPE, _ROPE, _NOPE, interpret=True)  # heads pair up
+    assert not mh._kernel_path(64, 4, 8, 4, 8, interpret=True)  # tiny_latent_moe's head
+    assert not mh._kernel_path(64, 4, _NOPE, _ROPE, _NOPE, interpret=False)  # no TPU here
+    arrays, weights = _mixer_arrays(jnp.float32, s=48, seed=4)
+    got = _through_the_pass(*arrays, interpret=True)
+    for g, w in zip(got, _as_the_parent_wrote_them(*arrays)):
+        np.testing.assert_allclose(g[..., :_NOPE], w[..., :_NOPE], atol=1e-5)
+    np.testing.assert_allclose(_seen(got, weights),
+                               _seen(_as_the_parent_wrote_them(*arrays), weights), rtol=1e-5)
+
+
+def test_merge_heads_is_the_output_projection_and_its_transposes():
+    """``merge_heads`` writes dO with batch and head as the product's batch
+    dimensions; the numbers are ``bhsk,hkd->bsd``'s and its two transposes'."""
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    o = jax.random.normal(keys[0], (2, 4, 16, 8))
+    wo = jax.random.normal(keys[1], (4, 8, 32))
+    ct = jax.random.normal(keys[2], (2, 16, 32))
+    plain = lambda o, wo: jnp.einsum("bhsk,hkd->bsd", o, wo, precision="highest")  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(mh.merge_heads(o, wo), plain(o, wo), atol=1e-5)
+        got = jax.grad(lambda *a: jnp.sum(mh.merge_heads(*a) * ct), argnums=(0, 1))(o, wo)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct), argnums=(0, 1))(o, wo)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=1e-4)

@@ -435,6 +435,72 @@ def test_attention_mixer_moves_each_tensor_once_at_published_widths(one_chip, no
     assert _named_bytes(text) < ceiling * 2**30 < parent * 2**30
 
 
+# module → (GiB the parent's module names outside its kernels, GiB this one may)
+_LATENT_MIXER_BYTES = {"forward": (4.05, 2.7), "gradient": (9.99, 7.5)}
+
+
+def test_latent_attention_mixer_moves_each_tensor_once_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch):
+    """``latent_moe._attention`` for 2 x 8192 tokens at JoyAI-LLM-Flash's
+    widths (2048 → 1536 | 512 + 64 → 32 heads of 128 + 64 | 128), the forward
+    alone (what remat runs a second time) and the gradient (no
+    recomputation): the flash kernels take q, k ``bf16[64,8192,192]`` and v
+    ``bf16[64,8192,128]`` — what benchmark/readers/latent_moe.py parses —;
+    from the four token-major products to those operands and back is one
+    kernel each way (``mla_heads_fwd`` | ``mla_heads_bwd``); under
+    ``mla_attention`` nothing copies, transposes, broadcasts, concatenates or
+    slices 64 MB or more (dO too is written head-major by its product:
+    ``ops/mla_heads.merge_heads``) and no f32 array of 256 MB is written,
+    other than the forward kernel's logsumexp on 128 lanes and XLA's copy of
+    it to take lane 0 (ROADMAP S6f).  The bytes the top-level operations
+    outside the kernels name (``_named_bytes``): the parent's module of the
+    same case **4.05 GiB** forward | **9.99** gradient (both products written
+    sequence-minor and five whole-tensor copies between layouts, q and k
+    concatenated from halves, the rotary key broadcast to 32 heads, kv and the
+    cotangents sliced, the rotary columns in f32 on a last dimension of 2),
+    this one **1.28 | 5.30 GiB** (0.75 of the gradient's is the logsumexp's
+    copy and squeeze), held under 2.7 | 7.5.  (ISSUE 50 counted the parent's
+    module on a copy of its own at 4.36 | 10.86.)"""
+    from byteps_tpu.models import latent_moe as lm
+    from byteps_tpu.ops import mla_heads as mh
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(mh, "_platform", lambda: "tpu")
+    cfg = lm.LatentMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    b, s, scope = 2, cfg.max_seq, "mla_attention"
+    assert (s, cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim) == (
+        8192, 32, 128, 64, 128)
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in lm._attention_shapes(cfg).items()}
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(lm._attention(cfg, x, lp).astype(jnp.float32) ** 2)
+
+    for module, fn in (("forward", loss), ("gradient", jax.grad(loss, argnums=(0, 1)))):
+        text = _compile(fn, x, lp).as_text()
+        ops = _top_level(text)
+        calls = lambda kernel: [o[0] for o in ops if o[4] and o[0].startswith(kernel)]  # noqa: E731
+        backward = module == "gradient"
+        wanted = {fa.FWD_KERNEL: 1, mh.FWD_KERNEL: 1, fa.BWD_KERNEL: backward,
+                  mh.BWD_KERNEL: backward}
+        assert {k: len(calls(k)) for k in wanted} == wanted
+        for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL)[:1 + backward]:
+            assert _kernel_operands(text, kernel)[:3] == [
+                "bf16[64,8192,192]", "bf16[64,8192,192]", "bf16[64,8192,128]"]
+        lse = set(calls(fa.FWD_KERNEL))
+        for name, opcode, result, _, kernel, op_name in ops:
+            size = _bytes_of(result)
+            moved = size >= 64 * 2**20 and (
+                opcode in ("broadcast", "copy", "transpose", "concatenate", "slice")
+                or re.search(r"/(concatenate|slice|split|broadcast_in_dim)$", op_name))
+            wide = "f32[" in result and size >= 256 * 2**20
+            if not kernel and scope in op_name and (moved or wide):
+                assert lse & set(_sources(ops, name)), f"{name}: {opcode} of {result} under {scope}"
+        parent, ceiling = _LATENT_MIXER_BYTES[module]
+        assert _named_bytes(text) < ceiling * 2**30 < parent * 2**30
+
+
 def _sources(ops: list, name: str) -> list:
     """``name``'s operands, through ``get-tuple-element``s and ``bitcast``s."""
     by_name = {o[0]: o for o in ops}

@@ -40,6 +40,16 @@ the kernels took grouped heads).  The sizing of Trinity's mixers, 32 | 4:
 
     python tools/flash_tune.py --seqs 16384 --bh 1,32 --kv-heads 4 --dh 128 \
         --window 2048 --blocks 1024 --no-dense --check --causal-too --no-write
+
+``--mla-heads`` times no flash kernel but the pass that stands before and
+after them in the latent-attention mixer (``ops/mla_heads.py``: the four
+token-major products → q | k | v head-major, and its transpose), forward alone
+and forward + backward, at ``--blocks`` rows a block, beside XLA's form of the
+same equations, each held to the other first; times are the device's busy time
+in a profile of the calls, and GB/s the bytes the pass must move (every
+operand and result once) over them.  JoyAI-LLM-Flash's mixer:
+
+    python tools/flash_tune.py --mla-heads --seqs 8192 --bh 2,32 --blocks 256,512,1024
 """
 
 import argparse
@@ -50,6 +60,79 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import numpy as np
+
+
+def time_mla_heads(args) -> int:
+    """``--mla-heads``: the pass of ops/mla_heads.py, kernels beside XLA's form."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.ops import mla_heads as mh
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark"))
+    import xplane  # the benchmark's reduction from a profile to (name, start, end)
+
+    b, h = (int(x) for x in args.bh.split(","))
+    n, r, d_v, theta = 128, 64, 128, 32e6
+    rng = np.random.default_rng(0)
+    make = lambda *dims: jnp.asarray(rng.normal(size=dims).astype(np.float32), jnp.bfloat16)  # noqa: E731
+
+    def timed(fn, *xs):
+        """Device-busy ms a call, from a profile of the calls back to back.
+        Alone the pass reads 3.2 ms each way on the device (a wall clock
+        agrees); inside the cell's step a trace shows the same kernels at
+        1.7–1.9 ms (PERF.md §6 PR 50, §7): size the pass from a traced step."""
+        f = jax.jit(fn)
+        jax.block_until_ready(f(*xs))
+        with tempfile.TemporaryDirectory() as log_dir:
+            with jax.profiler.trace(log_dir):
+                for _ in range(args.steps):
+                    out = f(*xs)
+                jax.block_until_ready(out)
+            ops = xplane.load(log_dir)["devices"][0]["ops"]
+        busy = xplane.union(ops, min(a for _, a, _ in ops), max(b for _, _, b in ops))
+        return sum(b - a for a, b in busy) / args.steps * 1e3
+
+    for s in (int(x) for x in args.seqs.split(",")):
+        xs = (make(b, s, h * n), make(b, s, h * r), make(b, s, h * n), make(b, s, h * d_v),
+              make(b, s, r))
+        cts = (make(b, h, s, n + r), make(b, h, s, n + r), make(b, h, s, d_v))
+        moved = 2 * sum(x.size for x in xs + cts)  # bytes a pass reads and writes, bf16
+
+        def both(forward):
+            """``forward`` and its transpose on ``cts``, every array an argument."""
+            def run(cts, *xs):
+                out, back = jax.vjp(forward, *xs)
+                return out, back(cts)
+            return run
+
+        xla = lambda *xs: mh._forward(*xs, h, mh.rope_tables(s, r, theta))  # noqa: E731
+        want = jax.jit(both(xla))(cts, *xs)
+        if not args.rehearse:
+            print(f"seq {s} XLA's form: forward {timed(xla, *xs):7.3f} ms, with its transpose "
+                  f"{timed(both(xla), cts, *xs):7.3f} ms")
+        for rows in (int(x) for x in args.blocks.split(",")):
+            if s % rows:
+                continue
+            mh.BLOCK_ROWS = rows
+            kernels = lambda *xs: mh.mla_heads(*xs, h, theta, interpret=args.rehearse)  # noqa: E731
+            got = jax.jit(both(kernels))(cts, *xs)
+            off = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) - w.astype(jnp.float32))))
+                      for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+            # the kernels add the shared key's cotangent over the heads in
+            # f32 and round once; XLA's form is the same sum in another order
+            print(f"seq {s} rows {rows}: kernels off XLA's form by {off:.3g} at most")
+            if not off < 0.5:
+                raise SystemExit("the kernels disagree with XLA's form")
+            if args.rehearse:
+                continue
+            fwd_ms, ms = timed(kernels, *xs), timed(both(kernels), cts, *xs)
+            print(f"seq {s} rows {rows}: forward {fwd_ms:7.3f} ms = {moved / fwd_ms / 1e6:6.1f} "
+                  f"GB/s, with its transpose {ms:7.3f} ms = {2 * moved / ms / 1e6:6.1f} GB/s "
+                  f"({moved / 1e9:.3f} GB a pass)")
+    return 0
 
 
 def main() -> int:
@@ -78,6 +161,8 @@ def main() -> int:
                     help="with --window: also time the full causal kernels at the plain entry")
     ap.add_argument("--no-write", action="store_true",
                     help="don't persist winners to ops/flash_blocks.json")
+    ap.add_argument("--mla-heads", action="store_true",
+                    help="time ops/mla_heads.py's pass (heads of 128 | 64 | 128) and nothing else")
     args = ap.parse_args()
 
     import jax
@@ -88,6 +173,8 @@ def main() -> int:
         return 2
     if args.rehearse:
         args.no_write = True
+    if args.mla_heads:
+        return time_mla_heads(args)
 
     from byteps_tpu.ops.flash_attention import flash_attention, _dense_reference
 
