@@ -1,6 +1,6 @@
 """What the mixture-of-experts families behind ``build_train_step`` share —
-``latent_moe``, ``delta_moe``, ``conv_moe``, ``window_moe`` — each decision
-written once.
+``latent_moe``, ``delta_moe``, ``conv_moe``, ``window_moe``,
+``early_route_moe``, ``ssm_moe`` — each decision written once.
 
 A device holds the experts ``[expert_lo, expert_lo + experts_held)`` of every
 expert layer and the first ``vocab_size`` rows of the vocabulary: its share of
@@ -99,7 +99,9 @@ class Family:
 class PatternedFamily(Family):
     """A family whose layers are static data: ``layer_types[i]`` names layer
     i's mixer, and the first ``n_dense_layers`` layers' MLP is dense, the
-    others' routed (stacks ``dense``, ``moe``)."""
+    others' routed (stacks ``dense``, ``moe``).  A family whose layer is ONE
+    part — a mixer or an MLP alone — lists every part's type in ``mixers``
+    and gives :meth:`kinds` itself, one stack a layer (``ssm_moe``)."""
 
     #: ``layer_types`` entry → the stack that holds that mixer's parameters
     mixers = {}
@@ -119,8 +121,8 @@ class PatternedFamily(Family):
     def n_layers(self) -> int:
         return len(self.layer_types)
 
-    def kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """Layer by layer, the stacks (mixer's, MLP's) it reads."""
+    def kinds(self) -> Tuple[Tuple[str, ...], ...]:
+        """Layer by layer, the stacks it reads, in order: (mixer's, MLP's)."""
         return tuple((self.mixers[t], "dense" if i < self.n_dense_layers else "moe")
                      for i, t in enumerate(self.layer_types))
 
@@ -175,6 +177,17 @@ def fan_in(*dims: int):
     """N(0, 1 / fan-in), the fan-in the product of the dims a product with
     this leaf contracts."""
     return lambda key, shape: normal(math.prod(shape[d] for d in dims) ** -0.5)(key, shape)
+
+
+def uniform(bound: float):
+    """U(−bound, bound)."""
+    return lambda key, shape: jax.random.uniform(key(), shape, jnp.float32, -bound, bound)
+
+
+def linear(*dims: int):
+    """``torch.nn.Linear``'s own start, U(−1/√fan-in, 1/√fan-in) (variance
+    1 / (3 fan-in)), the fan-in as :func:`fan_in`'s."""
+    return lambda key, shape: uniform(math.prod(shape[d] for d in dims) ** -0.5)(key, shape)
 
 
 def log_uniform(lo: float, hi: float):
@@ -290,7 +303,10 @@ def routed_mlp(cfg, g32, g, lp, route: Union[Callable, Decision],
     plus, under ``shared_scope`` where
     the family has one, the shared expert that every token takes — behind
     ``sigmoid(g · shared_gate)`` where the layer has that leaf, at weight 1
-    where not.  ``route(g32, lp)`` → (ids, weights) is the family's router, or
+    where not.  Both kinds of expert are gated MLPs ``(act(g W_gate) ⊙ g W_up)
+    W_down`` where the layer has the gate's matrix (``e_gate``, ``s_gate``),
+    and ``act(g W_up) W_down`` where it has none.
+    ``route(g32, lp)`` → (ids, weights) is the family's router, or
     ``route`` is the :class:`Decision` made earlier (:func:`decide`).
     ``g`` is what the experts read: the tokens in the compute dtype where the
     family has them, or ``g32`` again — then they are cast where each expert
@@ -303,16 +319,21 @@ def routed_mlp(cfg, g32, g, lp, route: Union[Callable, Decision],
             ids, weights = route(g32, lp)
     with jax.named_scope("moe_experts"):
         # cast before the plan: the order the four older families' steps lower in
-        read = g.astype(cdt), *(lp[w].astype(cdt) for w in ("e_gate", "e_up", "e_down"))
+        tokens = g.astype(cdt)
+        e_gate = lp["e_gate"].astype(cdt) if "e_gate" in lp else None
+        e_up, e_down = lp["e_up"].astype(cdt), lp["e_down"].astype(cdt)
         plan, weights = route if made else (
             held_expert_plan(ids, cfg.expert_lo, cfg.experts_held), weights)
-        y, stats = held_expert_apply(read[0], plan, weights, *read[1:],
+        y, stats = held_expert_apply(tokens, plan, weights, e_gate, e_up, e_down,
                                      n_experts=cfg.n_experts, act=act)
     if shared_scope is None:
         return y, stats
     with jax.named_scope(shared_scope):
         g = g.astype(cdt)
-        shared = swiglu(g, *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
+        if "s_gate" in lp:
+            shared = swiglu(g, *(lp[w].astype(cdt) for w in ("s_gate", "s_up", "s_down")))
+        else:
+            shared = act(g @ lp["s_up"].astype(cdt)) @ lp["s_down"].astype(cdt)
         open_ = None
         if "shared_gate" in lp:
             open_ = jax.nn.sigmoid(jnp.dot(g, lp["shared_gate"].astype(cdt),
@@ -340,10 +361,11 @@ class Handed(NamedTuple):
 
 def walk(cfg, run: Dict[str, Callable], flash: Tuple[str, ...], params, x):
     """The layers of a :class:`PatternedFamily`, unrolled: layer by layer of
-    ``cfg.kinds()``, the mixer's part and the MLP's, each on the next entry of
-    its stack.  ``run[stack](x, lp)`` → x, or (x, routing stats) from a part
-    that routes; each is rebuilt in the backward pass on its own, one at a
-    time (the stacks of ``flash`` keeping their kernel's output).  A mixer's
+    ``cfg.kinds()``, the mixer's part and the MLP's (or the one part of a
+    layer that is either alone), each on the next entry of its stack.
+    ``run[stack](x, lp)`` → x, or (x, routing stats) from a part that routes;
+    each is rebuilt in the backward pass on its own, one at a time (the
+    stacks of ``flash`` keeping their kernel's output).  A mixer's
     part may return :class:`Handed`: its value goes to the MLP's part of the
     same layer as a third argument — an output of the one rebuilt part and an
     input of the other, so it is kept, and what only it needs (a sort, say) is

@@ -249,11 +249,12 @@ def held_walk(every: int, n_held: int, n_experts: int) -> tuple:
 
 
 def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
-                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                      w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
                       n_experts: int, act: Callable) -> tuple:
     """The routed experts' part of a layer's output that THIS device's
     experts give: ``Σ_{i chosen and held} w_i E_i(g)``, every ``E`` a
-    bias-free gated MLP ``(act(g W_gate) ⊙ g W_up) W_down``.
+    bias-free gated MLP ``(act(g W_gate) ⊙ g W_up) W_down`` — or, where the
+    experts have no gate matrix (``w_gate`` None), ``act(g W_up) W_down``.
 
     g:        (T, D) tokens, compute dtype
     plan:     :func:`held_expert_plan` of the chosen ids (T, k)
@@ -261,7 +262,8 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
               of the model
     w_gate, w_up: (n_held, D, F), w_down: (n_held, F, D) — the experts
               ``[lo, lo + n_held)``, which this device holds
-    act:      the gate's activation (``jax.nn.silu``: SwiGLU)
+    act:      the gate's activation (``jax.nn.silu``: SwiGLU), the hidden
+              units' own where there is no gate
 
     The slots (token, choice) whose expert is held come ordered by expert
     and are multiplied in grouped products (``lax.ragged_dot``: each
@@ -280,11 +282,13 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
     t, k = weights.shape
     order, sizes = plan
     every = t * k
-    first_rows, tail_rows = held_walk(every, w_gate.shape[0], n_experts)
+    # the experts' matrices as the products take them: (gate,) up, down
+    ws = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    first_rows, tail_rows = held_walk(every, w_up.shape[0], n_experts)
     n_tail = -(-(every - first_rows) // tail_rows)
     order = jnp.pad(order, (0, first_rows + n_tail * tail_rows - every))
 
-    def rows_of(g, flat_w, w_gate, w_up, w_down, order, ends, sizes, first, rows):
+    def rows_of(g, flat_w, ws, order, ends, sizes, first, rows):
         """The slots [first, first + rows) of the ordered list: what they add
         to y (rows, D) f32, and beside it the tokens they add it to and how
         many slots the grouped products took."""
@@ -302,7 +306,11 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         def grouped(lhs, rhs):
             return jnp.where(live, lax.ragged_dot(lhs, rhs, mine), 0)
 
-        out = grouped(act(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
+        *w_in, w_down = ws  # (gate,) up
+        hidden = act(grouped(xs, w_in[0]))
+        if len(w_in) == 2:
+            hidden = hidden * grouped(xs, w_in[1])
+        out = grouped(hidden, w_down)
         return out.astype(jnp.float32) * flat_w[slot][:, None], (tok, jnp.sum(mine))
 
     # The tail: a loop that is as long as the slots ask, which jax cannot turn
@@ -344,7 +352,7 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
             return jax.tree.map(jnp.add, sums, pull(_take_rows(dy, tok)))
 
         sums = lax.fori_loop(jnp.zeros_like(ran), ran, chunk,
-                             tuple(jnp.zeros_like(x) for x in inputs))
+                             jax.tree.map(jnp.zeros_like, tuple(inputs)))
         return (dy, *sums, None, None, None)  # the plan is integers
 
     tail.defvjp(tail_forth, tail_back)
@@ -352,8 +360,8 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
     ends = jnp.cumsum(sizes)
     n_slots = ends[-1]
     varying = jax.typeof(g).vma  # under shard_map the walk's arrays vary as g does
-    y, *read = (_varying(x, varying) for x in (
-        jnp.zeros((t, g.shape[1]), jnp.float32), g, weights.reshape(-1), w_gate, w_up, w_down,
+    y, *read = jax.tree.map(lambda x: _varying(x, varying), (
+        jnp.zeros((t, g.shape[1]), jnp.float32), g, weights.reshape(-1), ws,
         order, ends, sizes))
     add, (tok, taken) = rows_of(*read, 0, first_rows)
     y, walked = _add_rows(y, tok, add), jnp.asarray(first_rows, jnp.int32)

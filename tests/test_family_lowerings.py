@@ -27,14 +27,15 @@ import numpy as np
 import optax
 import pytest
 
-from byteps_tpu.models import conv_moe, delta_moe, early_route_moe, latent_moe, window_moe
+from byteps_tpu.models import (conv_moe, delta_moe, early_route_moe, latent_moe, ssm_moe,
+                               window_moe)
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
-            "window_moe": window_moe, "early_route_moe": early_route_moe}
+            "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe}
 #: family → the reader of benchmark/readers/ its cell's metrics go through,
 #: where that is not one of its own name
 READERS = {"early_route_moe": "window_moe"}
@@ -58,7 +59,10 @@ READERS = {"early_route_moe": "window_moe"}
 #: projection): its two digests and its ``mla_attention`` count below were
 #: taken again on its tree; the nine other digests and all five
 #: ``FROZEN_PARAMETERS`` stood (the column order is applied in the step, not
-#: to a leaf).
+#: to a leaf).  ``ssm_moe``'s were taken when PR 51 wrote the family; the eleven
+#: above stood through the seam that PR opened in ``held_expert_apply`` and
+#: ``routed_mlp`` (an absent gate matrix is data: ``w_gate`` None, no
+#: ``e_gate`` | ``s_gate`` leaf).
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     ("latent_moe", "float32"): "2f39501233c5fb12999aad5f6244e64071b14dda6ce394942f3ffbfe3e4ad237",
@@ -71,6 +75,8 @@ FROZEN_LOWERINGS = {
     ("window_moe", "bfloat16"): "6e28ab1f925e5f3e429bfba4c578ac3253130e116416cb4fa10427a923d48bcf",
     ("early_route_moe", "float32"): "a459dbbc96cd775e1555b0a2aba6b83b8ac111826972fc5eabfb690c37e0afb4",
     ("early_route_moe", "bfloat16"): "f9c56cdea66713f8086887fc62d6d1f0698cb987a71c68729ebbed125766a931",
+    ("ssm_moe", "float32"): "fa3d5e3595281c57e3470e38db5e4f4e4152b822b4c64dfa062576af6a8f5d2d",
+    ("ssm_moe", "bfloat16"): "e85d034554c3199ee625d014e34a03b3c224e9dc1907aaadfb2662a840eb1cb0",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -81,6 +87,7 @@ FROZEN_PARAMETERS = {
     "conv_moe": "f4fc219077bd48be16757941eb58ce12bd017c8d91e938d818e638eccd7d39b4",
     "window_moe": "3d45bbea9a827d634d53bc91e00efbd1c80f4f58fcc242b9d196fc2d1b06a46e",
     "early_route_moe": "4185b5dc3604eb74127f4bad08f21f9bc5d63374e23957bc4be2b2751ced5698",
+    "ssm_moe": "f490c4e980eef04431d28a3f03db0f49592603f5f02f9153b547ad90a9220f01",
 }
 
 #: family → scope → operations of the bfloat16 step filed under it.  The scopes
@@ -89,7 +96,9 @@ FROZEN_PARAMETERS = {
 #: has as a segment, which is the readers' rule.  ``early_route_moe`` goes
 #: through ``window_moe``'s reader and has four of its six scopes: no dense
 #: layer and no shared expert; its ``moe_route`` holds the held experts' plan
-#: (the sort) beside the router, its ``moe_experts`` no sort.
+#: (the sort) beside the router, its ``moe_experts`` no sort.  ``ssm_moe``'s
+#: layers are one part each: two Mamba-2 mixers (``ssd_scan`` between the two
+#: ``ssm_proj``), one attention layer, two expert layers.
 FROZEN_SCOPE_OPERATIONS = {
     "latent_moe": {"mtp": 578, "mla_attention": 1938, "moe_route": 154, "moe_experts": 1002,
                    "moe_shared": 80},
@@ -101,6 +110,8 @@ FROZEN_SCOPE_OPERATIONS = {
                    "moe_route": 231, "shared_expert": 132, "moe_experts": 1947},
     "early_route_moe": {"window_attention": 1248, "global_attention": 222, "moe_route": 412,
                         "moe_experts": 2104},
+    "ssm_moe": {"ssd_scan": 960, "ssm_proj": 174, "nope16_attention": 221, "moe_route": 152,
+                "shared_expert": 64, "moe_experts": 936},
 }
 
 

@@ -204,6 +204,80 @@ def test_flash_kernels_compile_at_the_early_routed_shape(one_chip, no_compile_ca
     assert not re.search(r"bf16\[2,4,7,16384,128\]\S* broadcast\(", text)
 
 
+def test_flash_kernels_compile_at_sixteen_query_heads_a_key_value_head(one_chip, no_compile_cache,
+                                                                       monkeypatch):
+    """(2, 32 | 2, 8192, 128 | 128) — the state-space family's attention
+    layer: sixteen query heads a key/value head (the kernels find head
+    ``h // 16`` by index map; K and V are their operands at 2 x 2 heads), the
+    full causal pair with the blocks ops/flash_blocks.json commits for 8192,
+    no positions."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 2, 8192, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=128 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k).as_text()
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+        q_, k_, v_ = _kernel_operands(text, kernel)[:3]
+        assert (q_, k_, v_) == ("bf16[64,8192,128]",) + ("bf16[4,8192,128]",) * 2
+    assert not re.search(r"bf16\[2,2,16,8192,128\]\S* broadcast\(", text)
+
+
+def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
+    """2 x 8192 tokens, 64 heads of 64 with a 64 x 128 state, B and C in 8
+    groups of 128, chunks of 128, bf16 operands: a Mamba-2 layer whole —
+    ``in_proj``, the biased convolution, the chunked scan (a block of chunks
+    rebuilt at a time), ``D x``, the gated grouped norm, ``out_proj`` — and its
+    gradients.  What stands at a time stays a few copies of the (16 384,
+    10 304) projection (338 MB in bf16) and ONE block's decay matrices, not a
+    layer's (537 MB in f32, and their products beside them)."""
+    from byteps_tpu.models import ssm_moe as sm
+
+    cfg = sm.SsmMoEConfig(compute_dtype=jnp.bfloat16)
+    assert (cfg.d_model, cfg.d_inner, cfg.conv_channels, cfg.chunk) == (2688, 4096, 6144, 128)
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    lp = {k: shape(*s) for k, s in sm.stacks(cfg)["ssm"][1].items()}
+
+    def loss(x, lp):
+        return jnp.sum(sm._ssm_layer(cfg, x, lp).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)),
+                        shape(2, 8192, 2688, dtype=jnp.bfloat16), lp)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+def test_ungated_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache):
+    """16 384 tokens at d 2688, top-6 of 128, 8 held UNGATED experts 1856
+    wide (two matrices each, relu squared between them): the sort, the grouped
+    products and their gradients, the first chunk and the tail's loop."""
+    from byteps_tpu.models.ssm_moe import relu2
+    from byteps_tpu.parallel import moe
+
+    t, d, f, held, experts, k = 16384, 2688, 1856, 8, 128, 6
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def loss(g, router, bias, w_up, w_down):
+        ids, weights = moe.sigmoid_topk_route(g, router, bias, k, 2.5)
+        plan = moe.held_expert_plan(ids, 0, held)
+        y, stats = moe.held_expert_apply(g, plan, weights, None, w_up, w_down, experts, relu2)
+        return jnp.sum(y), stats
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 3, 4), has_aux=True),
+        shape(t, d), shape(d, experts, dtype=jnp.float32), shape(experts, dtype=jnp.float32),
+        shape(held, d, f), shape(held, f, d))
+    assert compiled.as_text().count("ragged-dot") >= 2
+    assert moe.held_walk(t * k, held, experts)[0] == 7168  # 9/8 of 6144, in tiles of 512
+    # a chunk of 7168 rows at a time: 0.91 GiB, far under what all 98 304 slots would take
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 2**30
+
+
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
     """2 x 8192 tokens, 2048 channels, 3 taps, bf16 operands: the double-gated
     short convolution between its two projections and its four gradients.

@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
-"""Where the limits on the five MoE families' precision come from.
+"""Where the limits on the six MoE families' precision come from.
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
     python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
     python tools/latent_moe_precision.py --config lfm2_24b_a2b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config trinity_mini_ep16 --seeds ...
     python tools/latent_moe_precision.py --config smallthinker_21b_ep8 --seeds ...
+    python tools/latent_moe_precision.py --config nemotron_twotower_30b_ep16 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -19,8 +20,9 @@ it), on the TPU:
             bf16 operands, f32 norm statistics, router and softmax — a model
             of the program, to show that the control reads like it
   below     the same with the norms' statistics, the router's scores and
-            weights and the softmax in bf16: the nearest precision below,
-            which ``correct`` has to refuse
+            weights and the softmax (and, where the configuration has a
+            state-space scan, its step sizes, decay sums and states) in bf16:
+            the nearest precision below, which ``correct`` has to refuse
 
 and against f32, as ``benchmark/run.py`` and leg E read them: the largest
 relative distance of the three losses (``reference_rtol``); the parameters
