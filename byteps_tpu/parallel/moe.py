@@ -248,6 +248,26 @@ def held_walk(every: int, n_held: int, n_experts: int) -> tuple:
     return first, _round_up(-(-every * n_held // (4 * n_experts)), unit)
 
 
+#: a width under this is handed to the grouped products as it is
+HELD_TILES_FLOOR = 1024
+
+
+def held_tiles(n: int) -> int:
+    """The width :func:`held_expert_apply`'s grouped products see for a model
+    or expert width ``n``, from the number alone.  XLA:TPU's ragged-dot kernel
+    tiles the contraction and the output width each by the largest of 512 |
+    256 | 128 that divides it, and on 128-tiles it pays its per-step cost, not
+    the MXU's (2688 × 1856: ``512,128,128``, a tenth of the peak; PERF.md §6
+    PR 53).  So a large width that is no multiple of 256 is padded with zeros
+    up to whole tiles of 512 (26 % more multiply-adds at 3072 × 2048 cost less
+    than the 256-tiles of 2816 × 2048: the same probe); a multiple of 256 is
+    served well as it is, and the widths under the floor — every CPU test's —
+    lower as they always did."""
+    if n < HELD_TILES_FLOOR or n % 256 == 0:
+        return n
+    return _round_up(n, 512)
+
+
 def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
                       w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
                       n_experts: int, act: Callable) -> tuple:
@@ -284,6 +304,13 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
     every = t * k
     # the experts' matrices as the products take them: (gate,) up, down
     ws = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    d, f = g.shape[1], w_up.shape[2]
+    d_wide, f_wide = held_tiles(d), held_tiles(f)  # what the products see
+    if (d_wide, f_wide) != (d, f):
+        def widen(w, rows, cols):
+            return jnp.pad(w, ((0, 0), (0, rows - w.shape[1]), (0, cols - w.shape[2])))
+
+        ws = (*(widen(w, d_wide, f_wide) for w in ws[:-1]), widen(w_down, f_wide, d_wide))
     first_rows, tail_rows = held_walk(every, w_up.shape[0], n_experts)
     n_tail = -(-(every - first_rows) // tail_rows)
     order = jnp.pad(order, (0, first_rows + n_tail * tail_rows - every))
@@ -302,6 +329,8 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         # product's operand and result is cleared there by selection
         live = (first + jnp.arange(rows) < ends[-1])[:, None]
         xs = jnp.where(live, _take_rows(g, tok), 0)  # (rows, D)
+        if d_wide != d:
+            xs = jnp.pad(xs, ((0, 0), (0, d_wide - d)))
 
         def grouped(lhs, rhs):
             return jnp.where(live, lax.ragged_dot(lhs, rhs, mine), 0)
@@ -311,6 +340,8 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         if len(w_in) == 2:
             hidden = hidden * grouped(xs, w_in[1])
         out = grouped(hidden, w_down)
+        if d_wide != d:
+            out = out[:, :d]
         return out.astype(jnp.float32) * flat_w[slot][:, None], (tok, jnp.sum(mine))
 
     # The tail: a loop that is as long as the slots ask, which jax cannot turn

@@ -290,6 +290,75 @@ def test_the_ungated_walk_reaches_its_tail_chunks():
     assert not np.any(grads[1][2])  # experts 4, 5 are held and never chosen
 
 
+# the six MoE configurations' model and expert widths (benchmark/configs/*.json)
+_PUBLISHED_WIDTHS = {
+    "joyai_d": (2048, 2048), "joyai_f": (768, 768), "qwen3_next_d": (2048, 2048),
+    "qwen3_next_f": (512, 512), "lfm2_d": (2048, 2048), "lfm2_f": (1536, 1536),
+    "trinity_d": (2048, 2048), "trinity_f": (1024, 1024), "smallthinker_d": (2560, 2560),
+    "smallthinker_f": (768, 768), "nemotron_d": (2688, 3072), "nemotron_f": (1856, 2048)}
+# what the CPU tests and the rehearsals' toy cuts use, and the floor's two sides
+_SMALL_WIDTHS = {f"small_{n}": (n, n) for n in (6, 8, 16, 48, 64, 128, 200, 1000, 1023)}
+
+
+@pytest.mark.parametrize("n, want", [
+    *_PUBLISHED_WIDTHS.values(), *_SMALL_WIDTHS.values(), (1025, 1536), (1152, 1536),
+    (1280, 1280), (3712, 4096)],
+    ids=[*_PUBLISHED_WIDTHS, *_SMALL_WIDTHS, "over_the_floor", "nine_lane_tiles",
+         "a_multiple_of_256", "twenty_nine_lane_tiles"])
+def test_held_tiles_is_a_rule_of_the_width_alone(n, want):
+    """A multiple of 256 and every width under the floor go to the grouped
+    products as they are; a large width that XLA:TPU would tile by 128 is
+    rounded up to whole tiles of 512.  Of the twelve published widths only
+    nemotron's two move."""
+    assert moe.held_tiles(n) == want
+    assert moe.held_tiles(want) == want  # what the rule gives it leaves alone
+    assert 512 <= moe.HELD_TILES_FLOOR <= 1024
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["two_matrices_relu2", "three_matrices_silu"])
+def test_padded_widths_add_exact_zeros(gated, monkeypatch):
+    """With the rule's floor lowered the toy widths 8 and 6 are padded to 512
+    each: ``y``, the statistics and every operand's gradient are what the
+    unpadded call gives — every extra term of every sum is an exact zero, in
+    the first chunk and in the tail's loop with its own backward pass — and
+    the gradients come back at the operands' own shapes."""
+    g, _, weights, w_gate, w_up, w_down, n_experts = _expert_case(t=64)
+    ids = jnp.tile(jnp.asarray([[2, 3], [4, 2]], jnp.int32), (32, 1))  # every slot is held
+    plan = moe.held_expert_plan(ids, 2, 4)
+    assert moe.held_walk(128, 4, n_experts)[0] < 128  # so tail chunks run
+    act, gate = (jax.nn.silu, (w_gate,)) if gated else (sm.relu2, ())
+    mark = jnp.asarray(np.random.default_rng(2).normal(size=g.shape), jnp.float32)
+
+    def apply(g, weights, w_up, w_down, *gate):
+        y, stats = moe.held_expert_apply(g, plan, weights, *(gate or (None,)), w_up, w_down,
+                                         n_experts, act)
+        return jnp.sum(y * mark), (y, stats)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(apply, argnums=tuple(range(4 + gated)), has_aux=True)(
+                g, weights, w_up, w_down, *gate)
+
+    def lowered():  # a new function a call: jax keeps a trace by the function traced
+        return jax.jit(lambda *a: apply(*a)).lower(g, weights, w_up, w_down, *gate).as_text()
+
+    (_, (y, stats)), grads = run()
+    assert "x512x" not in lowered()
+    monkeypatch.setattr(moe, "HELD_TILES_FLOOR", 0)
+    assert (moe.held_tiles(8), moe.held_tiles(6)) == (512, 512)
+    assert "4x512x512x" in lowered()  # the products do see the padded matrices
+    (_, (y_wide, stats_wide)), grads_wide = run()
+    held = dict(zip(moe.ROUTING_STATS, np.asarray(stats)))
+    assert held["moe_slots_held"] == 128 and held["moe_slots_dropped"] == 0
+    assert held["moe_rows_walked"] > moe.held_walk(128, 4, n_experts)[0]
+    np.testing.assert_array_equal(stats, stats_wide)
+    np.testing.assert_allclose(y_wide, y, rtol=1e-6, atol=1e-6)
+    for wide, narrow, operand in zip(grads_wide, grads, (g, weights, w_up, w_down, *gate)):
+        assert wide.shape == narrow.shape == operand.shape
+        assert np.any(narrow)
+        np.testing.assert_allclose(wide, narrow, rtol=1e-5, atol=1e-5)
+
+
 def test_the_shared_expert_is_ungated_where_the_layer_has_no_gate_matrix():
     """``routed_mlp`` on a layer without ``s_gate``: ``down(relu(up x)²)`` at
     weight 1 beside the routed part; with ``s_gate`` a SwiGLU as before."""

@@ -100,6 +100,9 @@ def test_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cac
         shape(t, d), shape(d, experts, dtype=jnp.float32), shape(experts, dtype=jnp.float32),
         shape(held, d, f), shape(held, d, f), shape(held, f, d))
     assert "ragged-dot" in compiled.as_text()
+    # d and 768 are whole tiles of the grouped products' kernel: passed as they are
+    assert (moe.held_tiles(d), moe.held_tiles(f)) == (d, f)
+    assert not re.search(rf" pad\(\S*\[{held},({d},{f}|{f},{d})\]", compiled.as_text())
     assert moe.held_walk(t * k, held, experts)[0] == first_rows
     # a chunk of 9/8 of the even load at a time (a tail chunk is a quarter of
     # it): 0.44 | 1.17 GiB, far under what all t·k slots would take
@@ -273,9 +276,20 @@ def test_ungated_held_expert_layer_compiles_at_published_widths(one_chip, no_com
         shape(t, d), shape(d, experts, dtype=jnp.float32), shape(experts, dtype=jnp.float32),
         shape(held, d, f), shape(held, f, d))
     assert compiled.as_text().count("ragged-dot") >= 2
+    # neither width is a multiple of 256, and XLA:TPU tiles K and N by the
+    # largest of 512 | 256 | 128 dividing them: as they come all 12 calls (the
+    # first chunk's and the tail's, forward and the gradient forms) read
+    # "512,128,128", 6615 grid steps a product at a tenth of the MXU's peak;
+    # held_tiles pads both to whole tiles
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', compiled.as_text())
+    assert len(tilings) == 12
+    assert all("128" not in (tk, tn) for _, tk, tn in tilings), tilings
     assert moe.held_walk(t * k, held, experts)[0] == 7168  # 9/8 of 6144, in tiles of 512
-    # a chunk of 7168 rows at a time: 0.91 GiB, far under what all 98 304 slots would take
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 2**30
+    # a chunk of 7168 rows at a time: 1.54 GiB, far under what all 98 304
+    # slots would take (0.91 at the published widths: the two padded matrices
+    # and their two padded gradients, 96 MiB each, stand where the arguments and
+    # the outputs themselves did, and eleven more buffers grow from 76 to 96)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2**30
 
 
 def test_short_conv_mixer_compiles_at_published_widths(one_chip, no_compile_cache):

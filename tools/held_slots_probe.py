@@ -18,6 +18,22 @@ step.  Refuses to run off a TPU unless ``--rehearse`` (the configuration's
 rehearsal cuts on the CPU: control flow only).
 
     python tools/held_slots_probe.py --workload smallthinker_ep8_train16k --seed 7
+
+``--products D'xF',...`` is another reading, of the grouped products alone: at
+the cell's first-chunk rows (``held_walk``) and held experts it times
+``lax.ragged_dot`` forward, its lhs gradient and its rhs gradient, each as the
+up product (K = D', N = F') and as the down product (K = F', N = D'), bf16, for
+every listed pair of widths, and beside each time the tile XLA:TPU chose
+(``ragged_dot_tiling="tm,tk,tn"`` in the compiled text: the largest of 512 |
+256 | 128 dividing K and N — on 128-tiles the kernel runs at a tenth of the
+peak).  Then the whole of ``held_expert_apply`` with its gradients at the
+cell's tokens under a uniform router, ``held_tiles`` forced to that pair: the
+pads and slices beside the products.  Times are the device's busy time in a
+profile of the calls back to back.  One JSON line; size a configuration with
+an odd width from it before the cell's first run (PERF.md §6 PR 53).
+
+    python tools/held_slots_probe.py --workload nemotron_twotower_ep16_train8k --seed 7 \
+        --products 2688x1856,2688x2048,2816x2048,3072x2048
 """
 
 import argparse
@@ -29,9 +45,96 @@ import time
 
 #: bins of the load's histogram, an eighth of the even load each (the last: that and more)
 BINS = 32
+#: calls back to back in the profile of one ``--products`` reading
+CALLS = 5
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, ROOT)
 sys.path.insert(1, os.path.join(ROOT, "benchmark"))
+
+
+def time_products(args, mcfg, tokens: int) -> int:
+    """``--products``: the three forms of the grouped product, both ways, and
+    ``held_expert_apply`` whole, for each listed (D', F')."""
+    import re
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    import xplane  # benchmark/xplane.py: from a profile to (name, start, end)
+    from byteps_tpu.models.ssm_moe import relu2
+    from byteps_tpu.parallel import moe
+
+    d, f, held, experts, k = (mcfg.d_model, mcfg.d_expert, mcfg.experts_held, mcfg.n_experts,
+                              mcfg.top_k)
+    gated = any(name.endswith(".e_gate")
+                for name in sys.modules[type(mcfg).__module__].layouts(mcfg))
+    rows = moe.held_walk(tokens * k, held, experts)[0]
+    sizes = jnp.full((held,), rows // held, jnp.int32)
+    keys = iter(jax.random.split(jax.random.PRNGKey(args.seed & 0x7FFFFFFF), 64))
+    make = lambda *dims: jax.random.normal(next(keys), dims, jnp.bfloat16)  # noqa: E731
+
+    def timed(fn, *xs):
+        """(busy ms a call, of them in ragged-dot calls, their tiles); no time
+        off a TPU."""
+        run = jax.jit(fn).lower(*xs).compile()
+        tiles = re.findall(r'ragged_dot_tiling="([0-9,]+)"', run.as_text())
+        jax.block_until_ready(run(*xs))
+        if args.rehearse:
+            return None, None, tiles
+        with tempfile.TemporaryDirectory() as log_dir:
+            with jax.profiler.trace(log_dir):
+                for _ in range(CALLS):
+                    out = run(*xs)
+                jax.block_until_ready(out)
+            ops = xplane.load(log_dir)["devices"][0]["ops"]
+        busy = xplane.union(ops, min(a for _, a, _ in ops), max(b for _, _, b in ops))
+        products = sum(b - a for name, a, b in ops if "ragged-dot" in name)
+        return sum(b - a for a, b in busy) / CALLS * 1e3, products / CALLS * 1e3, tiles
+
+    def forms(kk, n):
+        """A product (rows, kk) x (held, kk, n): forward, lhs and rhs gradient."""
+        x, w, ct = make(rows, kk), make(held, kk, n), make(rows, n)
+        dot = lambda x, w: lax.ragged_dot(x, w, sizes)  # noqa: E731
+        out = {}
+        for name, fn, xs in (
+                ("forward", dot, (x, w)),
+                ("lhs_gradient", lambda ct, w: jax.vjp(lambda x: dot(x, w), x)[1](ct)[0], (ct, w)),
+                ("rhs_gradient", lambda x, ct: jax.vjp(lambda w: dot(x, w), w)[1](ct)[0], (x, ct))):
+            ms, _, tiles = timed(fn, *xs)
+            out[name] = {"ms": ms, "tiling": tiles}
+        return out
+
+    ids = lax.top_k(jax.random.uniform(next(keys), (tokens, experts)), k)[1].astype(jnp.int32)
+    weights = jax.random.uniform(next(keys), (tokens, k), jnp.float32, 0.1, 1.0)
+    plan = jax.jit(lambda ids: moe.held_expert_plan(ids, 0, held))(ids)
+    g, w_up, w_down = make(tokens, d), make(held, d, f), make(held, f, d)
+    w_gate, act = (make(held, d, f), jax.nn.silu) if gated else (None, relu2)
+
+    def loss(g, weights, w_up, w_down, w_gate):
+        return jnp.sum(moe.held_expert_apply(g, plan, weights, w_gate, w_up, w_down,
+                                             experts, act)[0])
+
+    rule, readings = moe.held_tiles, []
+    for pair in args.products.split(","):
+        d_wide, f_wide = (int(n) for n in pair.split("x"))
+        moe.held_tiles = lambda n, to={d: d_wide, f: f_wide}: to.get(n, n)
+        try:  # a new function a pair: jax keeps a trace by the function traced
+            ms, in_products, tiles = timed(
+                jax.grad(lambda *a: loss(*a), argnums=(0, 1, 2, 3) + ((4,) if gated else ())),
+                g, weights, w_up, w_down, w_gate)
+        finally:
+            moe.held_tiles = rule
+        readings.append({
+            "widths": [d_wide, f_wide], "up": forms(d_wide, f_wide), "down": forms(f_wide, d_wide),
+            "held_expert_apply": {"ms": ms, "ragged_dot_ms": in_products, "tiling": tiles}})
+    print(json.dumps({
+        "workload": args.workload, "seed": args.seed, "rows": rows, "groups": held,
+        "rows_a_group": rows // held, "tokens": tokens, "top_k": k, "experts": experts,
+        "published_widths": [d, f], "gated": gated, "the_rule_gives": [rule(d), rule(f)],
+        "calls_a_reading": CALLS, "products": readings}), flush=True)
+    return 0
 
 
 def main() -> int:
@@ -40,6 +143,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=40.0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--products", metavar="DxF,...",
+                    help="time the grouped products at these padded widths instead")
     args = ap.parse_args()
 
     import run as bench_run  # benchmark/run.py: the cell's files by name
@@ -73,8 +178,11 @@ def main() -> int:
         eighths = jnp.minimum(8 * jnp.sum(plan.sizes) // even, BINS - 1)
         return y, jnp.concatenate([stats, jax.nn.one_hot(eighths, BINS, dtype=stats.dtype)])
 
-    mf.held_expert_apply = probed
     builder = bench_run.load_module("builders", config["builder"])
+    if args.products:
+        mcfg = builder._model_config(config)
+        return time_products(args, mcfg, config["batch_per_chip"] * mcfg.max_seq)
+    mf.held_expert_apply = probed
     key = jax.random.fold_in(jax.random.PRNGKey(args.seed & 0x7FFFFFFF), args.seed >> 31)
     params, batch, _ = builder.make_state(config, key, get_global_mesh())
     step = builder.build(config, traffic, params, batch, get_global_mesh())
