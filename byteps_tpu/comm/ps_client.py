@@ -725,10 +725,14 @@ class _AsyncRpc:
         self.t_sent_done = 0.0
         try:
             # lane lock + frame + sendmsg: the part of this attempt that is
-            # the sender's (under bps.stage.PUSH | PULL on a stage thread),
-            # named like that thread's account (PUSH's second: ".1")
-            with span("rpc.send." + self.op + thread_tag()):
+            # the sender's (under bps.stage.PUSH on a stage thread), named
+            # like that thread's account (PUSH's second: ".1").  A PULL is
+            # a 50-byte request under bps.stage.PULL: no span of its own
+            if self.op == "PULL":
                 sc.send_msg(msg)
+            else:
+                with span("rpc.send." + self.op + thread_tag()):
+                    sc.send_msg(msg)
             self.t_sent_done = time.monotonic()
             # every frame that actually hit the wire (incl. retries):
             # what fusion lowers (tests/test_fusion.py compares it)

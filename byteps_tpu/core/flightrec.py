@@ -15,7 +15,10 @@ profiler.  This module closes the loop:
   deltas, wire tx/rx bytes, fused/compressed counts, robustness-event
   deltas, and the membership/map epoch + scheduler incarnation the step
   ran under.  No tracing required; the record costs a counter snapshot
-  and a handful of bucket subtractions.
+  and a handful of bucket subtractions.  A compiled training step
+  (``core/tracing.stepped``) stamps a lighter one, ``record_interval``:
+  the time from one call to the next and what the host did meanwhile
+  (:func:`host_readings`), no registry delta.
 - A **trigger engine** evaluates a small rule table on every record:
   ``slow_step`` (rolling median × ``BYTEPS_FLIGHT_SLOW_FACTOR``),
   ``straggler_server`` (one rank's RPC p99 ≫ the median of its peers),
@@ -40,8 +43,12 @@ profiler.  This module closes the loop:
 
 from __future__ import annotations
 
+import bisect
+import gc
 import json
+import operator
 import os
+import resource
 import statistics
 import threading
 import time
@@ -49,6 +56,7 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
 from byteps_tpu.core.telemetry import (
+    HeldHistogram,
     _state_percentile,
     counters,
     metrics,
@@ -96,6 +104,108 @@ def _env_int(name: str, default: int) -> int:
         return int(v) if v not in (None, "") else default
     except ValueError:
         return default
+
+
+# --- what the host did meanwhile -------------------------------------------
+#
+# A slow step's first question is which side of the device it was on.  Five
+# readings at a step's boundary answer it by their growth over the step: the
+# process's and the calling thread's CPU seconds, the switches the machine
+# forced on the process and the ones it made itself, the pages it had to
+# fetch back, and the collector's pauses.
+
+#: [a running collection's start, the pauses' sum, their count]
+_gc_pauses = [0.0, 0.0, 0]
+#: (generation, pause) of the collections since the last reading
+_gc_unobserved: deque = deque(maxlen=4096)
+_gc_hists: Dict[int, HeldHistogram] = {}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # inside the collector, on whichever thread allocated last and under
+    # whatever lock that thread holds (a histogram's, the registry's): this
+    # takes none, and the next reading observes the pause
+    if phase == "start":
+        _gc_pauses[0] = time.perf_counter()
+        return
+    pause = time.perf_counter() - _gc_pauses[0]
+    _gc_pauses[1] += pause
+    _gc_pauses[2] += 1
+    _gc_unobserved.append((info.get("generation", 0), pause))
+
+
+def watch_gc() -> None:
+    """Count the collector's pauses from now on (``gc.callbacks``, once a
+    process): their sum and count go into :func:`host_readings`, and each
+    reading observes the pauses since the one before in
+    ``gc_pause_seconds{generation}``."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _observe_gc_pauses() -> None:
+    while _gc_unobserved:
+        try:
+            generation, pause = _gc_unobserved.popleft()
+        except IndexError:  # another thread's reading took the last one
+            return
+        hist = _gc_hists.get(generation)
+        if hist is None:
+            hist = _gc_hists[generation] = metrics().held(
+                "gc_pause_seconds", {"generation": str(generation)})
+        hist.observe(pause)
+
+
+#: the names :func:`host_deltas` gives the growth of :func:`host_readings`
+HOST_DELTAS = ("cpu_process_s", "cpu_thread_s", "nivcsw", "nvcsw", "majflt",
+               "gc_s", "gc_n")
+
+
+def host_readings() -> tuple:
+    """The calling thread's reading of :data:`HOST_DELTAS`' sources, now: two
+    system calls (a CPU clock is one on the chip's host, 5.8 µs: PERF.md §6
+    PR 38; the process's CPU seconds are ``getrusage``'s own user + system,
+    what ``time.process_time()`` would read with a third)."""
+    _observe_gc_pauses()
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return (ru.ru_utime + ru.ru_stime, time.thread_time(), ru.ru_nivcsw,
+            ru.ru_nvcsw, ru.ru_majflt, _gc_pauses[1], _gc_pauses[2])
+
+
+def host_deltas(before: tuple, after: tuple) -> dict:
+    return dict(zip(HOST_DELTAS, map(operator.sub, after, before)))
+
+
+def _rounded(values: dict) -> dict:
+    return {k: round(v, 6) if isinstance(v, float) else v
+            for k, v in values.items()}
+
+
+class _Rolling:
+    """The last ``maxlen`` durations and their median, kept in order: a step
+    pays two bisects, not a sort (the compiled step's seam runs the
+    ``slow_step`` rule at every call)."""
+
+    def __init__(self, maxlen: int = 64) -> None:
+        self._maxlen = maxlen
+        self._arrived: deque = deque()
+        self._ordered: List[float] = []
+
+    def __len__(self) -> int:
+        return len(self._ordered)
+
+    def append(self, value: float) -> None:
+        if len(self._arrived) == self._maxlen:
+            gone = self._arrived.popleft()
+            del self._ordered[bisect.bisect_left(self._ordered, gone)]
+        self._arrived.append(value)
+        bisect.insort(self._ordered, value)
+
+    def median(self) -> float:
+        half, odd = divmod(len(self._ordered), 2)
+        if odd:
+            return self._ordered[half]
+        return (self._ordered[half - 1] + self._ordered[half]) / 2
 
 
 class FlightRecorder:
@@ -178,10 +288,13 @@ class FlightRecorder:
         self._base_counts: Dict[str, int] = {}
         self._base_labeled: Dict[str, Dict[tuple, int]] = {}
         self._base_hists: Dict[Tuple[str, tuple], Tuple[List[int], float, int]] = {}
-        # rule state
-        self._durs: deque = deque(maxlen=64)
+        # rule state: the rolling median's history, a kind of record each
+        # (an engine round and a compiled step's interval are two clocks)
+        self._durs: Dict[str, _Rolling] = {"step": _Rolling(), "train": _Rolling()}
+        self._host: Optional[tuple] = None  # host_readings() at the last step
         self._last_degraded: Optional[int] = None
-        self._last_fire: Dict[str, float] = {}
+        #: rule → (when its last bundle was dumped, that firing's ``dur``)
+        self._last_fire: Dict[str, Tuple[float, float]] = {}
         self.bundles_written: List[str] = []
 
     # --- properties ------------------------------------------------------
@@ -233,13 +346,50 @@ class FlightRecorder:
             rec["step"] = self._step
             self._delta_counters(rec)
             self._delta_hists(rec)
+            if dur is not None:
+                # rounds end on whichever thread delivered the last reply:
+                # the process's readings, not that thread's CPU clock
+                now, before = host_readings(), self._host
+                self._host = now
+                if before is not None:
+                    rec["host"] = host_deltas(before, now)
+                    del rec["host"]["cpu_thread_s"]
             self._ring.append(rec)
         if dur is not None:
             self._registry.gauge_set("node_step_seconds", dur)
         self._evaluate(rec)
         if dur is not None:
             with self._lock:
-                self._durs.append(dur)
+                self._durs["step"].append(dur)
+        return rec
+
+    def record_interval(self, dur: float, host: dict) -> Optional[dict]:
+        """The light entry of a compiled step (``core/tracing.stepped``): the
+        time from one call of the step to the next and what the host did
+        meanwhile (``host``: the step's number, its ``dispatch_s``, ``fold_s``
+        and ``caller_s``, :func:`host_deltas`) go into the ring and to the
+        ``slow_step`` rule.  No registry delta: a counter snapshot waits for
+        the routing statistics of the step in flight
+        (``parallel/moe.RoutingCounters``) and would serialise the host with
+        the device — so a firing's bundle holds no ``metrics.json`` either.
+        Never raises into the step."""
+        if not self.enabled:
+            return None
+        rec = {"k": "train", "t": time.time(), "dur": dur, "host": host,
+               "trig": []}
+        try:
+            with self._lock:
+                self._step += 1
+                rec["step"] = self._step
+                self._ring.append(rec)
+            evidence = _rule_slow_step(self, rec)
+            if evidence is not None:
+                self._fire("slow_step", evidence, rec)
+            self._durs["train"].append(dur)  # the step's one thread
+        except Exception as e:  # noqa: BLE001 — observability ≠ a crash
+            from byteps_tpu.common import logging as bpslog
+
+            bpslog.warning("flight recorder interval failed: %r", e)
         return rec
 
     def _delta_counters(self, rec: dict) -> None:
@@ -375,11 +525,16 @@ class FlightRecorder:
     def _fire(self, rule: str, evidence: dict, rec: dict) -> None:
         rec["trig"].append(rule)
         self._counters.bump("flight_trigger", labels={"rule": rule})
-        now = time.monotonic()
+        now, dur = time.monotonic(), rec.get("dur") or 0.0
         last = self._last_fire.get(rule)
-        if last is not None and now - last < self.bundle_interval_s:
-            return  # rate limiter holds: counted, not dumped
-        self._last_fire[rule] = now
+        if last is not None and now - last[0] < self.bundle_interval_s and not (
+                rule == "slow_step" and dur > last[1] * self.slow_factor):
+            # rate limiter holds: counted, not dumped.  A step slower again by
+            # the rule's own factor than the one that was dumped passes: a
+            # step of 182 ms among 59 took the limit five steps before one
+            # of 2957 (PERF.md §6 PR 54)
+            return
+        self._last_fire[rule] = (now, dur)
         try:
             path = self.dump_bundle(rule, evidence, rec)
         except Exception as e:  # noqa: BLE001
@@ -433,8 +588,9 @@ class FlightRecorder:
         with open(os.path.join(path, "ledger.jsonl"), "w") as f:
             for r in self.snapshot():
                 f.write(json.dumps(r, default=str) + "\n")
-        with open(os.path.join(path, "metrics.json"), "w") as f:
-            json.dump(self._registry.snapshot(), f, indent=2, default=str)
+        if rec.get("k") != "train":  # see record_interval
+            with open(os.path.join(path, "metrics.json"), "w") as f:
+                json.dump(self._registry.snapshot(), f, indent=2, default=str)
         env = {
             k: v for k, v in os.environ.items()
             if k.startswith(("BYTEPS_", "DMLC_"))
@@ -471,16 +627,28 @@ class FlightRecorder:
 
 
 def _rule_slow_step(rec: "FlightRecorder", r: dict) -> Optional[dict]:
-    """This step took ≫ the rolling median of recent steps.  The evidence
-    names what a reader asks first: the stage whose dwell grew most against
-    the step before, and the retries and expired deadlines of this step."""
+    """This step took ≫ the rolling median of recent steps of its kind.  The
+    evidence names what a reader asks first.  Of an engine round: the stage
+    whose dwell grew most against the step before, the retries and expired
+    deadlines of this step, and what the host did meanwhile.  Of a compiled
+    step's interval (``record_interval``): which of dispatch, fold and the
+    caller's own time was the largest, and what the host did meanwhile
+    (docs/observability.md "Reading a slow step")."""
     dur = r.get("dur")
-    if dur is None or len(rec._durs) < rec.min_history:
+    durs = rec._durs.get(r.get("k"), ())  # a beat has no duration and no history
+    if len(durs) < rec.min_history:
         return None
-    med = statistics.median(rec._durs)
+    med = durs.median()
     if not (med > 0 and dur > med * rec.slow_factor):
         return None
-    evidence = {"dur": dur, "median": round(med, 6), "factor": rec.slow_factor}
+    host = r.get("host") or {}
+    if r.get("k") == "train":
+        parts = {k: host[k] for k in ("dispatch_s", "fold_s", "caller_s")}
+        return _rounded({"step": host["step"], "interval_s": dur, "median_s": med,
+                         **parts, "where": max(parts, key=parts.get)[:-2],
+                         **{k: host[k] for k in HOST_DELTAS}})
+    evidence = {"dur": dur, "median": round(med, 6), "factor": rec.slow_factor,
+                **_rounded(host)}
     with rec._lock:
         earlier = [x for x in rec._ring if x is not r and x.get("k") == "step"]
     before = earlier[-1].get("stages", {}) if earlier else {}
@@ -769,6 +937,12 @@ def ensure_process_recorder(cfg=None, context_fn=None,
     global _recorder
     with _recorder_lock:
         if _recorder is None:
+            _recorder = FlightRecorder(
+                cfg=cfg, context_fn=context_fn, tracer=tracer
+            )
+        elif _recorder._context_fn is None and context_fn is not None:
+            # a compiled step made that one before a PS plane was joined:
+            # the plane's recorder is made from its configuration
             _recorder = FlightRecorder(
                 cfg=cfg, context_fn=context_fn, tracer=tracer
             )

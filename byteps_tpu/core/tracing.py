@@ -40,10 +40,17 @@ import os
 import random
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation, start_trace, stop_trace
 
+from byteps_tpu.core.flightrec import (
+    ensure_process_recorder,
+    get_process_recorder,
+    host_deltas,
+    host_readings,
+    watch_gc,
+)
 from byteps_tpu.core.telemetry import HeldHistogram, metrics
 
 _id_rng = random.SystemRandom()
@@ -341,6 +348,76 @@ class span:
                               **self.attrs),
                 )
         return False
+
+
+class stepped:
+    """The seam around a compiled training step: what ``jax.jit`` made, called
+    through two spans and a clock, so that the compiled path accounts for the
+    host's side of a step as the two-level step's ``hybrid.*`` spans do.
+
+    A call is ``span("train.dispatch", step=n, wall_ns=...)`` around the
+    jitted call — the two stats ``hybrid.step`` carries, which tie the
+    profiler's clock to ``time.time()`` — and, where the step has something
+    to take off its outputs on the host (``fold``: ``build_train_step``'s
+    routing statistics), ``span("train.fold")`` around that.
+
+    **The step clock.**  Every entry takes the time since the previous entry:
+    in a closed loop, and in a pipelined one that the runtime's queue
+    throttles, that IS the previous step's time.  It is observed in
+    ``train_step_interval_seconds`` and split: ``dispatch_s`` and ``fold_s``
+    as the spans read them, and ``caller_s``, the rest — the caller's wait
+    for the device and whatever else it does between two steps, which the
+    program cannot span.  With it go :func:`flightrec.host_readings`' growth
+    over the interval.  All of that is the evidence of the process's
+    ``FlightRecorder.record_interval``, whose ``slow_step`` rule says on
+    stderr which side of the device a step of several times the median was on
+    (docs/observability.md "Reading a slow step"); ``BYTEPS_FLIGHT_STEPS=0``
+    turns the rule off and leaves the spans and histograms.
+
+    One thread calls a step.  Every attribute the jitted function has
+    (``lower``, ``trace``, ``eval_shape`` …) is this object's too.
+    """
+
+    def __init__(self, jitted: Callable, fold: Optional[Callable] = None) -> None:
+        self._jitted = jitted
+        self._fold = fold
+        self._n = 0
+        self._entered: Optional[float] = None  # the previous entry
+        self._readings: tuple = ()
+        self._dispatch_s = self._fold_s = 0.0
+        self._interval = metrics().held("train_step_interval_seconds")
+        watch_gc()
+
+    def __getattr__(self, name: str):
+        if name == "_jitted":  # not made yet: a copy, an unpickling
+            raise AttributeError(name)
+        return getattr(self._jitted, name)
+
+    def __call__(self, *args, **kwargs):
+        now, readings = time.perf_counter(), host_readings()
+        if self._entered is not None:
+            self._account(now - self._entered, readings)
+        self._entered, self._readings = now, readings
+        self._n += 1
+        with span("train.dispatch", step=self._n, wall_ns=time.time_ns()) as call:
+            out = self._jitted(*args, **kwargs)
+        self._dispatch_s, self._fold_s = call.ended - call.started, 0.0
+        if self._fold is not None:
+            with span("train.fold") as fold:
+                out = self._fold(out)
+            self._fold_s = fold.ended - fold.started
+        return out
+
+    def _account(self, interval: float, readings: tuple) -> None:
+        """The step that the previous entry began, now that it is over."""
+        self._interval.observe(interval)
+        recorder = get_process_recorder() or ensure_process_recorder()
+        if recorder.enabled:
+            recorder.record_interval(interval, {
+                "step": self._n, "dispatch_s": self._dispatch_s,
+                "fold_s": self._fold_s,
+                "caller_s": interval - self._dispatch_s - self._fold_s,
+                **host_deltas(self._readings, readings)})
 
 
 @contextlib.contextmanager

@@ -207,7 +207,8 @@ def _hidden(cfg: ConvMoEConfig, params, tokens):
 
     run = {"conv": residual(_conv_mixer), "attn": residual(_attention_mixer),
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_mlp(cfg, x, lp)}
-    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.compute_dtype)
     return mf.walk(cfg, run, ("attn",), params, x)
 
 

@@ -339,7 +339,8 @@ def _hidden(cfg: DeltaMoEConfig, params, tokens):
         x, last = mlp(attention(x, lps["full"]), lps["full"])
         return x, stats + last
 
-    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.compute_dtype)
     x, stats = lax.scan(period, x, {kind: mf.stack_of(params, kind) for kind in ("lin", "full")})
     return x, jnp.sum(stats, 0)
 

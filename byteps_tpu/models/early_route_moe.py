@@ -202,8 +202,9 @@ def _hidden(cfg: EarlyRouteMoEConfig, params, tokens):
     run = {"win": lambda x, lp: _mixer_part(cfg, x, lp, "win"),
            "glob": lambda x, lp: _mixer_part(cfg, x, lp, "glob"),
            "moe": lambda x, lp, decision: _expert_part(cfg, x, lp, decision)}
-    x = params["embed"][tokens]
-    return mf.walk(cfg, run, tuple(SCOPES), params, x.astype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.compute_dtype)
+    return mf.walk(cfg, run, tuple(SCOPES), params, x)
 
 
 def local_logits(cfg: EarlyRouteMoEConfig, params, tokens):

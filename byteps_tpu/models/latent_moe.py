@@ -194,9 +194,10 @@ def _attention(cfg: LatentMoEConfig, x, lp):
 def _dense_layer(cfg: LatentMoEConfig, x, lp):
     cdt = cfg.compute_dtype
     x = _attention(cfg, x, lp)
-    g = rms(x, lp["mlp_norm"], cfg.norm_eps).astype(cdt)
-    y = swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
-    return x + y.astype(x.dtype)
+    with jax.named_scope("dense_mlp"):
+        g = rms(x, lp["mlp_norm"], cfg.norm_eps).astype(cdt)
+        y = swiglu(g, *(lp[w].astype(cdt) for w in ("w_gate", "w_up", "w_down")))
+        return x + y.astype(x.dtype)
 
 
 def expert_mlp(cfg: LatentMoEConfig, g32, lp):
@@ -237,7 +238,8 @@ def _hidden(cfg: LatentMoEConfig, params, tokens, targets=None):
     MTP module's (None without targets or modules), and the routing stats
     summed over the expert-kind layers."""
     cdt = cfg.compute_dtype
-    x = params["embed"][tokens].astype(cdt)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cdt)
     x, _ = _run_stack(cfg, lambda c, x, lp: (_dense_layer(c, x, lp), None), params, "dense", x)
     x, stats = _run_stack(cfg, _expert_layer, params, "moe", x)
     stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32) if stats is None else jnp.sum(stats, 0)
@@ -277,7 +279,8 @@ def _xent_sums(cfg: LatentMoEConfig, params, x, norm: str, targets):
 
     if cfg.remat:
         sums = jax.checkpoint(sums)
-    return sums(x, params[norm], params["head"])
+    with jax.named_scope("lm_head"):
+        return sums(x, params[norm], params["head"])
 
 
 def local_loss(cfg: LatentMoEConfig, mesh: Mesh, params, tokens, targets):

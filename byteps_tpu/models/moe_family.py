@@ -416,9 +416,12 @@ def xent_sums(cfg, logits: Callable, x, targets, scale, head):
 
     if cfg.remat:
         one = jax.checkpoint(one)
-    total = jnp.sum(lax.map(lambda xs: one(*xs, scale, head),
-                            (x.reshape(-1, block, d), targets.reshape(-1, block))))
-    return total, jnp.sum(targets >= 0).astype(jnp.float32)
+    # around the whole map: the blocks rebuilt in the backward pass file
+    # under lm_head too
+    with jax.named_scope("lm_head"):
+        total = jnp.sum(lax.map(lambda xs: one(*xs, scale, head),
+                                (x.reshape(-1, block, d), targets.reshape(-1, block))))
+        return total, jnp.sum(targets >= 0).astype(jnp.float32)
 
 
 def over_ranks(*sums):

@@ -303,8 +303,9 @@ def _hidden(cfg: SsmMoEConfig, params, tokens):
     run = {"ssm": lambda x, lp: _ssm_layer(cfg, x, lp),
            "attn": lambda x, lp: _attention_layer(cfg, x, lp),
            "moe": lambda x, lp: _expert_layer(cfg, x, lp)}
-    x = params["embed"][tokens]
-    return mf.walk(cfg, run, ("attn",), params, x.astype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.compute_dtype)
+    return mf.walk(cfg, run, ("attn",), params, x)
 
 
 def local_logits(cfg: SsmMoEConfig, params, tokens):

@@ -38,6 +38,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from byteps_tpu.core.tracing import stepped
 from byteps_tpu.parallel.moe import moe_aux_loss, moe_mlp, routing_counters
 from byteps_tpu.parallel.ring_attention import ring_attention
 
@@ -550,11 +551,12 @@ def _local_forward(cfg: TransformerConfig, mesh: Mesh, params, tokens):
 
     b_local, s_local = tokens.shape
     sp_idx = lax.axis_index("sp")
-    x = params["embed"][tokens]
-    if cfg.pos_emb == "learned":
-        positions = sp_idx * s_local + jnp.arange(s_local)
-        x = x + params["pos"][positions]
-    x = _vary_all(x.astype(cfg.compute_dtype), mesh)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        if cfg.pos_emb == "learned":
+            positions = sp_idx * s_local + jnp.arange(s_local)
+            x = x + params["pos"][positions]
+        x = _vary_all(x.astype(cfg.compute_dtype), mesh)
 
     m = cfg.microbatches or pp
     if b_local % m:
@@ -562,8 +564,9 @@ def _local_forward(cfg: TransformerConfig, mesh: Mesh, params, tokens):
     x_mb = x.reshape(m, b_local // m, s_local, cfg.d_model)
 
     outputs, aux = _pipeline(cfg, mesh, stage_fn, stage_params, x_mb)
-    h = _ln(outputs, params["ln_f_s"], params["ln_f_b"]).astype(cfg.compute_dtype)
-    logits = jnp.einsum("mbsd,dv->mbsv", h, params["head"].astype(cfg.compute_dtype))
+    with jax.named_scope("lm_head"):
+        h = _ln(outputs, params["ln_f_s"], params["ln_f_b"]).astype(cfg.compute_dtype)
+        logits = jnp.einsum("mbsd,dv->mbsv", h, params["head"].astype(cfg.compute_dtype))
     return logits, aux
 
 
@@ -580,13 +583,14 @@ def _local_loss(cfg: TransformerConfig, mesh: Mesh, params, tokens, targets):
     tgt = targets.reshape(m, -1, targets.shape[-1])
     valid = (tgt >= 0).astype(jnp.float32)
     safe_tgt = jnp.maximum(tgt, 0)
-    logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    gold = jnp.take_along_axis(
-        logits.astype(jnp.float32), safe_tgt[..., None], axis=-1
-    )[..., 0]
-    token_loss = (logz - gold) * valid  # (M, Bmb, S_local)
-    local_sum = jnp.sum(token_loss)
-    local_cnt = jnp.sum(valid)
+    with jax.named_scope("lm_head"):
+        logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        gold = jnp.take_along_axis(
+            logits.astype(jnp.float32), safe_tgt[..., None], axis=-1
+        )[..., 0]
+        token_loss = (logz - gold) * valid  # (M, Bmb, S_local)
+        local_sum = jnp.sum(token_loss)
+        local_cnt = jnp.sum(valid)
     # only the last stage holds real logits; the pp-psum picks its value
     # (free no-ops at axis size 1, and they make the loss VMA-invariant
     # over every mesh axis so it is truly replicated)
@@ -1008,10 +1012,9 @@ def build_train_step(
 
     jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
-    def step(params, opt_state, tokens, targets):
-        params, opt_state, loss, counts = jitted(params, opt_state, tokens, targets)
-        routing_counters().push(counts)
-        return params, opt_state, loss
+    def fold(out):
+        # a device_get of whatever is ready: a place a host stall can hide
+        routing_counters().push(out[3])
+        return out[:3]
 
-    step.lower = jitted.lower
-    return step
+    return stepped(jitted, fold)

@@ -216,10 +216,12 @@ def _hidden(cfg: WindowMoEConfig, params, tokens):
 
     run = {"win": residual(_attention_mixer, "win"), "glob": residual(_attention_mixer, "glob"),
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_layer(cfg, x, lp)}
-    x = params["embed"][tokens]
-    if cfg.mup:
-        x = x * math.sqrt(cfg.d_model)
-    return mf.walk(cfg, run, tuple(SCOPES), params, x.astype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        if cfg.mup:
+            x = x * math.sqrt(cfg.d_model)
+        x = x.astype(cfg.compute_dtype)
+    return mf.walk(cfg, run, tuple(SCOPES), params, x)
 
 
 def local_logits(cfg: WindowMoEConfig, params, tokens):

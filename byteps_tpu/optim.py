@@ -30,6 +30,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.comm.mesh import DP_AXIS, get_global_mesh
+from byteps_tpu.core.tracing import stepped
 
 
 def allreduce_gradients(
@@ -269,7 +270,7 @@ def _compile_spmd_step(
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    return jax.jit(sharded, donate_argnums=(0, 1) if donate else ())
+    return stepped(jax.jit(sharded, donate_argnums=(0, 1) if donate else ()))
 
 
 def build_data_parallel_step(
@@ -431,7 +432,7 @@ def _compile_spmd_step_with_state_axis(local_step, mesh, axis_name, donate):
         out_specs=(P(), P(axis_name), P()),
         check_vma=False,
     )
-    return jax.jit(sharded, donate_argnums=(0, 1) if donate else ())
+    return stepped(jax.jit(sharded, donate_argnums=(0, 1) if donate else ()))
 
 
 def build_flax_data_parallel_step(
