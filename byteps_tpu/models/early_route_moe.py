@@ -19,7 +19,9 @@ ReLU-gated MLP ``(relu(b W_gate) ⊙ b W_up) W_down``.
 The mixers are plain grouped-query softmax attention, no head norm and no
 gate, each key/value head serving its group of query heads (seven at the
 published sizes), and ``layer_types[i]`` says which kind:
-``"sliding_attention"`` takes rope over the whole head and sees the last
+``"sliding_attention"`` takes rope over the whole head (``ops/head_norm.py``'s
+``head_rope``: the q | k products stay token-major and one pass turns them
+and writes the kernels' head-major operand) and sees the last
 ``sliding_window`` keys, itself included (``ops/flash_attention.py``'s banded
 kernels); ``"full_attention"`` takes NO positional encoding and is causal.
 Bias-free, RMSNorm ``w · x / rms(x)``, untied head, no embedding scale, no
@@ -49,7 +51,8 @@ from jax.sharding import Mesh
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import rms
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.parallel.moe import softmax_topk_route
+from byteps_tpu.ops.head_norm import head_rope
+from byteps_tpu.parallel.moe import softmax_topk_route, take_rows, varying
 
 #: ``layer_types`` entry → the stack that holds that mixer's parameters
 MIXERS = {"sliding_attention": "win", "full_attention": "glob"}
@@ -174,9 +177,14 @@ def _mixer_part(cfg: EarlyRouteMoEConfig, x, lp, stack: str):
     decision = mf.decide(cfg, a32.reshape(b * s, d), lp, functools.partial(_route, cfg))
     with jax.named_scope(SCOPES[stack]):
         a = a32.astype(cdt)
-        q, k, v = (jnp.einsum("bsd,dhk->bhsk", a, lp[w].astype(cdt)) for w in ("wq", "wk", "wv"))
         if stack == "win":
-            q, k = (mf.rope_partial(t, hd, cfg.rope_theta) for t in (q, k))
+            # a turned head's product stays token-major, its heads side by
+            # side: the rotation's pass writes it head-major
+            q, k = (head_rope(jnp.einsum("bsd,df->bsf", a, lp[w].astype(cdt).reshape(d, -1)),
+                              hd, cfg.rope_theta) for w in ("wq", "wk"))
+        else:
+            q, k = (jnp.einsum("bsd,dhk->bhsk", a, lp[w].astype(cdt)) for w in ("wq", "wk"))
+        v = jnp.einsum("bsd,dhk->bhsk", a, lp["wv"].astype(cdt))
         # the kernels find a query head's key/value head themselves: K and V
         # go in at their own head count
         o = flash_attention(q, k, v, causal=True, scale=hd ** -0.5,
@@ -203,7 +211,10 @@ def _hidden(cfg: EarlyRouteMoEConfig, params, tokens):
            "glob": lambda x, lp: _mixer_part(cfg, x, lp, "glob"),
            "moe": lambda x, lp, decision: _expert_part(cfg, x, lp, decision)}
     with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.compute_dtype)
+        # the gather whose transpose sorts its scatter-add by hand: XLA's own
+        # takes twice as long at this slice's (rows, model) under 2 x 16 384 tokens
+        rows = take_rows(varying(params["embed"], jax.typeof(tokens).vma), tokens.reshape(-1))
+        x = rows.reshape(*tokens.shape, -1).astype(cfg.compute_dtype)
     return mf.walk(cfg, run, tuple(SCOPES), params, x)
 
 

@@ -29,6 +29,7 @@ a table).  No ``*_moe`` module imports another; all import this one.
 from __future__ import annotations
 
 import fnmatch
+import functools
 import math
 import sys
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Union
@@ -40,7 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
 from byteps_tpu.parallel.moe import (ROUTING_STATS, HeldPlan, held_expert_apply,
-                                     held_expert_plan)
+                                     held_expert_plan, varying)
 
 _ALL_AXES = ("dp", "pp", "sp", "tp")
 #: rows of logits that stand at a time in the blocked loss
@@ -401,26 +402,72 @@ def row_logits(cfg, x, scale, rows):
                            preferred_element_type=jnp.float32)
 
 
+def _varying_as(tree, *like):
+    """``tree``'s leaves typed as varying over every axis one of ``like``
+    varies over (under ``shard_map``; themselves elsewhere)."""
+    axes = frozenset().union(*(jax.typeof(a).vma for a in like))
+    return jax.tree.map(lambda leaf: varying(leaf, axes), tree)
+
+
+def _block_loss(rows, tb):
+    """One block's masked sum of token cross-entropies from its f32 logits,
+    and the logsumexp it took."""
+    lse = jax.nn.logsumexp(rows, axis=-1)
+    gold = jnp.take_along_axis(rows, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
+    return jnp.sum((lse - gold) * (tb >= 0)), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _blocked_xent(cfg, logits: Callable, xs, ts, scale, head):
+    """The sum of token cross-entropies over blocks of rows ``xs`` (n, block,
+    D), ``ts`` (n, block); undifferentiated, the plain blocked sum."""
+    return jnp.sum(lax.map(lambda xt: _block_loss(logits(cfg, xt[0], scale, head), xt[1])[0],
+                           (xs, ts)))
+
+
+def _blocked_xent_up(cfg, logits: Callable, xs, ts, scale, head):
+    """Differentiated, a block's gradient is taken where its logits stand:
+    ``softmax − onehot`` over the counted rows, f32, pulled back through the
+    family's ``logits`` at once — dx of the block written, the scale's and the
+    head's gradients summed in f32 across the blocks.  Three products a
+    block; nothing of a block is rebuilt on the way down."""
+
+    def one(sums, xt):
+        xb, tb = xt
+        rows, pull = jax.vjp(functools.partial(logits, cfg), xb, scale, head)
+        loss, lse = _block_loss(rows, tb)
+        counted = (tb >= 0)[:, None]
+        hot = jnp.arange(rows.shape[-1], dtype=tb.dtype) == tb[:, None]
+        dxb, *into = pull(jnp.where(counted, jnp.exp(rows - lse[:, None]) - hot, 0.0))
+        return tuple(s + d.astype(jnp.float32) for s, d in zip(sums, into)), (loss, dxb)
+
+    sums = tuple(jnp.zeros_like(w, jnp.float32) for w in (scale, head))
+    sums, (losses, dxs) = lax.scan(one, _varying_as(sums, xs, scale, head), (xs, ts))
+    return jnp.sum(losses), (dxs, *(s.astype(w.dtype) for s, w in zip(sums, (scale, head))))
+
+
+def _blocked_xent_down(cfg, logits, kept, ct):
+    dxs, d_scale, d_head = ((ct * g).astype(g.dtype) for g in kept)
+    return dxs, None, d_scale, d_head
+
+
+_blocked_xent.defvjp(_blocked_xent_up, _blocked_xent_down)
+
+
 def xent_sums(cfg, logits: Callable, x, targets, scale, head):
     """(sum of token cross-entropies, tokens counted); targets < 0 are
     ignored.  ``logits(cfg, x, scale, head)`` is the family's.  A block of
-    rows at a time, each rebuilt in the backward pass: the (B·S, V) logits
-    never stand whole."""
+    rows at a time: the (B·S, V) logits never stand whole, and under
+    differentiation each block's gradient is taken while its logits stand
+    (:func:`_blocked_xent_up`), so no block is built twice."""
     d = x.shape[-1]
     block = math.gcd(x.size // d, ROW_BLOCK)
-
-    def one(xb, tb, scale, head):
-        rows = logits(cfg, xb, scale, head)
-        gold = jnp.take_along_axis(rows, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
-        return jnp.sum((jax.nn.logsumexp(rows, axis=-1) - gold) * (tb >= 0))
-
-    if cfg.remat:
-        one = jax.checkpoint(one)
-    # around the whole map: the blocks rebuilt in the backward pass file
-    # under lm_head too
     with jax.named_scope("lm_head"):
-        total = jnp.sum(lax.map(lambda xs: one(*xs, scale, head),
-                                (x.reshape(-1, block, d), targets.reshape(-1, block))))
+        # under shard_map the scale and the head are replicated and x varies:
+        # their cotangents are summed over x's axes by this cast's transpose
+        scale, head = _varying_as((scale, head), x)
+        total = _blocked_xent(cfg, logits, x.reshape(-1, block, d), targets.reshape(-1, block),
+                              scale, head)
         return total, jnp.sum(targets >= 0).astype(jnp.float32)
 
 

@@ -4,7 +4,9 @@
   MXU matmuls), used by the transformer's per-device attention.
 - :mod:`head_norm` — a head's RMSNorm and rotary embedding in one pass each
   way (bf16 in, f32 in registers, bf16 out), between a q | k projection and
-  the flash kernels of the sliding-window family's mixers.
+  the flash kernels of the sliding-window family's mixers; and the rotation
+  alone (``head_rope``), which reads a token-major product and writes the
+  kernels' head-major operand, for the early-routed family's.
 - :mod:`mla_heads` — from a latent-attention mixer's four token-major
   products to the flash kernels' head-major q | k | v (the rotary columns
   turned in f32, the shared rotary key read once a block) and its transpose,

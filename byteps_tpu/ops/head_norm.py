@@ -23,6 +23,19 @@ roll's slices) and a head-dim-major copy (tests/test_tpu_compile.py holds
 what it holds now).  The backward pass keeps x and w of the forward and
 rebuilds the statistic, one lane reduction a row.
 
+``head_rope(x, d, theta)`` is the pass without the norm, for a mixer whose
+heads take positions and no norm (``models/early_route_moe.py``'s sliding
+layers): the same tables, the same ``_turn``, the same blocks and grid, the
+same chooser.  It reads x (B, S, H·d) as the projection's product stands —
+token-major, heads side by side, no transposed copy of it — and writes the
+flash kernels' head-major operand (``head_rope_fwd``); the backward pass turns
+the cotangent by the negative angle, writes it token-major for the
+projection's two transposed products and keeps nothing (``head_rope_bwd``):
+the change of layout is the blocks' index maps.  Which form a traced call
+took is counted, ``head_rope_kernel_traces`` | ``head_rope_xla_traces``
+(``bps.get_robustness_counters()``), as ``ops/gated_delta.py`` counts its
+rule's: a step that fell back to XLA's form can be told from the registry.
+
 The kernels' grid is (query block, batch·head), the heads innermost: a
 block of the (S, d) f32 tables is fetched once a query block and stands
 still while the heads pass (Pallas does not copy a block whose index did not
@@ -37,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from byteps_tpu.core.telemetry import counters
 from byteps_tpu.ops.flash_attention import _vma_union as _vma
 
 LANES = 128
@@ -46,6 +60,8 @@ BLOCK_ROWS = 1024
 SUBLANES = 8
 
 FWD_KERNEL, BWD_KERNEL = "head_norm_fwd", "head_norm_bwd"
+#: the norm-less pass: one kernel body, named by the way it turns
+ROPE_FWD_KERNEL, ROPE_BWD_KERNEL = "head_rope_fwd", "head_rope_bwd"
 
 
 def rope_tables(s: int, d: int, theta: float):
@@ -242,3 +258,84 @@ def head_norm_rope(x, w, eps: float, theta=None, interpret: bool = False):
     if need:
         w = lax.pcast(w, need, to="varying")
     return _head_norm_rope(x, w, eps, theta, interpret)
+
+
+# ---------------------------------------------------------------------------
+# the rotation alone: a head that takes positions and no norm
+# ---------------------------------------------------------------------------
+
+
+def _turn_kernel(x, d, tables, back, interpret):
+    """``_turn`` on the norm's blocks and grid, and the change of layout in
+    the blocks' index maps: forth it reads a head's columns of the
+    token-major x (B, S, H·d) and writes them head-major (B·H, S, d), back
+    the other way."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s = x.shape[:3] if back else (x.shape[0], x.shape[2] // d, x.shape[1])
+    grid, heads, _, table_specs = _specs(b * h, s, d, _block_rows(s), True)
+    tokens = pl.BlockSpec((1, _block_rows(s), d), lambda qi, i: (i // h, qi, i % h))
+
+    def kernel(x_ref, cos_ref, sin_ref, y_ref):
+        y_ref[0] = _turn(x_ref[0].astype(jnp.float32), cos_ref[...], sin_ref[...],
+                         _lane_roll).astype(y_ref.dtype)
+
+    y = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d) if back else (b * h, s, d), x.dtype,
+                                       vma=_vma(x)),
+        grid=grid,
+        in_specs=[heads if back else tokens, *table_specs],
+        out_specs=tokens if back else heads,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=ROPE_BWD_KERNEL if back else ROPE_FWD_KERNEL,
+    )(x.reshape(b * h, s, d) if back else x, *tables)
+    return y if back else y.reshape(b, h, s, d)
+
+
+def _turned(x, d, theta, back, kernels, interpret):
+    """Forth: x (B, S, H·d) turned by its positions, head-major (B, H, S,
+    d).  Back: a head-major cotangent turned by the negative angles — the
+    rotation's transpose — token-major.  f32 inside, rounded once."""
+    b, s = x.shape[0], x.shape[2 if back else 1]
+    cos, sin = rope_tables(s, d, theta)
+    tables = (cos, -sin) if back else (cos, sin)
+    if kernels:
+        return _turn_kernel(x, d, tables, back, interpret)
+    x = x if back else jnp.swapaxes(x.reshape(b, s, -1, d), 1, 2)
+    y = _turn(x.astype(jnp.float32), *tables, _xla_roll).astype(x.dtype)
+    return jnp.swapaxes(y, 1, 2).reshape(b, s, -1) if back else y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _head_rope(x, d, theta, kernels, interpret):
+    return _rope_fwd(x, d, theta, kernels, interpret)[0]
+
+
+def _rope_fwd(x, d, theta, kernels, interpret):
+    return _turned(x, d, theta, False, kernels, interpret), None
+
+
+def _rope_bwd(d, theta, kernels, interpret, _, g):
+    return (_turned(g, d, theta, True, kernels, interpret),)
+
+
+_head_rope.defvjp(_rope_fwd, _rope_bwd)
+
+
+def head_rope(x, d: int, theta: float, interpret: bool = False):
+    """x (B, S, H·d), heads of ``d`` side by side as a projection's product
+    stands → each head turned by its position over the whole head (what
+    :func:`head_norm_rope` does after its norm), head-major (B, H, S, d) as
+    the flash kernels read it, in x's dtype.  The backward pass turns the
+    cotangent back, writes it token-major and keeps nothing.  What runs where
+    is :func:`_kernel_path`'s call, made once a traced call and counted:
+    ``head_rope_kernel_traces`` | ``head_rope_xla_traces``
+    (``bps.get_robustness_counters()``)."""
+    if d % 2 or x.shape[-1] % d:
+        raise ValueError(f"{x.shape[-1]} columns are no heads of {d} with halves to pair")
+    kernels = _kernel_path(x.shape[1], d, interpret)
+    counters().bump("head_rope_kernel_traces" if kernels else "head_rope_xla_traces")
+    return _head_rope(x, d, theta, kernels, interpret)

@@ -205,14 +205,14 @@ def held_expert_mlp(g: jax.Array, ids: jax.Array, weights: jax.Array,
     return held_expert_apply(g, plan, weights, w_gate, w_up, w_down, n_experts, jax.nn.silu)
 
 
-def _varying(x: jax.Array, axes: frozenset) -> jax.Array:
+def varying(x: jax.Array, axes: frozenset) -> jax.Array:
     """x typed as varying over ``axes`` too (under ``shard_map``; x elsewhere)."""
     need = tuple(axes - jax.typeof(x).vma)
     return lax.pcast(x, need, to="varying") if need else x
 
 
 @jax.custom_vjp
-def _add_rows(y: jax.Array, at: jax.Array, rows: jax.Array) -> jax.Array:
+def add_rows(y: jax.Array, at: jax.Array, rows: jax.Array) -> jax.Array:
     """``y.at[at].add(rows)``, the sort made by hand.  XLA:TPU sorts a
     scatter's indices itself and gathers the rows in that order; at some
     shapes it fuses that gather into the scatter, which then takes over twice
@@ -225,16 +225,16 @@ def _add_rows(y: jax.Array, at: jax.Array, rows: jax.Array) -> jax.Array:
 
 
 @jax.custom_vjp
-def _take_rows(x: jax.Array, at: jax.Array) -> jax.Array:
-    """``x[at]``, whose backward pass is :func:`_add_rows` (as that one's is
+def take_rows(x: jax.Array, at: jax.Array) -> jax.Array:
+    """``x[at]``, whose backward pass is :func:`add_rows` (as that one's is
     this): each the other's transpose, so both ways a scatter is sorted by hand."""
     return x[at]
 
 
-_add_rows.defvjp(lambda y, at, rows: (_add_rows(y, at, rows), at),
-                 lambda at, d: (d, None, _take_rows(d, at)))
-_take_rows.defvjp(lambda x, at: (x[at], (x, at)),
-                  lambda kept, d: (_add_rows(jnp.zeros_like(kept[0]), kept[1], d), None))
+add_rows.defvjp(lambda y, at, rows: (add_rows(y, at, rows), at),
+                lambda at, d: (d, None, take_rows(d, at)))
+take_rows.defvjp(lambda x, at: (x[at], (x, at)),
+                 lambda kept, d: (add_rows(jnp.zeros_like(kept[0]), kept[1], d), None))
 
 
 def held_walk(every: int, n_held: int, n_experts: int) -> tuple:
@@ -328,7 +328,7 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         # product leaves them unwritten (garbage on the TPU), so every
         # product's operand and result is cleared there by selection
         live = (first + jnp.arange(rows) < ends[-1])[:, None]
-        xs = jnp.where(live, _take_rows(g, tok), 0)  # (rows, D)
+        xs = jnp.where(live, take_rows(g, tok), 0)  # (rows, D)
         if d_wide != d:
             xs = jnp.pad(xs, ((0, 0), (0, d_wide - d)))
 
@@ -357,7 +357,7 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
         def chunk(carry):
             i, y, took = carry
             add, (tok, n) = rows_of(*read, first_rows + i * tail_rows, tail_rows)
-            return i + 1, _add_rows(y, tok, add), took + n
+            return i + 1, add_rows(y, tok, add), took + n
 
         n_slots = read[-2][-1]
         none = jnp.zeros_like(n_slots)  # typed as the counts are, also under shard_map
@@ -380,7 +380,7 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
             _, pull, (tok, _) = jax.vjp(
                 lambda *a: rows_of(*a, order, ends, sizes, first_rows + i * tail_rows, tail_rows),
                 *inputs, has_aux=True)
-            return jax.tree.map(jnp.add, sums, pull(_take_rows(dy, tok)))
+            return jax.tree.map(jnp.add, sums, pull(take_rows(dy, tok)))
 
         sums = lax.fori_loop(jnp.zeros_like(ran), ran, chunk,
                              jax.tree.map(jnp.zeros_like, tuple(inputs)))
@@ -390,12 +390,12 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
 
     ends = jnp.cumsum(sizes)
     n_slots = ends[-1]
-    varying = jax.typeof(g).vma  # under shard_map the walk's arrays vary as g does
-    y, *read = jax.tree.map(lambda x: _varying(x, varying), (
+    axes = jax.typeof(g).vma  # under shard_map the walk's arrays vary as g does
+    y, *read = jax.tree.map(lambda x: varying(x, axes), (
         jnp.zeros((t, g.shape[1]), jnp.float32), g, weights.reshape(-1), ws,
         order, ends, sizes))
     add, (tok, taken) = rows_of(*read, 0, first_rows)
-    y, walked = _add_rows(y, tok, add), jnp.asarray(first_rows, jnp.int32)
+    y, walked = add_rows(y, tok, add), jnp.asarray(first_rows, jnp.int32)
     if n_tail:
         # behind ONE cond: the usual step ends with the first chunk and pays
         # for no branch it does not take (a cond a chunk cost 16 zero-filled

@@ -341,3 +341,88 @@ def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
         builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
     assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
     assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
+
+
+# ---------------------------------------------------------------------------
+# the embedding's gather, whose transpose sorts its scatter-add by hand
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows,n,d", [(11, 40, 6), (40, 11, 3), (5, 64, 8)],
+                         ids=["most_rows_hit_twice", "most_rows_not_hit", "every_row_many_times"])
+def test_take_rows_and_add_rows_are_the_plain_gather_and_scatter(rows, n, d):
+    """``parallel/moe.take_rows`` is ``x[at]`` and ``add_rows`` is
+    ``y.at[at].add(rows)`` — repeated and absent rows alike —, and each one's
+    gradient is the other: d ``take_rows`` / dx scatters, d ``add_rows`` / d
+    rows gathers, d ``add_rows`` / dy passes through."""
+    rng = np.random.default_rng(rows * n)
+    x = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32)
+    at = jnp.asarray(rng.integers(0, rows, size=n), jnp.int32)
+    new = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    ct_taken, ct_added = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                          for shape in ((n, d), (rows, d)))
+    np.testing.assert_array_equal(moe.take_rows(x, at), x[at])
+    np.testing.assert_allclose(moe.add_rows(x, at, new), x.at[at].add(new), rtol=1e-6, atol=1e-6)
+    got = jax.grad(lambda x: jnp.sum(moe.take_rows(x, at) * ct_taken))(x)
+    want = jax.grad(lambda x: jnp.sum(x[at] * ct_taken))(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    got = jax.grad(lambda y, r: jnp.sum(moe.add_rows(y, at, r) * ct_added), argnums=(0, 1))(x, new)
+    want = jax.grad(lambda y, r: jnp.sum(y.at[at].add(r) * ct_added), argnums=(0, 1))(x, new)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_embeddings_gradient_is_the_scatter_add_of_its_rows(dtype):
+    """The step's embedding (``_hidden`` under ``embed``: ``take_rows`` on
+    the flattened ids, then the cast): the rows are ``embed[tokens]`` and the
+    leaf's gradient is ``zeros.at[tokens].add(cotangent)`` in f32, a token that
+    comes twice adding twice."""
+    cfg = em.tiny_early_route_moe(compute_dtype=jnp.dtype(dtype), layer_types=(FULL,))
+    params, tokens, _ = _state(cfg, seed=5)
+    tokens = tokens.at[:, 1].set(tokens[:, 0])  # a row that comes twice a sequence
+    seen = {}
+
+    def walk(cfg, run, flash, params, x):  # the stack left out: the embedding alone
+        seen["x"] = x
+        return x, None
+
+    ct = jnp.asarray(np.random.default_rng(2).normal(size=tokens.shape + (cfg.d_model,)),
+                     jnp.float32)
+
+    def through(embed):
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(mf, "walk", walk)
+            x, _ = em._hidden(cfg, {**params, "embed": embed}, tokens)
+        return jnp.sum(x.astype(jnp.float32) * ct)
+
+    got = jax.grad(through)(params["embed"])
+    assert seen["x"].dtype == cfg.compute_dtype and seen["x"].shape == ct.shape
+    want = jax.grad(lambda e: jnp.sum(e[tokens].astype(cfg.compute_dtype).astype(jnp.float32) * ct))(
+        params["embed"])
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_the_embeddings_gradient_is_summed_over_the_ranks():
+    """Under ``shard_map`` on two devices, a sequence each: the embedding is
+    replicated where the ids vary, and its gradient — each rank's sorted
+    scatter-add — comes out summed over the ranks, the unsharded one's."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    rng = np.random.default_rng(9)
+    embed = jnp.asarray(rng.normal(size=(12, 4)), jnp.float32)
+    tokens = jnp.asarray(rng.integers(0, 12, size=(2, 8)), jnp.int32)
+    ct = jnp.asarray(rng.normal(size=(2, 8, 4)), jnp.float32)
+
+    def local(embed, tokens, ct):
+        def total(embed):
+            rows = moe.take_rows(moe.varying(embed, jax.typeof(tokens).vma), tokens.reshape(-1))
+            return jnp.sum(rows.reshape(ct.shape) * ct)
+        return jax.grad(total)(embed)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    got = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), P("dp"), P("dp")),
+                                out_specs=P(), check_vma=True))(embed, tokens, ct)
+    want = jnp.zeros_like(embed).at[tokens.reshape(-1)].add(ct.reshape(-1, 4))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)

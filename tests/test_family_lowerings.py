@@ -18,6 +18,7 @@ import functools
 import glob
 import hashlib
 import importlib.util
+import math
 import os
 import re
 
@@ -62,21 +63,29 @@ READERS = {"early_route_moe": "window_moe"}
 #: to a leaf).  ``ssm_moe``'s were taken when PR 51 wrote the family; the eleven
 #: above stood through the seam that PR opened in ``held_expert_apply`` and
 #: ``routed_mlp`` (an absent gate matrix is data: ``w_gate`` None, no
-#: ``e_gate`` | ``s_gate`` leaf).
+#: ``e_gate`` | ``s_gate`` leaf).  ISSUE 56 meant to move the five families'
+#: steps on ``moe_family.xent_sums`` and only those (the blocked loss takes a
+#: block's gradient while its logits stand, behind a ``custom_vjp``; and in
+#: ``early_route_moe`` alone a sliding layer's q | k products stay token-major
+#: for ``ops/head_norm.head_rope`` and the embedding's gather is
+#: ``parallel/moe.take_rows``): their ten digests, their ``lm_head`` counts and
+#: ``early_route_moe``'s ``embed`` and ``window_attention`` counts below were
+#: taken again on its tree; ``latent_moe``'s and ``bert``'s digests, every
+#: other count and all six ``FROZEN_PARAMETERS`` stood.
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     ("latent_moe", "float32"): "2f39501233c5fb12999aad5f6244e64071b14dda6ce394942f3ffbfe3e4ad237",
-    ("delta_moe", "float32"): "437ff0afc25fd4c9488d929566e981e8a131633cdb50a7a2f647b6a92a95c009",
-    ("conv_moe", "float32"): "95f2090fe5229e127f12ed6b1ea8617c4d6dcfa72e8d8882f5f9bd7ff93a5556",
-    ("window_moe", "float32"): "daf994c570bd0e34feff7e82530c81cd591b7f3ee9278f906a67de0bbdf427d3",
+    ("delta_moe", "float32"): "fe105203baafff63b7b15b833c1344c2d531268490ff242bd9115db8bedf4141",
+    ("conv_moe", "float32"): "3c848278b7f215d90da5af8be1453d60157b992152875c775e5cfd8127ac5ea6",
+    ("window_moe", "float32"): "5fa6c86a602cc5b537a9cee5e9dce944f6c84180fa3a0d986aad9c8d89ddfb5c",
     ("latent_moe", "bfloat16"): "da39e2ae36751672fca8343047e2e8a663c2bb73ef2db55a0bf153b15a600c57",
-    ("delta_moe", "bfloat16"): "b407658493f399ca07d5cc5550f69cd57e090804b42b4ff5d8f0c47db8b1820b",
-    ("conv_moe", "bfloat16"): "f49e110bb95eb7f469300f5a008e0271a50926ce4d48d5ac930c2b29d4c66dc3",
-    ("window_moe", "bfloat16"): "6e28ab1f925e5f3e429bfba4c578ac3253130e116416cb4fa10427a923d48bcf",
-    ("early_route_moe", "float32"): "a459dbbc96cd775e1555b0a2aba6b83b8ac111826972fc5eabfb690c37e0afb4",
-    ("early_route_moe", "bfloat16"): "f9c56cdea66713f8086887fc62d6d1f0698cb987a71c68729ebbed125766a931",
-    ("ssm_moe", "float32"): "fa3d5e3595281c57e3470e38db5e4f4e4152b822b4c64dfa062576af6a8f5d2d",
-    ("ssm_moe", "bfloat16"): "e85d034554c3199ee625d014e34a03b3c224e9dc1907aaadfb2662a840eb1cb0",
+    ("delta_moe", "bfloat16"): "07b0ccef01165ac7b18897dbd77e8761dccf20c2ad5c97f49685ab804bc069a9",
+    ("conv_moe", "bfloat16"): "cb18f95a6e3209e62567e45b5d5bb60c0590b4e58a14ccb5a7cdc7289adcf63f",
+    ("window_moe", "bfloat16"): "4454b585256577f84c672ad7f23299f90fe8cf25de2ee0f1ad3884d0713b9bfa",
+    ("early_route_moe", "float32"): "3ad9b4b5455c7129e73f776a03785f1dcdf59f349978b9d8985ef137a5b969b2",
+    ("early_route_moe", "bfloat16"): "606fbf2469a7d2d98009ea2d4af5aaa3c74e595dae7fe215bb6c60c402534b23",
+    ("ssm_moe", "float32"): "f56ad196d0485eacbb243acfe3880c4355be59dc388ea94bbd556ba5c31003c6",
+    ("ssm_moe", "bfloat16"): "4ecec2e060f947af1526a1e3295b9d7e51e51384f6a032be54fc84e576924a0b",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -108,7 +117,7 @@ FROZEN_SCOPE_OPERATIONS = {
                  "moe_route": 231, "moe_experts": 1488},
     "window_moe": {"window_attention": 1884, "global_attention": 456, "dense_mlp": 165,
                    "moe_route": 231, "shared_expert": 132, "moe_experts": 1947},
-    "early_route_moe": {"window_attention": 1248, "global_attention": 222, "moe_route": 412,
+    "early_route_moe": {"window_attention": 1254, "global_attention": 222, "moe_route": 412,
                         "moe_experts": 2104},
     "ssm_moe": {"ssd_scan": 960, "ssm_proj": 174, "nope16_attention": 221, "moe_route": 152,
                 "shared_expert": 64, "moe_experts": 936},
@@ -124,15 +133,18 @@ FROZEN_SCOPE_OPERATIONS = {
 #: learned positions and its scatter-add —, and ``dense_mlp`` in
 #: ``latent_moe`` (``conv_moe``'s and ``window_moe``'s stand above: their own
 #: readers file them).  Taken on ISSUE 54's tree; every digest, parameter and
-#: count above stood: the scopes moved locations, not text.
+#: count above stood: the scopes moved locations, not text.  The five
+#: ``xent_sums`` families' ``lm_head`` counts (62 → 53: one loop over the
+#: blocks, not two) and ``early_route_moe``'s ``embed`` (16 → 41: the sort and
+#: the sorted scatter-add of ``parallel/moe.add_rows``) are ISSUE 56's.
 FROZEN_REST_OPERATIONS = {
     "bert": {"lm_head": 111, "embed": 46},
-    "conv_moe": {"lm_head": 62, "embed": 17},
-    "delta_moe": {"lm_head": 62, "embed": 16},
-    "early_route_moe": {"lm_head": 62, "embed": 16},
+    "conv_moe": {"lm_head": 53, "embed": 17},
+    "delta_moe": {"lm_head": 53, "embed": 16},
+    "early_route_moe": {"lm_head": 53, "embed": 41},
     "latent_moe": {"lm_head": 319, "embed": 17, "dense_mlp": 106},
-    "ssm_moe": {"lm_head": 62, "embed": 16},
-    "window_moe": {"lm_head": 62, "embed": 20},
+    "ssm_moe": {"lm_head": 53, "embed": 16},
+    "window_moe": {"lm_head": 53, "embed": 20},
 }
 
 
@@ -314,3 +326,151 @@ def test_the_blocked_loss_is_the_unblocked_one(monkeypatch):
         jnp.sum(targets >= 0)) < 14
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The blocked loss takes its gradient on the way up (ISSUE 56)
+# ---------------------------------------------------------------------------
+
+
+def _plain_sum(cfg, logits, x, targets, scale, head):
+    """The unblocked loss: every row's logits at once."""
+    rows = logits(cfg, x, scale, head)
+    gold = jnp.take_along_axis(rows, jnp.maximum(targets, 0)[..., None], axis=-1)[..., 0]
+    return jnp.sum((jax.nn.logsumexp(rows, axis=-1) - gold) * (targets >= 0))
+
+
+def _checkpointed_sum(cfg, logits, x, targets, scale, head):
+    """The blocked form this tree's parent ran: a ``jax.checkpoint``ed block
+    under ``lax.map``, rebuilt in the backward pass."""
+    d = x.shape[-1]
+    block = math.gcd(x.size // d, mf.ROW_BLOCK)
+    one = jax.checkpoint(lambda xb, tb: _plain_sum(cfg, logits, xb, tb, scale, head))
+    return jnp.sum(jax.lax.map(lambda xt: one(*xt),
+                               (x.reshape(-1, block, d), targets.reshape(-1, block))))
+
+
+#: family → (the ``logits`` its ``local_loss`` hands ``xent_sums``, the leaf
+#: that is its head, whether that lies (model, vocabulary)): the five callers.
+#: ``latent_moe`` keeps its own blocked loss (its configuration states the
+#: logits' recomputation) and is not among them.
+HEADS = {"early_route_moe": (mf.row_logits, "head", False),
+         "ssm_moe": (mf.row_logits, "head", False),
+         "window_moe": (mf.row_logits, "head", False),
+         "conv_moe": (mf.row_logits, "embed", False),  # the tied embedding
+         "delta_moe": (delta_moe._logits, "head", True)}
+
+
+def _head_case(family: str, dtype: str, seed: int = 56):
+    """14 rows in blocks of gcd(14, 4) = 2: rows of both sequences ignored and
+    the third block (rows 4, 5) ignored whole.  x is rounded to ``dtype``
+    once, here; the scale and the head are f32 leaves, as a step's are."""
+    cfg = getattr(FAMILIES[family], f"tiny_{family}")(compute_dtype=jnp.dtype(dtype))
+    logits, leaf, model_major = HEADS[family]
+    assert cfg.layouts()[leaf][0] == ((cfg.d_model, cfg.vocab_size) if model_major else
+                                      (cfg.vocab_size, cfg.d_model))
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(2, 7, cfg.d_model)), cfg.compute_dtype)
+    scale = jnp.asarray(1 + 0.1 * rng.normal(size=cfg.d_model), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(cfg.vocab_size, cfg.d_model)) * cfg.d_model ** -0.5,
+                       jnp.float32)
+    targets = rng.integers(0, cfg.vocab_size, size=(2, 7))
+    targets[0, [0, 4, 5]] = targets[1, 6] = -1
+    return cfg, logits, x, jnp.asarray(targets, jnp.int32), scale, head.T if model_major else head
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 0.3], ids=["unit", "0.3_of_count"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", sorted(HEADS))
+def test_the_blocked_loss_takes_the_plain_gradient_on_the_way_up(monkeypatch, family, dtype,
+                                                                 cotangent):
+    """``jax.value_and_grad`` through ``xent_sums`` — each block's ``softmax −
+    onehot`` pulled back while its logits stand, the backward pass a scale —
+    against the same through the plain unblocked ``logsumexp − gold`` and
+    through the parent's checkpointed blocks: value, dx, d scale, d head, for
+    every caller's ``logits``, at a unit cotangent and at 0.3 / count.
+    float32: to 2e-6 of a leaf's largest entry.  bfloat16: the forms differ by
+    where a sum is rounded to bfloat16 (a block's dx and the rounding of
+    ``softmax − onehot`` before, not after, the cotangent's scale): 2⁻⁷."""
+    monkeypatch.setattr(mf, "ROW_BLOCK", 4)
+    cfg, logits, x, targets, scale, head = _head_case(family, dtype)
+    count = int(jnp.sum(targets >= 0))
+    assert count == 10 and not bool(jnp.any(targets.reshape(-1, 2)[2] >= 0))
+    weight = cotangent if cotangent == 1.0 else cotangent / count
+
+    def blocked(x, scale, head):
+        total, counted = mf.xent_sums(cfg, logits, x, targets, scale, head)
+        assert counted.dtype == jnp.float32
+        return total * weight
+
+    tol = 2e-6 if dtype == "float32" else 2.0 ** -7
+    loss, got = jax.value_and_grad(blocked, argnums=(0, 1, 2))(x, scale, head)
+    # differentiated or not, the value is the blocks' sums
+    np.testing.assert_allclose(loss, blocked(x, scale, head), rtol=1e-6)
+    for other in (_plain_sum, _checkpointed_sum):
+        want_loss, want = jax.value_and_grad(
+            lambda *at: other(cfg, logits, at[0], targets, *at[1:]) * weight,
+            argnums=(0, 1, 2))(x, scale, head)
+        np.testing.assert_allclose(loss, want_loss, rtol=2 * tol)
+        for name, a, b in zip(("x", "scale", "head"), got, want):
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            a, b = (np.asarray(v, np.float32) for v in (a, b))
+            assert np.abs(b).max() > 0
+            np.testing.assert_allclose(a, b, rtol=tol, atol=tol * np.abs(b).max(), err_msg=name)
+    # an ignored row's gradient is exactly nothing, a whole ignored block's too
+    assert not np.asarray(got[0], np.float32)[0, [0, 4, 5]].any()
+    assert not np.asarray(got[0], np.float32)[1, 6].any()
+
+
+@pytest.mark.parametrize("family", sorted(HEADS))
+def test_a_block_is_one_product_in_the_loss_and_three_with_its_gradient(family):
+    """The witness: the loss alone lowers to ONE product of the head's size in
+    its loop over the blocks; the loss with its gradient to THREE in that one
+    loop (the logits, dx, d head) and none after it — four stood here while a
+    block was rebuilt in the backward pass, in two loops."""
+    cfg, logits, x, targets, scale, head = _head_case(family, "bfloat16")
+
+    def loss(x, scale, head):
+        total, count = mf.xent_sums(cfg, logits, x, targets, scale, head)
+        return total / count
+
+    alone = jax.jit(loss).lower(x, scale, head).as_text()
+    both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(x, scale, head).as_text()
+    assert alone.count("stablehlo.dot_general") == 1
+    assert both.count("stablehlo.dot_general") == 3
+    assert alone.count("stablehlo.while") == both.count("stablehlo.while") == 1
+
+
+@pytest.mark.parametrize("family", ["early_route_moe", "delta_moe"])
+def test_the_blocked_loss_sums_its_gradients_over_the_ranks(monkeypatch, family):
+    """Under ``shard_map`` on two devices, a sequence each: the head and the
+    final norm's scale are replicated where x varies, and their gradients come
+    out summed over the ranks (one ``psum`` after the blocks, the transpose of
+    the cast that typed them as varying) — the unsharded loss's; dx stays each
+    rank's own."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    monkeypatch.setattr(mf, "ROW_BLOCK", 4)
+    cfg, logits, x, targets, scale, head = _head_case(family, "float32")
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+
+    def local(x, targets, scale, head):
+        def total(x, scale, head):
+            return mf.xent_sums(cfg, logits, x, targets, scale, head)[0]
+
+        loss, grads = jax.value_and_grad(total, argnums=(0, 1, 2))(x, scale, head)
+        return jax.lax.psum(loss, "dp"), grads
+
+    sharded = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("dp"), P("dp"), P(), P()),
+        out_specs=(P(), (P("dp"), P(), P())), check_vma=True))
+    loss, got = sharded(x, targets, scale, head)
+    want_loss, want = jax.value_and_grad(
+        lambda *at: _plain_sum(cfg, logits, at[0], targets, *at[1:]), argnums=(0, 1, 2))(
+            x, scale, head)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for name, a, b in zip(("x", "scale", "head"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6 * float(jnp.abs(b).max()),
+                                   err_msg=name)
+    text = sharded.lower(x, targets, scale, head).as_text()
+    assert text.count("stablehlo.all_reduce") == 3  # the loss, d scale, d head: none a block

@@ -552,6 +552,135 @@ class TestHeadNormRope:
         assert _kernel_names(grad, small, jax.ShapeDtypeStruct((64,), jnp.float32)) == []
 
 
+class TestHeadRope:
+    """``ops/head_norm.head_rope``: the rotation alone, one pass each way —
+    a token-major product's heads turned over the whole head and written
+    head-major — held to ``moe_family.rope_partial`` at ``rotary_dim`` = the
+    head (the pass the early-routed family's sliding mixers made), XLA's form
+    and the kernels (interpret mode) alike."""
+
+    @staticmethod
+    def _old(x, d, theta):
+        from byteps_tpu.models.moe_family import rope_partial
+
+        b, s, f = x.shape
+        return rope_partial(jnp.swapaxes(x.reshape(b, s, f // d, d), 1, 2), d, theta)
+
+    @staticmethod
+    def _inputs(b, s, h, d, dtype=jnp.float32, seed=56):
+        rng = np.random.default_rng(seed)
+        x = jnp.asarray(rng.normal(size=(b, s, h * d)).astype(np.float32) * 1.7, dtype)
+        ct = jnp.asarray(rng.normal(size=(b, h, s, d)).astype(np.float32), dtype)
+        return x, ct
+
+    # (B, S, H, d, theta, interpret): heads of a lane tile take the kernels
+    # under the interpreter (one block and several a sequence, seven heads a
+    # group as the cell's), anything else XLA's form
+    @pytest.mark.parametrize("b,s,h,d,theta,interpret", [
+        (2, 256, 3, 128, 10000.0, True), (1, 2048, 2, 128, 1.5e6, True),
+        (2, 256, 7, 128, 1.5e6, True), (1, 2048, 2, 256, 500000.0, True),
+        (2, 256, 3, 128, 10000.0, False), (2, 16, 7, 8, 1.5e6, False),
+        (2, 16, 1, 8, 1.5e6, False), (1, 24, 2, 6, 10000.0, True),
+    ])
+    def test_matches_rope_partial_and_its_gradient(self, b, s, h, d, theta, interpret):
+        """f32 in: the output (B, H, S, d) and dx (B, S, H·d) are
+        ``rope_partial``'s at the whole head to 1e-6."""
+        from byteps_tpu.ops import head_norm as hn
+
+        assert hn._kernel_path(s, d, interpret) == (interpret and d % 128 == 0)
+        x, ct = self._inputs(b, s, h, d)
+
+        def run(f):
+            y, pull = jax.vjp(f, x)
+            return y, pull(ct)[0]
+
+        got = run(lambda x: hn.head_rope(x, d, theta, interpret=interpret))
+        want = run(lambda x: self._old(x, d, theta))
+        assert got[0].shape == (b, h, s, d) and got[1].shape == x.shape
+        for name, g, r in zip(("out", "dx"), got, want):
+            assert g.shape == r.shape and g.dtype == r.dtype
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0,
+                                       atol=1e-6 * float(jnp.max(jnp.abs(r))), err_msg=name)
+
+    @pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+    def test_bf16_in_is_f32_arithmetic_rounded_once(self, interpret):
+        """bf16 in and out: the tables and the products are f32 and the
+        result is rounded once, both ways — ``rope_partial``'s own, to the
+        bit on XLA's form and to half a unit in the last place by the
+        kernels."""
+        from byteps_tpu.ops import head_norm as hn
+
+        x, ct = self._inputs(1, 256, 2, 128, jnp.bfloat16)
+        exact = self._old(x.astype(jnp.float32), 128, 1.5e6)
+        y, pull = jax.vjp(lambda x: hn.head_rope(x, 128, 1.5e6, interpret=interpret), x)
+        (dx,) = pull(ct)
+        assert y.dtype == dx.dtype == jnp.bfloat16
+        off = np.abs(np.asarray(y, np.float32) - np.asarray(exact))
+        assert np.all(off <= np.abs(np.asarray(exact)) * 2.0 ** -8 + 1e-30)
+        _, old_pull = jax.vjp(lambda x: self._old(x, 128, 1.5e6), x)
+        old = np.asarray(old_pull(ct)[0], np.float32)
+        assert np.all(np.abs(np.asarray(dx, np.float32) - old) <= np.abs(old) * 2.0 ** -7 + 1e-30)
+        if not interpret:
+            assert np.array_equal(np.asarray(y, np.float32),
+                                  np.asarray(self._old(x, 128, 1.5e6), np.float32))
+
+    def test_the_backward_pass_keeps_nothing(self):
+        """The rotation's transpose is the rotation by the negative angle:
+        the forward rule's residuals are empty, and turning forth then back
+        gives x again."""
+        from byteps_tpu.ops import head_norm as hn
+
+        x, _ = self._inputs(1, 16, 2, 8)
+        _, kept = hn._rope_fwd(x, 8, 1e4, False, False)
+        assert kept is None
+        back = hn._turned(hn._turned(x, 8, 1e4, False, False, False), 8, 1e4, True, False, False)
+        np.testing.assert_allclose(back, x, atol=1e-5)
+
+    @pytest.mark.parametrize("f,d", [(21, 7), (24, 16)], ids=["odd_head", "no_whole_heads"])
+    def test_columns_that_are_no_paired_heads_are_refused(self, f, d):
+        from byteps_tpu.ops import head_norm as hn
+
+        with pytest.raises(ValueError, match="no heads of"):
+            hn.head_rope(jnp.ones((1, 4, f)), d, 10000.0)
+
+    def test_a_traced_call_is_counted_by_its_path(self, monkeypatch):
+        """``head_rope_kernel_traces`` | ``head_rope_xla_traces``: one count a
+        traced call, by what ``_kernel_path`` chose — a step that fell back to
+        XLA's form can be told from the registry."""
+        from byteps_tpu.core.telemetry import counters
+        from byteps_tpu.ops import head_norm as hn
+
+        def grown(run):
+            before = counters().snapshot()
+            run()
+            after = counters().snapshot()
+            return tuple(after.get(k, 0) - before.get(k, 0)
+                         for k in ("head_rope_kernel_traces", "head_rope_xla_traces"))
+
+        x, _ = self._inputs(1, 256, 2, 128)
+        assert grown(lambda: hn.head_rope(x, 128, 1e4)) == (0, 1)  # no TPU here
+        assert grown(lambda: hn.head_rope(x, 128, 1e4, interpret=True)) == (1, 0)
+        step = jax.jit(jax.grad(lambda x: jnp.sum(hn.head_rope(x, 128, 1e4))))
+        assert grown(lambda: (step(x), step(x))) == (0, 1)  # traced once, run twice
+        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+        small = jax.ShapeDtypeStruct((1, 256, 2 * 64), jnp.bfloat16)
+        assert grown(lambda: jax.eval_shape(lambda x: hn.head_rope(x, 64, 1e4), small)) == (0, 1)
+
+    def test_the_kernels_are_the_program_where_heads_tile(self, monkeypatch):
+        """On a TPU at heads of a lane tile both passes are one Pallas call
+        each — ``head_rope_fwd``, ``head_rope_bwd`` —, read from the traced
+        gradient; at a head that does not tile, none (``_kernel_path``, the
+        one chooser the normed pass has)."""
+        from byteps_tpu.ops import head_norm as hn
+
+        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+        grad = jax.grad(lambda x: jnp.sum(hn.head_rope(x, 128, 1.5e6).astype(jnp.float32)))
+        x = jax.ShapeDtypeStruct((2, 2048, 7 * 128), jnp.bfloat16)
+        assert _kernel_names(grad, x) == [hn.ROPE_BWD_KERNEL, hn.ROPE_FWD_KERNEL]
+        grad64 = jax.grad(lambda x: jnp.sum(hn.head_rope(x, 64, 1.5e6).astype(jnp.float32)))
+        assert _kernel_names(grad64, jax.ShapeDtypeStruct((2, 2048, 7 * 64), jnp.bfloat16)) == []
+
+
 class TestOneBitDevice:
     # a block multiple; the engine's default partition (BYTEPS_PARTITION_BYTES
     # / 4, NOT a block multiple: padded on the device); a ragged tail

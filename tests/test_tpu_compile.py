@@ -523,6 +523,67 @@ def test_attention_mixer_moves_each_tensor_once_at_published_widths(one_chip, no
     assert _named_bytes(text) < ceiling * 2**30 < parent * 2**30
 
 
+def test_early_routed_window_mixer_turns_each_head_once_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch):
+    """``early_route_moe._mixer_part``'s gradient (no recomputation) for 2 x
+    16 384 tokens at SmallThinker's widths (2560 → 28 | 4 heads of 128), a
+    sliding layer: rope over the whole head is one kernel each way for q and
+    for k (``head_rope_fwd`` | ``head_rope_bwd``, ``ops/head_norm.head_rope``)
+    where ``moe_family.rope_partial`` was XLA's.  The q | k products stand
+    token-major, heads side by side (``bf16[2,16384,3584]`` | ``…,512]``: what
+    the forward kernel reads and the backward one writes), the banded flash
+    kernels take q ``bf16[56,16384,128]`` and K, V ``bf16[8,16384,128]``
+    straight from the pass; under ``window_attention`` nothing concatenates,
+    slices or broadcasts 64 MB or more (``rope_partial``'s two half heads and
+    their concatenation, both ways), no f32 array of 256 MB is written (its
+    f32 copy of q) other than the forward kernel's logsumexp on 128 lanes and
+    XLA's copy of it to take lane 0, and the one copy of 64 MB is dO's, which
+    the output projection's transpose writes sequence-minor (the parent's
+    too; ISSUE 56, the rule-seven check trinity's mixer has above).  The
+    bytes the top-level operations outside the kernels name: **13.94 GiB**
+    with ``rope_partial`` in ``head_rope``'s place (same tree, same case),
+    **8.01** now, held under 8.5."""
+    from byteps_tpu.models import early_route_moe as er
+    from byteps_tpu.ops import head_norm as hn
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    cfg = er.EarlyRouteMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    b, s, scope = 2, cfg.max_seq, er.SCOPES["win"]
+    assert (s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (16384, 28, 4, 128)
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in er.stacks(cfg)["win"][1].items()}
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(er._mixer_part(cfg, x, lp, "win").x.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), x, lp).as_text()
+    for kernel in (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL):
+        assert _kernel_operands(text, kernel)[:3] == [
+            "bf16[56,16384,128]", "bf16[8,16384,128]", "bf16[8,16384,128]"]
+    ops = _top_level(text)
+    types = {o[0]: re.sub(r"\{.*", "", o[2].strip()) for o in ops}
+    calls = lambda kernel: [o for o in ops if o[4] and o[0].startswith(kernel)]  # noqa: E731
+    wide = ["bf16[2,16384,3584]", "bf16[2,16384,512]"]  # q's and k's heads side by side
+    assert sorted(types[o[3][0]] for o in calls(hn.ROPE_FWD_KERNEL)) == wide
+    assert sorted(types[o[0]] for o in calls(hn.ROPE_BWD_KERNEL)) == wide
+    lse = {o[0] for o in calls(fa.FWD_WIN_KERNEL)}
+    assert len(lse) == 1 and len(calls(fa.BWD_WIN_KERNEL)) == 1
+    assert "rope_partial" not in text
+    for name, opcode, result, _, kernel, op_name in ops:
+        size = _bytes_of(result)
+        if kernel or scope not in op_name or lse & set(_sources(ops, name)):
+            continue
+        moved = size >= 64 * 2**20 and (
+            opcode in ("broadcast", "transpose", "concatenate", "slice")
+            or re.search(r"/(concatenate|slice|split)$", op_name)
+            or opcode == "copy" and not op_name.endswith("bhsk,hkd->bsd/transpose"))
+        assert not moved, f"{name}: {opcode} of {result} under {scope}"
+        assert not ("f32[" in result and size >= 256 * 2**20), f"{name}: {result} under {scope}"
+    assert _named_bytes(text) < 8.5 * 2**30 < 13.94 * 2**30
+
+
 # module → (GiB the parent's module names outside its kernels, GiB this one may)
 _LATENT_MIXER_BYTES = {"forward": (4.05, 2.7), "gradient": (9.99, 7.5)}
 
