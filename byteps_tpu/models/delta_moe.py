@@ -39,7 +39,7 @@ from byteps_tpu.parallel.moe import ROUTING_STATS, softmax_topk_route
 
 
 @dataclasses.dataclass(frozen=True)
-class DeltaMoEConfig(mf.Family):
+class DeltaMoEConfig(mf.ExpertFamily):
     vocab_size: int = 151936  # rows of the vocabulary held here
     d_model: int = 2048
     n_layers: int = 48

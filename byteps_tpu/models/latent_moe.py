@@ -34,7 +34,7 @@ from byteps_tpu.parallel.moe import ROUTING_STATS, sigmoid_topk_route
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentMoEConfig(mf.Family):
+class LatentMoEConfig(mf.ExpertFamily):
     vocab_size: int = 129280  # rows of the vocabulary held here
     d_model: int = 2048
     n_heads: int = 32

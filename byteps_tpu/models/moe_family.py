@@ -1,29 +1,35 @@
-"""What the mixture-of-experts families behind ``build_train_step`` share —
+"""What the families behind ``build_train_step`` that are no
+``TransformerConfig`` share — the six mixture-of-experts families
 ``latent_moe``, ``delta_moe``, ``conv_moe``, ``window_moe``,
-``early_route_moe``, ``ssm_moe`` — each decision written once.
+``early_route_moe``, ``ssm_moe``, and ``looped_dense``, which has no experts —
+each decision written once.
 
-A device holds the experts ``[expert_lo, expert_lo + experts_held)`` of every
-expert layer and the first ``vocab_size`` rows of the vocabulary: its share of
-a deployment in which several devices share each layer.  The router scores
-all ``n_experts``; what the experts held elsewhere would add is left out
+A device of an expert family (:class:`ExpertFamily`) holds the experts
+``[expert_lo, expert_lo + experts_held)`` of every expert layer and the first
+``vocab_size`` rows of the vocabulary: its share of a deployment in which
+several devices share each layer.  The router scores all ``n_experts``; what
+the experts held elsewhere would add is left out
 (``parallel/moe.held_expert_mlp``).
 
 A family is a module.  ``transformer.build_train_step`` / ``build_forward``
 take its config as they take a ``TransformerConfig``: a frozen dataclass that
-says ``class XConfig(Family)`` (or :class:`PatternedFamily`, where
-``layer_types`` lists the layers one by one) and names itself (``family``,
-``lacks``); beside it the module holds ``layouts(cfg)``, ``local_loss(cfg,
-mesh, params, tokens, targets)`` and ``local_logits(cfg, params, tokens)``,
-and the base answers with them.  What a family writes itself is its fields,
-its shapes, its mixers, how its layers are stacked and run, and one table of
-rules for :func:`init_params`; what it takes from here is the norm, the
-SwiGLU, the short convolution and the rotary embedding, the held experts' MLP
-with its scopes, the blocked cross-entropy, the sums over the ranks, and the
-walk over listed layers.
+says ``class XConfig(Family)`` (:class:`ExpertFamily` where it holds experts,
+or :class:`PatternedFamily`, where ``layer_types`` lists the layers one by
+one) and names itself (``family``, ``lacks``); beside it the module holds
+``layouts(cfg)``, ``local_loss(cfg, mesh, params, tokens, targets)`` — the
+loss and what the step counts, by name — and ``local_logits(cfg, params,
+tokens)``, and the base answers with them.  What a family writes itself is
+its fields, its shapes, its mixers, how its layers are stacked and run, and
+one table of rules for :func:`init_params`; what it takes from here is the
+norm, the SwiGLU, the short convolution and the rotary embedding, the held
+experts' MLP with its scopes, the blocked cross-entropy (with a weight a row
+where the family's loss has one), the sums over the ranks, and the walk over
+listed layers.
 
 Nothing here branches on which family calls: what differs between them comes
-in as data (``1 + w`` or ``w``, a router, a scope's name, a logits function,
-a table).  No ``*_moe`` module imports another; all import this one.
+in as data (``1 + w`` or ``w``, a router, a scope's name, a logits function
+and what it reads, a weight a row or none, a table).  No family's module
+imports another; all import this one.
 """
 
 from __future__ import annotations
@@ -54,19 +60,15 @@ ROW_BLOCK = 2048
 
 
 class Family:
-    """Base of a family's config.  It reads the fields every family has
-    (``expert_lo``, ``experts_held``, ``n_experts``) and the family's module
-    (see the module's docstring)."""
+    """Base of a family's config: it reads the family's module (see the
+    module's docstring) and no field but those its checks are asked about —
+    a family without experts is a family (``models/looped_dense.py``)."""
 
-    #: the words of :meth:`validate_mesh`'s refusal: the family's name, and
-    #: what beside data parallelism is not built for it
+    #: the words of :meth:`validate_mesh`'s refusal: the family's name, what
+    #: kind of family it is, and what beside data parallelism is not built
+    #: for it
     family = lacks = ""
-
-    def __post_init__(self):
-        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
-            raise ValueError(
-                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
-                f"lie outside the router's {self.n_experts}")
+    kind = "family"
 
     def _check_grouped_heads(self):
         if self.n_heads % self.n_kv_heads:
@@ -86,7 +88,7 @@ class Family:
         for ax in ("pp", "sp", "tp"):
             if mesh.shape.get(ax, 1) != 1:
                 raise ValueError(
-                    f"the {self.family} MoE family runs data-parallel only: mesh has "
+                    f"the {self.family} {self.kind} runs data-parallel only: mesh has "
                     f"{ax}={mesh.shape[ax]} (no {self.lacks} is built for it yet)")
 
     def local_loss(self, mesh: Mesh, params, tokens, targets):
@@ -97,7 +99,20 @@ class Family:
         return self._module().local_logits(self, params, tokens)[None]
 
 
-class PatternedFamily(Family):
+class ExpertFamily(Family):
+    """Base of a family with expert layers: it reads the fields each of them
+    has (``expert_lo``, ``experts_held``, ``n_experts``)."""
+
+    kind = "MoE family"
+
+    def __post_init__(self):
+        if not 0 <= self.expert_lo <= self.n_experts - self.experts_held:
+            raise ValueError(
+                f"held experts [{self.expert_lo}, {self.expert_lo + self.experts_held}) "
+                f"lie outside the router's {self.n_experts}")
+
+
+class PatternedFamily(ExpertFamily):
     """A family whose layers are static data: ``layer_types[i]`` names layer
     i's mixer, and the first ``n_dense_layers`` layers' MLP is dense, the
     others' routed (stacks ``dense``, ``moe``).  A family whose layer is ONE
@@ -409,66 +424,92 @@ def _varying_as(tree, *like):
     return jax.tree.map(lambda leaf: varying(leaf, axes), tree)
 
 
-def _block_loss(rows, tb):
-    """One block's masked sum of token cross-entropies from its f32 logits,
-    and the logsumexp it took."""
+def _block_loss(rows, tb, wb=None):
+    """One block's masked sum of token cross-entropies from its f32 logits —
+    each row's weighed by ``wb`` where there is one —, the logsumexp it took,
+    and the rows' cross-entropies (0 where the target is ignored)."""
     lse = jax.nn.logsumexp(rows, axis=-1)
     gold = jnp.take_along_axis(rows, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
-    return jnp.sum((lse - gold) * (tb >= 0)), lse
+    each = (lse - gold) * (tb >= 0)
+    return jnp.sum(each if wb is None else each * wb), lse, each
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _blocked_xent(cfg, logits: Callable, xs, ts, scale, head):
+def _blocked_xent(cfg, logits: Callable, xs, ts, ws, reads):
     """The sum of token cross-entropies over blocks of rows ``xs`` (n, block,
-    D), ``ts`` (n, block); undifferentiated, the plain blocked sum."""
-    return jnp.sum(lax.map(lambda xt: _block_loss(logits(cfg, xt[0], scale, head), xt[1])[0],
-                           (xs, ts)))
+    D), ``ts`` (n, block) — each row's weighed by ``ws`` (n, block) f32 where
+    that is not None; ``reads`` is what ``logits`` reads beside a block.
+    Undifferentiated, the plain blocked sum."""
+    return jnp.sum(lax.map(
+        lambda xtw: _block_loss(logits(cfg, xtw[0], *reads), *xtw[1:])[0], (xs, ts, ws)))
 
 
-def _blocked_xent_up(cfg, logits: Callable, xs, ts, scale, head):
+def _blocked_xent_up(cfg, logits: Callable, xs, ts, ws, reads):
     """Differentiated, a block's gradient is taken where its logits stand:
-    ``softmax − onehot`` over the counted rows, f32, pulled back through the
-    family's ``logits`` at once — dx of the block written, the scale's and the
-    head's gradients summed in f32 across the blocks.  Three products a
-    block; nothing of a block is rebuilt on the way down."""
+    ``softmax − onehot`` over the counted rows — times the row's weight —,
+    f32, pulled back through the family's ``logits`` at once: dx of the block
+    written, the gradients of what ``logits`` reads (a final norm's scale, the
+    head) summed in f32 across the blocks.  Three products a block; nothing
+    of a block is rebuilt on the way down.  A weight's gradient is its row's
+    cross-entropy, kept for the way down."""
 
-    def one(sums, xt):
-        xb, tb = xt
-        rows, pull = jax.vjp(functools.partial(logits, cfg), xb, scale, head)
-        loss, lse = _block_loss(rows, tb)
+    def one(sums, xtw):
+        xb, tb, wb = xtw
+        rows, pull = jax.vjp(functools.partial(logits, cfg), xb, *reads)
+        loss, lse, each = _block_loss(rows, tb, wb)
         counted = (tb >= 0)[:, None]
         hot = jnp.arange(rows.shape[-1], dtype=tb.dtype) == tb[:, None]
-        dxb, *into = pull(jnp.where(counted, jnp.exp(rows - lse[:, None]) - hot, 0.0))
-        return tuple(s + d.astype(jnp.float32) for s, d in zip(sums, into)), (loss, dxb)
+        slope = jnp.where(counted, jnp.exp(rows - lse[:, None]) - hot, 0.0)
+        dxb, *into = pull(slope if wb is None else slope * wb[:, None])
+        return (tuple(s + d.astype(jnp.float32) for s, d in zip(sums, into)),
+                (loss, dxb, None if wb is None else each))
 
-    sums = tuple(jnp.zeros_like(w, jnp.float32) for w in (scale, head))
-    sums, (losses, dxs) = lax.scan(one, _varying_as(sums, xs, scale, head), (xs, ts))
-    return jnp.sum(losses), (dxs, *(s.astype(w.dtype) for s, w in zip(sums, (scale, head))))
+    sums = tuple(jnp.zeros_like(w, jnp.float32) for w in reads)
+    sums, (losses, dxs, each) = lax.scan(one, _varying_as(sums, xs, *reads), (xs, ts, ws))
+    return jnp.sum(losses), (dxs, each, *(s.astype(w.dtype) for s, w in zip(sums, reads)))
 
 
 def _blocked_xent_down(cfg, logits, kept, ct):
-    dxs, d_scale, d_head = ((ct * g).astype(g.dtype) for g in kept)
-    return dxs, None, d_scale, d_head
+    dxs, each, *into = kept
+    dxs, *into = ((ct * g).astype(g.dtype) for g in (dxs, *into))
+    return dxs, None, None if each is None else ct * each, tuple(into)
 
 
 _blocked_xent.defvjp(_blocked_xent_up, _blocked_xent_down)
 
 
-def xent_sums(cfg, logits: Callable, x, targets, scale, head):
-    """(sum of token cross-entropies, tokens counted); targets < 0 are
-    ignored.  ``logits(cfg, x, scale, head)`` is the family's.  A block of
-    rows at a time: the (B·S, V) logits never stand whole, and under
-    differentiation each block's gradient is taken while its logits stand
-    (:func:`_blocked_xent_up`), so no block is built twice."""
+def _xent_blocks(cfg, logits: Callable, x, targets, weights, reads):
+    """:func:`_blocked_xent` on x (..., D) cut into blocks of rows, under
+    ``lm_head``."""
     d = x.shape[-1]
     block = math.gcd(x.size // d, ROW_BLOCK)
     with jax.named_scope("lm_head"):
-        # under shard_map the scale and the head are replicated and x varies:
-        # their cotangents are summed over x's axes by this cast's transpose
-        scale, head = _varying_as((scale, head), x)
-        total = _blocked_xent(cfg, logits, x.reshape(-1, block, d), targets.reshape(-1, block),
-                              scale, head)
+        # under shard_map what the logits read is replicated and x varies: the
+        # cotangents are summed over x's axes by this cast's transpose
+        reads = _varying_as(reads, x)
+        return _blocked_xent(cfg, logits, x.reshape(-1, block, d), targets.reshape(-1, block),
+                             None if weights is None else weights.reshape(-1, block), reads)
+
+
+def xent_sums(cfg, logits: Callable, x, targets, *reads):
+    """(sum of token cross-entropies, tokens counted); targets < 0 are
+    ignored.  ``logits(cfg, x, *reads)`` is the family's (``reads``: a final
+    norm's scale and the head).  A block of rows at a time: the (B·S, V)
+    logits never stand whole, and under differentiation each block's gradient
+    is taken while its logits stand (:func:`_blocked_xent_up`), so no block
+    is built twice."""
+    total = _xent_blocks(cfg, logits, x, targets, None, reads)
+    with jax.named_scope("lm_head"):
         return total, jnp.sum(targets >= 0).astype(jnp.float32)
+
+
+def weighted_xent(cfg, logits: Callable, x, targets, weights, *reads):
+    """:func:`xent_sums` with a weight a row: ``Σ weight · cross-entropy``
+    over the counted rows.  The one blocked implementation: the weights go
+    in, so a block's gradient is still taken while its logits stand, and they
+    may be learned — a weight's gradient is its row's cross-entropy (0 where
+    the target is ignored)."""
+    return _xent_blocks(cfg, logits, x, targets, weights.astype(jnp.float32), reads)
 
 
 def over_ranks(*sums):

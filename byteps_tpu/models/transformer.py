@@ -973,7 +973,8 @@ def build_train_step(
     the sharded views under GSPMD propagation outside the shard_map.
 
     The loss is the model family's (``cfg.local_loss``), and so is what it
-    counts beside it (a latent-attention MoE's routing statistics): the
+    counts beside it (an MoE family's routing statistics, the looped dense
+    family's layer passes and mean exit step): the
     counts leave the compiled step with the loss and reach the process's
     counters (``parallel/moe.RoutingCounters``) without a blocking read.
     The returned step has the compiled function's ``lower``.

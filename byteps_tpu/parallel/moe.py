@@ -411,7 +411,8 @@ def held_expert_apply(g: jax.Array, plan: HeldPlan, weights: jax.Array,
 
 class RoutingCounters:
     """What compiled steps count (name → int32 scalar: the ROUTING_STATS of
-    the expert layers), added to the process's counters
+    the expert layers, or whatever a family without experts counts — any
+    name a step's ``local_loss`` returns), added to the process's counters
     (``bps.get_robustness_counters()``) without a blocking read:
     :meth:`push` keeps a step's device arrays and folds in only what is
     ready; a snapshot of the counters waits for the rest."""

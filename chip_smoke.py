@@ -666,6 +666,8 @@ def gradient_readings(got: dict, want: dict) -> dict:
     rest = [k for k in apart if k not in routed]
 
     def worst(keys, value):
+        if not keys:  # a family without experts has no routed leaf
+            return None
         k = max(keys, key=value)
         return [k, value(k)]
 

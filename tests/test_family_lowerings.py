@@ -28,15 +28,16 @@ import numpy as np
 import optax
 import pytest
 
-from byteps_tpu.models import (conv_moe, delta_moe, early_route_moe, latent_moe, ssm_moe,
-                               window_moe)
+from byteps_tpu.models import (conv_moe, delta_moe, early_route_moe, latent_moe, looped_dense,
+                               ssm_moe, window_moe)
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
-            "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe}
+            "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe,
+            "looped_dense": looped_dense}
 #: family → the reader of benchmark/readers/ its cell's metrics go through,
 #: where that is not one of its own name
 READERS = {"early_route_moe": "window_moe"}
@@ -71,7 +72,12 @@ READERS = {"early_route_moe": "window_moe"}
 #: ``parallel/moe.take_rows``): their ten digests, their ``lm_head`` counts and
 #: ``early_route_moe``'s ``embed`` and ``window_attention`` counts below were
 #: taken again on its tree; ``latent_moe``'s and ``bert``'s digests, every
-#: other count and all six ``FROZEN_PARAMETERS`` stood.
+#: other count and all six ``FROZEN_PARAMETERS`` stood.  ``looped_dense``'s were
+#: taken when ISSUE 57 wrote the family — the first without experts; all
+#: thirteen above, every count and all six ``FROZEN_PARAMETERS`` stood through
+#: what that PR did to ``moe_family``: ``Family`` | ``ExpertFamily``, and ONE
+#: blocked loss under ``xent_sums`` and ``weighted_xent`` (a weight a row is
+#: data: ``ws`` None, no product with it; what the logits read is a tuple).
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     ("latent_moe", "float32"): "2f39501233c5fb12999aad5f6244e64071b14dda6ce394942f3ffbfe3e4ad237",
@@ -86,6 +92,8 @@ FROZEN_LOWERINGS = {
     ("early_route_moe", "bfloat16"): "606fbf2469a7d2d98009ea2d4af5aaa3c74e595dae7fe215bb6c60c402534b23",
     ("ssm_moe", "float32"): "f56ad196d0485eacbb243acfe3880c4355be59dc388ea94bbd556ba5c31003c6",
     ("ssm_moe", "bfloat16"): "4ecec2e060f947af1526a1e3295b9d7e51e51384f6a032be54fc84e576924a0b",
+    ("looped_dense", "float32"): "8f77ef7cd0b04aa67647a10ba8b7aef4b656ff5aab136dcee546c5ebabcd3915",
+    ("looped_dense", "bfloat16"): "b4715038548ff6df5a843859cbce33e92e88f5735e974d20a5a0dbb0057324cd",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -97,6 +105,7 @@ FROZEN_PARAMETERS = {
     "window_moe": "3d45bbea9a827d634d53bc91e00efbd1c80f4f58fcc242b9d196fc2d1b06a46e",
     "early_route_moe": "4185b5dc3604eb74127f4bad08f21f9bc5d63374e23957bc4be2b2751ced5698",
     "ssm_moe": "f490c4e980eef04431d28a3f03db0f49592603f5f02f9153b547ad90a9220f01",
+    "looped_dense": "0b4e0693777702fb56a74903a1a7c62bdf5c74d861676882e23cca3fff028e97",
 }
 
 #: family → scope → operations of the bfloat16 step filed under it.  The scopes
@@ -121,6 +130,10 @@ FROZEN_SCOPE_OPERATIONS = {
                         "moe_experts": 2104},
     "ssm_moe": {"ssd_scan": 960, "ssm_proj": 174, "nope16_attention": 221, "moe_route": 152,
                 "shared_expert": 64, "moe_experts": 936},
+    # the looped family's four scopes; ``loop_heads`` holds the blocked loss's
+    # ``lm_head`` inside it.  What stands under ``loop_steps`` and under none of
+    # them — the reader's ``loop_carry`` — and ``embed`` are held below
+    "looped_dense": {"exit_gate": 126, "loop_heads": 109, "loop_attention": 470, "loop_mlp": 163},
 }
 
 #: family → scope → operations of the step (bfloat16; ``bert``'s float32 one)
@@ -255,9 +268,39 @@ def test_the_head_the_loss_and_the_embedding_stand_under_scopes(family):
 # ---------------------------------------------------------------------------
 
 
+#: operations of ``looped_dense``'s bfloat16 step under ``loop_steps`` and none
+#: of the scopes inside it, and under ``embed``
+LOOP_CARRY_OPERATIONS, LOOPED_EMBED_OPERATIONS = 73, 39
+
+
+def test_the_looped_family_files_its_loop_and_its_embedding():
+    """Beside its four scopes: every operation under ``loop_steps`` is read
+    under one of the reader's five names (``loop_carry`` where no scope inside
+    the loop holds it), ``embed`` is outside the loop, and the blocked loss's
+    ``lm_head`` is nowhere but inside ``loop_heads``."""
+    reader = _reader("looped_dense")
+    text = _lowered("looped_dense", "bfloat16").as_text(debug_info=True)
+    paths = dict(_NAMED_LOC.findall(text))
+    filed = {}
+    for loc in _OPERATION.findall(text):
+        path = paths.get(loc, "")
+        name = reader.scope_of(path)
+        filed[name] = filed.get(name, 0) + 1
+        parts = path.split("/")
+        assert ("lm_head" not in parts) or name == "loop_heads", path
+        assert ("embed" not in parts) or (name is None and reader.LOOP not in parts), path
+        assert (reader.LOOP not in parts) or name is not None, path
+    assert {k: n for k, n in filed.items() if k} == {
+        **FROZEN_SCOPE_OPERATIONS["looped_dense"], reader.CARRY: LOOP_CARRY_OPERATIONS}
+    assert scope_operations(text, ("embed",)) == {"embed": LOOPED_EMBED_OPERATIONS}
+
+
 def test_no_family_imports_another():
-    """Every ``*_moe`` module leans on ``moe_family`` and on no sibling."""
-    for path in glob.glob(os.path.join(REPO, "byteps_tpu", "models", "*_moe.py")):
+    """Every ``*_moe`` module — and the looped dense family — leans on
+    ``moe_family`` and on no sibling."""
+    models = os.path.join(REPO, "byteps_tpu", "models")
+    for path in glob.glob(os.path.join(models, "*_moe.py")) + [
+            os.path.join(models, "looped_dense.py")]:
         imported = set()
         with open(path) as source:
             tree = ast.parse(source.read())

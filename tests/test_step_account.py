@@ -211,8 +211,16 @@ def test_a_collection_between_two_calls_shows_in_gc_s(capsys):
     step(x)
     (evidence,) = slow_lines(capsys, step=11)
     assert evidence["gc_s"] > 0 and evidence["gc_n"] >= 1
-    assert evidence["where"] == "caller" and evidence["cpu_thread_s"] >= evidence["gc_s"] * 0.5
+    # what the recorder counted, each inside the next on ONE clock: the pause
+    # lies in the caller's own time, and that in the interval.  (Not the
+    # thread's CPU time against the pause: under six xdist workers the
+    # collector is off the CPU for most of its wall time, and the ratio of
+    # the two clocks says how loaded the machine is, not where the step went.)
+    assert evidence["where"] == "caller"
+    assert evidence["gc_s"] <= evidence["caller_s"] <= evidence["interval_s"]
+    assert evidence["caller_s"] > max(evidence["dispatch_s"], evidence["fold_s"])
     assert hist("gc_pause_seconds", generation="2")["count"] >= 1
+    assert hist("gc_pause_seconds", generation="2")["sum"] > 0
 
 
 def test_a_second_slow_step_inside_the_rate_limit_is_counted_and_prints_nothing(capsys, recorder):

@@ -230,6 +230,70 @@ def test_flash_kernels_compile_at_sixteen_query_heads_a_key_value_head(one_chip,
     assert not re.search(r"bf16\[2,2,16,8192,128\]\S* broadcast\(", text)
 
 
+def test_flash_kernels_compile_at_sixteen_heads_each_with_its_own_keys(one_chip, no_compile_cache,
+                                                                      monkeypatch):
+    """(1, 16 | 16, 8192, 128 | 128) — the looped dense family's attention:
+    the kernel's plainest shape, every query head its own key/value head,
+    the full causal pair with the blocks ops/flash_blocks.json commits for
+    8192."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=128 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q).as_text()
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+        assert _kernel_operands(text, kernel)[:3] == ["bf16[16,8192,128]"] * 3
+
+
+def test_looped_dense_step_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch):
+    """Ouro-2.6B's widths, 6 layers run 4 times over 1 x 8192 tokens, adamw,
+    bf16 operands: the whole train step of ``build_train_step`` — the scan
+    over the loop steps around the scan over the layers, the rotary passes
+    and the flash kernels inside both, the four heads as one blocked loss, the
+    exit gate — for one described chip.  What it keeps for the backward pass
+    stays what the family's docstring says: a (loop step, layer) the layer's
+    f32 input and the flash kernel's output and row statistics, the layer
+    rebuilt whole (with the input of the MLP part kept too the temporaries
+    are 12.75 GiB), so they stay under 10.7 GiB by the compiler's count
+    (10.45)."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.models import looped_dense as ld
+    from byteps_tpu.models.transformer import build_train_step
+    from byteps_tpu.parallel.mesh_utils import make_training_mesh
+
+    hn = importlib.import_module("byteps_tpu.ops.head_norm")
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    cfg = ld.LoopedDenseConfig(n_layers=6, compute_dtype=jnp.bfloat16)
+    assert (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_loops, cfg.max_seq) == (
+        2048, 16, 128, 5632, 4, 8192)
+    mesh = make_training_mesh(1, {"dp": 1, "pp": 1, "sp": 1, "tp": 1},
+                              devices=[one_chip._device])
+    held = NamedSharding(mesh, P())
+    params = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=held)
+              for k, (s, _, _) in cfg.layouts().items()}
+    tokens = jax.ShapeDtypeStruct((1, cfg.max_seq), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp", "sp")))
+    tx = optax.adamw(1e-6)
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=held),
+                         jax.eval_shape(tx.init, params))
+    compiled = build_train_step(cfg, mesh, tx).lower(params, state, tokens, tokens).compile()
+    text = compiled.as_text()
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL, "head_rope_fwd", "head_rope_bwd"):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+    memory = compiled.memory_analysis()
+    held_bytes = 12 * sum(math.prod(s) for s, _, _ in cfg.layouts().values())
+    # parameters and adamw's two moments (+ its step count, the tokens, padding)
+    assert held_bytes <= memory.argument_size_in_bytes < held_bytes + 2**20
+    assert memory.temp_size_in_bytes < 10.7 * 2**30
+
+
 def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
     """2 x 8192 tokens, 64 heads of 64 with a 64 x 128 state, B and C in 8
     groups of 128, chunks of 128, bf16 operands: a Mamba-2 layer whole —

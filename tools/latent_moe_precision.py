@@ -1,5 +1,7 @@
 #!/usr/bin/env python3
-"""Where the limits on the six MoE families' precision come from.
+"""Where the limits on the precision of the families behind ``build_train_step``
+come from: the six MoE families', and the looped dense family's (no experts:
+no routed leaf among the gradient's readings, no ``--pin``).
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
     python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
@@ -7,6 +9,7 @@
     python tools/latent_moe_precision.py --config trinity_mini_ep16 --seeds ...
     python tools/latent_moe_precision.py --config smallthinker_21b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config nemotron_twotower_30b_ep16 --seeds ...
+    python tools/latent_moe_precision.py --config ouro_2_6b_pp8 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -21,8 +24,13 @@ it), on the TPU:
             of the program, to show that the control reads like it
   below     the same with the norms' statistics, the router's scores and
             weights and the softmax (and, where the configuration has a
-            state-space scan, its step sizes, decay sums and states) in bf16:
-            the nearest precision below, which ``correct`` has to refuse
+            state-space scan, its step sizes, decay sums and states; where it
+            has an exit gate, the gate, its distribution and entropy; where
+            it states a float32 residual stream, that) in bf16: the nearest
+            precision below, which ``correct`` has to refuse
+  below_stream | below_statistics   where the builder's ``plain_loss`` takes a
+            ``stream`` dtype: the two steps between ``stated`` and ``below``,
+            the stream alone and the statistics alone in bf16
 
 and against f32, as ``benchmark/run.py`` and leg E read them: the largest
 relative distance of the three losses (``reference_rtol``); the parameters
@@ -38,6 +46,7 @@ from __future__ import annotations
 import argparse
 import functools
 import importlib.util
+import inspect
 import json
 import math
 import os
@@ -123,6 +132,12 @@ def main() -> int:
     runs = {"program": program_steps,
             "stated": plain_steps(builder.plain_loss(cfg, jnp.bfloat16, jnp.float32)),
             "below": plain_steps(builder.plain_loss(cfg, jnp.bfloat16, jnp.bfloat16))}
+    if "stream" in inspect.signature(builder.plain_loss).parameters:
+        # a family that states a float32 residual stream: "below" has stream
+        # and statistics in bf16 together; these are the two steps between
+        for name, dtypes in (("below_stream", (jnp.float32, jnp.bfloat16)),
+                             ("below_statistics", (jnp.bfloat16, jnp.float32))):
+            runs[name] = plain_steps(builder.plain_loss(cfg, jnp.bfloat16, *dtypes))
     reference = plain_steps(builder.plain_loss(cfg))
 
     @jax.jit
