@@ -15,8 +15,9 @@ scale, untied head, no position table.
 A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
 says one is (the share of experts and vocabulary this device holds, the
 protocol, what the families share).  The stack is one ``lax.scan`` over the
-periods, every mixer and every MLP in it rebuilt in the backward pass.  The
-plain reference is ``models/delta_moe_reference.py``.
+periods, every mixer and every MLP in it rebuilt in the backward pass but for
+what a mixer's costliest kernel wrote (``_layer_parts``).  The plain reference
+is ``models/delta_moe_reference.py``.
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ from jax.sharding import Mesh
 
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import causal_conv, rope_partial
+from byteps_tpu.ops import gated_delta_kernels
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.gated_delta import CHUNK, chunked_gated_delta_rule
 from byteps_tpu.parallel.moe import ROUTING_STATS, softmax_topk_route
@@ -314,9 +316,9 @@ def _mlp(cfg: DeltaMoEConfig, x, lp):
     return x + y.reshape(b, s, d).astype(x.dtype), stats
 
 
-def _hidden(cfg: DeltaMoEConfig, params, tokens):
-    """tokens (B, S) → the stack's output before the final norm, and the
-    routing stats summed over the layers."""
+def _layer_parts(cfg: DeltaMoEConfig):
+    """(the linear mixer, the attention mixer, the MLP) as a layer runs them:
+    the mixers with their residual, ``(x, lp) → x``; the MLP → (x, stats)."""
     def residual(mixer):
         return lambda x, lp: x + mixer(cfg, x, lp).astype(x.dtype)
 
@@ -325,11 +327,23 @@ def _hidden(cfg: DeltaMoEConfig, params, tokens):
     if cfg.remat:
         # a layer's mixer and its MLP are each rebuilt in the backward pass,
         # one at a time (a period's four layers at once do not fit beside the
-        # state at 16k tokens); of gated attention all but the kernel's
-        # output and row statistics, so that the forward kernel does not run
-        # twice
-        delta, mlp = jax.checkpoint(delta), jax.checkpoint(mlp)
+        # state at 16k tokens); of either mixer all but what its costliest
+        # kernel wrote, so that it does not run twice: gated attention's
+        # output and row statistics, the delta rule's triangular inverse
+        # (where the rule takes XLA's form nothing carries that name, and all
+        # of the mixer is rebuilt)
+        mlp = jax.checkpoint(mlp)
+        delta = jax.checkpoint(
+            delta, policy=jax.checkpoint_policies.save_only_these_names(
+                *gated_delta_kernels.SAVED))
         attention = jax.checkpoint(attention, policy=mf.keep_flash())
+    return delta, attention, mlp
+
+
+def _hidden(cfg: DeltaMoEConfig, params, tokens):
+    """tokens (B, S) → the stack's output before the final norm, and the
+    routing stats summed over the layers."""
+    delta, attention, mlp = _layer_parts(cfg)
 
     def period(x, lps):
         stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)

@@ -15,13 +15,22 @@ stacked to the MXU's 128 rows (two chunks of 64 form one block-diagonal
 chunks [sequential]); the d_k × d_v f32 states of a key head's value heads
 stay in VMEM scratch across the chunk axis.  A chunk:
 ``U = T β (V − e^γ ∘ K S)``, ``o = e^γ ∘ (Q S) + (D ∘ Q Kᵀ) U``,
-``S ← e^{γ_C} S + Kᵀ (e^{γ_C − γ} ∘ U)``.  Writes o and, for the backward
-pass, the chunk's entering state in the compute dtype.
+``S ← e^{γ_C} S + Kᵀ (e^{γ_C − γ} ∘ U)``.  Writes o and, where the rule is
+differentiated, the chunk's entering state in the compute dtype.
 
 ``gdn_scan_bwd`` — the same walk from the last chunk to the first carrying
 dS, a chunk's forward rebuilt from T and its entering state; the inverse's
 rule ``dA = −Tᵀ dT Tᵀ`` at f32 accuracy; gradients for q, k (summed over
 their value heads), v, g and β.
+
+The ``custom_vjp``'s forward rule gives T the name in ``SAVED``, so a caller
+that rebuilds its layer in the backward pass (``jax.checkpoint``) can keep it
+by a policy and run the inverse, the costliest of the three, once —
+``models/delta_moe._layer_parts`` does, as the attention parts keep the flash
+kernel's output and row statistics.  The entering states and o carry no name:
+inside a ``lax.scan`` over layers a kept array is copied into the scan's stack
+and out again, and for those two the copies cost more than ``gdn_scan_fwd``
+(measured: PERF.md §6, PR 58).
 
 The operands are token-major, as the projections around the rule write and
 read them: q, k ``(B, S, H_k·d_k)``, v, o and their cotangents
@@ -45,12 +54,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from byteps_tpu.ops.flash_attention import _vma_union as _vma
 
 #: the kernels' names: a trace files their time under these (none starts
 #: with ``flash_``: the benchmark's readers take such calls for flash kernels)
 INVERSE_KERNEL, FWD_KERNEL, BWD_KERNEL = "gdn_chunk_inverse", "gdn_scan_fwd", "gdn_scan_bwd"
+
+#: the triangular inverse carries this name wherever the rule is
+#: differentiated: a ``jax.checkpoint`` whose policy saves it
+#: (``save_only_these_names(*SAVED)``) does not run ``gdn_chunk_inverse`` again
+#: in its backward pass, as ``flash_attention.SAVED``
+SAVED = ("gdn_inverse",)
 
 #: rows of the MXU: chunks are stacked to this many for the inverse
 STACK = 128
@@ -392,7 +408,9 @@ def _rule(q, k, v, g, beta, hk, chunk, blocks, interpret):
 
 
 def _rule_fwd(q, k, v, g, beta, hk, chunk, blocks, interpret):
-    t = _chunk_inverse(k, g, beta, hk, chunk, blocks[0], interpret)
+    # T takes its name before the walk reads it: a rebuilt walk reads the array
+    # it was traced with, and an unnamed one brings the inverse back to feed it
+    t = checkpoint_name(_chunk_inverse(k, g, beta, hk, chunk, blocks[0], interpret), SAVED[0])
     o, entering = _scan_forward(q, k, v, g, beta, t, hk, chunk, blocks[1], True, interpret)
     return o, (q, k, v, g, beta, t, entering)
 

@@ -10,6 +10,8 @@ reference: tests/test_delta_moe.py.  Two files so that ``--dist loadfile``
 spreads them.)
 """
 
+import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -26,6 +28,7 @@ from byteps_tpu.ops import gated_delta as gd
 from byteps_tpu.parallel import moe
 
 from test_delta_moe import _state, _worst
+from test_ops import _kernel_names
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -310,6 +313,50 @@ def test_the_token_major_mixer_is_the_head_major_one():
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_rebuilt_linear_layer_runs_the_inverse_once(monkeypatch, dtype):
+    """The gradient through the linear layer as ``_hidden`` rebuilds it
+    (``_layer_parts``: a ``jax.checkpoint`` that keeps, by name, the rule's
+    triangular inverse) holds one ``gdn_chunk_inverse``, two ``gdn_scan_fwd``
+    (the forward pass's and the rebuilt one, which reads the kept T) and one
+    ``gdn_scan_bwd``; with no policy, what the family had, the inverse twice
+    too.  The gradients of x and of every parameter are the same bit for bit:
+    the kept array is the one the rebuild would have written.  The kernels in
+    the interpreter: two chunks of 64, a key head of two value heads, the
+    state crossing a grid step."""
+    from byteps_tpu.ops import gated_delta_kernels as gk
+
+    monkeypatch.setattr(dm, "chunked_gated_delta_rule", functools.partial(
+        gd.chunked_gated_delta_rule, interpret=True, blocks=(2, 1, 1)))
+    cfg = dm.tiny_delta_moe(lin_k_heads=1, lin_v_heads=2, lin_k_dim=128, lin_v_dim=128,
+                            chunk=64, max_seq=128, compute_dtype=dtype)
+    assert cfg.remat
+    mixer = ("mixer_norm", "w_qkvz", "w_ba", "conv", "a_log", "dt_bias", "gdn_norm", "w_out")
+    ks = jax.random.split(jax.random.PRNGKey(3), len(mixer) + 1)
+    lp = {name: 0.3 * jax.random.normal(key, dm.layer_shapes(cfg)["lin"][name])
+          for name, key in zip(mixer, ks)}
+    x = jax.random.normal(ks[-1], (2, cfg.max_seq, cfg.d_model)).astype(dtype)
+    # the family's rebuilt layer, and the same layer under a checkpoint with no policy
+    layers = {"family": dm._layer_parts(cfg)[0],
+              "none": jax.checkpoint(dm._layer_parts(dataclasses.replace(cfg, remat=False))[0])}
+    inverses = {"family": 1, "none": 2}
+
+    def grad_of(layer):
+        return jax.grad(lambda x, lp: jnp.sum(jnp.sin(layer(x, lp).astype(jnp.float32))),
+                        argnums=(0, 1))
+
+    grads = {}
+    for policy, layer in layers.items():
+        assert _kernel_names(grad_of(layer), x, lp) == sorted(
+            [gk.INVERSE_KERNEL] * inverses[policy] + [gk.FWD_KERNEL] * 2 + [gk.BWD_KERNEL]), policy
+        grads[policy] = jax.jit(grad_of(layer))(x, lp)
+    (dx, dlp), (dx_none, dlp_none) = grads["family"], grads["none"]
+    np.testing.assert_array_equal(dx, dx_none)
+    for name in mixer:
+        assert float(jnp.abs(dlp[name]).max()) > 0, name  # every leaf is reached
+        np.testing.assert_array_equal(dlp[name], dlp_none[name], err_msg=name)
 
 
 def test_softmax_router_against_top_k_of_a_dense_softmax_with_planted_ties():

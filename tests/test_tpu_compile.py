@@ -9,6 +9,7 @@ process may load libtpu, and every xdist worker imports every test file.
 All such tests stay in THIS file, so one worker holds the library.
 """
 
+import dataclasses
 import functools
 import importlib
 import math
@@ -475,6 +476,41 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
         assert _relayouts_under(text, "gdn_scan", 64 * 2**20) == []
     assert gk.BWD_KERNEL in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+@pytest.mark.parametrize("policy, calls", [("family", (1, 2, 1)), ("none", (2, 2, 1))])
+def test_delta_layer_runs_the_inverse_once_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch, policy, calls):
+    """The gradient of a rebuilt linear layer (``x + _delta_mixer``, the
+    part ``delta_moe._layer_parts`` hands ``_hidden``'s scan) for one sequence
+    of 16 384 tokens at Qwen3-Next's widths: the compiled module calls
+    ``gdn_chunk_inverse`` once — the recomputation keeps T by name
+    (``gated_delta_kernels.SAVED``) —, ``gdn_scan_fwd`` twice and
+    ``gdn_scan_bwd`` once, where a ``jax.checkpoint`` with no policy, what the
+    family had, calls the inverse twice too.  The operands stay token-major
+    either way: no ``transpose`` and no layout-changing ``copy`` of 64 MB or
+    more under ``gdn_scan``."""
+    from byteps_tpu.models import delta_moe as dm
+    from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import gated_delta_kernels as gk
+
+    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    cfg = dm.DeltaMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    assert cfg.remat and cfg.max_seq == 16384
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in dm.layer_shapes(cfg)["lin"].items()}
+    x = jax.ShapeDtypeStruct((1, cfg.max_seq, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    layer = dm._layer_parts(cfg)[0] if policy == "family" else jax.checkpoint(
+        dm._layer_parts(dataclasses.replace(cfg, remat=False))[0])
+
+    def loss(x, lp):
+        return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), x, lp).as_text()
+    kernels = [op_name for *_, kernel, op_name in _top_level(text) if kernel]
+    assert tuple(sum(bool(re.search(rf"\b{name}\b", op_name)) for op_name in kernels)
+                 for name in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL)) == calls
+    assert _relayouts_under(text, "gdn_scan", 64 * 2**20) == []
 
 
 _ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
