@@ -10,6 +10,11 @@ build the two pieces worth owning are:
   the next batch's H2D transfer overlaps the current step (the D2H/H2D
   overlap the reference builds with CUDA copy streams, done here with
   jax async dispatch).
+- :func:`block_diffusion_noise` — the noising of block-diffusion training
+  (``models/block_diffusion_moe.py``): a noise level a block, a mask token
+  where a coin at that level says so, and the loss weight ``1 / t`` that
+  goes with it.  Jittable: a job runs it in its input pipeline, on the
+  device, once a step.
 """
 
 from __future__ import annotations
@@ -18,6 +23,7 @@ import collections
 from typing import Any, Iterable, Iterator, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -113,3 +119,29 @@ def prefetch_to_device(
         except StopIteration:
             pass
         yield out
+
+
+def block_diffusion_noise(key: jax.Array, tokens: jax.Array, block_length: int, mask_id: int,
+                          lo: float, hi: float) -> tuple:
+    """Block diffusion's forward process under the linear schedule ``α_t = 1 −
+    t``: for each sequence of ``tokens`` (..., L) and each block of
+    ``block_length`` tokens draw ``t_b ~ U[lo, hi]``; each token of the block
+    becomes ``mask_id`` independently with probability ``t_b``.  Returns
+    ``(x_t, weights)``: the noised copy (``tokens``' dtype) and the f32 loss
+    weights, ``1 / t_b`` where the token was masked and 0 elsewhere — the
+    weight of the objective's ``−α'_t / (1 − α_t) = 1 / t``.  ``U(0, 1]`` is
+    the unclipped objective; a clipped range (``lo`` > 0) bounds the weights
+    at ``1 / lo``, which is what small blocks are trained under.  ``tokens``
+    are the targets as they are: ``mask_id`` is a row of the vocabulary that
+    data never holds."""
+    if not 0.0 < lo <= hi <= 1.0:
+        raise ValueError(f"noise levels U[{lo}, {hi}] lie outside (0, 1]")
+    length = tokens.shape[-1]
+    if length % block_length:
+        raise ValueError(f"blocks of {block_length} do not tile a sequence of {length}")
+    k_level, k_coin = jax.random.split(key)
+    blocks = tokens.shape[:-1] + (length // block_length,)
+    t = jnp.repeat(jax.random.uniform(k_level, blocks, jnp.float32, lo, hi), block_length, axis=-1)
+    masked = jax.random.uniform(k_coin, tokens.shape, jnp.float32) < t
+    return (jnp.where(masked, jnp.asarray(mask_id, tokens.dtype), tokens),
+            jnp.where(masked, 1.0 / t, 0.0))

@@ -977,12 +977,18 @@ def build_train_step(
     family's layer passes and mean exit step): the
     counts leave the compiled step with the loss and reach the process's
     counters (``parallel/moe.RoutingCounters``) without a blocking read.
+    A family may DECLARE further leaves of the batch (``cfg.batch_leaves``,
+    names; the block-diffusion family's ``("weights",)``, a f32 loss weight a
+    token): the step then takes them after ``targets``, each (batch, seq) and
+    sharded as the rows are, and hands them to ``cfg.local_loss`` in order.
+    A family that declares none lowers to the text it always did.
     The returned step has the compiled function's ``lower``.
     """
     validate_mesh(cfg, mesh)
     specs = param_specs(cfg)
+    leaves = tuple(getattr(cfg, "batch_leaves", ()))
 
-    def loss_and_grad(params, tokens, targets):
+    def loss_and_grad(params, tokens, targets, *more):
         # With VMA checking on, shard_map AD handles gradient sync itself:
         # cotangents of replicated (invariant-typed) params are psum'd over
         # exactly the axes they're replicated on — the DistributedOptimizer
@@ -990,22 +996,25 @@ def build_train_step(
         def forward(p):
             # the backward pass reads transpose(jvp(forward)) in a trace
             with jax.named_scope("forward"):
-                return cfg.local_loss(mesh, p, tokens, targets)
+                return cfg.local_loss(mesh, p, tokens, targets, *more)
 
         return jax.value_and_grad(forward, has_aux=True)(params)
 
     shmapped = jax.shard_map(
         loss_and_grad,
         mesh=mesh,
-        in_specs=(specs, P("dp", "sp"), P("dp", "sp")),
+        in_specs=(specs, P("dp", "sp"), P("dp", "sp")) + (P("dp", "sp"),) * len(leaves),
         out_specs=((P(), P()), specs),
         check_vma=True,
     )
 
     # the name is the trace's module line (jit_train_step) and part of the
     # compile cache's key, which ignores scopes (see optim.py)
-    def train_step(params, opt_state, tokens, targets):
-        (loss, counts), grads = shmapped(params, tokens, targets)
+    def train_step(params, opt_state, tokens, targets, *more):
+        if len(more) != len(leaves):
+            raise TypeError(f"the step's batch is {('tokens', 'targets') + leaves}: "
+                            f"{2 + len(more)} leaves given")
+        (loss, counts), grads = shmapped(params, tokens, targets, *more)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

@@ -60,6 +60,25 @@ their own kernel names (``flash_fwd_win``, ``flash_bwd_win``) and their own
 table of blocks, keyed by (sequence, window).  ``window=None`` is the code
 above, unchanged.
 
+The block-diffusion mask (:func:`block_diffusion_attention`): the keys are two
+copies of one sequence of ``L`` tokens, a noised copy in rows ``[0, L)`` and
+the clean copy in rows ``[L, 2L)``, and with ``blk(i) = (i mod L) // B`` a
+NOISY query sees the noisy keys of its own block (both directions) and the
+clean keys of every earlier block; a CLEAN query sees the clean keys of its own
+and every earlier block; nothing else.  ``L² + L·B`` entries over ``2L`` rows
+in a shape that is neither causal nor a band, so the pair of kernels
+(``flash_fwd_bd``, ``flash_bwd_bd``) walks a TABLE of tiles: which key tiles a
+query tile meets, in ascending order, how many, and which of them are visible
+whole (no mask is computed there) — made from the shapes alone
+(:func:`_bd_tiles`), handed to the kernels as scalars before the grid runs, and
+read by the index maps, so a tile that holds no visible entry (the whole
+clean-query × noisy-key quadrant; all of noisy × noisy but its diagonal) is
+neither computed, nor fetched, nor stepped over.  The innermost grid axis is
+as long as the longest row of the table; the steps past a row's end repeat its
+last tile and run nothing.  The queries may be the noisy half alone
+(``L`` rows against ``2L`` keys: what a last layer's loss reads).  dQ is
+written as the banded kernel writes it: the running sum at every pair.
+
 This is the per-device compute of the transformer's attention; sequence
 parallelism composes on top (ring attention rotates KV blocks *between*
 devices, these kernels handle the blocks *within* one device).
@@ -97,6 +116,8 @@ SAVED = ("flash_out", "flash_lse")
 FWD_KERNEL, BWD_KERNEL = "flash_fwd", "flash_bwd"
 #: and of a banded call's (``window=``): filed apart whatever scope path is kept
 FWD_WIN_KERNEL, BWD_WIN_KERNEL = "flash_fwd_win", "flash_bwd_win"
+#: and of a block-diffusion call's (:func:`block_diffusion_attention`)
+FWD_BD_KERNEL, BWD_BD_KERNEL = "flash_fwd_bd", "flash_bwd_bd"
 
 
 def _dense_reference(q, k, v, causal, scale, window=None):
@@ -576,17 +597,19 @@ _tuned_cache: Optional[dict] = None
 
 
 def _tuned_table() -> dict:
-    """The artifact's two tables: ``blocks`` by sequence, ``banded`` by
-    (sequence, window); each empty where the file has none that reads."""
+    """The artifact's three tables: ``blocks`` by sequence, ``banded`` by
+    (sequence, window), ``block_diffusion`` by (key rows, block length); each
+    empty where the file has none that reads."""
     global _tuned_cache
     if _tuned_cache is None:
-        _tuned_cache = {"blocks": {}, "banded": {}}
+        _tuned_cache = {"blocks": {}, "banded": {}, "block_diffusion": {}}
         try:
             with open(_TUNED_PATH) as f:
                 doc = json.load(f)
             _tuned_cache["blocks"] = {int(k): tuple(v) for k, v in doc["blocks"].items()}
-            _tuned_cache["banded"] = {tuple(int(x) for x in k.split(",")): tuple(v)
-                                      for k, v in doc.get("banded", {}).items()}
+            for table in ("banded", "block_diffusion"):
+                _tuned_cache[table] = {tuple(int(x) for x in k.split(",")): tuple(v)
+                                       for k, v in doc.get(table, {}).items()}
         except (OSError, ValueError, KeyError, TypeError, AttributeError):
             pass
     return _tuned_cache
@@ -713,3 +736,413 @@ def flash_attention(
     if not _kernel_path(q.shape[2], bq, bk, interpret):
         return _dense_reference(q, k, v, causal, scale, window)
     return _flash(q, k, v, causal, scale, bq, bk, interpret, window)
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion mask: a noised copy and the clean copy of one sequence
+# ---------------------------------------------------------------------------
+
+
+def _block_of(x, block: int):
+    """``x // block`` of non-negative ints, a shift where ``block`` is a power
+    of two (what a kernel's vector unit has)."""
+    return x >> (block.bit_length() - 1) if block & (block - 1) == 0 else x // block
+
+
+def block_diffusion_visible(rows, cols, half: int, block: int):
+    """Whether query row ``rows`` sees key row ``cols`` (ints that broadcast,
+    numpy's or jax's): rows ``[0, half)`` are the noised copy and ``[half,
+    2 half)`` the clean one, ``blk(i) = (i mod half) // block``, and a noisy
+    query sees the noisy keys of its own block and the clean keys of every
+    earlier block; a clean query the clean keys of its own and every earlier
+    block.  Written so that what is two-dimensional is three operations: the
+    two cases of the key's half are folded into what is compared."""
+    nr, nc = rows < half, cols < half
+    br = _block_of(rows - half * (1 - nr), block)
+    bc = _block_of(cols - half * (1 - nc), block)
+    # noisy key: the same block, of a noisy query (−1 | −2 never meet);
+    # clean key: a block the query's is past, or has reached if it is clean
+    same = (br * nr - (1 - nr)) == (bc * nc - 2 * (1 - nc))
+    reached = (br - nr) >= (bc + nc * (1 << 30))
+    return same | reached
+
+
+def _dense_block_diffusion_lse(q, k, v, block, scale):
+    """Dense (out, lse) under the block-diffusion mask, from one (s_q, 2L)
+    score matrix a head; key/value heads repeated for their groups."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    sq, sk = q.shape[2], k.shape[2]
+    seen = block_diffusion_visible(jnp.arange(sq)[:, None], jnp.arange(sk)[None, :],
+                                   sk // 2, block)
+    s32 = jnp.where(seen, jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale,
+                    NEG_INF).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(s32, axis=-1)
+    p = jnp.exp(s32 - lse[..., None]).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v), lse
+
+
+@functools.lru_cache(maxsize=None)
+def _bd_tiles(sq: int, half: int, block: int, bq: int, bk: int) -> dict:
+    """The table of tiles of a block-diffusion call, from the shapes alone
+    (numpy, int32).  ``kv_of`` (nq · steps_f,): query tile ``qi``'s key tiles
+    in ascending order at ``[qi · steps_f, …)``, ``n_kv`` (nq,) of them, the
+    rest of the row its last one again; ``kv_whole`` beside it: 1 where every
+    entry of the pair is visible.  ``q_of``, ``n_q``, ``q_whole`` the same by
+    key tile, for the backward kernel; ``first_kv`` (nq,) the first key tile a
+    query tile meets.  A pair is listed iff it holds a visible entry —
+    decided from the tiles' ranges of blocks in each half, not entry by
+    entry — and every tile of either side is listed somewhere (a key tile no
+    query sees would leave the backward kernel a block it never writes)."""
+    import numpy as np
+
+    def halves(lo, n):  # rows [lo, lo + n) → ranges of blocks (noisy, clean), or None
+        hi = lo + n - 1
+        noisy = (lo // block, min(hi, half - 1) // block) if lo < half else None
+        clean = ((max(lo, half) - half) // block, (hi - half) // block) if hi >= half else None
+        return noisy, clean
+
+    def any_all(r, c, rel):  # over a rectangle of block ranges r × c
+        if r is None or c is None:
+            return False, True  # empty: nothing visible, nothing hidden
+        if rel == "same":
+            return r[0] <= c[1] and c[0] <= r[1], r[0] == r[1] == c[0] == c[1]
+        if rel == "past":
+            return r[1] > c[0], r[0] > c[1]
+        if rel == "reached":
+            return r[1] >= c[0], r[0] >= c[1]
+        return False, False  # a clean query and a noisy key
+
+    nq, nk = sq // bq, 2 * half // bk
+    needed, whole = np.zeros((nq, nk), bool), np.zeros((nq, nk), bool)
+    for qi in range(nq):
+        rn, rc = halves(qi * bq, bq)
+        for j in range(nk):
+            cn, cc = halves(j * bk, bk)
+            parts = [any_all(rn, cn, "same"), any_all(rn, cc, "past"),
+                     any_all(rc, cn, "never"), any_all(rc, cc, "reached")]
+            needed[qi, j] = any(a for a, _ in parts)
+            whole[qi, j] = all(w for _, w in parts)
+    if not (needed.any(axis=1).all() and needed.any(axis=0).all()):
+        raise ValueError(f"flash attention: at tiles ({bq}, {bk}) of a block-diffusion call "
+                         f"({sq} queries, 2 x {half} keys, blocks of {block}) some tile meets "
+                         "no other; take larger tiles")
+
+    def rows_of(needed, whole):
+        steps = int(needed.sum(axis=1).max())
+        of = np.zeros((needed.shape[0], steps), np.int32)
+        full = np.zeros_like(of)
+        for i, row in enumerate(needed):
+            at = np.flatnonzero(row)
+            of[i, :len(at)], of[i, len(at):] = at, at[-1]
+            full[i, :len(at)] = whole[i, at]
+        return of.reshape(-1), needed.sum(axis=1).astype(np.int32), full.reshape(-1), steps
+
+    kv_of, n_kv, kv_whole, steps_f = rows_of(needed, whole)
+    q_of, n_q, q_whole, steps_b = rows_of(needed.T, whole.T)
+    return {"kv_of": kv_of, "n_kv": n_kv, "kv_whole": kv_whole, "steps_f": steps_f,
+            "q_of": q_of, "n_q": n_q, "q_whole": q_whole, "steps_b": steps_b,
+            "first_kv": kv_of.reshape(nq, steps_f)[:, 0].copy(), "pairs": int(needed.sum())}
+
+
+def _bd_keep(qi, j, bq: int, bk: int, half: int, block: int, keys_down: bool = False):
+    """Bool mask of a tile pair's visible positions: (bq, bk), or (bk, bq)
+    with the keys down; rows and columns are one vector each until compared."""
+    q_shape, k_shape, q_dim = ((1, bq), (bk, 1), 1) if keys_down else ((bq, 1), (1, bk), 0)
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_dim)
+    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_dim)
+    return block_diffusion_visible(rows, cols, half, block)
+
+
+def _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block):
+    from jax.experimental import pallas as pl
+
+    def kernel(kv_of, n_kv, kv_whole, q_ref, k_ref, v_ref, o_ref, lse_ref,
+               m_scr, l_scr, acc_scr):
+        qi, step = pl.program_id(1), pl.program_id(2)
+        at = qi * steps + step
+        j = kv_of[at]
+
+        @pl.when(step == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        def pair(masked: bool):
+            # a row with no visible key in a tile adds it at weight 1 under
+            # m = NEG_INF, which its first real maximum wipes: every row sees
+            # a key — a noisy one itself, a clean one the sequence's first
+            v = v_ref[0]
+            s = jax.lax.dot_general(
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            if masked:
+                s = jnp.where(_bd_keep(qi, j, bq, bk, half, block), s, NEG_INF)
+            m, l = m_scr[:], l_scr[:]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[:, 0:1])
+            m_scr[:] = m_new
+            l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        needed, whole = step < n_kv[qi], kv_whole[at] == 1
+        pl.when(needed & whole)(lambda: pair(False))
+        pl.when(needed & jnp.logical_not(whole))(lambda: pair(True))
+
+        @pl.when(step == steps - 1)
+        def _emit():
+            l = l_scr[:]
+            l = jnp.where(l == 0, 1.0, l)
+            o_ref[0] = (acc_scr[:] / l[:, 0:1]).astype(o_ref.dtype)
+            lse_ref[0] = m_scr[:] + jnp.log(l)
+
+    return kernel
+
+
+def _bd_scalars(tiles: dict, names: tuple, vma) -> tuple:
+    """The named tables as the kernels' scalar operands, typed as varying as
+    the tensors are (under ``shard_map``)."""
+    made = (jnp.asarray(tiles[n]) for n in names)
+    return tuple(jax.lax.pcast(x, tuple(vma), to="varying") if vma else x for x in made)
+
+
+def _bd_forward(q, k, v, block, scale, bq, bk, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    vma = _vma_union(q, k, v)
+    b, h, sq, dqk = q.shape
+    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    tiles = _bd_tiles(sq, sk // 2, block, bq, bk)
+    steps, group = tiles["steps_f"], h // h_kv
+    kv_index = lambda i, qi, t, kv_of, *_: (i // group, kv_of[qi * steps + t], 0)  # noqa: E731
+    q_index = lambda i, qi, t, *_: (i, qi, 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        _bd_fwd_kernel_factory(bq, bk, steps, scale, sk // 2, block),
+        out_shape=(
+            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32, vma=vma),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h, sq // bq, steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, dqk), q_index),
+                pl.BlockSpec((1, bk, dqk), kv_index),
+                pl.BlockSpec((1, bk, dv), kv_index),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, bq, dv), q_index),
+                pl.BlockSpec((1, bq, LANES), q_index),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name=FWD_BD_KERNEL,
+    )(*_bd_scalars(tiles, ("kv_of", "n_kv", "kv_whole"), vma),
+      q.reshape(b * h, sq, dqk), k.reshape(b * h_kv, sk, dqk), v.reshape(b * h_kv, sk, dv))
+    return out.reshape(b, h, sq, dv), lse
+
+
+def _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block):
+    from jax.experimental import pallas as pl
+
+    def kernel(q_of, n_q, q_whole, first_kv, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+        j, step = pl.program_id(1), pl.program_id(2)  # key tile; its step-th query tile
+        at = j * steps + step
+        qi = q_of[at]
+        rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query tile's of dq_scr
+
+        @pl.when(step == 0)
+        def _init_dkv():
+            dk_scr[:] = jnp.zeros_like(dk_scr)
+            dv_scr[:] = jnp.zeros_like(dv_scr)
+
+        def pair(masked: bool):
+            @pl.when(j == first_kv[qi])  # key tiles ascend: the first this query tile meets
+            def _clear_dq():
+                dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
+
+            # (bk, bq), keys down and queries across, as flash_bwd computes a pair
+            q, k, do = q_ref[0], k_ref[0], do_ref[0]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            pt = jnp.exp(st - lse_ref[0])
+            if masked:
+                pt = jnp.where(_bd_keep(qi, j, bq, bk, half, block, keys_down=True), pt, 0.0)
+            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dpt = jax.lax.dot_general(
+                v_ref[0], do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+            dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
+                dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
+                dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            # the running sum; a query tile's last pair writes it whole
+            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+
+        needed, whole = step < n_q[j], q_whole[at] == 1
+        pl.when(needed & whole)(lambda: pair(False))
+        pl.when(needed & jnp.logical_not(whole))(lambda: pair(True))
+
+        @pl.when(step == steps - 1)
+        def _emit_dkv():
+            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    return kernel
+
+
+def _bd_backward(q, k, v, o, lse, do, block, scale, bq, bk, interpret, dlse=None):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    vma = _vma_union(q, k, v, o, lse, do)
+    b, h, sq, dqk = q.shape
+    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    bh, group = b * h, h // h_kv
+    tiles = _bd_tiles(sq, sk // 2, block, bq, bk)
+    steps = tiles["steps_b"]
+    dof = do.reshape(bh, sq, dv)
+    delta = jnp.sum(dof.astype(jnp.float32) * o.reshape(bh, sq, dv).astype(jnp.float32), axis=-1)
+    if dlse is not None:  # as _flash_backward folds it
+        delta = delta - dlse.reshape(bh, sq).astype(jnp.float32)
+    delta, lse = delta.reshape(bh, 1, sq), lse.reshape(bh, 1, sq)
+
+    q_index = lambda i, j, t, q_of, *_: (i, q_of[j * steps + t], 0)  # noqa: E731
+    row_index = lambda i, j, t, q_of, *_: (i, 0, q_of[j * steps + t])  # noqa: E731
+    kv_index = lambda i, j, t, *_: (i, j, 0)  # noqa: E731
+    kv_read = lambda i, j, t, *_: (i // group, j, 0)  # noqa: E731
+    dq, dk, dv_ = pl.pallas_call(
+        _bd_bwd_kernel_factory(bq, bk, steps, scale, sk // 2, block),
+        out_shape=(
+            jax.ShapeDtypeStruct((bh, sq, dqk), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, sk, dqk), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype, vma=vma),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, sk // bk, steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, dqk), q_index),
+                pl.BlockSpec((1, bk, dqk), kv_read),
+                pl.BlockSpec((1, bk, dv), kv_read),
+                pl.BlockSpec((1, bq, dv), q_index),
+                pl.BlockSpec((1, 1, bq), row_index),
+                pl.BlockSpec((1, 1, bq), row_index),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, bq, dqk), q_index),
+                pl.BlockSpec((1, bk, dqk), kv_index),
+                pl.BlockSpec((1, bk, dv), kv_index),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((sq, dqk), jnp.float32),
+                pltpu.VMEM((bk, dqk), jnp.float32),
+                pltpu.VMEM((bk, dv), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(sq, bq, bk, dqk, dv, q.dtype.itemsize),
+        ),
+        interpret=interpret,
+        name=BWD_BD_KERNEL,
+    )(*_bd_scalars(tiles, ("q_of", "n_q", "q_whole", "first_kv"), vma),
+      q.reshape(bh, sq, dqk), k.reshape(b * h_kv, sk, dqk), v.reshape(b * h_kv, sk, dv),
+      dof, lse, delta)
+    if group > 1:
+        dk, dv_ = (jnp.sum(x.reshape(b, h_kv, group, sk, x.shape[-1]), axis=2) for x in (dk, dv_))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _bd_flash(q, k, v, block, scale, bq, bk, interpret):
+    out, lse = _bd_forward(q, k, v, block, scale, bq, bk, interpret)
+    return out, lse[..., 0].reshape(q.shape[:3])
+
+
+def _bd_flash_fwd(q, k, v, block, scale, bq, bk, interpret):
+    out, lse, res = _residuals(q, k, v, *_bd_forward(q, k, v, block, scale, bq, bk, interpret))
+    return (out, lse.reshape(q.shape[:3])), res
+
+
+def _bd_flash_bwd(block, scale, bq, bk, interpret, res, g):
+    q, k, v, o, lse = res
+    return _bd_backward(q, k, v, o, lse, g[0], block, scale, bq, bk, interpret, dlse=g[1])
+
+
+_bd_flash.defvjp(_bd_flash_fwd, _bd_flash_bwd)
+
+
+def tuned_block_diffusion_blocks(keys: int, block_length: int, queries: int) -> tuple:
+    """(block_q, block_k) of a block-diffusion call over ``keys`` = 2L key rows:
+    the artifact's ``block_diffusion`` entry for (keys, block length) where it
+    has one whose tiles divide the queries and the keys, else the half's plain
+    entry (:func:`tuned_blocks`, which divides L and so both)."""
+    entry = _tuned_table()["block_diffusion"].get((keys, block_length))
+    if entry and queries % entry[0] == 0 and keys % entry[1] == 0:
+        return entry
+    return tuned_blocks(keys // 2)
+
+
+def block_diffusion_attention_lse(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    block_length: int,
+    scale: Optional[float] = None,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: bool = False,
+) -> tuple:
+    """Attention over a noised and a clean copy of one sequence under the
+    block-diffusion mask (:func:`block_diffusion_visible`; the module's
+    docstring): k (B, H_kv, 2L, d_qk), v (B, H_kv, 2L, d_v), rows ``[0, L)``
+    the noised copy's and ``[L, 2L)`` the clean copy's, each copy's row ``i``
+    at position ``i`` (the caller's rope); q (B, H, 2L, d_qk), both copies'
+    queries, or (B, H, L, d_qk), the noised copy's alone.  ``block_length``
+    divides L.  Returns (out (B, H, rows of q, d_v), the per-row logsumexp
+    (B, H, rows of q)), differentiable in q, k, v through both.  Heads group
+    as :func:`flash_attention`'s.  Kernels ``flash_fwd_bd`` | ``flash_bwd_bd``
+    on a TPU, the dense mask elsewhere (:func:`_kernel_path`)."""
+    sq, sk = q.shape[2], k.shape[2]
+    if sk % 2 or sq not in (sk, sk // 2) or block_length < 1 or (sk // 2) % block_length:
+        raise ValueError(f"block diffusion: {sk} key rows are two copies of L tokens, the "
+                         f"{sq} queries both copies' or the first's, and the block length "
+                         f"{block_length} divides L")
+    scale = _resolve(q, k, v, scale, None, None)[0]  # the head counts' check, the default scale
+    tq, tk = tuned_block_diffusion_blocks(sk, block_length, sq)
+    bq = min(block_q if block_q is not None else tq, sq)
+    bk = min(block_k if block_k is not None else tk, sk)
+    if not (_kernel_path(sq, bq, bq, interpret) and _kernel_path(sk, bk, bk, interpret)):
+        return _dense_block_diffusion_lse(q, k, v, block_length, scale)
+    return _bd_flash(q, k, v, block_length, scale, bq, bk, interpret)
+
+
+def block_diffusion_attention(q, k, v, block_length: int, **kw) -> jax.Array:
+    """:func:`block_diffusion_attention_lse`'s output alone."""
+    return block_diffusion_attention_lse(q, k, v, block_length, **kw)[0]

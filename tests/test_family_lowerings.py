@@ -28,8 +28,8 @@ import numpy as np
 import optax
 import pytest
 
-from byteps_tpu.models import (conv_moe, delta_moe, early_route_moe, latent_moe, looped_dense,
-                               ssm_moe, window_moe)
+from byteps_tpu.models import (block_diffusion_moe, conv_moe, delta_moe, early_route_moe,
+                               latent_moe, looped_dense, ssm_moe, window_moe)
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
@@ -37,10 +37,14 @@ from byteps_tpu.parallel.mesh_utils import make_training_mesh
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
             "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe,
-            "looped_dense": looped_dense}
+            "looped_dense": looped_dense, "block_diffusion_moe": block_diffusion_moe}
 #: family → the reader of benchmark/readers/ its cell's metrics go through,
 #: where that is not one of its own name
 READERS = {"early_route_moe": "window_moe"}
+#: family → a second reader, whose scopes the accepted metrics that list the
+#: family's cell read it by: the block-diffusion family's own reader knows its
+#: two scopes, its router and experts are read through ``delta_moe``'s
+ALSO_READ_BY = {"block_diffusion_moe": "delta_moe"}
 
 #: sha256 of the StableHLO text of one tiny train step (sgd 1.0, batch 2, no
 #: donation, one CPU device).  The four float32 digests of ``bert``,
@@ -94,6 +98,16 @@ FROZEN_LOWERINGS = {
     ("ssm_moe", "bfloat16"): "4ecec2e060f947af1526a1e3295b9d7e51e51384f6a032be54fc84e576924a0b",
     ("looped_dense", "float32"): "8f77ef7cd0b04aa67647a10ba8b7aef4b656ff5aab136dcee546c5ebabcd3915",
     ("looped_dense", "bfloat16"): "b4715038548ff6df5a843859cbce33e92e88f5735e974d20a5a0dbb0057324cd",
+    # taken when ISSUE 59 wrote the family — the first whose step takes a third
+    # leaf of the batch; the fifteen above, every count and all seven
+    # ``FROZEN_PARAMETERS`` stood through what that PR did to
+    # ``build_train_step`` (a family that declares no ``batch_leaves`` lowers
+    # to the text it did) and to ``ops/flash_attention.py`` (a third pair of
+    # kernels beside the two)
+    ("block_diffusion_moe", "float32"):
+        "1aeb34d192f5164aa8e365f70e07c547a591c012826be5cb8a06c77d378f9ce2",
+    ("block_diffusion_moe", "bfloat16"):
+        "0e5298b98e47989502e1d2fdf991ef436b05f568401091cce5a36b0f4ccf1ab6",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -106,6 +120,7 @@ FROZEN_PARAMETERS = {
     "early_route_moe": "4185b5dc3604eb74127f4bad08f21f9bc5d63374e23957bc4be2b2751ced5698",
     "ssm_moe": "f490c4e980eef04431d28a3f03db0f49592603f5f02f9153b547ad90a9220f01",
     "looped_dense": "0b4e0693777702fb56a74903a1a7c62bdf5c74d861676882e23cca3fff028e97",
+    "block_diffusion_moe": "ce733faf1c2e8c92691f1c00b4099bb1d77ac078e40cc86c2f5e229fde94a92b",
 }
 
 #: family → scope → operations of the bfloat16 step filed under it.  The scopes
@@ -134,6 +149,10 @@ FROZEN_SCOPE_OPERATIONS = {
     # ``lm_head`` inside it.  What stands under ``loop_steps`` and under none of
     # them — the reader's ``loop_carry`` — and ``embed`` are held below
     "looped_dense": {"exit_gate": 126, "loop_heads": 109, "loop_attention": 470, "loop_mlp": 163},
+    # three layers, the last on the noisy half alone; ``copies_assembly`` is the
+    # ids' concatenation and the last layer's cut with its transpose
+    "block_diffusion_moe": {"block_diffusion_attention": 1904, "copies_assembly": 4,
+                            "moe_route": 261, "moe_experts": 1683},
 }
 
 #: family → scope → operations of the step (bfloat16; ``bert``'s float32 one)
@@ -158,6 +177,8 @@ FROZEN_REST_OPERATIONS = {
     "latent_moe": {"lm_head": 319, "embed": 17, "dense_mlp": 106},
     "ssm_moe": {"lm_head": 53, "embed": 16},
     "window_moe": {"lm_head": 53, "embed": 20},
+    # the blocked loss with a weight a row (ISSUE 59)
+    "block_diffusion_moe": {"lm_head": 61, "embed": 16},
 }
 
 
@@ -173,8 +194,10 @@ def _lowered(family: str, dtype: str):
     mesh = make_training_mesh(1, {"dp": 1, "pp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
     tx = optax.sgd(1.0)
     tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
+    # the leaves a family declares beyond (tokens, targets): f32, a row's shape
+    more = (jnp.ones(tokens.shape, jnp.float32),) * len(getattr(cfg, "batch_leaves", ()))
     return tfm.build_train_step(cfg, mesh, tx, donate=False).lower(
-        params, tx.init(params), tokens, tokens)
+        params, tx.init(params), tokens, tokens, *more)
 
 
 @pytest.mark.parametrize("family,dtype", sorted(FROZEN_LOWERINGS),
@@ -235,6 +258,8 @@ def _readers_scopes(family: str) -> tuple:
 def test_every_scope_the_readers_file_by_holds_its_operations(family):
     want = FROZEN_SCOPE_OPERATIONS[family]
     known = _readers_scopes(READERS.get(family, family))
+    if family in ALSO_READ_BY:
+        known += tuple(s for s in _readers_scopes(ALSO_READ_BY[family]) if s in want)
     # a family's own reader knows just its scopes; one that borrows a reader
     # has some of that reader's, in its order
     assert tuple(want) == (tuple(s for s in known if s in want) if family in READERS else known)

@@ -180,6 +180,40 @@ def test_flash_kernels_compile_at_the_sliding_window_shape(one_chip, no_compile_
         assert not re.search(r"bf16\[4,8,16384,128\]\S* broadcast\(", text)
 
 
+@pytest.mark.parametrize("queries", [16384, 8192], ids=["both_copies", "noisy_copy_alone"])
+def test_flash_kernels_compile_at_the_block_diffusion_shape(one_chip, no_compile_cache,
+                                                            monkeypatch, queries):
+    """(1, 32 | 4, 16384 or 8192 | 16384, 128 | 128) — SDAR's mixer under the
+    block-diffusion mask at block length 4: the pair ``flash_fwd_bd`` |
+    ``flash_bwd_bd`` with its tables of tiles as scalars before the grid, at
+    the blocks the artifact commits (dQ's f32 accumulator over the queries, 8
+    MiB in VMEM at 16384); both copies' queries, and the noised copy's alone
+    (a last layer's call).  K and V are the kernels' operands at 4 heads; the
+    innermost grid axes are as long as the table's longest rows, not as the
+    sequence, and the mask keeps under 0.6 of a causal call's tile pairs."""
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    bq, bk = fa.tuned_block_diffusion_blocks(16384, 4, queries)
+    tiles = fa._bd_tiles(queries, 8192, 4, bq, bk)
+    causal_pairs = sum(min((qi + 1) * bq - 1, 16383) // bk + 1 for qi in range(16384 // bq))
+    if queries == 16384:
+        assert tiles["pairs"] < 0.6 * causal_pairs
+    assert tiles["steps_f"] <= 8192 // bk + 8192 // bq and tiles["steps_b"] <= 2 * 8192 // bq
+    q = jax.ShapeDtypeStruct((1, 32, queries, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = fa.block_diffusion_attention_lse(q, k, v, 4, scale=128 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k).as_text()
+    for kernel in (fa.FWD_BD_KERNEL, fa.BWD_BD_KERNEL):
+        assert kernel in text, f"{kernel} is not in the compiled program"
+        q_, k_, v_ = [o for o in _kernel_operands(text, kernel) if o.startswith("bf16")][:3]
+        assert (q_, k_, v_) == (f"bf16[32,{queries},128]",) + ("bf16[4,16384,128]",) * 2
+    assert text.count("tpu_custom_call") >= 2
+    assert not re.search(r"bf16\[4,8,16384,128\]\S* broadcast\(", text)
+
+
 @pytest.mark.parametrize("window", [4096, None], ids=["banded_group_of_7", "global_group_of_7"])
 def test_flash_kernels_compile_at_the_early_routed_shape(one_chip, no_compile_cache, monkeypatch,
                                                          window):

@@ -50,6 +50,20 @@ in a profile of the calls, and GB/s the bytes the pass must move (every
 operand and result once) over them.  JoyAI-LLM-Flash's mixer:
 
     python tools/flash_tune.py --mla-heads --seqs 8192 --bh 2,32 --blocks 256,512,1024
+
+``--block-diffusion B`` sweeps the BLOCK-DIFFUSION kernels (``flash_fwd_bd`` |
+``flash_bwd_bd``: ``--seqs`` are KEY rows, 2L — a noised and a clean copy of L
+tokens under ``ops/flash_attention.block_diffusion_visible`` at block length
+B) with both copies' queries, times the noised copy's queries alone at the
+winner (a last layer's call) and, with ``--causal-too``, the full causal
+kernels over the same 2L rows (the yardstick: the mask keeps half a causal
+mask's entries); ``--check-rows N`` first holds every tile pair to the dense mask
+at N key rows (out, lse, dQ, dK, dV; f32 scores stand whole there, so N is
+small).  Winners go to the artifact's third table, ``block_diffusion``, keyed
+"key rows,block length".  SDAR's mixer, 32 | 4 heads of 128 over 2 x 8192:
+
+    python tools/flash_tune.py --block-diffusion 4 --seqs 16384 --bh 1,32 --kv-heads 4 \
+        --dh 128 --blocks 512,1024 --check-rows 3072 --causal-too --out chiprun_out/flash_blocks_bd.json
 """
 
 import argparse
@@ -135,6 +149,124 @@ def time_mla_heads(args) -> int:
     return 0
 
 
+def tune_block_diffusion(args) -> int:
+    """The block-diffusion kernels' sweep (the module's docstring)."""
+    import importlib
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    b, h = (int(x) for x in args.bh.split(","))
+    dh, dv, h_kv, blk = args.dh, args.dv or args.dh, args.kv_heads or h, args.block_diffusion
+    blocks = [int(x) for x in args.blocks.split(",")]
+    rng = np.random.default_rng(0)
+
+    def operands(sq, sk):
+        return tuple(jnp.asarray(rng.normal(size=(b, heads, rows, d)).astype(np.float32) * 0.1,
+                                 jnp.bfloat16)
+                     for heads, rows, d in ((h, sq, dh), (h_kv, sk, dh), (h_kv, sk, dv)))
+
+    def kernels(bq, bk):
+        return lambda q, k, v: fa.block_diffusion_attention_lse(
+            q, k, v, blk, block_q=bq, block_k=bk, interpret=args.rehearse)
+
+    def time_fn(fn, *xs, grad=True):
+        loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)  # noqa: E731
+        f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
+        jax.block_until_ready(f(*xs))
+        t0 = time.perf_counter()
+        for _ in range(args.steps):
+            out = f(*xs)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / args.steps * 1e3  # ms
+
+    if args.check_rows:
+        sk = args.check_rows
+        for sq in (sk, sk // 2):
+            q, k, v = operands(sq, sk)
+            ct = jnp.asarray(rng.normal(size=q.shape[:3] + (dv,)).astype(np.float32), jnp.bfloat16)
+            cl = jnp.asarray(rng.normal(size=q.shape[:3]).astype(np.float32))
+
+            def weighed(fn, q, k, v):
+                out, lse = fn(q, k, v)
+                return jnp.sum(out.astype(jnp.float32) * ct) + jnp.sum(lse * cl), (out, lse)
+
+            def dense(q, k, v):
+                return fa._dense_block_diffusion_lse(
+                    *(x.astype(jnp.float32) for x in (q, k, v)), blk, dh ** -0.5)
+
+            with jax.default_matmul_precision("highest"):
+                (_, aux), grads = jax.jit(jax.value_and_grad(
+                    lambda q, k, v: weighed(dense, q, k, v), argnums=(0, 1, 2),
+                    has_aux=True))(q, k, v)
+            want = (*aux, *grads)
+            for bq in blocks:
+                for bk in blocks:
+                    if sq % bq or sk % bk:
+                        continue
+                    (_, aux), grads = jax.jit(jax.value_and_grad(
+                        lambda q, k, v, f=kernels(bq, bk): weighed(f, q, k, v),
+                        argnums=(0, 1, 2), has_aux=True))(q, k, v)
+                    off = [float(jnp.linalg.norm(a.astype(jnp.float32) - w)
+                                 / jnp.linalg.norm(w)) for a, w in zip((*aux, *grads), want)]
+                    print(f"{sq} queries, {sk} keys, blocks of {blk}, bq={bq} bk={bk}: out, lse, "
+                          f"dQ, dK, dV off the dense mask by "
+                          f"{' '.join(f'{x:.2e}' for x in off)} (relative L2)", flush=True)
+                    if not max(off) < 2e-2:  # bf16 operands against f32
+                        raise SystemExit("the block-diffusion kernels disagree with the dense mask")
+
+    winners = {}
+    for sk in (int(x) for x in args.seqs.split(",")):
+        q, k, v = operands(sk, sk)
+        if args.causal_too:
+            causal = lambda q, k, v: fa.flash_attention(  # noqa: E731
+                q, k, v, causal=True, interpret=args.rehearse)
+            ms, fwd_ms = time_fn(causal, q, k, v), time_fn(causal, q, k, v, grad=False)
+            print(f"{sk} rows full causal kernels at the plain entry: {ms:8.2f} ms "
+                  f"(forward alone {fwd_ms:6.2f})", flush=True)
+        best = None
+        for bq in blocks:
+            for bk in blocks:
+                if (sk // 2) % bq or sk % bk:
+                    continue
+                fn = lambda q, k, v, f=kernels(bq, bk): f(q, k, v)[0]  # noqa: E731
+                try:
+                    ms, fwd_ms = time_fn(fn, q, k, v), time_fn(fn, q, k, v, grad=False)
+                    last = time_fn(fn, q[:, :, :sk // 2], k, v)
+                except Exception as e:  # noqa: BLE001
+                    print(f"{sk} key rows bq={bq} bk={bk}: {type(e).__name__}: {e}"[:300])
+                    continue
+                tiles = fa._bd_tiles(sk, sk // 2, blk, bq, bk)
+                tag = ""
+                if best is None or ms < best[0]:
+                    best, tag = (ms, bq, bk, fwd_ms, last), " *"
+                print(f"{sk} key rows bq={bq} bk={bk}: {ms:8.2f} ms (forward alone {fwd_ms:6.2f}; "
+                      f"the noised copy's queries alone {last:8.2f}); {tiles['pairs']} tile pairs, "
+                      f"grid steps {tiles['steps_f']} | {tiles['steps_b']}{tag}", flush=True)
+        if best is not None:
+            winners[f"{sk},{blk}"] = {"blocks": [best[1], best[2]], "flash_ms": round(best[0], 3),
+                                      "fwd_ms": round(best[3], 3),
+                                      "noisy_queries_ms": round(best[4], 3), "bh": args.bh,
+                                      "dh": dh, "dv": dv, "kv_heads": h_kv}
+    if winners and not args.no_write:
+        path = args.out or fa._TUNED_PATH
+        try:
+            with open(path) as f:
+                doc = json.load(f)
+        except (OSError, ValueError):
+            doc = {}
+        table, meta = doc.get("block_diffusion", {}), doc.get("block_diffusion_meta", {})
+        for entry, w in winners.items():
+            table[entry] = w.pop("blocks")
+            meta[entry] = w
+        with open(path, "w") as f:
+            json.dump({**doc, "block_diffusion": table, "block_diffusion_meta": meta}, f, indent=1)
+        print(f"wrote {len(winners)} block-diffusion entries -> {path}")
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seqs", default="512,1024,2048,4096")
@@ -163,6 +295,12 @@ def main() -> int:
                     help="don't persist winners to ops/flash_blocks.json")
     ap.add_argument("--mla-heads", action="store_true",
                     help="time ops/mla_heads.py's pass (heads of 128 | 64 | 128) and nothing else")
+    ap.add_argument("--block-diffusion", type=int, default=0,
+                    help="sweep the block-diffusion kernels at this block length (--seqs are "
+                         "key rows, 2L) and nothing else")
+    ap.add_argument("--check-rows", type=int, default=0,
+                    help="with --block-diffusion: first hold every tile pair to the dense mask "
+                         "at this many key rows")
     args = ap.parse_args()
 
     import jax
@@ -175,6 +313,8 @@ def main() -> int:
         args.no_write = True
     if args.mla_heads:
         return time_mla_heads(args)
+    if args.block_diffusion:
+        return tune_block_diffusion(args)
 
     from byteps_tpu.ops.flash_attention import flash_attention, _dense_reference
 

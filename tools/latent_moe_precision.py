@@ -10,6 +10,8 @@ no routed leaf among the gradient's readings, no ``--pin``).
     python tools/latent_moe_precision.py --config smallthinker_21b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config nemotron_twotower_30b_ep16 --seeds ...
     python tools/latent_moe_precision.py --config ouro_2_6b_pp8 --seeds ...
+    python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --seeds ...
+    python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --fault unit_weights --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -39,6 +41,15 @@ and in the worst (``reference_update_rtol``: value, leaf_value); the first
 gradient by leaf (leg E's limits).  One JSON line a seed on stdout, all of
 them in ``chiprun_out/<config>_precision.json``.  ``--rehearse`` runs the
 configuration's rehearsal cuts on the CPU: control flow only, never a reading.
+
+``--fault`` (the block-diffusion family's configuration alone) runs, in place
+of the program and the controls, the program with a fault PLANTED — what the
+cell's limits have to refuse: ``unit_weights``, the loss weights ignored (every
+masked token at weight 1, where the batch says 1 / t); ``causal_noisy``, the
+noised copy given the causal mask among its own rows (a noisy query sees every
+earlier noisy key, where it should see its own block's, both directions; the
+clean keys as they should be) — :func:`causal_noisy_attention` in the mixer's
+place, XLA's dense form a block of queries at a time.
 """
 
 from __future__ import annotations
@@ -64,6 +75,38 @@ def _builder(name: str):
     return module
 
 
+def causal_noisy_attention(q, k, v, block_length, scale=None, **_):
+    """``ops/flash_attention.block_diffusion_attention``'s signature with the
+    PLANTED fault: noisy query ``i`` sees noisy key ``j`` iff ``j <= i``.
+    Dense, 256 queries at a time, each block rebuilt in the backward pass."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, sq, d = q.shape
+    half, group = k.shape[2] // 2, h // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    rows = min(256, sq)
+    cols = jnp.arange(2 * half)
+    c_noisy, c_pos = cols < half, cols % half
+
+    @jax.checkpoint
+    def one(xs):
+        qb, first = xs
+        r = first + jnp.arange(rows)
+        r_noisy, r_pos = (r < half)[:, None], (r % half)[:, None]
+        rb, cb = r_pos // block_length, c_pos // block_length
+        seen = jnp.where(c_noisy, r_noisy & (c_pos <= r_pos),
+                         jnp.where(r_noisy, rb > cb, rb >= cb))
+        scores = jnp.einsum("bhqd,bhkd->bhqk", qb, k,
+                            preferred_element_type=jnp.float32) * (scale or d ** -0.5)
+        p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+    blocks = jnp.moveaxis(q.reshape(b, h, sq // rows, rows, d), 2, 0)
+    out = jax.lax.map(one, (blocks, rows * jnp.arange(sq // rows)))
+    return jnp.moveaxis(out, 0, 2).reshape(b, h, sq, -1)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
@@ -72,6 +115,9 @@ def main() -> int:
                          "takes (compute, statistics)")
     ap.add_argument("--pin", action="store_true",
                     help="with chip_smoke.pin_choice: every token picks the same experts")
+    ap.add_argument("--fault", choices=("unit_weights", "causal_noisy"),
+                    help="the block-diffusion configuration's program with this fault planted, "
+                         "in place of the program and the controls")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
 
@@ -138,6 +184,14 @@ def main() -> int:
         for name, dtypes in (("below_stream", (jnp.float32, jnp.bfloat16)),
                              ("below_statistics", (jnp.bfloat16, jnp.float32))):
             runs[name] = plain_steps(builder.plain_loss(cfg, jnp.bfloat16, *dtypes))
+    if args.fault == "unit_weights":
+        runs = {"fault": lambda p, batch: program_steps(
+            p, (*batch[:2], (batch[2] > 0).astype(batch[2].dtype)))}
+    elif args.fault:
+        import byteps_tpu.models.block_diffusion_moe as family
+
+        family.block_diffusion_attention = causal_noisy_attention  # before any step is traced
+        runs = {"fault": program_steps}
     reference = plain_steps(builder.plain_loss(cfg))
 
     @jax.jit
@@ -182,7 +236,8 @@ def main() -> int:
         del start, want_grads, want_params
     if not args.rehearse:
         os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-        name = f"{args.config}_precision{'_pinned' if args.pin else ''}.json"
+        name = (f"{args.config}_precision{'_pinned' if args.pin else ''}"
+                f"{'_' + args.fault if args.fault else ''}.json")
         with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
             json.dump(lines, f, indent=1)
     return 0
