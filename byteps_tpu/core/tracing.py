@@ -374,13 +374,21 @@ class stepped:
     (docs/observability.md "Reading a slow step"); ``BYTEPS_FLIGHT_STEPS=0``
     turns the rule off and leaves the spans and histograms.
 
+    ``first``, where given, is called with the FIRST call's arguments and
+    returns the arguments the jitted function is called with
+    (``build_train_step``: an optimizer state that arrives uncommitted is
+    committed, so that the second call finds the first's program); every
+    later call pays one test for it.
+
     One thread calls a step.  Every attribute the jitted function has
     (``lower``, ``trace``, ``eval_shape`` …) is this object's too.
     """
 
-    def __init__(self, jitted: Callable, fold: Optional[Callable] = None) -> None:
+    def __init__(self, jitted: Callable, fold: Optional[Callable] = None,
+                 first: Optional[Callable] = None) -> None:
         self._jitted = jitted
         self._fold = fold
+        self._first = first
         self._n = 0
         self._entered: Optional[float] = None  # the previous entry
         self._readings: tuple = ()
@@ -400,6 +408,8 @@ class stepped:
         self._entered, self._readings = now, readings
         self._n += 1
         with span("train.dispatch", step=self._n, wall_ns=time.time_ns()) as call:
+            if self._first is not None:
+                args, self._first = self._first(*args), None
             out = self._jitted(*args, **kwargs)
         self._dispatch_s, self._fold_s = call.ended - call.started, 0.0
         if self._fold is not None:

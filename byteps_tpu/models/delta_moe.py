@@ -15,9 +15,15 @@ scale, untied head, no position table.
 A family behind ``transformer.build_train_step`` as ``models/moe_family.py``
 says one is (the share of experts and vocabulary this device holds, the
 protocol, what the families share).  The stack is one ``lax.scan`` over the
-periods, every mixer and every MLP in it rebuilt in the backward pass but for
-what a mixer's costliest kernel wrote (``_layer_parts``).  The plain reference
-is ``models/delta_moe_reference.py``.
+periods — the program does not grow with the depth — and a period's layers
+are unrolled inside it: every mixer and every MLP is rebuilt in the backward
+pass but for what a mixer's kernels wrote (``_layer_parts``), and an array a
+``lax.scan``'s body keeps for its backward pass is copied into the scan's
+stack and out again (2.4 ms each way for a linear layer's 256 MiB on a v5e:
+PERF.md §6, PRs 58 and 60), so nothing a linear layer keeps crosses an inner
+stack.  It crosses the outer one where a stage holds several periods (one
+turn is no loop to XLA).  The plain reference is
+``models/delta_moe_reference.py``.
 """
 
 from __future__ import annotations
@@ -347,9 +353,13 @@ def _hidden(cfg: DeltaMoEConfig, params, tokens):
 
     def period(x, lps):
         stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
-        if lps["lin"]:
-            x, each = lax.scan(lambda x, lp: mlp(delta(x, lp), lp), x, lps["lin"])
-            stats = stats + jnp.sum(each, 0)
+        # the linear layers unrolled, each on its slice of the stacked leaves:
+        # what a layer keeps for its backward pass would be copied into a
+        # scan's stack and out again (module docstring)
+        for i in range(cfg.full_attention_interval - 1):
+            lp = {name: leaf[i] for name, leaf in lps["lin"].items()}
+            x, each = mlp(delta(x, lp), lp)
+            stats = stats + each
         x, last = mlp(attention(x, lps["full"]), lps["full"])
         return x, stats + last
 

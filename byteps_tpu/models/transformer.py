@@ -983,6 +983,17 @@ def build_train_step(
     sharded as the rows are, and hands them to ``cfg.local_loss`` in order.
     A family that declares none lowers to the text it always did.
     The returned step has the compiled function's ``lower``.
+
+    The step compiles ONE program.  It returns the optimizer state on the
+    mesh (``NamedSharding``); a state made as the examples make it,
+    ``jax.jit(optimizer.init)(params)``, arrives as uncommitted single-device
+    arrays, which ``jax.jit`` keys another program by.  So the first call
+    commits what arrives uncommitted — a leaf of a parameter's shape on the
+    parameter's spec, every other leaf replicated — and runs the program
+    every later call runs.  A committed leaf is left where it is; one whose
+    spec names a mesh axis of size one is handed on under the spec without it,
+    the same placement as jax names it on the way back (``home``).  No leaf is
+    copied.
     """
     validate_mesh(cfg, mesh)
     specs = param_specs(cfg)
@@ -1022,9 +1033,46 @@ def build_train_step(
 
     jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
+    def home(spec):
+        # The spec as jax reads it back off a compiled program's results: a
+        # mesh axis of size one leaves no trace there, nor does a trailing
+        # None.  A leaf that goes in under another name for the same placement
+        # (this family's own layouts name pp and tp on any mesh) comes back
+        # under this one, and the second call is keyed apart from the first.
+        dims = []
+        for entry in spec:
+            names = tuple(a for a in ((entry,) if isinstance(entry, str) else entry or ())
+                          if mesh.shape[a] > 1)
+            dims.append(names[0] if len(names) == 1 else names or None)
+        while dims and dims[-1] is None:
+            dims.pop()
+        return NamedSharding(mesh, P(*dims))
+
+    def commit(params, opt_state, *batch):
+        # no second copy of the moments: the committed array takes the
+        # uncommitted one's buffer where the step may (it donates its state),
+        # and shares it where the devices are the same otherwise
+        held = {"donate": True} if donate else {"may_alias": True}
+
+        def place(leaf, sharding):
+            # a committed leaf stays where the caller put it; only another
+            # name for the placement it has is exchanged for jax's own
+            if getattr(leaf, "committed", False) and (
+                    leaf.sharding == sharding
+                    or not sharding.is_equivalent_to(leaf.sharding, leaf.ndim)):
+                return leaf
+            return jax.device_put(leaf, sharding, **held)
+
+        # which of the state's leaves are parameter-shaped is the optimizer's to say
+        opt_state = jax.tree.map(place, opt_state, optax.tree_map_params(
+            optimizer, lambda _, spec: home(spec), opt_state, specs,
+            transform_non_params=lambda _: home(P())))
+        params = jax.tree.map(place, params, {k: home(spec) for k, spec in specs.items()})
+        return (params, opt_state) + batch
+
     def fold(out):
         # a device_get of whatever is ready: a place a host stall can hide
         routing_counters().push(out[3])
         return out[:3]
 
-    return stepped(jitted, fold)
+    return stepped(jitted, fold, first=commit)

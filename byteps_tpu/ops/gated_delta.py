@@ -43,9 +43,9 @@ shapes): on a TPU, at shapes the kernels tile, the Pallas kernels of
 behind a ``custom_vjp``, ``gdn_scan_bwd``: a chunk stays in VMEM from its
 first product to its last, the state in VMEM scratch along the sequence; the
 backward pass keeps T and a chunk's entering state in the compute dtype, and
-T carries the name in ``gated_delta_kernels.SAVED`` for a caller's
-``jax.checkpoint`` policy to keep, so that a rebuilt layer does not run the
-inverse again);
+T, the entering states and o carry the names in ``gated_delta_kernels.SAVED``
+for a caller's ``jax.checkpoint`` policy to keep, so that a rebuilt layer runs
+neither forward kernel again);
 everywhere else XLA's form below (:func:`_chunked_xla`: the scan over chunks
 and autodiff through it, which keeps a chunk's entering state and u), which
 is also the kernels' oracle beside the recurrence.  Nothing a token is kept

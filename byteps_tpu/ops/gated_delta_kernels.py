@@ -23,14 +23,14 @@ dS, a chunk's forward rebuilt from T and its entering state; the inverse's
 rule ``dA = −Tᵀ dT Tᵀ`` at f32 accuracy; gradients for q, k (summed over
 their value heads), v, g and β.
 
-The ``custom_vjp``'s forward rule gives T the name in ``SAVED``, so a caller
-that rebuilds its layer in the backward pass (``jax.checkpoint``) can keep it
-by a policy and run the inverse, the costliest of the three, once —
-``models/delta_moe._layer_parts`` does, as the attention parts keep the flash
-kernel's output and row statistics.  The entering states and o carry no name:
-inside a ``lax.scan`` over layers a kept array is copied into the scan's stack
-and out again, and for those two the copies cost more than ``gdn_scan_fwd``
-(measured: PERF.md §6, PR 58).
+The ``custom_vjp``'s forward rule gives T, the entering states and o the names
+in ``SAVED``, so a caller that rebuilds its layer in the backward pass
+(``jax.checkpoint``) can keep them by a policy and run both forward kernels
+once — ``models/delta_moe._layer_parts`` does, as the attention parts keep the
+flash kernel's output and row statistics.  That pays where the layer is NOT
+the body of a ``lax.scan``: there a kept array is copied into the scan's stack
+and out again, and for the entering states and o the copies cost more than
+``gdn_scan_fwd`` (measured: PERF.md §6, PRs 58 and 60).
 
 The operands are token-major, as the projections around the rule write and
 read them: q, k ``(B, S, H_k·d_k)``, v, o and their cotangents
@@ -62,11 +62,11 @@ from byteps_tpu.ops.flash_attention import _vma_union as _vma
 #: with ``flash_``: the benchmark's readers take such calls for flash kernels)
 INVERSE_KERNEL, FWD_KERNEL, BWD_KERNEL = "gdn_chunk_inverse", "gdn_scan_fwd", "gdn_scan_bwd"
 
-#: the triangular inverse carries this name wherever the rule is
-#: differentiated: a ``jax.checkpoint`` whose policy saves it
-#: (``save_only_these_names(*SAVED)``) does not run ``gdn_chunk_inverse`` again
-#: in its backward pass, as ``flash_attention.SAVED``
-SAVED = ("gdn_inverse",)
+#: the triangular inverse, the chunks' entering states and o carry these names
+#: wherever the rule is differentiated: a ``jax.checkpoint`` whose policy saves
+#: them (``save_only_these_names(*SAVED)``) runs neither ``gdn_chunk_inverse``
+#: nor ``gdn_scan_fwd`` again in its backward pass, as ``flash_attention.SAVED``
+SAVED = ("gdn_inverse", "gdn_entering", "gdn_out")
 
 #: rows of the MXU: chunks are stacked to this many for the inverse
 STACK = 128
@@ -412,6 +412,7 @@ def _rule_fwd(q, k, v, g, beta, hk, chunk, blocks, interpret):
     # it was traced with, and an unnamed one brings the inverse back to feed it
     t = checkpoint_name(_chunk_inverse(k, g, beta, hk, chunk, blocks[0], interpret), SAVED[0])
     o, entering = _scan_forward(q, k, v, g, beta, t, hk, chunk, blocks[1], True, interpret)
+    entering, o = checkpoint_name(entering, SAVED[1]), checkpoint_name(o, SAVED[2])
     return o, (q, k, v, g, beta, t, entering)
 
 

@@ -318,14 +318,14 @@ def test_the_token_major_mixer_is_the_head_major_one():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_a_rebuilt_linear_layer_runs_the_inverse_once(monkeypatch, dtype):
     """The gradient through the linear layer as ``_hidden`` rebuilds it
-    (``_layer_parts``: a ``jax.checkpoint`` that keeps, by name, the rule's
-    triangular inverse) holds one ``gdn_chunk_inverse``, two ``gdn_scan_fwd``
-    (the forward pass's and the rebuilt one, which reads the kept T) and one
-    ``gdn_scan_bwd``; with no policy, what the family had, the inverse twice
-    too.  The gradients of x and of every parameter are the same bit for bit:
-    the kept array is the one the rebuild would have written.  The kernels in
-    the interpreter: two chunks of 64, a key head of two value heads, the
-    state crossing a grid step."""
+    (``_layer_parts``: a ``jax.checkpoint`` that keeps, by name, what
+    ``gated_delta_kernels.SAVED`` lists: the rule's triangular inverse, the
+    chunks' entering states and o) holds one ``gdn_chunk_inverse``, one
+    ``gdn_scan_fwd`` and one ``gdn_scan_bwd``; with no policy, what the family
+    had, both forward kernels twice.  The gradients of x and of every
+    parameter are the same bit for bit: the kept arrays are the ones the
+    rebuild would have written.  The kernels in the interpreter: two chunks of
+    64, a key head of two value heads, the state crossing a grid step."""
     from byteps_tpu.ops import gated_delta_kernels as gk
 
     monkeypatch.setattr(dm, "chunked_gated_delta_rule", functools.partial(
@@ -341,7 +341,8 @@ def test_a_rebuilt_linear_layer_runs_the_inverse_once(monkeypatch, dtype):
     # the family's rebuilt layer, and the same layer under a checkpoint with no policy
     layers = {"family": dm._layer_parts(cfg)[0],
               "none": jax.checkpoint(dm._layer_parts(dataclasses.replace(cfg, remat=False))[0])}
-    inverses = {"family": 1, "none": 2}
+    forward_kernels = {"family": 1, "none": 2}  # the inverse and the walk, each
+    assert gk.SAVED == ("gdn_inverse", "gdn_entering", "gdn_out")
 
     def grad_of(layer):
         return jax.grad(lambda x, lp: jnp.sum(jnp.sin(layer(x, lp).astype(jnp.float32))),
@@ -350,13 +351,77 @@ def test_a_rebuilt_linear_layer_runs_the_inverse_once(monkeypatch, dtype):
     grads = {}
     for policy, layer in layers.items():
         assert _kernel_names(grad_of(layer), x, lp) == sorted(
-            [gk.INVERSE_KERNEL] * inverses[policy] + [gk.FWD_KERNEL] * 2 + [gk.BWD_KERNEL]), policy
+            [gk.INVERSE_KERNEL, gk.FWD_KERNEL] * forward_kernels[policy] + [gk.BWD_KERNEL]), policy
         grads[policy] = jax.jit(grad_of(layer))(x, lp)
     (dx, dlp), (dx_none, dlp_none) = grads["family"], grads["none"]
     np.testing.assert_array_equal(dx, dx_none)
     for name in mixer:
         assert float(jnp.abs(dlp[name]).max()) > 0, name  # every leaf is reached
         np.testing.assert_array_equal(dlp[name], dlp_none[name], err_msg=name)
+
+
+def _scanned_hidden(cfg, params, tokens):
+    """``delta_moe._hidden`` as it ran a period up to PR 59: the linear layers
+    the body of a ``lax.scan`` over their stacked leaves, inside the scan over
+    the periods.  The same parts (``_layer_parts``), the same order."""
+    delta, attention, mlp = dm._layer_parts(cfg)
+
+    def period(x, lps):
+        x, each = jax.lax.scan(lambda x, lp: mlp(delta(x, lp), lp), x, lps["lin"])
+        x, last = mlp(attention(x, lps["full"]), lps["full"])
+        return x, jnp.sum(each, 0) + last
+
+    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    x, stats = jax.lax.scan(period, x, {kind: mf.stack_of(params, kind)
+                                        for kind in ("lin", "full")})
+    return x, jnp.sum(stats, 0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_unrolled_period_is_the_scanned_one(monkeypatch, dtype):
+    """``_hidden`` runs a period's linear layers as a Python loop, each on its
+    slice of the stacked ``lin`` leaves (nothing a layer keeps for its
+    backward pass crosses a scan's stack).  Against the same layers run by a
+    ``lax.scan``: the loss, the routing statistics and the gradient of every
+    leaf — a slice's transpose writes each layer's gradient to its own rows of
+    the stack.  Two periods of two linear layers and a full one; the rule's
+    kernels in the interpreter (so the rebuild keeps what ``SAVED`` names),
+    one sequence of two chunks."""
+    monkeypatch.setattr(dm, "chunked_gated_delta_rule", functools.partial(
+        gd.chunked_gated_delta_rule, interpret=True, blocks=(2, 1, 1)))
+    cfg = dm.tiny_delta_moe(n_layers=6, full_attention_interval=3, lin_k_heads=1, lin_v_heads=2,
+                            lin_k_dim=128, lin_v_dim=128, chunk=64, max_seq=128,
+                            compute_dtype=dtype)
+    assert cfg.remat and cfg.n_periods == 2
+    params = dm.init_params(cfg, jax.random.PRNGKey(5))
+    assert params["lin.w_qkvz"].shape[:2] == (2, 2)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (1, cfg.max_seq), 0, cfg.vocab_size)
+    targets = jnp.roll(tokens, -1, axis=1)
+
+    def loss_of(hidden):
+        def loss(params):
+            x, stats = hidden(cfg, params, tokens)
+            logits = dm._logits(cfg, x, params["norm_f"], params["head"])
+            gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold), stats
+        # the same operations in the same order, but XLA fuses a loop's body
+        # apart from its surroundings and, left to itself, skips the bfloat16
+        # roundings inside a fusion: held to them, the two agree to f32's last bits
+        return jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+
+    (loss, stats), grads = loss_of(dm._hidden)(params)
+    (want, want_stats), want_grads = loss_of(_scanned_hidden)(params)
+    tol = 1e-5
+    np.testing.assert_allclose(loss, want, rtol=tol)
+    np.testing.assert_array_equal(stats, want_stats)
+    assert set(grads) == set(params)
+    for name, leaf in want_grads.items():  # every leaf is reached, in every layer of its stack
+        stacked = {"lin": 2, "full": 1}.get(name.split(".")[0], 0)
+        reached = jnp.abs(leaf).reshape(leaf.shape[:stacked] + (-1,)).max(-1)
+        assert bool(jnp.all(reached > 0)), name
+    off, leaf = _worst(grads, want_grads)
+    assert off < tol, (off, leaf)
 
 
 def test_softmax_router_against_top_k_of_a_dense_softmax_with_planted_ties():

@@ -82,14 +82,20 @@ ALSO_READ_BY = {"block_diffusion_moe": "delta_moe"}
 #: what that PR did to ``moe_family``: ``Family`` | ``ExpertFamily``, and ONE
 #: blocked loss under ``xent_sums`` and ``weighted_xent`` (a weight a row is
 #: data: ``ws`` None, no product with it; what the logits read is a tuple).
+#: ISSUE 60 meant to move ``delta_moe``'s step and only that (a period's linear
+#: layers are a Python loop over slices of the stacked ``lin`` leaves, no inner
+#: ``lax.scan``): its two digests were taken again on its tree; the fifteen
+#: other digests, every count and all eight ``FROZEN_PARAMETERS`` stood —
+#: ``build_train_step`` commits an uncommitted optimizer state on the host at
+#: the first CALL, and ``lower`` is the compiled function's own.
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     ("latent_moe", "float32"): "2f39501233c5fb12999aad5f6244e64071b14dda6ce394942f3ffbfe3e4ad237",
-    ("delta_moe", "float32"): "fe105203baafff63b7b15b833c1344c2d531268490ff242bd9115db8bedf4141",
+    ("delta_moe", "float32"): "449d8aa16bb6630471a60b6aa42b8824a7b97f79bdae6743dddb03e93592ba48",
     ("conv_moe", "float32"): "3c848278b7f215d90da5af8be1453d60157b992152875c775e5cfd8127ac5ea6",
     ("window_moe", "float32"): "5fa6c86a602cc5b537a9cee5e9dce944f6c84180fa3a0d986aad9c8d89ddfb5c",
     ("latent_moe", "bfloat16"): "da39e2ae36751672fca8343047e2e8a663c2bb73ef2db55a0bf153b15a600c57",
-    ("delta_moe", "bfloat16"): "07b0ccef01165ac7b18897dbd77e8761dccf20c2ad5c97f49685ab804bc069a9",
+    ("delta_moe", "bfloat16"): "555ab85d04197f4677a0ec83d8fc914b064a3f48826cb4301d1394754ce61fe2",
     ("conv_moe", "bfloat16"): "cb18f95a6e3209e62567e45b5d5bb60c0590b4e58a14ccb5a7cdc7289adcf63f",
     ("window_moe", "bfloat16"): "4454b585256577f84c672ad7f23299f90fe8cf25de2ee0f1ad3884d0713b9bfa",
     ("early_route_moe", "float32"): "3ad9b4b5455c7129e73f776a03785f1dcdf59f349978b9d8985ef137a5b969b2",
@@ -205,6 +211,59 @@ def _lowered(family: str, dtype: str):
 def test_the_steps_lower_as_before(family, dtype):
     text = _lowered(family, dtype).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == FROZEN_LOWERINGS[family, dtype]
+
+
+@pytest.mark.parametrize("family, dp, tp", [("bert", 1, 1), ("bert", 2, 2)] + [
+    (family, 1, 1) for family in sorted(FAMILIES)])
+def test_the_step_compiles_one_program(family, dp, tp):
+    """The state made as the builders and the examples make it,
+    ``jax.jit(tx.init)(params)``: adamw's leaves come back uncommitted on one
+    device, and the step returns them on the mesh.  The step commits them at
+    its first call (ISSUE 60), so three calls hold ONE entry in the jitted
+    step's cache; what the first call lowers is, text for text, what a call on
+    the returned state lowers — the program every later step runs —, and a
+    state that arrives committed is handed on as it is, leaf for leaf.
+    ``bert``'s layouts name ``pp`` and ``tp`` on any mesh: a spec comes back
+    without its axes of size one, so that is how parameters and state go in
+    (one chip, and two data-parallel ranks of two tensor-parallel devices)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    if family == "bert":
+        cfg = tfm.tiny_test(causal=False)
+        params = tfm.init_params(cfg)
+    else:
+        cfg = getattr(FAMILIES[family], f"tiny_{family}")()
+        params = FAMILIES[family].init_params(cfg, jax.random.PRNGKey(0))
+    mesh = make_training_mesh(dp * tp, {"dp": dp, "pp": 1, "sp": 1, "tp": tp},
+                              devices=jax.devices()[:dp * tp])
+    placed = {k: NamedSharding(mesh, spec) for k, spec in tfm.param_specs(cfg).items()}
+    params = jax.device_put(dict(params), placed)
+    tokens = jax.device_put(jnp.zeros((2 * dp, cfg.max_seq), jnp.int32),
+                            NamedSharding(mesh, P("dp", "sp")))
+    batch = (tokens, tokens) + (jnp.ones(tokens.shape, jnp.float32),) * len(
+        getattr(cfg, "batch_leaves", ()))
+    tx = optax.adamw(1e-3)
+    state = jax.jit(tx.init)(params)
+    assert not any(leaf.committed for leaf in jax.tree.leaves(state))
+    step = tfm.build_train_step(cfg, mesh, tx, donate=False)
+    committed = step._first(params, state, *batch)
+    assert jax.tree.structure(committed[1]) == jax.tree.structure(state)
+    assert all(leaf.committed and leaf.sharding.mesh == mesh
+               for leaf in jax.tree.leaves(committed[:2]))
+    # the same placement under jax's own name for it; nothing moved
+    assert all(committed[0][k].sharding.is_equivalent_to(placed[k], params[k].ndim)
+               for k in params)
+    moments = committed[1][0]  # adamw: (ScaleByAdamState, …)
+    assert all(moments.mu[k].sharding == moments.nu[k].sharding == committed[0][k].sharding
+               for k in params)
+    assert moments.count.sharding.spec == P()
+    again = step._first(*committed)
+    assert all(a is b for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(committed)))
+    first = step.lower(*committed).as_text()
+    for _ in range(3):
+        params, state, _ = step(params, state, *batch)
+        assert step._jitted._cache_size() == 1
+    assert step.lower(params, state, *batch).as_text() == first
 
 
 def parameters_digest(params) -> str:

@@ -139,6 +139,24 @@ def test_the_fold_has_its_span_and_gives_the_caller_what_is_left():
     assert hist("span_seconds", name="train.dispatch")["count"] == 3
 
 
+def test_the_first_calls_arguments_go_through_the_hook_once():
+    """``first`` (``build_train_step``'s commit of an uncommitted optimizer
+    state) sees the first call's arguments, inside its dispatch span, and what
+    it returns is what the jitted function is called with; a later call pays
+    one test for it and is handed on as it came."""
+    seen = []
+
+    def first(x, y):
+        seen.append((x, y))
+        return x + 1, y
+
+    step = tracing.stepped(lambda x, y: (x, y), first=first)
+    assert step(1, "a") == (2, "a")
+    assert [step(1, "b"), step(5, "c")] == [(1, "b"), (5, "c")]
+    assert seen == [(1, "a")] and step._first is None
+    assert hist("span_seconds", name="train.dispatch")["count"] == 3
+
+
 def test_the_dispatch_span_ties_the_profilers_clock_to_the_wall_clock():
     seen = []
     real = tracing.TraceAnnotation

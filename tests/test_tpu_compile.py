@@ -512,18 +512,18 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
 
 
-@pytest.mark.parametrize("policy, calls", [("family", (1, 2, 1)), ("none", (2, 2, 1))])
+@pytest.mark.parametrize("policy, calls", [("family", (1, 1, 1)), ("none", (2, 2, 1))])
 def test_delta_layer_runs_the_inverse_once_at_published_widths(
         one_chip, no_compile_cache, monkeypatch, policy, calls):
     """The gradient of a rebuilt linear layer (``x + _delta_mixer``, the
-    part ``delta_moe._layer_parts`` hands ``_hidden``'s scan) for one sequence
-    of 16 384 tokens at Qwen3-Next's widths: the compiled module calls
-    ``gdn_chunk_inverse`` once — the recomputation keeps T by name
-    (``gated_delta_kernels.SAVED``) —, ``gdn_scan_fwd`` twice and
-    ``gdn_scan_bwd`` once, where a ``jax.checkpoint`` with no policy, what the
-    family had, calls the inverse twice too.  The operands stay token-major
-    either way: no ``transpose`` and no layout-changing ``copy`` of 64 MB or
-    more under ``gdn_scan``."""
+    part ``delta_moe._layer_parts`` hands ``_hidden``'s period) for one
+    sequence of 16 384 tokens at Qwen3-Next's widths: the compiled module
+    calls ``gdn_chunk_inverse``, ``gdn_scan_fwd`` and ``gdn_scan_bwd`` once
+    each — the recomputation keeps T, the entering states and o by name
+    (``gated_delta_kernels.SAVED``) —, where a ``jax.checkpoint`` with no
+    policy, what the family had, calls both forward kernels twice.  The
+    operands stay token-major either way: no ``transpose`` and no
+    layout-changing ``copy`` of 64 MB or more under ``gdn_scan``."""
     from byteps_tpu.models import delta_moe as dm
     from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
@@ -549,6 +549,80 @@ def test_delta_layer_runs_the_inverse_once_at_published_widths(
 
 _ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
 _SHAPE = r"\b(bf16|f32|s32|u32|pred)\[([\d,]*)\]"
+
+
+def _slices_moved(text: str, least_bytes: int) -> list:
+    """The ``dynamic-slice`` and ``dynamic-update-slice`` instructions of an
+    optimized module (any computation) that move at least ``least_bytes``: a
+    slice's result, an update's written operand — what a ``lax.scan`` reads
+    from and writes to its stack a turn."""
+    defined = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)$", re.M)
+
+    def size(dtype, dims):
+        return math.prod(int(d) for d in dims.split(",") if d) * _ITEM.get(dtype, 4)
+
+    sizes = {name: size(dtype, dims) for name, dtype, dims, _, _ in defined.findall(text)}
+    found = []
+    for name, dtype, dims, op, operands in defined.findall(text):
+        if op == "dynamic-slice":
+            moved = size(dtype, dims)
+        elif op == "dynamic-update-slice":
+            moved = sizes[re.findall(r"%([\w.\-]+)", operands)[1]]
+        else:
+            continue
+        if moved >= least_bytes:
+            found.append(f"{name}: {op} of {moved / 2**20:.0f} MiB")
+    return found
+
+
+def test_delta_step_copies_nothing_it_keeps_at_published_widths(one_chip, no_compile_cache,
+                                                                monkeypatch):
+    """The whole train step of ``build_train_step`` for one period of
+    Qwen3-Next at the published widths — three gated-delta layers and a gated
+    attention layer over 1 x 16 384 tokens, 16 of 512 experts held, adamw, bf16
+    operands: the cell's program — for one described chip.  A period's linear
+    layers are unrolled (``delta_moe._hidden``), so what a layer keeps for its
+    backward pass (x 64 MiB; T, the entering states and o 256 MiB each) is
+    written once where it is made and read where it is: the module holds no
+    ``dynamic-update-slice`` and no ``dynamic-slice`` of 64 MB or more (as
+    the body of a ``lax.scan`` each kept array was copied into the scan's
+    stack and out again: PERF.md §6, PRs 58 and 60; the blocked loss's 8 MiB
+    blocks are the largest left), every kernel of the rule is called once a
+    layer, and the program fits the chip beside nothing else of its size."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.models import delta_moe as dm
+    from byteps_tpu.models.transformer import build_train_step
+    from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import gated_delta_kernels as gk
+    from byteps_tpu.parallel.mesh_utils import make_training_mesh
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    cfg = dm.DeltaMoEConfig(vocab_size=18992, n_layers=4, experts_held=16,
+                            compute_dtype=jnp.bfloat16)  # every width as published
+    assert (cfg.n_periods, cfg.full_attention_interval, cfg.max_seq, cfg.remat) == (
+        1, 4, 16384, True)
+    mesh = make_training_mesh(1, {"dp": 1, "pp": 1, "sp": 1, "tp": 1},
+                              devices=[one_chip._device])
+    held = NamedSharding(mesh, P())
+    params = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=held)
+              for k, (s, _, _) in cfg.layouts().items()}
+    tokens = jax.ShapeDtypeStruct((1, cfg.max_seq), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp", "sp")))
+    tx = optax.adamw(1e-6)
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=held),
+                         jax.eval_shape(tx.init, params))
+    compiled = build_train_step(cfg, mesh, tx).lower(params, state, tokens, tokens).compile()
+    text = compiled.as_text()
+    assert _slices_moved(text, 64 * 10**6) == []
+    kernels = [op_name for *_, kernel, op_name in _top_level(text) if kernel]
+    assert tuple(sum(bool(re.search(rf"\b{name}\b", op_name)) for op_name in kernels)
+                 for name in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL)) == (3, 3, 3)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 10.2 * 2**30
 
 
 def _bytes_of(shapes: str) -> int:
