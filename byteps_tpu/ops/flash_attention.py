@@ -39,7 +39,7 @@ clamped to the last one needed, and Pallas does not copy a block whose index
 did not change.  On a TPU the kernels are the only path (a sequence the
 blocks do not divide raises);
 off a TPU the dense reference stands in — see :func:`_kernel_path`, the one
-place that decides.  Differentiable end to end.
+place that asks (``_dispatch.kernels_run`` answers).  Differentiable end to end.
 
 The band (``window=W`` with ``causal=True``): query ``i`` sees key ``j`` iff
 ``0 <= i - j < W`` — itself and the ``W - 1`` keys before it.  A banded call
@@ -79,6 +79,14 @@ last tile and run nothing.  The queries may be the noisy half alone
 (``L`` rows against ``2L`` keys: what a last layer's loss reads).  dQ is
 written as the banded kernel writes it: the running sum at every pair.
 
+One body a direction for all three masks: every forward kernel's pair is
+:func:`_softmax_pair` (between :func:`_softmax_init` and :func:`_softmax_emit`),
+every backward kernel's :func:`_grad_pair`, and each direction has one
+``pallas_call`` site (:func:`_forward_call`, :func:`_backward_call`: a plain
+grid, or with tables before it a ``PrefetchScalarGridSpec``).  A kernel
+factory holds what its mask family really differs in: how a step finds its
+tile, the mask, and when dQ's rows are cleared and written.
+
 This is the per-device compute of the transformer's attention; sequence
 parallelism composes on top (ring attention rotates KV blocks *between*
 devices, these kernels handle the blocks *within* one device).
@@ -87,7 +95,6 @@ devices, these kernels handle the blocks *within* one device).
 from __future__ import annotations
 
 import functools
-import json
 import os
 from typing import Optional
 
@@ -95,16 +102,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-NEG_INF = -1e30
+from byteps_tpu.ops._dispatch import LANES, kernels_run, tuned, vma_union
 
-# TPU vector lanes.  The forward kernel carries its per-row softmax state (m,
-# l, and the lse it emits) broadcast across a trailing LANES dim, so every
-# block-mapped ref keeps its last two dims (8, 128)-tileable — a (bh, s)
-# output with (1, bq) blocks fails Mosaic's block-mapping check (the same
-# layout jax's bundled TPU flash kernel uses for its l/m residuals).  The
-# residual is one value a row, and the backward kernel reads it so: lse and Δ
-# as (bh, 1, s), a (1, bq) block along the lanes.
-LANES = 128
+NEG_INF = -1e30
 
 #: what the forward leaves for the backward, by name: a ``jax.checkpoint``
 #: whose policy saves these (``save_only_these_names(*SAVED)``) does not run
@@ -210,66 +210,74 @@ def _band_steps(s: int, bq: int, bk: int, window: int) -> tuple:
     return fwd, bwd
 
 
+# The forward kernels carry their per-row softmax state (m, l, and the lse they
+# emit) broadcast across a trailing LANES dim, so every block-mapped ref keeps
+# its last two dims (8, 128)-tileable — a (bh, s) output with (1, bq) blocks
+# fails Mosaic's block-mapping check (the same layout jax's bundled TPU flash
+# kernel uses for its l/m residuals).  The residual is one value a row, and the
+# backward kernels read it so: lse and Δ as (bh, 1, s), a (1, bq) block along
+# the lanes.
+
+
+def _softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _softmax_pair(q_ref, k_ref, v_ref, keep, scale, m_scr, l_scr, acc_scr):
+    """One (query block, key block) pair of the online softmax, for every
+    forward kernel.  ``keep``: ``None`` where the whole tile is visible, else
+    a function that gives the bool tile of visible positions — a function, so
+    that the mask is made where it is used, after the scores.  A row with no
+    visible key in a tile adds it at weight 1 under m = NEG_INF, which its
+    first real maximum's exp(NEG_INF - m) wipes: every row sees at least one
+    key, itself or the sequence's first."""
+    v = v_ref[0]
+    s = jax.lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if keep is not None:
+        s = jnp.where(keep(), s, NEG_INF)
+    m = m_scr[:]  # (bq, LANES), value broadcast across lanes
+    l = l_scr[:]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[:, 0:1])
+    m_scr[:] = m_new
+    l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _softmax_emit(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    l = l_scr[:]
+    l = jnp.where(l == 0, 1.0, l)
+    o_ref[0] = (acc_scr[:] / l[:, 0:1]).astype(o_ref.dtype)
+    lse_ref[0] = m_scr[:] + jnp.log(l)
+
+
 def _fwd_kernel_factory(bq, bk, nk, causal, scale, window=None):
     """``nk``: the innermost grid axis's length — the KV blocks, or at a
     window the band's (:func:`_band_steps`), step ``t`` being KV block
     ``first + t`` of its query block."""
     from jax.experimental import pallas as pl
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch):
         qi = pl.program_id(1)
         step = j = pl.program_id(2)
         if window is not None:
             j = _first_kv_block(qi, bq, bk, window) + step
-
-        @pl.when(step == 0)
-        def _init():
-            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[:] = jnp.zeros_like(l_scr)
-            acc_scr[:] = jnp.zeros_like(acc_scr)
-
-        # a row with no visible key in its band's first block adds that block
-        # at weight 1 under m = NEG_INF, which the first real maximum's
-        # exp(NEG_INF - m) wipes: every row sees at least itself
-        @pl.when(_block_needed(causal, qi, j, bq, bk, window))
-        def _block():
-            v = v_ref[0]
-            s = jax.lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if causal:
-                s = jnp.where(_causal_keep(qi, j, bq, bk, window=window), s, NEG_INF)
-            m = m_scr[:]  # (bq, LANES), value broadcast across lanes
-            l = l_scr[:]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[:, 0:1])
-            m_scr[:] = m_new
-            l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-        @pl.when(step == nk - 1)
-        def _emit():
-            l = l_scr[:]
-            l = jnp.where(l == 0, 1.0, l)
-            o_ref[0] = (acc_scr[:] / l[:, 0:1]).astype(o_ref.dtype)
-            lse_ref[0] = m_scr[:] + jnp.log(l)
+        keep = functools.partial(_causal_keep, qi, j, bq, bk, window=window) if causal else None
+        pl.when(step == 0)(functools.partial(_softmax_init, *scratch))
+        pl.when(_block_needed(causal, qi, j, bq, bk, window))(
+            functools.partial(_softmax_pair, q_ref, k_ref, v_ref, keep, scale, *scratch))
+        pl.when(step == nk - 1)(functools.partial(_softmax_emit, o_ref, lse_ref, *scratch))
 
     return kernel
-
-
-def _vma_union(*xs):
-    """Union of the inputs' varying-manual-axes sets, for pallas out_shapes.
-
-    Under ``shard_map(check_vma=True)`` pallas_call outputs must declare how
-    they vary across the manual mesh axes; the attention output varies over
-    exactly the axes any of q/k/v vary over.
-    """
-    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _kv_index(causal, bq, bk, window=None, group=1):
@@ -309,40 +317,44 @@ def _q_index(causal, bq, bk, window=None, nq=None):
     return lambda i, j, qi: (i, jnp.maximum(qi, _first_q_block(j, bq, bk)), 0)
 
 
-def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
+def _forward_call(kernel, name, steps, kv_index, q, k, v, bq, bk, interpret, scalars=()):
+    """Every forward kernel's ``pallas_call``: a (batch·head, query block,
+    ``steps``) grid, the query blocks' index map the plain one, K's and V's
+    ``kv_index``.  ``scalars``: tables handed to the kernel and the index maps
+    before the grid runs (the table-driven kernel's); none is a plain grid."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    vma = _vma_union(q, k, v)
-    b, h, s, dqk = q.shape
-    dv, h_kv = v.shape[-1], k.shape[1]
-    nk = s // bk if window is None else _band_steps(s, bq, bk, window)[0]
-    bh = b * h
-    qf = q.reshape(bh, s, dqk)
-    kf = k.reshape(b * h_kv, s, dqk)
-    vf = v.reshape(b * h_kv, s, dv)
-    kv_index = _kv_index(causal, bq, bk, window, h // h_kv)
-    out, lse = pl.pallas_call(
-        _fwd_kernel_factory(bq, bk, nk, causal, scale, window),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dv), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32, vma=vma),
-        ),
-        grid=(bh, s // bq, nk),
+    vma = vma_union(q, k, v)
+    b, h, sq, dqk = q.shape
+    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    q_index = lambda i, qi, t, *_: (i, qi, 0)  # noqa: E731
+    grid = dict(
+        grid=(b * h, sq // bq, steps),
         in_specs=[
-            pl.BlockSpec((1, bq, dqk), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bq, dqk), q_index),
             pl.BlockSpec((1, bk, dqk), kv_index),
             pl.BlockSpec((1, bk, dv), kv_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bq, dv), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, bq, dv), q_index),
+            pl.BlockSpec((1, bq, LANES), q_index),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
+    )
+    if scalars:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), **grid))
+    out, lse = pl.pallas_call(
+        kernel,
+        out_shape=(
+            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32, vma=vma),
+        ),
         compiler_params=pltpu.CompilerParams(
             # bh and q-block cells are independent; only the k scan (which
             # accumulates into scratch) is order-dependent — telling Mosaic
@@ -350,14 +362,74 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=FWD_KERNEL if window is None else FWD_WIN_KERNEL,
-    )(qf, kf, vf)
-    return out.reshape(b, h, s, dv), lse
+        name=name,
+        **grid,
+    )(*scalars, q.reshape(b * h, sq, dqk), k.reshape(b * h_kv, sk, dqk),
+      v.reshape(b * h_kv, sk, dv))
+    return out.reshape(b, h, sq, dv), lse
+
+
+def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
+    s, group = q.shape[2], q.shape[1] // k.shape[1]
+    nk = s // bk if window is None else _band_steps(s, bq, bk, window)[0]
+    return _forward_call(
+        _fwd_kernel_factory(bq, bk, nk, causal, scale, window),
+        FWD_KERNEL if window is None else FWD_WIN_KERNEL, nk,
+        _kv_index(causal, bq, bk, window, group), q, k, v, bq, bk, interpret)
 
 
 # ---------------------------------------------------------------------------
 # backward kernel
 # ---------------------------------------------------------------------------
+
+
+def _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
+               dq_scr, dk_scr, dv_scr):
+    """One (key block, query block) pair of every backward kernel: the five
+    products and the three accumulations, dQ's into ``rows`` of its accumulator.
+    Everything is (bk, bq), keys down and queries across: lse and Δ are then
+    one row, and of the five products only dQ's takes its left operand
+    transposed.  ``keep`` as :func:`_softmax_pair`'s, of a keys-down tile."""
+    q, k, do = q_ref[0], k_ref[0], do_ref[0]
+    st = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    pt = jnp.exp(st - lse_ref[0])
+    if keep is not None:
+        pt = jnp.where(keep(), pt, 0.0)
+    dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dpt = jax.lax.dot_general(
+        v_ref[0], do, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+    dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
+        dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
+        dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dkv_init(dk_scr, dv_scr):
+    dk_scr[:] = jnp.zeros_like(dk_scr)
+    dv_scr[:] = jnp.zeros_like(dv_scr)
+
+
+def _dkv_emit(dk_ref, dv_ref, dk_scr, dv_scr):
+    dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _dq_clear(dq_scr, rows, bq):
+    dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
+
+
+def _dq_write(dq_ref, dq_scr, rows):
+    dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
 
 
 def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
@@ -376,20 +448,14 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
         if window is not None:
             qi = _first_q_block(j, bq, bk) + step
         rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query block's of dq_scr
-
-        def clear_dq():
-            dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
-
-        def write_dq():
-            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+        keep = (functools.partial(_causal_keep, qi, j, bq, bk, keys_down=True, window=window)
+                if causal else None)
+        clear_dq = functools.partial(_dq_clear, dq_scr, rows, bq)
+        write_dq = functools.partial(_dq_write, dq_ref, dq_scr, rows)
 
         if window is None:
             pl.when(j == 0)(clear_dq)
-
-        @pl.when(step == 0)
-        def _init_dkv():
-            dk_scr[:] = jnp.zeros_like(dk_scr)
-            dv_scr[:] = jnp.zeros_like(dv_scr)
+        pl.when(step == 0)(functools.partial(_dkv_init, dk_scr, dv_scr))
 
         needed = _block_needed(causal, qi, j, bq, bk, window)
         if window is not None:
@@ -399,40 +465,12 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
         def _block():
             if window is not None:  # the first key block this query block meets
                 pl.when(j == _first_kv_block(qi, bq, bk, window))(clear_dq)
-            # everything (bk, bq), keys down and queries across: lse and Δ are
-            # then one row, and of the five products only dQ's takes its left
-            # operand transposed
-            q, k, do = q_ref[0], k_ref[0], do_ref[0]
-            st = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            pt = jnp.exp(st - lse_ref[0])
-            if causal:
-                pt = jnp.where(_causal_keep(qi, j, bq, bk, keys_down=True, window=window),
-                               pt, 0.0)
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dpt = jax.lax.dot_general(
-                v_ref[0], do, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
-            dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-                dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
-                dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
+            _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
+                       dq_scr, dk_scr, dv_scr)
             if window is not None:  # the running sum; a block's last pair writes it whole
                 write_dq()
 
-        @pl.when(step == steps - 1)
-        def _emit_dkv():
-            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
+        pl.when(step == steps - 1)(functools.partial(_dkv_emit, dk_ref, dv_ref, dk_scr, dv_scr))
         if window is None:
             pl.when(j == nk - 1)(write_dq)
 
@@ -453,48 +491,50 @@ def _bwd_vmem_bytes(s, bq, bk, dqk, dv, itemsize) -> int:
     return max((acc + blocks + inter) * 5 // 4, 16 * 2**20)
 
 
-def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
-                    dlse=None, window=None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _heads_flat(q, k, v) -> tuple:
+    """q, k, v with batch and heads as one leading dim, each at its own head count."""
+    return tuple(x.reshape(-1, *x.shape[2:]) for x in (q, k, v))
 
-    vma = _vma_union(q, k, v, o, lse, do)
-    b, h, s, dqk = q.shape
-    dv, h_kv = v.shape[-1], k.shape[1]
-    bh, group = b * h, h // h_kv
-    nq, nk = s // bq, s // bk
-    qf, kf = q.reshape(bh, s, dqk), k.reshape(b * h_kv, s, dqk)
-    vf, dof = v.reshape(b * h_kv, s, dv), do.reshape(bh, s, dv)
+
+def _rows_flat(do, o, lse, dlse) -> tuple:
+    """(dO with batch and heads as one dim, lse and Δ = rowsum(dO ∘ O) one
+    value a row, laid along the lanes: a (1, bq) block of (bh, 1, s)).  An lse
+    cotangent (ring attention's online-softmax merge, a block-diffusion loss:
+    both consume lse) folds EXACTLY into Δ: with ∂lse/∂s_ij = p_ij,
+    ds_ij = p_ij·(dp_ij − Δ_i + dlse_i), so the kernels run unchanged on
+    Δ' = Δ − dlse."""
+    bh, s, dv = do.shape[0] * do.shape[1], do.shape[2], do.shape[3]
+    dof = do.reshape(bh, s, dv)
     delta = jnp.sum(
         dof.astype(jnp.float32) * o.reshape(bh, s, dv).astype(jnp.float32), axis=-1
     )
     if dlse is not None:
-        # An lse cotangent (ring-attention online-softmax merge, which
-        # consumes lse) folds EXACTLY into the delta term: with
-        # ∂lse/∂s_ij = p_ij, ds_ij = p_ij·(dp_ij − Δ_i + dlse_i), so the
-        # kernel runs unchanged on Δ' = Δ − dlse.
         delta = delta - dlse.reshape(bh, s).astype(jnp.float32)
-    # one value a row, laid along the lanes: a (1, bq) block of (bh, 1, s)
     delta, lse = delta.reshape(bh, 1, s), lse.reshape(bh, 1, s)
+    return dof, lse, delta
 
-    steps = nq if window is None else _band_steps(s, bq, bk, window)[1]
-    q_index = _q_index(causal, bq, bk, window, nq)
-    row_index = lambda i, j, qi: (i, 0, q_index(i, j, qi)[1])  # noqa: E731
-    kv_index = lambda i, j, qi: (i, j, 0)  # noqa: E731
+
+def _backward_call(kernel, name, steps, q_index, dq_index, like, flat, bq, bk, interpret,
+                   scalars=()):
+    """Every backward kernel's ``pallas_call``: a (batch·head, key block,
+    ``steps``) grid.  ``q_index``: the index map of a step's Q | dO block (and
+    of its lse | Δ row), ``dq_index`` of the dQ block it writes; ``like``: q, k,
+    v as the caller has them, ``flat``: :func:`_heads_flat`'s and
+    :func:`_rows_flat`'s six operands; ``scalars`` as :func:`_forward_call`'s."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, k, v = like
+    vma = vma_union(*flat)
+    b, h, sq, dqk = q.shape
+    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    bh, group = b * h, h // h_kv
+    row_index = lambda *at: (at[0], 0, q_index(*at)[1])  # noqa: E731
+    kv_index = lambda i, j, t, *_: (i, j, 0)  # noqa: E731
     # K and V come in a key/value head, dK and dV go out a query head
     kv_read = _kv_row(kv_index, group)
-    # dQ's block leaves VMEM once: its index stands still until the last key
-    # block, whose steps write one query block each.  At a window it follows
-    # the query blocks, each pair writing the running sum
-    dq_index = lambda i, j, qi: (i, jnp.where(j == nk - 1, qi, 0), 0)  # noqa: E731
-    dq, dk, dv_ = pl.pallas_call(
-        _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window, steps),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, s, dqk), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s, dqk), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s, dv), v.dtype, vma=vma),
-        ),
-        grid=(bh, nk, steps),
+    grid = dict(
+        grid=(bh, sk // bk, steps),
         in_specs=[
             pl.BlockSpec((1, bq, dqk), q_index),
             pl.BlockSpec((1, bk, dqk), kv_read),
@@ -504,31 +544,59 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
             pl.BlockSpec((1, 1, bq), row_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, bq, dqk), dq_index if window is None else q_index),
+            pl.BlockSpec((1, bq, dqk), dq_index),
             pl.BlockSpec((1, bk, dqk), kv_index),
             pl.BlockSpec((1, bk, dv), kv_index),
         ),
         scratch_shapes=[
-            pltpu.VMEM((s, dqk), jnp.float32),
+            pltpu.VMEM((sq, dqk), jnp.float32),
             pltpu.VMEM((bk, dqk), jnp.float32),
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
+    )
+    if scalars:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), **grid))
+    dq, dk, dv_ = pl.pallas_call(
+        kernel,
+        out_shape=(
+            jax.ShapeDtypeStruct((bh, sq, dqk), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, sk, dqk), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype, vma=vma),
+        ),
         compiler_params=pltpu.CompilerParams(
             # dK/dV accumulate along the query blocks and dQ along the key
             # blocks of one (batch·head): only that axis is free
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(s, bq, bk, dqk, dv, q.dtype.itemsize),
+            vmem_limit_bytes=_bwd_vmem_bytes(sq, bq, bk, dqk, dv, q.dtype.itemsize),
         ),
         interpret=interpret,
-        name=BWD_KERNEL if window is None else BWD_WIN_KERNEL,
-    )(qf, kf, vf, dof, lse, delta)
-
+        name=name,
+        **grid,
+    )(*scalars, *flat)
     if group > 1:
         # dK and dV leave the kernel one per QUERY head; a key/value head's is
         # the sum over its group, in the operands' dtype: what the transpose of
         # a caller's ``jnp.repeat`` was
-        dk, dv_ = (jnp.sum(x.reshape(b, h_kv, group, s, x.shape[-1]), axis=2) for x in (dk, dv_))
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape))
+        dk, dv_ = (jnp.sum(x.reshape(b, h_kv, group, sk, x.shape[-1]), axis=2) for x in (dk, dv_))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
+
+
+def _flash_backward(q, k, v, o, lse, do, causal, scale, bq, bk, interpret,
+                    dlse=None, window=None):
+    s = q.shape[2]
+    nq, nk = s // bq, s // bk
+    steps = nq if window is None else _band_steps(s, bq, bk, window)[1]
+    q_index = dq_index = _q_index(causal, bq, bk, window, nq)
+    if window is None:
+        # dQ's block leaves VMEM once: its index stands still until the last
+        # key block, whose steps write one query block each.  (At a window it
+        # follows the query blocks, each pair writing the running sum.)
+        dq_index = lambda i, j, qi: (i, jnp.where(j == nk - 1, qi, 0), 0)  # noqa: E731
+    return _backward_call(
+        _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window, steps),
+        BWD_KERNEL if window is None else BWD_WIN_KERNEL, steps, q_index, dq_index,
+        (q, k, v), _heads_flat(q, k, v) + _rows_flat(do, o, lse, dlse), bq, bk, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -593,26 +661,22 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 #: guard keeps foreign sequence lengths on safe defaults).
 _TUNED_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "flash_blocks.json")
-_tuned_cache: Optional[dict] = None
+
+
+def _sections(doc: dict) -> dict:
+    def pairs(table):
+        return {tuple(int(x) for x in k.split(",")): tuple(v)
+                for k, v in doc.get(table, {}).items()}
+
+    return {"blocks": {int(k): tuple(v) for k, v in doc["blocks"].items()},
+            "banded": pairs("banded"), "block_diffusion": pairs("block_diffusion")}
 
 
 def _tuned_table() -> dict:
     """The artifact's three tables: ``blocks`` by sequence, ``banded`` by
-    (sequence, window), ``block_diffusion`` by (key rows, block length); each
-    empty where the file has none that reads."""
-    global _tuned_cache
-    if _tuned_cache is None:
-        _tuned_cache = {"blocks": {}, "banded": {}, "block_diffusion": {}}
-        try:
-            with open(_TUNED_PATH) as f:
-                doc = json.load(f)
-            _tuned_cache["blocks"] = {int(k): tuple(v) for k, v in doc["blocks"].items()}
-            for table in ("banded", "block_diffusion"):
-                _tuned_cache[table] = {tuple(int(x) for x in k.split(",")): tuple(v)
-                                       for k, v in doc.get(table, {}).items()}
-        except (OSError, ValueError, KeyError, TypeError, AttributeError):
-            pass
-    return _tuned_cache
+    (sequence, window), ``block_diffusion`` by (key rows, block length); none
+    where the file does not read."""
+    return tuned(_TUNED_PATH, _sections)
 
 
 def tuned_blocks(seq: int, window: Optional[int] = None) -> tuple:
@@ -627,10 +691,10 @@ def tuned_blocks(seq: int, window: Optional[int] = None) -> tuple:
     window): a band is a few blocks wide, so smaller blocks compute less
     outside it.  Where that has no entry that divides, the sequence's."""
     tables = _tuned_table()
-    banded = tables["banded"].get((seq, window))
+    banded = tables.get("banded", {}).get((seq, window))
     if banded and seq % banded[0] == 0 and seq % banded[1] == 0:
         return banded
-    table = tables["blocks"]
+    table = tables.get("blocks", {})
 
     def fits(entry) -> bool:
         bq, bk = entry
@@ -644,15 +708,10 @@ def tuned_blocks(seq: int, window: Optional[int] = None) -> tuple:
     return (128, 128)
 
 
-def _platform() -> str:
-    """Platform of the default device (a function so tests can stand in a
-    TPU without mocking devices)."""
-    return jax.devices()[0].platform
-
-
 def _kernel_path(s: int, bq: int, bk: int, interpret: bool) -> bool:
-    """THE decision between the Pallas kernels (True) and the dense
-    reference (False) — the only place either wrapper makes it.
+    """The Pallas kernels (True) or the dense reference (False): the blocks
+    must divide the sequence, and the rest is ``_dispatch.kernels_run``'s
+    call — the only place either wrapper asks.
 
     On a TPU the kernels always run: neither ``interpret`` nor anything
     else selects the reference there, and a sequence the blocks do not
@@ -662,14 +721,12 @@ def _kernel_path(s: int, bq: int, bk: int, interpret: bool) -> bool:
     dense reference stands in, unless the caller asked for the Pallas
     interpreter and the blocks divide."""
     divides = s % bq == 0 and s % bk == 0
-    if _platform() == "tpu":
-        if not divides:
-            raise ValueError(
-                f"flash attention: blocks ({bq}, {bk}) do not divide seq {s}; "
-                "pad the sequence or pass block_q/block_k that divide it"
-            )
-        return True
-    return interpret and divides
+    if not divides and kernels_run(True, interpret=False):  # unasked, kernels run on a TPU alone
+        raise ValueError(
+            f"flash attention: blocks ({bq}, {bk}) do not divide seq {s}; "
+            "pad the sequence or pass block_q/block_k that divide it"
+        )
+    return kernels_run(divides, interpret)
 
 
 def _resolve(q, k, v, scale, block_q, block_k, causal=True, window=None):
@@ -858,104 +915,41 @@ def _bd_keep(qi, j, bq: int, bk: int, half: int, block: int, keys_down: bool = F
 def _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block):
     from jax.experimental import pallas as pl
 
-    def kernel(kv_of, n_kv, kv_whole, q_ref, k_ref, v_ref, o_ref, lse_ref,
-               m_scr, l_scr, acc_scr):
+    def kernel(kv_of, n_kv, kv_whole, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch):
         qi, step = pl.program_id(1), pl.program_id(2)
         at = qi * steps + step
         j = kv_of[at]
+        pl.when(step == 0)(functools.partial(_softmax_init, *scratch))
 
-        @pl.when(step == 0)
-        def _init():
-            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[:] = jnp.zeros_like(l_scr)
-            acc_scr[:] = jnp.zeros_like(acc_scr)
-
-        def pair(masked: bool):
-            # a row with no visible key in a tile adds it at weight 1 under
-            # m = NEG_INF, which its first real maximum wipes: every row sees
-            # a key — a noisy one itself, a clean one the sequence's first
-            v = v_ref[0]
-            s = jax.lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if masked:
-                s = jnp.where(_bd_keep(qi, j, bq, bk, half, block), s, NEG_INF)
-            m, l = m_scr[:], l_scr[:]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[:, 0:1])
-            m_scr[:] = m_new
-            l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+        def pair(keep):
+            _softmax_pair(q_ref, k_ref, v_ref, keep, scale, *scratch)
 
         needed, whole = step < n_kv[qi], kv_whole[at] == 1
-        pl.when(needed & whole)(lambda: pair(False))
-        pl.when(needed & jnp.logical_not(whole))(lambda: pair(True))
-
-        @pl.when(step == steps - 1)
-        def _emit():
-            l = l_scr[:]
-            l = jnp.where(l == 0, 1.0, l)
-            o_ref[0] = (acc_scr[:] / l[:, 0:1]).astype(o_ref.dtype)
-            lse_ref[0] = m_scr[:] + jnp.log(l)
+        pl.when(needed & whole)(functools.partial(pair, None))
+        pl.when(needed & jnp.logical_not(whole))(functools.partial(
+            pair, functools.partial(_bd_keep, qi, j, bq, bk, half, block)))
+        pl.when(step == steps - 1)(functools.partial(_softmax_emit, o_ref, lse_ref, *scratch))
 
     return kernel
 
 
-def _bd_scalars(tiles: dict, names: tuple, vma) -> tuple:
+def _bd_scalars(tiles: dict, names: tuple, *tensors) -> tuple:
     """The named tables as the kernels' scalar operands, typed as varying as
     the tensors are (under ``shard_map``)."""
+    vma = vma_union(*tensors)
     made = (jnp.asarray(tiles[n]) for n in names)
     return tuple(jax.lax.pcast(x, tuple(vma), to="varying") if vma else x for x in made)
 
 
 def _bd_forward(q, k, v, block, scale, bq, bk, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    vma = _vma_union(q, k, v)
-    b, h, sq, dqk = q.shape
-    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
-    tiles = _bd_tiles(sq, sk // 2, block, bq, bk)
-    steps, group = tiles["steps_f"], h // h_kv
-    kv_index = lambda i, qi, t, kv_of, *_: (i // group, kv_of[qi * steps + t], 0)  # noqa: E731
-    q_index = lambda i, qi, t, *_: (i, qi, 0)  # noqa: E731
-    out, lse = pl.pallas_call(
-        _bd_fwd_kernel_factory(bq, bk, steps, scale, sk // 2, block),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32, vma=vma),
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b * h, sq // bq, steps),
-            in_specs=[
-                pl.BlockSpec((1, bq, dqk), q_index),
-                pl.BlockSpec((1, bk, dqk), kv_index),
-                pl.BlockSpec((1, bk, dv), kv_index),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, bq, dv), q_index),
-                pl.BlockSpec((1, bq, LANES), q_index),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, dv), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name=FWD_BD_KERNEL,
-    )(*_bd_scalars(tiles, ("kv_of", "n_kv", "kv_whole"), vma),
-      q.reshape(b * h, sq, dqk), k.reshape(b * h_kv, sk, dqk), v.reshape(b * h_kv, sk, dv))
-    return out.reshape(b, h, sq, dv), lse
+    sq, half, group = q.shape[2], k.shape[2] // 2, q.shape[1] // k.shape[1]
+    tiles = _bd_tiles(sq, half, block, bq, bk)
+    steps = tiles["steps_f"]
+    kv_index = lambda i, qi, t, kv_of, *_: (i, kv_of[qi * steps + t], 0)  # noqa: E731
+    return _forward_call(
+        _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block), FWD_BD_KERNEL, steps,
+        _kv_row(kv_index, group), q, k, v, bq, bk, interpret,
+        _bd_scalars(tiles, ("kv_of", "n_kv", "kv_whole"), q, k, v))
 
 
 def _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block):
@@ -967,116 +961,34 @@ def _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block):
         at = j * steps + step
         qi = q_of[at]
         rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query tile's of dq_scr
+        pl.when(step == 0)(functools.partial(_dkv_init, dk_scr, dv_scr))
 
-        @pl.when(step == 0)
-        def _init_dkv():
-            dk_scr[:] = jnp.zeros_like(dk_scr)
-            dv_scr[:] = jnp.zeros_like(dv_scr)
-
-        def pair(masked: bool):
-            @pl.when(j == first_kv[qi])  # key tiles ascend: the first this query tile meets
-            def _clear_dq():
-                dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
-
-            # (bk, bq), keys down and queries across, as flash_bwd computes a pair
-            q, k, do = q_ref[0], k_ref[0], do_ref[0]
-            st = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            pt = jnp.exp(st - lse_ref[0])
-            if masked:
-                pt = jnp.where(_bd_keep(qi, j, bq, bk, half, block, keys_down=True), pt, 0.0)
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dpt = jax.lax.dot_general(
-                v_ref[0], do, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
-            dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-                dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
-                dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            # the running sum; a query tile's last pair writes it whole
-            dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+        def pair(keep):
+            # key tiles ascend: the first this query tile meets
+            pl.when(j == first_kv[qi])(functools.partial(_dq_clear, dq_scr, rows, bq))
+            _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
+                       dq_scr, dk_scr, dv_scr)
+            _dq_write(dq_ref, dq_scr, rows)  # the running sum; a tile's last pair writes it whole
 
         needed, whole = step < n_q[j], q_whole[at] == 1
-        pl.when(needed & whole)(lambda: pair(False))
-        pl.when(needed & jnp.logical_not(whole))(lambda: pair(True))
-
-        @pl.when(step == steps - 1)
-        def _emit_dkv():
-            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        pl.when(needed & whole)(functools.partial(pair, None))
+        pl.when(needed & jnp.logical_not(whole))(functools.partial(
+            pair, functools.partial(_bd_keep, qi, j, bq, bk, half, block, keys_down=True)))
+        pl.when(step == steps - 1)(functools.partial(_dkv_emit, dk_ref, dv_ref, dk_scr, dv_scr))
 
     return kernel
 
 
 def _bd_backward(q, k, v, o, lse, do, block, scale, bq, bk, interpret, dlse=None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    vma = _vma_union(q, k, v, o, lse, do)
-    b, h, sq, dqk = q.shape
-    h_kv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
-    bh, group = b * h, h // h_kv
-    tiles = _bd_tiles(sq, sk // 2, block, bq, bk)
+    half = k.shape[2] // 2
+    tiles = _bd_tiles(q.shape[2], half, block, bq, bk)
     steps = tiles["steps_b"]
-    dof = do.reshape(bh, sq, dv)
-    delta = jnp.sum(dof.astype(jnp.float32) * o.reshape(bh, sq, dv).astype(jnp.float32), axis=-1)
-    if dlse is not None:  # as _flash_backward folds it
-        delta = delta - dlse.reshape(bh, sq).astype(jnp.float32)
-    delta, lse = delta.reshape(bh, 1, sq), lse.reshape(bh, 1, sq)
-
     q_index = lambda i, j, t, q_of, *_: (i, q_of[j * steps + t], 0)  # noqa: E731
-    row_index = lambda i, j, t, q_of, *_: (i, 0, q_of[j * steps + t])  # noqa: E731
-    kv_index = lambda i, j, t, *_: (i, j, 0)  # noqa: E731
-    kv_read = lambda i, j, t, *_: (i // group, j, 0)  # noqa: E731
-    dq, dk, dv_ = pl.pallas_call(
-        _bd_bwd_kernel_factory(bq, bk, steps, scale, sk // 2, block),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, dqk), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, sk, dqk), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype, vma=vma),
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(bh, sk // bk, steps),
-            in_specs=[
-                pl.BlockSpec((1, bq, dqk), q_index),
-                pl.BlockSpec((1, bk, dqk), kv_read),
-                pl.BlockSpec((1, bk, dv), kv_read),
-                pl.BlockSpec((1, bq, dv), q_index),
-                pl.BlockSpec((1, 1, bq), row_index),
-                pl.BlockSpec((1, 1, bq), row_index),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, bq, dqk), q_index),
-                pl.BlockSpec((1, bk, dqk), kv_index),
-                pl.BlockSpec((1, bk, dv), kv_index),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((sq, dqk), jnp.float32),
-                pltpu.VMEM((bk, dqk), jnp.float32),
-                pltpu.VMEM((bk, dv), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(sq, bq, bk, dqk, dv, q.dtype.itemsize),
-        ),
-        interpret=interpret,
-        name=BWD_BD_KERNEL,
-    )(*_bd_scalars(tiles, ("q_of", "n_q", "q_whole", "first_kv"), vma),
-      q.reshape(bh, sq, dqk), k.reshape(b * h_kv, sk, dqk), v.reshape(b * h_kv, sk, dv),
-      dof, lse, delta)
-    if group > 1:
-        dk, dv_ = (jnp.sum(x.reshape(b, h_kv, group, sk, x.shape[-1]), axis=2) for x in (dk, dv_))
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
+    rows = _rows_flat(do, o, lse, dlse)
+    scalars = _bd_scalars(tiles, ("q_of", "n_q", "q_whole", "first_kv"), q, k, v, o, lse, do)
+    return _backward_call(
+        _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block), BWD_BD_KERNEL, steps,
+        q_index, q_index, (q, k, v), _heads_flat(q, k, v) + rows, bq, bk, interpret, scalars)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -1103,7 +1015,7 @@ def tuned_block_diffusion_blocks(keys: int, block_length: int, queries: int) -> 
     the artifact's ``block_diffusion`` entry for (keys, block length) where it
     has one whose tiles divide the queries and the keys, else the half's plain
     entry (:func:`tuned_blocks`, which divides L and so both)."""
-    entry = _tuned_table()["block_diffusion"].get((keys, block_length))
+    entry = _tuned_table().get("block_diffusion", {}).get((keys, block_length))
     if entry and queries % entry[0] == 0 and keys % entry[1] == 0:
         return entry
     return tuned_blocks(keys // 2)

@@ -37,8 +37,9 @@ tiles lie over the last two dims); XLA's form turns to head-major inside
 itself.
 
 Two implementations of that one algorithm, chosen by :func:`_kernel_path`
-from what the code can observe (the default device's platform and the
-shapes): on a TPU, at shapes the kernels tile, the Pallas kernels of
+from what the code can observe (the shapes, and through
+``_dispatch.kernels_run`` the default device's platform): on a TPU, at
+shapes the kernels tile, the Pallas kernels of
 ``ops/gated_delta_kernels.py`` (``gdn_chunk_inverse``, ``gdn_scan_fwd`` and,
 behind a ``custom_vjp``, ``gdn_scan_bwd``: a chunk stays in VMEM from its
 first product to its last, the state in VMEM scratch along the sequence; the
@@ -54,8 +55,6 @@ by either.
 
 from __future__ import annotations
 
-import functools
-import json
 import os
 from typing import Optional, Sequence
 
@@ -64,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from byteps_tpu.core.telemetry import counters
+from byteps_tpu.ops._dispatch import LANES, kernels_run, tuned
 from byteps_tpu.ops.gated_delta_kernels import STACK, gated_delta_kernels
 
 CHUNK = 64
@@ -147,30 +147,23 @@ def _inverse_bwd(t, dt):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def _platform() -> str:
-    """Platform of the default device (a function so tests can stand in a
-    TPU without mocking devices)."""
-    return jax.devices()[0].platform
-
-
 def _kernel_path(chunk: int, dk: int, dv: int, interpret: bool) -> bool:
-    """THE decision between the Pallas kernels (True) and XLA's chunked form
-    (False), from the platform and the shapes alone.  The kernels tile a
-    chunk of 64 or 128 tokens (whole sublane tiles of any compute dtype, and
-    a whole number of them stacks to the MXU's 128 rows) and head sizes of
-    whole lane tiles; off a TPU they run only where the caller asked for the
-    Pallas interpreter.  Everything else is XLA's."""
-    tiles = chunk in (STACK // 2, STACK) and dk % 128 == 0 and dv % 128 == 0
-    return tiles and (interpret or _platform() == "tpu")
+    """The Pallas kernels (True) or XLA's chunked form (False), from the
+    platform and the shapes alone.  The kernels tile a chunk of 64 or 128
+    tokens (whole sublane tiles of any compute dtype, and a whole number of
+    them stacks to the MXU's 128 rows) and head sizes of whole lane tiles;
+    where they fit, ``_dispatch.kernels_run`` decides.  Everything else is
+    XLA's."""
+    tiles = chunk in (STACK // 2, STACK) and dk % LANES == 0 and dv % LANES == 0
+    return kernels_run(tiles, interpret)
 
 
-@functools.cache
+def _sections(doc: dict) -> dict:
+    return {int(s): tuple(b) for s, b in doc["blocks"].items()}
+
+
 def _tuned_table() -> dict:
-    try:
-        with open(_TUNED_PATH) as f:
-            return {int(s): tuple(b) for s, b in json.load(f)["blocks"].items()}
-    except (OSError, ValueError, KeyError, TypeError, AttributeError):
-        return {}
+    return tuned(_TUNED_PATH, _sections)
 
 
 def tuned_blocks(n_chunks: int, chunk: int, blocks: Optional[Sequence[int]] = None) -> tuple:

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from byteps_tpu.ops.flash_attention import _vma_union as _vma
+from byteps_tpu.ops._dispatch import vma_union
 
 #: the kernels' names: a trace files their time under these (none starts
 #: with ``flash_``: the benchmark's readers take such calls for flash kernels)
@@ -165,7 +165,7 @@ def _chunk_inverse(k, g, beta, hk, chunk, nb, interpret):
     scalars = pl.BlockSpec((r, groups, 1, w), lambda i, j: (i, j, 0, 0))
     return pl.pallas_call(
         _inverse_kernel(chunk, w, groups, r),
-        out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), _F32, vma=_vma(k, g, beta)),
+        out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), _F32, vma=vma_union(k, g, beta)),
         grid=(bhk, n // nb),
         in_specs=[pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i // hk, j, i % hk)),
                   scalars, scalars],
@@ -262,7 +262,7 @@ def _scan_forward(q, k, v, g, beta, t, hk, chunk, nb, save, interpret):
     bhk, bhv, r, dk, dv = _dims(q, v, g, hk)
     s = q.shape[1]
     n, cdt = s // chunk, q.dtype
-    vma = _vma(q, k, v, g, beta, t)
+    vma = vma_union(q, k, v, g, beta, t)
     spec = _specs(hk, r, nb, chunk, dk, dv, lambda j: j)
     by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
     out_shape = [jax.ShapeDtypeStruct(v.shape, _F32, vma=vma)]
@@ -372,7 +372,7 @@ def _scan_backward(q, k, v, g, beta, t, entering, do, hk, chunk, nb, interpret):
     bhk, bhv, r, dk, dv = _dims(q, v, g, hk)
     s = q.shape[1]
     n, cdt = s // chunk, q.dtype
-    vma = _vma(q, k, v, g, beta, t, entering, do)
+    vma = vma_union(q, k, v, g, beta, t, entering, do)
     last = n // nb - 1
     # from the last block to the first
     spec = _specs(hk, r, nb, chunk, dk, dv, lambda j: last - j)

@@ -12,9 +12,10 @@ dimension i is paired with i + d/2, both turned by ``pos · theta^(-2i/d)``
 the same angles and ``sin`` carries the sign, minus on the first half.
 
 Two implementations behind one ``custom_vjp``, chosen in ONE function
-(:func:`_kernel_path`, from the platform and the shapes): on a TPU at head
-sizes of whole lane tiles two Pallas kernels, ``head_norm_fwd`` (reads x,
-writes the flash kernels' operand) and ``head_norm_bwd`` (reads the cotangent
+(:func:`_kernel_path`: the shapes here, the platform in
+``_dispatch.kernels_run``): on a TPU at head sizes of whole lane tiles two
+Pallas kernels, ``head_norm_fwd`` (reads x, writes the flash kernels'
+operand) and ``head_norm_bwd`` (reads the cotangent
 and x, writes dx and the scale's gradient a query block), each a block's
 arithmetic in registers; XLA's form of the same equations everywhere else
 (every CPU test) and as the kernels' oracle.  Written as XLA's form alone the
@@ -25,7 +26,7 @@ rebuilds the statistic, one lane reduction a row.
 
 ``head_rope(x, d, theta)`` is the pass without the norm, for a mixer whose
 heads take positions and no norm (``models/early_route_moe.py``'s sliding
-layers): the same tables, the same ``_turn``, the same blocks and grid, the
+layers): the same tables, the same ``turn``, the same blocks and grid, the
 same chooser.  It reads x (B, S, H·d) as the projection's product stands —
 token-major, heads side by side, no transposed copy of it — and writes the
 flash kernels' head-major operand (``head_rope_fwd``); the backward pass turns
@@ -51,9 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from byteps_tpu.core.telemetry import counters
-from byteps_tpu.ops.flash_attention import _vma_union as _vma
+from byteps_tpu.ops._dispatch import LANES, kernels_run, vma_union
 
-LANES = 128
 #: rows of a block: (1024, 128) is 256 kB of bf16 and 512 kB a table
 BLOCK_ROWS = 1024
 #: f32 sublanes: the scale's gradient leaves the kernel as (8, d) partial sums
@@ -73,23 +73,16 @@ def rope_tables(s: int, d: int, theta: float):
     return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
 
 
-def _platform() -> str:
-    """Platform of the default device (a function so that a test or a
-    compile for a described chip can stand in a TPU)."""
-    return jax.devices()[0].platform
-
-
 def _block_rows(s: int) -> int:
     return min(BLOCK_ROWS, s)
 
 
 def _kernel_path(s: int, d: int, interpret: bool) -> bool:
-    """THE decision between the Pallas kernels (True) and XLA's form
-    (False).  The kernels take heads of whole lane tiles and a sequence of
-    whole blocks; on a TPU they run wherever they can, off a TPU (Mosaic
-    cannot compile there) only under the Pallas interpreter."""
+    """The Pallas kernels (True) or XLA's form (False).  The kernels take
+    heads of whole lane tiles and a sequence of whole blocks; where they fit,
+    ``_dispatch.kernels_run`` decides."""
     fits = d % LANES == 0 and s % _block_rows(s) == 0 and _block_rows(s) % SUBLANES == 0
-    return fits and (interpret or _platform() == "tpu")
+    return kernels_run(fits, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -97,27 +90,27 @@ def _kernel_path(s: int, d: int, interpret: bool) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _turn(y, cos, sin, roll):
+def turn(y, cos, sin, roll):
     return y * cos + roll(y) * sin
 
 
 def _forward(x32, w, eps, tables, roll):
     r = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     y = x32 * r * w
-    return y if tables is None else _turn(y, *tables, roll)
+    return y if tables is None else turn(y, *tables, roll)
 
 
 def _backward(g32, x32, w, eps, tables, roll):
     """(dx, g · n before any sum): ``g`` turned back (the rotation's
     transpose is the rotation by the negative angle), then the norm's."""
     if tables is not None:
-        g32 = _turn(g32, tables[0], -tables[1], roll)
+        g32 = turn(g32, tables[0], -tables[1], roll)
     r = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     n, dn = x32 * r, g32 * w
     return r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True)), g32 * n
 
 
-def _xla_roll(y):
+def xla_roll(y):
     return jnp.roll(y, y.shape[-1] // 2, axis=-1)
 
 
@@ -158,7 +151,7 @@ def _forward_kernels(x, w, eps, tables, interpret):
 
     y = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), x.dtype, vma=_vma(x, w)),
+        out_shape=jax.ShapeDtypeStruct((b * h, s, d), x.dtype, vma=vma_union(x, w)),
         grid=grid,
         in_specs=[block, scale, *table_specs],
         out_specs=block,
@@ -192,7 +185,7 @@ def _backward_kernels(g, x, w, eps, tables, interpret):
         # across sublanes in the kernel, the (8, d) that is left is XLA's
         dw_ref[0] = dw_ref[0] + jnp.sum(gn.reshape(rows // SUBLANES, SUBLANES, d), axis=0)
 
-    vma = _vma(g, x, w)
+    vma = vma_union(g, x, w)
     dx, dw = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((b * h, s, d), x.dtype, vma=vma),
@@ -228,7 +221,7 @@ def _fwd(x, w, eps, theta, interpret):
     if _kernel_path(x.shape[-2], x.shape[-1], interpret):
         y = _forward_kernels(x, w, eps, _tables(x, theta), interpret)
     else:
-        y = _forward(x.astype(jnp.float32), w, eps, _tables(x, theta), _xla_roll).astype(x.dtype)
+        y = _forward(x.astype(jnp.float32), w, eps, _tables(x, theta), xla_roll).astype(x.dtype)
     return y, (x, w)
 
 
@@ -238,7 +231,7 @@ def _bwd(eps, theta, interpret, res, g):
         dx, dw = _backward_kernels(g, x, w, eps, _tables(x, theta), interpret)
     else:
         dx, gn = _backward(g.astype(jnp.float32), x.astype(jnp.float32), w, eps,
-                           _tables(x, theta), _xla_roll)
+                           _tables(x, theta), xla_roll)
         dx, dw = dx.astype(x.dtype), jnp.sum(gn, axis=tuple(range(gn.ndim - 1)))
     return dx, dw.astype(w.dtype)
 
@@ -266,7 +259,7 @@ def head_norm_rope(x, w, eps: float, theta=None, interpret: bool = False):
 
 
 def _turn_kernel(x, d, tables, back, interpret):
-    """``_turn`` on the norm's blocks and grid, and the change of layout in
+    """``turn`` on the norm's blocks and grid, and the change of layout in
     the blocks' index maps: forth it reads a head's columns of the
     token-major x (B, S, H·d) and writes them head-major (B·H, S, d), back
     the other way."""
@@ -278,13 +271,13 @@ def _turn_kernel(x, d, tables, back, interpret):
     tokens = pl.BlockSpec((1, _block_rows(s), d), lambda qi, i: (i // h, qi, i % h))
 
     def kernel(x_ref, cos_ref, sin_ref, y_ref):
-        y_ref[0] = _turn(x_ref[0].astype(jnp.float32), cos_ref[...], sin_ref[...],
+        y_ref[0] = turn(x_ref[0].astype(jnp.float32), cos_ref[...], sin_ref[...],
                          _lane_roll).astype(y_ref.dtype)
 
     y = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, s, h * d) if back else (b * h, s, d), x.dtype,
-                                       vma=_vma(x)),
+                                       vma=vma_union(x)),
         grid=grid,
         in_specs=[heads if back else tokens, *table_specs],
         out_specs=tokens if back else heads,
@@ -305,7 +298,7 @@ def _turned(x, d, theta, back, kernels, interpret):
     if kernels:
         return _turn_kernel(x, d, tables, back, interpret)
     x = x if back else jnp.swapaxes(x.reshape(b, s, -1, d), 1, 2)
-    y = _turn(x.astype(jnp.float32), *tables, _xla_roll).astype(x.dtype)
+    y = turn(x.astype(jnp.float32), *tables, xla_roll).astype(x.dtype)
     return jnp.swapaxes(y, 1, 2).reshape(b, s, -1) if back else y
 
 

@@ -24,7 +24,8 @@ common permutation: the scores, and so the attention's output, are the
 interleaved rope's (tests/test_latent_moe_pieces.py holds both statements).
 
 Two implementations behind one ``custom_vjp``, chosen in ONE function
-(:func:`_kernel_path`, from the platform and the shapes): on a TPU at heads of
+(:func:`_kernel_path`: the shapes here, the platform in
+``_dispatch.kernels_run``): on a TPU at heads of
 [one lane tile | half a lane tile] and values of one lane tile, two Pallas
 kernels — ``mla_heads_fwd`` reads a block of each product by index map and
 writes the three operands, ``mla_heads_bwd`` is its transpose: reads dq | dk |
@@ -51,8 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from byteps_tpu.ops.flash_attention import _vma_union as _vma
-from byteps_tpu.ops.head_norm import LANES, _turn, _xla_roll, rope_tables
+from byteps_tpu.ops._dispatch import LANES, kernels_run, vma_union
+from byteps_tpu.ops.head_norm import rope_tables, turn, xla_roll
 
 #: rows of a block: a pair of heads is (rows, 2 · 192) of bf16 each of q and k
 BLOCK_ROWS = 512
@@ -68,25 +69,18 @@ def even_first(w):
     return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
-def _platform() -> str:
-    """Platform of the default device (a function so that a test or a
-    compile for a described chip can stand in a TPU)."""
-    return jax.devices()[0].platform
-
-
 def _block_rows(s: int) -> int:
     return min(BLOCK_ROWS, s)
 
 
 def _kernel_path(s: int, h: int, n: int, r: int, d_v: int, interpret: bool) -> bool:
-    """THE decision between the Pallas kernels (True) and XLA's form
-    (False).  The kernels take heads in pairs, each [a lane tile | half a
-    lane tile], values of a lane tile and a sequence of whole blocks; on a TPU
-    they run wherever they can, off a TPU (Mosaic cannot compile there) only
-    under the Pallas interpreter."""
+    """The Pallas kernels (True) or XLA's form (False).  The kernels take
+    heads in pairs, each [a lane tile | half a lane tile], values of a lane
+    tile and a sequence of whole blocks; where they fit,
+    ``_dispatch.kernels_run`` decides."""
     fits = (n == d_v == LANES and 2 * r == LANES and h % 2 == 0
             and s % _block_rows(s) == 0 and _block_rows(s) % SUBLANES == 0)
-    return fits and (interpret or _platform() == "tpu")
+    return kernels_run(fits, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +96,7 @@ def _forward(q_nope, q_rope, k_nope, v, k_rope, h, tables):
         return x.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
 
     def turned(x):
-        return _turn(x.astype(jnp.float32), cos, sin, _xla_roll).astype(x.dtype)
+        return turn(x.astype(jnp.float32), cos, sin, xla_roll).astype(x.dtype)
 
     key = jnp.broadcast_to(turned(k_rope)[:, None], (b, h, s, k_rope.shape[-1]))
     return (jnp.concatenate([heads(q_nope), turned(heads(q_rope))], axis=-1),
@@ -119,7 +113,7 @@ def _backward(dq, dk, dv, n, tables):
         return x.transpose(0, 2, 1, 3).reshape(b, s, -1)
 
     def back(x32, dtype):
-        return _turn(x32, cos, -sin, _xla_roll).astype(dtype)
+        return turn(x32, cos, -sin, xla_roll).astype(dtype)
 
     key = jnp.sum(dk[..., n:].astype(jnp.float32), axis=1)
     return (tokens(dq[..., :n]), tokens(back(dq[..., n:].astype(jnp.float32), dq.dtype)),
@@ -196,7 +190,7 @@ def _forward_kernels(q_nope, q_rope, k_nope, v, k_rope, h, theta, interpret):
             vo_ref[one] = v_ref[0, :, one * d_v:(one + 1) * d_v]
 
     args = (q_nope, q_rope, k_nope, v, k_rope)
-    vma = _vma(*args)
+    vma = vma_union(*args)
     q, k, v = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((b * h, s, n + r), q_nope.dtype, vma=vma),
@@ -245,7 +239,7 @@ def _backward_kernels(dq, dk, dv, n, theta, interpret):
             whole = sum_ref[...] + _other_head(sum_ref[...])
             kr_ref[0] = _turn_pair(whole, cos, back)[:, :r].astype(kr_ref.dtype)
 
-    vma = _vma(dq, dk, dv)
+    vma = vma_union(dq, dk, dv)
     token = lambda width, like: jax.ShapeDtypeStruct((b, s, width), like.dtype, vma=vma)  # noqa: E731
     return pl.pallas_call(
         kernel,

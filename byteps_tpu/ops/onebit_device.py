@@ -13,7 +13,7 @@ consumes device-compressed payloads unchanged.
 The packing is a Pallas kernel (lane reduction over a 32-wide bit-weight
 expansion).  On a TPU it is the only packer: any length is zero-padded on
 the device to the kernel's block.  Off a TPU :func:`_pack_jnp` stands in —
-see ``onebit_compress_device``, the one place that decides.
+see ``onebit_compress_device``, the one place that asks ``_dispatch.kernels_run``.
 """
 
 from __future__ import annotations
@@ -23,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from byteps_tpu.ops._dispatch import kernels_run
 
 
 def _scale(flat: jax.Array, scaling: bool) -> jax.Array:
@@ -75,13 +77,12 @@ def onebit_compress_device(
 
     flat = grad.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
-    # THE decision between the Pallas packer and _pack_jnp.  On a TPU the
-    # kernel runs for every length (the engine's default partition,
-    # 1,024,000 elements, is not a block multiple — hence the padding
-    # below).  Off a TPU Mosaic cannot compile, so the jnp packer stands
-    # in (what the CPU suite's engine tests run) unless the caller asked
-    # for the Pallas interpreter.
-    if jax.devices()[0].platform != "tpu" and not interpret:
+    # The Pallas packer or _pack_jnp: the kernel fits every length (the
+    # engine's default partition, 1,024,000 elements, is not a block
+    # multiple — hence the padding below), so ``_dispatch.kernels_run``
+    # alone decides; off a TPU the jnp packer stands in (what the CPU
+    # suite's engine tests run) unless the caller asked for the interpreter.
+    if not kernels_run(True, interpret):
         return _pack_jnp(flat, scaling)
 
     # pad with +0.0: sign bit clear, and the scale is taken over the n real

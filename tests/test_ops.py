@@ -4,11 +4,14 @@ Flash attention must match dense attention exactly; device onebit must be
 bit-identical to the host/C++ codec's wire format.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from byteps_tpu.ops import _dispatch
 from byteps_tpu.ops.flash_attention import _dense_reference, flash_attention
 from byteps_tpu.ops.onebit_device import (
     onebit_compress_device,
@@ -53,11 +56,11 @@ class TestFlashAttention:
     def test_odd_shapes_raise_on_a_tpu(self, monkeypatch):
         """On a TPU the dense reference is never chosen quietly: a sequence
         the blocks do not divide is an error in both wrappers.  The platform
-        is injected at the one decision point (_kernel_path)."""
+        is injected at the one probe (``_dispatch.platform``)."""
         import importlib
 
         fa = importlib.import_module("byteps_tpu.ops.flash_attention")
-        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
         q = jnp.zeros((1, 1, 200, 32), jnp.float32)  # 200 % 128 != 0
         with pytest.raises(ValueError, match="do not divide seq 200"):
             fa.flash_attention(q, q, q, causal=True)
@@ -412,7 +415,7 @@ class TestFlashBand:
         import hashlib
 
         fa = self._fa()
-        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
         q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16)
         grad = jax.grad(lambda q, k, v: jnp.sum(
             fa.flash_attention(q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
@@ -428,7 +431,6 @@ class TestFlashBand:
         path.write_text(json.dumps({"blocks": {"512": [256, 256]},
                                     "banded": {"512,128": [64, 128], "512,96": [100, 100]}}))
         monkeypatch.setattr(fa, "_TUNED_PATH", str(path))
-        monkeypatch.setattr(fa, "_tuned_cache", None)
         assert fa.tuned_blocks(512) == (256, 256)  # the plain entry, untouched
         assert fa.tuned_blocks(512, 128) == (64, 128)
         assert fa.tuned_blocks(512, 64) == (256, 256)  # no banded entry: the sequence's
@@ -437,7 +439,6 @@ class TestFlashBand:
     def test_the_committed_tables(self, monkeypatch):
         """The existing entries stand as they were; the cell's banded entry is there."""
         fa = self._fa()
-        monkeypatch.setattr(fa, "_tuned_cache", None)
         assert [fa.tuned_blocks(s) for s in (512, 1024, 2048, 8192, 16384)] == [
             (512, 512), (512, 512), (512, 512), (1024, 1024), (1024, 1024)]
         bq, bk = fa.tuned_blocks(16384, 2048)
@@ -541,7 +542,7 @@ class TestHeadNormRope:
         gradient; at a head that does not tile, none."""
         from byteps_tpu.ops import head_norm as hn
 
-        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
 
         grad = jax.grad(lambda x, w: jnp.sum(hn.head_norm_rope(x, w, 1e-5, 10000.0)
                                              .astype(jnp.float32)), argnums=(0, 1))
@@ -662,7 +663,7 @@ class TestHeadRope:
         assert grown(lambda: hn.head_rope(x, 128, 1e4, interpret=True)) == (1, 0)
         step = jax.jit(jax.grad(lambda x: jnp.sum(hn.head_rope(x, 128, 1e4))))
         assert grown(lambda: (step(x), step(x))) == (0, 1)  # traced once, run twice
-        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
         small = jax.ShapeDtypeStruct((1, 256, 2 * 64), jnp.bfloat16)
         assert grown(lambda: jax.eval_shape(lambda x: hn.head_rope(x, 64, 1e4), small)) == (0, 1)
 
@@ -673,7 +674,7 @@ class TestHeadRope:
         one chooser the normed pass has)."""
         from byteps_tpu.ops import head_norm as hn
 
-        monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
         grad = jax.grad(lambda x: jnp.sum(hn.head_rope(x, 128, 1.5e6).astype(jnp.float32)))
         x = jax.ShapeDtypeStruct((2, 2048, 7 * 128), jnp.bfloat16)
         assert _kernel_names(grad, x) == [hn.ROPE_BWD_KERNEL, hn.ROPE_FWD_KERNEL]
@@ -721,6 +722,19 @@ class TestOneBitDevice:
         assert words.shape == (4,)  # ceil(100/32)
         out = onebit_decompress_device(scale, words, 100)
         np.testing.assert_allclose(np.asarray(out), 1.0)
+
+    def test_the_packer_is_chosen_at_the_seam(self, monkeypatch):
+        """``onebit_compress_device`` asks ``_dispatch.kernels_run`` as every
+        kernel module does: a TPU stood in at ``_dispatch.platform`` gives the
+        Pallas packer without ``interpret``, the CPU the jnp one.  (A length no
+        other test traces: the function is jitted, and a trace is kept.)"""
+        def packers(n):
+            grad = jax.ShapeDtypeStruct((n,), jnp.float32)
+            return len(list(_pallas_calls(jax.make_jaxpr(onebit_compress_device)(grad).jaxpr)))
+
+        assert packers(7_001) == 0
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+        assert packers(7_003) == 1
 
 
 class TestFlashLse:
@@ -930,14 +944,12 @@ class TestTunedBlocks:
         path = tmp_path / "flash_blocks.json"
         path.write_text(json.dumps(doc))
         monkeypatch.setattr(fa, "_TUNED_PATH", str(path))
-        monkeypatch.setattr(fa, "_tuned_cache", None)
         return fa
 
     def test_default_when_untuned(self, monkeypatch, tmp_path):
         fa = self._module()
 
         monkeypatch.setattr(fa, "_TUNED_PATH", str(tmp_path / "absent.json"))
-        monkeypatch.setattr(fa, "_tuned_cache", None)
         assert fa.tuned_blocks(512) == (128, 128)
 
     def test_exact_and_nearest_below(self, monkeypatch, tmp_path):
@@ -997,3 +1009,134 @@ class TestTunedBlocks:
         )
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# every kernel's body, held: what a refactor under byteps_tpu/ops/ must not move
+# ---------------------------------------------------------------------------
+
+
+def _ops_modules():
+    import importlib
+    import pkgutil
+
+    import byteps_tpu.ops as ops
+
+    return [importlib.import_module(f"byteps_tpu.ops.{m.name}")
+            for m in pkgutil.iter_modules(ops.__path__)]
+
+
+def _all_pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_pallas_eqns(sub)
+
+
+def _flash_family(**kw):
+    fa = TestFlashBand._fa()
+    q, k = jnp.ones((1, 2, 128, 32), jnp.float32), jnp.ones((1, 1, 128, 32), jnp.float32)
+    v = jnp.ones((1, 1, 128, 48), jnp.float32)
+    return (lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=32, interpret=True, **kw))), (q, k, v)
+
+
+_banded_family = functools.partial(_flash_family, window=40)
+
+
+def _block_diffusion_family():
+    fa = TestFlashBand._fa()
+    q, k = jnp.ones((1, 4, 32, 8), jnp.float32), jnp.ones((1, 2, 64, 8), jnp.float32)
+    v = jnp.ones((1, 2, 64, 16), jnp.float32)
+    return (lambda q, k, v: jnp.sum(fa.block_diffusion_attention(
+        q, k, v, 4, block_q=16, block_k=16, interpret=True))), (q, k, v)
+
+
+def _delta_family():
+    from byteps_tpu.ops import gated_delta as gd
+
+    q = jnp.ones((1, 128, 1, 128), jnp.float32)
+    v, g = jnp.ones((1, 128, 2, 128), jnp.float32), -jnp.ones((1, 128, 2), jnp.float32)
+    return (lambda q, k, v, g, beta: jnp.sum(gd.chunked_gated_delta_rule(
+        q, k, v, g, beta, chunk=64, interpret=True))), (q, q, v, g, -g)
+
+
+def _head_norm_family():
+    from byteps_tpu.ops.head_norm import head_norm_rope
+
+    x, w = jnp.ones((1, 2, 16, 128), jnp.bfloat16), jnp.ones((128,), jnp.float32)
+    return (lambda x, w: jnp.sum(head_norm_rope(x, w, 1e-6, 1e4, interpret=True)
+                                 .astype(jnp.float32))), (x, w)
+
+
+def _head_rope_family():
+    from byteps_tpu.ops.head_norm import head_rope
+
+    x = jnp.ones((1, 16, 2 * 128), jnp.bfloat16)
+    return (lambda x: jnp.sum(head_rope(x, 128, 1e4, interpret=True).astype(jnp.float32))), (x,)
+
+
+def _mla_heads_family():
+    from byteps_tpu.ops.mla_heads import mla_heads
+
+    wide, rope = jnp.ones((1, 16, 2 * 128), jnp.bfloat16), jnp.ones((1, 16, 2 * 64), jnp.bfloat16)
+    key = jnp.ones((1, 16, 64), jnp.bfloat16)
+    return (lambda *xs: sum(jnp.sum(y.astype(jnp.float32)) for y in mla_heads(
+        *xs, 2, 1e4, interpret=True))), (wide, rope, wide, wide, key)
+
+
+#: kernel name → (how its public function is traced, sha256 of the
+#: ``pallas_call`` equation that carries the name: grid, every block's shape
+#: and index map, the compiler's parameters and the kernel's inner jaxpr —
+#: scratch shapes are its arguments' —, printed without source info).  Taken at
+#: 5708eba, before PR 61 moved a line under ``byteps_tpu/ops/``: a refactor of
+#: a kernel keeps its digest, a change that means to move a kernel re-takes
+#: that kernel's and says so.  Tracing alone; nothing runs.
+FROZEN_KERNELS = {
+    "flash_fwd": (_flash_family, "17f4984afcbb30d5"),
+    "flash_bwd": (_flash_family, "dea075dbecfc2238"),
+    "flash_fwd_win": (_banded_family, "751d10e1f1c0fbe9"),
+    "flash_bwd_win": (_banded_family, "06380e8f4f614eda"),
+    "flash_fwd_bd": (_block_diffusion_family, "0e5913f639e34235"),
+    "flash_bwd_bd": (_block_diffusion_family, "aaa3ccccd3238e29"),
+    "gdn_chunk_inverse": (_delta_family, "d774a47cbde0deb6"),
+    "gdn_scan_fwd": (_delta_family, "fda07b824fb7a6f1"),
+    "gdn_scan_bwd": (_delta_family, "9d43e1a5acde0992"),
+    "head_norm_fwd": (_head_norm_family, "4ac4c6ccddd05b64"),
+    "head_norm_bwd": (_head_norm_family, "5c72e95db22fa069"),
+    "head_rope_fwd": (_head_rope_family, "d5d946f207dc99c3"),
+    "head_rope_bwd": (_head_rope_family, "1e07dd621526ddea"),
+    "mla_heads_fwd": (_mla_heads_family, "509e04eb94d7e3c1"),
+    "mla_heads_bwd": (_mla_heads_family, "64c84232f3393545"),
+}
+
+
+@functools.cache
+def _traced_kernels(family):
+    """{kernel name: digest} of a family's function and its gradient."""
+    import hashlib
+
+    fn, args = family()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(fn, argnums=tuple(range(len(args)))))(*args).jaxpr
+    found = {}
+    for eqn in _all_pallas_eqns(jaxpr):
+        grid = eqn.params["grid_mapping"]
+        text = "\n".join([
+            f"grid {grid.grid} index operands {grid.num_index_operands}",
+            *(f"block {b.block_shape} of {b.array_aval.shape} at {b.index_map_jaxpr}"
+              for b in grid.block_mappings),
+            str(eqn.params["compiler_params"]), f"out {eqn.params['out_avals']}",
+            eqn.params["jaxpr"].pretty_print(source_info=False, name_stack=False)])
+        assert eqn.params["name"] not in found, "a kernel traced twice: two digests for one name"
+        found[eqn.params["name"]] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(FROZEN_KERNELS))
+def test_a_kernels_body_grid_and_blocks_stand(name):
+    named = {value for module in _ops_modules() for key, value in vars(module).items()
+             if key.endswith("_KERNEL")}
+    assert named == set(FROZEN_KERNELS), "every *_KERNEL name under byteps_tpu/ops/ is held here"
+    family, digest = FROZEN_KERNELS[name]
+    assert _traced_kernels(family)[name] == digest

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from byteps_tpu.ops import _dispatch
+
 fa = importlib.import_module("byteps_tpu.ops.flash_attention")
 
 
@@ -58,7 +60,7 @@ def test_flash_kernels_compile_at_the_latent_attention_shape(one_chip, no_compil
     kernel (dQ's f32 accumulator over the whole sequence, 8.4 MB in VMEM
     under the limit the kernel states), with the blocks ops/flash_blocks.json
     commits for that sequence."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     b, h, s, d_qk, d_v = 2, 32, 8192, 192, 128
     q, k = (jax.ShapeDtypeStruct((b, h, s, d_qk), jnp.bfloat16, sharding=one_chip)
             for _ in range(2))
@@ -117,7 +119,7 @@ def test_flash_kernels_compile_at_the_gated_attention_shape(one_chip, no_compile
     1024 x 1024, which the pair of backward kernels could not hold under
     Mosaic's default VMEM limit at head size 256; the one kernel asks for its
     own (dQ's accumulator alone is 16.8 MB here)."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     assert fa.tuned_blocks(16384) == (1024, 1024)
     q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16, sharding=one_chip)
 
@@ -134,7 +136,7 @@ def test_flash_kernels_compile_at_the_grouped_query_shape(one_chip, no_compile_c
     key/value heads repeated, half a lane tile in the contraction — the
     forward kernel and the backward kernel, with the blocks
     ops/flash_blocks.json commits for that sequence."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
@@ -157,7 +159,7 @@ def test_flash_kernels_compile_at_the_sliding_window_shape(one_chip, no_compile_
     as long as the band is wide and not as the sequence; and the full causal
     pair of the global layer at the sequence's plain entry.  Grouped, K and V
     are the kernels' operands at 4 heads and no array of 32 stands for them."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     bq, bk = fa.tuned_blocks(16384, window)
     assert (16384, 2048) in fa._tuned_table()["banded"]
     assert max(fa._band_steps(16384, bq, bk, 2048)) <= 6 < 16384 // max(bq, bk)
@@ -191,7 +193,7 @@ def test_flash_kernels_compile_at_the_block_diffusion_shape(one_chip, no_compile
     (a last layer's call).  K and V are the kernels' operands at 4 heads; the
     innermost grid axes are as long as the table's longest rows, not as the
     sequence, and the mask keeps under 0.6 of a causal call's tile pairs."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     bq, bk = fa.tuned_block_diffusion_blocks(16384, 4, queries)
     tiles = fa._bd_tiles(queries, 8192, 4, bq, bk)
     causal_pairs = sum(min((qi + 1) * bq - 1, 16383) // bk + 1 for qi in range(16384 // bq))
@@ -223,7 +225,7 @@ def test_flash_kernels_compile_at_the_early_routed_shape(one_chip, no_compile_ca
     them), the banded pair at window 4096 with the blocks
     ops/flash_blocks.json commits for (16384, 4096), and the full causal pair
     of the global layer."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     assert (16384, 4096) in fa._tuned_table()["banded"]
     q = jax.ShapeDtypeStruct((2, 28, 16384, 128), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((2, 4, 16384, 128), jnp.bfloat16, sharding=one_chip)
@@ -249,7 +251,7 @@ def test_flash_kernels_compile_at_sixteen_query_heads_a_key_value_head(one_chip,
     ``h // 16`` by index map; K and V are their operands at 2 x 2 heads), the
     full causal pair with the blocks ops/flash_blocks.json commits for 8192,
     no positions."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((2, 2, 8192, 128), jnp.bfloat16, sharding=one_chip)
 
@@ -271,7 +273,7 @@ def test_flash_kernels_compile_at_sixteen_heads_each_with_its_own_keys(one_chip,
     the kernel's plainest shape, every query head its own key/value head,
     the full causal pair with the blocks ops/flash_blocks.json commits for
     8192."""
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
@@ -303,8 +305,7 @@ def test_looped_dense_step_compiles_at_published_widths(one_chip, no_compile_cac
     from byteps_tpu.parallel.mesh_utils import make_training_mesh
 
     hn = importlib.import_module("byteps_tpu.ops.head_norm")
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = ld.LoopedDenseConfig(n_layers=6, compute_dtype=jnp.bfloat16)
     assert (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_loops, cfg.max_seq) == (
         2048, 16, 128, 5632, 4, 8192)
@@ -424,7 +425,7 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_compile_ca
     from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
 
-    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         dims, dtype, sharding=one_chip)
     s = 16384
@@ -487,10 +488,9 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
     stay under what the parent's module of the same case needs (2.63 GiB; this
     one 2.41)."""
     from byteps_tpu.models import delta_moe as dm
-    from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
 
-    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = dm.DeltaMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
     s = cfg.max_seq
     assert (s, cfg.lin_channels, cfg.lin_v_heads * cfg.lin_v_dim) == (16384, 8192, 4096)
@@ -525,10 +525,9 @@ def test_delta_layer_runs_the_inverse_once_at_published_widths(
     operands stay token-major either way: no ``transpose`` and no
     layout-changing ``copy`` of 64 MB or more under ``gdn_scan``."""
     from byteps_tpu.models import delta_moe as dm
-    from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
 
-    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = dm.DeltaMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
     assert cfg.remat and cfg.max_seq == 16384
     lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
@@ -595,12 +594,10 @@ def test_delta_step_copies_nothing_it_keeps_at_published_widths(one_chip, no_com
 
     from byteps_tpu.models import delta_moe as dm
     from byteps_tpu.models.transformer import build_train_step
-    from byteps_tpu.ops import gated_delta as gd
     from byteps_tpu.ops import gated_delta_kernels as gk
     from byteps_tpu.parallel.mesh_utils import make_training_mesh
 
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(gd, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = dm.DeltaMoEConfig(vocab_size=18992, n_layers=4, experts_held=16,
                             compute_dtype=jnp.bfloat16)  # every width as published
     assert (cfg.n_periods, cfg.full_attention_interval, cfg.max_seq, cfg.remat) == (
@@ -696,8 +693,7 @@ def test_attention_mixer_moves_each_tensor_once_at_published_widths(one_chip, no
     from byteps_tpu.models import window_moe as wm
     from byteps_tpu.ops import head_norm as hn
 
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = wm.WindowMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
     s, scope = cfg.max_seq, wm.SCOPES[stack]
     assert (s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (16384, 32, 4, 128)
@@ -754,8 +750,7 @@ def test_early_routed_window_mixer_turns_each_head_once_at_published_widths(
     from byteps_tpu.models import early_route_moe as er
     from byteps_tpu.ops import head_norm as hn
 
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(hn, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = er.EarlyRouteMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
     b, s, scope = 2, cfg.max_seq, er.SCOPES["win"]
     assert (s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (16384, 28, 4, 128)
@@ -821,8 +816,7 @@ def test_latent_attention_mixer_moves_each_tensor_once_at_published_widths(
     from byteps_tpu.models import latent_moe as lm
     from byteps_tpu.ops import mla_heads as mh
 
-    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
-    monkeypatch.setattr(mh, "_platform", lambda: "tpu")
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = lm.LatentMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
     b, s, scope = 2, cfg.max_seq, "mla_attention"
     assert (s, cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim) == (

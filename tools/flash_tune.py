@@ -69,45 +69,32 @@ small).  Winners go to the artifact's third table, ``block_diffusion``, keyed
 import argparse
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import numpy as np
 
+from tools.timing import timed  # noqa: E402
+
 
 def time_mla_heads(args) -> int:
     """``--mla-heads``: the pass of ops/mla_heads.py, kernels beside XLA's form."""
-    import tempfile
-
     import jax
     import jax.numpy as jnp
 
     from byteps_tpu.ops import mla_heads as mh
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark"))
-    import xplane  # the benchmark's reduction from a profile to (name, start, end)
 
     b, h = (int(x) for x in args.bh.split(","))
     n, r, d_v, theta = 128, 64, 128, 32e6
     rng = np.random.default_rng(0)
     make = lambda *dims: jnp.asarray(rng.normal(size=dims).astype(np.float32), jnp.bfloat16)  # noqa: E731
 
-    def timed(fn, *xs):
-        """Device-busy ms a call, from a profile of the calls back to back.
-        Alone the pass reads 3.2 ms each way on the device (a wall clock
-        agrees); inside the cell's step a trace shows the same kernels at
-        1.7–1.9 ms (PERF.md §6 PR 50, §7): size the pass from a traced step."""
-        f = jax.jit(fn)
-        jax.block_until_ready(f(*xs))
-        with tempfile.TemporaryDirectory() as log_dir:
-            with jax.profiler.trace(log_dir):
-                for _ in range(args.steps):
-                    out = f(*xs)
-                jax.block_until_ready(out)
-            ops = xplane.load(log_dir)["devices"][0]["ops"]
-        busy = xplane.union(ops, min(a for _, a, _ in ops), max(b for _, _, b in ops))
-        return sum(b - a for a, b in busy) / args.steps * 1e3
+    def pass_ms(fn, *xs):
+        """Alone the pass reads 3.2 ms each way (the device's busy time in a
+        profile of the calls says the same); inside the cell's step a trace
+        shows the same kernels at 1.7–1.9 ms (PERF.md §6 PR 50, §7): size the
+        pass from a traced step."""
+        return timed(jax.jit(fn), *xs, steps=args.steps)
 
     for s in (int(x) for x in args.seqs.split(",")):
         xs = (make(b, s, h * n), make(b, s, h * r), make(b, s, h * n), make(b, s, h * d_v),
@@ -125,8 +112,8 @@ def time_mla_heads(args) -> int:
         xla = lambda *xs: mh._forward(*xs, h, mh.rope_tables(s, r, theta))  # noqa: E731
         want = jax.jit(both(xla))(cts, *xs)
         if not args.rehearse:
-            print(f"seq {s} XLA's form: forward {timed(xla, *xs):7.3f} ms, with its transpose "
-                  f"{timed(both(xla), cts, *xs):7.3f} ms")
+            print(f"seq {s} XLA's form: forward {pass_ms(xla, *xs):7.3f} ms, with its transpose "
+                  f"{pass_ms(both(xla), cts, *xs):7.3f} ms")
         for rows in (int(x) for x in args.blocks.split(",")):
             if s % rows:
                 continue
@@ -142,7 +129,7 @@ def time_mla_heads(args) -> int:
                 raise SystemExit("the kernels disagree with XLA's form")
             if args.rehearse:
                 continue
-            fwd_ms, ms = timed(kernels, *xs), timed(both(kernels), cts, *xs)
+            fwd_ms, ms = pass_ms(kernels, *xs), pass_ms(both(kernels), cts, *xs)
             print(f"seq {s} rows {rows}: forward {fwd_ms:7.3f} ms = {moved / fwd_ms / 1e6:6.1f} "
                   f"GB/s, with its transpose {ms:7.3f} ms = {2 * moved / ms / 1e6:6.1f} GB/s "
                   f"({moved / 1e9:.3f} GB a pass)")
@@ -175,12 +162,7 @@ def tune_block_diffusion(args) -> int:
     def time_fn(fn, *xs, grad=True):
         loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)  # noqa: E731
         f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
-        jax.block_until_ready(f(*xs))
-        t0 = time.perf_counter()
-        for _ in range(args.steps):
-            out = f(*xs)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / args.steps * 1e3  # ms
+        return timed(f, *xs, steps=args.steps)
 
     if args.check_rows:
         sk = args.check_rows
@@ -366,13 +348,7 @@ def main() -> int:
     def time_fn(fn, *xs, grad=True):
         loss = lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)  # noqa: E731
         f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
-        out = f(*xs)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(args.steps):
-            out = f(*xs)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / args.steps * 1e3  # ms
+        return timed(f, *xs, steps=args.steps)
 
     def repeated(fn):
         """``fn`` on key/value heads the caller repeats for their groups."""

@@ -27,9 +27,10 @@ import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+from tools.timing import timed  # noqa: E402
 
 
 def main() -> int:
@@ -74,13 +75,7 @@ def main() -> int:
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hv)))
 
     def ms(fn, *xs):
-        f = jax.jit(fn)
-        jax.block_until_ready(f(*xs))
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            out = f(*xs)
-        jax.block_until_ready(out)
-        return round((time.perf_counter() - t0) / steps * 1e3, 3)
+        return round(timed(jax.jit(fn), *xs, steps=steps), 3)
 
     def whole(rule):
         # all five gradients: with fewer XLA drops what only the others need

@@ -63,6 +63,7 @@ def time_products(args, mcfg, tokens: int) -> int:
     from jax import lax
 
     import xplane  # benchmark/xplane.py: from a profile to (name, start, end)
+    from tools import timing
     from byteps_tpu.models.ssm_moe import relu2
     from byteps_tpu.parallel import moe
 
@@ -80,14 +81,11 @@ def time_products(args, mcfg, tokens: int) -> int:
         off a TPU."""
         run = jax.jit(fn).lower(*xs).compile()
         tiles = re.findall(r'ragged_dot_tiling="([0-9,]+)"', run.as_text())
-        jax.block_until_ready(run(*xs))
         if args.rehearse:
+            jax.block_until_ready(run(*xs))
             return None, None, tiles
         with tempfile.TemporaryDirectory() as log_dir:
-            with jax.profiler.trace(log_dir):
-                for _ in range(CALLS):
-                    out = run(*xs)
-                jax.block_until_ready(out)
+            timing.timed(run, *xs, steps=CALLS, trace_dir=log_dir)  # the device's time, below
             ops = xplane.load(log_dir)["devices"][0]["ops"]
         busy = xplane.union(ops, min(a for _, a, _ in ops), max(b for _, _, b in ops))
         products = sum(b - a for name, a, b in ops if "ragged-dot" in name)
