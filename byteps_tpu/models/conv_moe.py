@@ -209,7 +209,7 @@ def _hidden(cfg: ConvMoEConfig, params, tokens):
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_mlp(cfg, x, lp)}
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.compute_dtype)
-    return mf.walk(cfg, run, ("attn",), params, x)
+    return mf.walk(cfg, run, {"attn": mf.FLASH_SAVED}, params, x)
 
 
 def local_logits(cfg: ConvMoEConfig, params, tokens):

@@ -215,7 +215,7 @@ def _hidden(cfg: EarlyRouteMoEConfig, params, tokens):
         # takes twice as long at this slice's (rows, model) under 2 x 16 384 tokens
         rows = take_rows(varying(params["embed"], jax.typeof(tokens).vma), tokens.reshape(-1))
         x = rows.reshape(*tokens.shape, -1).astype(cfg.compute_dtype)
-    return mf.walk(cfg, run, tuple(SCOPES), params, x)
+    return mf.walk(cfg, run, dict.fromkeys(SCOPES, mf.FLASH_SAVED), params, x)
 
 
 def local_logits(cfg: EarlyRouteMoEConfig, params, tokens):

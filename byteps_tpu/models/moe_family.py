@@ -375,21 +375,28 @@ class Handed(NamedTuple):
     value: Any
 
 
-def walk(cfg, run: Dict[str, Callable], flash: Tuple[str, ...], params, x):
+def walk(cfg, run: Dict[str, Callable], kept: Dict[str, Tuple[str, ...]], params, x):
     """The layers of a :class:`PatternedFamily`, unrolled: layer by layer of
     ``cfg.kinds()``, the mixer's part and the MLP's (or the one part of a
     layer that is either alone), each on the next entry of its stack.
     ``run[stack](x, lp)`` → x, or (x, routing stats) from a part that routes;
-    each is rebuilt in the backward pass on its own, one at a time (the
-    stacks of ``flash`` keeping their kernel's output).  A mixer's
+    each is rebuilt in the backward pass on its own, one at a time, but for
+    what ``kept[stack]`` names: the ``checkpoint_name``s of its kernels'
+    outputs (``FLASH_SAVED`` for a stack of attention parts, ``ops/ssd.SAVED``
+    for Mamba-2 mixers), so that a forward kernel does not run twice; a stack
+    that ``kept`` leaves out is rebuilt whole.  The layers are a Python loop:
+    a kept array crosses no ``lax.scan``'s stack.  A mixer's
     part may return :class:`Handed`: its value goes to the MLP's part of the
     same layer as a third argument — an output of the one rebuilt part and an
     input of the other, so it is kept, and what only it needs (a sort, say) is
     in neither rebuild.  Returns
     (x, the routing stats summed over the layers)."""
     if cfg.remat:
-        policy = keep_flash()
-        run = {k: jax.checkpoint(f, policy=policy if k in flash else None)
+        # one policy object a set of names (see keep_flash): the stacks that
+        # keep the same lower as one
+        policy = {names: jax.checkpoint_policies.save_only_these_names(*names)
+                  for names in set(kept.values())}
+        run = {k: jax.checkpoint(f, policy=policy[kept[k]] if k in kept else None)
                for k, f in run.items()}
     stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
     stacked = {stack: stack_of(params, stack) for stack in run}

@@ -34,7 +34,8 @@ protocol of a family with listed layers, what the families share).
 Parameters are stacked by kind (``ssm``, ``attn``, ``moe``), layer ``i`` takes
 the next entry of its ONE stack, and every layer is rebuilt in the backward
 pass on its own (the attention keeping its kernel's output and row
-statistics).  The untied head is laid out as the embedding is, (vocabulary,
+statistics, a Mamba-2 mixer the scan kernel's output and chunk states:
+``ops/ssd.SAVED``).  The untied head is laid out as the embedding is, (vocabulary,
 model).  The plain reference is ``models/ssm_moe_reference.py``.
 """
 
@@ -53,7 +54,7 @@ from jax.sharding import Mesh
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import rms
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.ops.ssd import CHUNK, ssd_scan
+from byteps_tpu.ops.ssd import CHUNK, SAVED as SSD_SAVED, ssd_scan
 from byteps_tpu.parallel.moe import sigmoid_topk_route
 
 #: ``layer_types`` entry (``hybrid_override_pattern``'s characters) → the
@@ -230,15 +231,46 @@ def init_params(cfg: SsmMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def over_runs(stat, width: int):
+    """stat (..., G) → (..., G · width): each value over its run of ``width``
+    channels, as a chain of selects on the channel's run — elementwise, so it
+    fuses into whoever reads it.  (``jnp.repeat`` is a broadcast to (..., G,
+    width) and a reshape, and on a TPU, whose tiles lie over the last two
+    dims, that reshape is a copy: 268 MB broadcast and 268 MB copied a call at
+    2 x 8192 tokens, 1.23 ms, three times a layer — PERF.md §6, PR 62.)  Its
+    transpose is the sum over each run."""
+    run = lax.broadcasted_iota(jnp.int32, (stat.shape[-1] * width,), 0) // width
+    out = stat[..., :1]
+    for i in range(1, stat.shape[-1]):
+        out = jnp.where(run == i, stat[..., i:i + 1], out)
+    return jnp.broadcast_to(out, (*stat.shape[:-1], run.shape[0]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def sum_runs(x, width: int):
+    """x (..., G · width) → (..., G): the sum over each run of ``width``
+    channels — :func:`over_runs`' transpose, and it its."""
+    run = lax.broadcasted_iota(jnp.int32, (x.shape[-1],), 0) // width
+    return jnp.stack([jnp.sum(jnp.where(run == i, x, 0.0), axis=-1)
+                      for i in range(x.shape[-1] // width)], axis=-1)
+
+
+over_runs.defvjp(lambda stat, width: (over_runs(stat, width), None),
+                 lambda width, _, ct: (sum_runs(ct, width),))
+sum_runs.defvjp(lambda x, width: (sum_runs(x, width), None),
+                lambda width, _, ct: (over_runs(ct, width),))
+
+
 def grouped_gated_norm(y, z, w, groups: int, eps: float):
     """``w · g / rms(g)`` of ``g = y · silu(z)``, the statistics taken over
     each of ``groups`` runs of channels on their own (the gate goes on BEFORE
     the norm; ``delta_moe``'s norms first and gates after).  y, z (..., C);
     f32 inside, returns f32."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    runs = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
-    runs = runs * lax.rsqrt(jnp.mean(jnp.square(runs), axis=-1, keepdims=True) + eps)
-    return runs.reshape(g.shape) * w
+    width = g.shape[-1] // groups
+    mean_square = sum_runs(jnp.square(g), width) / width
+    return g * over_runs(lax.rsqrt(mean_square + eps), width) * w
 
 
 def _ssd_part(cfg: SsmMoEConfig, zxbcdt, lp):
@@ -248,9 +280,16 @@ def _ssd_part(cfg: SsmMoEConfig, zxbcdt, lp):
     cdt, f32 = cfg.compute_dtype, jnp.float32
     di, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
     hs, hp = cfg.ssm_heads, cfg.ssm_head_dim
-    z, xbc, dt = zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_channels], zxbcdt[..., -hs:]
-    xbc = jax.nn.silu(mf.causal_conv(xbc, lp["conv"]) + lp["conv_bias"]).astype(cdt)
-    x, b, c = xbc[..., :di], xbc[..., di:di + gn], xbc[..., di + gn:]
+    z, dt = zxbcdt[..., :di], zxbcdt[..., -hs:]
+
+    def conved(lo, hi):
+        """Channels lo:hi of x | B | C after the convolution, its bias and silu."""
+        return jax.nn.silu(mf.causal_conv(zxbcdt[..., di + lo:di + hi], lp["conv"][:, lo:hi])
+                           + lp["conv_bias"][lo:hi]).astype(cdt)
+
+    # the convolution is a channel's own: x, B and C each from their columns,
+    # so that no one array of the three is written to be cut again
+    x, b, c = conved(0, di), conved(di, di + gn), conved(di + gn, di + 2 * gn)
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"])
     y = ssd_scan(x, dt, -jnp.exp(lp["a_log"]), b, c, hs, cfg.ssm_groups,
                  chunk=cfg.chunk, compute_dtype=cdt)  # f32
@@ -305,7 +344,7 @@ def _hidden(cfg: SsmMoEConfig, params, tokens):
            "moe": lambda x, lp: _expert_layer(cfg, x, lp)}
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.compute_dtype)
-    return mf.walk(cfg, run, ("attn",), params, x)
+    return mf.walk(cfg, run, {"ssm": SSD_SAVED, "attn": mf.FLASH_SAVED}, params, x)
 
 
 def local_logits(cfg: SsmMoEConfig, params, tokens):

@@ -221,7 +221,7 @@ def _hidden(cfg: WindowMoEConfig, params, tokens):
         if cfg.mup:
             x = x * math.sqrt(cfg.d_model)
         x = x.astype(cfg.compute_dtype)
-    return mf.walk(cfg, run, tuple(SCOPES), params, x)
+    return mf.walk(cfg, run, dict.fromkeys(SCOPES, mf.FLASH_SAVED), params, x)
 
 
 def local_logits(cfg: WindowMoEConfig, params, tokens):

@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from byteps_tpu.ops._chunk import (EXACT, F32, NN, NT, TN, by_head, column, decays, dot, iotas,
+                                   row, total)
 from byteps_tpu.ops._dispatch import vma_union
 
 #: the kernels' names: a trace files their time under these (none starts
@@ -71,43 +73,6 @@ SAVED = ("gdn_inverse", "gdn_entering", "gdn_out")
 #: rows of the MXU: chunks are stacked to this many for the inverse
 STACK = 128
 
-_F32 = jnp.float32
-_EXACT = lax.Precision.HIGHEST
-
-
-def _dot(x, y, contract, precision=None):
-    """x · y over the given pair of dims, f32 out."""
-    return lax.dot_general(x, y, ((contract[:1], contract[1:]), ((), ())),
-                           precision=precision, preferred_element_type=_F32)
-
-
-_NN, _NT, _TN = (1, 0), (1, 1), (0, 0)
-
-
-def _iotas(n):
-    return (lax.broadcasted_iota(jnp.int32, (n, n), 0), lax.broadcasted_iota(jnp.int32, (n, n), 1))
-
-
-def _column(row, eye):
-    """(1, n) → (n, 1): a masked sum, no transpose."""
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
-
-
-def _row(column, eye):
-    return jnp.sum(jnp.where(eye, column, 0.0), axis=0, keepdims=True)
-
-
-def _decays(g_row, seen, eye):
-    """From a chunk's g as a row: γ as a column, and the decay matrix D (0
-    above the diagonal; the mask goes on the exponent too)."""
-    gam_col = jnp.sum(jnp.where(seen, g_row, 0.0), axis=1, keepdims=True)
-    exponent = jnp.where(seen, gam_col - _row(gam_col, eye), 0.0)
-    return gam_col, jnp.where(seen, jnp.exp(exponent), 0.0)
-
-
-def _total(x):
-    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)  # (1, 1)
-
 
 # ---------------------------------------------------------------------------
 # inside a chunk: T = (I + A)⁻¹
@@ -121,7 +86,7 @@ def _inverse_kernel(chunk, w, groups, r):
     per = w // chunk
 
     def kernel(k_ref, g_ref, b_ref, t_ref):
-        rows, cols = _iotas(w)
+        rows, cols = iotas(w)
         same = (rows // chunk) == (cols // chunk)
         seen, strict, eye = same & (rows >= cols), same & (rows > cols), rows == cols
         # the round that fills (i, j): the highest bit in which i and j differ
@@ -132,15 +97,15 @@ def _inverse_kernel(chunk, w, groups, r):
 
         def group(i, carry):
             k = k_ref[0, pl.ds(pl.multiple_of(i * w, w), w), :]
-            kk = _dot(k, k, _NT)
+            kk = dot(k, k, NT)
             for h in range(r):
-                _, decay = _decays(g_ref[h, i], seen, eye)
-                a = _column(b_ref[h, i], eye) * decay * kk
+                _, decay = decays(g_ref[h, i], seen, eye)
+                a = column(b_ref[h, i], eye) * decay * kk
                 # with T = diag(P⁻¹, Q⁻¹) so far and L the block below: T − T L T
                 inv = jnp.where(eye, 1.0, 0.0) - jnp.where(level == 0, a, 0.0)
                 for s in range(1, chunk.bit_length() - 1):
-                    inv = inv - _dot(_dot(inv, jnp.where(level == s, a, 0.0), _NN, _EXACT),
-                                     inv, _NN, _EXACT)
+                    inv = inv - dot(dot(inv, jnp.where(level == s, a, 0.0), NN, EXACT),
+                                    inv, NN, EXACT)
                 for j in range(per):
                     t_ref[h, i * per + j] = inv[j * chunk:(j + 1) * chunk,
                                                 j * chunk:(j + 1) * chunk]
@@ -165,7 +130,7 @@ def _chunk_inverse(k, g, beta, hk, chunk, nb, interpret):
     scalars = pl.BlockSpec((r, groups, 1, w), lambda i, j: (i, j, 0, 0))
     return pl.pallas_call(
         _inverse_kernel(chunk, w, groups, r),
-        out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), _F32, vma=vma_union(k, g, beta)),
+        out_shape=jax.ShapeDtypeStruct((bhv, n, chunk, chunk), F32, vma=vma_union(k, g, beta)),
         grid=(bhk, n // nb),
         in_specs=[pl.BlockSpec((1, nb * chunk, dk), lambda i, j: (i // hk, j, i % hk)),
                   scalars, scalars],
@@ -185,14 +150,14 @@ def _chunk_forward(q, k, v, t, g_row, b_row, state, masks, cdt):
     """One chunk of one value head from its entering state (f32).  Returns
     what the backward pass shares with it."""
     seen, eye = masks
-    gam_col, decay = _decays(g_row, seen, eye)
+    gam_col, decay = decays(g_row, seen, eye)
     gam_end = jnp.sum(g_row, axis=1, keepdims=True)  # (1, 1)
     e_gamma, to_end = jnp.exp(gam_col), jnp.exp(gam_end - gam_col)
     held = state.astype(cdt)
-    ks, qs = _dot(k, held, _NN), _dot(q, held, _NN)
-    rhs = (v.astype(_F32) - e_gamma * ks).astype(cdt)
+    ks, qs = dot(k, held, NN), dot(q, held, NN)
+    rhs = (v.astype(F32) - e_gamma * ks).astype(cdt)
     t_beta = (t * b_row).astype(cdt)  # the row scales go on T's columns
-    u = _dot(t_beta, rhs, _NN)
+    u = dot(t_beta, rhs, NN)
     return dict(decay=decay, e_gamma=e_gamma, to_end=to_end, last=jnp.exp(gam_end), held=held,
                 ks=ks, qs=qs, rhs=rhs, t_beta=t_beta, u=u, u_cdt=u.astype(cdt),
                 u_to_end=(to_end * u).astype(cdt))
@@ -204,7 +169,7 @@ def _fwd_kernel(chunk, nb, r, cdt, save):
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, o_ref, *rest):
         entering_ref, state = rest if save else (None, rest[0])
         dv = v_ref.shape[-1] // r  # value head h of the key head: lanes h·d_v …
-        rows, cols = _iotas(chunk)
+        rows, cols = iotas(chunk)
         masks = (rows >= cols, rows == cols)
 
         @pl.when(pl.program_id(1) == 0)
@@ -214,16 +179,16 @@ def _fwd_kernel(chunk, nb, r, cdt, save):
         def one(c, carry):
             at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
             q, k = q_ref[0, at, :], k_ref[0, at, :]
-            qk = _dot(q, k, _NT)
+            qk = dot(q, k, NT)
             for h in range(r):
                 lanes = slice(h * dv, (h + 1) * dv)
                 f = _chunk_forward(q, k, v_ref[0, at, lanes], t_ref[h, c], g_ref[h, c],
                                    b_ref[h, c], state[h], masks, cdt)
                 if save:
                     entering_ref[h, c] = f["held"]
-                o_ref[0, at, lanes] = f["e_gamma"] * f["qs"] + _dot(
-                    (f["decay"] * qk).astype(cdt), f["u_cdt"], _NN)
-                state[h] = f["last"] * state[h] + _dot(k, f["u_to_end"], _TN)
+                o_ref[0, at, lanes] = f["e_gamma"] * f["qs"] + dot(
+                    (f["decay"] * qk).astype(cdt), f["u_cdt"], NN)
+                state[h] = f["last"] * state[h] + dot(k, f["u_to_end"], TN)
             return carry
 
         lax.fori_loop(0, nb, one, 0)
@@ -265,7 +230,7 @@ def _scan_forward(q, k, v, g, beta, t, hk, chunk, nb, save, interpret):
     vma = vma_union(q, k, v, g, beta, t)
     spec = _specs(hk, r, nb, chunk, dk, dv, lambda j: j)
     by_chunk = lambda x: x.reshape(bhv, n, 1, chunk)  # noqa: E731
-    out_shape = [jax.ShapeDtypeStruct(v.shape, _F32, vma=vma)]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, F32, vma=vma)]
     out_specs = [spec["v"]]
     if save:
         out_shape.append(jax.ShapeDtypeStruct((bhv, n, dk, dv), cdt, vma=vma))
@@ -276,7 +241,7 @@ def _scan_forward(q, k, v, g, beta, t, hk, chunk, nb, save, interpret):
         grid=(bhk, n // nb),
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"], spec["t"]],
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((r, dk, dv), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=FWD_KERNEL,
@@ -294,7 +259,7 @@ def _bwd_kernel(chunk, nb, r, cdt):
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, entering_ref, do_ref,
                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate):
-        rows, cols = _iotas(chunk)
+        rows, cols = iotas(chunk)
         seen, strict, eye = rows >= cols, rows > cols, rows == cols
         is_last = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
         dv = v_ref.shape[-1] // r
@@ -307,11 +272,11 @@ def _bwd_kernel(chunk, nb, r, cdt):
             c = nb - 1 - step
             at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
             q, k = q_ref[0, at, :], k_ref[0, at, :]
-            qk, kk = _dot(q, k, _NT), _dot(k, k, _NT)
-            dq = jnp.zeros(q.shape, _F32)
-            dk = jnp.zeros(k.shape, _F32)
-            dqk = jnp.zeros((chunk, chunk), _F32)  # summed over the value heads
-            dkk = jnp.zeros((chunk, chunk), _F32)
+            qk, kk = dot(q, k, NT), dot(k, k, NT)
+            dq = jnp.zeros(q.shape, F32)
+            dk = jnp.zeros(k.shape, F32)
+            dqk = jnp.zeros((chunk, chunk), F32)  # summed over the value heads
+            dkk = jnp.zeros((chunk, chunk), F32)
             for h in range(r):
                 t, b_row = t_ref[h, c], b_ref[h, c]
                 held = entering_ref[h, c]
@@ -319,45 +284,45 @@ def _bwd_kernel(chunk, nb, r, cdt):
                 f = _chunk_forward(q, k, v_ref[0, at, lanes], t, g_ref[h, c], b_row,
                                    held, (seen, eye), cdt)
                 decay, e_gamma, to_end = f["decay"], f["e_gamma"], f["to_end"]
-                b_col = _column(b_row, eye)
+                b_col = column(b_row, eye)
                 do = do_ref[0, at, lanes]
                 leaving = dstate[h]  # the cotangent of the state this chunk leaves
                 leaving_cdt = leaving.astype(cdt)
 
-                k_dstate = _dot(k, leaving_cdt, _NN)  # cotangent of e^{γ_C − γ} ∘ U
-                du = (_dot((decay * qk).astype(cdt), do.astype(cdt), _TN)
+                k_dstate = dot(k, leaving_cdt, NN)  # cotangent of e^{γ_C − γ} ∘ U
+                du = (dot((decay * qk).astype(cdt), do.astype(cdt), TN)
                       + to_end * k_dstate).astype(cdt)
-                dp = _dot(do.astype(cdt), f["u_cdt"], _NT)
+                dp = dot(do.astype(cdt), f["u_cdt"], NT)
                 dqs = (e_gamma * do).astype(cdt)
-                drhs = _dot(f["t_beta"], du, _TN)
-                dt_beta = _dot(du, f["rhs"], _NT)
+                drhs = dot(f["t_beta"], du, TN)
+                dt_beta = dot(du, f["rhs"], NT)
                 dks = (-e_gamma * drhs).astype(cdt)
-                dq = dq + _dot(dqs, held, _NT)
-                dk = dk + _dot(dks, held, _NT) + _dot(f["u_to_end"], leaving_cdt, _NT)
+                dq = dq + dot(dqs, held, NT)
+                dk = dk + dot(dks, held, NT) + dot(f["u_to_end"], leaving_cdt, NT)
                 dv_ref[0, at, lanes] = drhs.astype(dv_ref.dtype)
-                dstate[h] = f["last"] * leaving + _dot(q, dqs, _TN) + _dot(k, dks, _TN)
+                dstate[h] = f["last"] * leaving + dot(q, dqs, TN) + dot(k, dks, TN)
 
                 # T = (I + A)⁻¹: dA = −Tᵀ dT Tᵀ, strictly lower
-                da = jnp.where(strict, -_dot(_dot(t, dt_beta * b_row, _TN, _EXACT),
-                                             t, _NT, _EXACT), 0.0)
+                da = jnp.where(strict, -dot(dot(t, dt_beta * b_row, TN, EXACT),
+                                             t, NT, EXACT), 0.0)
                 dqk = dqk + decay * dp
                 dkk = dkk + da * b_col * decay
                 # the exponents γ_i − γ_j of D, through P = D ∘ Q Kᵀ and A = β D ∘ K Kᵀ
                 dexp = (dp * qk + da * b_col * kk) * decay
                 d_e_gamma = jnp.sum(do * f["qs"] - drhs * f["ks"], axis=1, keepdims=True)
                 d_to_end = jnp.sum(f["u"] * k_dstate, axis=1, keepdims=True) * to_end
-                d_last = _total(held.astype(_F32) * leaving) * f["last"]
+                d_last = total(held.astype(F32) * leaving) * f["last"]
                 dgam = (jnp.sum(dexp, axis=1, keepdims=True) + d_e_gamma * e_gamma - d_to_end
-                        - _column(jnp.sum(dexp, axis=0, keepdims=True), eye))
-                dgam = dgam + jnp.where(is_last, _total(d_to_end) + d_last, 0.0)
+                        - column(jnp.sum(dexp, axis=0, keepdims=True), eye))
+                dgam = dgam + jnp.where(is_last, total(d_to_end) + d_last, 0.0)
                 # γ is g's running sum: dg_m = Σ_{i ≥ m} dγ_i, as a row
                 dg_ref[h, c] = jnp.sum(jnp.where(seen, dgam, 0.0), axis=0, keepdims=True)
                 db_ref[h, c] = (jnp.sum(dt_beta * t, axis=0, keepdims=True)
-                                + _row(jnp.sum(da * decay * kk, axis=1, keepdims=True), eye))
+                                + row(jnp.sum(da * decay * kk, axis=1, keepdims=True), eye))
             dqk, dkk = dqk.astype(cdt), dkk.astype(cdt)
-            dq_ref[0, at, :] = (dq + _dot(dqk, k, _NN)).astype(dq_ref.dtype)
-            dk_ref[0, at, :] = (dk + _dot(dqk, q, _TN) + _dot(dkk, k, _NN)
-                                + _dot(dkk, k, _TN)).astype(dk_ref.dtype)
+            dq_ref[0, at, :] = (dq + dot(dqk, k, NN)).astype(dq_ref.dtype)
+            dk_ref[0, at, :] = (dk + dot(dqk, q, TN) + dot(dkk, k, NN)
+                                + dot(dkk, k, TN)).astype(dk_ref.dtype)
             return carry
 
         lax.fori_loop(0, nb, one, 0)
@@ -381,13 +346,13 @@ def _scan_backward(q, k, v, g, beta, t, entering, do, hk, chunk, nb, interpret):
         x.shape, dtype or x.dtype, vma=vma)
     dq, dk_, dv_, dg, db = pl.pallas_call(
         _bwd_kernel(chunk, nb, r, cdt),
-        out_shape=[shape(q), shape(k), shape(v), shape(by_chunk(g), _F32),
-                   shape(by_chunk(beta), _F32)],
+        out_shape=[shape(q), shape(k), shape(v), shape(by_chunk(g), F32),
+                   shape(by_chunk(beta), F32)],
         grid=(bhk, n // nb),
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"], spec["t"],
                   spec["state"], spec["v"]],
         out_specs=[spec["qk"], spec["qk"], spec["v"], spec["scalar"], spec["scalar"]],
-        scratch_shapes=[pltpu.VMEM((r, dk, dv), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=BWD_KERNEL,
@@ -423,13 +388,6 @@ def _rule_bwd(hk, chunk, blocks, interpret, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def by_value_head(x):
-    """A per-token scalar of every value head, (B, S, H_v) → (B·H_v, S): the
-    rows the kernels read g and β from (2 MB each at 16k tokens: XLA's)."""
-    b, s, hv = x.shape
-    return jnp.moveaxis(x, 2, 1).reshape(b * hv, s)
-
-
 def gated_delta_kernels(q, k, v, g, beta, chunk, blocks, interpret=False):
     """q, k (B, S, H_k, d_k) and v (B, S, H_v, d_v) in the compute dtype, g
     and beta (B, S, H_v) f32; ``blocks`` = chunks a grid step of the three
@@ -438,5 +396,5 @@ def gated_delta_kernels(q, k, v, g, beta, chunk, blocks, interpret=False):
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
     o = _rule(q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk), v.reshape(b, s, hv * dv),
-              by_value_head(g), by_value_head(beta), hk, chunk, tuple(blocks), interpret)
+              by_head(g), by_head(beta), hk, chunk, tuple(blocks), interpret)
     return o.reshape(b, s, hv, dv)

@@ -32,32 +32,52 @@ their operands in ``compute_dtype`` and accumulate in f32.
 — tokens down, heads side by side along the lanes, as the projection before
 the scan writes them and the one after it reads y ``(B, S, H·P)``.
 
-One implementation, XLA's (:func:`_chunked_xla`): a ``lax.scan`` over blocks
-of :data:`BLOCK_CHUNKS` chunks, every chunk of a block and every head at once
-in batched products, the state carried from block to block; a block is rebuilt
+Two implementations of that one algorithm, chosen by :func:`_kernel_path`
+from what the code can observe (the shapes, and through
+``_dispatch.kernels_run`` the default device's platform): on a TPU, at shapes
+the kernels tile, the Pallas kernels of ``ops/ssd_kernels.py``
+(``ssd_scan_fwd`` and, behind a ``custom_vjp``, ``ssd_scan_bwd``: a chunk
+stays in VMEM from its first product to its last — no decay matrix ever
+reaches HBM —, a group's states in VMEM scratch along the sequence; the
+backward pass keeps a chunk's entering states in f32, and they and y carry
+the names in :data:`SAVED` for a caller's ``jax.checkpoint`` policy to keep,
+so that a rebuilt layer does not run the forward kernel again); everywhere
+else XLA's form (:func:`_chunked_xla`): a ``lax.scan`` over blocks of
+:data:`BLOCK_CHUNKS` chunks, every chunk of a block and every head at once in
+batched products, the state carried from block to block; a block is rebuilt
 in the backward pass (autodiff through the scan keeps a block's operands and
 its entering state, (B, H, N, P) f32: the decay matrices of one block stand at
-a time, 1/16 of a layer's at 8192 tokens).  A traced call bumps
-``ssd_xla_traces`` (``bps.get_robustness_counters()``), as
-``gdn_xla_traces`` counts the gated delta rule's.
+a time, 1/16 of a layer's at 8192 tokens).  XLA's form is every CPU test's
+path and, beside the recurrence, the kernels' oracle.  A traced call bumps
+``ssd_kernel_traces`` or ``ssd_xla_traces``
+(``bps.get_robustness_counters()``), as ``gdn_kernel_traces`` |
+``gdn_xla_traces`` count the gated delta rule's.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from byteps_tpu.core.telemetry import counters
+from byteps_tpu.ops._dispatch import LANES, kernels_run
+from byteps_tpu.ops.ssd_kernels import SAVED  # noqa: F401 — for a caller's policy, by this name
+from byteps_tpu.ops.ssd_kernels import heads_a_tile, ssd_kernels
 
 CHUNK = 128
-#: chunks a step of the scan over the sequence takes at once (fewer where that
-#: does not divide the sequence's chunks).  On the chip at (2, 8192) tokens, 64
-#: heads of 64 x 128 in 8 groups, forward + backward of the scan alone: 22.9 ms
-#: at 4, 24.9 at 2, 25.7 at 8, 33.8 at 16, 38.9 at 32 (PERF.md §6, PR 51)
+#: XLA's form: chunks a step of its scan over the sequence takes at once (fewer
+#: where that does not divide the sequence's chunks).  On the chip at (2, 8192)
+#: tokens, 64 heads of 64 x 128 in 8 groups, forward + backward of the scan
+#: alone: 22.9 ms at 4, 24.9 at 2, 25.7 at 8, 33.8 at 16, 38.9 at 32 (PERF.md
+#: §6, PR 51)
 BLOCK_CHUNKS = 4
+#: the kernels: chunks a grid step of ``ssd_scan_fwd`` | ``ssd_scan_bwd``
+#: (fewer where that does not divide the sequence's chunks)
+KERNEL_BLOCKS = (4, 4)
 
 
 def ssd_recurrence(x, dt, a, b, c):
@@ -80,21 +100,47 @@ def ssd_recurrence(x, dt, a, b, c):
     return jnp.moveaxis(y, 0, 1)
 
 
-def ssd_scan(x, dt, a, b, c, heads: int, groups: int, chunk: int = CHUNK, compute_dtype=None):
+def _kernel_path(chunk: int, head_dim: int, state: int, heads_a_group: int,
+                 interpret: bool) -> bool:
+    """The Pallas kernels (True) or XLA's chunked form (False), from the
+    platform and the shapes alone.  The kernels tile a chunk of 128 tokens
+    (the decay matrix is one f32 128 x 128 block), a state of whole lane
+    tiles, and heads that fill whole lane tiles — alone, or a whole number of
+    them side by side in one tile with the group's heads a whole number of
+    tiles; where they fit, ``_dispatch.kernels_run`` decides.  Everything
+    else (the CPU tests' heads of 6 and state of 5) is XLA's."""
+    whole = head_dim % LANES == 0 or (
+        LANES % head_dim == 0 and heads_a_group % heads_a_tile(head_dim) == 0)
+    return kernels_run(chunk == LANES and state % LANES == 0 and whole, interpret)
+
+
+def ssd_scan(x, dt, a, b, c, heads: int, groups: int, chunk: int = CHUNK, compute_dtype=None,
+             interpret: bool = False, blocks: Optional[Sequence[int]] = None):
     """x (B, S, H·P), dt (B, S, H) > 0 — the softplus taken by the caller —,
     a (H,) < 0, b and c (B, S, G·N); each group serves H / G heads in a row
     (it is never repeated in memory).  Returns y (B, S, H·P) f32, without
     ``D x``.  A sequence that ``chunk`` does not divide raises: padding would
     have to be the caller's choice (a padded token writes to the state unless
-    its dt is 0)."""
+    its dt is 0).  Which implementation runs is :func:`_kernel_path`'s call;
+    ``interpret`` asks for the Pallas interpreter off a TPU (the CPU tests),
+    ``blocks`` overrides :data:`KERNEL_BLOCKS`."""
     s = x.shape[1]
     if s % chunk:
         raise ValueError(f"state-space scan: chunk {chunk} does not divide sequence {s}")
     if heads % groups or x.shape[-1] % heads or b.shape[-1] % groups:
         raise ValueError(f"{heads} heads in {groups} groups do not divide the operands' "
                          f"{x.shape[-1]} | {b.shape[-1]} channels")
-    counters().bump("ssd_xla_traces")  # once a traced call
-    return _chunked_xla(x, dt, a, b, c, heads, groups, chunk, compute_dtype or x.dtype)
+    cdt = compute_dtype or x.dtype
+    # decided once a traced call; bps.get_robustness_counters() shows which
+    if not _kernel_path(chunk, x.shape[-1] // heads, b.shape[-1] // groups, heads // groups,
+                        interpret):
+        counters().bump("ssd_xla_traces")
+        return _chunked_xla(x, dt, a, b, c, heads, groups, chunk, cdt)
+    counters().bump("ssd_kernel_traces")
+    f32 = jnp.float32
+    fit = tuple(math.gcd(s // chunk, nb) for nb in blocks or KERNEL_BLOCKS)
+    return ssd_kernels(x.astype(cdt), dt.astype(f32), a.astype(f32), b.astype(cdt), c.astype(cdt),
+                       groups, chunk, fit, interpret)
 
 
 def _chunked_xla(x, dt, a, b, c, heads, groups, chunk, cdt):

@@ -88,6 +88,17 @@ ALSO_READ_BY = {"block_diffusion_moe": "delta_moe"}
 #: other digests, every count and all eight ``FROZEN_PARAMETERS`` stood —
 #: ``build_train_step`` commits an uncommitted optimizer state on the host at
 #: the first CALL, and ``lower`` is the compiled function's own.
+#: ISSUE 62 meant to move ``ssm_moe``'s step and only that: the Mamba-2 mixer
+#: takes x, B and C each from their own columns of the convolution (no one
+#: array of the three is written to be cut again) and the grouped gated norm's
+#: statistics go over and come from their runs of channels as selects on the
+#: channel's run (``ssm_moe.over_runs`` | ``sum_runs``: on a TPU the reshape
+#: form is two copies of 268 MB a call) — its two digests and its ``ssd_scan``
+#: count below were taken again on its tree; on the CPU the scan itself takes
+#: XLA's form as before (``ops/ssd._kernel_path``) and nothing carries a
+#: ``checkpoint_name``.  The fifteen other digests, every other count and all
+#: eight ``FROZEN_PARAMETERS`` stood through ``moe_family.walk``'s new third
+#: argument (stack → the names its rebuild keeps).
 FROZEN_LOWERINGS = {
     ("bert", "float32"): "4749126c30bbafacbac2acbde40fdf1c9a70436ee18c993510b931cde25b1bd9",
     ("latent_moe", "float32"): "2f39501233c5fb12999aad5f6244e64071b14dda6ce394942f3ffbfe3e4ad237",
@@ -100,8 +111,8 @@ FROZEN_LOWERINGS = {
     ("window_moe", "bfloat16"): "4454b585256577f84c672ad7f23299f90fe8cf25de2ee0f1ad3884d0713b9bfa",
     ("early_route_moe", "float32"): "3ad9b4b5455c7129e73f776a03785f1dcdf59f349978b9d8985ef137a5b969b2",
     ("early_route_moe", "bfloat16"): "606fbf2469a7d2d98009ea2d4af5aaa3c74e595dae7fe215bb6c60c402534b23",
-    ("ssm_moe", "float32"): "f56ad196d0485eacbb243acfe3880c4355be59dc388ea94bbd556ba5c31003c6",
-    ("ssm_moe", "bfloat16"): "4ecec2e060f947af1526a1e3295b9d7e51e51384f6a032be54fc84e576924a0b",
+    ("ssm_moe", "float32"): "f82d641f3db572a06fe71ccb3eba5923e99039590c9bd3dea2feb33efbded777",
+    ("ssm_moe", "bfloat16"): "77012d531ac48dbde6e7efc021f5be1ca82a8203b411855619676d957b8b87e1",
     ("looped_dense", "float32"): "8f77ef7cd0b04aa67647a10ba8b7aef4b656ff5aab136dcee546c5ebabcd3915",
     ("looped_dense", "bfloat16"): "b4715038548ff6df5a843859cbce33e92e88f5735e974d20a5a0dbb0057324cd",
     # taken when ISSUE 59 wrote the family — the first whose step takes a third
@@ -149,7 +160,7 @@ FROZEN_SCOPE_OPERATIONS = {
                    "moe_route": 231, "shared_expert": 132, "moe_experts": 1947},
     "early_route_moe": {"window_attention": 1254, "global_attention": 222, "moe_route": 412,
                         "moe_experts": 2104},
-    "ssm_moe": {"ssd_scan": 960, "ssm_proj": 174, "nope16_attention": 221, "moe_route": 152,
+    "ssm_moe": {"ssd_scan": 1750, "ssm_proj": 174, "nope16_attention": 221, "moe_route": 152,
                 "shared_expert": 64, "moe_experts": 936},
     # the looped family's four scopes; ``loop_heads`` holds the blocked loss's
     # ``lm_head`` inside it.  What stands under ``loop_steps`` and under none of

@@ -1062,6 +1062,15 @@ def _delta_family():
         q, k, v, g, beta, chunk=64, interpret=True))), (q, q, v, g, -g)
 
 
+def _ssd_family():
+    from byteps_tpu.ops import ssd
+
+    x, b = jnp.ones((1, 256, 2 * 64), jnp.float32), jnp.ones((1, 256, 128), jnp.float32)
+    dt, a = jnp.ones((1, 256, 2), jnp.float32), -jnp.ones((2,), jnp.float32)
+    return (lambda x, dt, a, b, c: jnp.sum(ssd.ssd_scan(
+        x, dt, a, b, c, 2, 1, interpret=True))), (x, dt, a, b, b)
+
+
 def _head_norm_family():
     from byteps_tpu.ops.head_norm import head_norm_rope
 
@@ -1103,6 +1112,9 @@ FROZEN_KERNELS = {
     "gdn_chunk_inverse": (_delta_family, "d774a47cbde0deb6"),
     "gdn_scan_fwd": (_delta_family, "fda07b824fb7a6f1"),
     "gdn_scan_bwd": (_delta_family, "9d43e1a5acde0992"),
+    # taken when ISSUE 62 wrote the two kernels
+    "ssd_scan_fwd": (_ssd_family, "8a92173852f35429"),
+    "ssd_scan_bwd": (_ssd_family, "b8f29c716357b833"),
     "head_norm_fwd": (_head_norm_family, "4ac4c6ccddd05b64"),
     "head_norm_bwd": (_head_norm_family, "5c72e95db22fa069"),
     "head_rope_fwd": (_head_rope_family, "d5d946f207dc99c3"),

@@ -130,6 +130,146 @@ def test_sequences_and_shapes_that_do_not_divide_are_refused():
 
 
 # ---------------------------------------------------------------------------
+# the kernels (ops/ssd_kernels.py) in the Pallas interpreter
+# ---------------------------------------------------------------------------
+
+
+def _kernel_operands(heads, groups, p, n_chunks, batch=1, dt=(1e-3, 0.1), rate=(1.0, 16.0),
+                     seed=0):
+    """x, dt, a, b, c at the sizes the kernels tile: chunks of 128, a state of
+    128, B and C at a product's scale."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    s, n = n_chunks * ssd.CHUNK, 128
+    x = jax.random.normal(keys[0], (batch, s, heads * p))
+    b, c = (jax.random.normal(k, (batch, s, groups * n)) * n ** -0.5 for k in keys[1:3])
+    step = jnp.exp(jax.random.uniform(keys[3], (batch, s, heads), minval=np.log(dt[0]),
+                                      maxval=np.log(dt[1])))
+    a = -jax.random.uniform(keys[4], (heads,), minval=rate[0], maxval=rate[1])
+    return x, step, a, b, c
+
+
+#: name → the operands' sizes and the chunks a grid step of the forward | the
+#: backward kernel: 1 | 2 | 8 heads a group (a head of 128 alone in its lane
+#: tile, heads of 64 two a tile), a sequence of one block of chunks and of
+#: several (the state crossing a grid step), two batches of two groups (every
+#: index map), and a head whose decay over a chunk underflows
+KERNEL_SCANS = {
+    "one_head_a_group": dict(heads=2, groups=2, p=128, n_chunks=2, blocks=(2, 2)),
+    "two_heads_a_group": dict(heads=2, groups=1, p=64, n_chunks=2, blocks=(1, 1)),
+    "eight_heads_a_group": dict(heads=8, groups=1, p=64, n_chunks=2, blocks=(2, 1)),
+    "several_blocks_of_chunks": dict(heads=4, groups=2, p=64, n_chunks=4, batch=2,
+                                     blocks=(2, 2)),
+    "a_decay_that_underflows": dict(heads=2, groups=1, p=64, n_chunks=2, blocks=(1, 2),
+                                    dt=(90.0, 110.0), rate=(10.0, 20.0)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(KERNEL_SCANS))
+def test_the_kernels_are_the_chunked_form_and_the_recurrence(case, dtype):
+    """y and the gradients of x, dt, a, B and C through ``ssd_scan_fwd`` |
+    ``ssd_scan_bwd``: against XLA's chunked form at the same operand dtype
+    (the same casts: a product's rounding apart) and against the f32
+    recurrence (at bf16 operands, bf16's rounding apart)."""
+    sizes = dict(KERNEL_SCANS[case])
+    blocks = sizes.pop("blocks")
+    x, dt, a, b, c = _kernel_operands(**sizes)
+    heads, groups = sizes["heads"], sizes["groups"]
+    assert ssd._kernel_path(ssd.CHUNK, sizes["p"], 128, heads // groups, interpret=True)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def cast(f):
+        return lambda x, dt, a, b, c: f(x.astype(dtype), dt, a, b.astype(dtype), c.astype(dtype))
+
+    forms = {
+        "kernels": cast(lambda *t: ssd.ssd_scan(*t, heads, groups, compute_dtype=dtype,
+                                                interpret=True, blocks=blocks)),
+        "xla": cast(lambda *t: ssd._chunked_xla(*t, heads, groups, ssd.CHUNK, dtype)),
+        "recurrence": lambda x, dt, a, b, c: ssd.ssd_recurrence(
+            x.reshape(*x.shape[:2], heads, -1), dt, a, b.reshape(*b.shape[:2], groups, -1),
+            c.reshape(*c.shape[:2], groups, -1)).reshape(x.shape),
+    }
+    out = {name: (f(x, dt, a, b, c), *jax.grad(lambda *t: jnp.sum(f(*t) * weight),
+                                               argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c))
+           for name, f in forms.items()}
+    assert out["kernels"][0].dtype == jnp.float32
+    exact = dtype == jnp.float32
+    for oracle, tol in (("xla", 1e-4 if exact else 3e-2), ("recurrence", 1e-4 if exact else 3e-2)):
+        for name, got, want in zip("y x dt a b c".split(), out["kernels"], out[oracle]):
+            assert np.all(np.isfinite(got)), name
+            # a decay that underflows forgets: the rates' gradient is then 0 = 0
+            np.testing.assert_allclose(
+                got, want, atol=tol * max(float(jnp.abs(want).max()), 1e-30),
+                err_msg=f"{name} against {oracle}")
+    if not exact:  # the same casts as XLA's form: y differs by a sum's order
+        off = jnp.linalg.norm(out["kernels"][0] - out["xla"][0]) / jnp.linalg.norm(out["xla"][0])
+        assert float(off) < 2e-3, off
+
+
+@pytest.mark.parametrize("policy, forward_kernels", [("named", 1), ("none", 2)])
+def test_a_rebuilt_scan_keeps_what_the_forward_kernel_wrote(policy, forward_kernels):
+    """The gradient through a ``jax.checkpoint`` whose policy keeps, by name,
+    what ``ssd.SAVED`` lists (the chunks' entering states and y) holds ONE
+    ``ssd_scan_fwd`` and one ``ssd_scan_bwd``; with no policy the forward
+    kernel twice.  The gradients are the same bit for bit: the kept arrays
+    are the ones the rebuild would have written."""
+    from byteps_tpu.ops import ssd_kernels as sk
+    from test_ops import _kernel_names
+
+    assert ssd.SAVED == sk.SAVED == ("ssd_entering", "ssd_out")
+    operands = _kernel_operands(heads=2, groups=1, p=64, n_chunks=2)
+
+    def scan(*t):
+        return jnp.sin(ssd.ssd_scan(*t, 2, 1, interpret=True, blocks=(1, 1)))
+
+    policies = {"named": jax.checkpoint_policies.save_only_these_names(*ssd.SAVED), "none": None}
+
+    def grad_of(rebuilt):
+        return jax.grad(lambda *t: jnp.sum(rebuilt(*t)), argnums=(0, 1, 2, 3, 4))
+
+    grads = {name: grad_of(jax.checkpoint(scan, policy=kept)) for name, kept in policies.items()}
+    assert _kernel_names(grads[policy], *operands) == sorted(
+        [sk.FWD_KERNEL] * forward_kernels + [sk.BWD_KERNEL])
+    for got, want in zip(jax.jit(grads[policy])(*operands), jax.jit(grads["none"])(*operands)):
+        np.testing.assert_array_equal(got, want)
+
+
+def _ssd_traces():
+    from byteps_tpu.core.telemetry import counters
+
+    snapshot = counters().snapshot()
+    return snapshot.get("ssd_kernel_traces", 0), snapshot.get("ssd_xla_traces", 0)
+
+
+def test_the_scans_path_is_chosen_from_platform_and_shapes(monkeypatch):
+    """One function decides: off a TPU and at shapes the kernels do not tile,
+    XLA's form; on a TPU at whole tiles, the kernels; each traced call
+    counted."""
+    from byteps_tpu.ops import _dispatch
+
+    published = dict(chunk=128, head_dim=64, state=128, heads_a_group=8)
+    assert not ssd._kernel_path(**published, interpret=False)  # this is a CPU
+    assert ssd._kernel_path(**published, interpret=True)
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    assert ssd._kernel_path(**published, interpret=False)
+    assert ssd._kernel_path(128, 128, 256, 1, interpret=False)
+    assert ssd._kernel_path(128, 32, 128, 4, interpret=False)
+    for chunk, p, n, r in [(CHUNK, P, N, H // G), (64, 64, 128, 8), (256, 64, 128, 8),
+                           (128, 64, 64, 8), (128, 64, 128, 1), (128, 96, 128, 4),
+                           (128, 64, 128, 3)]:
+        assert not ssd._kernel_path(chunk, p, n, r, interpret=True), (chunk, p, n, r)
+
+    # a stand-in TPU traces the kernels (nothing is lowered on this CPU) ...
+    operands = _kernel_operands(heads=2, groups=1, p=64, n_chunks=2)
+    kernels, xla = _ssd_traces()
+    text = str(jax.make_jaxpr(lambda *t: ssd.ssd_scan(*t, 2, 1))(*operands))
+    assert text.count("pallas_call") == 1 and _ssd_traces() == (kernels + 1, xla)
+    # ... and XLA's form where the shapes do not tile, as tiny_ssm_moe's do not
+    text = str(jax.make_jaxpr(_chunked)(*_operands(2)))
+    assert "pallas_call" not in text and _ssd_traces() == (kernels + 1, xla + 1)
+
+
+# ---------------------------------------------------------------------------
 # the mixer's other parts
 # ---------------------------------------------------------------------------
 
@@ -178,6 +318,41 @@ def test_the_gated_norm_gates_first_and_norms_in_groups():
         [y_np[..., i:i + 4] / np.sqrt(np.mean(y_np[..., i:i + 4] ** 2, -1, keepdims=True) + 1e-5)
          for i in (0, 4, 8)], axis=-1) * np.asarray(w) * (g / y_np)
     assert np.abs(then_gate - want).max() > 1e-2
+
+
+@pytest.mark.parametrize("groups, width", [(1, 6), (3, 4), (8, 16)])
+def test_a_runs_sum_and_its_spread_are_each_others_transposes(groups, width):
+    """``over_runs`` is ``jnp.repeat`` and ``sum_runs`` the sum over a reshape
+    — written as selects on a channel's run so that neither is a relayout on a
+    TPU —, and each one's gradient is the other."""
+    rng = np.random.default_rng(groups)
+    stat = jnp.asarray(rng.normal(size=(2, 3, groups)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(2, 3, groups * width)), jnp.float32)
+    np.testing.assert_array_equal(sm.over_runs(stat, width), jnp.repeat(stat, width, axis=-1))
+    np.testing.assert_allclose(sm.sum_runs(x, width),
+                               x.reshape(2, 3, groups, width).sum(-1), rtol=1e-6)
+    np.testing.assert_allclose(jax.grad(lambda s: jnp.sum(sm.over_runs(s, width) * x))(stat),
+                               sm.sum_runs(x, width), rtol=1e-6)
+    np.testing.assert_array_equal(jax.grad(lambda t: jnp.sum(sm.sum_runs(t, width) * stat))(x),
+                                  sm.over_runs(stat, width))
+
+
+def test_the_grouped_gated_norms_gradient_is_the_plain_forms():
+    """Through ``over_runs`` | ``sum_runs``' hand-written transposes the
+    gradients of y, z and the scale are what autodiff gives the reshape form."""
+    rng = np.random.default_rng(6)
+    y, z = (jnp.asarray(rng.normal(size=(2, 3, 12)), jnp.float32) for _ in range(2))
+    w = jnp.asarray(1 + 0.2 * rng.normal(size=12), jnp.float32)
+
+    def plain(y, z, w):
+        g = (y * jax.nn.silu(z)).reshape(2, 3, 3, 4)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + 1e-5)
+        return jnp.sum(jnp.sin(g.reshape(2, 3, 12) * w))
+
+    got = jax.grad(lambda *t: jnp.sum(jnp.sin(sm.grouped_gated_norm(*t, groups=3, eps=1e-5))),
+                   argnums=(0, 1, 2))(y, z, w)
+    for name, g, want in zip("y z w".split(), got, jax.grad(plain, argnums=(0, 1, 2))(y, z, w)):
+        np.testing.assert_allclose(g, want, rtol=1e-4, atol=1e-6, err_msg=name)
 
 
 def test_the_mixer_is_the_references_and_adds_d_x():

@@ -330,16 +330,24 @@ def test_looped_dense_step_compiles_at_published_widths(one_chip, no_compile_cac
     assert memory.temp_size_in_bytes < 10.7 * 2**30
 
 
-def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cache):
+@pytest.mark.parametrize("implementation", ["xla", "kernels"])
+def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch,
+                                                        implementation):
     """2 x 8192 tokens, 64 heads of 64 with a 64 x 128 state, B and C in 8
     groups of 128, chunks of 128, bf16 operands: a Mamba-2 layer whole —
-    ``in_proj``, the biased convolution, the chunked scan (a block of chunks
-    rebuilt at a time), ``D x``, the gated grouped norm, ``out_proj`` — and its
-    gradients.  What stands at a time stays a few copies of the (16 384,
-    10 304) projection (338 MB in bf16) and ONE block's decay matrices, not a
-    layer's (537 MB in f32, and their products beside them)."""
+    ``in_proj``, the biased convolution, the chunked scan, ``D x``, the gated
+    grouped norm, ``out_proj`` — and its gradients.  ``xla`` is the form
+    every platform but a TPU takes (a block of chunks rebuilt at a time): what
+    stands at a time stays a few copies of the (16 384, 10 304) projection
+    (338 MB in bf16) and ONE block's decay matrices, not a layer's (537 MB in
+    f32, and their products beside them).  ``kernels`` is a TPU's path: the
+    two Pallas kernels once each, no scan left to XLA, and no f32 array of a
+    decay matrix's shape anywhere."""
     from byteps_tpu.models import ssm_moe as sm
+    from byteps_tpu.ops import ssd_kernels as sk
 
+    if implementation == "kernels":
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     cfg = sm.SsmMoEConfig(compute_dtype=jnp.bfloat16)
     assert (cfg.d_model, cfg.d_inner, cfg.conv_channels, cfg.chunk) == (2688, 4096, 6144, 128)
     shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
@@ -352,6 +360,57 @@ def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cac
     compiled = _compile(jax.grad(loss, argnums=(0, 1)),
                         shape(2, 8192, 2688, dtype=jnp.bfloat16), lp)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+    text = compiled.as_text()
+    if implementation == "kernels":
+        assert sk.FWD_KERNEL in text and sk.BWD_KERNEL in text and "while" not in text
+        assert not re.search(r"f32\[[\d,]*128,128,8,8\]|f32\[[\d,]*,128,128\]", text)
+    else:
+        assert "while" in text and "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("policy, calls", [("family", (1, 1)), ("none", (2, 1))])
+def test_state_space_layer_runs_the_scan_once_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch, policy, calls):
+    """The gradient of a rebuilt Mamba-2 layer (``_ssm_layer`` under
+    ``moe_family.walk``'s ``jax.checkpoint``) for 2 x 8192 tokens at the
+    published widths: the compiled module calls ``ssd_scan_fwd`` and
+    ``ssd_scan_bwd`` once each — the recomputation keeps the entering states
+    and y by name (``ops/ssd.SAVED``: what ``ssm_moe._hidden`` tells ``walk``
+    the ``ssm`` stack keeps) —, where a ``jax.checkpoint`` with no policy,
+    what the family had, calls the forward kernel twice.  The kernels take x,
+    B and C token-major: no ``transpose`` and no layout-changing ``copy`` of
+    4 MB or more under ``ssd_scan`` but the per-token scalars' (B or C alone
+    is 33.5 MB in bf16), and the kept arrays are f32."""
+    from byteps_tpu.models import moe_family as mf
+    from byteps_tpu.models import ssm_moe as sm
+    from byteps_tpu.ops import ssd
+    from byteps_tpu.ops import ssd_kernels as sk
+
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    cfg = sm.SsmMoEConfig(compute_dtype=jnp.bfloat16, layer_types=("M",))
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    params = {f"ssm.{k}": shape(1, *s) for k, s in sm.stacks(cfg)["ssm"][1].items()}
+    kept = {"ssm": ssd.SAVED} if policy == "family" else {}
+
+    def loss(x, params):
+        run = {"ssm": lambda x, lp: sm._ssm_layer(cfg, x, lp)}
+        return jnp.sum(mf.walk(cfg, run, kept, params, x)[0].astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)),
+                        shape(2, 8192, 2688, dtype=jnp.bfloat16), params)
+    text = compiled.as_text()
+    kernels = [op_name for *_, kernel, op_name in _top_level(text) if kernel]
+    assert tuple(sum(bool(re.search(rf"\b{name}\b", op_name)) for op_name in kernels)
+                 for name in (sk.FWD_KERNEL, sk.BWD_KERNEL)) == calls
+    # dt and the log-decay, and their cotangents, are turned to a row a head
+    # for the kernels (4 MB each: XLA's); nothing else is
+    assert [found for found in _relayouts_under(text, "ssd_scan", 4 * 2**20)
+            if "f32[2,64,8192]" not in found] == []
+    assert _kernel_operands(text, sk.BWD_KERNEL)[5:] == [
+        "f32[16,64,128,512]", "f32[2,8192,4096]"]  # the entering states and dy
+    # the kept arrays, 268 MB each, beside what the layer's gradient took before
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0 * 2**30
 
 
 def test_ungated_held_expert_layer_compiles_at_published_widths(one_chip, no_compile_cache):

@@ -84,7 +84,7 @@ def main() -> int:
     # each kernel alone, on what the kernels take: q, k, v token-major with
     # the heads side by side, g and beta a row a value head
     flat = (q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk), v.reshape(b, s, hv * dv),
-            gk.by_value_head(g), gk.by_value_head(beta))
+            gk.by_head(g), gk.by_head(beta))
     t = jax.jit(lambda k, g, beta: gk._chunk_inverse(k, g, beta, hk, chunk, sizes[0], interpret))(
         flat[1], flat[3], flat[4])
     _, entering = jax.jit(lambda *a: gk._scan_forward(*a, hk, chunk, sizes[0], True, interpret))(
