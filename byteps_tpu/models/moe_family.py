@@ -112,10 +112,11 @@ class ExpertFamily(Family):
                 f"lie outside the router's {self.n_experts}")
 
 
-class PatternedFamily(ExpertFamily):
+class Patterned(Family):
     """A family whose layers are static data: ``layer_types[i]`` names layer
     i's mixer, and the first ``n_dense_layers`` layers' MLP is dense, the
-    others' routed (stacks ``dense``, ``moe``).  A family whose layer is ONE
+    others' routed (stacks ``dense``, ``moe``) — with every layer dense, a
+    family without experts (``cross_decoder``).  A family whose layer is ONE
     part — a mixer or an MLP alone — lists every part's type in ``mixers``
     and gives :meth:`kinds` itself, one stack a layer (``ssm_moe``)."""
 
@@ -131,7 +132,6 @@ class PatternedFamily(ExpertFamily):
         if not 0 <= self.n_dense_layers <= len(self.layer_types):
             raise ValueError(f"{self.n_dense_layers} leading dense layers in a model of "
                              f"{len(self.layer_types)}")
-        super().__post_init__()
 
     @property
     def n_layers(self) -> int:
@@ -147,6 +147,15 @@ class PatternedFamily(ExpertFamily):
         shapes), the stacks some layer reads."""
         used = [stack for pair in self.kinds() for stack in pair]
         return {k: (used.count(k), v) for k, v in shapes.items() if k in used}
+
+
+class PatternedFamily(Patterned, ExpertFamily):
+    """:class:`Patterned` with expert layers (``conv_moe``, ``window_moe``,
+    ``early_route_moe``, ``ssm_moe``)."""
+
+    def __post_init__(self):
+        Patterned.__post_init__(self)
+        ExpertFamily.__post_init__(self)
 
 
 # ---------------------------------------------------------------------------
@@ -255,6 +264,13 @@ def rms(x, w, eps: float, plus_one: bool = False):
     x = x.astype(jnp.float32)
     return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * (
         1.0 + w if plus_one else w)
+
+
+def layer_norm(x, w, b, eps: float):
+    """LayerNorm ``w · (x − mean) / std + b`` with f32 statistics; returns f32."""
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w + b
 
 
 def swiglu(g, w_gate, w_up, w_down):
@@ -375,7 +391,18 @@ class Handed(NamedTuple):
     value: Any
 
 
-def walk(cfg, run: Dict[str, Callable], kept: Dict[str, Tuple[str, ...]], params, x):
+class Carried(NamedTuple):
+    """What a part returns to :func:`walk` when LATER layers are to get values
+    beside ``x``: ``values`` (name → array or tuple of arrays) travel on with
+    the residual stream to every part that ``takes`` them."""
+
+    x: jax.Array
+    values: Dict[str, Any]
+
+
+def walk(cfg, run: Dict[str, Callable], kept: Dict[str, Tuple[str, ...]], params, x,
+         takes: Optional[Dict[str, Tuple[str, ...]]] = None,
+         carried: Optional[Dict[str, Any]] = None):
     """The layers of a :class:`PatternedFamily`, unrolled: layer by layer of
     ``cfg.kinds()``, the mixer's part and the MLP's (or the one part of a
     layer that is either alone), each on the next entry of its stack.
@@ -389,8 +416,14 @@ def walk(cfg, run: Dict[str, Callable], kept: Dict[str, Tuple[str, ...]], params
     part may return :class:`Handed`: its value goes to the MLP's part of the
     same layer as a third argument — an output of the one rebuilt part and an
     input of the other, so it is kept, and what only it needs (a sort, say) is
-    in neither rebuild.  Returns
-    (x, the routing stats summed over the layers)."""
+    in neither rebuild.  A part may return :class:`Carried`: its values are
+    written to ``carried`` (the caller's dict, name → value; what earlier
+    layers held ELSEWHERE exported comes in through it and what these layers
+    export is read from it afterwards), and every later part whose stack
+    ``takes`` names them gets them as further arguments, in that order.  A
+    carried value is an output of one rebuilt part and an input of others: it
+    is kept, not rebuilt, and autodiff sums its cotangent over its readers.
+    Returns (x, the routing stats summed over the layers)."""
     if cfg.remat:
         # one policy object a set of names (see keep_flash): the stacks that
         # keep the same lower as one
@@ -401,13 +434,17 @@ def walk(cfg, run: Dict[str, Callable], kept: Dict[str, Tuple[str, ...]], params
     stats = jnp.zeros((len(ROUTING_STATS),), jnp.int32)
     stacked = {stack: stack_of(params, stack) for stack in run}
     seen = dict.fromkeys(run, 0)  # how many layers of each stack have run
+    takes, carried = takes or {}, {} if carried is None else carried
     for pair in cfg.kinds():
         handed = ()
         for stack in pair:
             lp = {k: v[seen[stack]] for k, v in stacked[stack].items()}
             seen[stack] += 1
-            x, handed = run[stack](x, lp, *handed), ()
-            if isinstance(x, Handed):
+            x, handed = run[stack](x, lp, *handed, *(carried[n] for n in takes.get(stack, ()))), ()
+            if isinstance(x, Carried):
+                carried.update(x.values)
+                x = x.x
+            elif isinstance(x, Handed):
                 x, handed = x.x, (x.value,)
             elif isinstance(x, tuple):
                 x, each = x

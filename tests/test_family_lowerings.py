@@ -28,8 +28,8 @@ import numpy as np
 import optax
 import pytest
 
-from byteps_tpu.models import (block_diffusion_moe, conv_moe, delta_moe, early_route_moe,
-                               latent_moe, looped_dense, ssm_moe, window_moe)
+from byteps_tpu.models import (block_diffusion_moe, conv_moe, cross_decoder, delta_moe,
+                               early_route_moe, latent_moe, looped_dense, ssm_moe, window_moe)
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
@@ -37,7 +37,8 @@ from byteps_tpu.parallel.mesh_utils import make_training_mesh
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
             "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe,
-            "looped_dense": looped_dense, "block_diffusion_moe": block_diffusion_moe}
+            "looped_dense": looped_dense, "block_diffusion_moe": block_diffusion_moe,
+            "cross_decoder": cross_decoder}
 #: family → the reader of benchmark/readers/ its cell's metrics go through,
 #: where that is not one of its own name
 READERS = {"early_route_moe": "window_moe"}
@@ -125,6 +126,15 @@ FROZEN_LOWERINGS = {
         "1aeb34d192f5164aa8e365f70e07c547a591c012826be5cb8a06c77d378f9ce2",
     ("block_diffusion_moe", "bfloat16"):
         "0e5298b98e47989502e1d2fdf991ef436b05f568401091cce5a36b0f4ccf1ab6",
+    # taken when ISSUE 63 wrote the family — the first on ``moe_family.Patterned``
+    # without experts, the first whose parts hand values forward
+    # (``moe_family.Carried``); the seventeen above, every count and all eight
+    # ``FROZEN_PARAMETERS`` stood through ``Patterned``'s split from
+    # ``ExpertFamily`` and ``walk``'s ``takes`` | ``carried``
+    ("cross_decoder", "float32"):
+        "f7b54ed6a2262764759d51bf30d25c96639b74fd62fbb4e0af5c0c9b8b150ab1",
+    ("cross_decoder", "bfloat16"):
+        "55587533d5913f24b270d8e739d224e80705f1fb9a26b8c37975675d82eb28cd",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -138,6 +148,7 @@ FROZEN_PARAMETERS = {
     "ssm_moe": "f490c4e980eef04431d28a3f03db0f49592603f5f02f9153b547ad90a9220f01",
     "looped_dense": "0b4e0693777702fb56a74903a1a7c62bdf5c74d861676882e23cca3fff028e97",
     "block_diffusion_moe": "ce733faf1c2e8c92691f1c00b4099bb1d77ac078e40cc86c2f5e229fde94a92b",
+    "cross_decoder": "c52348f3776f8cca65570fe990e5659bcf3307ca0077bb28cabe5c5f0397ad73",
 }
 
 #: family → scope → operations of the bfloat16 step filed under it.  The scopes
@@ -170,6 +181,11 @@ FROZEN_SCOPE_OPERATIONS = {
     # ids' concatenation and the last layer's cut with its transpose
     "block_diffusion_moe": {"block_diffusion_attention": 1904, "copies_assembly": 4,
                             "moe_route": 261, "moe_experts": 1683},
+    # a whole model of 8 layers: three Mamba-1 mixers, two window layers, the
+    # full layer, one GMU, one cross layer, eight SwiGLUs (ISSUE 63)
+    "cross_decoder": {"selective_scan": 435, "mamba_proj": 1027, "diff_window_attention": 1536,
+                      "diff_full_attention": 755, "diff_cross_attention": 598,
+                      "gated_memory": 141, "dense_mlp": 1152, "lm_head": 59, "embed": 17},
 }
 
 #: family → scope → operations of the step (bfloat16; ``bert``'s float32 one)

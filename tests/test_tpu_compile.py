@@ -942,3 +942,57 @@ def test_engine_split_compiles_for_the_largest_vgg16_leaf(one_chip, no_compile_c
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes == 0
     assert n * 4 <= memory.output_size_in_bytes < n * 4 + 101 * 4096
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window_512", "full"])
+def test_flash_kernels_compile_at_the_differential_attention_shape(one_chip, no_compile_cache,
+                                                                   monkeypatch, window):
+    """(1, 20 | 10, 16384, 64 | 128) — the cross-decoder family's mixers: one
+    softmax of a differential pair is one call, queries and keys 64 wide, the
+    value pair's one vector 128 wide, two query pairs a key/value pair; the
+    banded pair at window 512 — the narrowest band the repo runs, 32 windows
+    in a sequence — and the full causal pair of the full and cross layers."""
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((1, 20, 16384, 64), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 10, 16384, 64), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 10, 16384, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, scale=64 ** -0.5, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v).as_text()
+    wanted = (fa.FWD_WIN_KERNEL, fa.BWD_WIN_KERNEL) if window else (fa.FWD_KERNEL, fa.BWD_KERNEL)
+    for kernel in wanted:
+        assert kernel in text, f"{kernel} is not in the compiled program"
+        assert tuple(_kernel_operands(text, kernel)[:3]) == (
+            "bf16[20,16384,64]", "bf16[10,16384,64]", "bf16[10,16384,128]")
+    assert (fa.FWD_WIN_KERNEL in text) == bool(window)
+
+
+def test_selective_scan_keeps_its_state_out_of_the_hbm_a_token(one_chip, no_compile_cache,
+                                                               monkeypatch):
+    """The Mamba-1 scan at the cell's shape, XLA's form compiled for a
+    described v5e: three token loops a gradient (forward, a chunk's rebuild,
+    the adjoint), and no array with a state a TOKEN — the largest f32 array
+    is a chunk's worth of states, (128, 1, 16, 5120)."""
+    from byteps_tpu.ops import selective_scan as ss
+
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    tokens, channels, state = 16384, 5120, 16
+    x = jax.ShapeDtypeStruct((1, tokens, channels), jnp.bfloat16, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((1, tokens, channels), jnp.float32, sharding=one_chip)
+    a = jax.ShapeDtypeStruct((channels, state), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((1, tokens, state), jnp.bfloat16, sharding=one_chip)
+    d = jax.ShapeDtypeStruct((channels,), jnp.float32, sharding=one_chip)
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(ss.selective_scan(x, dt, a, b, c, d).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=tuple(range(6))), x, dt, a, b, b, d)
+    text = compiled.as_text()
+    sizes = [int(n) * int(m) * state * channels
+             for n, m in re.findall(r"f32\[(\d+),(\d+),1,16,5120\]", text)]
+    assert sizes and max(sizes) <= 128 * state * channels * 128  # a state a chunk, all chunks
+    assert not re.search(r"f32\[16384,1,16,5120\]|f32\[1,16384,16,5120\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

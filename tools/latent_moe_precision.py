@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Where the limits on the precision of the families behind ``build_train_step``
-come from: the six MoE families', and the looped dense family's (no experts:
-no routed leaf among the gradient's readings, no ``--pin``).
+come from: the six MoE families', and the looped dense and cross-decoder
+families' (no experts: no routed leaf among the gradient's readings, no
+``--pin``).
 
     python tools/latent_moe_precision.py --seeds 2900002001 2900002011 ...
     python tools/latent_moe_precision.py --config qwen3_next_80b_ep32 --seeds ...
@@ -12,6 +13,7 @@ no routed leaf among the gradient's readings, no ``--pin``).
     python tools/latent_moe_precision.py --config ouro_2_6b_pp8 --seeds ...
     python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --fault unit_weights --seeds ...
+    python tools/latent_moe_precision.py --config phi4_mini_flash_vp8 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -26,7 +28,9 @@ it), on the TPU:
             of the program, to show that the control reads like it
   below     the same with the norms' statistics, the router's scores and
             weights and the softmax (and, where the configuration has a
-            state-space scan, its step sizes, decay sums and states; where it
+            state-space scan, its step sizes, decay sums and states — a
+            Mamba-1 scan's Δ, decay and state; where it has differential
+            attention, λ and the pair norm's statistics; where it
             has an exit gate, the gate, its distribution and entropy; where
             it states a float32 residual stream, that) in bf16: the nearest
             precision below, which ``correct`` has to refuse
@@ -167,13 +171,19 @@ def main() -> int:
     grad_step = build_train_step(model, mesh4, keep, donate=False)
     train_step = build_train_step(model, mesh4, tx)  # as the builder's ``build`` makes it
 
+    # a builder may hand run.py its parameters in a tree of its own (phi4flash's)
+    own = jax.jit(functools.partial(getattr(builder, "program_params", lambda _, p: p), cfg),
+                  donate_argnums=0)
+    compared = getattr(builder, "compared_params", lambda p: p)
+
     def program_steps(p, batch):
-        first = jax.device_get(grad_step(p, keep.init(p), *batch)[1])
+        p = own(p)
+        first = jax.device_get(compared(grad_step(p, keep.init(p), *batch)[1]))
         s, losses = jax.jit(tx.init)(p), []
         for _ in range(steps):
             p, s, loss = train_step(p, s, *batch)
             losses.append(float(loss))
-        return losses, first, p
+        return losses, first, compared(p)
 
     runs = {"program": program_steps,
             "stated": plain_steps(builder.plain_loss(cfg, jnp.bfloat16, jnp.float32)),
