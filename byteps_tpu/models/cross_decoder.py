@@ -72,6 +72,7 @@ from jax.sharding import Mesh
 
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import Carried, layer_norm
+from byteps_tpu.ops.causal_conv import conv_silu
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.selective_scan import CHUNK, SAVED as SCAN_SAVED, selective_scan
 
@@ -259,7 +260,7 @@ def _mamba_layer(cfg: CrossDecoderConfig, x, lp):
     with jax.named_scope("mamba_proj"):
         xz = _normed(cfg, x, lp) @ lp["w_in"].astype(cdt)
         z = xz[..., di:]
-        xc = jax.nn.silu(mf.causal_conv(xz[..., :di], lp["conv"]) + lp["conv_bias"]).astype(cdt)
+        xc = conv_silu(xz, lp["conv"], lp["conv_bias"], lo=0, hi=di)
         dbc = xc @ lp["w_x"].astype(cdt)
         b, c = dbc[..., r:r + n], dbc[..., r + n:]
         dt = jnp.einsum("bsr,rc->bsc", dbc[..., :r], lp["w_dt"].astype(cdt),

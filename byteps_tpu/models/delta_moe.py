@@ -39,8 +39,9 @@ from jax import lax
 from jax.sharding import Mesh
 
 from byteps_tpu.models import moe_family as mf
-from byteps_tpu.models.moe_family import causal_conv, rope_partial
+from byteps_tpu.models.moe_family import rope_partial
 from byteps_tpu.ops import gated_delta_kernels
+from byteps_tpu.ops.causal_conv import conv_silu, per_head
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.gated_delta import CHUNK, chunked_gated_delta_rule
 from byteps_tpu.parallel.moe import ROUTING_STATS, softmax_topk_route
@@ -180,83 +181,26 @@ def init_params(cfg: DeltaMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
 _rms = functools.partial(mf.rms, plus_one=True)
 
 
-def _inv_l2(x, eps: float = 1e-6):
-    """1 / ‖x‖ over the last dim, kept."""
-    return lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
-
-
-def _head_tiles(x, n: int):
-    """x (B, S, n·d) → (B, S/8, n, 8, d): a head's lanes last, eight tokens
-    above them (fewer where 8 does not divide S).  That is the (8, 128) tile a
-    token-major array already lies in on a TPU, so the view moves nothing and
-    a reduction over d stays inside a tile; (B, S, n, d) is another layout
-    there and costs a copy each way (PERF.md §6, PR 45)."""
-    b, s, c = x.shape
-    rows = math.gcd(s, 8)
-    return x.reshape(b, s // rows, rows, n, c // n).transpose(0, 1, 3, 2, 4)
-
-
-def _tokens(t):
-    """:func:`_head_tiles` back: (B, S/r, n, r, d) → (B, S, n·d)."""
-    b, m, n, rows, d = t.shape
-    return t.transpose(0, 1, 3, 2, 4).reshape(b, m * rows, n * d)
-
-
-def _per_head(x, n: int, stat):
-    """``stat`` (over the last dim, kept) of each of the n heads of x
-    (B, S, n·d), on every lane of its head: (B, S, n·d).  The one thing in
-    the linear mixer that needs the tiles' view."""
-    tiles = _head_tiles(x, n)
-    return _tokens(jnp.broadcast_to(stat(tiles), tiles.shape))
-
-
-@jax.custom_vjp
-def _conv_rounded(x, taps):
-    """:func:`causal_conv` rounded to x's dtype (the taps in f32, their sum
-    rounded: what the backward pass keeps of the channels is in the compute
-    dtype), with the backward pass written as the convolution is itself:
-    ``dx_t = Σ_j taps[j] · dy_{t+K-1-j}``, the shifted copies of ONE padded
-    ``dy`` taken in its dtype, multiplied and summed in f32, rounded once.
-    (Autodiff pads each tap's rounded product apart and adds the four in x's
-    dtype: nine passes over the channels where this makes two.)"""
-    return causal_conv(x, taps).astype(x.dtype)
-
-
-def _conv_fwd(x, taps):
-    return _conv_rounded(x, taps), (x, taps)
-
-
-def _conv_bwd(res, dy):
-    x, taps = res
-    k, s = taps.shape[0], x.shape[1]
-    ahead = jnp.pad(dy, ((0, 0), (0, k - 1), (0, 0)))
-    dx = sum(ahead[:, k - 1 - j:k - 1 - j + s].astype(jnp.float32) * taps[j] for j in range(k))
-    (dtaps,) = jax.vjp(lambda t: causal_conv(x, t), taps)[1](dy.astype(jnp.float32))
-    return dx.astype(x.dtype), dtaps
-
-
-_conv_rounded.defvjp(_conv_fwd, _conv_bwd)
-
-
 def _delta_scan(cfg: DeltaMoEConfig, qkvz, ba, lp):
     """The linear mixer between its projections: ``qkvz`` (B, S, channels +
     H_v·d_v) in the compute dtype and ``ba`` (B, S, 2·H_v) f32 → what
     ``w_out`` takes, (B, S, H_v·d_v) in the compute dtype.  Token-major from
     end to end: a head is a run of lanes, the rule's (B, S, n, d) a reshape
     that its own undoes, and the statistics a head are taken on the tiles'
-    view (:func:`_per_head`); each of q, k, v is convolved from its own
-    columns of ``qkvz``, so that nothing computed is split afterwards."""
+    view (``causal_conv.per_head``); each of q, k, v is convolved from its own
+    columns of ``qkvz`` (``ops/causal_conv.conv_silu``: convolution, silu and
+    a key head's l2 norm in one pass, rounded once), so that nothing computed
+    is split afterwards."""
     cdt, f32 = cfg.compute_dtype, jnp.float32
     hk, hv, dk, dv = cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim
     b, s, _ = qkvz.shape
 
-    def convolved(lo, hi):
-        return jax.nn.silu(_conv_rounded(qkvz[..., lo:hi], lp["conv"][:, lo:hi]).astype(f32))
+    def convolved(lo, hi, **norm):
+        return conv_silu(qkvz, lp["conv"][:, lo:hi], lo=lo, hi=hi, **norm)
 
-    q, k = convolved(0, hk * dk), convolved(hk * dk, 2 * hk * dk)
-    q = (q * _per_head(q, hk, _inv_l2) * dk ** -0.5).astype(cdt)
-    k = (k * _per_head(k, hk, _inv_l2)).astype(cdt)
-    v = convolved(2 * hk * dk, cfg.lin_channels).astype(cdt)
+    q = convolved(0, hk * dk, l2_head=dk, scale=dk ** -0.5)
+    k = convolved(hk * dk, 2 * hk * dk, l2_head=dk)
+    v = convolved(2 * hk * dk, cfg.lin_channels)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(lp["a_log"]) * jax.nn.softplus(ba[..., hv:] + lp["dt_bias"])
     # each key head serves lin_v_heads / lin_k_heads value heads
@@ -264,7 +208,7 @@ def _delta_scan(cfg: DeltaMoEConfig, qkvz, ba, lp):
         q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk), v.reshape(b, s, hv, dv), g, beta,
         chunk=cfg.chunk, compute_dtype=cdt).reshape(b, s, hv * dv)  # f32
     # the gated norm: over each head's values, one scale for all heads
-    o = jnp.tile(lp["gdn_norm"], hv) * o * _per_head(o, hv, lambda head: lax.rsqrt(
+    o = jnp.tile(lp["gdn_norm"], hv) * o * per_head(o, hv, lambda head: lax.rsqrt(
         jnp.mean(jnp.square(head), axis=-1, keepdims=True) + cfg.norm_eps))
     return (o * jax.nn.silu(qkvz[..., cfg.lin_channels:].astype(f32))).astype(cdt)
 

@@ -53,6 +53,7 @@ from jax.sharding import Mesh
 
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import rms
+from byteps_tpu.ops.causal_conv import conv_silu
 from byteps_tpu.ops.flash_attention import flash_attention
 from byteps_tpu.ops.ssd import CHUNK, SAVED as SSD_SAVED, ssd_scan
 from byteps_tpu.parallel.moe import sigmoid_topk_route
@@ -284,8 +285,8 @@ def _ssd_part(cfg: SsmMoEConfig, zxbcdt, lp):
 
     def conved(lo, hi):
         """Channels lo:hi of x | B | C after the convolution, its bias and silu."""
-        return jax.nn.silu(mf.causal_conv(zxbcdt[..., di + lo:di + hi], lp["conv"][:, lo:hi])
-                           + lp["conv_bias"][lo:hi]).astype(cdt)
+        return conv_silu(zxbcdt, lp["conv"][:, lo:hi], lp["conv_bias"][lo:hi],
+                         lo=di + lo, hi=di + hi)
 
     # the convolution is a channel's own: x, B and C each from their columns,
     # so that no one array of the three is written to be cut again

@@ -17,6 +17,11 @@ XLA's form of the same equations beside it.
   layers.
 - :mod:`ssd` — the state-space scan in chunks, XLA's form alone (no kernel,
   no chooser), for the state-space family's mixers.
+- :mod:`causal_conv` — the depthwise causal convolution before a linear
+  mixer with its bias, silu and a head's l2 norm, one pass each way
+  (``conv_silu_fwd``, ``conv_silu_bwd``) over a column range of the
+  projection's output found by index map, and XLA's form, for the
+  gated-delta, state-space and cross-decoder families' mixers.
 - :mod:`head_norm` — a head's RMSNorm and rotary embedding in one pass each
   way (bf16 in, f32 in registers, bf16 out), between a q | k projection and
   the flash kernels of the sliding-window family's mixers; and the rotation

@@ -24,6 +24,7 @@ import pytest
 from byteps_tpu.models import delta_moe as dm
 from byteps_tpu.models import delta_moe_reference as ref
 from byteps_tpu.models import moe_family as mf
+from byteps_tpu.ops import causal_conv as cc
 from byteps_tpu.ops import gated_delta as gd
 from byteps_tpu.parallel import moe
 
@@ -82,31 +83,6 @@ def test_causal_conv_reads_the_past_only():
     np.testing.assert_allclose(got[0, :, 0], [1000, 3100, 5310, 7531, 9753])
 
 
-@pytest.mark.parametrize("taps_k", [3, 4])
-def test_the_rounded_convolution_has_the_convolutions_own_gradients(taps_k):
-    """``_conv_rounded`` is ``causal_conv`` rounded to x's dtype; its written
-    backward pass — the shifted copies of one padded cotangent — gives
-    autodiff's dx and dtaps (in f32 to rounding; the first and the last
-    positions, where the padding shows, among them)."""
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 6))
-    taps = jax.random.normal(jax.random.PRNGKey(1), (taps_k, 6))
-    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
-    np.testing.assert_array_equal(dm._conv_rounded(x, taps), mf.causal_conv(x, taps))
-    got = jax.grad(lambda x, t: jnp.sum(dm._conv_rounded(x, t) * weigh), argnums=(0, 1))(x, taps)
-    want = jax.grad(lambda x, t: jnp.sum(mf.causal_conv(x, t) * weigh), argnums=(0, 1))(x, taps)
-    for name, g, w in zip(("dx", "dtaps"), got, want):
-        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6, err_msg=name)
-    # in bf16 the result is the f32 sum rounded once, and dx comes back in bf16
-    low = dm._conv_rounded(x.astype(jnp.bfloat16), taps)
-    assert low.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        low, mf.causal_conv(x.astype(jnp.bfloat16), taps).astype(jnp.bfloat16))
-    dx = jax.grad(lambda x: jnp.sum(dm._conv_rounded(x, taps).astype(jnp.float32) * weigh))(
-        x.astype(jnp.bfloat16))
-    assert dx.dtype == jnp.bfloat16
-    np.testing.assert_allclose(dx.astype(jnp.float32), want[0], rtol=2e-2, atol=2e-2)
-
-
 @pytest.mark.parametrize("s", [16, 12, 5], ids=["eight_rows", "four_rows", "one_row"])
 def test_the_tiles_view_is_the_heads_of_the_token_major_array(s):
     """(B, S, n·d) → (B, S/r, n, r, d) and back: position (b, t, h·d + i)
@@ -114,14 +90,14 @@ def test_the_tiles_view_is_the_heads_of_the_token_major_array(s):
     last dim there is the statistic over a head's lanes."""
     b, n, d = 2, 3, 4
     x = jax.random.normal(jax.random.PRNGKey(s), (b, s, n * d))
-    tiles = dm._head_tiles(x, n)
+    tiles = cc.head_tiles(x, n)
     rows = np.gcd(s, 8)
     assert tiles.shape == (b, s // rows, n, rows, d)
-    np.testing.assert_array_equal(dm._tokens(tiles), x)
+    np.testing.assert_array_equal(cc.tokens(tiles), x)
     for t, h in [(0, 0), (s - 1, n - 1), (s // 2, 1)]:
         np.testing.assert_array_equal(tiles[1, t // rows, h, t % rows], x[1, t, h * d:(h + 1) * d])
     np.testing.assert_allclose(
-        dm._tokens(jnp.broadcast_to(jnp.sum(tiles ** 2, -1, keepdims=True), tiles.shape)),
+        cc.tokens(jnp.broadcast_to(jnp.sum(tiles ** 2, -1, keepdims=True), tiles.shape)),
         jnp.repeat(jnp.sum(x.reshape(b, s, n, d) ** 2, -1), d, axis=-1), rtol=1e-6)
 
 
@@ -144,7 +120,7 @@ def test_the_token_major_mixer_is_the_head_major_one():
         act = jax.nn.silu(mf.causal_conv(qkvz[..., :cfg.lin_channels], lp["conv"]))
         q, k, v = jnp.split(act, [hk * dk, 2 * hk * dk], axis=-1)
         heads = lambda t, n, d: jnp.moveaxis(t.reshape(b, s, n, d), 2, 1)  # noqa: E731
-        q, k = (t * dm._inv_l2(t) for t in (heads(q, hk, dk), heads(k, hk, dk)))
+        q, k = (t * cc.inv_l2(t) for t in (heads(q, hk, dk), heads(k, hk, dk)))
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(lp["a_log"]) * jax.nn.softplus(ba[..., hv:] + lp["dt_bias"])
         r = hv // hk

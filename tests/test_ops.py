@@ -682,6 +682,220 @@ class TestHeadRope:
         assert _kernel_names(grad64, jax.ShapeDtypeStruct((2, 2048, 7 * 64), jnp.bfloat16)) == []
 
 
+class TestConvSilu:
+    """``ops/causal_conv.conv_silu``: the depthwise causal convolution before a
+    linear mixer with its bias, silu and a head's l2 norm, taken from a column
+    range of a projection's output — the kernels (interpret mode) and XLA's
+    form alike held to a token-by-token loop, forward and every gradient."""
+
+    B, S, W, LO, HI, K = 2, 64, 640, 256, 512, 4
+    #: four row blocks a sequence, one or two lane blocks a range, two chunks a block
+    BLOCKS = (16, 128, 8)
+
+    @classmethod
+    def _inputs(cls, with_bias, dtype=jnp.float32, seed=64):
+        rng = np.random.default_rng(seed)
+        normal = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))  # noqa: E731
+        c = cls.HI - cls.LO
+        return (normal(cls.B, cls.S, cls.W).astype(dtype), normal(cls.K, c) * 0.5,
+                normal(c) if with_bias else None, normal(cls.B, cls.S, c).astype(dtype))
+
+    @classmethod
+    def _loop(cls, wide, taps, bias, l2_head, scale):
+        """The definition, a token at a time, in f32."""
+        x = wide[..., cls.LO:cls.HI].astype(jnp.float32)
+        out = []
+        for t in range(cls.S):
+            u = sum(taps[j] * x[:, t - cls.K + 1 + j]
+                    for j in range(cls.K) if t - cls.K + 1 + j >= 0)
+            a = jax.nn.silu(u if bias is None else u + bias)
+            if l2_head:
+                heads = a.reshape(cls.B, -1, l2_head)
+                norm = jnp.sqrt(jnp.sum(heads * heads, axis=-1, keepdims=True) + 1e-6)
+                a = (heads / norm * scale).reshape(a.shape)
+            out.append(a)
+        return jnp.stack(out, axis=1)
+
+    @classmethod
+    def _run(cls, wide, taps, bias, l2_head, scale, **how):
+        from byteps_tpu.ops.causal_conv import conv_silu
+
+        return conv_silu(wide, taps, bias, lo=cls.LO, hi=cls.HI, l2_head=l2_head, scale=scale,
+                         **how)
+
+    @pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+    @pytest.mark.parametrize("l2_head", [None, 128], ids=["plain", "l2_heads"])
+    @pytest.mark.parametrize("with_bias", [False, True], ids=["no_bias", "bias"])
+    def test_forward_and_every_gradient_are_the_token_loops(self, with_bias, l2_head, interpret):
+        """f32: y, d wide (zero outside the columns, the halo's rows across
+        every block edge and at the sequence's start), d taps and d bias
+        against the loop's autodiff to 2e-6 of each one's largest."""
+        from byteps_tpu.ops import causal_conv as cc
+
+        wide, taps, bias, ct = self._inputs(with_bias)
+        fit = cc._blocks(wide, taps, self.LO, self.HI, l2_head, 0.3, interpret, self.BLOCKS)
+        assert (fit.rows, fit.lanes, fit.chunk, fit.halo) == (16, l2_head or 128, 8, 8)
+        assert cc._kernel_path(fit, interpret) == interpret
+        args = (wide, taps) + ((bias,) if with_bias else ())
+
+        def both(f):
+            y, pull = jax.vjp(lambda w, t, *b: f(w, t, *(b or (None,))), *args)
+            return (y, *pull(ct))
+
+        got = both(lambda w, t, b: self._run(w, t, b, l2_head, 0.3, interpret=interpret,
+                                             blocks=self.BLOCKS))
+        want = both(lambda w, t, b: self._loop(w, t, b, l2_head, 0.3))
+        assert len(got) == 3 + with_bias and got[1].shape == wide.shape
+        assert not np.any(np.asarray(got[1][..., :self.LO])) and not np.any(
+            np.asarray(got[1][..., self.HI:]))
+        for name, g, r in zip(("y", "dwide", "dtaps", "dbias"), got, want):
+            assert g.shape == r.shape and g.dtype == r.dtype, name
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0,
+                                       atol=2e-6 * float(jnp.max(jnp.abs(r))), err_msg=name)
+
+    # a cotangent on ONE token: the sequence's first, a block's last, a
+    # block's first (its dx lies in the block before), the sequence's last
+    @pytest.mark.parametrize("token", [0, 15, 16, 32, 63])
+    def test_a_tokens_cotangent_reaches_the_taps_before_it_across_a_block_edge(self, token):
+        wide, taps, bias, ct = self._inputs(True)
+        ct = jnp.zeros_like(ct).at[:, token].set(ct[:, token])
+
+        def dwide(f):
+            return np.asarray(jax.vjp(f, wide)[1](ct)[0])
+
+        got = dwide(lambda w: self._run(w, taps, bias, 128, 0.3, interpret=True,
+                                        blocks=self.BLOCKS))
+        want = dwide(lambda w: self._loop(w, taps, bias, 128, 0.3))
+        reached = np.flatnonzero(np.any(got != 0, axis=(0, 2)))
+        assert list(reached) == list(range(max(token - self.K + 1, 0), token + 1))
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-6 * np.abs(want).max())
+
+    @pytest.mark.parametrize("taps_k,kernels", [(3, True), (9, True), (10, False)])
+    def test_any_tap_count_whose_reach_is_inside_a_sublane_tile(self, taps_k, kernels):
+        """K taps reach K − 1 rows back, read from ONE f32 sublane tile before
+        a chunk (8 rows): 3 and 9 taps take the kernels, 10 XLA's form —
+        each equal to ``causal_conv`` + bias + silu by autodiff."""
+        from byteps_tpu.models.moe_family import causal_conv
+        from byteps_tpu.ops import causal_conv as cc
+
+        wide, _, bias, ct = self._inputs(True)
+        taps = jnp.asarray(np.random.default_rng(taps_k).normal(
+            size=(taps_k, self.HI - self.LO)).astype(np.float32)) * 0.4
+        fit = cc._blocks(wide, taps, self.LO, self.HI, None, 1.0, True, self.BLOCKS)
+        assert (fit is not None) == kernels
+
+        def both(f):
+            y, pull = jax.vjp(f, wide, taps, bias)
+            return (y, *pull(ct))
+
+        got = both(lambda w, t, b: self._run(w, t, b, None, 1.0, interpret=True,
+                                             blocks=self.BLOCKS))
+        want = both(lambda w, t, b: jax.nn.silu(causal_conv(w[..., self.LO:self.HI], t) + b))
+        for name, g, r in zip(("y", "dwide", "dtaps", "dbias"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0,
+                                       atol=2e-6 * float(jnp.max(jnp.abs(r))), err_msg=name)
+
+    @pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+    def test_bf16_in_is_f32_arithmetic_rounded_once(self, interpret):
+        """bf16 in and out: products, silu, the statistic and the sums over
+        the tokens are f32, y and d wide are rounded ONCE — within half a unit
+        in the last place of the loop's f32 result —, and the taps' and the
+        bias' gradients stay f32 (what ``delta_moe._conv_rounded``'s test
+        held of the pass this one replaced)."""
+        wide, taps, bias, ct = self._inputs(True, jnp.bfloat16)
+
+        def both(f, wide, ct):
+            y, pull = jax.vjp(f, wide, taps, bias)
+            return (y, *pull(ct))
+
+        got = both(lambda w, t, b: self._run(w, t, b, 128, 0.3, interpret=interpret,
+                                             blocks=(32, 128, 16)), wide, ct)
+        exact = both(lambda w, t, b: self._loop(w, t, b, 128, 0.3),
+                     wide.astype(jnp.float32), ct.astype(jnp.float32))
+        assert [g.dtype for g in got] == [jnp.bfloat16, jnp.bfloat16, jnp.float32, jnp.float32]
+        y, r = np.asarray(got[0], np.float32), np.asarray(exact[0])
+        assert np.all(np.abs(y - r) <= np.abs(r) * 2.0 ** -8 + 1e-30)
+        if interpret:  # autodiff's dx (XLA's form) adds the taps' rounded products in bf16
+            dx, r = np.asarray(got[1], np.float32), np.asarray(exact[1])
+            assert np.all(np.abs(dx - r) <= np.abs(r) * 2.0 ** -8 + 1e-6)
+        for g, r in zip(got[2:], exact[2:]):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0,
+                                       atol=3e-6 * float(jnp.max(jnp.abs(r))))
+
+    def test_the_backward_pass_keeps_the_projection_and_nothing_new(self):
+        """The forward rule's residuals are ``wide``, the taps and the bias
+        as they came in: no pre-activation, no statistic."""
+        from byteps_tpu.ops import causal_conv as cc
+
+        wide, taps, bias, _ = self._inputs(True)
+        fit = cc._blocks(wide, taps, self.LO, self.HI, 128, 0.3, True, self.BLOCKS)
+        _, kept = cc._fwd(wide, taps, bias, fit)
+        assert [k is x for k, x in zip(kept, (wide, taps, bias))] == [True] * 3
+
+    @pytest.mark.parametrize("lo,hi,l2_head", [(256, 700, None), (512, 256, None), (0, 256, 96)],
+                             ids=["past_the_width", "empty", "no_whole_heads"])
+    def test_ranges_that_are_no_columns_are_refused(self, lo, hi, l2_head):
+        from byteps_tpu.ops.causal_conv import conv_silu
+
+        with pytest.raises(ValueError, match="no such convolution"):
+            conv_silu(jnp.ones((1, 16, 640)), jnp.ones((4, max(hi - lo, 1))), lo=lo, hi=hi,
+                      l2_head=l2_head)
+
+    def test_a_traced_call_is_counted_by_its_path(self, monkeypatch):
+        """``conv_kernel_traces`` | ``conv_xla_traces``: one count a traced
+        call, by what ``_kernel_path`` chose."""
+        from byteps_tpu.core.telemetry import counters
+
+        def grown(run):
+            before = counters().snapshot()
+            run()
+            after = counters().snapshot()
+            return tuple(after.get(k, 0) - before.get(k, 0)
+                         for k in ("conv_kernel_traces", "conv_xla_traces"))
+
+        wide, taps, bias, _ = self._inputs(True)
+        assert grown(lambda: self._run(wide, taps, bias, None, 1.0)) == (0, 1)  # no TPU here
+        assert grown(lambda: self._run(wide, taps, bias, None, 1.0, interpret=True)) == (1, 0)
+        step = jax.jit(jax.grad(lambda w: jnp.sum(self._run(w, taps, bias, 128, 1.0))))
+        assert grown(lambda: (step(wide), step(wide))) == (0, 1)  # traced once, run twice
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+        shape = jax.ShapeDtypeStruct(wide.shape, jnp.bfloat16)
+        assert grown(lambda: jax.eval_shape(
+            lambda w: self._run(w, taps, bias, 128, 1.0), shape)) == (1, 0)
+
+    # (batch, sequence, the projection's width, lo, hi, a head, a bias): the
+    # three families' ranges at their published widths, then what does not
+    # tile — columns that start inside a lane tile, heads of 64, a sequence
+    # that is no whole sublane tiles of bf16
+    @pytest.mark.parametrize("b,s,width,lo,hi,l2_head,with_bias,kernels", [
+        (1, 16384, 12288, 2048, 4096, 128, False, True),
+        (2, 8192, 10304, 8192, 9216, None, True, True),
+        (1, 16384, 10240, 0, 5120, None, True, True),
+        (1, 16384, 12288, 64, 2112, None, False, False),
+        (1, 16384, 12288, 0, 2048, 64, False, False),
+        (1, 24, 12288, 0, 2048, 128, False, False),
+    ], ids=["gated_delta_k", "state_space_B", "mamba1_x", "inside_a_tile", "heads_of_64",
+            "ragged_sequence"])
+    def test_the_kernels_are_the_program_where_the_columns_tile(
+            self, monkeypatch, b, s, width, lo, hi, l2_head, with_bias, kernels):
+        """On a TPU both passes are one Pallas call each, read from the traced
+        gradient; where the shapes do not tile, none — ``_kernel_path`` alone
+        chooses, from the platform and the shapes."""
+        from byteps_tpu.ops import causal_conv as cc
+
+        monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+        shapes = [jax.ShapeDtypeStruct((b, s, width), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((4, hi - lo), jnp.float32)]
+        shapes += [jax.ShapeDtypeStruct((hi - lo,), jnp.float32)] * with_bias
+
+        def loss(wide, taps, *bias):
+            return jnp.sum(cc.conv_silu(wide, taps, *bias, lo=lo, hi=hi, l2_head=l2_head)
+                           .astype(jnp.float32))
+
+        names = _kernel_names(jax.grad(loss, argnums=tuple(range(len(shapes)))), *shapes)
+        assert names == ([cc.CONV_BWD_KERNEL, cc.CONV_FWD_KERNEL] if kernels else [])
+
+
 class TestOneBitDevice:
     # a block multiple; the engine's default partition (BYTEPS_PARTITION_BYTES
     # / 4, NOT a block multiple: padded on the device); a ragged tail
@@ -1086,6 +1300,15 @@ def _head_rope_family():
     return (lambda x: jnp.sum(head_rope(x, 128, 1e4, interpret=True).astype(jnp.float32))), (x,)
 
 
+def _conv_silu_family():
+    from byteps_tpu.ops.causal_conv import conv_silu
+
+    wide, taps = jnp.ones((1, 64, 3 * 128), jnp.bfloat16), jnp.ones((4, 256), jnp.float32)
+    return (lambda wide, taps, bias: jnp.sum(conv_silu(
+        wide, taps, bias, lo=128, hi=384, l2_head=128, scale=0.5, interpret=True,
+        blocks=(32, 128, 16)).astype(jnp.float32))), (wide, taps, taps[0])
+
+
 def _mla_heads_family():
     from byteps_tpu.ops.mla_heads import mla_heads
 
@@ -1121,6 +1344,9 @@ FROZEN_KERNELS = {
     "head_rope_bwd": (_head_rope_family, "1e07dd621526ddea"),
     "mla_heads_fwd": (_mla_heads_family, "509e04eb94d7e3c1"),
     "mla_heads_bwd": (_mla_heads_family, "64c84232f3393545"),
+    # taken when ISSUE 64 wrote the two kernels
+    "conv_silu_fwd": (_conv_silu_family, "868f93399d84f57d"),
+    "conv_silu_bwd": (_conv_silu_family, "25892f57a3c9a72b"),
 }
 
 

@@ -344,6 +344,7 @@ def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cac
     two Pallas kernels once each, no scan left to XLA, and no f32 array of a
     decay matrix's shape anywhere."""
     from byteps_tpu.models import ssm_moe as sm
+    from byteps_tpu.ops import causal_conv as cc
     from byteps_tpu.ops import ssd_kernels as sk
 
     if implementation == "kernels":
@@ -364,6 +365,11 @@ def test_state_space_mixer_compiles_at_published_widths(one_chip, no_compile_cac
     if implementation == "kernels":
         assert sk.FWD_KERNEL in text and sk.BWD_KERNEL in text and "while" not in text
         assert not re.search(r"f32\[[\d,]*128,128,8,8\]|f32\[[\d,]*,128,128\]", text)
+        # x, B and C are read out of in_proj's product by the convolution's
+        # index maps (the smallest, B | C, is 32 MB): nothing of it is cut
+        # out, padded or shifted under the scope
+        assert cc.CONV_FWD_KERNEL in text and cc.CONV_BWD_KERNEL in text
+        assert _cuts_written_under(text, "ssd_scan", 16 * 2**20) == []
     else:
         assert "while" in text and "tpu_custom_call" not in text
 
@@ -547,6 +553,7 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
     stay under what the parent's module of the same case needs (2.63 GiB; this
     one 2.41)."""
     from byteps_tpu.models import delta_moe as dm
+    from byteps_tpu.ops import causal_conv as cc
     from byteps_tpu.ops import gated_delta_kernels as gk
 
     monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
@@ -567,7 +574,11 @@ def test_delta_mixer_stays_token_major_at_published_widths(one_chip, no_compile_
         # q | k as the kernels' operand
         assert f"bf16[1,{s},{cfg.lin_k_heads * cfg.lin_k_dim}]" in text
         assert _relayouts_under(text, "gdn_scan", 64 * 2**20) == []
-    assert gk.BWD_KERNEL in text
+        # q, k and v are read out of w_qkvz's product by the convolution's
+        # index maps: no columns of it are cut out or padded back
+        assert cc.CONV_FWD_KERNEL in text
+        assert _cuts_written_under(text, "gdn_scan", 32 * 2**20) == []
+    assert gk.BWD_KERNEL in text and cc.CONV_BWD_KERNEL in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
 
 
@@ -703,6 +714,18 @@ def _top_level(text: str) -> list:
                       "tpu_custom_call" in body,
                       (re.search(r'op_name="([^"]*)"', line) or ["", ""])[1]))
     return found
+
+
+def _cuts_written_under(text: str, scope: str, least_bytes: int) -> list:
+    """The top-level instructions under ``scope`` that WRITE columns cut out
+    of an array, put back or rows shifted — a ``slice`` | ``pad`` |
+    ``concatenate`` (dynamic ones too) of its own, or a fusion XLA named for
+    one — in at least ``least_bytes``.  (A slice INSIDE a fusion is an index
+    and writes nothing: the gated norm reads z's columns so.)"""
+    return [f"{name}: {opcode} {result.strip()}" for name, opcode, result, _, kernel, path
+            in _top_level(text)
+            if scope in path and not kernel and _bytes_of(result) >= least_bytes
+            and re.search(r"slice|pad|concatenate", name if opcode == "fusion" else opcode)]
 
 
 def _kernel_operands(text: str, kernel: str) -> list:

@@ -19,6 +19,18 @@ timing says nothing of Mosaic.
 
 (``--shape``: batch, key heads, value heads, sequence, d_k, d_v.)
 
+``--conv`` times the pass BEFORE the rule instead (ops/causal_conv.py:
+depthwise causal convolution + bias + silu + a head's l2 norm, taken from a
+column range of a projection's output): forward + every gradient of
+``sum(y**2)`` for XLA's form and for the two kernels at every block of
+``--conv-blocks`` (rows x lanes x a chunk's rows), and the forward alone, one JSON line a
+range; nothing is written.  A range is ``batch,sequence,width,lo,hi,l2_head,
+bias`` — the projection's width, the columns, a head's size or 0, 1 for a
+bias — and any family's fits: qwen3-next's q is the default, its v
+``1,16384,12288,4096,8192,0,0``, nemotron's x ``2,8192,10304,4096,8192,0,1``:
+
+    python tools/gdn_tune.py --conv 1,16384,12288,0,2048,128,0 [more ranges]
+
 ``--rehearse`` is the CPU pre-flight of the same control flow (the Pallas
 interpreter at a toy length, nothing written): counts and control flow only.
 """
@@ -33,8 +45,65 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 from tools.timing import timed  # noqa: E402
 
 
+def conv_lines(args, device, interpret) -> int:
+    """``--conv``: a JSON line a range, XLA's form beside the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.ops import causal_conv as cc
+
+    steps = 1 if args.rehearse else args.steps
+    cdt = jnp.float32 if interpret else jnp.bfloat16
+    sweep = [tuple(int(v) for v in pair.split("x")) for pair in args.conv_blocks.split(",")]
+
+    def ms(fn, *xs):
+        return round(timed(jax.jit(fn), *xs, steps=steps), 3)
+
+    for spec in args.conv:
+        b, s, width, lo, hi, l2_head, with_bias = (int(v) for v in spec.split(","))
+        ks = jax.random.split(jax.random.PRNGKey(0), 3)
+        wide = jax.random.normal(ks[0], (b, s, width)).astype(cdt)
+        small = (jax.random.normal(ks[1], (4, hi - lo)) * 0.5,
+                 *((jax.random.normal(ks[2], (hi - lo,)),) if with_bias else ()))
+
+        def run(**how):
+            return lambda wide, *small: cc.conv_silu(
+                wide, *small, lo=lo, hi=hi, l2_head=l2_head or None, scale=0.5, **how)
+
+        def xla(wide, taps, bias=None):  # XLA's form, on any platform
+            return cc._xla_form(wide, taps, bias, lo, hi, l2_head or None, 0.5)
+
+        def whole(fn):
+            return jax.value_and_grad(lambda *xs: jnp.sum(fn(*xs).astype(jnp.float32) ** 2),
+                                      argnums=tuple(range(1 + len(small))))
+
+        xla_ms, xla_forward_ms = ms(whole(xla), wide, *small), ms(xla, wide, *small)
+        by_blocks = {}
+        for blocks in sweep:
+            how = dict(interpret=interpret, blocks=blocks)
+            fit = cc._blocks(wide, small[0], lo, hi, l2_head or None, 0.5, interpret, blocks)
+            if fit is None or f"{fit.rows}x{fit.lanes}x{fit.chunk}" in by_blocks:
+                continue
+            by_blocks[f"{fit.rows}x{fit.lanes}x{fit.chunk}"] = {
+                "ms": ms(whole(run(**how)), wide, *small),
+                "forward_ms": ms(run(**how), wide, *small)}
+        print(json.dumps({
+            "device": f"{device.platform}:{device.device_kind}", "rehearsal": args.rehearse,
+            "conv": [b, s, width, lo, hi, l2_head, with_bias], "dtype": jnp.dtype(cdt).name,
+            "what": "forward + every gradient of sum(y**2), ms a call; forward_ms: the "
+                    "forward alone; by_blocks: the kernels at rows x lanes a block x rows a chunk",
+            "xla_ms": xla_ms, "xla_forward_ms": xla_forward_ms, "by_blocks": by_blocks}))
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--conv", nargs="*", default=None, metavar="RANGE",
+                    help="time ops/causal_conv.py instead: batch,sequence,width,lo,hi,"
+                    "l2_head,bias a range (default: qwen3-next's q)")
+    ap.add_argument("--conv-blocks", default="512x512x64,512x512x128,512x512x256,512x512x512,"
+                    "256x512x128,1024x512x256",
+                    help="rows x lanes of a block x rows of a chunk to try")
     ap.add_argument("--shape", default="1,16,32,16384,128,128",
                     help="batch, key heads, value heads, sequence, d_k, d_v")
     ap.add_argument("--chunk", type=int, default=64)
@@ -60,6 +129,9 @@ def main() -> int:
               "--rehearse runs the control flow on the CPU", file=sys.stderr)
         return 2
     interpret = device.platform != "tpu"
+    if args.conv is not None:
+        args.conv = args.conv or ["1,16384,12288,0,2048,128,0"]
+        return conv_lines(args, device, interpret)
     b, hk, hv, s, dk, dv = (int(x) for x in args.shape.split(","))
     chunk, steps = args.chunk, 1 if args.rehearse else args.steps
     cdt = jnp.float32 if interpret else jnp.bfloat16  # the CPU has no bf16 batched products
