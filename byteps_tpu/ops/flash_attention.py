@@ -87,6 +87,37 @@ grid, or with tables before it a ``PrefetchScalarGridSpec``).  A kernel
 factory holds what its mask family really differs in: how a step finds its
 tile, the mask, and when dQ's rows are cleared and written.
 
+A partial tile is walked by sub-blocks.  A tile the mask crosses — the causal
+diagonal, a band's near and far edge, a block-diffusion diagonal — is not
+computed whole and masked: the pair's body takes a static LIST of rectangles
+(query rows × key columns, in whole sub-blocks of ``SUB_BLOCK`` a side) that
+hold at least one visible entry, and makes the scores, the mask, the
+exponentials and the products for those alone; a sub-block the mask hides
+whole costs nothing.  The tile, its grid step and its DMA stay what they were.
+A KIND of tile is a distinct map of such sub-blocks, made in numpy at trace
+time from the mask's own predicate (:func:`_band_kinds` from
+:func:`_band_visible` — with ``bq == bk`` a tile's pattern depends on its
+offset ``qi − j`` and the window alone; :func:`_bd_kinds` from
+:func:`block_diffusion_visible`, tile by tile, so two quadrants whose tiles
+list the same sub-blocks share a kind), and a kernel has one instance of the
+body a kind, chosen by ``pl.when``: the causal kernels on the diagonal, the
+banded ones on the offset, the table-driven ones from a ``kind`` table beside
+``kv_whole`` | ``q_whole``.  Adjacent sub-blocks merge into one rectangle
+along a strip (:func:`_rectangles`): the forward kernels cut a tile into strips
+of QUERY ROWS, so a row's maximum, sum and P V lose only terms that were exact
+zeros; the backward kernel into strips of KEYS, so dK's and dV's sums do —
+dQ's sum over a tile's keys is then taken a strip at a time into its f32
+accumulator, equal to rounding and not bit for bit (``STRIPS``).  The mask is
+still applied inside a listed rectangle.  The path is taken only where the
+geometry lines up (tiles of whole sub-blocks, at least two a side; for the
+causal and banded kernels ``bq == bk``) AND a kind spares at least
+``MIN_SPARED`` of its tile's sub-blocks; everything else — every whole tile,
+every small-block test, ``bq != bk`` — lowers as it did.  ``SUB_BLOCK`` and
+``MIN_SPARED`` come from one on-chip sweep (``tools/flash_tune.py
+--sub-blocks``; its numbers are in ``flash_blocks.json``'s source texts), and
+:func:`computed_entries` says from the shapes what a call computes and what
+its mask keeps.
+
 This is the per-device compute of the transformer's attention; sequence
 parallelism composes on top (ring attention rotates KV blocks *between*
 devices, these kernels handle the blocks *within* one device).
@@ -95,6 +126,7 @@ devices, these kernels handle the blocks *within* one device).
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -156,16 +188,105 @@ def _block_needed(causal: bool, qi, j, bq: int, bk: int, window=None):
     return needed
 
 
-def _causal_keep(qi, j, bq: int, bk: int, keys_down: bool = False, window=None):
-    """Bool mask of a block pair's causally-visible positions: (bq, bk), or
-    (bk, bq) with the keys down and the queries across; at a window, of those
-    the ones fewer than ``window`` back."""
-    shape, q_dim = ((bk, bq), 1) if keys_down else ((bq, bk), 0)
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+def _band_visible(rows, cols, window=None):
+    """The causal rule, and the band's: query ``rows`` sees key ``cols`` iff
+    ``0 <= rows - cols`` (``< window``).  Ints that broadcast, numpy's (the
+    lists of sub-blocks, :func:`_band_kinds`) or jax's (the kernels' mask)."""
     if window is not None:
         return (rows >= cols) & (rows - cols < window)
     return rows >= cols
+
+
+def _causal_keep(qi, j, bq: int, bk: int, keys_down: bool = False, window=None, rect=None):
+    """Bool mask of a block pair's causally-visible positions: (bq, bk), or
+    (bk, bq) with the keys down and the queries across; at a window, of those
+    the ones fewer than ``window`` back.  ``rect``: of that rectangle of the
+    pair alone (:func:`_rectangles`)."""
+    r0, r1, c0, c1 = rect or (0, bq, 0, bk)
+    shape, q_dim = ((c1 - c0, r1 - r0), 1) if keys_down else ((r1 - r0, c1 - c0), 0)
+    rows = _from(qi * bq, r0) + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    cols = _from(j * bk, c0) + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    return _band_visible(rows, cols, window)
+
+
+def _from(base, lo: int):
+    """``base + lo``; ``base`` itself at 0, so that a whole tile's text is the
+    one it was before a tile could be cut."""
+    return base + lo if lo else base
+
+
+# ---------------------------------------------------------------------------
+# a partial tile's sub-blocks: which of them hold a visible entry
+# ---------------------------------------------------------------------------
+
+#: side of a sub-block: a tile the mask crosses is walked in squares of this
+#: many rows and columns, and a square with no visible entry is not computed
+SUB_BLOCK = 256
+#: the least share of its sub-blocks a kind of tile must spare to be walked so
+MIN_SPARED = 0.3
+#: the axis of the strips a direction cuts a tile into: the forward kernels by
+#: query rows (a row's maximum, sum and P V keep their order), the backward
+#: kernel by keys (dK's and dV's sums keep theirs; dQ's is taken in pieces)
+STRIPS = {"fwd": "q", "bwd": "k"}
+#: a table-driven call with more distinct kinds than this lowers whole tiles
+MAX_KINDS = 4
+
+
+def _lines_up(bq: int, bk: int, sub: int) -> bool:
+    """Whether a (bq, bk) tile is whole sub-blocks, at least two a side."""
+    return bq % sub == 0 and bk % sub == 0 and min(bq, bk) >= 2 * sub
+
+
+def _sub_map(visible, sub: int):
+    """(bq, bk) bools → (bq / sub, bk / sub): a sub-block holds a visible entry."""
+    bq, bk = visible.shape
+    return visible.reshape(bq // sub, sub, bk // sub, sub).any(axis=(1, 3))
+
+
+def _spares_enough(sub_map, least: float) -> bool:
+    return 1 - sub_map.mean() >= least
+
+
+def _rectangles(sub_map, sub: int, by: str) -> tuple:
+    """The listed sub-blocks of a map as rectangles ``(r0, r1, c0, c1)`` of
+    entries (query rows × key columns), strips ascending: ``by`` "q" cuts the
+    tile into strips of query rows one sub-block high, "k" of key columns; a
+    strip's adjacent sub-blocks are one rectangle, and adjacent strips that
+    list the same run merge."""
+    import numpy as np
+
+    found = []  # [strip from, to, run from, to), in sub-blocks
+    for at, line in enumerate(sub_map if by == "q" else sub_map.T):
+        edges = np.flatnonzero(np.diff(np.r_[0, line.astype(int), 0])).tolist()
+        for run in zip(edges[::2], edges[1::2]):  # a run's first sub-block, and one past its last
+            same = [f for f in found if f[1] == at and f[2:] == list(run)]
+            if same:
+                same[0][1] = at + 1
+            else:
+                found.append([at, at + 1, *run])
+    return tuple(tuple(sub * x for x in (f if by == "q" else f[2:] + f[:2])) for f in found)
+
+
+@functools.lru_cache(maxsize=None)
+def _band_kinds(bq: int, bk: int, window, sub: int, least: float) -> dict:
+    """offset ``qi - j`` → sub-block map (numpy bools), for the kinds of tile
+    of a causal (``window`` None) or banded call that are walked by sub-blocks.
+    With ``bq == bk`` a tile's pattern is its offset's, whatever the tile; a
+    geometry that does not line up has no kinds and lowers whole tiles."""
+    import numpy as np
+
+    if bq != bk or not _lines_up(bq, bk, sub):
+        return {}
+    offsets = range(1 if window is None else (window + bq - 2) // bq + 1)
+    rows, cols = np.ogrid[:bq, :bk]
+    maps = {o: _sub_map(_band_visible(o * bq + rows, cols, window), sub) for o in offsets}
+    return {o: m for o, m in maps.items() if _spares_enough(m, least)}
+
+
+def _band_rects(bq, bk, window, direction: str) -> dict:
+    """offset → rectangles, at the module's constants, for ``direction``'s kernel."""
+    kinds = _band_kinds(bq, bk, window, SUB_BLOCK, MIN_SPARED)
+    return {o: _rectangles(m, SUB_BLOCK, STRIPS[direction]) for o, m in kinds.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -225,32 +346,50 @@ def _softmax_init(m_scr, l_scr, acc_scr):
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def _softmax_pair(q_ref, k_ref, v_ref, keep, scale, m_scr, l_scr, acc_scr):
+def _cut(ref, lo: int, hi: int, axis: int = 0):
+    """Rows (``axis`` 0) or lanes (1) ``[lo, hi)`` of a block ref's leading
+    item — static, on sub-block boundaries; the whole item where the range is."""
+    if (lo, hi) == (0, ref.shape[1 + axis]):
+        return ref[0]
+    return ref[0, lo:hi, :] if axis == 0 else ref[0, :, lo:hi]
+
+
+def _softmax_pair(q_ref, k_ref, v_ref, keep, scale, m_scr, l_scr, acc_scr, rects=None):
     """One (query block, key block) pair of the online softmax, for every
     forward kernel.  ``keep``: ``None`` where the whole tile is visible, else
     a function that gives the bool tile of visible positions — a function, so
     that the mask is made where it is used, after the scores.  A row with no
     visible key in a tile adds it at weight 1 under m = NEG_INF, which its
     first real maximum's exp(NEG_INF - m) wipes: every row sees at least one
-    key, itself or the sequence's first."""
-    v = v_ref[0]
-    s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    if keep is not None:
-        s = jnp.where(keep(), s, NEG_INF)
-    m = m_scr[:]  # (bq, LANES), value broadcast across lanes
-    l = l_scr[:]
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new[:, 0:1])
-    m_scr[:] = m_new
-    l_scr[:] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha[:, 0:1] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    key, itself or the sequence's first.
+
+    ``rects``: a partial tile's listed rectangles (:func:`_rectangles`) — the
+    scores, the mask (``keep(rect)``), the exponentials and P V are made for
+    these alone, each against its own rows of the state; a row outside every
+    rectangle has no visible key here and its state stands.  By strips of
+    query rows a row's maximum, sum and P V lose only terms that were exact
+    zeros."""
+    for rect in rects or (None,):
+        r0, r1, c0, c1 = rect or (0, q_ref.shape[1], 0, k_ref.shape[1])
+        rows = slice(None) if rect is None else slice(r0, r1)
+        v = _cut(v_ref, c0, c1)
+        s = jax.lax.dot_general(
+            _cut(q_ref, r0, r1), _cut(k_ref, c0, c1), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        if keep is not None:
+            s = jnp.where(keep(rect=rect), s, NEG_INF)
+        m = m_scr[rows]  # (rows, LANES), value broadcast across lanes
+        l = l_scr[rows]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[:, 0:1])
+        m_scr[rows] = m_new
+        l_scr[rows] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[rows] = acc_scr[rows] * alpha[:, 0:1] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def _softmax_emit(o_ref, lse_ref, m_scr, l_scr, acc_scr):
@@ -266,6 +405,8 @@ def _fwd_kernel_factory(bq, bk, nk, causal, scale, window=None):
     ``first + t`` of its query block."""
     from jax.experimental import pallas as pl
 
+    kinds = _band_rects(bq, bk, window, "fwd") if causal else {}
+
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch):
         qi = pl.program_id(1)
         step = j = pl.program_id(2)
@@ -273,11 +414,24 @@ def _fwd_kernel_factory(bq, bk, nk, causal, scale, window=None):
             j = _first_kv_block(qi, bq, bk, window) + step
         keep = functools.partial(_causal_keep, qi, j, bq, bk, window=window) if causal else None
         pl.when(step == 0)(functools.partial(_softmax_init, *scratch))
-        pl.when(_block_needed(causal, qi, j, bq, bk, window))(
-            functools.partial(_softmax_pair, q_ref, k_ref, v_ref, keep, scale, *scratch))
+        for needed, rects in _by_kind(_block_needed(causal, qi, j, bq, bk, window), qi, j, kinds):
+            pl.when(needed)(functools.partial(
+                _softmax_pair, q_ref, k_ref, v_ref, keep, scale, *scratch, rects=rects))
         pl.when(step == nk - 1)(functools.partial(_softmax_emit, o_ref, lse_ref, *scratch))
 
     return kernel
+
+
+def _by_kind(needed, qi, j, kinds: dict):
+    """(when, rectangles) of a causal or banded kernel's pair: the whole-tile
+    body where the tile's offset ``qi - j`` is of no kind — all there is where
+    the geometry does not line up —, and one body a kind (:func:`_band_kinds`)."""
+    if not kinds:
+        return [(needed, None)]
+    offset = qi - j
+    rest = functools.reduce(jnp.logical_and, [offset != o for o in kinds])
+    return [(needed & rest, None)] + [(needed & (offset == o), rects)
+                                      for o, rects in kinds.items()]
 
 
 def _kv_index(causal, bq, bk, window=None, group=1):
@@ -384,34 +538,48 @@ def _flash_forward(q, k, v, causal, scale, bq, bk, interpret, window=None):
 
 
 def _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
-               dq_scr, dk_scr, dv_scr):
+               dq_scr, dk_scr, dv_scr, rects=None):
     """One (key block, query block) pair of every backward kernel: the five
     products and the three accumulations, dQ's into ``rows`` of its accumulator.
     Everything is (bk, bq), keys down and queries across: lse and Δ are then
     one row, and of the five products only dQ's takes its left operand
-    transposed.  ``keep`` as :func:`_softmax_pair`'s, of a keys-down tile."""
-    q, k, do = q_ref[0], k_ref[0], do_ref[0]
-    st = jax.lax.dot_general(
-        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    pt = jnp.exp(st - lse_ref[0])
-    if keep is not None:
-        pt = jnp.where(keep(), pt, 0.0)
-    dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-        pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dpt = jax.lax.dot_general(
-        v_ref[0], do, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
-    dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-        dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    dq_scr[rows, :] = dq_scr[rows, :] + scale * jax.lax.dot_general(
-        dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    transposed.  ``keep`` as :func:`_softmax_pair`'s, of a keys-down tile.
+
+    ``rects`` as :func:`_softmax_pair`'s: the five products for the listed
+    rectangles alone, dQ into the rectangle's rows of ``rows``, dK | dV into
+    its columns' rows of their accumulators.  By strips of keys dK's and dV's
+    sums lose only exact zeros; dQ's sum over a tile's keys is then taken a
+    strip at a time, each added to the f32 accumulator: equal to rounding,
+    not bit for bit (by strips of query rows it is the other way round)."""
+    from jax.experimental import pallas as pl
+
+    for rect in rects or (None,):
+        r0, r1, c0, c1 = rect or (0, q_ref.shape[1], 0, k_ref.shape[1])
+        cols = slice(None) if rect is None else slice(c0, c1)
+        into = rows if rect is None else pl.ds(
+            pl.multiple_of(rows.start + r0, math.gcd(rows.size, r0)), r1 - r0)
+        q, k, do = _cut(q_ref, r0, r1), _cut(k_ref, c0, c1), _cut(do_ref, r0, r1)
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        pt = jnp.exp(st - _cut(lse_ref, r0, r1, axis=1))
+        if keep is not None:
+            pt = jnp.where(keep(rect=rect), pt, 0.0)
+        dv_scr[cols] = dv_scr[cols] + jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            _cut(v_ref, c0, c1), do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dst = (pt * (dpt - _cut(delta_ref, r0, r1, axis=1))).astype(q.dtype)
+        dk_scr[cols] = dk_scr[cols] + scale * jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dq_scr[into, :] = dq_scr[into, :] + scale * jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
 
 
 def _dkv_init(dk_scr, dv_scr):
@@ -440,6 +608,7 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
     from jax.experimental import pallas as pl
 
     steps = nq if steps is None else steps
+    kinds = _band_rects(bq, bk, window, "bwd") if causal else {}
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
@@ -461,14 +630,16 @@ def _bwd_kernel_factory(bq, bk, nq, nk, causal, scale, window=None, steps=None):
         if window is not None:
             needed &= qi < nq  # the band's steps past the sequence's end
 
-        @pl.when(needed)
-        def _block():
+        def _block(rects):
             if window is not None:  # the first key block this query block meets
                 pl.when(j == _first_kv_block(qi, bq, bk, window))(clear_dq)
             _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
-                       dq_scr, dk_scr, dv_scr)
+                       dq_scr, dk_scr, dv_scr, rects=rects)
             if window is not None:  # the running sum; a block's last pair writes it whole
                 write_dq()
+
+        for when, rects in _by_kind(needed, qi, j, kinds):
+            pl.when(when)(functools.partial(_block, rects))
 
         pl.when(step == steps - 1)(functools.partial(_dkv_emit, dk_ref, dv_ref, dk_scr, dv_scr))
         if window is None:
@@ -483,7 +654,9 @@ def _bwd_vmem_bytes(s, bq, bk, dqk, dv, itemsize) -> int:
     (Pallas double-buffers them), the (bk, bq) intermediates — scores, P, dP
     and dS in f32, P's and dS's casts —, a quarter over for what Mosaic
     spills, and never under Mosaic's own default.  A head size takes whole
-    lane tiles."""
+    lane tiles.  (A partial tile's rectangles hold smaller intermediates, one
+    rectangle at a time; the whole tiles beside them set the peak, so the
+    figure stands.)"""
     dqk, dv = (-(-d // LANES) * LANES for d in (dqk, dv))
     acc = 4 * (s * dqk + bk * (dqk + dv))
     blocks = 2 * itemsize * (2 * (bq + bk) * dqk + (bq + 2 * bk) * dv) + 2 * 2 * 8 * bq * 4
@@ -903,31 +1076,140 @@ def _bd_tiles(sq: int, half: int, block: int, bq: int, bk: int) -> dict:
             "first_kv": kv_of.reshape(nq, steps_f)[:, 0].copy(), "pairs": int(needed.sum())}
 
 
-def _bd_keep(qi, j, bq: int, bk: int, half: int, block: int, keys_down: bool = False):
+@functools.lru_cache(maxsize=None)
+def _bd_kinds(sq: int, half: int, block: int, bq: int, bk: int, sub: int, least: float) -> tuple:
+    """(``kind`` (nq, nk) int32, the kinds' sub-block maps, whether a partial
+    tile is left on the whole-tile path): for every tile
+    pair of a block-diffusion call 0 where it takes the whole-tile path, else
+    ``i + 1`` for the ``i``-th distinct map of sub-blocks that hold a visible
+    entry — made tile by tile from :func:`block_diffusion_visible`, for the
+    partial tiles of :func:`_bd_tiles` whose map spares enough.  Two quadrants
+    whose tiles list the same sub-blocks are one kind.  No kinds where the tiles
+    are not whole sub-blocks or the call has more than ``MAX_KINDS`` of them."""
+    import numpy as np
+
+    nq, nk = sq // bq, 2 * half // bk
+    kind, maps, rest = np.zeros((nq, nk), np.int32), [], False
+    if not _lines_up(bq, bk, sub):
+        return kind, (), True
+    tiles = _bd_tiles(sq, half, block, bq, bk)
+    steps = tiles["steps_f"]
+    for qi in range(nq):
+        for t in range(int(tiles["n_kv"][qi])):
+            j = int(tiles["kv_of"][qi * steps + t])
+            if tiles["kv_whole"][qi * steps + t]:
+                continue
+            rows, cols = np.ogrid[qi * bq:(qi + 1) * bq, j * bk:(j + 1) * bk]
+            sub_map = _sub_map(block_diffusion_visible(rows, cols, half, block), sub)
+            if not _spares_enough(sub_map, least):
+                rest = True
+                continue
+            same = [i for i, m in enumerate(maps) if np.array_equal(m, sub_map)]
+            if not same:
+                maps.append(sub_map)
+            kind[qi, j] = same[0] + 1 if same else len(maps)
+    if len(maps) > MAX_KINDS:
+        return np.zeros_like(kind), (), True
+    return kind, tuple(maps), rest
+
+
+def _bd_kind_tables(tiles: dict, sq, half, block, bq, bk) -> dict:
+    """``kv_kind`` laid out as ``kv_of`` and ``q_kind`` as ``q_of``, the kinds'
+    ``maps`` and whether the masked whole tile is still some pair's body
+    (``rest``), at the module's constants; no tables where the call has no kinds."""
+    import numpy as np
+
+    kind, maps, rest = _bd_kinds(sq, half, block, bq, bk, SUB_BLOCK, MIN_SPARED)
+    if not maps:
+        return {"maps": (), "rest": True}
+    nq, nk = kind.shape
+    return {"kv_kind": kind[np.repeat(np.arange(nq), tiles["steps_f"]), tiles["kv_of"]],
+            "q_kind": kind.T[np.repeat(np.arange(nk), tiles["steps_b"]), tiles["q_of"]],
+            "maps": maps, "rest": rest}
+
+
+def _bd_by_kind(needed, whole, kind, kinds: dict, direction: str):
+    """(when, masked, rectangles) of a table-driven kernel's pair: the tile
+    visible whole, the masked whole tile (where some pair still takes it), and
+    one body a kind; ``kind``: a function that reads this pair's from its table."""
+    yield needed & whole, False, None  # (a generator: each condition is traced at its branch)
+    partial = needed & jnp.logical_not(whole)
+    if not kinds["maps"]:
+        yield partial, True, None
+        return
+    kind = kind()
+    if kinds["rest"]:
+        yield partial & (kind == 0), True, None
+    for i, m in enumerate(kinds["maps"]):
+        yield needed & (kind == i + 1), True, _rectangles(m, SUB_BLOCK, STRIPS[direction])
+
+
+def computed_entries(sq: int, sk: int, bq: int, bk: int, window=None, block_length=None,
+                     whole_tiles: bool = False) -> tuple:
+    """(entries of the score matrix one head's call computes, entries its mask
+    keeps), from the shapes alone: a causal call (``sq == sk``), a banded one
+    (``window``) or a block-diffusion one (``block_length``; ``sk`` = 2L key
+    rows) at tiles of (bq, bk) and the module's constants.  A tile that is
+    walked by sub-blocks counts its listed rectangles, any other tile that holds
+    a visible entry counts whole; ``whole_tiles`` counts every tile whole (what
+    was computed before a tile could be cut).  The forward kernel's list — the
+    backward kernel's covers the same sub-blocks."""
+    import numpy as np
+
+    def listed(sub_map):
+        return int(sub_map.sum()) * SUB_BLOCK ** 2
+
+    if block_length is not None:
+        half = sk // 2
+        tiles = _bd_tiles(sq, half, block_length, bq, bk)
+        kind, maps, _ = _bd_kinds(sq, half, block_length, bq, bk, SUB_BLOCK, MIN_SPARED)
+        computed = tiles["pairs"] * bq * bk
+        if maps and not whole_tiles:
+            computed += sum(int(np.sum(kind == i + 1)) * (listed(m) - bq * bk)
+                            for i, m in enumerate(maps))
+        blocks = half // block_length  # a noisy query: its block, the clean ones before it
+        kept = half * block_length + block_length ** 2 * blocks * (blocks - 1) // 2
+        if sq == sk:  # a clean query: its block and the ones before it
+            kept += block_length ** 2 * blocks * (blocks + 1) // 2
+        return computed, kept
+    kinds = {} if whole_tiles else _band_kinds(bq, bk, window, SUB_BLOCK, MIN_SPARED)
+    computed = 0
+    for qi in range(sq // bq):
+        first = 0 if window is None else _first_kv_block(qi, bq, bk, window)
+        for j in range(first, _last_kv_block(qi, bq, bk) + 1):
+            computed += listed(kinds[qi - j]) if qi - j in kinds else bq * bk
+    span = sq if window is None else min(window, sq)  # row i keeps min(i + 1, span) keys
+    return computed, span * (span + 1) // 2 + (sq - span) * span
+
+
+def _bd_keep(qi, j, bq: int, bk: int, half: int, block: int, keys_down: bool = False, rect=None):
     """Bool mask of a tile pair's visible positions: (bq, bk), or (bk, bq)
-    with the keys down; rows and columns are one vector each until compared."""
-    q_shape, k_shape, q_dim = ((1, bq), (bk, 1), 1) if keys_down else ((bq, 1), (1, bk), 0)
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_dim)
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_dim)
+    with the keys down; rows and columns are one vector each until compared.
+    ``rect`` as :func:`_causal_keep`'s."""
+    r0, r1, c0, c1 = rect or (0, bq, 0, bk)
+    nr, nc = r1 - r0, c1 - c0
+    q_shape, k_shape, q_dim = ((1, nr), (nc, 1), 1) if keys_down else ((nr, 1), (1, nc), 0)
+    rows = _from(qi * bq, r0) + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_dim)
+    cols = _from(j * bk, c0) + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_dim)
     return block_diffusion_visible(rows, cols, half, block)
 
 
-def _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block):
+def _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block, kinds):
     from jax.experimental import pallas as pl
 
-    def kernel(kv_of, n_kv, kv_whole, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch):
+    def kernel(kv_of, n_kv, kv_whole, *refs):
+        (kv_kind,), refs = (refs[:1], refs[1:]) if kinds["maps"] else ((None,), refs)
+        q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch = refs
         qi, step = pl.program_id(1), pl.program_id(2)
         at = qi * steps + step
         j = kv_of[at]
         pl.when(step == 0)(functools.partial(_softmax_init, *scratch))
-
-        def pair(keep):
-            _softmax_pair(q_ref, k_ref, v_ref, keep, scale, *scratch)
-
-        needed, whole = step < n_kv[qi], kv_whole[at] == 1
-        pl.when(needed & whole)(functools.partial(pair, None))
-        pl.when(needed & jnp.logical_not(whole))(functools.partial(
-            pair, functools.partial(_bd_keep, qi, j, bq, bk, half, block)))
+        keep = functools.partial(_bd_keep, qi, j, bq, bk, half, block)
+        for when, masked, rects in _bd_by_kind(step < n_kv[qi], kv_whole[at] == 1,
+                                               lambda: kv_kind[at], kinds, "fwd"):
+            pl.when(when)(functools.partial(
+                _softmax_pair, q_ref, k_ref, v_ref, keep if masked else None, scale, *scratch,
+                rects=rects))
         pl.when(step == steps - 1)(functools.partial(_softmax_emit, o_ref, lse_ref, *scratch))
 
     return kernel
@@ -944,50 +1226,56 @@ def _bd_scalars(tiles: dict, names: tuple, *tensors) -> tuple:
 def _bd_forward(q, k, v, block, scale, bq, bk, interpret):
     sq, half, group = q.shape[2], k.shape[2] // 2, q.shape[1] // k.shape[1]
     tiles = _bd_tiles(sq, half, block, bq, bk)
+    kinds = _bd_kind_tables(tiles, sq, half, block, bq, bk)
     steps = tiles["steps_f"]
     kv_index = lambda i, qi, t, kv_of, *_: (i, kv_of[qi * steps + t], 0)  # noqa: E731
+    names = ("kv_of", "n_kv", "kv_whole") + ("kv_kind",) * bool(kinds["maps"])
     return _forward_call(
-        _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block), FWD_BD_KERNEL, steps,
+        _bd_fwd_kernel_factory(bq, bk, steps, scale, half, block, kinds), FWD_BD_KERNEL, steps,
         _kv_row(kv_index, group), q, k, v, bq, bk, interpret,
-        _bd_scalars(tiles, ("kv_of", "n_kv", "kv_whole"), q, k, v))
+        _bd_scalars({**tiles, **kinds}, names, q, k, v))
 
 
-def _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block):
+def _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block, kinds):
     from jax.experimental import pallas as pl
 
-    def kernel(q_of, n_q, q_whole, first_kv, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+    def kernel(q_of, n_q, q_whole, first_kv, *refs):
+        (q_kind,), refs = (refs[:1], refs[1:]) if kinds["maps"] else ((None,), refs)
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr) = refs
         j, step = pl.program_id(1), pl.program_id(2)  # key tile; its step-th query tile
         at = j * steps + step
         qi = q_of[at]
         rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)  # this query tile's of dq_scr
         pl.when(step == 0)(functools.partial(_dkv_init, dk_scr, dv_scr))
 
-        def pair(keep):
+        def pair(keep, rects):
             # key tiles ascend: the first this query tile meets
             pl.when(j == first_kv[qi])(functools.partial(_dq_clear, dq_scr, rows, bq))
             _grad_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, keep, scale, rows,
-                       dq_scr, dk_scr, dv_scr)
+                       dq_scr, dk_scr, dv_scr, rects=rects)
             _dq_write(dq_ref, dq_scr, rows)  # the running sum; a tile's last pair writes it whole
 
-        needed, whole = step < n_q[j], q_whole[at] == 1
-        pl.when(needed & whole)(functools.partial(pair, None))
-        pl.when(needed & jnp.logical_not(whole))(functools.partial(
-            pair, functools.partial(_bd_keep, qi, j, bq, bk, half, block, keys_down=True)))
+        keep = functools.partial(_bd_keep, qi, j, bq, bk, half, block, keys_down=True)
+        for when, masked, rects in _bd_by_kind(step < n_q[j], q_whole[at] == 1,
+                                               lambda: q_kind[at], kinds, "bwd"):
+            pl.when(when)(functools.partial(pair, keep if masked else None, rects))
         pl.when(step == steps - 1)(functools.partial(_dkv_emit, dk_ref, dv_ref, dk_scr, dv_scr))
 
     return kernel
 
 
 def _bd_backward(q, k, v, o, lse, do, block, scale, bq, bk, interpret, dlse=None):
-    half = k.shape[2] // 2
-    tiles = _bd_tiles(q.shape[2], half, block, bq, bk)
+    sq, half = q.shape[2], k.shape[2] // 2
+    tiles = _bd_tiles(sq, half, block, bq, bk)
+    kinds = _bd_kind_tables(tiles, sq, half, block, bq, bk)
     steps = tiles["steps_b"]
     q_index = lambda i, j, t, q_of, *_: (i, q_of[j * steps + t], 0)  # noqa: E731
     rows = _rows_flat(do, o, lse, dlse)
-    scalars = _bd_scalars(tiles, ("q_of", "n_q", "q_whole", "first_kv"), q, k, v, o, lse, do)
+    names = ("q_of", "n_q", "q_whole", "first_kv") + ("q_kind",) * bool(kinds["maps"])
+    scalars = _bd_scalars({**tiles, **kinds}, names, q, k, v, o, lse, do)
     return _backward_call(
-        _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block), BWD_BD_KERNEL, steps,
+        _bd_bwd_kernel_factory(bq, bk, steps, scale, half, block, kinds), BWD_BD_KERNEL, steps,
         q_index, q_index, (q, k, v), _heads_flat(q, k, v) + rows, bq, bk, interpret, scalars)
 
 

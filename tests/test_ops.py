@@ -389,6 +389,9 @@ class TestFlashBand:
         (True, False): "6d387562cec12f1e", (True, True): "15062387814322b4",
         (False, False): "86d5adc5ec66e202", (False, True): "d2905411e9475f56",
         "tpu_jaxpr": "410cbf3fa02cea60",
+        # re-taken on purpose at PR 65: at 1024 x 1024 the diagonal tiles are
+        # walked by sub-blocks; with no tile lined up the text above stands
+        "tpu_jaxpr_sub_blocks": "8a7ea95ee63ce71c",
     }
 
     @pytest.mark.parametrize("causal,lse_out", [(True, False), (True, True), (False, False),
@@ -411,17 +414,19 @@ class TestFlashBand:
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
             self.FROZEN_WITHOUT_A_WINDOW[causal, lse_out]
 
-    def test_without_a_window_the_tpu_path_traces_as_before(self, monkeypatch):
+    @pytest.mark.parametrize("held,side", [("tpu_jaxpr", 1 << 30), ("tpu_jaxpr_sub_blocks", 256)])
+    def test_without_a_window_the_tpu_path_traces_as_before(self, monkeypatch, held, side):
         import hashlib
 
         fa = self._fa()
         monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+        monkeypatch.setattr(fa, "SUB_BLOCK", side)
         q = jax.ShapeDtypeStruct((1, 16, 16384, 256), jnp.bfloat16)
         grad = jax.grad(lambda q, k, v: jnp.sum(
             fa.flash_attention(q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
         text = str(jax.make_jaxpr(grad)(q, q, q))
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-            self.FROZEN_WITHOUT_A_WINDOW["tpu_jaxpr"]
+            self.FROZEN_WITHOUT_A_WINDOW[held]
 
     def test_banded_blocks_have_a_table_of_their_own(self, monkeypatch, tmp_path):
         import json
@@ -443,6 +448,219 @@ class TestFlashBand:
             (512, 512), (512, 512), (512, 512), (1024, 1024), (1024, 1024)]
         bq, bk = fa.tuned_blocks(16384, 2048)
         assert 16384 % bq == 0 and 16384 % bk == 0 and (16384, 2048) in fa._tuned_table()["banded"]
+
+
+class TestFlashSubBlocks:
+    """A partial tile is walked by sub-blocks: the lists against the masks'
+    predicates entry by entry, the kernels on that path (interpret mode)
+    against the dense masks and against the whole-tile path, and the count of
+    what a call computes."""
+
+    _fa = staticmethod(TestFlashBand._fa)
+
+    @staticmethod
+    def _constants(monkeypatch, side, least=0.2):
+        fa = TestFlashBand._fa()
+        monkeypatch.setattr(fa, "SUB_BLOCK", side)
+        monkeypatch.setattr(fa, "MIN_SPARED", least)
+        return fa
+
+    @classmethod
+    def _kinds(cls, fa, family, tile, side):
+        """[(tile's first row, first column, its sub-block map)] of a family's
+        kinds at (tile, tile) tiles: ("band", window) over any sequence,
+        ("bd", block length, queries, key rows)."""
+        if family[0] == "band":
+            maps = fa._band_kinds(tile, tile, family[1], side, 0.01)
+            return [(o * tile, 0, m) for o, m in maps.items()]
+        _, block, sq, sk = family
+        kind, maps, _ = fa._bd_kinds(sq, sk // 2, block, tile, tile, side, 0.01)
+        firsts = [np.argwhere(kind == i + 1)[0] for i in range(len(maps))]
+        return [(qi * tile, j * tile, m) for (qi, j), m in zip(firsts, maps)]
+
+    @staticmethod
+    def _visible(fa, family, rows, cols):
+        """The mask by the dense references' own rule, not the lists'."""
+        if family[0] == "band":
+            seen = rows >= cols
+            return seen if family[1] is None else seen & (cols > rows - family[1])
+        return np.asarray(fa.block_diffusion_visible(rows, cols, family[3] // 2, family[1]))
+
+    # (family, tile, sub-block side, kinds expected, listed sub-blocks of each)
+    @pytest.mark.parametrize("family,tile,side,listed", [
+        (("band", None), 1024, 256, [10]), (("band", 4096), 1024, 256, [10, 10]),
+        (("band", 2048), 1024, 256, [10, 10]), (("band", 512), 1024, 256, [9, 3]),
+        (("band", None), 1024, 128, [36]), (("band", None), 1024, 512, [3]),
+        (("bd", 4, 16384, 16384), 1024, 256, [4, 10]), (("bd", 4, 8192, 16384), 1024, 256, [4, 10]),
+        (("bd", 32, 16384, 16384), 1024, 256, [4, 10]),
+        (("band", None), 64, 16, [10]), (("band", 64), 64, 16, [10, 10]),
+        (("band", 100), 64, 16, [10, 6]), (("band", 37), 64, 16, [10, 6]),
+        (("band", 200), 64, 16, [10, 13, 1]), (("bd", 4, 256, 256), 64, 16, [4, 10]),
+        (("bd", 4, 128, 256), 64, 16, [4, 10]), (("bd", 32, 256, 256), 64, 16, [8, 4, 12]),
+    ], ids=str)
+    @pytest.mark.parametrize("by", ["q", "k"])
+    def test_the_listed_rectangles_cover_what_the_mask_keeps(self, family, tile, side, listed, by):
+        fa = self._fa()
+        kinds = self._kinds(fa, family, tile, side)
+        assert [int(m.sum()) for _, _, m in kinds] == listed
+        for row0, col0, sub_map in kinds:
+            rows, cols = np.ogrid[row0:row0 + tile, col0:col0 + tile]
+            seen = self._visible(fa, family, rows, cols)
+            covered = np.zeros((tile, tile), int)
+            for r0, r1, c0, c1 in fa._rectangles(sub_map, side, by):
+                assert r0 % side == r1 % side == c0 % side == c1 % side == 0
+                covered[r0:r1, c0:c1] += 1
+            assert covered.max() == 1, "two rectangles overlap"
+            assert not np.any(seen & (covered == 0)), "a visible entry is in no rectangle"
+            per_block = (seen & (covered == 1)).reshape(
+                tile // side, side, tile // side, side).any(axis=(1, 3))
+            listed_blocks = covered.reshape(tile // side, side, tile // side, side).all(axis=(1, 3))
+            np.testing.assert_array_equal(per_block, listed_blocks,
+                                          err_msg="a listed sub-block is wholly hidden")
+            np.testing.assert_array_equal(listed_blocks, sub_map)
+
+    def test_two_quadrants_that_list_the_same_sub_blocks_are_one_kind(self):
+        """SDAR's call: the noisy x noisy diagonal is one kind (4 of 16), the
+        noisy x clean and clean x clean diagonals share the other (10 of 16),
+        and no partial tile is left on the whole-tile path."""
+        fa = self._fa()
+        kind, maps, rest = fa._bd_kinds(16384, 8192, 4, 1024, 1024, 256, 0.3)
+        assert [int(m.sum()) for m in maps] == [4, 10] and not rest
+        assert np.bincount(kind.ravel()).tolist() == [256 - 24, 8, 16]
+        assert all(kind[i, i] == 1 and kind[i, 8 + i] == 2 and kind[8 + i, 8 + i] == 2
+                   for i in range(8))
+
+    def test_too_many_kinds_or_too_little_spared_take_the_whole_tile(self):
+        fa = self._fa()
+        assert fa._band_kinds(1024, 1024, None, 512, 0.3) == {}  # 3 of 4 spares a quarter
+        assert sorted(fa._band_kinds(1024, 1024, None, 512, 0.25)) == [0]
+        assert fa._band_kinds(1024, 512, 2048, 256, 0.1) == {}  # bq != bk
+        assert fa._band_kinds(256, 256, None, 256, 0.1) == {}  # one sub-block a side
+        kind, maps, rest = fa._bd_kinds(256, 128, 32, 64, 64, 16, 0.2)
+        assert len(maps) == 3 and fa.MAX_KINDS >= 3
+
+    @staticmethod
+    def _run(attend, q, k, v, ct, cl):
+        def loss(q, k, v):
+            out, lse = attend(q, k, v)
+            return jnp.sum(out * ct) + jnp.sum(jnp.sin(lse) * cl), (out, lse)
+        (_, aux), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (*aux, *grads)
+
+    # grouped heads (4 | 2) and d_qk != d_v (32 | 48) in every case; tiles of 64,
+    # sub-blocks of 16: causal; a window that is a multiple of the tile, one that
+    # is not, one under the tile; block diffusion at 4 and 32, both copies'
+    # queries and the noised copy's alone
+    @pytest.mark.parametrize("mask", [
+        ("band", None), ("band", 64), ("band", 100), ("band", 37),
+        ("bd", 4, 256, 256), ("bd", 32, 256, 256), ("bd", 4, 128, 256), ("bd", 32, 128, 256),
+    ], ids=str)
+    def test_the_sub_block_path_matches_the_dense_mask_and_the_whole_tile_path(
+            self, monkeypatch, mask):
+        fa = self._constants(monkeypatch, 16)
+        rng = np.random.default_rng(65)
+        sq, sk = (256, 256) if mask[0] == "band" else mask[2:]
+        q, k, v, ct, cl = (jnp.asarray(rng.normal(size=shape).astype(np.float32)) for shape in (
+            (1, 4, sq, 32), (1, 2, sk, 32), (1, 2, sk, 48), (1, 4, sq, 48), (1, 4, sq)))
+        if mask[0] == "band":
+            kernels = lambda q, k, v: fa.flash_attention_lse(  # noqa: E731
+                q, k, v, causal=True, block_q=64, block_k=64, interpret=True, window=mask[1])
+            dense = lambda q, k, v: fa._dense_reference_lse(  # noqa: E731
+                q, k, v, True, 32 ** -0.5, mask[1])
+            counts = dict(window=mask[1])
+        else:
+            kernels = lambda q, k, v: fa.block_diffusion_attention_lse(  # noqa: E731
+                q, k, v, mask[1], block_q=64, block_k=64, interpret=True)
+            dense = lambda q, k, v: fa._dense_block_diffusion_lse(  # noqa: E731
+                q, k, v, mask[1], 32 ** -0.5)
+            counts = dict(block_length=mask[1])
+            assert not fa._bd_kinds(sq, sk // 2, mask[1], 64, 64, 16, 0.2)[2]
+        # every partial tile takes the path: nothing computed but listed sub-blocks
+        computed, kept = fa.computed_entries(sq, sk, 64, 64, **counts)
+        whole, _ = fa.computed_entries(sq, sk, 64, 64, whole_tiles=True, **counts)
+        assert kept <= computed < whole
+        got = self._run(kernels, q, k, v, ct, cl)
+        want = self._run(dense, q, k, v, ct, cl)
+        monkeypatch.setattr(fa, "SUB_BLOCK", 1 << 30)
+        assert fa.computed_entries(sq, sk, 64, 64, **counts)[0] == whole
+        whole_tiles = self._run(kernels, q, k, v, ct, cl)
+        for name, g, w, t in zip(("out", "lse", "dQ", "dK", "dV"), got, want, whole_tiles):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4,
+                                       err_msg=f"{name} against the dense mask")
+            np.testing.assert_allclose(np.asarray(g), np.asarray(t), rtol=1e-5, atol=1e-5,
+                                       err_msg=f"{name} against the whole-tile path")
+
+    @pytest.mark.parametrize("strips", ["qq", "kk", "kq"])
+    def test_either_cut_in_either_direction_is_the_same_attention(self, monkeypatch, strips):
+        fa = self._constants(monkeypatch, 16)
+        monkeypatch.setattr(fa, "STRIPS", {"fwd": strips[0], "bwd": strips[1]})
+        got, want = TestFlashBand()._both(100, 64, 64, 32, 48)
+        for name, g, w in zip(("out", "dQ", "dK", "dV"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4,
+                                       err_msg=name)
+
+    # a geometry that does not line up: bq != bk; a tile of one sub-block a
+    # side; a tile that is no whole sub-blocks — under sub-blocks of 16 | 64 | 48 |
+    # 12 the text is the one a side no tile can hold gives
+    @pytest.mark.parametrize("mask,bq,bk,side", [
+        (("band", None), 64, 32, 16), (("band", 40), 64, 32, 16), (("band", 40), 64, 64, 64),
+        (("band", None), 64, 64, 48), (("bd", 4), 16, 16, 16), (("bd", 4), 32, 16, 12),
+    ], ids=str)
+    def test_a_geometry_that_does_not_line_up_lowers_as_before(self, monkeypatch, mask, bq, bk,
+                                                                side):
+        fa = self._fa()
+        q, k = jnp.ones((1, 2, 128, 32), jnp.float32), jnp.ones((1, 1, 128, 32), jnp.float32)
+
+        def loss(q, k, v):
+            if mask[0] == "bd":
+                return jnp.sum(fa.block_diffusion_attention(
+                    q, k, v, mask[1], block_q=bq, block_k=bk, interpret=True))
+            return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                                              interpret=True, window=mask[1]))
+
+        def text(sub):
+            monkeypatch.setattr(fa, "SUB_BLOCK", sub)
+            monkeypatch.setattr(fa, "MIN_SPARED", 0.01)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).as_text()
+
+        assert text(side) == text(1 << 30)
+
+    # the issue's table: tile-equivalents a head computes, of those whole tiles
+    # would, at 1024 x 1024 tiles, sub-blocks of 256 and every 10-of-16 kind in
+    @pytest.mark.parametrize("sq,sk,mask,computed,whole", [
+        (16384, 16384, dict(block_length=4), 68, 80), (8192, 16384, dict(block_length=4), 35, 44),
+        (16384, 16384, dict(window=4096), 59.5, 70), (16384, 16384, dict(window=2048), 33.75, 45),
+        (16384, 16384, dict(window=512), 11.8125, 31), (8192, 8192, {}, 33, 36),
+        (16384, 16384, {}, 130, 136),
+    ], ids=str)
+    def test_computed_entries_counts_the_listed_sub_blocks(self, monkeypatch, sq, sk, mask,
+                                                           computed, whole):
+        fa = self._constants(monkeypatch, 256, 0.3)
+        got, kept = fa.computed_entries(sq, sk, 1024, 1024, **mask)
+        assert got == computed * 1024 ** 2
+        assert fa.computed_entries(sq, sk, 1024, 1024, whole_tiles=True, **mask) == (
+            whole * 1024 ** 2, kept)
+        rows, cols = np.ogrid[:sq // 16, :sk // 16]  # the same mask at a sixteenth the rows
+        if "block_length" in mask:
+            small = fa.computed_entries(sq // 16, sk // 16, 64, 64, block_length=4)[1]
+            assert small == int(fa.block_diffusion_visible(rows, cols, sk // 32, 4).sum())
+            half = sk // 2
+            assert kept == half * 4 + 16 * (half // 4) * (half // 4 - 1) // 2 + (
+                16 * (half // 4) * (half // 4 + 1) // 2 if sq == sk else 0)
+        else:
+            window = mask.get("window")
+            small = fa.computed_entries(sq // 16, sk // 16, 64, 64,
+                                        window=window and window // 16)[1]
+            assert small == int(fa._band_visible(rows, cols, window and window // 16).sum())
+
+    def test_the_committed_constants_spare_sdar_what_the_sweep_found(self):
+        """At the constants the module commits (one on-chip sweep, recorded in
+        flash_blocks.json's source texts) SDAR's call computes 68 of the 80
+        tiles' entries it computed whole."""
+        fa = self._fa()
+        assert (fa.SUB_BLOCK, fa.STRIPS) == (256, {"fwd": "q", "bwd": "k"})
+        computed, kept = fa.computed_entries(16384, 16384, 1024, 1024, block_length=4)
+        assert computed == 68 * 1024 ** 2 and kept / computed > 0.94
 
 
 class TestHeadNormRope:

@@ -51,6 +51,27 @@ operand and result once) over them.  JoyAI-LLM-Flash's mixer:
 
     python tools/flash_tune.py --mla-heads --seqs 8192 --bh 2,32 --blocks 256,512,1024
 
+Every line of every mode prints the entries a head's call computes | the
+entries its mask keeps and their ratio (``ops/flash_attention.computed_entries``:
+a tile the mask crosses is walked by sub-blocks, so fewer than whole tiles'),
+and which of out, dQ, dK, dV are bit for bit the whole-tile path's (``=``) or
+equal to rounding (``~``).  ``--sub-blocks 0,128,256,512`` times each line
+also at those sub-block sides (0: whole tiles), ``--min-spared`` at those least
+shares a kind of tile must spare, ``--strips qk,qq,kk`` at those cuts (forward
+then backward: strips of query rows or of keys) — beside the module's
+committed constants, the only variant a winner is chosen at; ``--check`` |
+``--check-rows`` hold every variant to the dense mask first.  The sweep behind
+``SUB_BLOCK``, ``MIN_SPARED`` and ``STRIPS`` (PR 65; its numbers are in
+flash_blocks.json's source texts), a line a shape:
+
+    python tools/flash_tune.py --no-dense --no-write --blocks 1024 --check \
+        --sub-blocks 0,128,256,512 --min-spared 0.2 --strips qk \
+        --seqs 16384 --bh 2,28 --kv-heads 4 --dh 128 --window 4096
+    ... --seqs 16384 --bh 1,32 --dh 128 --window 2048
+    ... --seqs 8192 --bh 2,32 --dh 192 --dv 128          (and --bh 1,16 --dh 128)
+    ... --block-diffusion 4 --seqs 16384 --bh 1,32 --kv-heads 4 --dh 128 \
+        --check-rows 3072 --min-spared 0.3,0.45
+
 ``--block-diffusion B`` sweeps the BLOCK-DIFFUSION kernels (``flash_fwd_bd`` |
 ``flash_bwd_bd``: ``--seqs`` are KEY rows, 2L — a noised and a clean copy of L
 tokens under ``ops/flash_attention.block_diffusion_visible`` at block length
@@ -67,6 +88,7 @@ small).  Winners go to the artifact's third table, ``block_diffusion``, keyed
 """
 
 import argparse
+import contextlib
 import os
 import sys
 
@@ -75,6 +97,53 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import numpy as np
 
 from tools.timing import timed  # noqa: E402
+
+
+def sub_block_variants(args, fa) -> list:
+    """The (label, sub-block side, least share spared, strips) a line is timed
+    at: the module's committed constants first — the only variant a winner is
+    chosen at —, then ``--sub-blocks`` x ``--min-spared`` x ``--strips``.  Side 0
+    is the whole-tile path (no tile lines up with a side it cannot hold)."""
+    committed = (fa.SUB_BLOCK, fa.MIN_SPARED, "".join(fa.STRIPS[d] for d in ("fwd", "bwd")))
+    found = [("committed",) + committed]
+    for side in (int(x) for x in args.sub_blocks.split(",") if x):
+        for least in [float(x) for x in args.min_spared.split(",") if x] or (committed[1],):
+            for strips in [x for x in args.strips.split(",") if x] or (committed[2],):
+                label = f"side {side} spared>={least} strips {strips}" if side else "whole tiles"
+                if (side, least, strips) != committed and label not in [f[0] for f in found]:
+                    found.append((label, side, least, strips))
+    return found
+
+
+@contextlib.contextmanager
+def applied(fa, variant):
+    """``fa``'s three constants at a variant's, for the calls traced inside."""
+    _, side, least, strips = variant
+    was = fa.SUB_BLOCK, fa.MIN_SPARED, dict(fa.STRIPS)
+    fa.SUB_BLOCK, fa.MIN_SPARED = side or 1 << 30, least
+    fa.STRIPS.update(fwd=strips[0], bwd=strips[1])
+    try:
+        yield
+    finally:
+        fa.SUB_BLOCK, fa.MIN_SPARED = was[:2]
+        fa.STRIPS.update(was[2])
+
+
+def entries_note(fa, sq, sk, bq, bk, **mask) -> str:
+    """"computed | kept entries and their ratio" of a call at the constants in
+    force, beside the whole-tile count (``fa.computed_entries``)."""
+    computed, kept = fa.computed_entries(sq, sk, bq, bk, **mask)
+    whole, _ = fa.computed_entries(sq, sk, bq, bk, whole_tiles=True, **mask)
+    return (f"entries a head: computed {computed} | kept {kept} = {kept / computed:.4f} kept "
+            f"(whole tiles computed {whole}: {computed / whole:.4f} of them)")
+
+
+def same_bits(got, want) -> str:
+    """Which of a call's results are bit for bit another path's."""
+    import jax.numpy as jnp
+
+    return " ".join(f"{name} {'=' if bool(jnp.all(g == w)) else '~'}"
+                    for name, g, w in zip(("out", "dQ", "dK", "dV"), got, want))
 
 
 def time_mla_heads(args) -> int:
@@ -164,6 +233,15 @@ def tune_block_diffusion(args) -> int:
         f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
         return timed(f, *xs, steps=args.steps)
 
+    def results(fn, *xs):
+        """(out, dQ, dK, dV) of the sweep's loss."""
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        (_, out), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(*xs)
+        return (out, *grads)
+
+    variants = sub_block_variants(args, fa)
     if args.check_rows:
         sk = args.check_rows
         for sq in (sk, sk // 2):
@@ -184,20 +262,22 @@ def tune_block_diffusion(args) -> int:
                     lambda q, k, v: weighed(dense, q, k, v), argnums=(0, 1, 2),
                     has_aux=True))(q, k, v)
             want = (*aux, *grads)
-            for bq in blocks:
-                for bk in blocks:
-                    if sq % bq or sk % bk:
-                        continue
+            for bq, bk, variant in ((bq, bk, x) for bq in blocks for bk in blocks
+                                    for x in variants):
+                if sq % bq or sk % bk:
+                    continue
+                with applied(fa, variant):
                     (_, aux), grads = jax.jit(jax.value_and_grad(
                         lambda q, k, v, f=kernels(bq, bk): weighed(f, q, k, v),
                         argnums=(0, 1, 2), has_aux=True))(q, k, v)
-                    off = [float(jnp.linalg.norm(a.astype(jnp.float32) - w)
-                                 / jnp.linalg.norm(w)) for a, w in zip((*aux, *grads), want)]
-                    print(f"{sq} queries, {sk} keys, blocks of {blk}, bq={bq} bk={bk}: out, lse, "
-                          f"dQ, dK, dV off the dense mask by "
-                          f"{' '.join(f'{x:.2e}' for x in off)} (relative L2)", flush=True)
-                    if not max(off) < 2e-2:  # bf16 operands against f32
-                        raise SystemExit("the block-diffusion kernels disagree with the dense mask")
+                    note = entries_note(fa, sq, sk, bq, bk, block_length=blk)
+                off = [float(jnp.linalg.norm(a.astype(jnp.float32) - w)
+                             / jnp.linalg.norm(w)) for a, w in zip((*aux, *grads), want)]
+                print(f"{sq} queries, {sk} keys, blocks of {blk}, bq={bq} bk={bk} "
+                      f"[{variant[0]}]: out, lse, dQ, dK, dV off the dense mask by "
+                      f"{' '.join(f'{x:.2e}' for x in off)} (relative L2); {note}", flush=True)
+                if not max(off) < 2e-2:  # bf16 operands against f32
+                    raise SystemExit("the block-diffusion kernels disagree with the dense mask")
 
     winners = {}
     for sk in (int(x) for x in args.seqs.split(",")):
@@ -214,19 +294,28 @@ def tune_block_diffusion(args) -> int:
                 if (sk // 2) % bq or sk % bk:
                     continue
                 fn = lambda q, k, v, f=kernels(bq, bk): f(q, k, v)[0]  # noqa: E731
-                try:
-                    ms, fwd_ms = time_fn(fn, q, k, v), time_fn(fn, q, k, v, grad=False)
-                    last = time_fn(fn, q[:, :, :sk // 2], k, v)
-                except Exception as e:  # noqa: BLE001
-                    print(f"{sk} key rows bq={bq} bk={bk}: {type(e).__name__}: {e}"[:300])
-                    continue
+                with applied(fa, ("whole tiles", 0, 1.0, "qk")):
+                    whole = results(fn, q, k, v)
                 tiles = fa._bd_tiles(sk, sk // 2, blk, bq, bk)
-                tag = ""
-                if best is None or ms < best[0]:
-                    best, tag = (ms, bq, bk, fwd_ms, last), " *"
-                print(f"{sk} key rows bq={bq} bk={bk}: {ms:8.2f} ms (forward alone {fwd_ms:6.2f}; "
-                      f"the noised copy's queries alone {last:8.2f}); {tiles['pairs']} tile pairs, "
-                      f"grid steps {tiles['steps_f']} | {tiles['steps_b']}{tag}", flush=True)
+                for variant in variants:
+                    with applied(fa, variant):
+                        try:
+                            bits = same_bits(results(fn, q, k, v), whole)
+                            ms, fwd_ms = time_fn(fn, q, k, v), time_fn(fn, q, k, v, grad=False)
+                            last = time_fn(fn, q[:, :, :sk // 2], k, v)
+                        except Exception as e:  # noqa: BLE001
+                            print(f"{sk} key rows bq={bq} bk={bk} {variant[0]}: "
+                                  f"{type(e).__name__}: {e}"[:300])
+                            continue
+                        note = entries_note(fa, sk, sk, bq, bk, block_length=blk)
+                    tag = ""
+                    if variant is variants[0] and (best is None or ms < best[0]):
+                        best, tag = (ms, bq, bk, fwd_ms, last), " *"
+                    print(f"{sk} key rows bq={bq} bk={bk} [{variant[0]}]: {ms:8.2f} ms (forward "
+                          f"alone {fwd_ms:6.2f}; the noised copy's queries alone {last:8.2f}); "
+                          f"{tiles['pairs']} tile pairs, grid steps {tiles['steps_f']} | "
+                          f"{tiles['steps_b']}; {note}; against whole tiles {bits}{tag}",
+                          flush=True)
         if best is not None:
             winners[f"{sk},{blk}"] = {"blocks": [best[1], best[2]], "flash_ms": round(best[0], 3),
                                       "fwd_ms": round(best[3], 3),
@@ -267,7 +356,7 @@ def main() -> int:
     ap.add_argument("--window", type=int, default=0,
                     help="sweep the banded kernels at this window (0: the full causal ones)")
     ap.add_argument("--check", action="store_true",
-                    help="with --window: hold every block pair to the dense mask first")
+                    help="hold every block pair (at every variant) to the dense mask first")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU pre-flight of the control flow: kernels interpreted, nothing "
                          "written, never a timing")
@@ -280,6 +369,13 @@ def main() -> int:
     ap.add_argument("--block-diffusion", type=int, default=0,
                     help="sweep the block-diffusion kernels at this block length (--seqs are "
                          "key rows, 2L) and nothing else")
+    ap.add_argument("--sub-blocks", default="",
+                    help="also time every line at these sub-block sides (0: whole tiles), "
+                         "beside the module's committed constants")
+    ap.add_argument("--min-spared", default="",
+                    help="with --sub-blocks: at these least shares a kind must spare")
+    ap.add_argument("--strips", default="",
+                    help="with --sub-blocks: at these cuts, forward then backward (qk, qq, kk, kq)")
     ap.add_argument("--check-rows", type=int, default=0,
                     help="with --block-diffusion: first hold every tile pair to the dense mask "
                          "at this many key rows")
@@ -298,8 +394,14 @@ def main() -> int:
     if args.block_diffusion:
         return tune_block_diffusion(args)
 
+    import importlib
+
     from byteps_tpu.ops.flash_attention import flash_attention, _dense_reference
 
+    # ops/__init__ re-exports the flash_attention FUNCTION, which shadows the
+    # submodule in from-import; resolve the module itself
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    variants = sub_block_variants(args, fa)
     b, h = (int(x) for x in args.bh.split(","))
     dh, dv = args.dh, args.dv or args.dh
     h_kv = args.kv_heads or h
@@ -313,6 +415,7 @@ def main() -> int:
         with it (the keys padded by the window at the front: their positions
         are negative and nobody sees them)."""
         s = q.shape[2]
+        window = args.window or s  # no window: causal, a band as wide as the sequence
         span, block = min(window, s), min(block, s)
         q, k, v, ct = (x.astype(jnp.float32) for x in (q, k, v, ct))
         pad = ((0, 0), (0, 0), (span, 0), (0, 0))
@@ -350,6 +453,15 @@ def main() -> int:
         f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)) if grad else loss)
         return timed(f, *xs, steps=args.steps)
 
+    def results(fn, q, k, v):
+        """(out, dQ, dK, dV) of sum(out * ct), ``--check``'s loss."""
+        def weighed(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighed, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (out, *grads)
+
     def repeated(fn):
         """``fn`` on key/value heads the caller repeats for their groups."""
         return lambda q, k, v: fn(q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1))
@@ -378,8 +490,8 @@ def main() -> int:
             dense_ms = None
             print(f"seq {s}: dense failed ({type(e).__name__})")
         best = None
-        if window and args.check:
-            ct = jnp.asarray(rng.normal(size=q.shape[:3] + (dv,)).astype(np.float32), jnp.bfloat16)
+        ct = jnp.asarray(rng.normal(size=q.shape[:3] + (dv,)).astype(np.float32), jnp.bfloat16)
+        if args.check:
             out, dq, *dkv = dense_banded_grads(
                 q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1), ct)
             # a key/value head's gradient is the sum over its group of queries
@@ -390,38 +502,41 @@ def main() -> int:
             ms, fwd_ms = time_fn(causal, q, k, v), time_fn(causal, q, k, v, grad=False)
             print(f"seq {s} full causal kernels at the plain entry: {ms:8.2f} ms "
                   f"(forward alone {fwd_ms:6.2f}){beside_repeated(causal, q, k, v)}")
-        for bq in blocks:
-            for bk in blocks:
-                if s % bq or s % bk:
-                    continue
-                flash = lambda q, k, v, bq=bq, bk=bk: flash_attention(  # noqa: E731
-                    q, k, v, causal=True, block_q=bq, block_k=bk, window=window,
-                    interpret=args.rehearse)
-                if window and args.check:
-                    def weighed(q, k, v, ct, flash=flash):
-                        out = flash(q, k, v)
-                        return jnp.sum(out.astype(jnp.float32) * ct), out
-
-                    (_, out), got = jax.jit(jax.value_and_grad(
-                        weighed, argnums=(0, 1, 2), has_aux=True))(q, k, v, ct)
+        for bq, bk in ((bq, bk) for bq in blocks for bk in blocks):
+            if s % bq or s % bk:
+                continue
+            flash = lambda q, k, v, bq=bq, bk=bk: flash_attention(  # noqa: E731
+                q, k, v, causal=True, block_q=bq, block_k=bk, window=window,
+                interpret=args.rehearse)
+            with applied(fa, ("whole tiles", 0, 1.0, "qk")):
+                whole = results(flash, q, k, v)
+            for variant in variants:
+                with applied(fa, variant):
+                    note = entries_note(fa, s, s, bq, bk, window=window)
+                    try:
+                        got = results(flash, q, k, v)
+                        ms = time_fn(flash, q, k, v)
+                        fwd_ms = time_fn(flash, q, k, v, grad=False)
+                        again = beside_repeated(flash, q, k, v) if variant is variants[0] else ""
+                    except Exception as e:  # noqa: BLE001
+                        print(f"seq {s} flash bq={bq} bk={bk} [{variant[0]}]: "
+                              f"{type(e).__name__}: {e}"[:300])
+                        continue
+                if args.check:
                     off = [float(jnp.linalg.norm(a.astype(jnp.float32) - w) / jnp.linalg.norm(w))
-                           for a, w in zip((out,) + got, want)]
-                    print(f"seq {s} window {window} bq={bq} bk={bk}: out, dQ, dK, dV off the "
-                          f"dense mask by {' '.join(f'{x:.2e}' for x in off)} (relative L2)")
+                           for a, w in zip(got, want)]
+                    print(f"seq {s} window {window} bq={bq} bk={bk} [{variant[0]}]: out, dQ, dK, "
+                          f"dV off the dense mask by {' '.join(f'{x:.2e}' for x in off)} "
+                          "(relative L2)")
                     if not max(off) < 2e-2:  # bf16 operands against f32
-                        raise SystemExit("the banded kernels disagree with the dense mask")
-                try:
-                    ms = time_fn(flash, q, k, v)
-                    fwd_ms = time_fn(flash, q, k, v, grad=False)
-                except Exception as e:  # noqa: BLE001
-                    print(f"seq {s} flash bq={bq} bk={bk}: {type(e).__name__}")
-                    continue
+                        raise SystemExit("the kernels disagree with the dense mask")
                 tag = ""
-                if best is None or ms < best[0]:
+                if variant is variants[0] and (best is None or ms < best[0]):
                     best = (ms, bq, bk, fwd_ms)
                     tag = " *"
-                print(f"seq {s} flash bq={bq} bk={bk}: {ms:8.2f} ms "
-                      f"(forward alone {fwd_ms:6.2f}){tag}{beside_repeated(flash, q, k, v)}")
+                print(f"seq {s} flash bq={bq} bk={bk} [{variant[0]}]: {ms:8.2f} ms "
+                      f"(forward alone {fwd_ms:6.2f}){tag}; {note}; against whole tiles "
+                      f"{same_bits(got, whole)}{again}", flush=True)
         if dense_ms is not None:
             print(f"seq {s} dense:               {dense_ms:8.2f} ms")
         if best is not None:
@@ -441,13 +556,9 @@ def main() -> int:
         # persist so the kernels' tuned_blocks() table picks the winners
         # up on the next run (then `python benchmark/run.py --workload
         # joyai_flash_ep32_train8k`, the cell that runs these kernels)
-        import importlib
         import json
 
-        # ops/__init__ re-exports the flash_attention FUNCTION, which
-        # shadows the submodule in from-import; resolve the module itself
-        _fa_mod = importlib.import_module("byteps_tpu.ops.flash_attention")
-        path = args.out or _fa_mod._TUNED_PATH  # producer/consumer share one location
+        path = args.out or fa._TUNED_PATH  # producer/consumer share one location
         try:
             with open(path) as f:
                 doc = json.load(f)
