@@ -32,9 +32,12 @@ import pytest  # noqa: E402
 # --- fail-fast guard for native-lane tests -------------------------------
 #
 # History: a native-server lifecycle bug once parked teardown forever
-# (AF_UNIX accept() ignores listener shutdown), and the 870s tier-1 budget
-# burned idle from the first native test onward — every test sorting after
-# it was simply never counted.  The bug is fixed, but a REGRESSION must
+# (AF_UNIX accept() ignores listener shutdown), and tier-1's budget — 870 s
+# then; since PR 60 1470 s for the driver's command, six xdist workers with
+# --dist loadfile and -m 'not slow' (/root/TESTS_LAST_RUN.json `commands`;
+# ROADMAP.md's serial "Tier-1 verify" line is one process) — burned idle
+# from the first native test onward: every test sorting after it was simply
+# never counted.  The bug is fixed, but a REGRESSION must
 # fail fast, not eat the rest of the suite.  Two layers, because the hang
 # classes differ:
 #
@@ -47,7 +50,7 @@ import pytest  # noqa: E402
 #   original bug was — never re-enters the eval loop, so the SIGALRM
 #   handler can never run.  faulthandler's C watchdog thread needs no
 #   interpreter: it dumps every thread's stack and _exit()s, killing the
-#   run loudly with diagnostics instead of idling out the tier-1 budget.
+#   run loudly with diagnostics instead of idling out that budget.
 
 _NATIVE_GUARD_S = int(os.environ.get("BYTEPS_NATIVE_TEST_TIMEOUT_S", "60"))
 

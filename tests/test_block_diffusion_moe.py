@@ -7,8 +7,6 @@ noisy block's logits may and may not depend on; the eight shares of the
 experts adding up to the uncut layer; what the step counts.
 """
 
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,17 +20,15 @@ from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel import moe
 
-from test_latent_moe import _mesh, _worst  # noqa: F401 (re-exported)
+import family_cases as fc
+from family_cases import _mesh
 
 
 def _state(cfg, seed=0, batch=4):
-    """Parameters with the norms' scales off their starting value, clean
-    tokens that are never the mask token, their noised copy and its weights."""
-    params = bd.init_params(cfg, jax.random.PRNGKey(seed))
-    for i, name in enumerate(params):
-        if "norm" in name:
-            params[name] = params[name] + 0.1 * jax.random.normal(
-                jax.random.PRNGKey(seed + 100 + i), params[name].shape)
+    """Parameters with the norms' scales off their starting value, the noised
+    copy of clean tokens that are never the mask token, the clean tokens and
+    the rows' weights."""
+    params, _, _ = fc._state(bd, cfg, seed, batch)
     mask_id = cfg.vocab_size - 1
     clean = jax.random.randint(jax.random.PRNGKey(seed + 1), (batch, cfg.max_seq), 0, mask_id)
     noisy, weights = block_diffusion_noise(jax.random.PRNGKey(seed + 2), clean,
@@ -40,60 +36,24 @@ def _state(cfg, seed=0, batch=4):
     return params, noisy, clean, weights
 
 
-def _system_loss_and_grads(cfg, params, noisy, clean, weights, dp=1):
-    """Through build_train_step itself, the gradient kept as the "optimizer's"
-    state."""
-    keep = optax.GradientTransformation(
-        lambda p: jax.tree.map(jnp.zeros_like, p),
-        lambda g, state, p=None: (jax.tree.map(jnp.zeros_like, g), g))
-    step = tfm.build_train_step(cfg, _mesh(dp), keep, donate=False)
-    _, grads, loss = step(params, keep.init(params), noisy, clean, weights)
-    return float(loss), {k: np.asarray(v) for k, v in jax.device_get(grads).items()}
+FAMILY = fc.Family(
+    name="block_diffusion_moe", model=bd, ref=ref, tiny=bd.tiny_block_diffusion_moe, state=_state,
+    variants={
+        "three_layers_blocks_of_four": dict(),
+        "blocks_of_two": dict(block_length=2),
+        "one_block_is_the_sequence": dict(block_length=16),
+        "one_head_a_key_value_head": dict(n_kv_heads=4),
+        "one_layer_is_a_last_layer": dict(n_layers=1),
+        "held_share_of_experts": dict(experts_held=2, expert_lo=4),
+        "no_remat": dict(remat=False),
+    },
+    learns=lambda cfg, name: True,  # every leaf learns
+    dp2=("three_layers_blocks_of_four", 1e-5),
+)
+globals().update(fc.family_cases(FAMILY))
 
 
-#: name → config overrides
-VARIANTS = {
-    "three_layers_blocks_of_four": dict(),
-    "blocks_of_two": dict(block_length=2),
-    "one_block_is_the_sequence": dict(block_length=16),
-    "one_head_a_key_value_head": dict(n_kv_heads=4),
-    "one_layer_is_a_last_layer": dict(n_layers=1),
-    "held_share_of_experts": dict(experts_held=2, expert_lo=4),
-    "no_remat": dict(remat=False),
-}
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    """``tiny(variant)`` → that variant's config and state, with the system's
-    and the reference's loss and gradients made once and shared by the cases."""
-    made = {}
-
-    def of(variant):
-        if variant not in made:
-            cfg = bd.tiny_block_diffusion_moe(**VARIANTS[variant])
-            state = _state(cfg)
-            runs = {}
-
-            def system(dp=1):
-                if dp not in runs:
-                    runs[dp] = _system_loss_and_grads(cfg, *state, dp)
-                return runs[dp]
-
-            def reference():
-                if "ref" not in runs:
-                    runs["ref"] = jax.jit(jax.value_and_grad(
-                        lambda p: ref.loss(cfg, p, *state[1:])))(state[0])
-                return runs["ref"]
-
-            made[variant] = types.SimpleNamespace(cfg=cfg, state=state, system=system,
-                                                  reference=reference)
-        return made[variant]
-
-    return of
-
-
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
+@pytest.mark.parametrize("variant", FAMILY.params_of(sorted(FAMILY.variants)))
 def test_the_noisy_halfs_logits_match_reference(tiny, variant):
     t = tiny(variant)
     params, noisy, clean, _ = t.state
@@ -103,29 +63,6 @@ def test_the_noisy_halfs_logits_match_reference(tiny, variant):
     np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.abs(want).max()))
 
 
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_loss_and_every_leaf_gradient_match_reference(tiny, variant):
-    """f32: what is left is the order of sums (the blocked loss, the grouped
-    products), a few 1e-6 of a leaf's gradient."""
-    t = tiny(variant)
-    loss, grads = t.system()
-    want_loss, want = t.reference()
-    assert loss == pytest.approx(float(want_loss), rel=1e-5)
-    assert set(grads) == set(want) == set(bd.layouts(t.cfg))
-    assert all(np.any(g) for g in grads.values())  # every leaf learns
-    off, leaf = _worst(grads, want)
-    assert off < 2e-4, f"{leaf} is {off:.2e} of its gradient off the reference's"
-
-
-def test_data_parallel_ranks_give_the_same_loss_and_gradients(tiny):
-    t = tiny("three_layers_blocks_of_four")
-    loss, grads = t.system()
-    loss2, grads2 = t.system(dp=2)
-    assert loss2 == pytest.approx(loss, rel=1e-6)
-    off, leaf = _worst(grads2, grads)
-    assert off < 1e-5, leaf
-
-
 @pytest.mark.parametrize("variant", ["three_layers_blocks_of_four", "blocks_of_two",
                                      "one_block_is_the_sequence", "held_share_of_experts"])
 def test_the_one_pass_form_is_the_block_by_block_definition(tiny, variant):
@@ -133,12 +70,12 @@ def test_the_one_pass_form_is_the_block_by_block_definition(tiny, variant):
     the block-causal mask are the one pass's — and so is the loss."""
     t = tiny(variant)
     params, noisy, clean, weights = t.state
-    one = ref.one_pass_logits(t.cfg, params, noisy, clean)
-    by_block = ref.block_by_block_logits(t.cfg, params, noisy, clean)
+    one = jax.jit(lambda *a: ref.one_pass_logits(t.cfg, *a))(params, noisy, clean)
+    by_block, loss = jax.jit(lambda p, a, b, w: (  # one program for the blocks' runs
+        ref.block_by_block_logits(t.cfg, p, a, b),
+        ref.loss(t.cfg, p, a, b, w, ref.block_by_block_logits)))(params, noisy, clean, weights)
     np.testing.assert_allclose(one, by_block, atol=2e-5 * float(jnp.abs(one).max()))
-    assert float(ref.loss(t.cfg, params, noisy, clean, weights)) == pytest.approx(
-        float(ref.loss(t.cfg, params, noisy, clean, weights, ref.block_by_block_logits)),
-        rel=1e-5)
+    assert float(t.reference()[0]) == pytest.approx(float(loss), rel=1e-5)
 
 
 def _moved(cfg, params, noisy, clean, noisy2, clean2):
@@ -216,8 +153,8 @@ def test_the_step_takes_the_batchs_third_leaf_and_counts_what_it_saw():
     # two layers over both copies and the last over the noisy half: 5 x L rows a sequence
     assert grown["moe_slots_routed"] == grown["moe_slots_held"] == 2 * 2 * 5 * 16 * cfg.top_k
     assert grown["moe_slots_dropped"] == 0
-    assert float(loss) == pytest.approx(float(ref.loss(cfg, params, noisy, clean, weights)),
-                                        rel=1e-5)
+    assert float(loss) == pytest.approx(
+        float(jax.jit(lambda *a: ref.loss(cfg, *a))(params, noisy, clean, weights)), rel=1e-5)
     with pytest.raises(TypeError, match=r"\('tokens', 'targets', 'weights'\): 2 leaves given"):
         step(params, tx.init(params), noisy, clean)
 
@@ -230,7 +167,7 @@ def test_unit_weights_on_every_row_are_the_mean_cross_entropy():
     tx = optax.sgd(0.0)
     loss = tfm.build_train_step(cfg, _mesh(), tx, donate=False)(
         params, tx.init(params), noisy, clean, jnp.ones(noisy.shape, jnp.float32))[2]
-    logits = ref.one_pass_logits(cfg, params, noisy, clean)
+    logits = jax.jit(lambda *a: ref.one_pass_logits(cfg, *a))(params, noisy, clean)
     gold = jnp.take_along_axis(logits, clean[..., None], axis=-1)[..., 0]
     assert float(loss) == pytest.approx(
         float(jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)), rel=1e-5)

@@ -206,11 +206,11 @@ def test_each_copy_counts_its_own_positions():
     params = bd.init_params(cfg, jax.random.PRNGKey(0))
     clean = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 95)
     noisy = clean.at[:, ::3].set(95)
-    got = bd.local_logits(cfg, params, noisy, clean)
+    got = jax.jit(lambda *a: bd.local_logits(cfg, *a))(params, noisy, clean)
     rows = jnp.concatenate([noisy, clean], axis=1)
     seen = ref.visible(16, cfg.block_length)
-    right = ref.forward(cfg, params, rows, seen, jnp.concatenate([jnp.arange(16)] * 2))[:, :16]
-    wrong = ref.forward(cfg, params, rows, seen, jnp.arange(32))[:, :16]
+    turned = jax.jit(lambda positions: ref.forward(cfg, params, rows, seen, positions)[:, :16])
+    right, wrong = turned(jnp.concatenate([jnp.arange(16)] * 2)), turned(jnp.arange(32))
     scale = float(jnp.abs(right).max())
     np.testing.assert_allclose(got, right, atol=1e-4 * scale)
     assert float(jnp.abs(got - wrong).max()) > 1e-2 * scale
@@ -223,7 +223,7 @@ def test_the_last_layer_runs_the_noisy_half_alone():
     params = bd.init_params(cfg, jax.random.PRNGKey(0))
     clean = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, 95)
     noisy = clean.at[:, 1::2].set(95)
-    x, stats = bd._hidden(cfg, params, noisy, clean)
+    x, stats = jax.jit(lambda *a: bd._hidden(cfg, *a))(params, noisy, clean)
     assert x.shape == (1, 16, cfg.d_model)
     assert int(stats[0]) == (32 + 16) * cfg.top_k  # routed: one full layer, one half
 
@@ -231,7 +231,7 @@ def test_the_last_layer_runs_the_noisy_half_alone():
         logits = bd.local_logits(cfg, {**params, "attn.wk": wk}, noisy, clean)
         return jnp.sum(logits[:, 8:] ** 2)  # later blocks read earlier clean keys
 
-    grad = jax.grad(through_clean_keys)(params["attn.wk"])
+    grad = jax.jit(jax.grad(through_clean_keys))(params["attn.wk"])
     assert float(jnp.abs(grad[-1]).max()) > 0  # the last layer's k: read, so it learns
     assert set(mf.stack_of(params, "attn")) == {"norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo"}
     assert set(mf.stack_of(params, "moe")) == {"norm", "router", "e_gate", "e_up", "e_down"}
@@ -249,17 +249,24 @@ def _masked_rows_fullest_share(cfg, params, seed=3):
                                      mask_id, 0.45, 0.95)
     rows = jnp.concatenate([noisy, clean], axis=1)
     masked = np.asarray(rows[0] == mask_id)
-    x = params["embed"][rows]
-    attn, moe = mf.stack_of(params, "attn"), mf.stack_of(params, "moe")
-    shares = []
-    for i in range(cfg.n_layers):
-        x = bd._attention_part(cfg, x, {k: v[i] for k, v in attn.items()})
-        lp = {k: v[i] for k, v in moe.items()}
-        ids, _ = softmax_topk_route(mf.rms(x[0], lp["norm"], cfg.norm_eps), lp["router"], cfg.top_k)
-        chosen = np.bincount(np.asarray(ids)[masked].reshape(-1), minlength=cfg.n_experts)
-        shares.append(chosen.max() / chosen.sum())
-        x, _ = bd._moe_part(cfg, x, lp)
-    return shares
+
+    @jax.jit
+    def choices(params):
+        """Every layer's chosen experts, row by row."""
+        x = params["embed"][rows]
+        attn, moe = mf.stack_of(params, "attn"), mf.stack_of(params, "moe")
+        ids = []
+        for i in range(cfg.n_layers):
+            x = bd._attention_part(cfg, x, {k: v[i] for k, v in attn.items()})
+            lp = {k: v[i] for k, v in moe.items()}
+            ids.append(softmax_topk_route(mf.rms(x[0], lp["norm"], cfg.norm_eps), lp["router"],
+                                          cfg.top_k)[0])
+            x, _ = bd._moe_part(cfg, x, lp)
+        return ids
+
+    chosen = [np.bincount(np.asarray(ids)[masked].reshape(-1), minlength=cfg.n_experts)
+              for ids in choices(params)]
+    return [c.max() / c.sum() for c in chosen]
 
 
 @pytest.mark.parametrize("start", ["unit_scales_collapse", "the_familys_start_spreads"])

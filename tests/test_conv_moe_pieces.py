@@ -10,9 +10,7 @@ reference: tests/test_conv_moe.py.  Two files so that ``--dist loadfile``
 spreads them.)
 """
 
-import importlib.util
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +22,9 @@ from byteps_tpu.models import conv_moe_reference as ref
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.parallel import moe
 
-from test_conv_moe import _state, _worst
+import family_cases as fc
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_state = functools.partial(fc._state, cm, bias=0.01)
 
 
 def _layer(cfg, stack, seed=3):
@@ -216,18 +214,8 @@ def test_8_shares_of_8_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, atol=2e-5)
 
 
-def test_no_slot_is_dropped_under_a_skewed_router():
-    """A selection bias that sends every token to the two held experts: sixteen
-    times the even load (the first chunk and every tail chunk run), none
-    dropped, output = reference."""
-    cfg = cm.tiny_conv_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2)
-    lp = _layer(cfg, "moe")
-    g = jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))
-    lp["router_bias"] = jnp.zeros_like(lp["router_bias"]).at[4:6].set(10.0)
-    y, stats = jax.jit(lambda g, lp: cm.expert_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest, walked = (int(v) for v in stats)
-    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
-    np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
+test_no_slot_is_dropped_under_a_skewed_router = fc.skewed_router_case(
+    cm.tiny_conv_moe, lambda cfg: _layer(cfg, "moe"), cm.expert_mlp, ref.expert_mlp)
 
 
 # ---------------------------------------------------------------------------
@@ -235,27 +223,12 @@ def test_no_slot_is_dropped_under_a_skewed_router():
 # ---------------------------------------------------------------------------
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-@pytest.fixture(scope="module")
-def rehearsal():
-    builder = _load("benchmark/builders/lfm2_moe.py", "test_lfm2_moe_builder")
-    with open(os.path.join(ROOT, "benchmark/configs/lfm2_24b_a2b_ep8.json")) as f:
-        cfg = json.load(f)
-    cfg.update(cfg["rehearsal"])
-    # toy widths: the blocking is what is under test, the widths are not; all
-    # five layers of the cut, so that both mixers meet both MLPs
-    cfg.update(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
-               intermediate_size=48, moe_intermediate_size=16, num_experts=4, router_width=16,
-               num_experts_per_tok=3, vocab_size=96, max_seq=64, num_hidden_layers=5)
-    mcfg = builder._model_config(cfg)
-    params, tokens, targets = _state(mcfg, batch=2)
-    return builder, cfg, mcfg, params, (tokens, targets)
+# toy widths: all five layers of the cut, so that both mixers meet both MLPs
+globals().update(fc.builder_cases(
+    "conv_moe", ref, _state, builder="lfm2_moe", config="lfm2_24b_a2b_ep8",
+    toy=dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+             intermediate_size=48, moe_intermediate_size=16, num_experts=4, router_width=16,
+             num_experts_per_tok=3, vocab_size=96, max_seq=64, num_hidden_layers=5)))
 
 
 def test_the_builder_runs_entries_1_to_5_of_the_published_list(rehearsal):
@@ -266,25 +239,3 @@ def test_the_builder_runs_entries_1_to_5_of_the_published_list(rehearsal):
     assert (mcfg.n_experts, mcfg.experts_held, mcfg.expert_lo) == (16, 4, 0)
     with pytest.raises(ValueError, match="conv_bias"):
         builder._model_config({**cfg, "conv_bias": True})
-
-
-def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch):
-    builder, cfg, mcfg, params, batch = rehearsal
-    # blocks smaller than the sequence, so that every loop has several turns
-    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2)):
-        monkeypatch.setattr(builder, name, size)
-    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
-    off, leaf = _worst(grads, want_grads)
-    assert off < 1e-4, f"{leaf}: {off:.2e}"
-
-
-@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
-def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
-    builder, cfg, _, params, batch = rehearsal
-    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(
-        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
-    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
-    assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost

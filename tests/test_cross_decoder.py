@@ -6,109 +6,48 @@ formula in its three forms; the slice test — a whole model equals its slices
 chained with x, the memory and the shared keys and values handed over."""
 
 import dataclasses
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from byteps_tpu.models import cross_decoder as cd
 from byteps_tpu.models import cross_decoder_reference as ref
 from byteps_tpu.models import moe_family as mf
-from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
 
-
-def _mesh(dp=1):
-    return make_training_mesh(dp, {"dp": dp, "pp": 1, "sp": 1, "tp": 1})
+import family_cases as fc
 
 
 def _state(cfg, seed=0, batch=2):
-    """Seeded parameters with every leaf that starts at a constant (biases,
-    norms, D) moved off it, so that each takes part; tokens and next-token
-    targets."""
-    params = cd.init_params(cfg, jax.random.PRNGKey(seed))
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(key, v.shape)
-              for (k, v), key in zip(params.items(), keys)}
-    tokens = jax.random.randint(jax.random.PRNGKey(seed + 2), (batch, cfg.max_seq), 0,
-                                cfg.vocab_size)
-    return params, tokens, jnp.roll(tokens, -1, axis=1)
+    """Every leaf that starts at a constant (biases, norms, D) is moved off
+    it with the rest, so that each takes part."""
+    return fc._state(cd, cfg, seed, batch, moved=lambda name: True)
 
 
-def _system_loss_and_grads(cfg, params, tokens, targets, dp=1):
-    """Through build_train_step itself, the gradient kept as the "optimizer's"
-    state (``params − new`` under sgd loses ``dt_bias``'s gradient in the
-    subtraction's rounding, as tests/test_ssm_moe.py says)."""
-    keep = optax.GradientTransformation(
-        lambda p: jax.tree.map(jnp.zeros_like, p),
-        lambda g, state, p=None: (jax.tree.map(jnp.zeros_like, g), g))
-    step = tfm.build_train_step(cfg, _mesh(dp), keep, donate=False)
-    _, grads, loss = step(params, keep.init(params), tokens, targets)
-    return float(loss), {k: np.asarray(v) for k, v in jax.device_get(grads).items()}
-
-
-#: name → config overrides
-VARIANTS = {
-    "whole_model_of_8": dict(),
-    "the_seam_3_to_7": dict(first_layer=3, held_layers=5),
-    "one_query_pair_a_key_pair": dict(n_heads=4, n_kv_heads=4),
-    "window_covers_the_sequence": dict(window=16),
-    "one_chunk_a_sequence": dict(chunk=16),
-    "chunk_does_not_divide": dict(chunk=5),
-    "no_remat": dict(remat=False),
-}
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    """``tiny(variant)`` → that variant's config and state, the reference's
-    loss and gradients made once."""
-    made = {}
-
-    def of(variant):
-        if variant not in made:
-            cfg = cd.tiny_cross_decoder(**VARIANTS[variant])
-            params, tokens, targets = _state(cfg)
-            want = jax.jit(jax.value_and_grad(
-                lambda p: ref.loss(cfg, p, tokens, targets)))(params)
-            made[variant] = types.SimpleNamespace(cfg=cfg, params=params, tokens=tokens,
-                                                  targets=targets, reference=want)
-        return made[variant]
-
-    return of
-
-
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_logits_match_reference(tiny, variant):
-    t = tiny(variant)
-    got = tfm.build_forward(t.cfg, _mesh())(t.params, t.tokens)[0]
-    want = jax.jit(lambda p, x: ref.forward(t.cfg, p, x))(t.params, t.tokens)
-    assert got.shape == t.tokens.shape + (t.cfg.vocab_size,)
-    np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.abs(want).max()))
-
-
-@pytest.mark.parametrize("variant,dp", [(v, 1) for v in sorted(VARIANTS)]
-                         + [("whole_model_of_8", 2), ("the_seam_3_to_7", 2)])
-def test_loss_and_every_leaf_gradient_match_reference(tiny, variant, dp):
-    """f32: what is left is the order of sums.  A key's bias moves every score
-    of a query alike, so softmax does not see it: its gradient is 0 in the
-    mathematics, rounding in the reference, and 0 in the program, which takes
-    it as that."""
-    t = tiny(variant)
-    loss, grads = _system_loss_and_grads(t.cfg, t.params, t.tokens, t.targets, dp)
-    want_loss, want = t.reference
-    assert loss == pytest.approx(float(want_loss), rel=1e-5)
-    assert set(grads) == set(want)
-    for name, g in grads.items():
-        w = np.asarray(want[name])
-        if name.endswith(".bk"):
-            assert np.abs(w).max() < 1e-5 * np.abs(want[name.replace(".bk", ".wk")]).max()
-            assert not g.any()
-            continue
-        np.testing.assert_allclose(g, w, atol=1e-4 * np.abs(w).max(), err_msg=name)
+FAMILY = fc.Family(
+    name="cross_decoder", model=cd, ref=ref, tiny=cd.tiny_cross_decoder, state=_state, batch=2,
+    variants={
+        "whole_model_of_8": dict(),
+        "the_seam_3_to_7": dict(first_layer=3, held_layers=5),
+        "one_query_pair_a_key_pair": dict(n_heads=4, n_kv_heads=4),
+        "window_covers_the_sequence": dict(window=16),
+        "one_chunk_a_sequence": dict(chunk=16),
+        "chunk_does_not_divide": dict(chunk=5),
+        "no_remat": dict(remat=False),
+    },
+    ref_logits=ref.forward,
+    # entry by entry, as the slice test below reads its leaves
+    grad_off=fc._worst_entry, grad_tol=1e-4,
+    # a key's bias moves every score of a query alike, so softmax does not see
+    # it: its gradient is 0 in the mathematics, rounding in the reference, and
+    # 0 in the program, which takes it as that
+    learns=lambda cfg, name: False if name.endswith(".bk") else None,
+    rounding={".bk": ".wk"},
+    dp2_to_reference=("whole_model_of_8", "the_seam_3_to_7"),
+)
+globals().update(fc.family_cases(FAMILY))
 
 
 def test_the_program_in_bf16_is_near_the_reference():
@@ -116,7 +55,7 @@ def test_the_program_in_bf16_is_near_the_reference():
     points away from the reference's."""
     cfg = cd.tiny_cross_decoder(first_layer=3, held_layers=5, compute_dtype=jnp.bfloat16)
     params, tokens, targets = _state(cfg, batch=1)
-    loss, grads = _system_loss_and_grads(cfg, params, tokens, targets)
+    loss, grads = fc._system_loss_and_grads(cfg, params, tokens, targets)
     want_loss, want = jax.jit(jax.value_and_grad(
         lambda p: ref.loss(cfg, p, tokens, targets)))(params)
     assert loss == pytest.approx(float(want_loss), rel=2e-2)
@@ -255,10 +194,10 @@ def test_differential_attention_is_its_dense_formula(form):
         return _dense_differential(cfg, u, lp, *cd._keys_values(cfg, u, source), window)
 
     weights = jax.random.normal(jax.random.PRNGKey(7), u.shape)
-    got, got_grads = jax.value_and_grad(
-        lambda *a: jnp.sum(weights * program(*a)), argnums=(0, 1, 2))(u, lp, source)
-    want, want_grads = jax.value_and_grad(
-        lambda *a: jnp.sum(weights * dense(*a)), argnums=(0, 1, 2))(u, lp, source)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(weights * program(*a)), argnums=(0, 1, 2)))(u, lp, source)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(weights * dense(*a)), argnums=(0, 1, 2)))(u, lp, source)
     assert got == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(g, w, atol=1e-4 * max(float(jnp.abs(w).max()), 0.1))
@@ -275,8 +214,9 @@ def test_shared_keys_and_values_gradients_sum_over_both_readers():
     kv = cd._keys_values(cfg, u, full)
     own = lambda kv: jnp.sum(cd.differential_attention(cfg, u, full, kv, None) ** 2)  # noqa: E731
     other = lambda kv: jnp.sum(cd.differential_attention(cfg, 2 * u, cross, kv, None) ** 2)  # noqa: E731
-    both = jax.grad(lambda kv: own(kv) + other(kv))(kv)
-    for g, a, b in zip(both, jax.grad(own)(kv), jax.grad(other)(kv)):
+    both, alone = jax.jit(lambda kv: (jax.grad(lambda kv: own(kv) + other(kv))(kv),
+                                      (jax.grad(own)(kv), jax.grad(other)(kv))))(kv)
+    for g, a, b in zip(both, *alone):
         np.testing.assert_allclose(g, a + b, atol=1e-5 * float(jnp.abs(g).max()))
         assert float(jnp.abs(a).max()) > 0 and float(jnp.abs(b).max()) > 0
 
@@ -339,8 +279,15 @@ def test_the_reference_chains_its_slices_too():
     x = jax.random.normal(jax.random.PRNGKey(15), (1, 16, whole.d_model))
     first = dataclasses.replace(whole, first_layer=0, held_layers=6)
     second = dataclasses.replace(whole, first_layer=6, held_layers=2)
-    want = ref.run_layers(whole, params, x)[0]
-    mid, memory, k, v = ref.run_layers(first, _slice_params(whole, params, 0, 6), x)
-    got = ref.run_layers(second, _slice_params(whole, params, 6, 8), mid, memory, k, v)[0]
+
+    @jax.jit
+    def runs(params, x):
+        """(the reference whole, its slices chained, the program whole)"""
+        mid, memory, k, v = ref.run_layers(first, _slice_params(whole, params, 0, 6), x)
+        return (ref.run_layers(whole, params, x)[0],
+                ref.run_layers(second, _slice_params(whole, params, 6, 8), mid, memory, k, v)[0],
+                cd.run_layers(whole, params, x)[0])
+
+    want, got, program = runs(params, x)
     np.testing.assert_allclose(got, want, atol=1e-5)
-    np.testing.assert_allclose(cd.run_layers(whole, params, x)[0], want, atol=1e-4)
+    np.testing.assert_allclose(program, want, atol=1e-4)

@@ -12,9 +12,6 @@ token-by-token recurrence: tests/test_gated_delta.py.  Three files so that
 
 import dataclasses
 import functools
-import importlib.util
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +25,10 @@ from byteps_tpu.ops import causal_conv as cc
 from byteps_tpu.ops import gated_delta as gd
 from byteps_tpu.parallel import moe
 
-from test_delta_moe import _state, _worst
-from test_ops import _kernel_names
+import family_cases as fc
+from family_cases import _kernel_names, _worst
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_state = functools.partial(fc._state, dm)
 
 
 # ---------------------------------------------------------------------------
@@ -278,10 +275,15 @@ def _mlp_params(cfg, seed=3):
     return {k.split(".", 1)[1]: v[0] for k, v in params.items() if k.startswith("full.")}
 
 
-def test_32_shares_of_16_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("held", [pytest.param(16, marks=pytest.mark.slow, id="32_shares_of_16"),
+                                  pytest.param(64, id="8_shares_of_64")])
+def test_the_shares_add_up_to_the_uncut_layer(held):
     """The cell's cut at toy widths: a 512-wide router, top-10, in 32 shares
     of 16 experts.  The shares' routed parts, and the gated shared expert
-    counted once, give what the reference gives with all 512."""
+    counted once, give what the reference gives with all 512.  (A share's
+    ``expert_lo`` is a constant of its walk's loop, so every share compiles
+    its own: tier-1 holds the same sum over 8 shares of 64, whose first and
+    last are held to the reference alike.)"""
     base = dict(n_experts=512, top_k=10, full_attention_interval=1, n_layers=1)
     whole = dm.tiny_delta_moe(experts_held=512, **base)
     lp = _mlp_params(whole)
@@ -289,33 +291,23 @@ def test_32_shares_of_16_add_up_to_the_uncut_layer():
     want = ref.expert_mlp(whole, g, lp)
     shared = jax.nn.sigmoid(g @ lp["shared_gate"])[:, None] * mf.swiglu(
         g, lp["s_gate"], lp["s_up"], lp["s_down"])
-    total, held = shared, 0
-    for lo in range(0, 512, 16):
-        share = dm.tiny_delta_moe(experts_held=16, expert_lo=lo, **base)
-        lp_share = {**lp, **{w: lp[w][lo:lo + 16] for w in ("e_gate", "e_up", "e_down")}}
+    total, slots = shared, 0
+    for lo in range(0, 512, held):
+        share = dm.tiny_delta_moe(experts_held=held, expert_lo=lo, **base)
+        lp_share = {**lp, **{w: lp[w][lo:lo + held] for w in ("e_gate", "e_up", "e_down")}}
         y, stats = dm.expert_mlp(share, g, lp_share)
         total = total + (y - shared)  # this share's routed part alone
-        held += int(stats[1])
+        slots += int(stats[1])
         assert int(stats[2]) == 0
-        if lo in (0, 496):  # and a share is what the reference gives for that share
+        if lo in (0, 512 - held):  # and a share is what the reference gives for that share
             np.testing.assert_allclose(y, ref.expert_mlp(share, g, lp_share), atol=1e-5)
-    assert held == 40 * 10  # every slot is held by exactly one share
+    assert slots == 40 * 10  # every slot is held by exactly one share
     np.testing.assert_allclose(total, want, atol=2e-5)
 
 
-def test_no_slot_is_dropped_under_a_skewed_router():
-    """A router that sends every token to the two held experts: sixteen times
-    the even load (the first chunk and every tail chunk run), none dropped,
-    output = reference."""
-    cfg = dm.tiny_delta_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2,
-                            full_attention_interval=1, n_layers=1)
-    lp = _mlp_params(cfg)
-    g = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))) + 0.1
-    lp["router"] = jnp.zeros_like(lp["router"]).at[:, 4:6].set(1.0)  # positive tokens: 4 and 5 win
-    y, stats = jax.jit(lambda g, lp: dm.expert_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest, walked = (int(v) for v in stats)
-    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
-    np.testing.assert_allclose(y, ref.expert_mlp(cfg, g, lp), atol=1e-5)
+test_no_slot_is_dropped_under_a_skewed_router = fc.skewed_router_case(
+    dm.tiny_delta_moe, _mlp_params, dm.expert_mlp, ref.expert_mlp, by_bias=False,
+    full_attention_interval=1, n_layers=1)
 
 
 # ---------------------------------------------------------------------------
@@ -323,48 +315,11 @@ def test_no_slot_is_dropped_under_a_skewed_router():
 # ---------------------------------------------------------------------------
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-@pytest.fixture(scope="module")
-def rehearsal():
-    builder = _load("benchmark/builders/qwen3_next.py", "test_qwen3_next_builder")
-    with open(os.path.join(ROOT, "benchmark/configs/qwen3_next_80b_ep32.json")) as f:
-        cfg = json.load(f)
-    cfg.update(cfg["rehearsal"])
-    # toy widths: the blocking is what is under test, the widths are not
-    cfg.update(hidden_size=32, head_dim=8, num_attention_heads=4, num_key_value_heads=2,
-               linear_key_head_dim=8, linear_value_head_dim=6, linear_num_key_heads=2,
-               linear_num_value_heads=4, moe_intermediate_size=16,
-               shared_expert_intermediate_size=12, num_experts=4, router_width=16,
-               num_experts_per_tok=3, vocab_size=96, max_seq=64, chunk=16)
-    mcfg = builder._model_config(cfg)
-    params, tokens, targets = _state(mcfg, batch=2)
-    return builder, cfg, mcfg, params, (tokens, targets)
-
-
-def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch):
-    builder, cfg, mcfg, params, batch = rehearsal
-    # blocks smaller than the sequence, so that every loop has several turns
-    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2), ("RUN", 16),
-                       ("HEAD_GROUPS", 2)):
-        monkeypatch.setattr(builder, name, size)
-    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
-    off, leaf = _worst(grads, want_grads)
-    assert off < 1e-4, f"{leaf}: {off:.2e}"
-
-
-@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
-def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
-    builder, cfg, _, params, batch = rehearsal
-    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(
-        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
-    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
-    assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
+globals().update(fc.builder_cases(
+    "delta_moe", ref, _state, builder="qwen3_next", config="qwen3_next_80b_ep32",
+    toy=dict(hidden_size=32, head_dim=8, num_attention_heads=4, num_key_value_heads=2,
+             linear_key_head_dim=8, linear_value_head_dim=6, linear_num_key_heads=2,
+             linear_num_value_heads=4, moe_intermediate_size=16,
+             shared_expert_intermediate_size=12, num_experts=4, router_width=16,
+             num_experts_per_tok=3, vocab_size=96, max_seq=64, chunk=16),
+    blocks=dict(RUN=16, HEAD_GROUPS=2)))

@@ -11,9 +11,6 @@ them.)
 """
 
 import functools
-import importlib.util
-import json
-import os
 import re
 
 import jax
@@ -29,9 +26,11 @@ from byteps_tpu.models import transformer as tfm
 from byteps_tpu.models import window_moe as wm
 from byteps_tpu.parallel import moe
 
-from test_early_route_moe import _mesh, _state, _worst
+import family_cases as fc
+from family_cases import _mesh
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_state = functools.partial(fc._state, em)
+
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
@@ -279,29 +278,15 @@ def test_no_slot_is_dropped_under_a_skewed_router():
 # ---------------------------------------------------------------------------
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-@pytest.fixture(scope="module")
-def rehearsal():
-    builder = _load("benchmark/builders/smallthinker.py", "test_smallthinker_builder")
-    with open(os.path.join(ROOT, "benchmark/configs/smallthinker_21b_ep8.json")) as f:
-        cfg = json.load(f)
-    cfg.update(cfg["rehearsal"])
-    # toy widths: the blocking is what is under test, the widths are not; the
-    # whole period, a group of three, and a window that is no multiple of the
-    # query block
-    cfg.update(hidden_size=32, num_attention_heads=6, num_key_value_heads=2, head_dim=8,
-               moe_ffn_hidden_size=16, moe_num_primary_experts=4, router_width=16,
-               moe_num_active_primary_experts=3, vocab_size=96, max_seq=64,
-               sliding_window_size=11)
-    mcfg = builder._model_config(cfg)
-    params, tokens, targets = _state(mcfg, batch=2)
-    return builder, cfg, mcfg, params, (tokens, targets)
+# toy widths: the whole period, a group of three, and a window that is no
+# multiple of the query block
+globals().update(fc.builder_cases(
+    "early_route_moe", ref, _state, builder="smallthinker", config="smallthinker_21b_ep8",
+    toy=dict(hidden_size=32, num_attention_heads=6, num_key_value_heads=2, head_dim=8,
+             moe_ffn_hidden_size=16, moe_num_primary_experts=4, router_width=16,
+             moe_num_active_primary_experts=3, vocab_size=96, max_seq=64,
+             sliding_window_size=11),
+    windows=("sliding_window_size", {"odd": 11, "two_blocks": 16, "over_the_sequence": 200})))
 
 
 def test_the_builder_runs_the_first_period_of_the_published_lists(rehearsal):
@@ -316,31 +301,6 @@ def test_the_builder_runs_the_first_period_of_the_published_lists(rehearsal):
             builder._model_config({**cfg, key: other})
     with pytest.raises(ValueError, match="rope_layout"):  # rope on a global layer is not built
         builder._model_config({**cfg, "rope_layout": [1] * 52})
-
-
-@pytest.mark.parametrize("window", [11, 16, 200], ids=["odd", "two_blocks", "over_the_sequence"])
-def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch, window):
-    builder, cfg, _, params, batch = rehearsal
-    cfg = {**cfg, "sliding_window_size": window}
-    mcfg = builder._model_config(cfg)
-    # blocks smaller than the sequence, so that every loop has several turns
-    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2)):
-        monkeypatch.setattr(builder, name, size)
-    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
-    off, leaf = _worst(grads, want_grads)
-    assert off < 1e-4, f"{leaf}: {off:.2e}"
-
-
-@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
-def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
-    builder, cfg, _, params, batch = rehearsal
-    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(
-        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
-    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
-    assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ interpreter), and the pass between the mixer's products and that kernel
 its reference: tests/test_latent_moe.py.)
 """
 
+import functools
 import importlib
 import threading
 
@@ -23,7 +24,10 @@ from byteps_tpu.models import transformer as tfm
 from byteps_tpu.ops import mla_heads as mh
 from byteps_tpu.parallel import moe
 
-from test_latent_moe import _mesh, _state, _worst
+import family_cases as fc
+from family_cases import _mesh, _worst
+
+_state = functools.partial(fc._state, lm, bias=0.3)
 
 fa = importlib.import_module("byteps_tpu.ops.flash_attention")
 

@@ -8,7 +8,7 @@ Each program here costs 10–20 s to differentiate at the rehearsal cuts, so
 every loss and gradient is made once a module and shared by the cases.
 """
 
-import importlib.util
+import functools
 import json
 import os
 
@@ -21,16 +21,10 @@ from byteps_tpu.models import latent_moe as lm
 from byteps_tpu.models import latent_moe_reference as ref
 from byteps_tpu.parallel import moe
 
-from test_latent_moe import _state, _worst
+import family_cases as fc
+from family_cases import ROOT, _load, _worst
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+_state = functools.partial(fc._state, lm, bias=0.3)
 
 
 @pytest.fixture(scope="module")
@@ -60,8 +54,8 @@ def plain(rehearsal):
 
     def at(*precision):
         if precision not in made:
-            made[precision] = jax.value_and_grad(
-                builder.plain_loss(cfg, *precision))(params, batch)
+            made[precision] = jax.jit(jax.value_and_grad(
+                builder.plain_loss(cfg, *precision)))(params, batch)
         return made[precision]
 
     return at

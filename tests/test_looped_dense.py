@@ -7,7 +7,6 @@ whole logits; the exit distribution and what the step counts.
 """
 
 import dataclasses
-import types
 
 import jax
 import jax.numpy as jnp
@@ -21,80 +20,39 @@ from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel import moe
 
-from test_latent_moe import _mesh, _worst  # noqa: F401 (re-exported)
+import family_cases as fc
+from family_cases import _mesh
 
 
 def _state(cfg, seed=0, batch=4):
     """Parameters with the norms' scales and the exit gate off their starting
     values (a gate at zero would hide a wrong exit distribution), tokens,
     next-token targets with two ignored."""
-    params = ld.init_params(cfg, jax.random.PRNGKey(seed))
-    for i, name in enumerate(params):
-        if "norm" in name:
-            params[name] = params[name] + 0.1 * jax.random.normal(
-                jax.random.PRNGKey(seed + 100 + i), params[name].shape)
+    params, tokens, targets = fc._state(ld, cfg, seed, batch)
     params["gate_w"] = 0.3 * jax.random.normal(jax.random.PRNGKey(seed + 5), (cfg.d_model,))
     params["gate_b"] = jnp.asarray(0.2, jnp.float32)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(seed + 1), (batch, cfg.max_seq), 0, cfg.vocab_size)
-    return params, tokens, jnp.roll(tokens, -1, axis=1).at[0, 3].set(-1).at[-1, -1].set(-1)
+    return params, tokens, targets.at[0, 3].set(-1).at[-1, -1].set(-1)
 
 
-def _system_loss_and_grads(cfg, params, tokens, targets, dp=1):
-    """Through build_train_step itself, the gradient kept as the "optimizer's"
-    state."""
-    keep = optax.GradientTransformation(
-        lambda p: jax.tree.map(jnp.zeros_like, p),
-        lambda g, state, p=None: (jax.tree.map(jnp.zeros_like, g), g))
-    step = tfm.build_train_step(cfg, _mesh(dp), keep, donate=False)
-    _, grads, loss = step(params, keep.init(params), tokens, targets)
-    return float(loss), {k: np.asarray(v) for k, v in jax.device_get(grads).items()}
+FAMILY = fc.Family(
+    name="looped_dense", model=ld, ref=ref, tiny=ld.tiny_looped_dense, state=_state,
+    variants={
+        "two_layers_three_loops": dict(),
+        "four_loops_as_published": dict(n_loops=4),
+        "one_head_a_key_value_head": dict(n_kv_heads=4),
+        "one_layer_looped": dict(n_layers=1, n_loops=4),
+        "one_loop_is_a_plain_stack": dict(n_loops=1),
+        "no_entropy_term": dict(exit_beta=0.0),
+        "no_remat": dict(remat=False),
+    },
+    # every leaf learns; with one loop step there is no gate to learn
+    learns=lambda cfg, name: cfg.n_loops > 1 or name not in ("gate_w", "gate_b"),
+    dp2=("two_layers_three_loops", 1e-5),
+)
+globals().update(fc.family_cases(FAMILY))
 
 
-#: name → config overrides
-VARIANTS = {
-    "two_layers_three_loops": dict(),
-    "four_loops_as_published": dict(n_loops=4),
-    "one_head_a_key_value_head": dict(n_kv_heads=4),
-    "one_layer_looped": dict(n_layers=1, n_loops=4),
-    "one_loop_is_a_plain_stack": dict(n_loops=1),
-    "no_entropy_term": dict(exit_beta=0.0),
-    "no_remat": dict(remat=False),
-}
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    """``tiny(variant)`` → that variant's config and state, with the system's
-    and the reference's loss and gradients made once and shared by the cases."""
-    made = {}
-
-    def of(variant):
-        if variant not in made:
-            cfg = ld.tiny_looped_dense(**VARIANTS[variant])
-            params, tokens, targets = _state(cfg)
-            runs = {}
-
-            def system(dp=1):
-                if dp not in runs:
-                    runs[dp] = _system_loss_and_grads(cfg, params, tokens, targets, dp)
-                return runs[dp]
-
-            def reference():
-                if "ref" not in runs:
-                    runs["ref"] = jax.jit(jax.value_and_grad(
-                        lambda p: ref.loss(cfg, p, tokens, targets)))(params)
-                return runs["ref"]
-
-            made[variant] = types.SimpleNamespace(
-                cfg=cfg, params=params, tokens=tokens, targets=targets,
-                system=system, reference=reference)
-        return made[variant]
-
-    return of
-
-
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
+@pytest.mark.parametrize("variant", FAMILY.params_of(sorted(FAMILY.variants)))
 def test_every_loop_steps_logits_match_reference(tiny, variant):
     t = tiny(variant)
     got, p = jax.jit(lambda q, x: ld.loop_logits(t.cfg, q, x))(t.params, t.tokens)
@@ -105,31 +63,6 @@ def test_every_loop_steps_logits_match_reference(tiny, variant):
     # build_forward gives the last loop step's: the published threshold of 1
     last = tfm.build_forward(t.cfg, _mesh())(t.params, t.tokens)[0]
     np.testing.assert_allclose(last, want[-1], atol=1e-4 * float(jnp.abs(want).max()))
-
-
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_loss_and_every_leaf_gradient_match_reference(tiny, variant):
-    """f32: what is left is the order of sums (the blocked loss, the gate's
-    logarithms against its products), a few 1e-6 of a leaf's gradient."""
-    t = tiny(variant)
-    loss, grads = t.system()
-    want_loss, want = t.reference()
-    assert loss == pytest.approx(float(want_loss), rel=1e-5)
-    assert set(grads) == set(want) == set(ld.layouts(t.cfg))
-    # every leaf learns; with one loop step there is no gate to learn
-    gate = {"gate_w", "gate_b"}
-    assert all(np.any(g) == (t.cfg.n_loops > 1 or name not in gate) for name, g in grads.items())
-    off, leaf = _worst({k: g for k, g in grads.items() if np.any(g)}, want)
-    assert off < 2e-4, f"{leaf} is {off:.2e} of its gradient off the reference's"
-
-
-def test_data_parallel_ranks_give_the_same_loss_and_gradients(tiny):
-    t = tiny("two_layers_three_loops")
-    loss, grads = t.system()
-    loss2, grads2 = t.system(dp=2)
-    assert loss2 == pytest.approx(loss, rel=1e-6)
-    off, leaf = _worst(grads2, grads)
-    assert off < 1e-5, leaf
 
 
 def test_a_shared_weights_gradient_is_the_sum_over_its_passes(tiny):

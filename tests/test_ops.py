@@ -19,19 +19,7 @@ from byteps_tpu.ops.onebit_device import (
     onebit_payload,
 )
 
-
-def _pallas_calls(jaxpr):
-    """(kernel name, operand shapes) of every ``pallas_call`` of a jaxpr,
-    nested ones too."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"], [x.aval.shape for x in eqn.invars]
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
-
-
-def _kernel_names(fn, *args):
-    return sorted(name for name, _ in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+from family_cases import _kernel_names, _pallas_calls
 
 
 class TestFlashAttention:
@@ -935,6 +923,16 @@ class TestConvSilu:
         return jnp.stack(out, axis=1)
 
     @classmethod
+    @functools.cache
+    def _loop_vjp(cls, with_bias, l2_head):
+        """(the inputs, the loop's y, its pullback) at scale 0.3: the
+        definition is differentiated once and read by every case on it."""
+        wide, taps, bias, ct = cls._inputs(with_bias)
+        args = (wide, taps) + ((bias,) if with_bias else ())
+        y, pull = jax.vjp(lambda w, t, *b: cls._loop(w, t, *(b or (None,)), l2_head, 0.3), *args)
+        return (wide, taps, bias, ct), y, pull
+
+    @classmethod
     def _run(cls, wide, taps, bias, l2_head, scale, **how):
         from byteps_tpu.ops.causal_conv import conv_silu
 
@@ -950,19 +948,14 @@ class TestConvSilu:
         against the loop's autodiff to 2e-6 of each one's largest."""
         from byteps_tpu.ops import causal_conv as cc
 
-        wide, taps, bias, ct = self._inputs(with_bias)
+        (wide, taps, bias, ct), want_y, want_pull = self._loop_vjp(with_bias, l2_head)
         fit = cc._blocks(wide, taps, self.LO, self.HI, l2_head, 0.3, interpret, self.BLOCKS)
         assert (fit.rows, fit.lanes, fit.chunk, fit.halo) == (16, l2_head or 128, 8, 8)
         assert cc._kernel_path(fit, interpret) == interpret
         args = (wide, taps) + ((bias,) if with_bias else ())
-
-        def both(f):
-            y, pull = jax.vjp(lambda w, t, *b: f(w, t, *(b or (None,))), *args)
-            return (y, *pull(ct))
-
-        got = both(lambda w, t, b: self._run(w, t, b, l2_head, 0.3, interpret=interpret,
-                                             blocks=self.BLOCKS))
-        want = both(lambda w, t, b: self._loop(w, t, b, l2_head, 0.3))
+        y, pull = jax.vjp(lambda w, t, *b: self._run(
+            w, t, *(b or (None,)), l2_head, 0.3, interpret=interpret, blocks=self.BLOCKS), *args)
+        got, want = (y, *pull(ct)), (want_y, *want_pull(ct))
         assert len(got) == 3 + with_bias and got[1].shape == wide.shape
         assert not np.any(np.asarray(got[1][..., :self.LO])) and not np.any(
             np.asarray(got[1][..., self.HI:]))
@@ -975,15 +968,11 @@ class TestConvSilu:
     # block's first (its dx lies in the block before), the sequence's last
     @pytest.mark.parametrize("token", [0, 15, 16, 32, 63])
     def test_a_tokens_cotangent_reaches_the_taps_before_it_across_a_block_edge(self, token):
-        wide, taps, bias, ct = self._inputs(True)
+        (wide, taps, bias, ct), _, loop_pull = self._loop_vjp(True, 128)
         ct = jnp.zeros_like(ct).at[:, token].set(ct[:, token])
-
-        def dwide(f):
-            return np.asarray(jax.vjp(f, wide)[1](ct)[0])
-
-        got = dwide(lambda w: self._run(w, taps, bias, 128, 0.3, interpret=True,
-                                        blocks=self.BLOCKS))
-        want = dwide(lambda w: self._loop(w, taps, bias, 128, 0.3))
+        got = np.asarray(jax.vjp(lambda w: self._run(
+            w, taps, bias, 128, 0.3, interpret=True, blocks=self.BLOCKS), wide)[1](ct)[0])
+        want = np.asarray(loop_pull(ct)[0])
         reached = np.flatnonzero(np.any(got != 0, axis=(0, 2)))
         assert list(reached) == list(range(max(token - self.K + 1, 0), token + 1))
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-6 * np.abs(want).max())

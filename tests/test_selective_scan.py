@@ -38,13 +38,30 @@ def test_values_are_the_recurrence(chunk):
                                ss.selective_scan_recurrence(*ops), atol=2e-5)
 
 
-@pytest.mark.parametrize("chunk", [4, 7, 20])
-@pytest.mark.parametrize("leaf", range(6), ids=NAMES)
-def test_every_gradient_is_the_recurrences(chunk, leaf):
+@pytest.fixture(scope="module")
+def gradients():
+    """``gradients(chunk)`` → the scan's six gradients at that chunk, and
+    ``gradients(None)`` the recurrence's: each differentiated once and read by
+    its leaves' cases."""
     ops = _operands(seed=1)
     weights = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
-    got = jax.grad(_weighted(lambda *o: ss.selective_scan(*o, chunk=chunk), weights), leaf)(*ops)
-    want = jax.grad(_weighted(ss.selective_scan_recurrence, weights), leaf)(*ops)
+    made = {}
+
+    def of(chunk):
+        if chunk not in made:
+            fn = ss.selective_scan_recurrence if chunk is None else (
+                lambda *o: ss.selective_scan(*o, chunk=chunk))
+            made[chunk] = jax.grad(_weighted(fn, weights), range(6))(*ops)
+        return ops, made[chunk]
+
+    return of
+
+
+@pytest.mark.parametrize("chunk", [4, 7, 20])
+@pytest.mark.parametrize("leaf", range(6), ids=NAMES)
+def test_every_gradient_is_the_recurrences(gradients, chunk, leaf):
+    (ops, got), (_, want) = gradients(chunk), gradients(None)
+    got, want = got[leaf], want[leaf]
     assert got.shape == ops[leaf].shape and got.dtype == ops[leaf].dtype
     np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
 

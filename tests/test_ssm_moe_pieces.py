@@ -9,9 +9,7 @@ models/ssm_moe_reference.py.  (The model against its reference:
 tests/test_ssm_moe.py.  Two files so that ``--dist loadfile`` spreads them.)
 """
 
-import importlib.util
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +22,11 @@ from byteps_tpu.models import ssm_moe_reference as ref
 from byteps_tpu.ops import ssd
 from byteps_tpu.parallel import moe
 
-from test_ssm_moe import PUBLISHED_PATTERN, _state, _worst
+import family_cases as fc
+from family_cases import _kernel_names
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_state = functools.partial(
+    fc._state, sm, moved=lambda name: "norm" in name or name.endswith(("router_bias", "d_skip")))
 
 
 def _layer(cfg, stack, seed=3, i=0):
@@ -214,7 +214,6 @@ def test_a_rebuilt_scan_keeps_what_the_forward_kernel_wrote(policy, forward_kern
     kernel twice.  The gradients are the same bit for bit: the kept arrays
     are the ones the rebuild would have written."""
     from byteps_tpu.ops import ssd_kernels as sk
-    from test_ops import _kernel_names
 
     assert ssd.SAVED == sk.SAVED == ("ssd_entering", "ssd_out")
     operands = _kernel_operands(heads=2, groups=1, p=64, n_chunks=2)
@@ -620,7 +619,7 @@ def test_sixteen_shares_add_up_to_the_uncut_expert_layer():
 
 
 def test_the_walk_runs_one_part_a_layer_in_the_patterns_order():
-    cfg = sm.tiny_ssm_moe(layer_types=PUBLISHED_PATTERN[:9], remat=False)
+    cfg = sm.tiny_ssm_moe(layer_types=tuple("MEMEM*EME"), remat=False)
     params = {f"{stack}.w": 10.0 * (i + 1) + jnp.arange(4.0)
               for i, stack in enumerate(("ssm", "attn", "moe"))}
     ran = []
@@ -643,31 +642,18 @@ def test_the_walk_runs_one_part_a_layer_in_the_patterns_order():
 # ---------------------------------------------------------------------------
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-@pytest.fixture(scope="module")
-def rehearsal():
-    builder = _load("benchmark/builders/nemotron_h.py", "test_nemotron_h_builder")
-    with open(os.path.join(ROOT, "benchmark/configs/nemotron_twotower_30b_ep16.json")) as f:
-        cfg = json.load(f)
-    cfg.update(cfg["rehearsal"])
-    # toy widths: the blocking is what is under test, the widths are not; the
-    # seven layers the cell runs, two heads a group, three query heads a
-    # key/value head, four chunks a sequence
-    cfg.update(first_layer=0, num_hidden_layers=7, hidden_size=32, mamba_num_heads=4,
-               mamba_head_dim=6, n_groups=2, ssm_state_size=5, chunk_size=16,
-               num_attention_heads=6, num_key_value_heads=2, head_dim=8,
-               moe_intermediate_size=16, moe_shared_expert_intermediate_size=32,
-               n_routed_experts=4, router_width=16, num_experts_per_tok=3, vocab_size=96,
-               max_seq=64)
-    mcfg = builder._model_config(cfg)
-    params, tokens, targets = _state(mcfg, batch=2)
-    return builder, cfg, mcfg, params, (tokens, targets)
+# toy widths: the seven layers the cell runs, two heads a group, three query
+# heads a key/value head, four chunks a sequence
+globals().update(fc.builder_cases(
+    "ssm_moe", ref, _state, builder="nemotron_h", config="nemotron_twotower_30b_ep16",
+    toy=dict(first_layer=0, num_hidden_layers=7, hidden_size=32, mamba_num_heads=4,
+             mamba_head_dim=6, n_groups=2, ssm_state_size=5, chunk_size=16,
+             num_attention_heads=6, num_key_value_heads=2, head_dim=8,
+             moe_intermediate_size=16, moe_shared_expert_intermediate_size=32,
+             n_routed_experts=4, router_width=16, num_experts_per_tok=3, vocab_size=96,
+             max_seq=64),
+    never_learns=("moe.router_bias",),  # picks, never learns
+    precision=(0.0, 5e-2)))
 
 
 def test_the_builder_runs_the_patterns_first_layers(rehearsal):
@@ -684,26 +670,3 @@ def test_the_builder_runs_the_patterns_first_layers(rehearsal):
             builder._model_config({**cfg, key: other})
     with pytest.raises(ValueError, match="hybrid_override_pattern"):
         builder._model_config({**cfg, "first_layer": 50})
-
-
-def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch):
-    builder, cfg, mcfg, params, batch = rehearsal
-    # blocks smaller than the sequence, so that every loop has several turns
-    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2)):
-        monkeypatch.setattr(builder, name, size)
-    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
-    grads.pop("moe.router_bias"), want_grads.pop("moe.router_bias")  # picks, never learns
-    off, leaf = _worst(grads, want_grads)
-    assert off < 1e-4, f"{leaf}: {off:.2e}"
-
-
-@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
-def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
-    builder, cfg, _, params, batch = rehearsal
-    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(
-        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
-    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
-    assert float(loss) == pytest.approx(want, rel=5e-2)

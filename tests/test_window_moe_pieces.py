@@ -10,9 +10,7 @@ moved.  (The model against its reference: tests/test_window_moe.py.  Two
 files so that ``--dist loadfile`` spreads them.)
 """
 
-import importlib.util
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +22,11 @@ from byteps_tpu.models import window_moe as wm
 from byteps_tpu.models import window_moe_reference as ref
 from byteps_tpu.parallel import moe
 
-from test_window_moe import _mesh, _state, _worst
+import family_cases as fc
+from family_cases import _mesh
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_state = functools.partial(fc._state, wm, bias=0.01)
+
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
@@ -273,18 +273,8 @@ def test_16_shares_of_8_with_the_shared_expert_once_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total - 15 * shared, want, atol=5e-5)
 
 
-def test_no_slot_is_dropped_under_a_skewed_router():
-    """A selection bias that sends every token to the two held experts:
-    sixteen times the even load (the first chunk and every tail chunk run),
-    none dropped, output = reference."""
-    cfg = wm.tiny_window_moe(n_experts=32, experts_held=2, expert_lo=4, top_k=2)
-    lp = _layer(cfg, "moe")
-    g = jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))
-    lp["router_bias"] = jnp.zeros_like(lp["router_bias"]).at[4:6].set(10.0)
-    y, stats = jax.jit(lambda g, lp: wm.moe_mlp(cfg, g, lp))(g, lp)
-    routed, held, dropped, fullest, walked = (int(v) for v in stats)
-    assert routed == held == walked == 128 and dropped == 0 and fullest == 64
-    np.testing.assert_allclose(y, ref.moe_mlp(cfg, g, lp), atol=1e-5)
+test_no_slot_is_dropped_under_a_skewed_router = fc.skewed_router_case(
+    wm.tiny_window_moe, lambda cfg: _layer(cfg, "moe"), wm.moe_mlp, ref.moe_mlp)
 
 
 # ---------------------------------------------------------------------------
@@ -292,29 +282,15 @@ def test_no_slot_is_dropped_under_a_skewed_router():
 # ---------------------------------------------------------------------------
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-@pytest.fixture(scope="module")
-def rehearsal():
-    builder = _load("benchmark/builders/afmoe.py", "test_afmoe_builder")
-    with open(os.path.join(ROOT, "benchmark/configs/trinity_mini_ep16.json")) as f:
-        cfg = json.load(f)
-    cfg.update(cfg["rehearsal"])
-    # toy widths: the blocking is what is under test, the widths are not; all
-    # five layers of the cut, so that both mixers meet both MLPs, and a window
-    # that is no multiple of the query block
-    cfg.update(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
-               intermediate_size=48, moe_intermediate_size=16, num_experts=4, router_width=16,
-               num_experts_per_tok=3, vocab_size=96, max_seq=64, sliding_window=11,
-               num_hidden_layers=5)
-    mcfg = builder._model_config(cfg)
-    params, tokens, targets = _state(mcfg, batch=2)
-    return builder, cfg, mcfg, params, (tokens, targets)
+# toy widths: all five layers of the cut, so that both mixers meet both MLPs,
+# and a window that is no multiple of the query block
+globals().update(fc.builder_cases(
+    "window_moe", ref, _state, builder="afmoe", config="trinity_mini_ep16",
+    toy=dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+             intermediate_size=48, moe_intermediate_size=16, num_experts=4, router_width=16,
+             num_experts_per_tok=3, vocab_size=96, max_seq=64, sliding_window=11,
+             num_hidden_layers=5),
+    windows=("sliding_window", {"odd": 11, "two_blocks": 16, "over_the_sequence": 200})))
 
 
 def test_the_builder_runs_entries_1_to_5_of_the_published_list(rehearsal):
@@ -328,28 +304,3 @@ def test_the_builder_runs_entries_1_to_5_of_the_published_list(rehearsal):
                        ("rope_scaling", {"type": "yarn"})):
         with pytest.raises(ValueError, match=key):
             builder._model_config({**cfg, key: other})
-
-
-@pytest.mark.parametrize("window", [11, 16, 200], ids=["odd", "two_blocks", "over_the_sequence"])
-def test_the_builders_blocked_copy_is_the_reference(rehearsal, monkeypatch, window):
-    builder, cfg, _, params, batch = rehearsal
-    cfg = {**cfg, "sliding_window": window}
-    mcfg = builder._model_config(cfg)
-    # blocks smaller than the sequence, so that every loop has several turns
-    for name, size in (("Q_BLOCK", 8), ("ROW_BLOCK", 32), ("KEY_GROUPS", 2)):
-        monkeypatch.setattr(builder, name, size)
-    got, grads = jax.jit(jax.value_and_grad(builder.plain_loss(cfg)))(params, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(mcfg, p, *batch)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
-    off, leaf = _worst(grads, want_grads)
-    assert off < 1e-4, f"{leaf}: {off:.2e}"
-
-
-@pytest.mark.parametrize("statistics", [jnp.float32, jnp.bfloat16], ids=["stated", "below"])
-def test_precision_controls_keep_f32_parameters_and_loss(rehearsal, statistics):
-    builder, cfg, _, params, batch = rehearsal
-    want = float(jax.jit(builder.plain_loss(cfg))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(
-        builder.plain_loss(cfg, jnp.bfloat16, statistics)))(params, batch)
-    assert loss.dtype == jnp.float32 and {g.dtype for g in grads.values()} == {jnp.dtype("float32")}
-    assert 1e-7 < abs(float(loss) - want) / want < 2e-2  # rounded somewhere, and not lost
