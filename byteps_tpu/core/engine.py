@@ -13,7 +13,8 @@ are XLA collectives inside the jitted step, so the *host* pipeline is:
     DECOMPRESS
     COPYH2D  (host→device, one partition at a time as its PULL or
               DECOMPRESS lands; the job's last partition then assembles
-              the leaf on the device — the caller's next step consumes it)
+              the leaf on the device, in the sharding the tensor was
+              submitted in — the caller's next step consumes it)
 
 Each stage is a ScheduledQueue + worker thread; PUSH/PULL completion is
 driven by PS-client callbacks, mirroring how ps-lite callbacks drive
@@ -49,19 +50,105 @@ from byteps_tpu.core.scheduler import ScheduledQueue
 
 
 @functools.cache
-def _assemble_program():
+def _assemble_program(sharding=None):
     """``(parts, shape) → leaf`` as ONE compiled program per distinct
-    partition layout (jit keys it by the parts' shapes and ``shape``); an
-    eager concatenate + reshape would dispatch two and hold the flat copy
-    between them.  Built on first use: importing the engine starts no
-    backend."""
+    partition layout (jit keys it by the parts' shapes and shardings and by
+    ``shape``); an eager concatenate + reshape would dispatch two and hold
+    the flat copy between them.  With ``sharding`` (a job's kept one,
+    ``_Placement``) the leaf comes out in it: what COPYH2D spread over the
+    devices is gathered first, array by array, so XLA writes one
+    all-gather each and their concatenation — left to place the gather
+    itself, XLA:TPU concatenates the shards by pad and maximum and holds
+    9.3 GB of temporaries for a 411 MB leaf of 101 partitions (against
+    0.41 GB this way; both compiled for a described v5e 2x2).  Built on
+    first use: importing the engine starts no backend."""
     import jax
     import jax.numpy as jnp
 
+    everywhere = None if sharding is None else _partition_shardings(sharding)[1]
+
     def assemble_parts(parts, shape):
+        if everywhere is not None:
+            parts = [jax.lax.with_sharding_constraint(p, everywhere) for p in parts]
         return jnp.concatenate(parts).reshape(shape)
 
-    return jax.jit(assemble_parts, static_argnums=1)
+    return jax.jit(assemble_parts, static_argnums=1, out_shardings=sharding)
+
+
+@functools.cache
+def _partition_shardings(sharding) -> tuple:
+    """``(split, everywhere)`` for the flat partitions of a job whose
+    result goes to ``sharding``: over a one-axis mesh of its devices in
+    their assignment order, an array cut evenly along that axis, and one
+    whole on every device.  Made once a sharding."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(sharding._device_assignment), ("parts",))
+    return (NamedSharding(mesh, PartitionSpec("parts")),
+            NamedSharding(mesh, PartitionSpec()))
+
+
+class _Placement:
+    """Where COPYH2D puts the partitions of a raw or host-codec jax job whose
+    tensor came in fully replicated, so that the result is MADE in that
+    sharding: every partition leaves host memory once, the sharding's n
+    devices take a share each over their own host links, and the assemble
+    program gathers (``_assemble_program``).
+
+    A transfer costs the stage thread ≈ 0.1 ms beyond its bytes (PERF.md
+    §6 PR 67: one put of 4 MB 0.26 ms, the same bytes cut in four 0.63), so
+    a partition is cut only where it must be.  The partitions of full
+    length — all but a tensor's last — are DEALT, whole, to the devices in
+    turn, as many as make whole runs of n; a run is then one array cut
+    evenly over the devices with nothing moved (``stitched``).  What is left
+    over — fewer than n partitions and the short last one — is put cut
+    evenly over the devices, or whole on each where n does not divide its
+    length.  With one device (a mesh of one chip) everything is "whole on
+    each": the plain put, on the sharding the caller will ask for."""
+
+    __slots__ = ("sharding", "split", "everywhere", "devices", "whole", "dealt")
+
+    def __init__(self, sharding, partitions) -> None:
+        self.sharding = sharding
+        self.split, self.everywhere = _partition_shardings(sharding)
+        self.devices = sharding._device_assignment
+        n = len(self.devices)
+        self.whole = partitions[0].length if partitions else 0
+        full = sum(p.length == self.whole for p in partitions)
+        self.dealt = full // n * n if n > 1 else 0
+
+    @staticmethod
+    def kept(sharding) -> bool:
+        """Is a fully replicated tensor's ``sharding`` one to make the
+        result in?  Over several of this process's devices, or one device
+        named by a ``NamedSharding`` (a mesh of one chip).  Not a plain
+        array's: that comes back on the default device, uncommitted, as
+        it went in."""
+        from jax.sharding import NamedSharding
+
+        return sharding.is_fully_addressable and (
+            sharding.num_devices > 1 or isinstance(sharding, NamedSharding))
+
+    def where(self, offset: int, length: int) -> tuple:
+        """→ (the ``device_put`` target of the partition at ``offset``,
+        whether its bytes are shared out over the devices)."""
+        n = len(self.devices)
+        index = offset // self.whole
+        if index < self.dealt:
+            return self.devices[index % n], True
+        if n > 1 and length % n == 0:
+            return self.split, True
+        return self.everywhere, False
+
+    def stitched(self, parts: list) -> list:
+        """A job's device partitions in offset order, each run of dealt
+        ones as the one array it is on the devices."""
+        import jax
+
+        n = len(self.devices)
+        run = (n * self.whole,)
+        return [jax.make_array_from_single_device_arrays(run, self.split, parts[i:i + n])
+                for i in range(0, self.dealt, n)] + parts[self.dealt:]
 
 
 @functools.cache
@@ -79,12 +166,14 @@ def _split_program():
     return jax.jit(split_parts, static_argnums=1)
 
 
-def _assemble(parts: list, shape: tuple):
+def _assemble(parts: list, shape: tuple, placement=None):
     """A job's device partitions, in offset order, as one array of its
-    submitted shape."""
-    if len(parts) == 1:
-        return parts[0].reshape(shape)
-    return _assemble_program()(parts, shape)
+    submitted shape — in the job's sharding, where it has a placement."""
+    if placement is None:
+        if len(parts) == 1:
+            return parts[0].reshape(shape)
+        return _assemble_program()(parts, shape)
+    return _assemble_program(placement.sharding)(placement.stitched(parts), shape)
 
 
 _TARGET_SITE = {"site": "pull_target"}
@@ -98,12 +187,13 @@ class _Job:
         "pending", "lock", "shape", "np_dtype", "is_jax", "version", "t0",
         "rowsparse", "device_codec", "device_parts", "failed", "trace_id",
         "parent_span", "step_counted", "d2h_parts", "holds_target",
-        "__weakref__",
+        "placement", "__weakref__",
     )
 
     def __init__(self, name, ctx, flat, result, dtype_id, average, handle,
                  pending, shape, np_dtype, is_jax, version, rowsparse=None,
-                 device_codec=False, d2h_parts=None, holds_target=False):
+                 device_codec=False, d2h_parts=None, holds_target=False,
+                 placement=None):
         self.name = name
         self.ctx = ctx
         # the tensor as one flat array the COPYD2H thread slices: a numpy
@@ -115,6 +205,9 @@ class _Job:
         # was started in submit (``_start_d2h``); COPYD2H pops each one,
         # so the device slice dies when its task leaves the stage
         self.d2h_parts = d2h_parts
+        # such a job's result is made in the sharding its tensor came in:
+        # where COPYH2D puts each partition (None: on the default device)
+        self.placement = placement
         # the host buffer every PULL lands in.  ``holds_target``: it is the
         # tensor's own (``ctx.pull_target``), lent to this job until its
         # last partition is on the device (``_return_target``)
@@ -783,9 +876,11 @@ class PipelineEngine:
         if not on_device:
             result, holds_target = self._pull_target(
                 ctx, is_jax, n_elements, np_dtype)
-        d2h_parts = None
+        d2h_parts = placement = None
         if is_jax and not on_device and tensor.is_fully_replicated:
             d2h_parts = self._start_d2h(tensor.addressable_data(0), ctx.partitions)
+            if _Placement.kept(tensor.sharding):
+                placement = _Placement(tensor.sharding, ctx.partitions)
         elif is_jax:
             flat = tensor.reshape(-1)  # device-side metadata op, async
         job = _Job(
@@ -793,7 +888,7 @@ class PipelineEngine:
             pending=len(ctx.partitions), shape=np.shape(tensor),
             np_dtype=np_dtype, is_jax=is_jax, version=ctx.version,
             device_codec=on_device, d2h_parts=d2h_parts,
-            holds_target=holds_target,
+            holds_target=holds_target, placement=placement,
         )
         # small-tensor fusion routing, per partition: uncompressed
         # partitions gauge their RAW size against the threshold;
@@ -1508,7 +1603,7 @@ class PipelineEngine:
 
                 jax.block_until_ready(parts)
                 self._return_target(job, reusable=True)
-            out = _assemble(parts, job.shape)
+            out = _assemble(parts, job.shape, job.placement)
             del parts
             if job.device_codec and job.average:
                 # raw and host-codec partitions were averaged on the host,
@@ -1521,16 +1616,17 @@ class PipelineEngine:
             out = out / self.client.num_workers
         get_state().handles.mark_done(job.handle, out.reshape(job.shape))
 
-    def _h2d(self, buf: np.ndarray, average: bool):
+    def _h2d(self, buf: np.ndarray, average: bool, where=None):
         """One host buffer onto the device (a partition on the COPYH2D
-        thread; the healed tensor in heal_degraded).  The average is taken
-        in place first — the same divide as the numpy path's
-        ``out / num_workers``, on bytes still in cache, and none with one
-        worker (``x / 1 == x`` bit for bit).  device_put returns with the
-        transfer issued; jax keeps ``buf`` alive until it completes, and
-        nothing writes those bytes before then: a buffer that outlives its
-        job (the tensor's pull target) goes back only when every put of
-        the round is ready (``_finalize``)."""
+        thread; the healed tensor in heal_degraded) — the default one, or
+        ``where`` its job's placement says: ONE ``device_put`` either way.
+        The average is taken in place first — the same divide as the numpy
+        path's ``out / num_workers``, on bytes still in cache, and none
+        with one worker (``x / 1 == x`` bit for bit).  device_put returns
+        with the transfer issued; jax keeps ``buf`` alive until it
+        completes, and nothing writes those bytes before then: a buffer
+        that outlives its job (the tensor's pull target) goes back only
+        when every put of the round is ready (``_finalize``)."""
         import jax
 
         from byteps_tpu.core.telemetry import counters
@@ -1539,7 +1635,7 @@ class PipelineEngine:
         if average and n != 1 and np.issubdtype(buf.dtype, np.floating):
             np.divide(buf, n, out=buf)
         counters().bump("h2d_bytes", buf.nbytes)
-        return jax.device_put(buf)
+        return jax.device_put(buf, where)
 
     # --- recovery plane (docs/robustness.md "healing flow") --------------
 
@@ -2351,10 +2447,18 @@ class PipelineEngine:
         one partition's copy and _finalize's device-side assemble.  numpy
         jobs keep their result on the host and device-codec jobs already
         decoded theirs on the device: both pass through."""
+        from byteps_tpu.core.telemetry import counters
+
         job: _Job = task.context
         if job.is_jax and not job.device_codec:
+            where = None
+            if job.placement is not None:
+                where, shared = job.placement.where(task.offset, task.length)
+                if shared:
+                    counters().bump("h2d_sharded_parts")
             part = self._h2d(
-                job.result[task.offset : task.offset + task.length], job.average
+                job.result[task.offset : task.offset + task.length],
+                job.average, where,
             )
             with job.lock:
                 job.device_parts[task.offset] = part

@@ -125,6 +125,16 @@ class RobustnessCounters:
     - ``fused_reply_malformed`` — fused replies that failed to decode
       (routed to the frame's error path instead of the recv lane)
 
+    The worker's device boundary (``core/engine.py``):
+
+    - ``d2h_bytes`` / ``h2d_bytes`` — bytes COPYD2H read off the device
+      and COPYH2D put back, a partition at a time (a raw jax round grows
+      each by the tensor's bytes)
+    - ``h2d_sharded_parts``    — partitions whose bytes COPYH2D shared
+      out over the devices of the sharding their tensor was submitted
+      in: dealt whole to one of them in turn, or cut evenly over them
+      (one ``device_put`` each; ``h2d_bytes`` counts it once)
+
     ``bump(name, n, labels={"server": "2"})`` additionally records the
     count under that label set: ``rpc_retry``/``rpc_deadline_expired``/
     ``conn_revive`` carry a per-server-rank dimension so ONE sick server

@@ -13,8 +13,10 @@ network through the PS push/pull plane.  The TPU translation:
   PS plane (``push_pull_async``, priority = −declaration order, so the
   OSDI scheduling applies to the inter-host leg exactly as in the
   reference), averaged across workers.
-- the optimizer applies the globally-averaged gradients and parameters
-  return to the device with their ``NamedSharding`` for the next step.
+- the optimizer applies the globally-averaged gradients — a replicated
+  leaf's comes back from the engine in the sharding it was submitted in,
+  a tensor-parallel leaf's is put back on its ``NamedSharding`` by the
+  step — and parameters stay on the device for the next step.
 
 This is the composition in one loop: the mesh plane and the PS plane
 are not alternatives, they are the two levels of one step.
@@ -162,6 +164,11 @@ class HybridDataParallel:
             with span("hybrid.hop_wait"):
                 averaged = [bps.synchronize(h) for h in handles]
             with span("hybrid.reput"):
+                # the engine made a replicated leaf's average in the
+                # sharding its gradient went in (COPYH2D), so this put is
+                # of an array onto the sharding it has: the same array
+                # back.  A leaf sharded for real (tensor-parallel) comes
+                # back on one device and is placed here
                 g_global = jax.tree_util.tree_unflatten(treedef, averaged)
                 g_global = jax.tree.map(
                     lambda g, sh: jax.device_put(jnp.asarray(g), sh),

@@ -4,7 +4,13 @@ left with a device-side assemble, and the job lets go of its buffers when it
 hands the result back.  The results must stay bit-equal to the arithmetic the
 host-assembled path had (``sum / num_workers`` in the tensor's dtype, no
 divide for one worker or ``average=False``); numpy callers keep the host path
-and count no ``h2d_bytes``.
+and count no ``h2d_bytes``.  The result is made in the sharding the tensor was
+submitted in (ISSUE 67): a tensor replicated over several devices has each
+partition put ONCE — the full-length ones dealt whole to the devices in turn
+while they make whole runs, the rest cut evenly over them where the length
+divides (both counted ``h2d_sharded_parts``) and whole on each where it does
+not — and the assemble program gathers; every device then holds the same
+bits the one-device path gives.
 
 One parametrised test against an in-process scheduler + server.  The other
 workers of a 2- or 3-worker case are bare ``PSClient``s that join the init
@@ -36,10 +42,27 @@ class Case:
     jax_input: bool = True
     codec: bool = False    # topk at full k under error feedback: the HOST codec path
     fusion: int = 0        # BYTEPS_FUSION_THRESHOLD
+    # the submitted array: 0 as ``jnp.asarray`` makes it (one device, no
+    # name), n replicated over a mesh of the first n devices (1: a
+    # NamedSharding that names one chip, as a one-chip HybridDataParallel's)
+    devices: int = 0
 
     @property
     def parts(self) -> int:
         return -(-int(np.prod(self.shape)) // PART_ELEMS)
+
+    @property
+    def shared_parts(self) -> int:
+        """Partitions whose bytes go out once over the devices: the
+        full-length ones that make whole runs of one a device (dealt, whole,
+        to the devices in turn), and of the rest those the device count
+        divides (cut evenly)."""
+        n, d = int(np.prod(self.shape)), self.devices
+        if d < 2:
+            return 0
+        lengths = [min(PART_ELEMS, n - off) for off in range(0, n, PART_ELEMS)]
+        dealt = sum(ln == lengths[0] for ln in lengths) // d * d
+        return dealt + sum(ln % d == 0 for ln in lengths[dealt:])
 
 
 CASES = [
@@ -54,6 +77,17 @@ CASES = [
     Case("host-codec-topk-ef-avg", (2, 2 * PART_ELEMS), codec=True),
     Case("fused-small", (500,), fusion=16384),
     Case("fused-small-2d", (20, 30), average=False, fusion=16384),
+    Case("named-one-device", (5, PART_ELEMS + 100), devices=1),
+    Case("named-one-device-one-part", (8, 16), workers=2, devices=1),
+    Case("replicated-2-divides", (3 * PART_ELEMS,), workers=2, devices=2),
+    Case("replicated-2-one-odd-part", (7, 9), devices=2),
+    Case("replicated-4-last-does-not-divide", (2 * PART_ELEMS + 6,), devices=4),
+    Case("replicated-4-one-part", (8, 16), average=False, devices=4),
+    Case("replicated-4-avg-w3", (5, PART_ELEMS + 100), workers=3, devices=4),
+    Case("replicated-2-runs-and-rest", (7 * PART_ELEMS + 3,), workers=2, devices=2),
+    Case("replicated-4-two-runs", (8, PART_ELEMS), average=False, devices=4),
+    Case("replicated-2-host-codec", (2, 2 * PART_ELEMS), codec=True, devices=2),
+    Case("replicated-4-fused-small", (500,), fusion=16384, devices=4),
 ]
 
 
@@ -129,6 +163,7 @@ def cluster(request, monkeypatch):
 def test_copyh2d_puts_partitions_and_finalize_assembles(cluster):
     import jax
     import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     import byteps_tpu as bps
     from byteps_tpu.common.partition import partition_tensor
@@ -170,8 +205,13 @@ def test_copyh2d_puts_partitions_and_finalize_assembles(cluster):
             before = bps.get_robustness_counters()
             for peer in peers:
                 peer.go[rnd].set()
-            out = bps.push_pull(jnp.asarray(x) if case.jax_input else x,
-                                name=name, average=case.average)
+            sent = x
+            if case.devices:
+                mesh = Mesh(np.array(jax.devices()[:case.devices]), ("dp",))
+                sent = jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
+            elif case.jax_input:
+                sent = jnp.asarray(x)
+            out = bps.push_pull(sent, name=name, average=case.average)
             after = bps.get_robustness_counters()
 
             # the parent's arithmetic: assemble on the host, then one divide
@@ -184,12 +224,20 @@ def test_copyh2d_puts_partitions_and_finalize_assembles(cluster):
             assert isinstance(out, jax.Array if case.jax_input else np.ndarray)
             assert out.shape == case.shape and out.dtype == np.float32
             np.testing.assert_array_equal(np.asarray(out).view(np.uint32), want.view(np.uint32))
+            if case.jax_input:
+                # in the submitted sharding, and every device's copy the same bits
+                assert out.sharding.is_equivalent_to(sent.sharding, out.ndim)
+                assert len(out.addressable_shards) == max(case.devices, 1)
+                for shard in out.addressable_shards:
+                    np.testing.assert_array_equal(
+                        np.asarray(shard.data).view(np.uint32), want.view(np.uint32))
 
             grew = {k: after.get(k, 0) - before.get(k, 0)
-                    for k in ("h2d_bytes", "d2h_bytes", "fused_frames")}
+                    for k in ("h2d_bytes", "d2h_bytes", "fused_frames", "h2d_sharded_parts")}
             moved = x.nbytes if case.jax_input else 0
             assert grew == {"h2d_bytes": moved, "d2h_bytes": moved,
-                            "fused_frames": int(bool(case.fusion))}
+                            "fused_frames": int(bool(case.fusion)),
+                            "h2d_sharded_parts": case.shared_parts}
 
             job, on_device, refs, had_result = finalized.pop()
             assert not finalized  # one finalize a job

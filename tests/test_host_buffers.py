@@ -332,8 +332,8 @@ class TestPullTarget:
         real_h2d = PipelineEngine._h2d
         real_return = PipelineEngine._return_target
 
-        def h2d(self, buf, average):
-            part = real_h2d(self, buf, average)
+        def h2d(self, buf, average, where=None):
+            part = real_h2d(self, buf, average, where)
             events.append(("put", id(part)))
             return part
 

@@ -219,6 +219,78 @@ class TestHybridPhases:
             assert 0 <= wait["sum"] <= dwell["sum"], stage
         assert hist("span_seconds", name="engine.finalize")["count"] == 2 * leaves
 
+    @pytest.mark.parametrize("axes", [{"dp": 2}, {"dp": 4}, {"dp": 2, "tp": 2}],
+                             ids=["dp2", "dp4", "dp2_tp2"])
+    def test_an_averaged_leaf_comes_back_where_the_step_wants_it(
+            self, traced_cluster, monkeypatch, axes):
+        """ISSUE 67: the engine makes a leaf's average in the sharding its
+        gradient was submitted in, so what ``hybrid.reput`` (still a span a
+        step) is left with for a replicated leaf is a put of an array onto
+        the sharding it has — the same array back, nothing moved.  A
+        tensor-parallel leaf's gradient is sharded for real: its average
+        comes back on one device and the span's put still places it."""
+        import byteps_tpu as bps
+        from byteps_tpu.parallel import hybrid
+
+        steps, tp = 2, "tp" in axes
+        bps.init()
+        rng = np.random.default_rng(3)
+        params = {"b": np.zeros(8, np.float32),
+                  "w1": rng.normal(0, 0.3, (64, 48)).astype(np.float32),
+                  "w2": rng.normal(0, 0.3, (48, 8)).astype(np.float32)}
+        batch = (rng.normal(size=(8, 64)).astype(np.float32),
+                 rng.normal(size=(8, 8)).astype(np.float32))
+
+        def loss_fn(p, b):
+            out = jnp.tanh(b[0] @ p["w1"]) @ p["w2"]  # column- then row-parallel under tp
+            return jnp.mean(((jax.lax.psum(out, "tp") if tp else out) + p["b"] - b[1]) ** 2)
+
+        specs = {"b": P(), "w1": P(None, "tp") if tp else P(), "w2": P("tp", None) if tp else P()}
+        devices = np.array(jax.devices()[:int(np.prod(list(axes.values())))])
+        hdp = hybrid.HybridDataParallel(
+            loss_fn, params, optax.sgd(0.1), mesh=Mesh(devices.reshape(*axes.values()), tuple(axes)),
+            param_specs=specs, batch_spec=(P("dp"), P("dp")))
+
+        # the step's own puts: the engine's are of host buffers
+        reputs, applied = [], []
+        real_put, real_apply = jax.device_put, hdp._apply
+
+        def put(x, *args, **kwargs):
+            out = real_put(x, *args, **kwargs)
+            if isinstance(x, jax.Array):
+                reputs.append((x, out))
+            return out
+
+        def apply(p, s, g):
+            applied.append(g)
+            return real_apply(p, s, g)
+
+        monkeypatch.setattr(jax, "device_put", put)
+        hdp._apply = apply
+        before = counters().snapshot().get("h2d_sharded_parts", 0)
+        try:
+            losses = [hdp.step(batch) for _ in range(steps)]
+        finally:
+            bps.shutdown()
+        assert losses[-1] < losses[0]
+        assert hist("span_seconds", name="hybrid.reput")["count"] == steps
+
+        replicated = [name for name, spec in specs.items() if spec == P()]
+        assert len(reputs) == steps * len(params) and len(applied) == steps
+        for g in applied:
+            for name, leaf in g.items():
+                assert leaf.sharding.is_equivalent_to(hdp._shardings[name], leaf.ndim), name
+        for i, (came, went) in enumerate(reputs):
+            name = sorted(params)[i % len(params)]  # a dict's leaves, in key order
+            if name in replicated:
+                assert went is came, name  # a put onto the sharding it has: nothing moved
+            else:
+                assert len(came.sharding.device_set) == 1 and went is not came, name
+        # w1's three partitions and w2's | b's one each, cut over the leaf's devices
+        parts = {"b": 1, "w1": 3, "w2": 1}
+        assert (counters().snapshot().get("h2d_sharded_parts", 0) - before
+                == steps * sum(parts[name] for name in replicated))
+
 
 # ---------------------------------------------------------------------------
 # named scopes inside the compiled steps

@@ -7,8 +7,8 @@ buys a PS cell (ROADMAP's first rule: ISSUE 30 sized its change in a CPU
 sandbox and predicted six times the gain the chip's host gave).  Its numbers
 are host-plane (Python, memcpy, loopback TCP between two processes), never a
 device metric.  One JSON line on stdout: median, p10, p90 ms a frame over
-``REPS`` timed passes after a warm-up pass.  No file; ``--mode d2h`` alone
-starts a jax backend (and so wants the chip to itself).
+``REPS`` timed passes after a warm-up pass.  No file; ``--mode d2h`` and
+``--mode h2d`` alone start a jax backend (and so want the chip to themselves).
 
 ``--mode frame``: ``--frames`` frames of ``--bytes`` one way through this
 tree's ``send_message`` / ``recv_message``, each acked by a bare header, with
@@ -37,6 +37,22 @@ calls since PR 32: one split program, then every partition's
 ``copy_to_host_async`` issued before the first is read.  Both keep a pass's host
 buffers until the next pass has its own (a step's cycles did, up to PR 33);
 ``issued_first_freed`` lets them go first, as a step does since PR 34.
+``--mode h2d``: ``--frames`` partitions of ``--bytes``, views of one host buffer
+as a job's are of its pull target, put on the device one thread as COPYH2D is,
+through ``PipelineEngine._h2d``, four ways: ``whole_to_one`` (no placement: the
+default device, the engine up to PR 66), ``dealt`` (whole, to the devices in
+turn: a full-length partition of a tensor that came in replicated, since PR
+67), ``split_over_all`` (cut evenly over every device: such a tensor's
+left-over partitions) and ``whole_to_each`` (whole on every device: a length the
+device count does not divide).  A reading: the put's own wall and thread-CPU µs
+a partition (``issue_*``: what the stage thread pays) and the pass until every
+array is ready.  Then a leaf of fc6's shape (25088 x 4096 f32, 101 partitions of
+``--bytes``) assembled three ways, wall from dispatch to ready:
+``assemble_on_one`` + ``reput`` (the engine's program on the default device,
+then ``hybrid.reput``'s put to every device), ``assemble_placed`` (the engine's
+own placement: runs of dealt partitions stitched, the rest cut, an all-gather an
+array inside the program) and ``assemble_all_split`` (every partition cut).  On
+one device the puts and the programs are the same.
 """
 
 import argparse
@@ -316,17 +332,92 @@ def d2h(frames: int, nbytes: int) -> dict:
     return out
 
 
+def h2d(frames: int, nbytes: int) -> dict:
+    import types
+
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from byteps_tpu.common.types import Partition
+    from byteps_tpu.core import engine
+
+    n = max(1, nbytes // 4)
+    devices = jax.devices()
+    everyone = NamedSharding(Mesh(np.array(devices), ("dp",)), PartitionSpec())
+    split, everywhere = engine._partition_shardings(everyone)
+    # the engine's own put, of a one-worker engine (no divide)
+    put = functools.partial(engine.PipelineEngine._h2d,
+                            types.SimpleNamespace(client=types.SimpleNamespace(num_workers=1)))
+
+    def partitions(host):
+        return [host[off:off + n] for off in range(0, host.size, n)]
+
+    out = {"device": devices[0].device_kind, "devices": len(devices)}
+    bufs = partitions(np.arange(frames * n, dtype=np.float32))
+    ways = {"whole_to_one": lambda i: None, "dealt": lambda i: devices[i % len(devices)],
+            "split_over_all": lambda i: split, "whole_to_each": lambda i: everywhere}
+    for name, where in ways.items():
+        wall_us, cpu_us, ready_ms = [], [], []
+        for _ in range(REPS + 1):
+            t0, c0 = time.perf_counter(), time.thread_time()
+            parts = [put(buf, False, where(i)) for i, buf in enumerate(bufs)]
+            t1, c1 = time.perf_counter(), time.thread_time()
+            jax.block_until_ready(parts)
+            ready_ms.append((time.perf_counter() - t0) / frames * 1e3)
+            wall_us.append((t1 - t0) / frames * 1e6)
+            cpu_us.append((c1 - c0) / frames * 1e6)
+            for shard in parts[-1].addressable_shards:
+                if not np.array_equal(shard.data, bufs[-1][shard.index]):
+                    raise SystemExit(f"hop_bench: {name} put the last partition changed")
+            del parts
+        out[name] = {**_summary(ready_ms), "issue_wall_us": float(np.median(wall_us[1:])),
+                     "issue_cpu_us": float(np.median(cpu_us[1:]))}
+        out[name]["gb_per_s"] = n * 4 / out[name]["median_ms"] / 1e6
+
+    shape = (25088, 4096)  # vgg16's fc6: the leaf most of a step's partitions belong to
+    leaf = partitions(np.arange(shape[0] * shape[1], dtype=np.float32))
+    keyed = [Partition(key=i, offset=i * n, length=buf.size) for i, buf in enumerate(leaf)]
+    placed, all_split = engine._Placement(everyone, keyed), engine._Placement(everyone, keyed)
+    all_split.dealt = 0  # nothing dealt: every partition cut, PR 67's first form
+    ms = {"assemble_on_one": [], "reput": [], "assemble_placed": [], "assemble_all_split": []}
+    for _ in range(REPS + 1):
+        parts = jax.block_until_ready([put(buf, False) for buf in leaf])
+        t0 = time.perf_counter()
+        on_one = jax.block_until_ready(engine._assemble(parts, shape))
+        t1 = time.perf_counter()
+        reput = jax.block_until_ready(jax.device_put(on_one, everyone))
+        ms["assemble_on_one"].append((t1 - t0) * 1e3)
+        ms["reput"].append((time.perf_counter() - t1) * 1e3)
+        del parts, on_one
+        for name, placement in (("assemble_placed", placed), ("assemble_all_split", all_split)):
+            parts = jax.block_until_ready(
+                [put(buf, False, placement.where(i * n, buf.size)[0]) for i, buf in enumerate(leaf)])
+            t0 = time.perf_counter()
+            made = jax.block_until_ready(engine._assemble(parts, shape, placement))
+            ms[name].append((time.perf_counter() - t0) * 1e3)
+            if jax.device_put(made, everyone) is not made:
+                raise SystemExit(f"hop_bench: {name}'s leaf is not where hybrid.reput wants it")
+            for a, b in zip(reput.addressable_shards, made.addressable_shards):
+                if not np.array_equal(a.data, b.data):
+                    raise SystemExit(f"hop_bench: {name}'s leaf differs on {a.device}")
+            del parts, made
+        del reput
+    out["fc6_leaf"] = {"partitions": len(leaf), "dealt": placed.dealt,
+                       **{name: _summary(readings) for name, readings in ms.items()}}
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--mode", choices=("frame", "echo", "d2h"), required=True)
+    ap.add_argument("--mode", choices=("frame", "echo", "d2h", "h2d"), required=True)
     ap.add_argument("--frames", type=int, default=None,
-                    help="frames a pass (default: 150 one way, 162 echoed or read — a vgg16 step)")
+                    help="frames a pass (default: 150 one way, 162 echoed, read or put — a vgg16 step)")
     ap.add_argument("--bytes", type=int, default=4_096_000, help="bytes a frame")
     args = ap.parse_args()
-    frames = {"frame": 150, "echo": 162, "d2h": 162}[args.mode] if args.frames is None else args.frames
+    frames = (150 if args.mode == "frame" else 162) if args.frames is None else args.frames
     if frames < 1 or args.bytes < 1:
         ap.error("--frames and --bytes are positive")
-    reading = {"frame": frame, "echo": echo, "d2h": d2h}[args.mode](frames, args.bytes)
+    reading = {"frame": frame, "echo": echo, "d2h": d2h, "h2d": h2d}[args.mode](frames, args.bytes)
     print(json.dumps({"mode": args.mode, "frames": frames, "bytes": args.bytes,
                       "timed_passes": REPS, "host_cores": os.cpu_count(),
                       "plane": "host", **reading}), flush=True)
