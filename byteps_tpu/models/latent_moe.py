@@ -28,8 +28,7 @@ from jax.sharding import Mesh
 
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models.moe_family import rms, swiglu
-from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.ops.mla_heads import even_first, merge_heads, mla_heads
+from byteps_tpu.ops.mla_heads import even_first
 from byteps_tpu.parallel.moe import ROUTING_STATS, sigmoid_topk_route
 
 
@@ -160,35 +159,11 @@ def init_params(cfg: LatentMoEConfig, key: jax.Array) -> Dict[str, jax.Array]:
 
 
 def _attention(cfg: LatentMoEConfig, x, lp):
-    """x (B, S, D) → x + latent attention.  Every product is written
-    token-major, (B, S, heads · width), from WEIGHT columns in the order
-    ``ops/mla_heads.py`` reads — all heads' parts without positions, all
-    heads' rotary parts (even columns first: that file says why the scores do
-    not see it), keys, values — and one pass builds the flash kernels'
-    operands from them; no activation is sliced, concatenated or turned
-    outside it."""
-    cdt, nope, r, heads = cfg.compute_dtype, cfg.qk_nope_dim, cfg.kv_lora_rank, cfg.n_heads
-    with jax.named_scope("mla_attention"):
-        def columns(w):  # (rank, heads, width) → (rank, heads · width)
-            return w.reshape(w.shape[0], -1)
-
-        h = rms(x, lp["attn_norm"], cfg.norm_eps).astype(cdt)
-        c_q = rms(h @ lp["wq_a"].astype(cdt), lp["q_norm"], cfg.norm_eps).astype(cdt)
-        wq_b = lp["wq_b"].astype(cdt)
-        q_nope = c_q @ columns(wq_b[..., :nope])
-        q_rope = c_q @ columns(even_first(wq_b[..., nope:]))
-        wkv_a = lp["wkv_a"].astype(cdt)
-        kv_a = h @ jnp.concatenate([wkv_a[:, :r], even_first(wkv_a[:, r:])], axis=-1)
-        c_kv = rms(kv_a[..., :r], lp["kv_norm"], cfg.norm_eps).astype(cdt)
-        wkv_b = lp["wkv_b"].astype(cdt)
-        k_nope = c_kv @ columns(wkv_b[..., :nope])
-        v = c_kv @ columns(wkv_b[..., nope:])
-        # one rotary key a token, shared by every head
-        q, k, v = mla_heads(q_nope, q_rope, k_nope, v, kv_a[..., r:], heads, cfg.rope_theta)
-        # the Pallas kernels on a TPU (the only way at 8k: one sequence's
-        # scores are 4.3 GB a layer); off a TPU this takes the dense path
-        o = flash_attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5)
-        return x + merge_heads(o, lp["wo"].astype(cdt)).astype(x.dtype)
+    """x (B, S, D) → x + latent attention (``moe_family.latent_attention``):
+    queries through their bottleneck, the interleaved rope on the rotary
+    columns — even columns first in the weights, ``ops/mla_heads.py`` says why
+    the scores do not see it."""
+    return mf.latent_attention(cfg, x, lp, "mla_attention", cfg.rope_theta, even_first)
 
 
 def _dense_layer(cfg: LatentMoEConfig, x, lp):

@@ -45,7 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED
+from byteps_tpu.ops.flash_attention import SAVED as FLASH_SAVED, flash_attention
+from byteps_tpu.ops.mla_heads import merge_heads, mla_heads
 from byteps_tpu.parallel.moe import (ROUTING_STATS, HeldPlan, held_expert_apply,
                                      held_expert_plan, varying)
 
@@ -381,6 +382,52 @@ def keep_flash():
     object each call, and jax keeps traced functions apart by it: a caller
     makes one for the parts it wants lowered as one."""
     return jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
+
+
+def latent_attention(cfg, x, lp, scope: str, theta: Optional[float],
+                     order: Callable = lambda w: w):
+    """x (B, S, D) → x + multi-head latent attention, under ``scope``: keys
+    and values through a low-rank bottleneck (``wkv_a`` to ``kv_lora_rank`` +
+    one ``qk_rope_dim``-wide key a token that every head shares, ``wkv_b`` to
+    ``n_heads`` of ``qk_nope_dim`` + ``v_head_dim``), queries through one too
+    where the layer has its leaves (``wq_a``, ``q_norm``, ``wq_b``) and
+    straight from the normed stream where it has ``wq`` alone; causal softmax
+    at scale ``qk_dim``^-½.  ``theta`` is the rope's base for the shared key
+    and the queries' last ``qk_rope_dim`` columns — None: no positions, those
+    columns are keys and queries as they come (``ops/mla_heads.py``: tables
+    of cos 1, sin 0) — and ``order`` the order the caller's rope wants those
+    columns' WEIGHTS in (``mla_heads.even_first`` for an interleaved rope).
+
+    Every product is written token-major, (B, S, heads · width), from weight
+    columns in the order ``ops/mla_heads.py`` reads — all heads' parts without
+    positions, all heads' rotary parts, keys, values — and one pass builds the
+    flash kernels' operands from them; no activation is sliced, concatenated
+    or turned outside it."""
+    cdt, nope, r, heads = cfg.compute_dtype, cfg.qk_nope_dim, cfg.kv_lora_rank, cfg.n_heads
+    with jax.named_scope(scope):
+        def columns(w):  # (rank, heads, width) → (rank, heads · width)
+            return w.reshape(w.shape[0], -1)
+
+        h = rms(x, lp["attn_norm"], cfg.norm_eps).astype(cdt)
+        if "wq_a" in lp:
+            c_q = rms(h @ lp["wq_a"].astype(cdt), lp["q_norm"], cfg.norm_eps).astype(cdt)
+            wq = lp["wq_b"].astype(cdt)
+        else:
+            c_q, wq = h, lp["wq"].astype(cdt)
+        q_nope = c_q @ columns(wq[..., :nope])
+        q_rope = c_q @ columns(order(wq[..., nope:]))
+        wkv_a = lp["wkv_a"].astype(cdt)
+        kv_a = h @ jnp.concatenate([wkv_a[:, :r], order(wkv_a[:, r:])], axis=-1)
+        c_kv = rms(kv_a[..., :r], lp["kv_norm"], cfg.norm_eps).astype(cdt)
+        wkv_b = lp["wkv_b"].astype(cdt)
+        k_nope = c_kv @ columns(wkv_b[..., :nope])
+        v = c_kv @ columns(wkv_b[..., nope:])
+        # one rotary key a token, shared by every head
+        q, k, v = mla_heads(q_nope, q_rope, k_nope, v, kv_a[..., r:], heads, theta)
+        # the Pallas kernels on a TPU (the only way at 8k: one sequence's
+        # scores are 4.3 GB a layer); off a TPU this takes the dense path
+        o = flash_attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5)
+        return x + merge_heads(o, lp["wo"].astype(cdt)).astype(x.dtype)
 
 
 class Handed(NamedTuple):

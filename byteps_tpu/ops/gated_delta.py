@@ -51,22 +51,63 @@ everywhere else XLA's form below (:func:`_chunked_xla`: the scan over chunks
 and autodiff through it, which keeps a chunk's entering state and u), which
 is also the kernels' oracle beside the recurrence.  Nothing a token is kept
 by either.
+
+**A decay a key channel.**  ``g`` may be ``(B, S, H_v, d_k)``: ``exp(g_t)`` is
+then a vector that multiplies S's ROWS, ``S ← Diag(exp(g_t)) S`` (Kimi Delta
+Attention; the scalar case is its broadcast, and the recurrence is written
+once for both).  With Γ_i the running sum of g inside a chunk, now a vector,
+the chunk's system keeps its shape —
+
+    u_i + β_i Σ_{j<i} A_ij u_j = β_i (v_i − S₀ᵀ(e^{Γ_i} ⊙ k_i)),
+    o_i = S₀ᵀ(e^{Γ_i} ⊙ q_i) + Σ_{j≤i} B_ij u_j,
+    S_C = Diag(e^{Γ_C}) S₀ + Σ_j (e^{Γ_C − Γ_j} ⊙ k_j) u_jᵀ
+
+— but ``A_ij = Σ_c k_ic k_jc e^{Γ_ic − Γ_jc}`` (B the same with q_i) does not
+factor into ``D_ij (k_i·k_j)``: the decay sits inside the contraction.  As a
+matrix product it is ``(k_i ⊙ e^{Γ_i − Γ_r}) · (k_j ⊙ e^{Γ_r − Γ_j})`` for a
+reference row r, and both exponents are ≤ 0 only where j ≤ r ≤ i.  So
+(:func:`_chunked_channel_xla`) a chunk is cut into sub-blocks of
+``SUB_CHUNK`` rows (half a chunk where that is fewer): a sub-block BELOW the
+diagonal takes r at its rows' first and is a matrix product; a sub-block ON the diagonal is summed pair by pair,
+``Σ_c x_ic k_jc e^{Γ_ic − Γ_jc}`` over i ≥ j alone, ``PAIRWISE_CHUNKS`` chunks'
+sub-blocks at a time (a ``lax.map`` over the chunks, rebuilt in the backward
+pass: the sub × sub × d_k terms of a few chunks stand, never a sequence's).  The promise
+above holds: every exponent is of a non-positive number.  No kernel is
+written for this form yet: it is XLA's everywhere.
 """
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from byteps_tpu.core.telemetry import counters
 from byteps_tpu.ops._dispatch import LANES, kernels_run, tuned
 from byteps_tpu.ops.gated_delta_kernels import STACK, gated_delta_kernels
 
 CHUNK = 64
+#: rows of a sub-block of the channel form: below the diagonal a sub-block is a
+#: matrix product, on it a sum pair by pair
+SUB_CHUNK = 16
+#: heads the channel form takes at a time (XLA's form keeps a dozen f32 arrays
+#: of q's size for its backward pass: at 32 heads of 128 and 16 384 tokens 4.6
+#: GiB all heads at once, PERF.md §6 PR 68)
+HEAD_BLOCK = 8
+#: chunks whose diagonal sub-blocks are summed pair by pair at a time: their
+#: sub × sub × d_k terms stand together (64 MiB in f32 for 8 heads of 128), and
+#: a sequence's 256 chunks are 16 turns of a loop, not 256
+PAIRWISE_CHUNKS = 16
+#: the ``checkpoint_name`` of what the channel form leaves for a caller's
+#: ``jax.checkpoint`` policy to keep: the rule's output o, f32.  Kept, a rebuilt
+#: layer does not run the rule's forward pass again for its value — the blocks
+#: of heads rebuild themselves in their own backward pass (:func:`_by_head_blocks`)
+CHANNEL_SAVED = ("gdn_channel_out",)
 
 #: chunks a grid step of the three kernels (inverse, forward, backward) by
 #: sequence length: tools/gdn_tune.py's sweep on the chip, as
@@ -76,14 +117,18 @@ _DEFAULT_BLOCKS = (8, 8, 8)
 
 
 def gated_delta_recurrence(q, k, v, g, beta):
-    """The rule token by token.  q, k (B, S, H, d_k), v (B, S, H, d_v), g and
-    beta (B, S, H); the state is carried in g's dtype.  Returns o (B, S, H, d_v)."""
+    """The rule token by token.  q, k (B, S, H, d_k), v (B, S, H, d_v), beta
+    (B, S, H) and g (B, S, H) — one decay a head — or (B, S, H, d_k) — one a
+    key channel, a row of the state —; the state is carried in g's dtype.
+    Returns o (B, S, H, d_v)."""
     st = g.dtype
     b, _, h, dk = q.shape
+    if g.ndim == beta.ndim:  # a head's one decay is every channel's
+        g = g[..., None]
 
     def token(state, xs):
-        q_t, k_t, v_t, g_t, beta_t = xs  # (B, H, d), (B, H)
-        state = jnp.exp(g_t)[..., None, None] * state
+        q_t, k_t, v_t, g_t, beta_t = xs  # (B, H, d), (B, H, d_k | 1), (B, H)
+        state = jnp.exp(g_t)[..., None] * state
         u = beta_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
         state = state + k_t[..., :, None] * u[..., None, :]
         return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
@@ -189,7 +234,9 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype
                              interpret: bool = False, blocks: Optional[Sequence[int]] = None):
     """q, k (B, S, H_k, d_k) — normalised and scaled by the caller —, v
     (B, S, H_v, d_v), g ≤ 0 and beta (B, S, H_v); each key head serves
-    H_v / H_k value heads in a row (it is never repeated in memory).  Returns
+    H_v / H_k value heads in a row (it is never repeated in memory).  With g
+    (B, S, H_v, d_k), a decay a key channel, H_k = H_v and the chunk is cut
+    into sub-blocks of ``SUB_CHUNK`` rows, two at least (the module's docstring).  Returns
     o (B, S, H_v, d_v) f32.  A sequence that ``chunk`` does not divide raises:
     padding would have to be the caller's choice (a padded token writes to
     the state unless its beta is 0).  Which implementation runs is
@@ -203,6 +250,14 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype
         raise ValueError(f"{hv} value heads are no multiple of {hk} key heads")
     cdt = compute_dtype or q.dtype
     # decided once a traced call; bps.get_robustness_counters() shows which
+    if g.ndim == q.ndim:
+        sub = min(SUB_CHUNK, chunk // 2)  # a short chunk still has a sub-block below the diagonal
+        if hv != hk or g.shape != q.shape or not sub or chunk % sub:
+            raise ValueError(f"gated delta rule, a decay a channel: g {g.shape} beside q "
+                             f"{q.shape}, v {v.shape}; sub-blocks of {sub} in a chunk of {chunk}")
+        counters().bump("gdn_channel_xla_traces")
+        return checkpoint_name(_by_head_blocks(q, k, v, g, beta, chunk, sub, cdt),
+                               CHANNEL_SAVED[0])
     if not _kernel_path(chunk, q.shape[-1], v.shape[-1], interpret):
         counters().bump("gdn_xla_traces")
         return _chunked_xla(q, k, v, g, beta, chunk, cdt)
@@ -271,3 +326,119 @@ def _chunked_xla(q, k, v, g, beta, chunk, cdt):
     qk = product("bhnik,bhnjk->bhnij", q, k)[:, :, None]
     o = o + product("bhrnij,bhrnjv->bhrniv", decay * qk, u)
     return jnp.moveaxis(o.reshape(b, hv, s, dv), 1, 2)
+
+
+def _pairwise(x, k, gamma, strict: bool):
+    """A chunk's diagonal sub-blocks pair by pair: x, k, gamma (..., sub, d_k)
+    f32 → ``Σ_c x_ic k_jc exp(γ_ic − γ_jc)`` (..., sub, sub) over i > j
+    (``strict``) or i ≥ j, 0 elsewhere.  γ falls along a sub-block's rows, so
+    the exponent that is taken is never positive; the mask goes on it too."""
+    rows = jnp.arange(x.shape[-2])
+    seen = rows[:, None] > rows[None, :] if strict else rows[:, None] >= rows[None, :]
+    fall = jnp.where(seen[..., None], gamma[..., :, None, :] - gamma[..., None, :, :], 0.0)
+    return jnp.where(seen, jnp.sum(
+        x[..., :, None, :] * k[..., None, :, :] * jnp.exp(fall), axis=-1), 0.0)
+
+
+def _by_head_blocks(q, k, v, g, beta, chunk, sub, cdt):
+    """:func:`_chunked_channel_xla` on ``HEAD_BLOCK`` heads at a time, one
+    block after another (``lax.map``), each rebuilt in the backward pass:
+    heads do not meet in the rule, and what autodiff keeps is one block's.
+    Fewer heads than two blocks, or no whole number of them, go at once."""
+    h = q.shape[2]
+    if h < 2 * HEAD_BLOCK or h % HEAD_BLOCK:
+        return _chunked_channel_xla(q, k, v, g, beta, chunk, sub, cdt)
+
+    def blocks(x):  # (B, S, H, ...) → (H / block, B, S, block, ...)
+        return jnp.moveaxis(
+            x.reshape(x.shape[:2] + (h // HEAD_BLOCK, HEAD_BLOCK) + x.shape[3:]), 2, 0)
+
+    one = jax.checkpoint(lambda xs: _chunked_channel_xla(*xs, chunk, sub, cdt))
+    o = jnp.moveaxis(lax.map(one, tuple(blocks(x) for x in (q, k, v, g, beta))), 0, 2)
+    return o.reshape(o.shape[:2] + (h,) + o.shape[4:])
+
+
+def _chunked_channel_xla(q, k, v, g, beta, chunk, sub, cdt):
+    """The rule with a decay a key channel (g (B, S, H, d_k)), XLA's form: as
+    :func:`_chunked_xla` — every chunk's T at once, one ``lax.scan`` over the
+    chunks, batched products after it, autodiff backwards — but A and B are
+    built sub-block by sub-block (the module's docstring) and the decays
+    that no longer factor out ride on the operands: ``k ⊙ e^Γ``, ``q ⊙ e^Γ``,
+    ``k ⊙ e^{Γ_C − Γ}``."""
+    q, k, v, g, beta = (jnp.moveaxis(x, 1, 2) for x in (q, k, v, g, beta))
+    b, h, s, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    n, m = s // chunk, chunk // sub
+
+    def product(spec, x, y):
+        return jnp.einsum(spec, x.astype(cdt), y.astype(cdt), preferred_element_type=f32)
+
+    # index letters: b batch, h head, n chunk, i/j rows of a chunk, I/J
+    # sub-blocks of it, a/c rows of a sub-block, k/v the two head sizes
+    q, k = (x.reshape(b, h, n, chunk, dk).astype(f32) for x in (q, k))
+    v = v.reshape(b, h, n, chunk, dv).astype(cdt)
+    beta = beta.astype(f32).reshape(b, h, n, chunk)
+    gamma = jnp.cumsum(g.astype(f32).reshape(b, h, n, chunk, dk), axis=-2)
+    e_gamma = jnp.exp(gamma)
+    k_end = k * jnp.exp(gamma[..., -1:, :] - gamma)  # e^{Γ_C − Γ_j} ⊙ k_j
+
+    # -- A (i > j, with k) and B (i ≥ j, with q), sub-block by sub-block
+    blocks = (b, h, n, m, sub, dk)
+    qs, ks, gs = (x.reshape(blocks) for x in (q, k, gamma))
+
+    @jax.checkpoint
+    def diagonal(xs):  # a chunk's sub-blocks: the sub × sub × d_k terms stand here alone
+        q_n, k_n, g_n = xs
+        return _pairwise(k_n, k_n, g_n, True), _pairwise(q_n, k_n, g_n, False)
+
+    a_diag, b_diag = (jnp.moveaxis(x, 0, 2) for x in lax.map(
+        diagonal, tuple(jnp.moveaxis(x, 2, 0) for x in (qs, ks, gs)),
+        batch_size=math.gcd(n, PAIRWISE_CHUNKS)))
+    same = jnp.eye(m, dtype=bool)[:, None, :, None]
+
+    def whole(diag, below):
+        """(…, m, sub, sub) on the diagonal and (…, m−1, sub, m−1, sub) below
+        it → a chunk's (chunk, chunk)."""
+        full = jnp.where(same, diag[..., :, :, None, :], 0.0)
+        full = full + jnp.pad(below, [(0, 0)] * 3 + [(1, 0), (0, 0), (0, 1), (0, 0)])
+        return full.reshape(b, h, n, chunk, chunk)
+
+    # sub-block I ≥ 1 below sub-block J < I: r is I's first row, so rows
+    # i ≥ r fall from it and Γ_r lies below every Γ_j
+    ref = gs[..., 1:, 0, :]  # (b, h, n, m − 1, d_k)
+    rows = jnp.exp(gs[..., 1:, :, :] - ref[..., None, :])
+    before = (jnp.arange(m - 1)[None, :] <= jnp.arange(m - 1)[:, None])[..., None, None]
+    rise = ref[..., :, None, None, :] - gs[..., None, :-1, :, :]
+    cols = (ks[..., None, :-1, :, :] * jnp.where(
+        before, jnp.exp(jnp.where(before, rise, 0.0)), 0.0)).astype(cdt)
+    a_below = product("bhnIak,bhnIJck->bhnIaJc", ks[..., 1:, :, :] * rows, cols)
+    b_below = product("bhnIak,bhnIJck->bhnIaJc", qs[..., 1:, :, :] * rows, cols)
+
+    t = unit_lower_inverse(beta[..., None] * whole(a_diag, a_below))
+    seen_u = whole(b_diag, b_below).astype(cdt)
+    # T (β (e^Γ ⊙ K)) and T (β V): the row scales go on T's columns
+    t_beta = t * beta[..., None, :]
+    w = product("bhnij,bhnjk->bhnik", t_beta, k * e_gamma).astype(cdt)
+    u0 = product("bhnij,bhnjv->bhniv", t_beta, v)
+
+    def step(state, xs):
+        w_n, u0_n, k_end_n, end_n = xs
+        held = state.astype(cdt)
+        u = u0_n - jnp.einsum("bhik,bhkv->bhiv", w_n, held, preferred_element_type=f32)
+        state = end_n[..., None] * state + jnp.einsum(
+            "bhik,bhiv->bhkv", k_end_n, u.astype(cdt), preferred_element_type=f32)
+        return state, (held, u.astype(cdt))
+
+    state0 = jnp.zeros((b, h, dk, dv), f32)
+    varying = tuple(jax.typeof(q).vma)  # the carry's type under shard_map: as q varies
+    if varying:
+        state0 = lax.pcast(state0, varying, to="varying")
+    per_chunk = tuple(jnp.moveaxis(x, 2, 0) for x in (
+        w, u0, k_end.astype(cdt), e_gamma[..., -1, :]))
+    _, (entering, u) = lax.scan(step, state0, per_chunk)
+    entering, u = jnp.moveaxis(entering, 0, 2), jnp.moveaxis(u, 0, 2)
+
+    o = product("bhnik,bhnkv->bhniv", q * e_gamma, entering)
+    o = o + product("bhnij,bhnjv->bhniv", seen_u, u)
+    return jnp.moveaxis(o.reshape(b, h, s, dv), 1, 2)

@@ -40,6 +40,11 @@ two heads' rotary columns are one lane tile, and the rotary key's block and
 the (S, 128) f32 tables are fetched once a query block and stand still while
 the heads pass (Pallas does not copy a block whose index did not change).
 
+**A configuration without positions** (``theta`` None) takes the same pass
+with tables of cos 1 and sin 0 (:func:`_tables`): the turn by no angle is the
+identity, q is ``[q_nope | q_rope]`` and k ``[k_nope | the shared key]`` as
+they came, and the tables are data — no second pass, no branch in a kernel.
+
 ``merge_heads(o, wo)`` is the way out: the output projection on the kernels'
 head-major o, with a transpose that writes dO head-major at once.
 """
@@ -47,6 +52,7 @@ head-major o, with a transpose that writes dO head-major at once.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +77,14 @@ def even_first(w):
 
 def _block_rows(s: int) -> int:
     return min(BLOCK_ROWS, s)
+
+
+def _tables(s: int, r: int, theta):
+    """(cos, sin) (S, r) f32 of the turn (``head_norm.rope_tables``); with
+    ``theta`` None the turn by no angle, cos 1 and sin 0."""
+    if theta is None:
+        return jnp.ones((s, r), jnp.float32), jnp.zeros((s, r), jnp.float32)
+    return rope_tables(s, r, theta)
 
 
 def _kernel_path(s: int, h: int, n: int, r: int, d_v: int, interpret: bool) -> bool:
@@ -125,10 +139,10 @@ def _backward(dq, dk, dv, n, tables):
 # ---------------------------------------------------------------------------
 
 
-def _pair_tables(s: int, r: int, theta: float):
+def _pair_tables(s: int, r: int, theta):
     """(cos, sin) (S, 128) f32: a head's tables twice along the lanes, once a
     head of a pair."""
-    return tuple(jnp.tile(t, (1, LANES // r)) for t in rope_tables(s, r, theta))
+    return tuple(jnp.tile(t, (1, LANES // r)) for t in _tables(s, r, theta))
 
 
 def _turn_pair(y, cos, sin):
@@ -268,7 +282,7 @@ def _mla_heads(q_nope, q_rope, k_nope, v, k_rope, h, n, theta, interpret):
     s, r = q_nope.shape[1], k_rope.shape[-1]
     if _kernel_path(s, h, n, r, v.shape[-1] // h, interpret):
         return _forward_kernels(q_nope, q_rope, k_nope, v, k_rope, h, theta, interpret)
-    return _forward(q_nope, q_rope, k_nope, v, k_rope, h, rope_tables(s, r, theta))
+    return _forward(q_nope, q_rope, k_nope, v, k_rope, h, _tables(s, r, theta))
 
 
 def _fwd(q_nope, q_rope, k_nope, v, k_rope, h, n, theta, interpret):
@@ -281,17 +295,18 @@ def _bwd(h, n, theta, interpret, _, cotangents):
     s, r = dq.shape[2], dq.shape[-1] - n
     if _kernel_path(s, h, n, r, dv.shape[-1], interpret):
         return tuple(_backward_kernels(dq, dk, dv, n, theta, interpret))
-    return _backward(dq, dk, dv, n, rope_tables(s, r, theta))
+    return _backward(dq, dk, dv, n, _tables(s, r, theta))
 
 
 _mla_heads.defvjp(_fwd, _bwd)
 
 
-def mla_heads(q_nope, q_rope, k_nope, v, k_rope, n_heads: int, theta: float,
+def mla_heads(q_nope, q_rope, k_nope, v, k_rope, n_heads: int, theta: Optional[float],
               interpret: bool = False):
     """The four token-major products and the shared rotary key (rotary columns
     even-first, :func:`even_first`) → the flash kernels' q, k (B, H, S, n + r)
-    and v (B, H, S, d_v); differentiable in all five.  What runs where is
+    and v (B, H, S, d_v); differentiable in all five.  ``theta`` None: no
+    positions, nothing is turned (:func:`_tables`).  What runs where is
     :func:`_kernel_path`'s call."""
     n, r = q_nope.shape[-1] // n_heads, k_rope.shape[-1]
     if r % 2 or q_rope.shape[-1] != n_heads * r or k_nope.shape[-1] != n_heads * n:

@@ -28,8 +28,9 @@ import numpy as np
 import optax
 import pytest
 
-from byteps_tpu.models import (block_diffusion_moe, conv_moe, cross_decoder, delta_moe,
-                               early_route_moe, latent_moe, looped_dense, ssm_moe, window_moe)
+from byteps_tpu.models import (block_diffusion_moe, channel_delta_moe, conv_moe, cross_decoder,
+                               delta_moe, early_route_moe, latent_moe, looped_dense, ssm_moe,
+                               window_moe)
 from byteps_tpu.models import moe_family as mf
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel.mesh_utils import make_training_mesh
@@ -38,7 +39,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = {"latent_moe": latent_moe, "delta_moe": delta_moe, "conv_moe": conv_moe,
             "window_moe": window_moe, "early_route_moe": early_route_moe, "ssm_moe": ssm_moe,
             "looped_dense": looped_dense, "block_diffusion_moe": block_diffusion_moe,
-            "cross_decoder": cross_decoder}
+            "cross_decoder": cross_decoder, "channel_delta_moe": channel_delta_moe}
 #: family → the reader of benchmark/readers/ its cell's metrics go through,
 #: where that is not one of its own name
 READERS = {"early_route_moe": "window_moe"}
@@ -145,6 +146,19 @@ FROZEN_LOWERINGS = {
         "f7b54ed6a2262764759d51bf30d25c96639b74fd62fbb4e0af5c0c9b8b150ab1",
     ("cross_decoder", "bfloat16"):
         "55587533d5913f24b270d8e739d224e80705f1fb9a26b8c37975675d82eb28cd",
+    # taken when ISSUE 68 wrote the family — the delta rule with a decay a key
+    # channel, latent attention with neither query bottleneck nor positions;
+    # the nineteen above, every count and all nine ``FROZEN_PARAMETERS`` stood
+    # through what that PR did to the shared code: ``latent_moe``'s mixer is
+    # ``moe_family.latent_attention`` (the same operations in the same order;
+    # a query bottleneck is a leaf that is there, a rope a ``theta`` that is
+    # not None), ``ops/mla_heads.py`` makes its tables in ``_tables``, and
+    # ``ops/gated_delta.py`` takes the channel form only where g has a
+    # channel's dim
+    ("channel_delta_moe", "float32"):
+        "74c43b3804f9ecbe792d63ebb8c1672b6b2d3f15f3f0081bd8374321e50dda04",
+    ("channel_delta_moe", "bfloat16"):
+        "3d77ebd17a3850f81856d925e870bcd25001b6198dac841ad5f962595403f0c7",
 }
 
 #: sha256 over ``init_params(tiny_<family>(), PRNGKey(0))``: every leaf's name,
@@ -159,6 +173,7 @@ FROZEN_PARAMETERS = {
     "looped_dense": "0b4e0693777702fb56a74903a1a7c62bdf5c74d861676882e23cca3fff028e97",
     "block_diffusion_moe": "ce733faf1c2e8c92691f1c00b4099bb1d77ac078e40cc86c2f5e229fde94a92b",
     "cross_decoder": "c52348f3776f8cca65570fe990e5659bcf3307ca0077bb28cabe5c5f0397ad73",
+    "channel_delta_moe": "5c82e8a945814760664f82c66e5efe068903a056fa7b888b7369a29f03db50ee",
 }
 
 #: family → scope → operations of the bfloat16 step filed under it.  The scopes
@@ -196,6 +211,12 @@ FROZEN_SCOPE_OPERATIONS = {
     "cross_decoder": {"selective_scan": 435, "mamba_proj": 1027, "diff_window_attention": 1536,
                       "diff_full_attention": 755, "diff_cross_attention": 598,
                       "gated_memory": 141, "dense_mlp": 1152, "lm_head": 59, "embed": 17},
+    # three layers, every kind once: two delta mixers (``kda_scan`` between the
+    # two ``kda_proj``; the chunked rule with its sub-blocks is most of it), one
+    # latent layer without positions, one dense MLP, two expert layers (ISSUE 68)
+    "channel_delta_moe": {"kda_scan": 3326, "kda_proj": 292, "nope_latent_attention": 433,
+                          "dense_mlp": 105, "moe_route": 152, "moe_experts": 988,
+                          "moe_shared": 80},
 }
 
 #: family → scope → operations of the step (bfloat16; ``bert``'s float32 one)
@@ -222,6 +243,7 @@ FROZEN_REST_OPERATIONS = {
     "window_moe": {"lm_head": 53, "embed": 20},
     # the blocked loss with a weight a row (ISSUE 59)
     "block_diffusion_moe": {"lm_head": 61, "embed": 16},
+    "channel_delta_moe": {"lm_head": 53, "embed": 16},
 }
 
 

@@ -93,6 +93,138 @@ def test_chunked_rule_is_the_recurrence(decay, implementation):
             np.testing.assert_allclose(got_g, want_g, atol=1e-4 * scale + 1e-9, err_msg=name)
 
 
+# ---------------------------------------------------------------------------
+# a decay a key channel (g (B, S, H, d_k)): the chunked form in sub-blocks
+# ---------------------------------------------------------------------------
+
+
+def _channel_inputs(decay, b=2, h=2, s=128, dk=8, dv=6, seed=1, constant=False):
+    """As ``_rule_inputs`` with H_k = H_v and a log-decay a channel; ``decay``
+    a number scales a softplus, ``constant`` makes every token's every
+    channel's log-decay exactly ``−decay``."""
+    q, k, v, _, beta = _rule_inputs(1.0, b=b, hk=h, r=1, s=s, dk=dk, dv=dv, seed=seed)
+    g = -decay * (jnp.ones((b, s, h, dk)) if constant else jax.nn.softplus(
+        jax.random.normal(jax.random.PRNGKey(seed + 7), (b, s, h, dk))))
+    return q, k, v, g, beta
+
+
+_CHANNEL_CASES = {
+    # chunk 64 in sub-blocks of SUB_CHUNK = 16: both kinds of sub-block, two chunks, d_k ≠ d_v
+    "64x16": dict(chunk=64),
+    "32x16": dict(chunk=32),
+    "8x4": dict(chunk=8),  # a short chunk: two sub-blocks of half of it
+    # 16 heads: two blocks of HEAD_BLOCK heads, one after another
+    "64x16-16_heads": dict(chunk=64, shape=dict(b=1, h=16, s=64)),
+}
+
+
+@pytest.mark.parametrize("decay", [1e-4, 1.0, 40.0], ids=["near_one", "middling", "near_zero"])
+@pytest.mark.parametrize("case", list(_CHANNEL_CASES))
+def test_channel_rule_is_the_recurrence(decay, case):
+    """Values and all five gradients of the chunked form with a decay a
+    channel against the token-by-token recurrence."""
+    case = dict(_CHANNEL_CASES[case])
+    args = _channel_inputs(decay, **case.pop("shape", {}))
+    rule = lambda *a: gd.chunked_gated_delta_rule(*a, **case)  # noqa: E731
+    got = jax.jit(rule)(*args)
+    want = jax.jit(gd.gated_delta_recurrence)(*args)
+    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()) + 1e-7)
+    weigh = jnp.cos(jnp.arange(got.size, dtype=jnp.float32)).reshape(got.shape)
+
+    def gradients(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * weigh), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    for name, got_g, want_g in zip("q k v g beta".split(), gradients(rule),
+                                   gradients(gd.gated_delta_recurrence)):
+        assert np.all(np.isfinite(got_g)), name
+        scale = float(jnp.abs(want_g).max())
+        np.testing.assert_allclose(got_g, want_g, atol=1e-4 * scale + 1e-9, err_msg=name)
+
+
+def test_channel_rule_takes_no_positive_exponent():
+    """Decays of −12 a token a channel: over a sub-block of 16 rows a
+    reference row at its START would need e^{+180}, which f32 does not hold,
+    and over a chunk e^{+756}.  Every exponential the chunked form takes is of
+    a non-positive number: every intermediate is finite (``jax_debug_nans``
+    and ``jax_debug_infs`` stop the first that is not), values and gradients
+    agree with the recurrence to f32 rounding."""
+    args = _channel_inputs(12.0, constant=True, b=1, h=2, s=128)
+    rule = lambda *a: gd.chunked_gated_delta_rule(*a, chunk=64)  # noqa: E731
+    weigh = jnp.cos(jnp.arange(128 * 2 * 6, dtype=jnp.float32)).reshape(1, 128, 2, 6)
+    loss = lambda fn: (lambda *a: jnp.sum(fn(*a) * weigh))  # noqa: E731
+    with jax.debug_nans(True), jax.debug_infs(True):
+        got = rule(*args)
+        grads = jax.grad(loss(rule), argnums=(0, 1, 2, 3, 4))(*args)
+    want = gd.gated_delta_recurrence(*args)
+    # the state hardly outlives a token (e^-12): o_t is β_t (k_t·q_t) v_t to six places
+    q, k, v, _, beta = args
+    np.testing.assert_allclose(want, (beta * jnp.sum(q * k, -1))[..., None] * v, atol=2e-5)
+    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()))
+    for name, got_g, want_g in zip("q k v g beta".split(), grads, jax.grad(
+            loss(gd.gated_delta_recurrence), argnums=(0, 1, 2, 3, 4))(*args)):
+        assert np.all(np.isfinite(got_g)), name
+        # g's own gradient is e^-12 of the others' (1e-7 and less): the chunked
+        # form sums it from a chunk's dΓ, whose terms of the others' size cancel,
+        # so it is held to THEIR rounding (2e-7), every other to its own size
+        np.testing.assert_allclose(got_g, want_g, err_msg=name, atol=2e-7 if name == "g" else (
+            1e-4 * float(jnp.abs(want_g).max()) + 1e-9))
+
+
+@pytest.mark.parametrize("decay", [0.05, 3.0])
+def test_a_decay_constant_over_channels_is_the_scalar_rule(decay):
+    """g broadcast over the key's channels: the channel form, the scalar
+    chunked form and both recurrences give one answer to rounding."""
+    q, k, v, g, beta = _rule_inputs(decay, b=1, hk=2, r=1, s=128)
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    scalar = gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=64)
+    channel = gd.chunked_gated_delta_rule(q, k, v, wide, beta, chunk=64)
+    scale = float(jnp.abs(scalar).max())
+    np.testing.assert_allclose(channel, scalar, atol=2e-6 * scale)
+    np.testing.assert_allclose(gd.gated_delta_recurrence(q, k, v, wide, beta),
+                               gd.gated_delta_recurrence(q, k, v, g, beta), atol=1e-7 * scale)
+
+
+def test_the_recurrence_with_a_decay_a_head_gives_what_it_gave():
+    """The one definition serves both: with g (B, S, H) it is, bit for bit,
+    the scan it was before g could have a channel's dim (written out here)."""
+    q, k, v, g, beta = _rule_inputs(1.0, r=1)
+
+    def as_it_was(q, k, v, g, beta):
+        def token(state, xs):
+            q_t, k_t, v_t, g_t, beta_t = xs
+            state = jnp.exp(g_t)[..., None, None] * state
+            u = beta_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+            state = state + k_t[..., :, None] * u[..., None, :]
+            return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+        xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+        state = jnp.zeros((q.shape[0], q.shape[2], q.shape[3], v.shape[-1]), g.dtype)
+        return jnp.moveaxis(jax.lax.scan(token, state, xs)[1], 0, 1)
+
+    np.testing.assert_array_equal(gd.gated_delta_recurrence(q, k, v, g, beta),
+                                  as_it_was(q, k, v, g, beta))
+
+
+def test_channel_form_is_counted_and_refuses_what_it_cannot_cut():
+    from byteps_tpu.core.telemetry import counters
+
+    args = _channel_inputs(1.0, b=1, s=32)
+    before = counters().snapshot()
+    jax.make_jaxpr(lambda *a: gd.chunked_gated_delta_rule(*a, chunk=16))(*args)
+    after = counters().snapshot()
+    grown = {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+    assert grown == {"gdn_channel_xla_traces": 1}  # neither of the scalar form's two
+    q, k, v, g, beta = args
+    with pytest.raises(ValueError, match="sub-blocks of 16 in a chunk of 40"):
+        gd.chunked_gated_delta_rule(*_channel_inputs(1.0, b=1, s=80), chunk=40)
+    with pytest.raises(ValueError, match="sub-blocks of 0 in a chunk of 1"):
+        gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=1)
+    with pytest.raises(ValueError, match="a decay a channel"):
+        gd.chunked_gated_delta_rule(q[:, :, :1], k[:, :, :1], v, g, beta, chunk=16)
+    with pytest.raises(ValueError, match="does not divide"):
+        gd.chunked_gated_delta_rule(*(x[:, :24] for x in args), chunk=16)
+
+
 def _traces():
     from byteps_tpu.core.telemetry import counters
 

@@ -356,6 +356,41 @@ def test_the_kernels_are_xlas_form_of_the_pass(monkeypatch):
         np.testing.assert_allclose(g, w, rtol=2**-7, atol=0)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+def test_the_turn_by_no_angle_is_the_identity(monkeypatch, dtype, interpret):
+    """``theta`` None — a configuration without positions — takes the same
+    pass with tables of cos 1, sin 0: q is ``[q_nope | q_rope]``, k ``[k_nope |
+    the shared key]`` as they came, BIT FOR BIT in either implementation, and
+    every cotangent is the plain concatenation's (the shared key's its sum
+    over the heads)."""
+    monkeypatch.setattr(mh, "BLOCK_ROWS", 32)
+    assert mh._kernel_path(64, 4, _NOPE, _ROPE, _NOPE, interpret) == interpret
+    (q, kv, key), weights = _mixer_arrays(dtype, seed=4)
+    b, h, s, _ = q.shape
+    tokens = lambda x: x.transpose(0, 2, 1, 3).reshape(b, s, -1)  # noqa: E731
+
+    def through(q, kv, key):
+        return mh.mla_heads(tokens(q[..., :_NOPE]), tokens(q[..., _NOPE:]),
+                            tokens(kv[..., :_NOPE]), tokens(kv[..., _NOPE:]), key, h, None,
+                            interpret=interpret)
+
+    def plain(q, kv, key):
+        shared = jnp.broadcast_to(key[:, None], (b, h, s, _ROPE))
+        return q, jnp.concatenate([kv[..., :_NOPE], shared], axis=-1), kv[..., _NOPE:]
+
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    for name, g, w in zip("qkv", through(q, kv, key), plain(q, kv, key)):
+        assert g.dtype == dtype
+        np.testing.assert_array_equal(f32(g), f32(w), err_msg=name)
+    grads = [jax.grad(lambda *a, f=f: _seen(f(*a), weights), argnums=(0, 1, 2))(q, kv, key)
+             for f in (through, plain)]
+    for name, g, w in zip(("q", "kv", "the shared key"), *grads):
+        worst = np.abs(f32(w)).max()
+        np.testing.assert_allclose(f32(g), f32(w), rtol=0, err_msg=f"d {name}",
+                                   atol=(1e-5 if dtype == jnp.float32 else 2**-6) * worst)
+
+
 def test_a_score_does_not_see_a_common_permutation():
     """The pass turns the rotary columns even-first, the configuration's rope
     turns them interleaved: q·k over a head is the same number either way,

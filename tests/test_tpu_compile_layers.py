@@ -573,3 +573,68 @@ def test_selective_scan_keeps_its_state_out_of_the_hbm_a_token(one_chip, no_comp
     assert sizes and max(sizes) <= 128 * state * channels * 128  # a state a chunk, all chunks
     assert not re.search(r"f32\[16384,1,16,5120\]|f32\[1,16384,16,5120\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_channel_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch):
+    """Kimi Delta Attention's rule for one sequence of 16 384 tokens, 32 heads
+    of 128 | 128 token-major, a log-decay a key channel in f32, chunks of 64 in
+    sub-blocks of 16, bf16 operands: the rule and its five gradients in XLA's
+    form — there is no kernel for it yet, on a TPU either —, a block of
+    ``HEAD_BLOCK`` heads at a time.  What decides whether the cell fits: all 32
+    heads at once, the backward pass kept 4.57 GiB of temporaries (a dozen f32
+    arrays of q's size; PERF.md §6 PR 68) and the step 17.8 GiB of the chip's
+    15.75; by blocks of 8 it reads 2.4 GiB and is held under 3 here.  Nothing of a chunk's
+    sub × sub × d_k terms stands for a whole sequence (4.3 GB at f32): the
+    largest f32 array is q's size."""
+    from byteps_tpu.ops import gated_delta as gd
+
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    s, h, d = 16384, 32, 128
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gd.chunked_gated_delta_rule(q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+
+    args = (shape(1, s, h, d), shape(1, s, h, d), shape(1, s, h, d),
+            shape(1, s, h, d, dtype=jnp.float32), shape(1, s, h, dtype=jnp.float32))
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+    text = compiled.as_text()
+    assert "while" in text and "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    widest = max(_bytes_of(m.group(0)) for m in re.finditer(r"f32\[[\d,]*\]", text))
+    assert widest <= 4 * s * h * d
+
+
+def test_position_free_latent_layer_takes_the_kernels_at_published_widths(
+        one_chip, no_compile_cache, monkeypatch):
+    """``moe_family.latent_attention`` as the channel-delta family calls it —
+    no query bottleneck, ``theta`` None — for 1 x 16 384 tokens at Kimi
+    Linear's widths (2304 → 32 heads of 128 + 64 | 128 through 512 + 64): the
+    gradient holds one call of each of the four kernels JoyAI's layer has, the
+    flash pair at q, k ``bf16[32,16384,192]`` and v ``bf16[32,16384,128]`` —
+    what benchmark/readers/channel_delta_moe.py parses — and the pass of
+    ``ops/mla_heads.py`` each way, its tables cos 1 and sin 0."""
+    from byteps_tpu.models import channel_delta_moe as cd
+    from byteps_tpu.models import moe_family as mf
+    from byteps_tpu.ops import mla_heads as mh
+
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    cfg = cd.ChannelDeltaMoEConfig(compute_dtype=jnp.bfloat16)  # the published widths
+    assert (cfg.max_seq, cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+            cfg.rope_theta) == (16384, 32, 128, 64, 128, None)
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, shape in cd.stacks(cfg)["latent"][1].items()}
+    x = jax.ShapeDtypeStruct((1, cfg.max_seq, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        y = mf.latent_attention(cfg, x, lp, "nope_latent_attention", cfg.rope_theta)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), x, lp).as_text()
+    ops = _top_level(text)
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL, mh.FWD_KERNEL, mh.BWD_KERNEL):
+        assert len([o for o in ops if o[4] and o[0].startswith(kernel)]) == 1, kernel
+    for kernel in (fa.FWD_KERNEL, fa.BWD_KERNEL):
+        assert _kernel_operands(text, kernel)[:3] == [
+            "bf16[32,16384,192]", "bf16[32,16384,192]", "bf16[32,16384,128]"]

@@ -14,6 +14,7 @@ families' (no experts: no routed leaf among the gradient's readings, no
     python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --seeds ...
     python tools/latent_moe_precision.py --config sdar_30b_a3b_ep8 --fault unit_weights --seeds ...
     python tools/latent_moe_precision.py --config phi4_mini_flash_vp8 --seeds ...
+    python tools/latent_moe_precision.py --config kimi_linear_48b_ep32 --seeds ...
 
 For each seed, at the size of benchmark/configs/<config>.json (by default
 joyai_llm_flash_ep32.json) and with the benchmark's own state (``make_state`` from the seed as run.py folds
@@ -29,7 +30,8 @@ it), on the TPU:
   below     the same with the norms' statistics, the router's scores and
             weights and the softmax (and, where the configuration has a
             state-space scan, its step sizes, decay sums and states — a
-            Mamba-1 scan's Δ, decay and state; where it has differential
+            Mamba-1 scan's Δ, decay and state; where it has a delta rule, its
+            log-decay (a head's or a key channel's), decay and state; where it has differential
             attention, λ and the pair norm's statistics; where it
             has an exit gate, the gate, its distribution and entropy; where
             it states a float32 residual stream, that) in bf16: the nearest
