@@ -35,9 +35,13 @@ Parameters are stacked by kind (``delta``, ``latent``: the mixers; ``dense``,
 ``moe``: the MLPs), layer ``i`` takes the next entry of its mixer's stack and of
 its MLP's, and every mixer and every MLP is rebuilt in the backward pass on
 its own — the latent layer keeping its kernel's output and row statistics, a
-delta layer the rule's output (``gated_delta.CHANNEL_SAVED``: the rule has
-XLA's form alone so far, a block of heads at a time with each block rebuilt in
-its own backward pass, so a rebuilt layer that has o runs no forward of it).
+delta layer ``DELTA_KEPT`` of what ``gated_delta.CHANNEL_SAVED`` names: the
+rule's triangular inverse and its output where the kernels of
+``ops/kda_kernels.py`` run (a TPU, shapes that tile), so that a rebuilt layer
+does not call ``kda_chunk_inverse`` again (``kda_scan_fwd`` it does: the
+chunks' entering states are NOT kept, ``DELTA_KEPT`` says why); the output
+alone where XLA's form runs (a block of heads at a time, each block rebuilt in
+its own backward pass).
 The three input projections are ONE matrix, columns ``[q | k | v]``, and the
 three narrow ones another, ``[f↓ | g↓ | β]``: each of q, k, v is convolved from
 its own columns (``ops/causal_conv.conv_silu``).  The untied head is laid out
@@ -64,6 +68,13 @@ from byteps_tpu.parallel.moe import sigmoid_topk_route
 
 #: ``layer_types`` entry → the stack that holds that mixer's parameters
 MIXERS = {"channel_delta": "delta", "latent_attention": "latent"}
+#: what a rebuilt delta layer keeps of the rule: T (134 MB a layer at 32 heads
+#: and 16 384 tokens, f32) and o (268 MB, f32).  The chunks' entering states
+#: (268 MB in bf16) would save the rebuild's ``kda_scan_fwd`` (6.8 ms a layer),
+#: but with them ``kimi_linear_ep32_train16k``'s step is 13.49 GiB by the
+#: compiler's count and does not load beside the set-up's reference (12.61
+#: without; PERF.md §6 PR 69)
+DELTA_KEPT = (CHANNEL_SAVED[0], CHANNEL_SAVED[2])
 #: the step sizes the decays start at: log-uniform between these (``_init``; the
 #: published configuration has no key for them)
 DT_MIN, DT_MAX = 1e-3, 0.1
@@ -306,7 +317,7 @@ def _hidden(cfg: ChannelDeltaMoEConfig, params, tokens):
            "dense": residual(_dense_mlp), "moe": lambda x, lp: _moe_mlp(cfg, x, lp)}
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.compute_dtype)
-    return mf.walk(cfg, run, {"latent": mf.FLASH_SAVED, "delta": CHANNEL_SAVED}, params, x)
+    return mf.walk(cfg, run, {"latent": mf.FLASH_SAVED, "delta": DELTA_KEPT}, params, x)
 
 
 def local_logits(cfg: ChannelDeltaMoEConfig, params, tokens):

@@ -2,7 +2,9 @@
 ``ops/gated_delta_kernels.py``; the selective state-space scan,
 ``ops/ssd_kernels.py``) do to a chunk in VMEM, once: the f32 product, a
 per-token scalar turned between row and column by a masked sum (no
-transposes), and the decay matrix from a row of log-decays.
+transposes), the decay matrix from a row of log-decays, and the block rounds
+of a unit lower-triangular inverse (both forms of the delta rule:
+``ops/gated_delta_kernels.py``, ``ops/kda_kernels.py``).
 """
 
 from __future__ import annotations
@@ -53,3 +55,23 @@ def by_head(x):
     kernels read such scalars from (a few MB: XLA's)."""
     b, s, h = x.shape
     return jnp.moveaxis(x, 2, 1).reshape(b * h, s)
+
+
+def round_levels(rows, cols, strict, chunk):
+    """The round of :func:`inverse_rounds` that fills (i, j): the highest bit
+    in which i and j differ — the block of that size below the diagonal of
+    the square twice it; −1 where ``strict`` is not set."""
+    differ = rows ^ cols
+    return jnp.where(strict, sum((differ >= 2 ** s).astype(jnp.int32)
+                                 for s in range(1, chunk.bit_length() - 1)), -1)
+
+
+def inverse_rounds(a, level, eye, chunk):
+    """``(I + a)⁻¹`` of a stack of chunks, block-diagonal and strictly lower
+    inside a chunk, by the block rounds of ``gated_delta._inverse_by_blocks``
+    at f32 accuracy: with T = diag(P⁻¹, Q⁻¹) so far and L the block below,
+    T − T L T."""
+    inv = jnp.where(eye, 1.0, 0.0) - jnp.where(level == 0, a, 0.0)
+    for s in range(1, chunk.bit_length() - 1):
+        inv = inv - dot(dot(inv, jnp.where(level == s, a, 0.0), NN, EXACT), inv, NN, EXACT)
+    return inv

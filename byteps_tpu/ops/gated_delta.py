@@ -65,15 +65,23 @@ the chunk's system keeps its shape —
 — but ``A_ij = Σ_c k_ic k_jc e^{Γ_ic − Γ_jc}`` (B the same with q_i) does not
 factor into ``D_ij (k_i·k_j)``: the decay sits inside the contraction.  As a
 matrix product it is ``(k_i ⊙ e^{Γ_i − Γ_r}) · (k_j ⊙ e^{Γ_r − Γ_j})`` for a
-reference row r, and both exponents are ≤ 0 only where j ≤ r ≤ i.  So
-(:func:`_chunked_channel_xla`) a chunk is cut into sub-blocks of
-``SUB_CHUNK`` rows (half a chunk where that is fewer): a sub-block BELOW the
-diagonal takes r at its rows' first and is a matrix product; a sub-block ON the diagonal is summed pair by pair,
-``Σ_c x_ic k_jc e^{Γ_ic − Γ_jc}`` over i ≥ j alone, ``PAIRWISE_CHUNKS`` chunks'
-sub-blocks at a time (a ``lax.map`` over the chunks, rebuilt in the backward
-pass: the sub × sub × d_k terms of a few chunks stand, never a sequence's).  The promise
-above holds: every exponent is of a non-positive number.  No kernel is
-written for this form yet: it is XLA's everywhere.
+reference row r, and both exponents are ≤ 0 only where j ≤ r ≤ i.  So a
+chunk is cut into sub-blocks of ``SUB_CHUNK`` rows (half a chunk where that is
+fewer): a sub-block BELOW the diagonal takes r at its rows' first and is a
+matrix product; a sub-block ON the diagonal is summed pair by pair,
+``Σ_c x_ic k_jc e^{Γ_ic − Γ_jc}`` over i ≥ j alone.  The promise above holds:
+every exponent is of a non-positive number.
+
+This form too has two implementations, chosen inside the ``g.ndim == q.ndim``
+branch by the same :func:`_kernel_path`: on a TPU at shapes that tile, the
+Pallas kernels of ``ops/kda_kernels.py`` (``kda_chunk_inverse``,
+``kda_scan_fwd``, ``kda_scan_bwd``: the sub-blocks are built in VMEM, nothing
+of a chunk's sub × sub × d_k terms is ever written; T, the entering states
+and o carry the names in ``CHANNEL_SAVED``); everywhere else XLA's form
+(:func:`_chunked_channel_xla` under :func:`_by_head_blocks`: ``HEAD_BLOCK``
+heads at a time, the diagonal sub-blocks of ``PAIRWISE_CHUNKS`` chunks at a
+time in a ``lax.map`` rebuilt in the backward pass — the terms of a few
+chunks stand, never a sequence's), the kernels' oracle beside the recurrence.
 """
 
 from __future__ import annotations
@@ -90,27 +98,30 @@ from jax.ad_checkpoint import checkpoint_name
 from byteps_tpu.core.telemetry import counters
 from byteps_tpu.ops._dispatch import LANES, kernels_run, tuned
 from byteps_tpu.ops.gated_delta_kernels import STACK, gated_delta_kernels
+from byteps_tpu.ops.kda_kernels import SAVED as CHANNEL_SAVED  # noqa: F401 (callers' policies)
+from byteps_tpu.ops.kda_kernels import kda_kernels
 
 CHUNK = 64
 #: rows of a sub-block of the channel form: below the diagonal a sub-block is a
 #: matrix product, on it a sum pair by pair
 SUB_CHUNK = 16
-#: heads the channel form takes at a time (XLA's form keeps a dozen f32 arrays
+#: heads XLA's channel form takes at a time (it keeps a dozen f32 arrays
 #: of q's size for its backward pass: at 32 heads of 128 and 16 384 tokens 4.6
 #: GiB all heads at once, PERF.md §6 PR 68)
 HEAD_BLOCK = 8
-#: chunks whose diagonal sub-blocks are summed pair by pair at a time: their
+#: chunks whose diagonal sub-blocks XLA's form sums pair by pair at a time: their
 #: sub × sub × d_k terms stand together (64 MiB in f32 for 8 heads of 128), and
 #: a sequence's 256 chunks are 16 turns of a loop, not 256
 PAIRWISE_CHUNKS = 16
-#: the ``checkpoint_name`` of what the channel form leaves for a caller's
-#: ``jax.checkpoint`` policy to keep: the rule's output o, f32.  Kept, a rebuilt
-#: layer does not run the rule's forward pass again for its value — the blocks
-#: of heads rebuild themselves in their own backward pass (:func:`_by_head_blocks`)
-CHANNEL_SAVED = ("gdn_channel_out",)
+# ``CHANNEL_SAVED`` (``kda_kernels.SAVED``): the ``checkpoint_name``s of what the
+# channel form leaves for a caller's ``jax.checkpoint`` policy to keep — the
+# kernels' T, entering states and o (f32); XLA's form names its o by the last
+# alone (its blocks of heads rebuild themselves in their own backward pass).
+# Kept, a rebuilt layer runs no forward of the rule again.
 
 #: chunks a grid step of the three kernels (inverse, forward, backward) by
-#: sequence length: tools/gdn_tune.py's sweep on the chip, as
+#: sequence length, of the scalar form (``blocks``) and of the channel form
+#: (``channel_blocks``): tools/gdn_tune.py's sweeps on the chip, as
 #: ops/flash_blocks.json is tools/flash_tune.py's
 _TUNED_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "gdn_blocks.json")
 _DEFAULT_BLOCKS = (8, 8, 8)
@@ -204,19 +215,23 @@ def _kernel_path(chunk: int, dk: int, dv: int, interpret: bool) -> bool:
 
 
 def _sections(doc: dict) -> dict:
-    return {int(s): tuple(b) for s, b in doc["blocks"].items()}
+    """(a decay a channel?, tokens) → the three kernels' chunks a grid step."""
+    return {(channel, int(s)): tuple(b)
+            for channel, section in ((False, "blocks"), (True, "channel_blocks"))
+            for s, b in doc.get(section, {}).items()}
 
 
 def _tuned_table() -> dict:
     return tuned(_TUNED_PATH, _sections)
 
 
-def tuned_blocks(n_chunks: int, chunk: int, blocks: Optional[Sequence[int]] = None) -> tuple:
-    """Chunks a grid step for (inverse, forward, backward): the caller's, or
-    the table's entry for this sequence, or the default — each brought down
-    to a power of two that divides the sequence's chunks, the first kept a
-    whole number of stacks."""
-    wanted = tuple(blocks or _tuned_table().get(n_chunks * chunk, _DEFAULT_BLOCKS))
+def tuned_blocks(n_chunks: int, chunk: int, blocks: Optional[Sequence[int]] = None,
+                 channel: bool = False) -> tuple:
+    """Chunks a grid step for (inverse, forward, backward) of the scalar form's
+    kernels or the ``channel`` form's: the caller's, or the table's entry for
+    this sequence, or the default — each brought down to a power of two that
+    divides the sequence's chunks, the first kept a whole number of stacks."""
+    wanted = tuple(blocks or _tuned_table().get((channel, n_chunks * chunk), _DEFAULT_BLOCKS))
     per_stack = max(STACK // chunk, 1)
     if n_chunks % per_stack:
         raise ValueError(f"gated delta kernels: {n_chunks} chunks of {chunk} are no whole "
@@ -255,9 +270,15 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, compute_dtype
         if hv != hk or g.shape != q.shape or not sub or chunk % sub:
             raise ValueError(f"gated delta rule, a decay a channel: g {g.shape} beside q "
                              f"{q.shape}, v {v.shape}; sub-blocks of {sub} in a chunk of {chunk}")
-        counters().bump("gdn_channel_xla_traces")
-        return checkpoint_name(_by_head_blocks(q, k, v, g, beta, chunk, sub, cdt),
-                               CHANNEL_SAVED[0])
+        if not _kernel_path(chunk, q.shape[-1], v.shape[-1], interpret):
+            counters().bump("gdn_channel_xla_traces")
+            return checkpoint_name(_by_head_blocks(q, k, v, g, beta, chunk, sub, cdt),
+                                   CHANNEL_SAVED[-1])
+        counters().bump("gdn_channel_kernel_traces")
+        return kda_kernels(
+            q.astype(cdt), k.astype(cdt), v.astype(cdt), g.astype(jnp.float32),
+            beta.astype(jnp.float32), chunk,
+            tuned_blocks(s // chunk, chunk, blocks, channel=True), interpret)
     if not _kernel_path(chunk, q.shape[-1], v.shape[-1], interpret):
         counters().bump("gdn_xla_traces")
         return _chunked_xla(q, k, v, g, beta, chunk, cdt)

@@ -56,8 +56,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from byteps_tpu.ops._chunk import (EXACT, F32, NN, NT, TN, by_head, column, decays, dot, iotas,
-                                   row, total)
+from byteps_tpu.ops._chunk import (EXACT, F32, NN, NT, TN, by_head, column, decays, dot,
+                                   inverse_rounds, iotas, round_levels, row, total)
 from byteps_tpu.ops._dispatch import vma_union
 
 #: the kernels' names: a trace files their time under these (none starts
@@ -89,23 +89,14 @@ def _inverse_kernel(chunk, w, groups, r):
         rows, cols = iotas(w)
         same = (rows // chunk) == (cols // chunk)
         seen, strict, eye = same & (rows >= cols), same & (rows > cols), rows == cols
-        # the round that fills (i, j): the highest bit in which i and j differ
-        # — the block of that size below the diagonal of the square twice it
-        differ = rows ^ cols
-        level = jnp.where(strict, sum((differ >= 2 ** s).astype(jnp.int32)
-                                      for s in range(1, chunk.bit_length() - 1)), -1)
+        level = round_levels(rows, cols, strict, chunk)
 
         def group(i, carry):
             k = k_ref[0, pl.ds(pl.multiple_of(i * w, w), w), :]
             kk = dot(k, k, NT)
             for h in range(r):
                 _, decay = decays(g_ref[h, i], seen, eye)
-                a = column(b_ref[h, i], eye) * decay * kk
-                # with T = diag(P⁻¹, Q⁻¹) so far and L the block below: T − T L T
-                inv = jnp.where(eye, 1.0, 0.0) - jnp.where(level == 0, a, 0.0)
-                for s in range(1, chunk.bit_length() - 1):
-                    inv = inv - dot(dot(inv, jnp.where(level == s, a, 0.0), NN, EXACT),
-                                    inv, NN, EXACT)
+                inv = inverse_rounds(column(b_ref[h, i], eye) * decay * kk, level, eye, chunk)
                 for j in range(per):
                     t_ref[h, i * per + j] = inv[j * chunk:(j + 1) * chunk,
                                                 j * chunk:(j + 1) * chunk]

@@ -1,6 +1,7 @@
 """``ops/gated_delta.py`` on its own, on the CPU: the chunked gated delta rule
 — XLA's form at toy widths, the Pallas kernels in the interpreter at shapes
-that tile — against the token-by-token recurrence, which form a call takes
+that tile, with a decay a head (``ops/gated_delta_kernels.py``) and a decay a
+key channel (``ops/kda_kernels.py``) — against the token-by-token recurrence, which form a call takes
 (``_kernel_path`` over ``_dispatch.kernels_run``) and how it is counted, the
 chunks a grid step, what the rule refuses, and the triangular inverse.  (The
 family's other pieces: tests/test_delta_moe_pieces.py, which held these cases
@@ -15,6 +16,7 @@ import pytest
 from byteps_tpu.models import delta_moe as dm
 from byteps_tpu.ops import _dispatch
 from byteps_tpu.ops import gated_delta as gd
+from byteps_tpu.ops import kda_kernels as kk
 
 
 # ---------------------------------------------------------------------------
@@ -115,42 +117,74 @@ _CHANNEL_CASES = {
     "8x4": dict(chunk=8),  # a short chunk: two sub-blocks of half of it
     # 16 heads: two blocks of HEAD_BLOCK heads, one after another
     "64x16-16_heads": dict(chunk=64, shape=dict(b=1, h=16, s=64)),
+    # the Pallas kernels of ops/kda_kernels.py in the interpreter (``blocks``:
+    # chunks a grid step), at shapes that tile; two batches of two heads in one
+    # call (an index map that took one for another would read another's
+    # numbers), the state crossing grid steps, d_v ≠ d_k, a chunk a grid step
+    "kernels-64": dict(chunk=64, blocks=(2, 2, 2), shape=dict(b=2, h=2, s=256, dk=128, dv=128)),
+    "kernels-128": dict(chunk=128, blocks=(2, 2, 2), shape=dict(b=1, h=2, s=512, dk=128, dv=128)),
+    "kernels-64-wide-v": dict(chunk=64, blocks=(2, 1, 1),
+                              shape=dict(b=1, h=2, s=128, dk=128, dv=256)),
+    "kernels-64-one-step": dict(chunk=64, blocks=(2, 2, 2),
+                                shape=dict(b=1, h=3, s=128, dk=128, dv=128)),
 }
+
+
+def _channel_rule(chunk, blocks=None):
+    """The chunked rule a case names: XLA's form, or with ``blocks`` the
+    kernels in the interpreter."""
+    how = dict(interpret=True, blocks=blocks) if blocks else {}
+    return lambda *a: gd.chunked_gated_delta_rule(*a, chunk=chunk, **how)
+
+
+def _channel_xla(chunk):
+    """XLA's channel form whatever the shapes: the kernels' other oracle."""
+    return lambda *a: gd._by_head_blocks(*a, chunk, min(gd.SUB_CHUNK, chunk // 2), jnp.float32)
 
 
 @pytest.mark.parametrize("decay", [1e-4, 1.0, 40.0], ids=["near_one", "middling", "near_zero"])
 @pytest.mark.parametrize("case", list(_CHANNEL_CASES))
 def test_channel_rule_is_the_recurrence(decay, case):
     """Values and all five gradients of the chunked form with a decay a
-    channel against the token-by-token recurrence."""
+    channel against the token-by-token recurrence; the kernels also against
+    XLA's channel form (two chunked forms, each within 1e-4 of the recurrence,
+    stand within 2e-4 of each other)."""
     case = dict(_CHANNEL_CASES[case])
     args = _channel_inputs(decay, **case.pop("shape", {}))
-    rule = lambda *a: gd.chunked_gated_delta_rule(*a, **case)  # noqa: E731
+    kernels = "blocks" in case
+    rule = _channel_rule(**case)
+    oracles = [(gd.gated_delta_recurrence, 1e-4)] + (
+        [(_channel_xla(case["chunk"]), 2e-4)] if kernels else [])
     got = jax.jit(rule)(*args)
-    want = jax.jit(gd.gated_delta_recurrence)(*args)
-    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()) + 1e-7)
     weigh = jnp.cos(jnp.arange(got.size, dtype=jnp.float32)).reshape(got.shape)
 
     def gradients(fn):
         return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * weigh), argnums=(0, 1, 2, 3, 4)))(*args)
 
-    for name, got_g, want_g in zip("q k v g beta".split(), gradients(rule),
-                                   gradients(gd.gated_delta_recurrence)):
-        assert np.all(np.isfinite(got_g)), name
-        scale = float(jnp.abs(want_g).max())
-        np.testing.assert_allclose(got_g, want_g, atol=1e-4 * scale + 1e-9, err_msg=name)
+    grads = gradients(rule)
+    for oracle, atol in oracles:
+        want = jax.jit(oracle)(*args)
+        np.testing.assert_allclose(
+            got, want, atol=(1e-5 if kernels else 2e-6) * float(jnp.abs(want).max()) + 1e-7)
+        for name, got_g, want_g in zip("q k v g beta".split(), grads, gradients(oracle)):
+            assert np.all(np.isfinite(got_g)), name
+            scale = float(jnp.abs(want_g).max())
+            np.testing.assert_allclose(got_g, want_g, atol=atol * scale + 1e-9, err_msg=name)
 
 
-def test_channel_rule_takes_no_positive_exponent():
+@pytest.mark.parametrize("implementation", ["xla", "kernels"])
+def test_channel_rule_takes_no_positive_exponent(implementation):
     """Decays of −12 a token a channel: over a sub-block of 16 rows a
     reference row at its START would need e^{+180}, which f32 does not hold,
     and over a chunk e^{+756}.  Every exponential the chunked form takes is of
     a non-positive number: every intermediate is finite (``jax_debug_nans``
-    and ``jax_debug_infs`` stop the first that is not), values and gradients
-    agree with the recurrence to f32 rounding."""
-    args = _channel_inputs(12.0, constant=True, b=1, h=2, s=128)
-    rule = lambda *a: gd.chunked_gated_delta_rule(*a, chunk=64)  # noqa: E731
-    weigh = jnp.cos(jnp.arange(128 * 2 * 6, dtype=jnp.float32)).reshape(1, 128, 2, 6)
+    and ``jax_debug_infs`` stop the first that is not — in the interpreter the
+    kernels' too), values and gradients agree with the recurrence to f32
+    rounding."""
+    shape, blocks = (dict(dk=128, dv=128), (2, 1, 1)) if implementation == "kernels" else ({}, None)
+    args = _channel_inputs(12.0, constant=True, b=1, h=2, s=128, **shape)
+    rule = _channel_rule(64, blocks)
+    weigh = jnp.cos(jnp.arange(args[2].size, dtype=jnp.float32)).reshape(args[2].shape)
     loss = lambda fn: (lambda *a: jnp.sum(fn(*a) * weigh))  # noqa: E731
     with jax.debug_nans(True), jax.debug_infs(True):
         got = rule(*args)
@@ -170,16 +204,20 @@ def test_channel_rule_takes_no_positive_exponent():
             1e-4 * float(jnp.abs(want_g).max()) + 1e-9))
 
 
+@pytest.mark.parametrize("implementation", ["xla", "kernels"])
 @pytest.mark.parametrize("decay", [0.05, 3.0])
-def test_a_decay_constant_over_channels_is_the_scalar_rule(decay):
+def test_a_decay_constant_over_channels_is_the_scalar_rule(decay, implementation):
     """g broadcast over the key's channels: the channel form, the scalar
-    chunked form and both recurrences give one answer to rounding."""
-    q, k, v, g, beta = _rule_inputs(decay, b=1, hk=2, r=1, s=128)
+    chunked form and both recurrences give one answer to rounding — XLA's two
+    forms, and the two sets of kernels in the interpreter."""
+    shape, how, atol = ((dict(dk=128, dv=128), dict(interpret=True, blocks=(2, 2, 2)), 1e-5)
+                        if implementation == "kernels" else ({}, {}, 2e-6))
+    q, k, v, g, beta = _rule_inputs(decay, b=1, hk=2, r=1, s=128, **shape)
     wide = jnp.broadcast_to(g[..., None], q.shape)
-    scalar = gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=64)
-    channel = gd.chunked_gated_delta_rule(q, k, v, wide, beta, chunk=64)
+    scalar = gd.chunked_gated_delta_rule(q, k, v, g, beta, chunk=64, **how)
+    channel = gd.chunked_gated_delta_rule(q, k, v, wide, beta, chunk=64, **how)
     scale = float(jnp.abs(scalar).max())
-    np.testing.assert_allclose(channel, scalar, atol=2e-6 * scale)
+    np.testing.assert_allclose(channel, scalar, atol=atol * scale)
     np.testing.assert_allclose(gd.gated_delta_recurrence(q, k, v, wide, beta),
                                gd.gated_delta_recurrence(q, k, v, g, beta), atol=1e-7 * scale)
 
@@ -223,6 +261,43 @@ def test_channel_form_is_counted_and_refuses_what_it_cannot_cut():
         gd.chunked_gated_delta_rule(q[:, :, :1], k[:, :, :1], v, g, beta, chunk=16)
     with pytest.raises(ValueError, match="does not divide"):
         gd.chunked_gated_delta_rule(*(x[:, :24] for x in args), chunk=16)
+
+
+def test_the_channel_path_is_chosen_from_platform_and_shapes(monkeypatch):
+    """The scalar form's one function decides for a decay a channel too: the
+    kernels of ops/kda_kernels.py in the interpreter and on a (stand-in) TPU at
+    whole tiles, XLA's form off a TPU and at shapes that do not tile; each
+    traced call counted under its own name, never under the scalar form's."""
+    from byteps_tpu.core.telemetry import counters
+
+    def grown(fn, *args):
+        before = counters().snapshot()
+        text = str(jax.make_jaxpr(fn)(*args))
+        after = counters().snapshot()
+        return text, {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+
+    tiling = _channel_inputs(1.0, b=1, h=2, s=256, dk=128, dv=128)
+    rule = lambda *a: gd.chunked_gated_delta_rule(*a, chunk=64)  # noqa: E731
+    text, counted = grown(rule, *tiling)
+    assert "pallas_call" not in text and counted == {"gdn_channel_xla_traces": 1}  # this is a CPU
+    text, counted = grown(_channel_rule(64, (2, 2, 2)), *tiling)
+    assert text.count("pallas_call") == 2 and counted == {"gdn_channel_kernel_traces": 1}
+    text, counted = grown(jax.grad(lambda *a: jnp.sum(_channel_rule(64, (2, 2, 2))(*a))), *tiling)
+    assert all(name in text for name in kk.SAVED)
+    assert [text.count(name) for name in (kk.INVERSE_KERNEL, kk.FWD_KERNEL, kk.BWD_KERNEL)] == [
+        1, 1, 1] and counted == {"gdn_channel_kernel_traces": 1}
+    monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
+    # (a new function: jax keeps a trace by the function traced; nothing is
+    # lowered on this CPU)
+    text, counted = grown(lambda *a: rule(*a), *tiling)
+    assert text.count("pallas_call") == 2 and counted == {"gdn_channel_kernel_traces": 1}
+    for chunk, shape in [(32, dict(dk=128, dv=128)), (64, dict(dk=64, dv=128)),
+                         (64, dict(dk=128, dv=96)), (8, {})]:
+        toy = _channel_inputs(1.0, b=1, h=2, s=256, **shape)
+        text, counted = grown(lambda *a: gd.chunked_gated_delta_rule(*a, chunk=chunk), *toy)
+        assert "pallas_call" not in text and counted == {"gdn_channel_xla_traces": 1}, (chunk, shape)
+    assert gd.tuned_blocks(256, 64, channel=True) == gd.tuned_blocks(
+        256, 64, gd._tuned_table()[True, 16384])  # the channel form's own entry of the table
 
 
 def _traces():

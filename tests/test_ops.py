@@ -1483,6 +1483,14 @@ def _delta_family():
         q, k, v, g, beta, chunk=64, interpret=True))), (q, q, v, g, -g)
 
 
+def _channel_delta_family():
+    from byteps_tpu.ops import gated_delta as gd
+
+    q = jnp.ones((1, 128, 2, 128), jnp.float32)
+    return (lambda q, k, v, g, beta: jnp.sum(gd.chunked_gated_delta_rule(
+        q, k, v, g, beta, chunk=64, interpret=True))), (q, q, q, -q, q[..., 0])
+
+
 def _ssd_family():
     from byteps_tpu.ops import ssd
 
@@ -1542,6 +1550,10 @@ FROZEN_KERNELS = {
     "gdn_chunk_inverse": (_delta_family, "d774a47cbde0deb6"),
     "gdn_scan_fwd": (_delta_family, "fda07b824fb7a6f1"),
     "gdn_scan_bwd": (_delta_family, "9d43e1a5acde0992"),
+    # taken when ISSUE 69 wrote the three kernels
+    "kda_chunk_inverse": (_channel_delta_family, "7cd1ca8e672166cd"),
+    "kda_scan_fwd": (_channel_delta_family, "fa133d8202b56221"),
+    "kda_scan_bwd": (_channel_delta_family, "35defe9fa6f48384"),
     # taken when ISSUE 62 wrote the two kernels
     "ssd_scan_fwd": (_ssd_family, "8a92173852f35429"),
     "ssd_scan_bwd": (_ssd_family, "b8f29c716357b833"),

@@ -575,33 +575,50 @@ def test_selective_scan_keeps_its_state_out_of_the_hbm_a_token(one_chip, no_comp
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
-def test_channel_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch):
+@pytest.mark.parametrize("implementation", ["kernels", "xla"])
+def test_channel_delta_rule_compiles_at_published_widths(one_chip, no_compile_cache, monkeypatch,
+                                                         implementation):
     """Kimi Delta Attention's rule for one sequence of 16 384 tokens, 32 heads
     of 128 | 128 token-major, a log-decay a key channel in f32, chunks of 64 in
-    sub-blocks of 16, bf16 operands: the rule and its five gradients in XLA's
-    form — there is no kernel for it yet, on a TPU either —, a block of
-    ``HEAD_BLOCK`` heads at a time.  What decides whether the cell fits: all 32
-    heads at once, the backward pass kept 4.57 GiB of temporaries (a dozen f32
-    arrays of q's size; PERF.md §6 PR 68) and the step 17.8 GiB of the chip's
-    15.75; by blocks of 8 it reads 2.4 GiB and is held under 3 here.  Nothing of a chunk's
-    sub × sub × d_k terms stands for a whole sequence (4.3 GB at f32): the
-    largest f32 array is q's size."""
+    sub-blocks of 16, bf16 operands: the rule and its five gradients.
+    ``kernels`` is the path a TPU takes at these shapes — the compiled module
+    holds the three Pallas kernels of ops/kda_kernels.py, no scan over the
+    chunks is left to XLA, and what the backward pass keeps (T, the entering
+    states, g and the cotangents around them) stays under 2 GiB.  ``xla`` is the
+    chunked form that stays their oracle, a block of ``HEAD_BLOCK`` heads at a
+    time: all 32 heads at once, its backward pass kept 4.57 GiB of temporaries
+    (a dozen f32 arrays of q's size; PERF.md §6 PR 68) and the step 17.8 GiB of
+    the chip's 15.75; by blocks of 8 it reads 2.4 GiB and is held under 3 here.
+    Nothing of a chunk's sub × sub × d_k terms stands for a whole sequence (4.3
+    GB at f32) in either: the largest f32 array is q's size."""
     from byteps_tpu.ops import gated_delta as gd
+    from byteps_tpu.ops import kda_kernels as kk
 
     monkeypatch.setattr(_dispatch, "platform", lambda: "tpu")
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         dims, dtype, sharding=one_chip)
     s, h, d = 16384, 32, 128
+    rule = (functools.partial(gd.chunked_gated_delta_rule, compute_dtype=jnp.bfloat16)
+            if implementation == "kernels"
+            else lambda *a: gd._by_head_blocks(*a, gd.CHUNK, gd.SUB_CHUNK, jnp.bfloat16))
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(gd.chunked_gated_delta_rule(q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+        return jnp.sum(rule(q, k, v, g, beta))
 
     args = (shape(1, s, h, d), shape(1, s, h, d), shape(1, s, h, d),
             shape(1, s, h, d, dtype=jnp.float32), shape(1, s, h, dtype=jnp.float32))
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
     text = compiled.as_text()
-    assert "while" in text and "tpu_custom_call" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    if implementation == "kernels":
+        for kernel in (kk.INVERSE_KERNEL, kk.FWD_KERNEL, kk.BWD_KERNEL):
+            assert kernel in text, f"{kernel} is not in the compiled program"
+        assert text.count("tpu_custom_call") == 3 and "while" not in text
+        forward = _compile(loss, *args).as_text()
+        assert kk.FWD_KERNEL in forward and kk.BWD_KERNEL not in forward
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    else:
+        assert "while" in text and "tpu_custom_call" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
     widest = max(_bytes_of(m.group(0)) for m in re.finditer(r"f32\[[\d,]*\]", text))
     assert widest <= 4 * s * h * d
 

@@ -19,6 +19,15 @@ timing says nothing of Mosaic.
 
 (``--shape``: batch, key heads, value heads, sequence, d_k, d_v.)
 
+``--channel`` sizes the rule with a decay a KEY CHANNEL instead
+(ops/kda_kernels.py; g (B, S, H, d_k), as many key heads as value heads) at
+the shape one layer of ``kimi_linear_48b_ep32`` runs: XLA's channel form (a
+block of heads at a time), the three ``kda_*`` kernels, and
+``models/channel_delta_moe._delta_scan`` around them; the winners go to the
+table's ``channel_blocks`` | ``channel_meta``:
+
+    python tools/gdn_tune.py --channel --shape 1,32,32,16384,128,128
+
 ``--conv`` times the pass BEFORE the rule instead (ops/causal_conv.py:
 depthwise causal convolution + bias + silu + a head's l2 norm, taken from a
 column range of a projection's output): forward + every gradient of
@@ -106,6 +115,8 @@ def main() -> int:
                     help="rows x lanes of a block x rows of a chunk to try")
     ap.add_argument("--shape", default="1,16,32,16384,128,128",
                     help="batch, key heads, value heads, sequence, d_k, d_v")
+    ap.add_argument("--channel", action="store_true",
+                    help="the rule with a decay a key channel (ops/kda_kernels.py)")
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--blocks", default="4,8,16", help="chunks a grid step to try")
     ap.add_argument("--steps", type=int, default=10)
@@ -119,9 +130,12 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
+    from byteps_tpu.models import channel_delta_moe as cdm
     from byteps_tpu.models import delta_moe as dm
     from byteps_tpu.ops import gated_delta as gd
-    from byteps_tpu.ops import gated_delta_kernels as gk
+    from byteps_tpu.ops import gated_delta_kernels, kda_kernels
+
+    gk = kda_kernels if args.channel else gated_delta_kernels
 
     device = jax.devices()[0]
     if device.platform != "tpu" and not args.rehearse:
@@ -143,7 +157,8 @@ def main() -> int:
     q = (unit(jax.random.normal(ks[0], (b, s, hk, dk))) * dk ** -0.5).astype(cdt)
     k = unit(jax.random.normal(ks[1], (b, s, hk, dk))).astype(cdt)
     v = jax.random.normal(ks[2], (b, s, hv, dv)).astype(cdt)
-    g = -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, s, hv)))
+    g = -0.1 * jax.nn.softplus(jax.random.normal(
+        ks[3], (b, s, hv) + ((dk,) if args.channel else ())))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hv)))
 
     def ms(fn, *xs):
@@ -156,7 +171,7 @@ def main() -> int:
     # each kernel alone, on what the kernels take: q, k, v token-major with
     # the heads side by side, g and beta a row a value head
     flat = (q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk), v.reshape(b, s, hv * dv),
-            gk.by_head(g), gk.by_head(beta))
+            g.reshape(b, s, hv * dk) if args.channel else gk.by_head(g), gk.by_head(beta))
     t = jax.jit(lambda k, g, beta: gk._chunk_inverse(k, g, beta, hk, chunk, sizes[0], interpret))(
         flat[1], flat[3], flat[4])
     _, entering = jax.jit(lambda *a: gk._scan_forward(*a, hk, chunk, sizes[0], True, interpret))(
@@ -175,28 +190,46 @@ def main() -> int:
     best = tuple(min(by_kernel[name], key=by_kernel[name].get)
                  for name in (gk.INVERSE_KERNEL, gk.FWD_KERNEL, gk.BWD_KERNEL))
 
-    xla_ms = ms(whole(lambda *a: gd._chunked_xla(*a, chunk, cdt)), q, k, v, g, beta)
+    xla_form = ((lambda *a: gd._by_head_blocks(*a, chunk, gd.SUB_CHUNK, cdt)) if args.channel
+                else (lambda *a: gd._chunked_xla(*a, chunk, cdt)))
+    xla_ms = ms(whole(xla_form), q, k, v, g, beta)
     kernels_ms = ms(whole(lambda *a: gd.chunked_gated_delta_rule(
         *a, chunk=chunk, compute_dtype=cdt, interpret=interpret, blocks=best)), q, k, v, g, beta)
 
-    # the mixer's whole gdn_scan part around the rule, the kernels at the
-    # committed table's blocks (what a train step takes)
-    cfg = dm.DeltaMoEConfig(lin_k_heads=hk, lin_v_heads=hv, lin_k_dim=dk, lin_v_dim=dv,
-                            chunk=chunk, compute_dtype=cdt, max_seq=s)
-    lp = {"conv": jax.random.normal(ks[6], (cfg.conv_kernel, cfg.lin_channels)) * 0.5,
-          "a_log": jnp.zeros((hv,)), "dt_bias": jnp.ones((hv,)), "gdn_norm": jnp.ones((dv,))}
-    qkvz = jax.random.normal(ks[7], (b, s, cfg.lin_channels + hv * dv)).astype(cdt)
-    ba = jax.random.normal(ks[8], (b, s, 2 * hv))
+    # the mixer's whole gdn_scan | kda_scan part around the rule, the kernels at
+    # the committed table's blocks (what a train step takes)
     # (a rehearsal's mixer takes XLA's form of the rule: _kernel_path's call)
-    mixer_ms = ms(jax.value_and_grad(
-        lambda qkvz, ba, lp: jnp.sum(dm._delta_scan(cfg, qkvz, ba, lp).astype(jnp.float32) ** 2),
-        argnums=(0, 1, 2)), qkvz, ba, lp)
+    if args.channel:
+        cfg = cdm.ChannelDeltaMoEConfig(lin_heads=hv, lin_k_dim=dk, lin_v_dim=dv, chunk=chunk,
+                                        compute_dtype=cdt, max_seq=s)
+        lp = {"conv": jax.random.normal(ks[6], (cfg.conv_kernel, cfg.lin_channels)) * 0.5,
+              "a_log": jnp.zeros((hv,)), "dt_bias": jnp.zeros((hv * dk,)),
+              "o_norm": jnp.ones((dv,))}
+        qkv = jax.random.normal(ks[7], (b, s, cfg.lin_channels)).astype(cdt)
+        narrow = jax.random.normal(ks[8], (b, s, hv * (dk + dv + 1)))
+        mixer_ms = ms(jax.value_and_grad(
+            lambda qkv, narrow, lp: jnp.sum(cdm._delta_scan(
+                cfg, qkv, narrow[..., :hv * dk] - 3.0, narrow[..., hv * dk:hv * (dk + dv)],
+                narrow[..., hv * (dk + dv):], lp).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2)), qkv, narrow, lp)
+    else:
+        cfg = dm.DeltaMoEConfig(lin_k_heads=hk, lin_v_heads=hv, lin_k_dim=dk, lin_v_dim=dv,
+                                chunk=chunk, compute_dtype=cdt, max_seq=s)
+        lp = {"conv": jax.random.normal(ks[6], (cfg.conv_kernel, cfg.lin_channels)) * 0.5,
+              "a_log": jnp.zeros((hv,)), "dt_bias": jnp.ones((hv,)), "gdn_norm": jnp.ones((dv,))}
+        qkvz = jax.random.normal(ks[7], (b, s, cfg.lin_channels + hv * dv)).astype(cdt)
+        ba = jax.random.normal(ks[8], (b, s, 2 * hv))
+        mixer_ms = ms(jax.value_and_grad(
+            lambda qkvz, ba, lp: jnp.sum(
+                dm._delta_scan(cfg, qkvz, ba, lp).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2)), qkvz, ba, lp)
 
     line = {
         "device": f"{device.platform}:{device.device_kind}", "rehearsal": args.rehearse,
         "shape": [b, hk, hv, s, dk, dv], "chunk": chunk, "dtype": jnp.dtype(cdt).name,
         "what": "forward + every gradient of sum(o**2), ms a call; by_kernel: one kernel "
-                "alone; mixer_ms: models/delta_moe._delta_scan, the rule inside it",
+                "alone; mixer_ms: the family's _delta_scan, the rule inside it",
+        "channel": args.channel,
         "xla_ms": xla_ms, "kernels_ms": kernels_ms, "mixer_ms": mixer_ms,
         "around_kernels_ms": round(mixer_ms - kernels_ms, 3), "blocks": list(best),
         "by_kernel": {name: {str(nb): t_ms for nb, t_ms in times.items()}
@@ -209,8 +242,9 @@ def main() -> int:
                 doc = json.load(f)
         except (OSError, ValueError):
             doc = {}
-        doc.setdefault("blocks", {})[str(s)] = list(best)
-        doc.setdefault("meta", {})[str(s)] = {k_: line[k_] for k_ in (
+        section, meta = ("channel_blocks", "channel_meta") if args.channel else ("blocks", "meta")
+        doc.setdefault(section, {})[str(s)] = list(best)
+        doc.setdefault(meta, {})[str(s)] = {k_: line[k_] for k_ in (
             "shape", "chunk", "dtype", "xla_ms", "kernels_ms", "mixer_ms", "by_kernel")}
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         with open(path, "w") as f:
