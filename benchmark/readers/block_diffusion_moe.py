@@ -4,12 +4,6 @@ trace and to the counters (byteps_tpu/models/block_diffusion_moe.py, the
 program without these scopes, kernels or counters (the parent of the PR that
 brought them), and a run without a TPU trace, read None everywhere.
 
-The eight metrics that read through it are defined in ``benchmark/unlisted/``
-(their entries in ``per_layer.block_diffusion_moe.json`` there: a file of its
-own, because a PR may edit no file the benchmark has) and not listed in
-``BENCHMARK.json`` while its ``per_layer`` stands at its cap of 128 entries
-(ROADMAP R0).
-
 ``scope_ms``: self time a traced step of device 0's operations filed under
 the scope ``match`` — forward, recomputation and backward together.  An
 operation is filed under the FIRST of ``SCOPES`` that its scope path has as
@@ -28,9 +22,9 @@ whatever tiles compute them — from the shapes in the operation's own HLO line
 and the ``block_length`` of the configuration the metric's file names
 (``config``: ``benchmark/configs/<config>.json``, the file the cell runs).
 
-``counter_per_step`` | ``counter_share``: growth of ``counter`` over the window
-a completed step | as % of ``of``'s growth; a counter the program does not
-keep reads None.
+``counter_per_step``: growth of ``counter`` over the window a completed step;
+a counter the program does not keep reads None.  (The family's three routing
+counters are read by ``latent_moe``'s reader, as the other families' are.)
 """
 
 from __future__ import annotations
@@ -132,19 +126,12 @@ def measure(trace: dict, quantity: str, match: str = "", peaks: dict | None = No
     raise ValueError(f"block_diffusion_moe reader has no quantity {quantity!r}")
 
 
-def _grown(run: dict, counter: str):
-    before, after = run["counters"]["before"], run["counters"]["after"]
-    return after[counter] - before.get(counter, 0) if counter in after else None
-
-
-def read(run: dict, quantity: str, match: str = "", counter: str = "", of: str = "",
-         config: str = ""):
+def read(run: dict, quantity: str, match: str = "", counter: str = "", config: str = ""):
     if quantity == "counter_per_step":
-        grown = _grown(run, counter)
-        return None if grown is None or not run["steps"] else grown / run["steps"]
-    if quantity == "counter_share":
-        grown, whole = _grown(run, counter), _grown(run, of)
-        return grown / whole * 100.0 if grown is not None and whole else None
+        before, after = run["counters"]["before"], run["counters"]["after"]
+        if counter not in after or not run["steps"]:
+            return None
+        return (after[counter] - before.get(counter, 0)) / run["steps"]
     if not run.get("trace"):  # a rehearsal's trace holds no TPU plane
         return None
     trace = _phases().newest_trace()

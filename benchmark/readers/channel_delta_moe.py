@@ -12,13 +12,6 @@ segment — the grouped products, which carry no scope path on this compiler,
 under ``moe_experts`` by name —; the scopes' times are disjoint and can be
 added.
 
-``unscoped_ms``: the operations under none of ``SCOPES``, none of
-readers/step_rest.py's (``lm_head`` | ``embed`` | ``dense_mlp``: its
-``train_step.head_loss_ms`` and ``embed_ms`` read this cell by data alone), none
-of ``optimizer`` | ``grad_sync`` and no grouped product: what this family's
-step leaves under no name.  (step_rest's own ``unscoped_ms`` knows five
-families' scopes and would count this one's mixers.)
-
 ``kda_scan_roofline_share``: the least time the chip could take for the delta
 rule of the traced steps, as % of the time of ALL the operations under
 ``kda_scan`` (the convolutions, the gates and the gated norm too) — so it
@@ -103,18 +96,11 @@ def measure(trace: dict, quantity: str, match: str = "", peaks: dict | None = No
         return least / took * 100.0 if took else None
     if quantity == "kda_scan_roofline_share":
         match = "kda_scan"
-    elif quantity not in ("scope_ms", "unscoped_ms"):
+    elif quantity != "scope_ms":
         raise ValueError(f"channel_delta_moe reader has no quantity {quantity!r}")
-    rest = _reader("step_rest")
-    others = set(rest.SCOPES) | set(rest.STEP_SCOPES)
-
-    def counts(path: str, name: str) -> bool:
-        if quantity == "unscoped_ms":
-            return scope_of(path, name) is None and not others.intersection(path.split("/"))
-        return scope_of(path, name) == match
-
     own = ph._xplane().self_seconds(trace["ops"], lo, hi)
-    filed = sum(t for name, t in own.items() if counts(trace["paths"].get(name, ""), name))
+    filed = sum(t for name, t in own.items()
+                if scope_of(trace["paths"].get(name, ""), name) == match)
     if not filed:
         return None
     if quantity == "kda_scan_roofline_share":

@@ -6,10 +6,10 @@ seconds / (chips x peak); forward and backward only, recomputation not
 counted), ``peak_hbm_gib`` (the largest footprint read in the window on the
 fullest device: ``bytes_in_use`` + ``bytes_reserved`` of one reading),
 ``peak_in_use_gib`` (the fullest device's ``peak_bytes_in_use`` after the
-window), ``step_ms_p50`` (the median of the window's step times, dispatch to
-the ``block_until_ready`` of loss and parameters, by the host's clock) or
-``slow_step_share`` (% of the window spent in steps slower than 1.25 x that
-median: stalls inside a run, where the median is the run's own level)."""
+window) or ``slow_step_share`` (% of the window spent in steps slower than
+1.25 x the median of the window's step times, dispatch to the
+``block_until_ready`` of loss and parameters, by the host's clock: stalls
+inside a run, where the median is the run's own level)."""
 
 import statistics
 
@@ -45,9 +45,7 @@ def read(run: dict, quantity: str):
     if quantity in _BYTES:
         peak = run.get(_BYTES[quantity])
         return peak / 2**30 if peak else None
-    if quantity in ("step_ms_p50", "slow_step_share"):
+    if quantity == "slow_step_share":
         record = step_record(run.get("step_s") or [], run["window_s"])
-        if not record:
-            return None
-        return record["p50_ms"] if quantity == "step_ms_p50" else record["slow_share"] * 100.0
+        return record["slow_share"] * 100.0 if record else None
     raise ValueError(f"harness reader has no quantity {quantity!r}")

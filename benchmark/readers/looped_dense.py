@@ -3,10 +3,6 @@ the counters (byteps_tpu/models/looped_dense.py).  A program without these
 scopes or counters (the parent of the PR that brought them), and a run without
 a TPU trace, read None everywhere.
 
-The eight metrics that read through it are defined in ``benchmark/unlisted/``
-and not listed in ``BENCHMARK.json`` while its ``per_layer`` stands at its cap
-of 128 entries (ROADMAP R0).
-
 ``scope_ms``: self time a traced step of device 0's operations filed under
 the scope ``match`` — forward, recomputation and backward together.  An
 operation is filed under the FIRST of ``SCOPES`` that its scope path has as

@@ -8,9 +8,8 @@ newest ``.xplane.pb`` under ``.bench_trace/`` itself, bounds the window as
 ``benchmark/xplane.py``'s ``reduce`` does (first ``bench.step.call`` start to
 last ``bench.step.block`` end, device 0) and gives, per traced step:
 
-``span_union_ms``: time covered by the spans named ``match``, over all
-threads, overlaps counted once.  ``idle_in_ms``: time in which no operation
-ran on device 0 and a span named ``match`` was open.
+``idle_in_ms``: time in which no operation ran on device 0 and a span named
+``match`` was open on some thread (overlapping spans counted once).
 ``idle_unattributed_share``: % of device 0's idle time that no ``bps.*``
 phase covers; the span of the whole step (``bps.hybrid.step``) is no phase:
 it would cover whatever its children leave unnamed.  ``scope_ms``: self time
@@ -238,12 +237,10 @@ def measure(trace: dict, quantity: str, match: str = ""):
         if not named or not idle_s:
             return None
         return (1.0 - _overlap(idle, named) / idle_s) * 100.0
-    if quantity in ("span_union_ms", "idle_in_ms"):
+    if quantity == "idle_in_ms":
         covered = _covered(trace["spans"], lambda n: n == match, lo, hi)
         if not covered:
             return None
-        if quantity == "span_union_ms":
-            return sum(b - a for a, b in covered) / steps * 1e3
         return _overlap(_idle(trace["ops"], lo, hi), covered) / steps * 1e3
     raise ValueError(f"phases reader has no quantity {quantity!r}")
 

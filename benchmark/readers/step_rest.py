@@ -23,11 +23,13 @@ readers/latent_moe.py files under ``mtp``.
 their path (XLA:TPU's grouped products, ``ragged-dot-…``, carry none).
 
 ``unscoped_ms``: the operations under none of this reader's ``SCOPES``, none
-of ``optimizer`` | ``grad_sync``, none of the ``SCOPES`` of the five family
-readers (read from their files) and whose name is no ``ragged-dot``: what is
-still under no name — parameter slices, copies, the loss's last sums.  It
-reads the whole stack in a step whose layers open no scope (the dense
-transformer, the flax step).
+of ``optimizer`` | ``grad_sync``, none of the ``SCOPES`` of the family readers
+(``FAMILY_READERS``, read from their files; a looped family's ``LOOP`` with
+them: what stands under ``loop_steps`` and no scope inside it is that reader's
+``loop_carry``) and whose name is no ``ragged-dot``: what is still under no
+name — parameter slices, copies, the loss's last sums.  It reads the whole
+stack in a step whose layers open no scope (the dense transformer, the flax
+step).
 
 ``no_phase_ms``: the operations that ``readers/phases.py``'s own rule files
 under none of ``forward``, ``backward``, ``optimizer`` and that are not under
@@ -44,7 +46,8 @@ import os
 HERE = os.path.dirname(os.path.abspath(__file__))
 SCOPES = ("lm_head", "embed", "dense_mlp")
 #: the readers whose SCOPES name the layers' own parts of a step
-FAMILY_READERS = ("latent_moe", "delta_moe", "conv_moe", "window_moe", "ssm_moe")
+FAMILY_READERS = ("latent_moe", "delta_moe", "conv_moe", "window_moe", "ssm_moe",
+                  "looped_dense", "block_diffusion_moe", "cross_decoder", "channel_delta_moe")
 #: as the grouped products' operations are named in a trace; they carry no scope path
 RAGGED_DOT = "ragged-dot"
 #: scopes of the step itself, outside the differentiated loss
@@ -64,8 +67,10 @@ def _reader(name: str):
 @functools.cache
 def named_scopes() -> frozenset:
     """Every scope some metric files an operation by."""
-    family = {s for name in FAMILY_READERS for s in _reader(name).SCOPES}
-    return frozenset(family | set(SCOPES) | set(STEP_SCOPES))
+    readers = [_reader(name) for name in FAMILY_READERS]
+    family = {s for reader in readers for s in reader.SCOPES}
+    loops = {reader.LOOP for reader in readers if hasattr(reader, "LOOP")}
+    return frozenset(family | loops | set(SCOPES) | set(STEP_SCOPES))
 
 
 def scope_of(path: str) -> str | None:

@@ -2,8 +2,7 @@
 ``reduce``), device 0.
 
 ``quantity``: ``busy_ms`` (device-op union per step), ``idle_share`` (% of
-the traced window with no operation running), ``exposed_ms`` (a traced
-step's wall time less its device-busy time), ``op_ms`` (summed time of the
+the traced window with no operation running), ``op_ms`` (summed time of the
 operations whose name contains ``match``, per step)."""
 
 
@@ -15,8 +14,6 @@ def read(run: dict, quantity: str, match: str = ""):
         return t["busy0_s"] / t["steps"] * 1e3
     if quantity == "idle_share":
         return (1.0 - t["busy0_s"] / t["window_s"]) * 100.0
-    if quantity == "exposed_ms":
-        return (t["window_s"] - t["busy0_s"]) / t["steps"] * 1e3
     if quantity == "op_ms":
         hit = [v for k, v in t["op_seconds"].items() if match in k]
         return sum(hit) / t["steps"] * 1e3 if hit else None

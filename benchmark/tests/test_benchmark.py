@@ -63,7 +63,6 @@ def test_busy_union_and_idle_share():
     run = {"trace": r}
     assert reader.read(run, quantity="busy_ms") == pytest.approx(300.0)
     assert reader.read(run, quantity="idle_share") == pytest.approx(70.0)
-    assert reader.read(run, quantity="exposed_ms") == pytest.approx(700.0)
     assert reader.read(run, quantity="op_ms", match="all-reduce") == pytest.approx(100.0)
     assert reader.read(run, quantity="op_ms", match="no-such-op") is None
     assert reader.read({"trace": None}, quantity="busy_ms") is None
@@ -164,6 +163,22 @@ def test_each_per_layer_entry_has_its_metric_file_and_reader():
         assert set(m.get("workloads", cells)) <= cells
         reader = load("readers", f"{spec['reader']}.py")
         assert callable(reader.read)
+
+
+def test_the_listing_holds_together():
+    """What ISSUE 70 set up, by data alone: ``per_layer`` under its cap, one entry
+    a metric file - no two files with the same reader and arguments, so a quantity
+    that several cells report is ONE entry listing them -, nothing waiting outside
+    the list, and every family reader known to ``step_rest``'s ``unscoped_ms``."""
+    assert len(BENCH["per_layer"]) <= 128
+    read_by = {}
+    for name in metric_files():
+        spec = load_json(HERE, "metrics", f"{name}.json")
+        read_by.setdefault((spec["reader"], json.dumps(spec["args"], sort_keys=True)), []).append(name)
+    assert [names for names in read_by.values() if len(names) > 1] == []
+    assert not os.path.exists(os.path.join(HERE, "unlisted"))
+    for name in load("readers", "step_rest.py").FAMILY_READERS:
+        assert load("readers", f"{name}.py").SCOPES, name
 
 
 def test_every_cell_finds_its_files_by_name():
@@ -301,12 +316,11 @@ def test_step_record(step_s, window_s, want):
 def test_step_quantities_of_the_harness_reader():
     reader = load("readers", "harness.py")
     run = {"step_s": STALLED, "window_s": 12.0}
-    assert reader.read(run, quantity="step_ms_p50") == pytest.approx(500.0)
     assert reader.read(run, quantity="slow_step_share") == pytest.approx(100 * 2.0 / 12.0)
     assert reader.read({"step_s": EVEN, "window_s": 10.0}, quantity="slow_step_share") == 0.0
     # an empty window has no step to read, and a run from before the record none either
     assert reader.step_record([], 0.0) is None
-    assert reader.read({"step_s": [], "window_s": 0.0}, quantity="step_ms_p50") is None
+    assert reader.read({"step_s": [], "window_s": 0.0}, quantity="slow_step_share") is None
     assert reader.read({"window_s": 40.0}, quantity="slow_step_share") is None
 
 

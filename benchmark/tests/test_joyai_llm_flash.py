@@ -41,7 +41,9 @@ def test_the_cell_finds_its_files_by_name():
     for name in ("flops_per_sample", "make_optimizer", "plain_loss", "make_state", "build"):
         assert callable(getattr(builder, name))
     mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
-    assert len(mine) == 8
+    assert [m["name"] for m in mine] == [
+        "train_step.attention_ms", "train_step.moe_route_ms", "train_step.moe_experts_ms",
+        "train_step.mtp_ms"]
     for m in mine:
         spec = load_json(HERE, "metrics", f"{m['name']}.json")
         assert spec["reader"] == "latent_moe" and m["moves"] == "samples_per_s"
@@ -174,6 +176,7 @@ def test_counters_per_step_and_what_a_program_without_them_reads():
     assert read(run, quantity="counter_share", counter="moe_fullest_expert_slots",
                 of="moe_slots_held") == 25.0
     parent = {"steps": 4, "counters": {"before": {}, "after": {"d2h_bytes": 7}}, "trace": None}
-    for spec in (m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]):
-        args = load_json(HERE, "metrics", f"{spec['name']}.json")["args"]
-        assert read(parent, **args) is None
+    for spec in BENCH["per_layer"]:  # every metric through this reader, whatever cells it lists
+        m = load_json(HERE, "metrics", f"{spec['name']}.json")
+        if m["reader"] == "latent_moe":
+            assert read(parent, **m["args"]) is None

@@ -18,18 +18,18 @@ SOURCE = "https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/ma
 REDUCED = ["num_hidden_layers", "num_experts", "vocab_size"]
 NAMES = {"train_step.kda_scan_ms", "train_step.kda_proj_ms",
          "train_step.nope_latent_attention_ms", "kernels.kda_scan_roofline_share",
-         "kernels.nope_mla_flash_roofline_share", "channel_delta_moe.held_slots_per_step",
-         "channel_delta_moe.dropped_slots_per_step", "channel_delta_moe.fullest_expert_share"}
-#: the rest of the cell's step, which the listed ``train_step.*`` entries read in the cells
-#: they name one by one: metric → the reader that reads it here
-REST = {"train_step.channel_delta_dense_mlp_ms": "channel_delta_moe",
-        "train_step.channel_delta_moe_route_ms": "channel_delta_moe",
-        "train_step.channel_delta_moe_experts_ms": "channel_delta_moe",
-        "train_step.channel_delta_moe_shared_ms": "channel_delta_moe",
-        "train_step.channel_delta_unscoped_ms": "channel_delta_moe",
-        "train_step.channel_delta_head_loss_ms": "step_rest",
-        "train_step.channel_delta_embed_ms": "step_rest"}
-NAMES |= set(REST)
+         "kernels.nope_mla_flash_roofline_share", "moe.held_slots_per_step",
+         "moe.dropped_slots_per_step", "moe.fullest_expert_share"}
+#: the three scopes that no general entry reads by this family's rule
+NAMES |= {"train_step.channel_delta_moe_route_ms", "train_step.channel_delta_moe_experts_ms",
+          "train_step.channel_delta_moe_shared_ms"}
+#: the routing counters, which every held-expert family reads through ``latent_moe``
+SHARED = {name for name in NAMES if name.startswith("moe.")}
+#: the general entries that list the cell since PR 70, in place of the stand-ins it waited with
+APPENDED = ("train_step.dense_mlp_ms", "train_step.head_loss_ms", "train_step.embed_ms",
+            "train_step.unscoped_ms", "train_step.no_phase_ms", "train_step.dispatch_ms",
+            "train_step.idle_in_dispatch_ms", "train_step.fold_ms",
+            "train_step.grouped_products_ms", "moe.rows_walked_per_step")
 
 
 def load(*parts):
@@ -47,7 +47,7 @@ def load_json(*parts):
 
 BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
-UNLISTED = load_json(HERE, "unlisted", "per_layer.channel_delta_moe.json")
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
 reader = load("readers", "channel_delta_moe.py")
 counters = load("readers", "latent_moe.py")
 READERS = {"channel_delta_moe": reader, "latent_moe": counters,
@@ -67,22 +67,22 @@ def test_the_cell_finds_its_files_by_name():
     for name in ("flops_per_sample", "make_optimizer", "plain_loss", "make_state", "build",
                  "_model_config", "_mesh4"):  # the last two: tools/latent_moe_precision.py's
         assert callable(getattr(builder, name))
-    # per_layer stands at its cap: the fifteen entries wait in benchmark/unlisted/
-    assert len(BENCH["per_layer"]) == 128
-    assert {m["name"] for m in UNLISTED} == NAMES
-    assert not NAMES & {m["name"] for m in BENCH["per_layer"]}
+    assert {m["name"] for m in MINE} == NAMES
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    assert all(CELL in listed[name]["workloads"] for name in APPENDED)
+    assert not [name for name in listed if "channel_delta" in name and name not in NAMES]
 
 
 @pytest.mark.parametrize("name", sorted(NAMES))
-def test_every_unlisted_metric_file_loads_and_names_the_cell(name):
-    m = next(m for m in UNLISTED if m["name"] == name)
-    spec = load_json(HERE, "unlisted", f"{name}.json")
-    # the counters are read by latent_moe's reader, head and embedding by step_rest's: by data alone
-    want = "latent_moe" if name.startswith("channel_delta_moe.") else REST.get(
-        name, "channel_delta_moe")
+def test_every_metric_file_loads_and_names_the_cell(name):
+    m = next(m for m in MINE if m["name"] == name)
+    spec = load_json(HERE, "metrics", f"{name}.json")
+    # the counters are read by latent_moe's reader, by data alone
+    want = "latent_moe" if name in SHARED else "channel_delta_moe"
     assert spec["reader"] == want and spec["what"]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert m["workloads"] == [CELL] and m["moves"] == "samples_per_s"
+    assert (CELL in m["workloads"] if name in SHARED else m["workloads"] == [CELL])
+    assert m["moves"] == "samples_per_s"
     if name.endswith("roofline_share"):
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (
             "%", "higher", "device_trace", "kernels")
@@ -96,7 +96,7 @@ def test_the_counters_are_read_from_a_runs_snapshots():
         "before": {"moe_slots_routed": 10, "moe_slots_held": 100, "moe_fullest_expert_slots": 20},
         "after": {"moe_slots_routed": 50, "moe_slots_held": 500, "moe_fullest_expert_slots": 80}}}
     value = lambda name: counters.read(  # noqa: E731
-        run, **load_json(HERE, "unlisted", f"channel_delta_moe.{name}.json")["args"])
+        run, **load_json(HERE, "metrics", f"moe.{name}.json")["args"])
     assert value("held_slots_per_step") == 100.0
     assert value("dropped_slots_per_step") == 0.0  # a counter that never grew is not there
     assert value("fullest_expert_share") == 15.0
@@ -206,7 +206,7 @@ def test_the_reader_on_a_made_up_trace():
     assert reader.measure(trace, "scope_ms", "nope_latent_attention") == pytest.approx(160.0)
     assert reader.measure(trace, "scope_ms", "dense_mlp") is None
     # under no name: the copy alone - not the head's, the optimizer's or the grouped product
-    assert reader.measure(trace, "unscoped_ms") == pytest.approx(10.0)
+    assert READERS["step_rest"].measure(trace, "unscoped_ms") == pytest.approx(10.0)
     assert READERS["step_rest"].measure(trace, "scope_ms", "lm_head") == pytest.approx(30.0)
     share = reader.measure(trace, "kda_scan_roofline_share", least_s=0.012)
     assert share == pytest.approx(4.0)

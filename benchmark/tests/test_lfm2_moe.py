@@ -32,7 +32,13 @@ def load_json(*parts):
 BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "conv_moe.py")
-MINE = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
+#: the family's own metrics, in BENCHMARK.json's order; the last five list other cells too
+NAMES = [
+    "train_step.short_conv_ms", "train_step.conv_proj_ms", "train_step.gqa_attention_ms",
+    "train_step.dense_mlp_ms", "train_step.sigmoid_route_ms", "train_step.small_experts_ms",
+    "kernels.short_conv_roofline_share", "kernels.flash_roofline_share",
+    "moe.held_slots_per_step", "moe.dropped_slots_per_step", "moe.fullest_expert_share"]
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
 
 #: the source's config.json, as the catalog has it
 PUBLISHED = {
@@ -55,20 +61,14 @@ def test_the_cell_finds_its_files_by_name():
     assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
     assert entry["source"] == "https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json"
     assert os.path.exists(os.path.join(HERE, "traffic", f"{cell['traffic']}.json"))
-    assert BENCH["workloads"][-1] is cell and BENCH["configs"][-1] is entry  # appended, last
     builder = load("builders", f"{CFG['builder']}.py")
     for name in ("flops_per_sample", "make_optimizer", "plain_loss", "make_state", "build"):
         assert callable(getattr(builder, name))
-    assert [m["name"] for m in MINE] == [
-        "train_step.short_conv_ms", "train_step.conv_proj_ms", "train_step.gqa_attention_ms",
-        "train_step.dense_mlp_ms", "train_step.sigmoid_route_ms", "train_step.small_experts_ms",
-        "kernels.short_conv_roofline_share", "kernels.gqa_flash_roofline_share",
-        "conv_moe.held_slots_per_step", "conv_moe.dropped_slots_per_step",
-        "conv_moe.fullest_expert_share"]
-    assert BENCH["per_layer"][-len(MINE):] == MINE
+    assert sorted(m["name"] for m in MINE) == sorted(NAMES)
     for m in MINE:
         spec = load_json(HERE, "metrics", f"{m['name']}.json")
-        assert spec["reader"] in ("conv_moe", "latent_moe") and m["moves"] == "samples_per_s"
+        assert spec["reader"] in ("conv_moe", "latent_moe", "step_rest")
+        assert m["moves"] == "samples_per_s" and CELL in m["workloads"]
         assert os.path.exists(os.path.join(HERE, "readers", f"{spec['reader']}.py"))
         assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
 

@@ -37,12 +37,12 @@ BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "ssm_moe.py")
 builder = load("builders", "nemotron_h.py")
-MINE = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
 NAMES = {"train_step.ssd_scan_ms", "train_step.ssm_proj_ms", "train_step.nope16_attention_ms",
-         "train_step.bias_route_ms", "train_step.relu2_experts_ms", "train_step.shared_relu2_ms",
-         "kernels.group16_flash_roofline_share", "kernels.ssd_scan_roofline_share",
-         "ssm_moe.held_slots_per_step", "ssm_moe.dropped_slots_per_step",
-         "ssm_moe.fullest_expert_share"}
+         "train_step.window_family_route_ms", "train_step.window_family_experts_ms", "train_step.shared_expert_ms",
+         "kernels.global_flash_roofline_share", "kernels.ssd_scan_roofline_share",
+         "moe.held_slots_per_step", "moe.dropped_slots_per_step",
+         "moe.fullest_expert_share"}
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
 
 
 def test_the_cell_finds_its_files_by_name():
@@ -60,6 +60,9 @@ def test_the_cell_finds_its_files_by_name():
                  "_model_config", "_mesh4"):  # the last two: tools/latent_moe_precision.py's
         assert callable(getattr(builder, name))
     assert {m["name"] for m in MINE} == NAMES
+    assert [m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [CELL]] == [
+        "train_step.ssd_scan_ms", "train_step.ssm_proj_ms", "train_step.nope16_attention_ms",
+        "kernels.ssd_scan_roofline_share"]
     # and every metric without a list of cells finds something to read here:
     # the ten that every training cell has
     everywhere = [m["name"] for m in BENCH["per_layer"]
@@ -75,7 +78,7 @@ def test_every_metric_file_loads_and_names_the_cell(name):
     assert m["moves"] == "samples_per_s"
     assert callable(load("readers", f"{spec['reader']}.py").read) and spec["what"]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert m["workloads"] == [CELL] and m["layer"] in ("train_step", "kernels", "moe")
+    assert CELL in m["workloads"] and m["layer"] in ("train_step", "kernels", "moe")
     if name.endswith("roofline_share"):
         assert m["unit"] == "%" and m["better"] == "higher" and m["source"] == "device_trace"
 
@@ -190,9 +193,9 @@ def test_the_scopes_the_metrics_read_are_the_programs():
     window = load("readers", "window_moe.py")
     for name, scope, own in (("ssd_scan_ms", "ssd_scan", True), ("ssm_proj_ms", "ssm_proj", True),
                              ("nope16_attention_ms", "nope16_attention", True),
-                             ("bias_route_ms", "moe_route", False),
-                             ("relu2_experts_ms", "moe_experts", False),
-                             ("shared_relu2_ms", "shared_expert", False)):
+                             ("window_family_route_ms", "moe_route", False),
+                             ("window_family_experts_ms", "moe_experts", False),
+                             ("shared_expert_ms", "shared_expert", False)):
         spec = load_json(HERE, "metrics", f"train_step.{name}.json")
         assert spec["args"] == {"quantity": "scope_ms", "match": scope}
         assert spec["reader"] == ("ssm_moe" if own else "window_moe")
@@ -206,7 +209,7 @@ def test_the_scopes_the_metrics_read_are_the_programs():
     assert reader.scope_of("jit(step)/ssm_proj/ssd_scan/mul") == "ssd_scan"  # the first of SCOPES
     assert reader.scope_of("", "%ragged-dot.3 = custom-call(") == "moe_experts"
     assert reader.scope_of("jit(step)/optimizer/add") is None
-    assert load_json(HERE, "metrics", "kernels.group16_flash_roofline_share.json")["args"] == {
+    assert load_json(HERE, "metrics", "kernels.global_flash_roofline_share.json")["args"] == {
         "quantity": "flash_roofline_share", "kind": "global"}
 
 

@@ -34,16 +34,18 @@ BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "looped_dense.py")
 builder = load("builders", "ouro.py")
-#: the family's eight per-layer metrics as a ``benchmark`` PR can list them:
-#: the entries in ``unlisted/per_layer.json``, their files beside it
-UNLISTED = os.path.join(HERE, "unlisted")
-MINE = load_json(UNLISTED, "per_layer.json")
+METRICS = os.path.join(HERE, "metrics")
 SCOPED = {"train_step.loop_attention_ms": "loop_attention", "train_step.loop_mlp_ms": "loop_mlp",
           "train_step.loop_heads_ms": "loop_heads", "train_step.exit_gate_ms": "exit_gate",
           "train_step.loop_carry_ms": "loop_carry"}
 COUNTED = {"looped.layer_passes_per_step": "looped_layer_passes",
            "looped.mean_exit_step_milli": "looped_exit_step_milli"}
 NAMES = set(SCOPED) | set(COUNTED) | {"kernels.mha128_flash_roofline_share"}
+#: the family's eight per-layer metrics, listed since PR 70
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
+#: the general entries that list the cell too (``lm_head`` stands inside ``loop_heads`` here)
+APPENDED = ("train_step.head_loss_ms", "train_step.embed_ms", "train_step.unscoped_ms",
+            "train_step.no_phase_ms", "train_step.dispatch_ms", "train_step.idle_in_dispatch_ms")
 
 
 def test_the_cell_finds_its_files_by_name():
@@ -61,13 +63,15 @@ def test_the_cell_finds_its_files_by_name():
                  "_model_config", "_mesh4"):  # the last two: tools/latent_moe_precision.py's
         assert callable(getattr(builder, name))
     assert {m["name"] for m in MINE} == NAMES
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    assert all(CELL in listed[name]["workloads"] for name in APPENDED)
     assert [w["name"] for w in BENCH["workloads"] if w["config"] == CONFIG] == [CELL]
 
 
 @pytest.mark.parametrize("name", sorted(NAMES))
 def test_every_metric_file_loads_and_names_the_cell(name):
     m = next(m for m in MINE if m["name"] == name)
-    spec = load_json(UNLISTED, f"{name}.json")
+    spec = load_json(METRICS, f"{name}.json")
     assert spec["reader"] == "looped_dense" and spec["what"]
     assert m["moves"] == "samples_per_s"
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
@@ -263,7 +267,7 @@ def test_a_program_without_the_family_reads_nothing():
     parent = {"steps": 4, "counters": {"before": {}, "after": {"d2h_bytes": 7, "moe_slots_routed": 0}},
               "trace": None, "global_batch": 1, "peak_flops_per_s": 197e12}
     for spec in MINE:
-        m = load_json(UNLISTED, f"{spec['name']}.json")
+        m = load_json(METRICS, f"{spec['name']}.json")
         assert load("readers", f"{m['reader']}.py").read(parent, **m["args"]) is None
 
 

@@ -31,7 +31,9 @@ def load_json(*parts):
 BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "delta_moe.py")
-MINE = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
+#: the family's own metrics: the entries that list the cell and read through its readers
+MINE = [m for m in BENCH["per_layer"] if CELL in m.get("workloads", ()) and load_json(
+    HERE, "metrics", f"{m['name']}.json")["reader"] in ("delta_moe", "latent_moe")]
 
 #: the source's config.json, as the catalog has it
 PUBLISHED = {
@@ -61,7 +63,8 @@ def test_the_cell_finds_its_files_by_name():
     builder = load("builders", f"{CFG['builder']}.py")
     for name in ("flops_per_sample", "make_optimizer", "plain_loss", "make_state", "build"):
         assert callable(getattr(builder, name))
-    assert len(MINE) == 10
+    # six through delta_moe; the flash share, the three routing counters and the rows walked
+    assert len(MINE) == 11
     for m in MINE:
         spec = load_json(HERE, "metrics", f"{m['name']}.json")
         assert spec["reader"] in ("delta_moe", "latent_moe") and m["moves"] == "samples_per_s"

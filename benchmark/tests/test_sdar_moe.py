@@ -34,24 +34,26 @@ BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "block_diffusion_moe.py")
 builder = load("builders", "sdar_moe.py")
-#: the family's eight per-layer metrics as a ``benchmark`` PR can list them: the
-#: entries in ``unlisted/per_layer.block_diffusion_moe.json``, their files beside it
-UNLISTED = os.path.join(HERE, "unlisted")
-MINE = load_json(UNLISTED, "per_layer.block_diffusion_moe.json")
+METRICS = os.path.join(HERE, "metrics")
 SCOPED = {"train_step.block_diffusion_attention_ms": "block_diffusion_attention",
           "train_step.copies_assembly_ms": "copies_assembly"}
 COUNTED = {"block_diffusion.masked_tokens_per_step": "block_diffusion_masked_tokens",
            "block_diffusion.mean_weight_milli": "block_diffusion_weight_milli",
-           "block_diffusion_moe.held_slots_per_step": "moe_slots_held",
-           "block_diffusion_moe.dropped_slots_per_step": "moe_slots_dropped"}
-SHARE = "block_diffusion_moe.fullest_expert_share"
+           "moe.held_slots_per_step": "moe_slots_held",
+           "moe.dropped_slots_per_step": "moe_slots_dropped"}
+SHARE = "moe.fullest_expert_share"
 ROOFLINE = "kernels.block_diffusion_flash_roofline_share"
 NAMES = set(SCOPED) | set(COUNTED) | {SHARE, ROOFLINE}
+#: the family's eight per-layer metrics, listed since PR 70: five of its own, and the
+#: three routing counters that every held-expert family reads through ``latent_moe``
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
+SHARED = {name for name in NAMES if name.startswith("moe.")}
 #: accepted metrics whose ``workloads`` the cell was appended to
 APPENDED = ("train_step.head_loss_ms", "train_step.embed_ms", "train_step.grouped_products_ms",
             "train_step.no_phase_ms", "train_step.dispatch_ms", "train_step.fold_ms",
             "train_step.idle_in_dispatch_ms", "moe.rows_walked_per_step",
-            "train_step.softmax_route_ms", "train_step.held_experts_ms")
+            "train_step.softmax_route_ms", "train_step.held_experts_ms",
+            "train_step.unscoped_ms")
 
 
 def test_the_cell_finds_its_files_by_name():
@@ -71,7 +73,6 @@ def test_the_cell_finds_its_files_by_name():
     assert {m["name"] for m in MINE} == NAMES
     assert [w["name"] for w in BENCH["workloads"] if w["config"] == CONFIG] == [CELL]
     listed = {m["name"]: m for m in BENCH["per_layer"]}
-    assert not NAMES & set(listed)  # the cap: a benchmark PR lists them
     for name in APPENDED:
         assert listed[name]["workloads"][-1] == CELL or CELL in listed[name]["workloads"]
 
@@ -79,11 +80,13 @@ def test_the_cell_finds_its_files_by_name():
 @pytest.mark.parametrize("name", sorted(NAMES))
 def test_every_metric_file_loads_and_names_the_cell(name):
     m = next(m for m in MINE if m["name"] == name)
-    spec = load_json(UNLISTED, f"{name}.json")
-    assert spec["reader"] == "block_diffusion_moe" and spec["what"]
+    spec = load_json(METRICS, f"{name}.json")
+    assert spec["reader"] == ("latent_moe" if name in SHARED else "block_diffusion_moe")
+    assert spec["what"]
     assert m["moves"] == "samples_per_s"
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert m["workloads"] == [CELL] and m["layer"] in ("train_step", "kernels", "moe")
+    assert (CELL in m["workloads"] if name in SHARED else m["workloads"] == [CELL])
+    assert m["layer"] in ("train_step", "kernels", "moe")
     if name == ROOFLINE:
         assert m["unit"] == "%" and m["better"] == "higher" and m["source"] == "device_trace"
         # the block length is the configuration's own, read where the cell reads it
@@ -267,15 +270,19 @@ def test_the_counters_read_their_growth_a_step():
                                   "block_diffusion_weight_milli": 5008}}}
     assert reader.read(run, "counter_per_step", counter="block_diffusion_masked_tokens") == 5734
     assert reader.read(run, "counter_per_step", counter="block_diffusion_weight_milli") == 1002
-    assert reader.read(run, "counter_share", counter="moe_fullest_expert_slots",
+    # the routing counters go through latent_moe's reader, as every held-expert family's
+    shared = load("readers", "latent_moe.py")
+    run["counters"]["after"]["moe_slots_routed"] = 3200
+    assert shared.read(run, "counter_share", counter="moe_fullest_expert_slots",
                        of="moe_slots_held") == 12.5
+    assert shared.read(run, "counter_per_step", counter="moe_slots_dropped") == 0.0
 
 
 def test_a_program_without_the_family_reads_nothing():
     parent = {"steps": 4, "counters": {"before": {}, "after": {"d2h_bytes": 7}},
               "trace": None, "global_batch": 1, "peak_flops_per_s": 197e12}
     for spec in MINE:
-        m = load_json(UNLISTED, f"{spec['name']}.json")
+        m = load_json(METRICS, f"{spec['name']}.json")
         assert load("readers", f"{m['reader']}.py").read(parent, **m["args"]) is None
 
 

@@ -33,7 +33,6 @@ def load_json(*parts):
 BENCH = load_json(ROOT, "BENCHMARK.json")
 CFG = load_json(HERE, "configs", f"{CONFIG}.json")
 reader = load("readers", "window_moe.py")
-MINE = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
 
 #: the source's config.json, as the catalog has it
 PUBLISHED = {
@@ -47,11 +46,14 @@ PUBLISHED = {
     "sliding_window_layout": [int(i % 4 != 0) for i in range(52)], "sliding_window_size": 4096,
     "tie_word_embeddings": False, "vocab_size": 151936,
 }
-NAMES = {"train_step.sliding_attention_ms", "train_step.nope_attention_ms",
-         "train_step.early_route_ms", "train_step.reglu_experts_ms",
-         "kernels.window4096_flash_roofline_share", "kernels.group7_flash_roofline_share",
-         "early_route_moe.held_slots_per_step", "early_route_moe.dropped_slots_per_step",
-         "early_route_moe.fullest_expert_share"}
+#: the family's own metrics; but for the one banded share they list the other
+#: cells of the window family's reader too (one entry a metric file)
+NAMES = {"train_step.window_attention_ms", "train_step.global_attention_ms",
+         "train_step.window_family_route_ms", "train_step.window_family_experts_ms",
+         "kernels.window4096_flash_roofline_share", "kernels.global_flash_roofline_share",
+         "moe.held_slots_per_step", "moe.dropped_slots_per_step",
+         "moe.fullest_expert_share"}
+MINE = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
 
 
 def test_the_cell_finds_its_files_by_name():
@@ -70,6 +72,8 @@ def test_the_cell_finds_its_files_by_name():
     for name in ("flops_per_sample", "make_optimizer", "plain_loss", "make_state", "build"):
         assert callable(getattr(builder, name))
     assert {m["name"] for m in MINE} == NAMES
+    assert [m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [CELL]] == [
+        "kernels.window4096_flash_roofline_share"]
     # and every metric without a list of cells finds something to read here:
     # the ten that every training cell has
     everywhere = [m["name"] for m in BENCH["per_layer"]
@@ -84,7 +88,7 @@ def test_every_metric_file_loads_and_names_the_cell(name):
     assert spec["reader"] in ("window_moe", "latent_moe") and m["moves"] == "samples_per_s"
     assert callable(load("readers", f"{spec['reader']}.py").read) and spec["what"]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert m["workloads"] == [CELL] and m["layer"] in ("train_step", "kernels", "moe")
+    assert CELL in m["workloads"] and m["layer"] in ("train_step", "kernels", "moe")
     if name.endswith("roofline_share"):
         assert m["unit"] == "%" and m["better"] == "higher" and m["source"] == "device_trace"
 
@@ -191,14 +195,14 @@ def test_flash_cost_at_window_4096_and_group_7():
         assert ops / 197e12 > 5 * nbytes / 819e9
     args = load_json(HERE, "metrics", "kernels.window4096_flash_roofline_share.json")["args"]
     assert args["window"] == CFG["sliding_window_size"] == 4096 and args["kind"] == "window"
-    assert load_json(HERE, "metrics", "kernels.group7_flash_roofline_share.json")["args"] == {
+    assert load_json(HERE, "metrics", "kernels.global_flash_roofline_share.json")["args"] == {
         "quantity": "flash_roofline_share", "kind": "global"}
 
 
 def test_the_scopes_the_metrics_read_are_the_programs():
-    for name, scope in (("sliding_attention_ms", "window_attention"),
-                        ("nope_attention_ms", "global_attention"),
-                        ("early_route_ms", "moe_route"), ("reglu_experts_ms", "moe_experts")):
+    for name, scope in (("window_attention_ms", "window_attention"),
+                        ("global_attention_ms", "global_attention"),
+                        ("window_family_route_ms", "moe_route"), ("window_family_experts_ms", "moe_experts")):
         args = load_json(HERE, "metrics", f"train_step.{name}.json")["args"]
         assert args == {"quantity": "scope_ms", "match": scope} and scope in reader.SCOPES
     with open(os.path.join(ROOT, "byteps_tpu", "models", "early_route_moe.py")) as f:
