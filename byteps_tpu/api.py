@@ -382,10 +382,27 @@ def get_metrics() -> dict:
     counters, gauges, and histogram p50/p90/p99 summaries (RPC round
     trips, per-stage dwell, server sum/publish latency, fused pack
     density — the catalog lives in docs/observability.md).  Process-wide;
-    usable before :func:`init`."""
+    usable before :func:`init`.
+
+    In an initialised distributed worker the snapshot also holds every
+    linked server's registry as it stands AT the call, under the labels
+    ``{role="server", rank="<r>"}``: its histograms among ``histograms``,
+    its counters among ``counters_labeled``, its gauges among ``gauges``
+    (``counters`` stays this process's own).  One control request a
+    server, answered within ``PSClient.METRICS_WAIT_S`` or left out: a dead
+    server, or one that does not know the request, costs that wait and the
+    call returns this process's registry."""
     from byteps_tpu.core.telemetry import metrics
 
-    return metrics().snapshot()
+    snapshot = metrics().snapshot()
+    client = get_state().ps_client
+    if client is not None:
+        for theirs in client.server_metrics():
+            for section in ("histograms", "gauges"):
+                snapshot[section].update(theirs.get(section, {}))
+            for name, per in theirs.get("counters_labeled", {}).items():
+                snapshot["counters_labeled"].setdefault(name, {}).update(per)
+    return snapshot
 
 
 def get_metrics_text() -> str:

@@ -37,7 +37,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from byteps_tpu.core.telemetry import counters, metrics
-from byteps_tpu.core.tracing import span, thread_tag
+from byteps_tpu.core.tracing import sampled, span, thread_tag
 
 from byteps_tpu.common.config import Config
 from byteps_tpu.common.hashing import assign_server
@@ -2305,6 +2305,44 @@ class PSClient:
             raise ConnectionError(errmsg)
         return box[0]
 
+    #: how long get_metrics() waits for the servers' registries, all together
+    METRICS_WAIT_S = 2.0
+
+    def server_metrics(self) -> List[dict]:
+        """Every linked server's registry now, as ``MetricsRegistry.snapshot``
+        under the server's own ``{role="server", rank}`` labels: one
+        ``Op.METRICS`` a server, all sent before any is waited for, all
+        waited for ``METRICS_WAIT_S`` together.  A server that is dead, does
+        not answer in time or does not know the request (the C++ engine
+        rejects it with status 1) is left out — nothing is retried and no
+        connection is torn down: the caller is reading numbers, not healing
+        a plane."""
+        waiting = []
+        for sc in list(self._servers):
+            done, box = threading.Event(), []
+            try:
+                seq = sc.alloc_seq(
+                    lambda msg, box=box, done=done: (box.append(msg), done.set()))
+                if seq >= 0:
+                    sc.send_msg(Message(Op.METRICS, seq=seq))
+            except (ConnectionError, OSError):
+                continue
+            waiting.append((sc, seq, done, box))
+        snapshots, deadline = [], time.monotonic() + self.METRICS_WAIT_S
+        for sc, seq, done, box in waiting:
+            if not done.wait(max(0.0, deadline - time.monotonic())):
+                sc.pop_cb(seq)  # a late reply finds nobody and is dropped
+                continue
+            msg = box[0]
+            if msg is None or msg.status or msg.op != Op.METRICS:
+                continue
+            try:
+                snapshots.append(json.loads(bytes(msg.payload)))
+            except ValueError:
+                pass
+            release_frame(msg.payload)
+        return snapshots
+
     def _start_recv_loops(self, sc: _ServerConn) -> None:
         """One receiver per lane; all lanes demux into the shared seq-keyed
         callback table (responses come back on the lane that carried the
@@ -2328,12 +2366,14 @@ class PSClient:
         # merged round, a fused reply): whoever consumes one releases it
         pool = FramePool()
         # one frame's service on this thread, parsed header → the callback's
-        # return (payload receive, integrity, the engine's _proceed), and of
-        # that the payload's receive alone.  A one-socket link files under
-        # "push", as lane_bulk_bytes does
+        # return (payload receive, integrity, the engine's _proceed); one
+        # frame in sampled.EVERY on the stage threads' two clocks, the payload's receive
+        # its releasing call (transport.recv_into), under stage="recv.<lane>"
+        # for the lane kind's threads together.  A one-socket link files
+        # under "push", as lane_bulk_bytes does
         lane = "pull" if pull_lane else "push"
         span_name = "recv.frame." + lane
-        receiving = metrics().held("recv_payload_seconds", {"lane": lane})
+        sample = sampled("recv." + lane)
         try:
             while not self._stop.is_set():
                 try:
@@ -2341,9 +2381,14 @@ class PSClient:
                 except (ConnectionError, OSError):
                     return
                 with span(span_name):
-                    if not self._recv_frame(sc, sock, pull_lane, header, pool,
-                                            ck_limit, receiving):
-                        return
+                    sample.begin()
+                    try:
+                        alive = self._recv_frame(sc, sock, pull_lane, header,
+                                                 pool, ck_limit)
+                    finally:
+                        sample.end()
+                if not alive:
+                    return
         finally:
             # one lane dying poisons the whole striped connection: close
             # every lane (wakes the sibling receivers).  The DRAIN — fail
@@ -2359,7 +2404,7 @@ class PSClient:
                         pass
 
     def _recv_frame(self, sc: _ServerConn, sock, pull_lane: bool, header,
-                    pool: FramePool, ck_limit: int, receiving) -> bool:
+                    pool: FramePool, ck_limit: int) -> bool:
         """Receive one reply frame's payload and hand it to its callback;
         False when the lane must close (its connection died, or it passed
         the checksum-mismatch limit)."""
@@ -2383,7 +2428,6 @@ class PSClient:
             # an owned payload (no zero-copy for compressed frames)
             zero_copied = (not lossless and sink is not None
                            and length == len(sink))
-            t0 = time.perf_counter()
             if zero_copied:
                 # zero-copy: the aggregated payload lands directly
                 # in the caller's result buffer — no intermediate
@@ -2395,7 +2439,6 @@ class PSClient:
                     recv_payload(sock, length, pool)
                     if length else b""
                 )
-            receiving.observe(time.perf_counter() - t0)
             if crc is not None and frame_checksum(
                 trace, sink if zero_copied else payload
             ) != crc:

@@ -52,6 +52,8 @@ import struct
 import threading
 from typing import Optional, Tuple
 
+from byteps_tpu.core.tracing import releasing
+
 MAGIC = 0xB5
 HEADER_FMT = "!BBBBIQIIQ"
 HEADER_SIZE = struct.calcsize(HEADER_FMT)
@@ -143,6 +145,13 @@ class Op(enum.IntEnum):
     MIGRATE_STATE = 25  # old owner → new owner: one key's full state
     WRONG_OWNER = 26    # server → worker reply: {new owner rank};
                         # header ``version`` carries the new map epoch
+    # observability plane (docs/observability.md "One scrape, both ends"):
+    # a worker's get_metrics() asks each linked server for its registry AT
+    # the call; the serve thread answers inline with MetricsRegistry.
+    # snapshot() as JSON — raw cumulative state, the heartbeat delta's
+    # baseline untouched.  The C++ engine rejects it (status 1) and the
+    # worker's snapshot then holds its own process alone.
+    METRICS = 27
 
 
 # --- end-to-end wire integrity (CHECKSUM_FLAG) ----------------------------
@@ -388,11 +397,12 @@ def recv_into(sock: socket.socket, view: memoryview) -> None:
     SArray, core_loops.cc:584-618)."""
     n = len(view)
     got = 0
-    while got < n:
-        r = sock.recv_into(view[got:], n - got)
-        if r == 0:
-            raise ConnectionError("peer closed")
-        got += r
+    with releasing():  # blocked in the kernel, and its copy: without the GIL
+        while got < n:
+            r = sock.recv_into(view[got:], n - got)
+            if r == 0:
+                raise ConnectionError("peer closed")
+            got += r
 
 
 def _recv_exact(sock: socket.socket, n: int) -> bytes:
@@ -567,9 +577,16 @@ def recv_message(sock: socket.socket,
     unflagged.  Both failures (:class:`ChecksumError` /
     :class:`LosslessError`) raise AFTER the frame is consumed — drop
     semantics, the stream stays framed."""
-    op, status, flags, seq, key, cmd, version, length, trace, crc, lossless = (
-        recv_header_ex(sock)
-    )
+    return recv_body(sock, recv_header_ex(sock), pool)
+
+
+def recv_body(sock: socket.socket, header: tuple,
+              pool: Optional[FramePool] = None) -> Message:
+    """What :func:`recv_message` does after the header (``header``: what
+    :func:`recv_header_ex` returned): a reader that accounts for its wait for
+    a frame apart from its work on one (the server's serve thread) calls the
+    two halves itself."""
+    op, status, flags, seq, key, cmd, version, length, trace, crc, lossless = header
     payload = recv_payload(sock, length, pool) if length else b""
     try:
         verify_checksum(crc, trace, payload, op=op)
@@ -592,22 +609,28 @@ def _send(sock: socket.socket, msg: Message) -> None:
     # swapping msg.payload for its compressed container
     hdr = msg.encode_header()
     payload = msg.payload
+    # the socket calls are the sender's releasing calls (tracing.releasing):
+    # the kernel's copy of a partition is made by this thread, on the CPU
+    # and without the GIL
     if not payload:
-        sock.sendall(hdr)
+        with releasing():
+            sock.sendall(hdr)
         return
     sendmsg = getattr(sock, "sendmsg", None)
     if sendmsg is None:
         # van object without scatter-gather: header-then-payload, still no
         # concat copy of the payload
-        sock.sendall(hdr)
-        sock.sendall(payload)
+        with releasing():
+            sock.sendall(hdr)
+            sock.sendall(payload)
         return
     # scatter-gather send: header + payload leave in ONE syscall with ZERO
     # payload memcpys (the kernel gathers straight from the caller's
     # buffer) — ps-lite's zero-copy ZPush property (core_loops.cc:538-582)
     bufs = [memoryview(hdr), memoryview(payload)]
     while bufs:
-        sent = sendmsg(bufs)
+        with releasing():
+            sent = sendmsg(bufs)
         while bufs and sent >= len(bufs[0]):
             sent -= len(bufs[0])
             bufs.pop(0)
@@ -616,11 +639,15 @@ def _send(sock: socket.socket, msg: Message) -> None:
 
 
 def send_message(sock: socket.socket, msg: Message, lock: Optional[threading.Lock] = None) -> None:
-    if lock is not None:
-        with lock:
-            _send(sock, msg)
-    else:
+    if lock is None:
         _send(sock, msg)
+        return
+    with releasing():  # the wait for a lane another sender is on
+        lock.acquire()
+    try:
+        _send(sock, msg)
+    finally:
+        lock.release()
 
 
 def connect(host: str, port: int, timeout: float = 30.0,

@@ -47,6 +47,7 @@ from byteps_tpu.common.types import (
 )
 from byteps_tpu.core.ready_table import ReadyTable
 from byteps_tpu.core.scheduler import ScheduledQueue
+from byteps_tpu.core.tracing import releasing
 
 
 @functools.cache
@@ -530,12 +531,6 @@ class PipelineEngine:
     #: a stage thread's longest wait for its queue before it looks at the
     #: stop flag again (and the coarsest the idle account's edges get)
     _POLL_S = 0.2
-    #: a stage thread reads its CPU clock around one task in this many:
-    #: time.thread_time() is a system call, 6 µs on the chip's host where a
-    #: monotonic reading is 0.09 (PERF.md §6 PR 38), and a step has 648 tasks.
-    #: Not a divisor of a model's partition count, so the sample walks
-    #: through a step's tasks
-    _CPU_EVERY = 16
 
     def __init__(self, cfg: Config, ps_client, telemetry=None, tracer=None,
                  flightrec=None) -> None:
@@ -751,7 +746,7 @@ class PipelineEngine:
 
     def _loop(self, q: ScheduledQueue, fn, index: int = 0) -> None:
         from byteps_tpu.core.telemetry import counters, metrics
-        from byteps_tpu.core.tracing import span, tag_thread
+        from byteps_tpu.core.tracing import sampled, span, tag_thread
 
         # values at hand only: nothing is formatted, and no label set is
         # hashed, per task on this path.  The thread's wall clock is its
@@ -766,14 +761,12 @@ class PipelineEngine:
         name = q.queue_type.name + suffix
         span_name = "stage." + name
         waited = metrics().held("stage_wait_seconds", {"stage": name})
-        # one service in _CPU_EVERY on two clocks, the thread's CPU time and
-        # the wall: their ratio is the share of a service the thread runs
-        on_cpu, on_wall = (
-            metrics().held("stage_sample_seconds", {"stage": name, "clock": c})
-            for c in ("cpu", "wall")
-        )
+        # one service in sampled.EVERY on two clocks, the thread's CPU time
+        # and the wall, inside and outside the calls that let the GIL go:
+        # how much of a service the thread runs, how much of it holding the
+        # GIL, and how long it waited to get it back
+        sample = sampled(name)
         idle = _StageIdle(name)
-        served = 0
         while not self._stop.is_set():
             task = q.get_task(timeout=self._POLL_S, waiting=idle)
             if task is None:
@@ -785,8 +778,6 @@ class PipelineEngine:
             # wait and the service together
             if task.enqueued_at:
                 waited.observe(time.monotonic() - task.enqueued_at)
-            served += 1
-            sampled = served % self._CPU_EVERY == 0
             # the task's SERVICE on this stage thread; the wall it does not
             # spend on the CPU it is blocked (a full socket buffer, a device
             # transfer, a lane's send lock) or waiting for the GIL
@@ -794,20 +785,16 @@ class PipelineEngine:
                            key=task.key, tensor=task.tensor_name)
             try:
                 with serving:
-                    if sampled:
-                        cpu0 = time.thread_time()
+                    sample.begin()
                     try:
                         fn(task)
                     finally:
-                        if sampled:
-                            on_cpu.observe(time.thread_time() - cpu0)
+                        sample.end()
             except Exception as e:  # surface errors on the handle
                 self._fail_task(
                     task, q.queue_type, repr(e),
                     degraded=isinstance(e, (ConnectionError, OSError)),
                 )
-            if sampled:
-                on_wall.observe(serving.ended - serving.started)
             if index:
                 # how much of the stage its second thread takes (PUSH alone
                 # has one: :meth:`start`)
@@ -1633,9 +1620,11 @@ class PipelineEngine:
 
         n = self.client.num_workers
         if average and n != 1 and np.issubdtype(buf.dtype, np.floating):
-            np.divide(buf, n, out=buf)
+            with releasing():
+                np.divide(buf, n, out=buf)
         counters().bump("h2d_bytes", buf.nbytes)
-        return jax.device_put(buf, where)
+        with releasing("copyh2d.put"):
+            return jax.device_put(buf, where)
 
     # --- recovery plane (docs/robustness.md "healing flow") --------------
 
@@ -1776,11 +1765,18 @@ class PipelineEngine:
             self._proceed(task)
             return
         if job.d2h_parts is not None:
-            task.cpubuff = np.asarray(job.d2h_parts.pop(task.offset)).reshape(-1)
+            # the wait for the copy in flight, by name: a gap of the device
+            # under this stage is the chip's copy or the thread's Python
+            with releasing("copyd2h.wait"):
+                task.cpubuff = np.asarray(job.d2h_parts.pop(task.offset)).reshape(-1)
             counters().bump("d2h_prefetched_parts")
         else:
             sl = job.flat[task.offset : task.offset + task.length]
-            task.cpubuff = sl if isinstance(sl, np.ndarray) else np.asarray(sl)
+            if isinstance(sl, np.ndarray):
+                task.cpubuff = sl
+            else:
+                with releasing("copyd2h.wait"):
+                    task.cpubuff = np.asarray(sl)
         if job.is_jax:
             counters().bump("d2h_bytes", task.cpubuff.nbytes)
         self._proceed(task)

@@ -646,6 +646,9 @@ def _rule_slow_step(rec: "FlightRecorder", r: dict) -> Optional[dict]:
         parts = {k: host[k] for k in ("dispatch_s", "fold_s", "caller_s")}
         return _rounded({"step": host["step"], "interval_s": dur, "median_s": med,
                          **parts, "where": max(parts, key=parts.get)[:-2],
+                         # ended by shutdown | exit, not by a next step: what
+                         # the caller did after its loop is in caller_s
+                         **({"closed": True} if host.get("closed") else {}),
                          **{k: host[k] for k in HOST_DELTAS}})
     evidence = {"dur": dur, "median": round(med, 6), "factor": rec.slow_factor,
                 **_rounded(host)}

@@ -227,6 +227,11 @@ def shutdown_state() -> None:
         if st.ps_client is not None:
             st.ps_client.close()
             st.ps_client = None
+        # a compiled loop's last step ends here, while the recorder that
+        # judges it is still the process's
+        from byteps_tpu.core.tracing import close_steps
+
+        close_steps()
         if st.flightrec is not None:
             # drop the process recorder: its context closure holds the
             # closed client, and the next init owns a fresh ring

@@ -683,35 +683,51 @@ class MetricsRegistry:
 
     # --- snapshots -------------------------------------------------------
 
-    def snapshot(self) -> dict:
+    def snapshot(self, labels: Optional[Dict[str, str]] = None) -> dict:
         """Full structured snapshot: counters (flat + labeled), gauges,
         histogram percentiles — the in-process observability surface
         (``bps.get_metrics()``).  Histograms are the COMBINED view:
         local observations plus live histogram providers (the native
-        C++ engines' ``native_*`` families)."""
+        C++ engines' ``native_*`` families).
+
+        ``labels`` are added to every series' own — how a server answers
+        ``Op.METRICS`` (``{role="server", rank=...}``), so that its series
+        stand beside the asking worker's under names of their own; a flat
+        counter then is a labeled one.  Reading moves nothing: the
+        heartbeat delta's baseline stays where the last beat left it."""
+        extra = _label_key(labels)
+        rendered = _render_labels
+        if extra:
+            def rendered(lkey: tuple) -> str:
+                return _render_labels(tuple(sorted(dict(lkey + extra).items())))
+
         with self._lock:
             gauges = dict(self._gauges)
             gauge_fns = dict(self._gauge_fns)
         out = {
             "counters": self.counters.snapshot(),
             "counters_labeled": {
-                name: {_render_labels(k) or "{}": v for k, v in per.items()}
+                name: {rendered(k) or "{}": v for k, v in per.items()}
                 for name, per in self.counters.snapshot_labeled().items()
             },
             "gauges": {
-                name + _render_labels(lkey): v
+                name + rendered(lkey): v
                 for (name, lkey), v in gauges.items()
             },
             "histograms": {},
         }
+        if extra:
+            for name, value in out["counters"].items():
+                out["counters_labeled"].setdefault(name, {})[rendered(())] = value
+            out["counters"] = {}
         for (name, lkey), fn in gauge_fns.items():
             try:
-                out["gauges"][name + _render_labels(lkey)] = float(fn())
+                out["gauges"][name + rendered(lkey)] = float(fn())
             except Exception:  # noqa: BLE001 — a broken gauge can't break scrape
                 continue
         for (name, lkey), st in self._hist_states().items():
             bounds, counts, vsum, count = st
-            out["histograms"][name + _render_labels(lkey)] = {
+            out["histograms"][name + rendered(lkey)] = {
                 "count": count,
                 "sum": vsum,
                 "p50": _state_percentile(bounds, counts, 0.50),

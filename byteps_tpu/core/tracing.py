@@ -34,12 +34,14 @@ file when it is on.
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import json
 import os
 import random
 import threading
 import time
+import weakref
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation, start_trace, stop_trace
@@ -350,6 +352,217 @@ class span:
         return False
 
 
+# --- a thread's service on two clocks --------------------------------------
+
+#: the clocks of a sampled service, by name so that a test can stand others in
+_wall_clock = time.perf_counter
+_cpu_clock = time.thread_time
+
+
+class _Sampling(threading.local):
+    """``acc``: what the releasing calls of this thread's open SAMPLED service
+    have taken so far, as ``[wall, cpu, brackets open]``; None where the
+    thread is in no sampled service (the class's own, so that a thread which
+    never sampled finds it without an AttributeError inside)."""
+
+    acc: Optional[list] = None
+
+
+_sampling = _Sampling()
+
+
+class _Unsampled:
+    """What :func:`releasing` hands a thread that is in no sampled service:
+    one shared object that does nothing on the way in or out."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_UNSAMPLED = _Unsampled()
+
+
+def releasing(name: Optional[str] = None):
+    """Bracket of a call that lets the GIL go while it blocks, ``with
+    releasing(): sock.sendmsg(...)`` (a socket's ``sendmsg`` | ``recv_into``,
+    a lock's acquire, the wait for a device transfer, ``device_put``, a large
+    numpy copy, a ctypes call).  Outside a sampled service (:class:`sampled`)
+    it is one thread-local read and one branch: no clock is read and nothing
+    is made.  Inside one, the bracket's wall and CPU time are added to the
+    service's released part; a bracket inside a bracket adds nothing of its
+    own.
+
+    With ``name`` it is also a :class:`span` — ``bps.<name>`` on the
+    profiler's clock and ``span_seconds{name}`` — so that a device's idle gap
+    under a stage is named by the call the stage thread was blocked in."""
+    acc = _sampling.acc
+    if acc is None:
+        return _UNSAMPLED if name is None else span(name)
+    return _Releasing(acc, span(name) if name else None)
+
+
+class _Releasing:
+    """A :func:`releasing` bracket inside a sampled service."""
+
+    __slots__ = ("_span", "_acc", "_wall", "_cpu")
+
+    def __init__(self, acc: list, named: Optional["span"]) -> None:
+        self._acc = acc
+        self._span = named
+
+    def __enter__(self) -> "_Releasing":
+        if self._span is not None:
+            self._span.__enter__()
+        acc = self._acc
+        acc[2] += 1
+        if acc[2] == 1:
+            self._wall, self._cpu = _wall_clock(), _cpu_clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        acc = self._acc
+        acc[2] -= 1
+        if acc[2] == 0:
+            # the CPU interval INSIDE the wall interval, here and in
+            # sampled.end(): cpu <= wall whatever a clock reading costs or
+            # charges (a thread_time() call is 6 us on a TPU v5e host, whose
+            # CPU clock moves in ticks of 10 ms: read after the wall clock,
+            # a tick that fell in the last reading made a 25 us service read
+            # 227 % on the CPU; PERF.md section 6 PR 71).  So every pair of
+            # intervals leaves about one call of wall outside the CPU: a
+            # sampled service reads gilwait one call long and one call short
+            # a bracket
+            acc[1] += _cpu_clock() - self._cpu
+            acc[0] += _wall_clock() - self._wall
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return False
+
+
+class sampled:
+    """One thread's services, one in ``every`` split on two clocks.
+
+    ``begin()`` ... ``end()`` bound a service; in a sampled one the wall clock
+    W and the thread's CPU clock C are read at both ends, and every
+    :class:`releasing` bracket the thread passes adds its own two readings to
+    Wr and Cr.  ``stage_sample_seconds{stage, clock}`` then holds
+
+    - ``cpu`` = C and ``wall`` = W;
+    - ``held`` = C - Cr: on the CPU outside every releasing call, which a
+      Python thread can only be while it HOLDS the GIL;
+    - ``gilwait`` = (W - Wr) - (C - Cr): off the CPU outside every releasing
+      call — the interpreter's hand-over (or the OS taking the core): a
+      lower bound of the thread's wait for the GIL.
+
+    What is left of W is Cr (on the CPU inside a releasing call: the
+    kernel's copy of a ``sendmsg``) and Wr - Cr (blocked in the call, plus the
+    wait to retake the GIL on the way out); held + gilwait + Wr = W.  ``held``
+    errs upward by whatever releasing call has no bracket.  Threads of one
+    kind (a link's receive threads, a server's serve threads) give the same
+    ``stage`` and share its series; each makes its own ``sampled``."""
+
+    CLOCKS = ("cpu", "wall", "held", "gilwait")
+    #: a thread_time pair is 12 us on the chip's host, and a sampled service
+    #: reads one at its ends and one a bracket, holding the GIL: 16 + 14 a
+    #: bracket us.  At one in 16 (the rate up to PR 70, when a sample was one
+    #: pair) that was 3 ms a VGG-16 step over the nine hop threads and
+    #: `samples_per_s` read 2.3 % lower on four chips; at one in 61 it is
+    #: under a millisecond (PERF.md section 6 PR 71).  Not rarer still: that
+    #: host's thread CPU clock moves in ticks of 10 ms, a sampled service
+    #: reads 0 or one tick, and only the sum over hundreds of them is a
+    #: share.  A prime, so the sample walks through a step's tasks whatever
+    #: their number
+    EVERY = 61
+
+    __slots__ = ("_hists", "_every", "_n", "_acc", "_wall", "_cpu")
+
+    def __init__(self, stage: str, every: Optional[int] = None) -> None:
+        self._hists = [
+            metrics().held("stage_sample_seconds", {"stage": stage, "clock": c})
+            for c in self.CLOCKS
+        ]
+        self._every = every or self.EVERY
+        self._n = 0
+        self._acc: Optional[list] = None
+
+    def begin(self) -> None:
+        self._n += 1
+        if self._n % self._every == 0:
+            self._acc = _sampling.acc = [0.0, 0.0, 0]
+            self._wall, self._cpu = _wall_clock(), _cpu_clock()
+
+    def end(self) -> None:
+        acc = self._acc
+        if acc is None:
+            return
+        cpu, wall = _cpu_clock() - self._cpu, _wall_clock() - self._wall
+        self._acc = _sampling.acc = None
+        held = cpu - acc[1]
+        for hist, value in zip(
+                self._hists, (cpu, wall, held, (wall - acc[0]) - held)):
+            hist.observe(value)
+
+
+#: threads alive by kind (``threads_alive{kind}``): what a kind's account of
+#: a window has to add up to
+_alive: Dict[str, int] = {}
+_alive_lock = threading.Lock()
+
+
+def _count_alive(kind: str, by: int) -> None:
+    with _alive_lock:
+        _alive[kind] = n = _alive.get(kind, 0) + by
+    metrics().gauge_set("threads_alive", n, {"kind": kind})
+
+
+class thread_account:
+    """The wall clock of one thread that serves one frame or task after
+    another, as the server's serve and engine threads do: every moment
+    between ``__init__`` and ``close()`` is in one observation of
+    ``thread_seconds{kind, state="service" | "idle"}`` — ``begin()`` ends an
+    idle stretch and ``end()`` a service, ``tick()`` cuts an idle stretch
+    that may last (a poll's timeout) so that a window's edge cuts little —
+    and one service in ``sampled.EVERY`` is split by :class:`sampled` under
+    ``stage=kind``.
+    One series a KIND of thread: threads come and go with their connections,
+    ``threads_alive{kind}`` says how many there are, and the sum is read."""
+
+    __slots__ = ("_kind", "_service", "_idle", "_sample", "_mark")
+
+    def __init__(self, kind: str) -> None:
+        self._kind = kind
+        self._service, self._idle = (
+            metrics().held("thread_seconds", {"kind": kind, "state": s})
+            for s in ("service", "idle"))
+        self._sample = sampled(kind)
+        self._mark = time.perf_counter()  # up to here all time is accounted
+        _count_alive(kind, 1)
+
+    def tick(self) -> None:
+        now = time.perf_counter()
+        self._idle.observe(now - self._mark)
+        self._mark = now
+
+    def begin(self) -> None:
+        self.tick()
+        self._sample.begin()
+
+    def end(self) -> None:
+        self._sample.end()
+        now = time.perf_counter()
+        self._service.observe(now - self._mark)
+        self._mark = now
+
+    def close(self) -> None:
+        self.tick()
+        _count_alive(self._kind, -1)
+
+
 class stepped:
     """The seam around a compiled training step: what ``jax.jit`` made, called
     through two spans and a clock, so that the compiled path accounts for the
@@ -380,6 +593,11 @@ class stepped:
     committed, so that the second call finds the first's program); every
     later call pays one test for it.
 
+    **A loop's last step** has no next entry to end it: :meth:`close` does,
+    called for every live step by :func:`close_steps` at ``bps.shutdown()``
+    and at interpreter exit, so that step reaches the rule too — with
+    whatever the caller did between it and the close in its ``caller_s``.
+
     One thread calls a step.  Every attribute the jitted function has
     (``lower``, ``trace``, ``eval_shape`` …) is this object's too.
     """
@@ -395,6 +613,8 @@ class stepped:
         self._dispatch_s = self._fold_s = 0.0
         self._interval = metrics().held("train_step_interval_seconds")
         watch_gc()
+        with _open_steps_lock:
+            _open_steps.add(self)
 
     def __getattr__(self, name: str):
         if name == "_jitted":  # not made yet: a copy, an unpickling
@@ -418,8 +638,18 @@ class stepped:
             self._fold_s = fold.ended - fold.started
         return out
 
-    def _account(self, interval: float, readings: tuple) -> None:
-        """The step that the previous entry began, now that it is over."""
+    def close(self) -> None:
+        """End the interval the last entry opened; the next call, if one
+        comes, starts a loop of its own."""
+        if self._entered is not None:
+            self._account(time.perf_counter() - self._entered, host_readings(),
+                          closed=True)
+            self._entered = None
+
+    def _account(self, interval: float, readings: tuple,
+                 closed: bool = False) -> None:
+        """The step that the previous entry began, now that it is over
+        (``closed``: ended by :meth:`close`, and the record says so)."""
         self._interval.observe(interval)
         recorder = get_process_recorder() or ensure_process_recorder()
         if recorder.enabled:
@@ -427,7 +657,25 @@ class stepped:
                 "step": self._n, "dispatch_s": self._dispatch_s,
                 "fold_s": self._fold_s,
                 "caller_s": interval - self._dispatch_s - self._fold_s,
+                **({"closed": True} if closed else {}),
                 **host_deltas(self._readings, readings)})
+
+
+#: every live :class:`stepped`, for :func:`close_steps`
+_open_steps: "weakref.WeakSet[stepped]" = weakref.WeakSet()
+_open_steps_lock = threading.Lock()
+
+
+def close_steps() -> None:
+    """Close the open interval of every compiled step (``bps.shutdown()``,
+    interpreter exit): a loop's last step reaches the ``slow_step`` rule."""
+    with _open_steps_lock:
+        steps = list(_open_steps)
+    for step in steps:
+        step.close()
+
+
+atexit.register(close_steps)
 
 
 @contextlib.contextmanager

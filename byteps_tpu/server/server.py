@@ -49,11 +49,14 @@ from byteps_tpu.comm.transport import (
     close_socket,
     connect,
     listen,
+    recv_body,
+    recv_header_ex,
     recv_message,
     release_frame,
     send_message,
 )
 from byteps_tpu.comm.rendezvous import GROUP_ALL
+from byteps_tpu.core.tracing import releasing, thread_account
 
 
 def _apply_lr_to_chain(codec, lr: float) -> None:
@@ -509,6 +512,10 @@ class _QuotaBucket:
 
 
 class PSServer:
+    #: an engine thread's longest wait for its queue before it looks at the
+    #: stop flag again (and the coarsest its idle account's edges get)
+    _POLL_S = 0.2
+
     def __init__(self, cfg: Config, host: str = "127.0.0.1") -> None:
         from byteps_tpu.comm.van import get_van
 
@@ -1850,10 +1857,17 @@ class PSServer:
         # this connection's receive buffers: a pushed partition lands in
         # memory the process already holds (transport.FramePool)
         pool = FramePool()
+        # this thread's wall clock (tracing.thread_account, kind "serve"):
+        # idle = blocked for the next header, service = header parsed →
+        # frame enqueued or answered (payload receive, integrity, _enqueue)
+        account = thread_account("serve")
         try:
             while not self._stop.is_set():
+                header = recv_header_ex(conn)
+                account.begin()
                 try:
-                    msg = recv_message(conn, pool)
+                    if not self._serve_frame(conn, send_lock, header, pool):
+                        return
                 except (ChecksumError, LosslessError) as e:
                     # end-to-end wire integrity (docs/robustness.md "Wire
                     # integrity"): a flipped payload bit that survived
@@ -1877,59 +1891,82 @@ class PSServer:
                     if ck_limit and ck_fails >= ck_limit:
                         counters().bump("wire_checksum_conn_drop")
                         return
-                    continue
-                if msg.op in (Op.PUSH, Op.PULL, Op.INIT, Op.FUSED):
-                    self._enqueue(msg, conn, send_lock)
-                elif msg.op == Op.RESYNC_QUERY:
-                    # recovery plane (docs/robustness.md): answered inline —
-                    # a read-mostly snapshot of the exactly-once ledger,
-                    # and the asking worker is stalled on it
-                    self._handle_resync(msg, conn, send_lock)
-                elif msg.op == Op.MIGRATE_STATE:
-                    # resharding plane: a peer server ships one key's
-                    # authoritative state — installed inline (the sender
-                    # blocks on the ack, and parked requests wake here)
-                    self._handle_migrate(msg, conn, send_lock)
-                elif msg.op == Op.REGISTER_COMPRESSOR and msg.flags & 1:
-                    # lr update for every EF chain (flag bit 0; payload =
-                    # big-endian f64) — the wire replacement for the
-                    # reference's lr.s mmap (vanilla_error_feedback.h:44-58).
-                    # Malformed sizes are acked and ignored like the C++
-                    # engine (ps_server.cc payload.size()==8 guard)
-                    import struct as _struct
-
-                    if len(msg.payload) == 8:
-                        (lr,) = _struct.unpack("!d", msg.payload)
-                        self._ef_lr = lr  # late-registered chains inherit it
-                        with self._keys_lock:
-                            chains = [ks.compressor for ks in self._keys.values()]
-                        for c in chains:
-                            _apply_lr_to_chain(c, lr)
-                    send_message(conn, Message(Op.REGISTER_COMPRESSOR, seq=msg.seq), send_lock)
-                elif msg.op == Op.REGISTER_COMPRESSOR:
-                    # compressor registration init-push (server.cc:228-257);
-                    # server chain skips momentum (compressor_registry.cc:44);
-                    # payload is key=value lines (shared with the C++ server)
-                    from byteps_tpu.compression.registry import create_compressor
-
-                    ks = self._key_state(msg.key)
-                    kwargs = dict(
-                        ln.split("=", 1)
-                        for ln in msg.payload.decode().splitlines() if "=" in ln
-                    )
-                    with ks.lock:
-                        ks.compressor_kwargs = kwargs
-                        size = ks.store.size if ks.store is not None else 0
-                        ks.compressor = create_compressor(kwargs, size, server=True)
-                        _apply_lr_to_chain(ks.compressor, self._ef_lr)
-                    send_message(conn, Message(Op.REGISTER_COMPRESSOR, seq=msg.seq), send_lock)
-                elif msg.op == Op.PING:
-                    send_message(conn, Message(Op.PING, seq=msg.seq), send_lock)
-                elif msg.op == Op.SHUTDOWN:
-                    send_message(conn, Message(Op.SHUTDOWN, seq=msg.seq), send_lock)
-                    return
+                finally:
+                    account.end()
         except (ConnectionError, OSError):
             return
+        finally:
+            account.close()
+
+    def _serve_frame(self, conn, send_lock, header: tuple,
+                     pool: FramePool) -> bool:
+        """One frame on its connection's serve thread, from the parsed
+        header on: receive the rest and enqueue it for its engine thread or
+        answer it here; False when the peer said SHUTDOWN."""
+        msg = recv_body(conn, header, pool)
+        if msg.op in (Op.PUSH, Op.PULL, Op.INIT, Op.FUSED):
+            self._enqueue(msg, conn, send_lock)
+        elif msg.op == Op.RESYNC_QUERY:
+            # recovery plane (docs/robustness.md): answered inline —
+            # a read-mostly snapshot of the exactly-once ledger,
+            # and the asking worker is stalled on it
+            self._handle_resync(msg, conn, send_lock)
+        elif msg.op == Op.MIGRATE_STATE:
+            # resharding plane: a peer server ships one key's
+            # authoritative state — installed inline (the sender
+            # blocks on the ack, and parked requests wake here)
+            self._handle_migrate(msg, conn, send_lock)
+        elif msg.op == Op.REGISTER_COMPRESSOR and msg.flags & 1:
+            # lr update for every EF chain (flag bit 0; payload =
+            # big-endian f64) — the wire replacement for the
+            # reference's lr.s mmap (vanilla_error_feedback.h:44-58).
+            # Malformed sizes are acked and ignored like the C++
+            # engine (ps_server.cc payload.size()==8 guard)
+            import struct as _struct
+
+            if len(msg.payload) == 8:
+                (lr,) = _struct.unpack("!d", msg.payload)
+                self._ef_lr = lr  # late-registered chains inherit it
+                with self._keys_lock:
+                    chains = [ks.compressor for ks in self._keys.values()]
+                for c in chains:
+                    _apply_lr_to_chain(c, lr)
+            send_message(conn, Message(Op.REGISTER_COMPRESSOR, seq=msg.seq), send_lock)
+        elif msg.op == Op.REGISTER_COMPRESSOR:
+            # compressor registration init-push (server.cc:228-257);
+            # server chain skips momentum (compressor_registry.cc:44);
+            # payload is key=value lines (shared with the C++ server)
+            from byteps_tpu.compression.registry import create_compressor
+
+            ks = self._key_state(msg.key)
+            kwargs = dict(
+                ln.split("=", 1)
+                for ln in msg.payload.decode().splitlines() if "=" in ln
+            )
+            with ks.lock:
+                ks.compressor_kwargs = kwargs
+                size = ks.store.size if ks.store is not None else 0
+                ks.compressor = create_compressor(kwargs, size, server=True)
+                _apply_lr_to_chain(ks.compressor, self._ef_lr)
+            send_message(conn, Message(Op.REGISTER_COMPRESSOR, seq=msg.seq), send_lock)
+        elif msg.op == Op.METRICS:
+            # observability plane (docs/observability.md "One scrape, both
+            # ends"): this process's registry as it stands, for the worker
+            # whose get_metrics() asked; a read, so the heartbeat's delta
+            # ships what it would have shipped
+            from byteps_tpu.core.telemetry import metrics
+
+            snapshot = metrics().snapshot(
+                labels={"role": "server", "rank": str(self.rank)})
+            send_message(conn, Message(Op.METRICS, seq=msg.seq,
+                                       payload=json.dumps(snapshot).encode()),
+                         send_lock)
+        elif msg.op == Op.PING:
+            send_message(conn, Message(Op.PING, seq=msg.seq), send_lock)
+        elif msg.op == Op.SHUTDOWN:
+            send_message(conn, Message(Op.SHUTDOWN, seq=msg.seq), send_lock)
+            return False
+        return True
 
     def _child_span(self, trace, key: int, name: str, t0: float,
                     dur: float, **extra) -> None:
@@ -2014,36 +2051,50 @@ class PSServer:
     # --- engine plane ----------------------------------------------------
 
     def _engine_loop(self, q: _EngineQueue) -> None:
-        while not self._stop.is_set():
-            item = q.get(timeout=0.2)
-            if item is None:
-                continue
-            msg, conn, send_lock, t_enq = item
-            try:
-                if msg.op == Op.INIT:
-                    self._handle_init(msg, conn, send_lock)
-                elif msg.op == Op.PUSH:
-                    self._handle_push(msg, conn, send_lock, t_enq)
-                elif msg.op == Op.PULL:
-                    self._handle_pull(msg, conn, send_lock, t_enq)
-                elif msg.op == Op.FUSED:
-                    self._handle_fused(msg, conn, send_lock, t_enq)
-            except (ConnectionError, OSError):
-                continue
-            except Exception as e:  # noqa: BLE001
-                # A malformed request (truncated compressed payload, skewed
-                # dtype, out-of-range topk index, …) must never kill the
-                # engine thread — every key pinned to it would stop being
-                # served.  Drop the offending connection, mirroring the
-                # native server's malformed-payload handling.
-                from byteps_tpu.common import logging as bpslog
+        # this thread's wall clock (tracing.thread_account, kind "engine"):
+        # service = dequeued → handler returned (sum, publish, the reply's
+        # send_message, _flush_pulls), idle = its queue empty
+        account = thread_account("engine")
+        try:
+            while not self._stop.is_set():
+                item = q.get(timeout=self._POLL_S)
+                if item is None:
+                    account.tick()
+                    continue
+                account.begin()
+                try:
+                    self._engine_serve(*item)
+                finally:
+                    account.end()
+        finally:
+            account.close()
 
-                bpslog.warning(
-                    "dropping connection after malformed request key=%d op=%d: %r",
-                    msg.key, int(msg.op), e,
-                )
-                close_socket(conn)  # FIN even while the serve thread recvs
-                continue
+    def _engine_serve(self, msg: Message, conn, send_lock, t_enq) -> None:
+        """One dequeued frame on its engine thread."""
+        try:
+            if msg.op == Op.INIT:
+                self._handle_init(msg, conn, send_lock)
+            elif msg.op == Op.PUSH:
+                self._handle_push(msg, conn, send_lock, t_enq)
+            elif msg.op == Op.PULL:
+                self._handle_pull(msg, conn, send_lock, t_enq)
+            elif msg.op == Op.FUSED:
+                self._handle_fused(msg, conn, send_lock, t_enq)
+        except (ConnectionError, OSError):
+            pass
+        except Exception as e:  # noqa: BLE001
+            # A malformed request (truncated compressed payload, skewed
+            # dtype, out-of-range topk index, …) must never kill the
+            # engine thread — every key pinned to it would stop being
+            # served.  Drop the offending connection, mirroring the
+            # native server's malformed-payload handling.
+            from byteps_tpu.common import logging as bpslog
+
+            bpslog.warning(
+                "dropping connection after malformed request key=%d op=%d: %r",
+                msg.key, int(msg.op), e,
+            )
+            close_socket(conn)  # FIN even while the serve thread recvs
 
     def _handle_init(self, msg: Message, conn, send_lock) -> None:
         """Init push = allocate + cross-worker barrier (server.cc:266-295).
@@ -2435,7 +2486,8 @@ class PSServer:
                 ks.compressor.sum_into(msg.payload, ks.store)
                 ks.store_version += 1
             else:
-                self._reducer(ks.store, arr)
+                with releasing():
+                    self._reducer(ks.store, arr)
                 ks.store_version += 1
         elif ks.opt_rule is not None and ks.opt_step == 0:
             # sync server-opt seed round: every worker pushes the SAME
@@ -2459,10 +2511,14 @@ class PSServer:
                 ks.compressor.sum_into(msg.payload, ks.accum)
             ks.recv_count += 1
         elif ks.recv_count == 0:
-            ks.accum[: len(arr)] = arr  # COPY_FIRST (server.cc:296)
+            # numpy copies a partition, and the reducer sums one, without
+            # the GIL: releasing calls both (tracing.releasing)
+            with releasing():
+                ks.accum[: len(arr)] = arr  # COPY_FIRST (server.cc:296)
             ks.recv_count += 1
         else:
-            self._reducer(ks.accum, arr)  # SUM_RECV
+            with releasing():
+                self._reducer(ks.accum, arr)  # SUM_RECV
             ks.recv_count += 1
         ks.pushed_total += 1
         self._record_push_locked(ks, msg)

@@ -8,7 +8,7 @@ variants and the cases no other family has, and takes the shared ones with
 blocked reference, in the ``_pieces`` file).
 
 **The budget of a family's tests** (tier-1 runs ``-m 'not slow'`` on six
-``xdist`` workers, ``--dist loadfile``, cold, inside 1470 s; the cost is
+``xdist`` workers, ``--dist load``, cold, inside 1470 s; the cost is
 XLA's compile time, which grows with the depth a case compiles and with the
 number of distinct programs it makes, not with widths or tokens):
 
@@ -26,9 +26,10 @@ number of distinct programs it makes, not with widths or tokens):
 - cases that read one lowered text or one compiled function share it through
   a module-scoped fixture; a reference walked once runs under ``jax.jit`` (one
   program, not an eager program an operation and shape);
-- ``loadfile`` hands out the files with the MOST cases first (xdist's
-  ``loadscopereorder``): a file of a dozen heavy cases starts last and is the
-  run's tail, so heavy cases go where many light ones are.
+- ``--dist load`` deals the cases one by one, in collection order, to
+  whichever worker is free: a dozen heavy cases at the end of the order are
+  the run's tail on however few workers take them, so heavy cases go where
+  many light ones follow.
 """
 
 import dataclasses

@@ -44,6 +44,18 @@ def test_one_json_line_with_positive_medians(reading, mode):
         assert 0 < side["p10_ms"] <= side["median_ms"] <= side["p90_ms"]
 
 
+def test_brackets_size_the_split_of_a_sampled_service(reading):
+    """A bracket outside a sampled service reads no clock, so it is the
+    cheaper; the three sending passes differ in their sampling alone."""
+    got = reading("brackets")
+    assert 0 < got["bracket_unsampled_us"] < got["bracket_sampled_us"]
+    assert 0 < got["service_unsampled_us"] < got["service_sampled_us"]
+    assert got["thread_time_pair_us"] > 0
+    for name in ("as_the_program", "every_one", "none"):
+        side = got["sent_sampling_" + name]
+        assert 0 < side["p10_ms"] <= side["median_ms"] <= side["p90_ms"]
+
+
 def test_echo_reads_one_connection_and_one_a_direction(reading):
     """``split`` is ``held`` with the pulls and their replies on a second
     connection to the same child (a TCP link's push lane and pull lane),

@@ -131,6 +131,25 @@ def test_every_step_builder_of_optim_returns_through_the_seam():
     assert isinstance(zero1, tracing.stepped)
 
 
+def test_a_loops_last_step_is_closed_at_shutdown(recorder):
+    """A two-step loop has two intervals: the second step has no next entry
+    to end it, ``close_steps()`` (``bps.shutdown()``, interpreter exit) does,
+    and the recorder that feeds ``slow_step`` holds both."""
+    step = tracing.stepped(Standin())
+    x = step(step(0))
+    assert x == 2 and hist("train_step_interval_seconds")["count"] == 1
+    time.sleep(EVEN)
+    tracing.close_steps()
+    intervals = hist("train_step_interval_seconds")
+    assert intervals["count"] == 2 and intervals["sum"] >= EVEN
+    assert [r["host"]["step"] for r in recorder.snapshot()] == [1, 2]
+    assert [r["host"].get("closed", False) for r in recorder.snapshot()] == [False, True]
+    tracing.close_steps()  # closed is closed: nothing is counted twice
+    assert hist("train_step_interval_seconds")["count"] == 2
+    step(x)  # a call after the close starts a loop of its own: no interval yet
+    assert hist("train_step_interval_seconds")["count"] == 2
+
+
 def test_the_fold_has_its_span_and_gives_the_caller_what_is_left():
     step = tracing.stepped(lambda x: (x, "counts"), fold=lambda out: out[:1])
     for i in range(3):

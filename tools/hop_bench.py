@@ -14,6 +14,10 @@ device metric.  One JSON line on stdout: median, p10, p90 ms a frame over
 tree's ``send_message`` / ``recv_message``, each acked by a bare header, with
 and without a 2-round 64 MiB ``RoundJournal``.  The payload is a read-only owned
 ``ndarray``, as ``np.asarray(slice)`` is on the TPU: the journal keeps a reference.
+``--mode brackets`` (PR 71): µs a call of ``core/tracing``'s ``releasing`` bracket
+and ``sampled`` service, unsampled and sampled, and ``--mode frame``'s frames
+with the sender's service sampled at the program's rate (one in
+``sampled.EVERY``), every one, or never.
 ``--mode echo``: ``--frames`` partitions pushed and pulled back over ONE
 connection from a child shaped like the server: a serve thread a connection that
 receives, an engine thread that copies into the store, acks and replies.  Five
@@ -230,6 +234,58 @@ def frame(frames: int, nbytes: int) -> dict:
     return out
 
 
+def brackets(frames: int, nbytes: int) -> dict:
+    """What the split of a sampled service costs (``core/tracing.releasing`` |
+    ``sampled``), in µs a call over 20 000 calls: an empty bracket outside a
+    sampled service (the flag and its branch), one inside (four clock
+    readings), a service's ``begin()`` + ``end()`` unsampled and sampled, and
+    a ``time.thread_time()`` pair alone.  Then ``--frames`` frames one way as
+    ``--mode frame``'s ``journal_off`` has them, the sender inside a service of
+    which one in ``sampled.EVERY`` (the program's rate), every one or none is
+    sampled: whether the brackets show in a sender's service."""
+    from byteps_tpu.core import tracing
+
+    def each_us(fn, calls=20_000):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            fn()
+        return (time.perf_counter() - t0) / calls * 1e6
+
+    def bracket():
+        with tracing.releasing():
+            pass
+
+    def service(sample):
+        def one():
+            sample.begin()
+            sample.end()
+        return one
+
+    out = {"bracket_unsampled_us": each_us(bracket)}
+    every_one = tracing.sampled("hop_bench.bracket", every=1)
+    every_one.begin()
+    out["bracket_sampled_us"] = each_us(bracket)
+    every_one.end()
+    out["service_unsampled_us"] = each_us(service(tracing.sampled("hop_bench.never", every=1 << 62)))
+    out["service_sampled_us"] = each_us(service(tracing.sampled("hop_bench.always", every=1)))
+    out["thread_time_pair_us"] = each_us(lambda: time.thread_time() - time.thread_time())
+    pool = _payloads(nbytes)
+    for name, every in (("as_the_program", None), ("every_one", 1), ("none", 1 << 62)):
+        sample = tracing.sampled("hop_bench." + name, every=every)
+        acks = threading.Semaphore(0)
+        with _connected(engine_threads=0) as (sock,):
+            def push(i, version, sample=sample, sock=sock):
+                sample.begin()
+                try:
+                    send_message(sock, Message(Op.PUSH, key=i, seq=i, payload=pool[i % POOL]))
+                finally:
+                    sample.end()
+
+            _on_each_header(sock, lambda op, key, acks=acks: acks.release())
+            out["sent_sampling_" + name] = _passes(frames, push, acks)
+    return out
+
+
 def echo(frames: int, nbytes: int) -> dict:
     pool = _payloads(nbytes)
     return {"fresh": _echo_passes(pool, frames, nbytes, held=False),
@@ -409,7 +465,7 @@ def h2d(frames: int, nbytes: int) -> dict:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--mode", choices=("frame", "echo", "d2h", "h2d"), required=True)
+    ap.add_argument("--mode", choices=("frame", "brackets", "echo", "d2h", "h2d"), required=True)
     ap.add_argument("--frames", type=int, default=None,
                     help="frames a pass (default: 150 one way, 162 echoed, read or put — a vgg16 step)")
     ap.add_argument("--bytes", type=int, default=4_096_000, help="bytes a frame")
@@ -417,7 +473,8 @@ def main() -> None:
     frames = (150 if args.mode == "frame" else 162) if args.frames is None else args.frames
     if frames < 1 or args.bytes < 1:
         ap.error("--frames and --bytes are positive")
-    reading = {"frame": frame, "echo": echo, "d2h": d2h, "h2d": h2d}[args.mode](frames, args.bytes)
+    reading = {"frame": frame, "brackets": brackets, "echo": echo, "d2h": d2h,
+               "h2d": h2d}[args.mode](frames, args.bytes)
     print(json.dumps({"mode": args.mode, "frames": frames, "bytes": args.bytes,
                       "timed_passes": REPS, "host_cores": os.cpu_count(),
                       "plane": "host", **reading}), flush=True)
